@@ -5,7 +5,8 @@ python/ray/_private/accelerators/tpu.py: /dev/accel* probing :98, metadata
 reads :150-210, pod-type parsing :240-300, TPU_VISIBLE_CHIPS visibility
 :360-397). Detection order per question:
 
-  chips      TPU_CHIPS_PER_HOST_BOUNDS -> /dev/accel* -> derived from type
+  chips      /dev/accel* -> /dev/vfio/<n> -> TPU_CHIPS_PER_HOST_BOUNDS
+             -> derived from type
   pod type   TPU_ACCELERATOR_TYPE (GKE) -> GCE metadata accelerator-type
   worker idx TPU_WORKER_ID (GKE)        -> GCE metadata agent-worker-number
   slice name TPU_NAME                   -> GCE metadata instance-id
@@ -117,7 +118,31 @@ class TpuAcceleratorManager(AcceleratorManager):
             )
         return self._metadata_cache[path]
 
+    def _count_device_nodes(self) -> int:
+        """Chips this host exposes as device nodes: /dev/accel<n> (the
+        accel driver) or, on VMs that pass chips through VFIO (v5e, v6e),
+        the numbered IOMMU groups /dev/vfio/<n> (reference: tpu.py
+        get_current_node_num_accelerators globs both)."""
+
+        def names(path: str) -> List[str]:
+            try:
+                return os.listdir(path)
+            except OSError:
+                return []
+
+        n_accel = sum(d.startswith("accel") for d in names(self._dev_dir))
+        return n_accel or sum(
+            d.isdigit() for d in names(os.path.join(self._dev_dir, "vfio"))
+        )
+
     def get_current_node_num_accelerators(self) -> int:
+        # Device nodes first: they are what a process can actually open.
+        # The bounds variable describes the slice image, not this host — a
+        # one-chip v5e machine was seen with TPU_CHIPS_PER_HOST_BOUNDS=2,2,1
+        # and a single /dev/vfio/0, and jax found one device there.
+        n_dev = self._count_device_nodes()
+        if n_dev:
+            return n_dev
         bounds = self._env.get("TPU_CHIPS_PER_HOST_BOUNDS")
         if bounds:
             try:
@@ -127,16 +152,8 @@ class TpuAcceleratorManager(AcceleratorManager):
                 return n
             except ValueError:
                 pass
-        try:
-            n_dev = sum(
-                1 for d in os.listdir(self._dev_dir) if d.startswith("accel")
-            )
-        except OSError:
-            n_dev = 0
-        if n_dev:
-            return n_dev
         # Last resort: a declared pod type implies this host's chip count
-        # (GKE sets the type env without exposing /dev/accel to the probe).
+        # (GKE sets the type env without exposing device nodes to the probe).
         pod_type = self.get_current_node_accelerator_type()
         if pod_type:
             parsed = parse_pod_type(pod_type)

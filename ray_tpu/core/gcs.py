@@ -1220,9 +1220,28 @@ class GcsService(ChaosPartitionRpc):
             chosen = best
         return {k: v for k, v in chosen.items() if k != "_used"}
 
+    def _forgive_own_stall(self, stalled_s: float) -> None:
+        """The failure detector must not charge nodes for ITS OWN pause:
+        while this process did not run, heartbeats could be neither
+        received nor recorded. Seen on a sandboxed one-host TPU machine,
+        where a worker's TPU runtime start-up freezes every process of
+        the host for ~8 s — longer than the heartbeat timeout — so the
+        GCS woke up, found every heartbeat stale and fenced the node,
+        killing the very worker that was opening the chip."""
+        _log.warning(
+            "health loop did not run for %.1fs; crediting it to node heartbeats",
+            stalled_s,
+        )
+        for sh in self._shards:
+            with self._locked(sh):
+                for n in sh.nodes.values():
+                    if n["alive"]:
+                        n["last_hb"] += stalled_s
+
     def _health_loop(self):
         tick = 0
         snap_every = max(1, int(CONFIG.gcs_snapshot_interval_s / 0.1))
+        last_check = time.monotonic()
         while not self._stop.wait(0.1):
             self._process_frees()
             tick += 1
@@ -1249,6 +1268,12 @@ class GcsService(ChaosPartitionRpc):
                 # in-memory _actor_restarting set dedupes overlapping
                 # sweeps per actor).
                 self._kick_stranded_restarts()
+            # Consecutive liveness checks are ~0.1 s apart; a gap of
+            # seconds means this thread (or the whole host) stood still.
+            now = time.monotonic()
+            if now - last_check > 1.0:
+                self._forgive_own_stall(now - last_check)
+            last_check = now
             dead = []
             lag_records: List[dict] = []
             sample_lag = tick % 10 == 0 and self._history is not None

@@ -76,6 +76,11 @@ class _ActorState:
 
 class LocalRuntime(Runtime):
     def __init__(self, resources: Optional[Dict[str, float]] = None, num_cpus: Optional[float] = None):
+        # Tasks and actors run as threads of THIS process, so it is the
+        # one that compiles (and, on a TPU host, the one that owns the chip).
+        from ..utils import compile_cache
+
+        compile_cache.configure()
         self._objects: Dict[ObjectID, Tuple[int, Any]] = {}
         self._futures: Dict[ObjectID, concurrent.futures.Future] = {}
         self._obj_lock = threading.Lock()

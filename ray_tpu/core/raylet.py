@@ -973,6 +973,12 @@ class RayletService(ChaosPartitionRpc):
                 w = self._workers.get(wid)
             if w:
                 w.proc.kill()
+                # kill() returns once the process is GONE (bounded): an
+                # accelerator belongs to one process, so the next actor to
+                # open the chip must not race the dying owner's teardown.
+                deadline = time.monotonic() + 10.0
+                while w.proc.poll() is None and time.monotonic() < deadline:
+                    time.sleep(0.005)
         self._gcs_call_fenced(
             "kill_actor", "actor_died", actor_id, "killed via kill()",
             no_restart, self.node_id,

@@ -253,6 +253,13 @@ class KVPoolExhaustedError(BackpressureError):
         )
 
 
+class EngineFailedError(RayTpuError):
+    """The LLM engine lost device state no later request can run without:
+    a jitted step raised after the KV page pool had been donated into it,
+    so the pool buffer is deleted. The engine stops and fails every
+    request with this error — the replica must be replaced, not retried."""
+
+
 class BatchItemError(RayTpuError):
     """One item of a `@serve.batch` invocation failed. The batch handler
     signalled a per-item failure (an Exception instance in that item's

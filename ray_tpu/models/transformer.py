@@ -31,7 +31,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..parallel.ring_attention import attention_reference, ring_attention
 from ..parallel.ulysses import ulysses_attention
@@ -445,11 +445,20 @@ def build_train_step(
     """
     import optax
 
+    # State starts replicated over the mesh, as the step returns it: a leaf
+    # left on the default device (the params, adam's scalar count) would
+    # give the second call a new input type — a second compile of the
+    # whole step — and, on several devices, pile the model on device 0.
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    def init_on_mesh(rng):
+        return jax.device_put(init_params(rng, cfg), replicated)
+
     if zero_axis is None:
 
         def init_state(rng):
-            params = init_params(rng, cfg)
-            return params, tx.init(params)
+            params = init_on_mesh(rng)
+            return params, jax.device_put(tx.init(params), replicated)
 
         def train_step(params, opt_state, tokens):
             loss, grads = jax.value_and_grad(next_token_loss)(
@@ -477,7 +486,7 @@ def build_train_step(
     )
 
     def init_state(rng):
-        params = init_params(rng, cfg)
+        params = init_on_mesh(rng)
         return params, _zero.init_opt_state(tx, params, mesh, zero_axis)
 
     return init_state, step

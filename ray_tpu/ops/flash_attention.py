@@ -20,36 +20,28 @@ Design notes (TPU-first):
   ~2x FLOP saving at long sequence;
 - backward = two kernels (dq; dk/dv) recomputing probabilities from the
   saved logsumexp, the standard flash-backward decomposition;
-- `interpret=True` (auto-selected off-TPU) runs the same kernels on CPU for
-  tests; the multi-chip ring/Ulysses paths compose on top of this per-shard
+- `interpret=True` (selected when this process's backend is not a TPU) runs
+  the same kernels on CPU for tests; the multi-chip ring/Ulysses paths compose on top of this per-shard
   kernel via shard_map.
 """
 
 from __future__ import annotations
 
 import functools
+import os as _os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is unavailable on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
-import os as _os
+from jax.experimental.pallas import tpu as pltpu
 
 # Tile sizes are tunable per chip generation (VMEM budget vs pipelining):
 # RAY_TPU_FLASH_BLOCK_Q / RAY_TPU_FLASH_BLOCK_K override the defaults.
-# 1024/1024 won the v5e sweep (0.511 -> 0.564 MFU on the 350M bench vs
-# 512/512; 2048-wide k blocks overflow VMEM); shorter sequences fall back
-# to the largest dividing tile automatically (_pick_block).
+# 1024/1024 came from a v5e sweep that predates PR 1 (not re-measured on
+# today's code; 2048-wide k blocks overflowed VMEM); shorter sequences take
+# the largest dividing tile automatically (_pick_block).
 DEFAULT_BLOCK = int(_os.environ.get("RAY_TPU_FLASH_BLOCK_Q", 1024))
 DEFAULT_BLOCK_K = int(_os.environ.get("RAY_TPU_FLASH_BLOCK_K", 1024))
 NEG_INF = -1e30
@@ -170,28 +162,36 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
 
 
 def _auto_interpret() -> bool:
-    """True when the computation will run on CPU (tests / virtual meshes).
-
-    Checked in priority order: the framework's platform pin
-    (RAY_TPU_PLATFORM=cpu, set by the test conftest and CPU-mesh scripts),
-    then an overridden jax default device, then the default backend.
-    """
-    import os
-
-    if os.environ.get("RAY_TPU_PLATFORM", "").lower() == "cpu":
-        return True
-    dd = jax.config.jax_default_device
-    if dd is not None:
-        return getattr(dd, "platform", None) == "cpu"
+    """True off-TPU: the Pallas interpreter runs the same kernels on the
+    CPU backend (tests, virtual meshes). A statement about this process's
+    backend only — compiling for a TPU topology from a CPU process must
+    pass interpret=False explicitly."""
     return jax.default_backend() != "tpu"
 
 
 def _pick_block(s: int, want: int) -> Optional[int]:
     """Largest power-of-two tile <= want dividing s; None when s has no
-    8-aligned tiling (caller falls back to the unfused path)."""
+    8-aligned tiling."""
     for b in (want, 512, 256, 128, 64, 32, 16, 8):
         if b <= want and s % b == 0:
             return b
+    return None
+
+
+def _pick_blocks(s: int, block_q: int, block_k: int, interpret: bool):
+    """(bq, bk) tiles for sequence length s, or None when s cannot be tiled
+    AND the kernel is interpreted (CPU tests take the unfused reference for
+    tiny shards). On the compiled TPU path an untileable shape raises: a
+    run that expected the fused kernel must not silently get the reference."""
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    if bq is not None and bk is not None:
+        return bq, bk
+    if not interpret:
+        raise ValueError(
+            f"flash attention cannot tile sequence length {s} (needs a "
+            f"multiple of 8; blocks q={block_q} k={block_k}); pad the "
+            "sequence or use attn_impl='naive'"
+        )
     return None
 
 
@@ -248,8 +248,6 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret, heads):
 
 
 def _scratch(shape, dtype):
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable")
     return pltpu.VMEM(shape, dtype)  # the interpreter accepts VMEM scratch too
 
 
@@ -411,12 +409,13 @@ def flash_attention_with_lse(
     scale = scale if scale is not None else d**-0.5
     if interpret is None:
         interpret = _auto_interpret()
-    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
-    if pltpu is None or bq is None or bk is None:
+    blocks = _pick_blocks(s, block_q, block_k, interpret)
+    if blocks is None:
         if h_kv != h:
             k = jnp.repeat(k, h // h_kv, axis=2)
             v = jnp.repeat(v, h // h_kv, axis=2)
         return reference_attention_with_lse(q, k, v, causal=causal, scale=scale)
+    bq, bk = blocks
 
     def to_bh(x):
         hh = x.shape[2]
@@ -444,9 +443,10 @@ def flash_attention(
 ) -> jax.Array:
     """Fused attention over [batch, seq, heads, head_dim] inputs.
 
-    Exact (not approximate) attention; O(s) memory per core. Falls back to
-    unfused attention for shapes the kernel cannot tile. `interpret` defaults
-    to True off-TPU so the same kernel runs (slowly) on CPU for tests.
+    Exact (not approximate) attention; O(s) memory per core. `interpret`
+    defaults to True off-TPU so the same kernel runs (slowly) on CPU for
+    tests; there, shapes the kernel cannot tile take the unfused reference.
+    Compiled for a TPU, an untileable shape raises instead.
     """
     b, s, h, d = q.shape
     h_kv = k.shape[2]
@@ -455,14 +455,15 @@ def flash_attention(
     scale = scale if scale is not None else d**-0.5
     if interpret is None:
         interpret = _auto_interpret()
-    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
-    if pltpu is None or bq is None or bk is None:
+    blocks = _pick_blocks(s, block_q, block_k, interpret)
+    if blocks is None:
         from ..parallel.ring_attention import attention_reference
 
         if h_kv != h:  # the unfused path wants expanded kv heads
             k = jnp.repeat(k, h // h_kv, axis=2)
             v = jnp.repeat(v, h // h_kv, axis=2)
         return attention_reference(q, k, v, causal=causal, scale=scale)
+    bq, bk = blocks
 
     def to_bh(x):
         hh = x.shape[2]

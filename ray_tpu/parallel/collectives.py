@@ -52,10 +52,7 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    # jax < 0.5 spelling: psum of a literal folds to the static axis size.
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def ring_permute(x: Any, axis: str, *, shift: int = 1):
@@ -93,14 +90,6 @@ def shard_map(
     """`jax.shard_map` with the framework mesh (per-shard programming model
     for kernels that need explicit collectives — ring attention, Ulysses,
     expert dispatch)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    # jax < 0.5: the API lives in jax.experimental and the vma flag is
-    # spelled check_rep (inverted default, same meaning for our uses).
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )

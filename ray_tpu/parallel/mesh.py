@@ -27,20 +27,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-
-def default_devices() -> List[jax.Device]:
-    """Framework device discovery. `RAY_TPU_PLATFORM` pins the backend
-    (tests set it to "cpu" together with xla_force_host_platform_device_count
-    to get a virtual multi-chip mesh on one host)."""
-    platform = os.environ.get("RAY_TPU_PLATFORM")
-    return list(jax.devices(platform) if platform else jax.devices())
 
 # Canonical axis order: outermost (slowest-varying, cheapest link) first.
 # data/fsdp/stage ride DCN across hosts if they must (pipeline transfers
@@ -69,7 +60,7 @@ class TpuTopology:
 
     @staticmethod
     def detect() -> "TpuTopology":
-        devs = default_devices()
+        devs = jax.devices()
         kind = devs[0].platform
         if kind != "tpu":
             return TpuTopology(generation=kind, chips_per_host=len(devs), num_hosts=1)
@@ -131,7 +122,6 @@ def build_mesh(
     spec: Optional[MeshSpec] = None,
     *,
     devices: Optional[Sequence[jax.Device]] = None,
-    axis_sizes: Optional[Dict[str, int]] = None,
 ) -> Mesh:
     """Builds a `jax.sharding.Mesh` with the framework's canonical axes.
 
@@ -142,14 +132,8 @@ def build_mesh(
     the reference's rank-ordering of NCCL communicators
     (reference: python/ray/util/collective/collective_group/nccl_collective_group.py:128).
     """
-    devices = list(devices) if devices is not None else default_devices()
-    if axis_sizes is None:
-        spec = spec or MeshSpec()
-        axis_sizes = spec.resolve(len(devices))
-    else:
-        axis_sizes = {k: axis_sizes.get(k, 1) for k in AXIS_ORDER}
-        if math.prod(axis_sizes.values()) != len(devices):
-            raise ValueError(f"axis sizes {axis_sizes} do not cover {len(devices)} devices")
+    devices = list(devices) if devices is not None else jax.devices()
+    axis_sizes = (spec or MeshSpec()).resolve(len(devices))
     arr = np.array(devices).reshape(tuple(axis_sizes[a] for a in AXIS_ORDER))
     return Mesh(arr, AXIS_ORDER)
 
@@ -164,12 +148,6 @@ def mesh_shape(mesh: Mesh) -> Dict[str, int]:
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec(*spec))
-
-
-def host_local_device_count() -> int:
-    """Devices on this host, honoring the RAY_TPU_PLATFORM override."""
-    this_process = jax.process_index()
-    return sum(1 for d in default_devices() if d.process_index == this_process)
 
 
 def data_parallel_rank(mesh: Mesh) -> int:

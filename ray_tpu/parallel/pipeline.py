@@ -25,8 +25,9 @@ from typing import Any, Callable, List
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from .collectives import shard_map
 
 PyTree = Any
 
@@ -105,14 +106,9 @@ def pipeline_apply(
         return lax.psum(outputs, axis)
 
     spec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    out = shard_map(
-        per_stage,
-        mesh=mesh,
-        in_specs=(spec_params, P()),
-        out_specs=P(),
-        check_rep=False,
+    return shard_map(
+        per_stage, mesh, in_specs=(spec_params, P()), out_specs=P()
     )(stage_params, microbatches)
-    return out
 
 
 def split_stacked_layers(stacked: PyTree, num_stages: int) -> PyTree:

@@ -237,8 +237,6 @@ class LearnerGroup:
             )
             return
 
-        import os
-
         import cloudpickle
 
         from .. import api
@@ -252,7 +250,6 @@ class LearnerGroup:
                 "initialize the cluster runtime (ray_tpu.init()) instead of "
                 "local_mode=True"
             )
-        platform = platform or os.environ.get("RAY_TPU_PLATFORM")
         host = coordinator_host or "127.0.0.1"
         coord = f"{host}:{free_port()}"
         actor_cls = api.remote(num_cpus=1)(_DistributedLearner)

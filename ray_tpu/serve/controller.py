@@ -374,6 +374,11 @@ class Replica:
         return {"ongoing": self._ongoing, "total": self._total}
 
     def health_check(self) -> bool:
+        """Raises what the deployment's own `check_health()` raises
+        (reference: serve's user-defined check_health hook)."""
+        check = getattr(self._callable, "check_health", None)
+        if check is not None:
+            check()
         return True
 
     def prepare_shutdown(self) -> bool:

@@ -86,7 +86,17 @@ class LLMServer:
         return self.feed.attach(resp_spec)
 
     def engine_stats(self) -> dict:
-        return self.engine.stats()
+        stats = self.engine.stats()
+        describe = getattr(self.model, "describe", None)
+        if describe is not None:
+            stats["model"] = describe()
+        return stats
+
+    def check_health(self) -> None:
+        """Replica.health_check calls this: a failed engine fails the
+        replica's health check with its typed error."""
+        if self.engine.failed is not None:
+            raise self.engine.failed
 
     def shutdown_engine(self) -> bool:
         self.feed.close()

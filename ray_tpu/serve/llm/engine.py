@@ -35,7 +35,12 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 
 from ...chaos.controller import kill_now as _chaos_kill
 from ...chaos.controller import maybe_inject as _chaos_inject
-from ...exceptions import BackpressureError, KVPoolExhaustedError, RayTpuError
+from ...exceptions import (
+    BackpressureError,
+    EngineFailedError,
+    KVPoolExhaustedError,
+    RayTpuError,
+)
 from ...utils import internal_metrics as imet
 from ...utils import lock_order
 from .kv_cache import PagedKVAllocator, SeqPages
@@ -124,6 +129,8 @@ class InferenceEngine:
         self._by_rid: Dict[int, _Seq] = {}
         self._cancels: Deque[int] = collections.deque()
         self._stop = False
+        # Set once by _fail(): the model lost state it cannot rebuild.
+        self.failed: Optional[EngineFailedError] = None
         self.shed_total = 0
         self.tokens_emitted = 0
         self.decode_steps = 0
@@ -156,6 +163,8 @@ class InferenceEngine:
                 f"per-sequence KV capacity ({cap} positions)"
             )
         with self._cond:
+            if self.failed is not None:
+                raise self.failed
             if self._stop:
                 raise RuntimeError("engine is shut down")
             if len(self._waiting) >= self.config.max_queue:
@@ -302,6 +311,17 @@ class InferenceEngine:
         eos = self.config.eos_token
         return eos is not None and int(tok) == int(eos)
 
+    def _fail(self, err: EngineFailedError) -> None:
+        """Stops serving: every in-flight and later request gets `err`.
+        Carrying on would answer each of them from a deleted buffer while
+        the replica looked alive."""
+        logger.error("engine %s failed and stopped: %s", self.name, err)
+        with self._cond:
+            self.failed = err
+            self._stop = True
+            for seq in list(self._by_rid.values()):
+                self._finish_locked(seq, "error", err)
+
     def _loop(self) -> None:
         T = self.config.page_tokens
         while True:
@@ -331,6 +351,9 @@ class InferenceEngine:
                     tok = self.model.prefill(
                         seq.prompt, seq.pages.pages, seq.pages.cached_tokens
                     )
+                except EngineFailedError as e:
+                    self._fail(e)
+                    return
                 except Exception as e:  # noqa: BLE001 - fail one request, not the loop
                     err = e
                 prefilled.append((seq, tok, err))
@@ -375,6 +398,9 @@ class InferenceEngine:
                         )
                 next_tokens = self.model.decode(tokens, positions, tables)
                 step_err: Optional[BaseException] = None
+            except EngineFailedError as e:
+                self._fail(e)
+                return
             except Exception as e:  # noqa: BLE001 - batch fail-fast, loop survives
                 next_tokens, step_err = None, e
 
@@ -418,6 +444,7 @@ class InferenceEngine:
                 "tokens_emitted": self.tokens_emitted,
                 "decode_steps": self.decode_steps,
                 "shed_total": self.shed_total,
+                "failed": repr(self.failed) if self.failed is not None else None,
                 "kv": self.alloc.stats(),
             }
 

@@ -19,6 +19,7 @@ import math
 import threading
 from typing import Any, Dict, List, Optional, Sequence
 
+from ...exceptions import EngineFailedError
 from .kv_cache import TRASH_PAGE
 
 
@@ -83,8 +84,10 @@ class PagedLM:
         import jax.numpy as jnp
 
         from ...models import transformer as tfm
+        from ...utils import compile_cache
 
         self._jax, self._jnp, self._tfm = jax, jnp, tfm
+        self._compile_watch = compile_cache.watch()
         if cfg is None:
             cfg = tfm.tiny(attn_impl="naive", dtype=jnp.float32)
         self.cfg = cfg
@@ -102,6 +105,23 @@ class PagedLM:
         # One lock around every jitted call: the engine loop is the only
         # steady-state caller, but tests poke prefill directly.
         self._mu = threading.Lock()
+
+    def describe(self) -> Dict[str, Any]:
+        """Which process and devices serve this model, and what compiling
+        cost so far (LLMServer.engine_stats() carries it out)."""
+        import os
+
+        devs = self._jax.devices()
+        return {
+            "pid": os.getpid(),
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "peak_bytes_in_use": [
+                (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs
+            ],
+            "compile": self._compile_watch.snapshot(),
+        }
 
     # ------------------------------------------------------------- compile
 
@@ -146,6 +166,34 @@ class PagedLM:
 
     # --------------------------------------------------------------- steps
 
+    def _run_step(self, call):
+        """Runs one jitted step `call(kv) -> (tokens, new_kv)`, waits for
+        its tokens on the host and installs the new pool. The wait is
+        inside the try because dispatch is asynchronous: a device-side
+        failure surfaces at the transfer, not at the call. If the step
+        raised after the pool was donated into it, the pool buffer is
+        deleted and nothing can be served any more — that is an
+        EngineFailedError, not a per-request error."""
+        import numpy as np
+
+        with self._mu:
+            kv = self.kv
+            if kv is None:
+                raise EngineFailedError("KV page pool was lost in an earlier failed step")
+            try:
+                out, new_kv = call(kv)
+                host = np.asarray(out)
+            except Exception as e:
+                if kv["k"].is_deleted() or kv["v"].is_deleted():
+                    self.kv = None
+                    raise EngineFailedError(
+                        "jitted step failed after the KV page pool was donated "
+                        f"to it; the pool is gone ({type(e).__name__}: {e})"
+                    ) from e
+                raise
+            self.kv = new_kv
+            return host
+
     def prefill(self, prompt: Sequence[int], pages: Sequence[int], cached_tokens: int) -> int:
         import numpy as np
 
@@ -158,16 +206,17 @@ class PagedLM:
         bt = np.full((bucket,), TRASH_PAGE, dtype=np.int32)
         bt[: len(pages)] = np.asarray(pages, dtype=np.int32)
         fn = self._get_prefill(bucket)
-        with self._mu:
-            tok, self.kv = fn(
+        tok = self._run_step(
+            lambda kv: fn(
                 self.params,
                 toks,
-                self.kv,
+                kv,
                 bt,
                 np.int32(len(prompt)),
                 np.int32(cached_tokens),
             )
-            return int(tok)
+        )
+        return int(tok)
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:
         import numpy as np
@@ -181,9 +230,8 @@ class PagedLM:
         for i, row in enumerate(block_tables):
             bts[i, : len(row)] = np.asarray(row, dtype=np.int32)
         fn = self._get_decode()
-        with self._mu:
-            out, self.kv = fn(self.params, toks, pos, self.kv, bts)
-            return [int(t) for t in np.asarray(out)]
+        out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts))
+        return [int(t) for t in out]
 
 
 def tiny_paged_lm(**kw) -> PagedLM:

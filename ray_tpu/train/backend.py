@@ -27,8 +27,9 @@ from typing import Any, Dict, Optional
 class JaxBackendConfig:
     """(reference analogue: train/torch/config.py TorchConfig)
 
-    platform: None = whatever the worker detects (TPU on real pods);
-        "cpu" = emulation, combined with devices_per_worker.
+    platform: None = what the worker's environment selects (JAX_PLATFORMS,
+        else jax's own discovery — TPU on real pods); "cpu" = emulation,
+        combined with devices_per_worker.
     devices_per_worker: virtual CPU device count per worker process
         (emulation only; None on real TPU hosts where local chips are real).
     coordinator_host: rank-0 rendezvous host. None = loopback (emulated
@@ -71,7 +72,7 @@ def setup_jax_distributed(
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={devices_per_worker}".strip()
         )
-    resolved_platform = platform or os.environ.get("RAY_TPU_PLATFORM")
+    resolved_platform = platform or os.environ.get("JAX_PLATFORMS")
     if world_size > 1 and resolved_platform == "cpu":
         # Deflake (tier-1 "gloo reset"): the CPU thunk runtime executes
         # independent collective thunks CONCURRENTLY, and two in-flight
@@ -94,7 +95,6 @@ def setup_jax_distributed(
         # jax snapshots JAX_PLATFORMS at import; the config update is the
         # reliable override for processes where jax is already imported.
         jax.config.update("jax_platforms", platform)
-        os.environ["RAY_TPU_PLATFORM"] = platform
     if resolved_platform == "cpu" and world_size > 1:
         # Cross-process collectives on the host platform go through gloo
         # (the emulation analogue of ICI; the reference's CPU fallback is

@@ -96,10 +96,7 @@ def load_pytree(directory: str, like: Any = None) -> Any:
                 return ckptr.restore(
                     orbax_path, args=ocp.args.PyTreeRestore(item=like, restore_args=restore_args)
                 )
-            meta = ckptr.metadata(orbax_path)
-            # orbax < 0.6 wraps the tree in .item_metadata; newer versions
-            # return the metadata tree (a dict) directly.
-            tree_meta = getattr(meta, "item_metadata", meta)
+            tree_meta = ckptr.metadata(orbax_path).item_metadata
             restore_args = jax.tree_util.tree_map(
                 lambda _: ocp.RestoreArgs(restore_type=np.ndarray), tree_meta
             )

@@ -2,8 +2,9 @@
 
 Mirrors the reference's ScalingConfig/RunConfig/FailureConfig/
 CheckpointConfig surface (reference: python/ray/air/config.py) with
-TPU-native additions: ScalingConfig speaks topology (`MeshSpec`,
-`topology`) instead of `use_gpu`, and placement is slice-gang-aware.
+TPU-native additions: ScalingConfig speaks a `MeshSpec` instead of
+`use_gpu` (chips are requested like any resource, through
+`resources_per_worker={"TPU": n}`), and placement is slice-gang-aware.
 """
 
 from __future__ import annotations
@@ -23,8 +24,6 @@ class ScalingConfig:
     python/ray/_private/accelerators/tpu.py:334-397)."""
 
     num_workers: int = 1
-    use_tpu: bool = False
-    topology: Optional[str] = None  # e.g. "v5e-8"; None = all local devices
     mesh: MeshSpec = dataclasses.field(default_factory=MeshSpec)
     resources_per_worker: Optional[Dict[str, float]] = None
     placement_strategy: str = "PACK"

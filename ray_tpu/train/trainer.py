@@ -430,16 +430,13 @@ class JaxTrainer:
             #    backend config): every worker-process rendezvouses via
             #    jax.distributed.initialize and builds the GLOBAL mesh;
             #  - single host: each worker builds the local-device mesh.
+            # Either way the MeshSpec resolves IN the worker: an
+            # accelerator belongs to one process, so the driver must never
+            # initialize a jax backend to count devices.
             if self._use_distributed(ws):
-                import os
-
                 from .backend import JaxBackendConfig, coordinator_address
 
                 cfg = sc.backend or JaxBackendConfig()
-                if cfg.platform is None and os.environ.get("RAY_TPU_PLATFORM"):
-                    cfg = dataclasses.replace(
-                        cfg, platform=os.environ["RAY_TPU_PLATFORM"]
-                    )
                 coord = coordinator_address(cfg)
                 api.get(
                     [
@@ -454,10 +451,7 @@ class JaxTrainer:
                     ]
                 )
             else:
-                from ..parallel.mesh import default_devices
-
-                mesh_axes = sc.mesh.resolve(len(default_devices()))
-                api.get([w.setup_mesh.remote(mesh_axes) for w in group.workers])
+                api.get([w.setup_mesh.remote(sc.mesh) for w in group.workers])
 
             blob = cloudpickle.dumps(self._train_loop)
             config = dict(self._config)

@@ -38,11 +38,13 @@ class _TrainWorker:
         fn = cloudpickle.loads(fn_blob)
         return fn(*args, **kwargs)
 
-    def setup_mesh(self, mesh_axes: Dict[str, int]):
-        """Backend hook: build the device mesh this worker participates in."""
+    def setup_mesh(self, mesh_spec=None):
+        """Backend hook: build the device mesh this worker participates in.
+        The spec resolves against THIS process's devices — the worker owns
+        the accelerator; the driver never queries a backend to size it."""
         from ..parallel.mesh import build_mesh
 
-        self._mesh = build_mesh(axis_sizes=mesh_axes) if mesh_axes else build_mesh()
+        self._mesh = build_mesh(mesh_spec)
         return {"devices": int(self._mesh.devices.size)}
 
     def setup_distributed(
@@ -79,19 +81,19 @@ class _TrainWorker:
         config: Dict[str, Any],
         trial_name: str,
         checkpoint_path: Optional[str],
-        setup_mesh_axes: Optional[Dict[str, int]] = "__unset__",  # type: ignore[assignment]
+        setup_mesh_spec="__unset__",
     ):
         import cloudpickle
 
         from .checkpoint import Checkpoint
 
         try:
-            if setup_mesh_axes != "__unset__":
+            if setup_mesh_spec != "__unset__":
                 # Folded-in mesh setup: a concurrent actor
                 # (max_concurrency>1) gives no cross-method ordering, so
                 # callers that must not block on a separate setup_mesh ack
-                # pass the axes here.
-                self.setup_mesh(setup_mesh_axes)
+                # pass the MeshSpec (None = default) here.
+                self.setup_mesh(setup_mesh_spec)
             fn = cloudpickle.loads(fn_blob)
             ckpt = Checkpoint(checkpoint_path) if checkpoint_path else None
             session = init_session(

@@ -235,7 +235,7 @@ class _TuneController:
             {**self._base_config, **trial.config},
             trial.trial_id,
             checkpoint_path or trial.latest_checkpoint,
-            setup_mesh_axes=None,
+            setup_mesh_spec=None,
         )
         trial.status = "RUNNING"
         self._trial_actor[trial.trial_id] = tracked

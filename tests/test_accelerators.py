@@ -111,8 +111,33 @@ def test_chip_count_from_fake_dev_dir(tmp_path):
     assert mgr.get_current_node_num_accelerators() == 4
 
 
-def test_chip_count_env_overrides_dev_dir(tmp_path):
-    (tmp_path / "accel0").touch()
+def test_chip_count_from_vfio_groups(tmp_path):
+    """v5e VMs pass chips through VFIO: no /dev/accel*, one numbered IOMMU
+    group per chip next to the /dev/vfio/vfio control node."""
+    (tmp_path / "vfio").mkdir()
+    for name in ("0", "1", "2", "3", "vfio"):
+        (tmp_path / "vfio" / name).touch()
+    mgr = TpuAcceleratorManager(dev_dir=str(tmp_path), env={}, transport=FakeTransport())
+    assert mgr.get_current_node_num_accelerators() == 4
+
+
+def test_chip_count_device_nodes_beat_env_bounds(tmp_path):
+    """The one-chip v5e machine of PR 21: the image exports the 2x2 slice
+    bounds but the host holds a single /dev/vfio/0, and jax finds one
+    device. Registering TPU: 4 there would schedule actors onto chips that
+    do not exist."""
+    (tmp_path / "vfio").mkdir()
+    (tmp_path / "vfio" / "0").touch()
+    (tmp_path / "vfio" / "vfio").touch()
+    mgr = TpuAcceleratorManager(
+        dev_dir=str(tmp_path),
+        env={"TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1", "TPU_ACCELERATOR_TYPE": "v5litepod-4"},
+        transport=FakeTransport(),
+    )
+    assert mgr.get_current_node_num_accelerators() == 1
+
+
+def test_chip_count_env_bounds_without_device_nodes(tmp_path):
     mgr = TpuAcceleratorManager(
         dev_dir=str(tmp_path),
         env={"TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1"},

@@ -96,10 +96,18 @@ def test_hot_paths_emit_on_two_nodes(two_node):
     assert "raytpu_worker_spawn_total" in names
 
     # Worker-pool gauges ride each raylet's heartbeat: both nodes report.
-    pool_nodes = {
-        m["tags"]["node_id"] for m in recs if m["name"] == "raytpu_worker_pool_idle"
-    }
-    assert cluster.head_node_id in pool_nodes and node2 in pool_nodes
+    # Polled on a FRESH read for the same reason as the method tag below:
+    # `recs` can predate node2's first 1 s-interval heartbeat (it does now
+    # that workers boot without importing jax).
+    def pool_nodes():
+        nodes = {
+            m["tags"]["node_id"]
+            for m in state.internal_metrics()
+            if m["name"] == "raytpu_worker_pool_idle"
+        }
+        return {cluster.head_node_id, node2} <= nodes
+
+    assert _wait_for(pool_nodes)
 
     # GCS RPC metrics carry the method tag. Polled on a FRESH read: the
     # `recs` snapshot above can predate the first 1 s-interval heartbeat

@@ -671,12 +671,25 @@ def test_metrics_history_cluster_acceptance():
         # `ray-tpu top` renders rates + sparklines from the same API.
         from ray_tpu.scripts import render_top
 
-        frame = render_top(
-            lambda m, r: state.metrics_history(m, None, 120.0, r),
-            state.active_alerts(),
-        )
-        assert "alerts: none" in frame
-        assert "tasks/s" in frame and "(no data)" not in frame.split("\n")[1]
+        # Polled, with a NEW resource shape each round: tasks/s is the
+        # raylet's dispatch histogram, and once a shape holds worker leases
+        # its tasks go to the workers directly — the histogram then stops
+        # moving and yields no second sample. Whether the first rounds
+        # straddled two raylet flushes used to depend on how slowly the
+        # workers booted (they no longer import jax at start-up).
+        rounds = iter(range(1000))
+
+        def top_frame():
+            shaped = f.options(num_cpus=0.5 + 0.01 * next(rounds))
+            rt.get([shaped.remote(i) for i in range(4)])
+            frame = render_top(
+                lambda m, r: state.metrics_history(m, None, 120.0, r),
+                state.active_alerts(),
+            )
+            return frame if "(no data)" not in frame.split("\n")[1] else None
+
+        frame = _wait_for(top_frame, timeout=30.0, interval=0.5)
+        assert frame and "alerts: none" in frame and "tasks/s" in frame
     finally:
         rt.shutdown()
 

@@ -73,10 +73,7 @@ def test_sharded_forward_matches_single_device():
 
     sparams = shard_tree(params, mesh)
     stokens = shard_batch({"tokens": tokens}, mesh)["tokens"]
-    # jax < 0.5 has no jax.set_mesh; the Mesh context manager is the old
-    # spelling of the same activation.
-    ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with ctx:
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda p, t: tfm.forward(p, t, cfg))(sparams, stokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), atol=3e-2, rtol=3e-2)
 

@@ -1,5 +1,5 @@
 """Parallel primitives on the virtual 8-device CPU mesh (conftest pins
-RAY_TPU_PLATFORM=cpu with xla_force_host_platform_device_count=8, mirroring
+JAX_PLATFORMS=cpu with xla_force_host_platform_device_count=8, mirroring
 the reference's single-machine multi-node Cluster fixture strategy)."""
 
 import jax

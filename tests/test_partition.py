@@ -87,6 +87,42 @@ def test_heartbeat_from_dead_node_fenced_not_resurrected():
         svc.stop()
 
 
+def test_gcs_own_stall_is_not_charged_to_nodes(monkeypatch):
+    """The failure detector pauses (a TPU runtime start-up freezes every
+    process of a sandboxed host for ~8 s): on waking it must not find every
+    heartbeat stale and fence the node — it credits its own pause. A node
+    that stays silent afterwards still dies."""
+    from ray_tpu.core import gcs as gcs_mod
+    from ray_tpu.core.gcs import GcsService
+
+    monkeypatch.setattr(gcs_mod, "HEARTBEAT_TIMEOUT_S", 0.6)
+    svc = GcsService()
+    try:
+        svc.register_node("nodeS", "/tmp/s.sock", "/tmp/s", {"CPU": 1.0})
+        stalled = threading.Event()
+        real = svc._process_frees
+
+        def freeze_once():
+            if not stalled.is_set():
+                stalled.set()
+                time.sleep(1.5)  # the health loop stands still, past the timeout
+            real()
+
+        monkeypatch.setattr(svc, "_process_frees", freeze_once)
+        assert stalled.wait(5)
+        time.sleep(1.7)  # the loop is back and has run its liveness check
+        assert svc.heartbeat("nodeS", {"CPU": 1.0})["ok"] is True  # not fenced
+        alive = {n["NodeID"]: n["Alive"] for n in svc.list_nodes()}
+        assert alive["nodeS"] is True
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline and alive["nodeS"]:
+            time.sleep(0.1)
+            alive = {n["NodeID"]: n["Alive"] for n in svc.list_nodes()}
+        assert alive["nodeS"] is False  # silence beyond the timeout still kills
+    finally:
+        svc.stop()
+
+
 def test_stale_epoch_rejected_on_mutation_rpcs():
     from ray_tpu.core.gcs import GcsService
 

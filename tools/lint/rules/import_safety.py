@@ -1,8 +1,9 @@
 """import-safety: no ray_tpu module initializes a JAX backend at import.
 
 Plugin wrapper around tools/check_import_safety.py (the bogus-platform
-canary subprocess — see that module for the mechanism and the r5 dryrun
-hang it guards against). Marked slow: it imports the whole package in a
+canary subprocess — see that module for the mechanism: an import that
+initializes a backend takes the chip from the process meant to own it).
+Marked slow: it imports the whole package in a
 child process, so CI surfaces that already run the canary directly
 (tests/test_import_safety.py) invoke the linter with --skip-slow.
 """
@@ -23,7 +24,7 @@ class ImportSafety(Analyzer):
     slow = True
     description = (
         "subprocess canary: importing every ray_tpu module under a bogus "
-        "JAX_PLATFORMS must not initialize a backend (hang guard)"
+        "JAX_PLATFORMS must not initialize a backend (one process per chip)"
     )
 
     def check_tree(self, ctxs: Sequence[FileContext]) -> Iterable[Finding]:
