@@ -1,0 +1,145 @@
+"""BENCHMARK.json against its contract, and the files its names lead to."""
+
+import importlib
+import json
+import os
+import re
+import types
+
+import pytest
+
+from benchmarks.lib import spec, traffic
+
+BENCH = spec.benchmark_json()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def test_top_level_keys_and_limits():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
+    assert 1 <= BENCH["run_seconds"] <= 51 and isinstance(BENCH["run_seconds"], int)
+    assert len(json.dumps(BENCH)) < 64 * 1024
+    assert BENCH["paths"] == ["benchmarks"] and BENCH["command"][-1].startswith("benchmarks/")
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+    assert len({(w["config"], w["traffic"], w["chips"]) for w in BENCH["workloads"]}) == len(CELLS)
+
+
+@pytest.mark.parametrize("entry", METRICS, ids=lambda m: m["name"])
+def test_metric_entry(entry):
+    allowed = {"name", "unit", "better", "source", "workloads"}
+    allowed |= {"bound"} if entry in BENCH["end_to_end"] else {"layer", "moves"}
+    assert set(entry) <= allowed
+    assert NAME.match(entry["name"]) and UNIT.match(entry["unit"])
+    assert entry["better"] in ("lower", "higher") and entry["source"] in SOURCES
+    if "bound" in entry:
+        assert 0.01 <= entry["bound"] <= 0.1
+        assert entry["source"] in ("host_clock", "device_trace")
+    if entry["name"].endswith("_roofline") or "_roofline." in entry["name"]:
+        assert entry["unit"] == "%"
+    for w in entry.get("workloads", []):
+        assert w in CELLS
+
+
+@pytest.mark.parametrize("entry", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_has_its_file_and_its_moves_is_reported_in_each_of_its_cells(entry):
+    cell = spec.find_cell(CELLS[0])
+    mf = spec.metric_file(cell, entry["name"])
+    assert mf["layer"] == entry["layer"] and mf["moves"] == entry["moves"]
+    assert mf["cells"] == entry.get("workloads", CELLS)
+    importlib.import_module(f"benchmarks.readers.{mf['reader']}").read  # the reader exists
+    moved = next(m for m in BENCH["end_to_end"] if m["name"] == entry["moves"])
+    for c in entry.get("workloads", CELLS):
+        assert c in moved.get("workloads", CELLS), f"{entry['name']} moves {moved['name']}, which {c} does not report"
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_files_exist_and_load(name):
+    cell = spec.find_cell(name)
+    w = next(w for w in BENCH["workloads"] if w["name"] == name)
+    assert NAME.match(name) and NAME.match(w["config"]) and NAME.match(w["traffic"])
+    assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"] and "\t" not in w["why"]
+    spec.load_runner(cell).run  # the runner exists
+    names = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in names and len(names) >= 2 and cell.per_layer
+    for m in cell.end_to_end:
+        importlib.import_module(f"benchmarks.readers.{spec.metric_file(cell, m['name'])['reader']}").read
+
+
+@pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_keeps_published_widths_and_reduces_only_depth(conf):
+    cfg = spec.load_json(os.path.join(spec.ROOT, conf["file"]))
+    assert conf["reduced"] == ["num_hidden_layers"] == list(cfg["reduced_from"])
+    assert cfg["source"] == conf["source"] and conf["source"].startswith("https://huggingface.co/")
+    published = {
+        "mistral-7b-v0.3-L4": dict(hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
+                                   num_key_value_heads=8, vocab_size=32768, rope_theta=1e6, rms_norm_eps=1e-5),
+        "deepseek-llm-7b-chat-L8": dict(hidden_size=4096, intermediate_size=11008, num_attention_heads=32,
+                                        num_key_value_heads=32, vocab_size=102400, rope_theta=1e4, rms_norm_eps=1e-6,
+                                        max_position_embeddings=4096),
+    }[conf["name"]]
+    for k, v in published.items():
+        assert cfg[k] == v, k
+    for k, v in cfg["assumed"].items():
+        assert v.get("why"), f"assumed.{k} has no reason"
+    assert "fewer_layers_mean" in cfg
+
+
+@pytest.mark.parametrize("name", [c for c in CELLS if "serve" in c])
+def test_warmed_prefill_buckets_cover_the_files_length_range(name):
+    """Warm-up covers what the FILE can produce, by the program's own bucket
+    rule; the benchmark's copy of that rule (rehearsal, AOT) agrees with it."""
+    from ray_tpu.serve.llm.model import PagedLM
+
+    cell = spec.find_cell(name)
+    assumed = {k: v["value"] for k, v in cell.config["assumed"].items()}
+    T, P = assumed["page_tokens"], assumed["max_pages_per_seq"]
+    lm = types.SimpleNamespace(max_pages_per_seq=P)
+    lo, hi = traffic.prompt_length_range(cell.traffic)
+    warmed = set(traffic.prefill_buckets(cell.traffic, T, P))
+    for n_tokens in range(lo, hi + 1):
+        pages = max(1, -(-n_tokens // T))
+        assert PagedLM._bucket_pages(lm, pages) == traffic.bucket_pages(pages, P)
+        assert traffic.bucket_pages(pages, P) in warmed
+    drawn = [r.prompt_tokens for r in traffic.generate(cell.traffic, 120)]
+    assert lo <= min(drawn) and max(drawn) <= hi
+    assert max(r.prompt_tokens + r.max_new_tokens for r in traffic.generate(cell.traffic, 120)) <= P * T
+    assert all(lo <= n <= hi for n in cell.traffic["correctness"]["probe_prompt_tokens"])
+
+
+@pytest.mark.parametrize("name", [c for c in CELLS if "serve" in c])
+def test_generator_is_deterministic_and_seed_changes_only_content(name):
+    cell = spec.find_cell(name)
+    a, b = traffic.generate(cell.traffic, 30), traffic.generate(cell.traffic, 30)
+    assert a == b and len(a) > 10
+    longer = traffic.generate(cell.traffic, 60)
+    if a[0].due_s is not None:
+        assert longer[: len(a)] == a  # a longer horizon extends the schedule, it does not reshuffle it
+    vocab = cell.config["vocab_size"]
+    p1, p1b = traffic.prompt_tokens(a[3], 7, vocab), traffic.prompt_tokens(a[3], 7, vocab)
+    p2 = traffic.prompt_tokens(a[3], 3000000019, vocab)
+    assert p1 == p1b and p1 != p2 and len(p1) == len(p2) == a[3].prompt_tokens
+    assert 0 < min(p1) and max(p1) < vocab
+
+
+def test_followup_turn_shares_its_sessions_prefix():
+    cell = spec.find_cell("dsllm7b-serve-chat-steady")
+    reqs = traffic.generate(cell.traffic, 60)
+    follow = next(r for r in reqs if len(r.segments) > 2)
+    parent = next(r for r in reqs if r.idx < follow.idx and r.segments == follow.segments[: len(r.segments)])
+    vocab = cell.config["vocab_size"]
+    a, b = traffic.prompt_tokens(parent, 5, vocab), traffic.prompt_tokens(follow, 5, vocab)
+    assert b[: len(a)] == a and len(b) > len(a) + follow.segments[-1][1]  # parent + stand-in answer + new turn
+    share = sum(1 for r in reqs if len(r.segments) > 2) / len(reqs)
+    assert 0.3 < share < 0.75
+
+
+def test_docqa_questions_share_their_document():
+    cell = spec.find_cell("dsllm7b-serve-docqa-batch")
+    reqs = [r for r in traffic.generate(cell.traffic, 30) if r.client == 0][:8]
+    assert [r.segments[0] for r in reqs[:4]] == [reqs[0].segments[0]] * 4
+    assert reqs[4].segments[0] != reqs[0].segments[0]
+    assert all(r.max_new_tokens == 32 for r in reqs)
