@@ -125,15 +125,19 @@ def init(
                 "ray_tpu.init(local_mode=True)"
             ) from e
 
-        rt = ClusterRuntime.create(
-            address=address,
-            num_cpus=num_cpus,
-            num_tpus=num_tpus,
-            resources=resources,
-            namespace=namespace,
-            object_store_memory=object_store_memory,
-            num_workers=num_workers,
-        )
+        from . import tracing
+
+        tracing.sync_env()
+        with tracing.span("rt.init", {"address": address or ""}):
+            rt = ClusterRuntime.create(
+                address=address,
+                num_cpus=num_cpus,
+                num_tpus=num_tpus,
+                resources=resources,
+                namespace=namespace,
+                object_store_memory=object_store_memory,
+                num_workers=num_workers,
+            )
     runtime_base.set_runtime(rt)
     return rt
 

@@ -154,8 +154,8 @@ class GraphExecutor:
         while not self.stop.is_set():
             # Iteration span (channel-wait / compute / collective
             # sub-spans inside _iterate), sharing the graph's compile-time
-            # trace_id and stepping the per-iteration flow chain. Tracing
-            # off = one None check per iteration.
+            # trace_id and stepping the per-iteration flow chain. A graph
+            # compiled with tracing off has no trace_ctx and records nothing.
             traced = trace_ctx is not None and _tracing.is_enabled()
             iter_cm = (
                 _tracing.continue_context(
@@ -168,7 +168,7 @@ class GraphExecutor:
             )
             try:
                 with iter_cm:
-                    self._iterate(nodes, traced)
+                    self._iterate(nodes)
             except (ChannelClosed, OSError):
                 break  # teardown raced a blocked read/write
             except Exception:  # noqa: BLE001
@@ -202,10 +202,10 @@ class GraphExecutor:
         # blocking forever on edges this actor will never write again.
         self.teardown()
 
-    def _iterate(self, nodes, traced: bool) -> None:
+    def _iterate(self, nodes) -> None:
         """One DAG iteration; sub-spans split the time into channel-wait
         vs compute vs collective when tracing is on."""
-        span = _tracing.span if traced else _tracing.null_span
+        span = _tracing.span
         vals: Dict[int, Any] = {}
         for node in nodes:
             if node["reads"]:

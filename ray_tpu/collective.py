@@ -589,7 +589,7 @@ def _op_span(kind: str, group: "_Group", **attrs):
                 f"{group.name!r} rank {group.rank}"
             )
     _flight_record("coll.op", (kind, group.name, group.rank))
-    return _tracing.maybe_span(
+    return _tracing.span(
         f"collective.{kind}",
         {
             "group": group.name,

@@ -98,11 +98,9 @@ def _entry_from_spec(spec: TaskSpec) -> dict:
 def _submit_span(entry: dict):
     """Submit-side anchor span for the Perfetto submit->execute flow
     arrow: carries `flow_out` paired with the flow id riding the entry's
-    trace_ctx (the executing span reports it as `flow_in`). Nullcontext
-    when tracing is off — submission pays nothing."""
-    ctx = entry.get("trace_ctx")
-    if not ctx:
-        return _tracing.null_span()
+    trace_ctx (the executing span reports it as `flow_in`). An entry made
+    with tracing off carries no trace_ctx and gets no span."""
+    ctx = entry.get("trace_ctx") or {}
     return _tracing.span(
         f"submit {entry.get('desc', 'task')}",
         {"task_id": entry.get("task_id", ""), "flow_out": ctx.get("flow")},
@@ -244,7 +242,12 @@ class ClusterRuntime(Runtime):
         # path (~15 acquires per dispatch); the wrapper would cost ~10%
         # tasks/s. Cross-plane deadlock coverage comes from the raylet/
         # GCS/serve-controller locks, which are off the fastpath.
-        self._ref_lock = threading.Lock()
+        # Re-entrant: any allocation inside a locked section can start a
+        # cyclic GC pass on this thread, and a collected ObjectRef's
+        # __del__ takes the lock again (remove_local_ref). With a plain
+        # Lock that is a self-deadlock (seen: _record_submission ->
+        # "Garbage-collecting" -> __del__ -> remove_local_ref, tier-1 hung).
+        self._ref_lock = threading.RLock()
         self._local_refs: Dict[str, int] = {}
         self._owned: set = set()  # oids this process created (put / submit)
         self._records: Dict[str, _TaskRecord] = {}
@@ -330,10 +333,11 @@ class ClusterRuntime(Runtime):
         with self._fast_seal_cv:
             self._fast_pending.update(entry["return_ids"])
 
-    def _fast_sealed(self, sealed: List[str], inline: Optional[dict] = None) -> None:
+    def _fast_sealed(self, sealed: List[str], inline: Optional[dict] = None) -> bool:
         """Completion ack from a direct worker: record inline results in
         the owner's memory store (reference: CoreWorker's in-memory store
-        for small returns — memory_store.h) and wake local waiters."""
+        for small returns — memory_store.h) and wake local waiters.
+        Returns whether a waiter was notified (core.stream_ack records it)."""
         if inline:
             memstore = self._memstore
             for h, blob in inline.items():
@@ -378,6 +382,8 @@ class ClusterRuntime(Runtime):
                 or (inline and any(h in waiting for h in inline))
             ):
                 self._fast_seal_cv.notify_all()
+                return True
+        return False
 
     def _log_subscriber(self) -> None:
         """Re-prints captured worker output at the driver with
@@ -1027,6 +1033,17 @@ class ClusterRuntime(Runtime):
         Items land incrementally (inline stream acks on the direct path,
         seal notifications otherwise); the header at return index 0 closes
         the stream with the item count."""
+        # core.stream_next: one span per call; `note` (its attrs, None with
+        # tracing off) counts what the call waited on and where it found
+        # the item. Joins core.stream_item / core.stream_ack on (task, index).
+        with _tracing.span("core.stream_next") as sp:
+            note = None
+            if sp is not None:
+                note = sp["attrs"]
+                note.update(task=task_id.hex()[:24], index=index, waits=0, remote_checks=0)
+            return self._stream_next(task_id, index, timeout, note)
+
+    def _stream_next(self, task_id, index: int, timeout: Optional[float], note: Optional[dict]):
         from .object_ref import STREAM_COUNT_KEY
 
         header_oid = task_id.object_id_for_return(0)
@@ -1036,11 +1053,15 @@ class ClusterRuntime(Runtime):
         last_remote_check = 0.0
         while True:
             if h_item in self._memstore or self._store.contains(item_oid):
+                if note is not None:
+                    note["found"] = "memstore" if h_item in self._memstore else "store"
                 self._adopt_stream_item(h_item)
                 return item_oid
             if h_header in self._memstore or self._store.contains(header_oid):
                 hdr = self._get_one(header_oid, None)  # raises task errors
                 if index >= hdr.get(STREAM_COUNT_KEY, 0):
+                    if note is not None:
+                        note["found"] = "header"
                     return None
                 # Item exists somewhere but is not local yet: fall through
                 # to the wait (the raylet path below pulls it in).
@@ -1053,6 +1074,8 @@ class ClusterRuntime(Runtime):
                 # Periodic raylet-side wait: pulls items produced on other
                 # nodes and covers lost acks (same safety net as _get_one).
                 last_remote_check = now
+                if note is not None:
+                    note["remote_checks"] += 1
                 try:
                     self._raylet.call(
                         "wait_objects", [h_item, h_header], 1, 0.2, True, timeout=10.0
@@ -1064,6 +1087,8 @@ class ClusterRuntime(Runtime):
                 # a stream whose producing NODE died would block forever.
                 self._maybe_recover(header_oid)
                 continue
+            if note is not None:
+                note["waits"] += 1
             with self._fast_seal_cv:
                 self._fast_seal_cv.wait(timeout=0.05)
 

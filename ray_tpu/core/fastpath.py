@@ -32,6 +32,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from .. import exceptions as exc
+from .. import tracing as _tracing
 from ..utils import internal_metrics as imet
 from .rpc import _recv_msg, _send_msg, parse_address
 
@@ -166,7 +167,19 @@ class DirectConn:
             elif msg[0] == "si":  # stream item: ("si", sealed, inline)
                 self.last_used = time.monotonic()
                 if self._on_sealed is not None:
-                    self._on_sealed(msg[1], msg[2])
+                    # core.stream_ack: the owner learns of one stream item.
+                    # No request context reaches this thread; the span joins
+                    # core.stream_item / core.stream_next on (task, index).
+                    with _tracing.span("core.stream_ack") as sp:
+                        notified = self._on_sealed(msg[1], msg[2])
+                        if sp is not None:
+                            h = next(iter(msg[2] or msg[1]), "")
+                            sp["attrs"].update(
+                                task=h[:24],
+                                index=_ObjectID.from_hex(h).return_index() - 1 if h else -1,
+                                inline=bool(msg[2]),
+                                notified=bool(notified),
+                            )
             elif msg[0] == "r":
                 # Lease revoked by the raylet (queued work needs the
                 # resources): stop new pushes, close once drained.

@@ -3040,7 +3040,7 @@ def main(argv: List[str]) -> None:
     server = RpcServer(sock_path, service)
     try:
         while not service._stop.wait(0.5):
-            pass
+            _tracing.flush()  # a raylet may be killed: keep its span file current
     finally:
         if tcp_server is not None:
             tcp_server.shutdown()

@@ -244,6 +244,7 @@ def main(argv: List[str]) -> None:
         the consumer reaches it."""
         import inspect as _inspect
 
+        from .. import tracing as _tracing
         from .ids import TaskID
         from .object_ref import STREAM_COUNT_KEY
 
@@ -289,13 +290,23 @@ def main(argv: List[str]) -> None:
                     fp_report(item_sealed, None)
                 count += 1
                 break
-            rid = tid.object_id_for_return(count + 1)
-            item_sealed = []
-            inline_d = _put_value(entry, rid, item, item_sealed)
-            if report is not None:
-                report(item_sealed, inline_d)
-            if item_sealed:
-                fp_report(item_sealed, None)
+            # core.stream_item: from the item in hand to its report sent.
+            # Joins core.stream_ack / core.stream_next on (task, index).
+            with _tracing.span("core.stream_item") as sp:
+                rid = tid.object_id_for_return(count + 1)
+                item_sealed = []
+                inline_d = _put_value(entry, rid, item, item_sealed)
+                if report is not None:
+                    report(item_sealed, inline_d)
+                if item_sealed:
+                    fp_report(item_sealed, None)
+                if sp is not None:
+                    sp["attrs"].update(
+                        task=rid.hex()[:24],
+                        index=count,
+                        route="inline" if inline_d else "shm",
+                        reported="direct" if report is not None else "seal",
+                    )
             count += 1
         header_inline = _put_value(
             entry, tid.object_id_for_return(0), {STREAM_COUNT_KEY: count}, sealed
@@ -519,6 +530,9 @@ def main(argv: List[str]) -> None:
             return False
         finally:
             reset_task_context(token)
+            # A worker is killed, not stopped: what this task buffered is
+            # written now (off: one test of the flag).
+            _tracing.flush()
 
     def done(entry: dict, ok: bool, sealed: List[str]) -> None:
         raylet.notify("worker_done", worker_id, ok, sealed, entry.get("task_id"))
@@ -593,6 +607,7 @@ def main(argv: List[str]) -> None:
                 {"actor_id": entry.get("actor_id", "")},
             ):
                 inst = cls(*args, **kwargs)
+            _tracing.flush()
             actor_instance[entry["actor_id"]] = inst
             mc = int(entry.get("max_concurrency", 1) or 1)
             cgroups = entry.get("concurrency_groups") or {}

@@ -6,6 +6,7 @@ from __future__ import annotations
 from typing import Any, Optional, Union
 
 from .. import api as core_api
+from .. import tracing as _tracing
 from .controller import get_or_create_controller
 from .deployment import Application, Deployment
 from .handle import DeploymentHandle, start_proxy, stop_proxy
@@ -29,11 +30,14 @@ def run(
 
     if not core_api.is_initialized():
         core_api.init(local_mode=True)
-    controller = get_or_create_controller()
-    _deploy_application(controller, target, name, cloudpickle)
-    if http_port is not None:
-        start_proxy(http_port)
-    return DeploymentHandle(name)
+    # One-off set-up span: controller start, replica actor launch (its
+    # actor_launch.* spans nest here) and the replica's constructor.
+    with _tracing.span("serve.run", {"app": name}):
+        controller = get_or_create_controller()
+        _deploy_application(controller, target, name, cloudpickle)
+        if http_port is not None:
+            start_proxy(http_port)
+        return DeploymentHandle(name)
 
 
 def _deploy_application(

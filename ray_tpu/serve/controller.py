@@ -392,6 +392,9 @@ class Replica:
                 self._callable.shutdown_engine()
             except Exception:  # lint: swallow-ok(kill follows regardless; engine may be half-built)
                 pass
+        # The kill that follows is a SIGKILL: what this replica (and its
+        # engine thread, now joined) buffered is written here or never.
+        _tracing.flush()
         return True
 
 

@@ -119,11 +119,13 @@ class DeploymentResponseGenerator:
     the streaming generator protocol of _raylet.pyx:281 — here the same
     layering, serve on top of core streaming)."""
 
-    def __init__(self, ref_gen, on_done, on_cancel=None):
+    def __init__(self, ref_gen, on_done, on_cancel=None, trace=None):
         self._gen = ref_gen
         self._on_done = on_done
         self._on_cancel = on_cancel
         self._finished = False
+        self._trace = trace  # the serve.request span's {trace_id, span_id}
+        self._traced_index = 0
 
     def _finish(self):
         if not self._finished:
@@ -134,6 +136,16 @@ class DeploymentResponseGenerator:
         return self
 
     def __next__(self):
+        if self._trace is None:
+            return self._next()
+        # From the call to the value in hand; core.stream_next nests in it.
+        with _tracing.span(
+            "serve.stream.next", {"index": self._traced_index}, parent=self._trace
+        ):
+            self._traced_index += 1
+            return self._next()
+
+    def _next(self):
         if self._gen is None:
             raise StopIteration
         # The outstanding counter holds until the stream is drained, so
@@ -266,20 +278,14 @@ class DeploymentHandle:
         # submission path injects; `flow_out` additionally arrows
         # request->response in the Perfetto view. TTFT falls out of the
         # replica span's start minus this span's start.
-        traced = _tracing.is_enabled()
-        resp_flow = _tracing.new_flow_id() if traced else None
-        span_cm = (
-            _tracing.span(
-                f"serve.request {self._app}",
-                {
-                    "app": self._app,
-                    "method": self._method,
-                    "replica": str(rid),
-                    "flow_out": resp_flow,
-                },
-            )
-            if traced
-            else None
+        span_cm = _tracing.span(
+            f"serve.request {self._app}",
+            {
+                "app": self._app,
+                "method": self._method,
+                "replica": str(rid),
+                "stream": self._stream,
+            },
         )
         if self._stream:
             import uuid as _uuid
@@ -288,7 +294,7 @@ class DeploymentHandle:
             # close() can name this stream to the replica.
             cancel_token = _uuid.uuid4().hex
             context = {**(context or {}), "cancel_token": cancel_token}
-            with span_cm or _tracing.null_span():
+            with span_cm as sp:
                 ref_gen = replica.handle_request_stream.options(
                     num_returns="streaming"
                 ).remote(self._method, args, kwargs, context)
@@ -296,16 +302,21 @@ class DeploymentHandle:
             def cancel():
                 replica.cancel_stream.remote(cancel_token)
 
-            return DeploymentResponseGenerator(ref_gen, done, on_cancel=cancel)
+            # The generator keeps the request's identity: its per-chunk
+            # spans run on whichever thread iterates, long after this span
+            # closed, and still belong to the request's trace.
+            trace = sp and {"trace_id": sp["trace_id"], "span_id": sp["span_id"]}
+            return DeploymentResponseGenerator(ref_gen, done, on_cancel=cancel, trace=trace)
         resp_ctx = None
-        with span_cm or _tracing.null_span() as sp:
+        with span_cm as sp:
             ref = replica.handle_request.remote(self._method, args, kwargs, context)
             if sp is not None:
                 resp_ctx = {
                     "trace_id": sp["trace_id"],
                     "span_id": sp["span_id"],
-                    "flow": resp_flow,
+                    "flow": _tracing.new_flow_id(),
                 }
+                sp["attrs"]["flow_out"] = resp_ctx["flow"]
         return DeploymentResponse(
             ref,
             done,

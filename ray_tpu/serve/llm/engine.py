@@ -41,6 +41,7 @@ from ...exceptions import (
     KVPoolExhaustedError,
     RayTpuError,
 )
+from ... import tracing as _tracing
 from ...utils import internal_metrics as imet
 from ...utils import lock_order
 from .kv_cache import PagedKVAllocator, SeqPages
@@ -75,6 +76,7 @@ class _Seq:
     __slots__ = (
         "rid", "prompt", "max_new", "pages", "sink", "slot",
         "last_token", "n_out", "cancelled", "finished", "t_submit", "t_first",
+        "trace", "waiting_ahead",
     )
 
     def __init__(self, rid: int, prompt: List[int], max_new: int, pages: SeqPages, sink: Sink):
@@ -90,6 +92,10 @@ class _Seq:
         self.finished = False
         self.t_submit = time.monotonic()
         self.t_first = 0.0
+        # The submitting thread's span context (None with tracing off):
+        # spans the engine thread records for this request parent to it.
+        self.trace = _tracing.current_context()
+        self.waiting_ahead = 0
 
     def write_pos(self) -> int:
         """Cache position the NEXT decode step writes (last emitted
@@ -108,7 +114,7 @@ class InferenceEngine:
         self.name = name
         cfg = self.config
         labels = {"deployment": name}
-        self._m_tpot = imet.SERVE_TPOT.labels(**labels)
+        self._m_step = imet.SERVE_DECODE_STEP.labels(**labels)
         self._m_tps = imet.SERVE_TOKENS_PER_S.labels(**labels)
         self._m_shed = imet.SERVE_REQUESTS_SHED.labels(**labels)
         self.alloc = PagedKVAllocator(
@@ -136,6 +142,20 @@ class InferenceEngine:
         self.decode_steps = 0
         self._tok_window = 0
         self._t_window = time.monotonic()
+        # Stage clocks (stats()["clocks"]): seconds of time.monotonic(),
+        # cumulative, written by the loop thread where it already reads the
+        # clock or holds _cond. `_stage` names what the loop is inside right
+        # now, so stats() can count the part of it already spent.
+        self._clk = {
+            "queue_wait": {"n": 0, "s": 0.0},
+            "first_token": {"n": 0, "s": 0.0},
+            "prefill": {"n": 0, "s": 0.0, "tokens": 0},
+            "decode": {"n": 0, "s": 0.0},
+            "idle": {"s": 0.0},
+        }
+        self._stage: Optional[tuple] = None  # (clock name, t0)
+        self._clk_lock = threading.Lock()
+        self._t_loop = (time.monotonic(), None)  # loop start, loop end
         self._thread = threading.Thread(
             target=self._loop, name=f"llm-engine-{name}", daemon=True
         )
@@ -181,6 +201,7 @@ class InferenceEngine:
                 raise
             rid = next(self._rid)
             seq = _Seq(rid, prompt, max_new, pages, sink)
+            seq.waiting_ahead = len(self._waiting)
             self._by_rid[rid] = seq
             self._waiting.append(seq)
             self._cond.notify()
@@ -264,6 +285,7 @@ class InferenceEngine:
         compute itself runs outside it."""
         budget = self.config.prefill_token_budget
         admitted: List[_Seq] = []
+        now = 0.0
         while self._waiting and None in self._slots:
             seq = self._waiting[0]
             new_tokens = len(seq.prompt) - seq.pages.cached_tokens
@@ -275,6 +297,23 @@ class InferenceEngine:
             self._slots[slot] = seq
             budget -= new_tokens
             admitted.append(seq)
+            now = now or time.monotonic()
+            clk = self._clk["queue_wait"]
+            clk["n"] += 1
+            clk["s"] += now - seq.t_submit
+            if seq.trace is not None:
+                _tracing.record_span(
+                    "llm.queue",
+                    int(seq.t_submit * 1e9),
+                    int(now * 1e9),
+                    {
+                        "rid": seq.rid,
+                        "prompt_tokens": len(seq.prompt),
+                        "cached_tokens": seq.pages.cached_tokens,
+                        "waiting_ahead": seq.waiting_ahead,
+                    },
+                    parent=seq.trace,
+                )
         return admitted
 
     def _finalize_admission_locked(self, seq: _Seq, tok: Optional[int], err) -> None:
@@ -289,6 +328,12 @@ class InferenceEngine:
         self.alloc.commit(seq.pages, seq.prompt)
         seq.last_token = tok
         seq.t_first = time.monotonic()
+        clk = self._clk["first_token"]
+        clk["n"] += 1
+        clk["s"] += seq.t_first - seq.t_submit
+        if seq.trace is not None:
+            t_ns = int(seq.t_first * 1e9)
+            _tracing.record_span("llm.first_token", t_ns, t_ns, {"rid": seq.rid}, parent=seq.trace)
         self._emit_locked(seq, tok)
         if self._done_after_emit(seq, tok):
             self._finish_locked(seq, "done", "stop")
@@ -323,7 +368,20 @@ class InferenceEngine:
                 self._finish_locked(seq, "error", err)
 
     def _loop(self) -> None:
-        T = self.config.page_tokens
+        try:
+            self._run()
+        finally:
+            self._t_loop = (self._t_loop[0], time.monotonic())
+
+    def _timed(self, clock: str, t0: float) -> float:
+        """Closes the stage opened at t0: its seconds go to `clock`."""
+        dt = time.monotonic() - t0
+        with self._clk_lock:  # stats() sees the stage open or its seconds, never both
+            self._stage = None
+            self._clk[clock]["s"] += dt
+        return dt
+
+    def _run(self) -> None:
         while True:
             with self._cond:
                 self._drain_cancels_locked()
@@ -334,103 +392,145 @@ class InferenceEngine:
                     and not self._cancels
                 ):
                     self._m_tps.set(0.0)
+                    self._stage = ("idle", time.monotonic())
                     self._cond.wait(timeout=1.0)
+                    self._timed("idle", self._stage[1])
                 if self._stop:
                     for seq in list(self._by_rid.values()):
                         self._finish_locked(seq, "error", RayTpuError("engine shut down"))
                     return
                 self._drain_cancels_locked()
                 admitted = self._pick_admissions_locked()
+                live = sum(1 for s in self._slots if s is not None)
+            if not live:
+                continue  # woken by a cancel; nothing to run
+            # One iteration with work in it, from the admissions to the
+            # last emit: prefills, one decode step, the emits.
+            with _tracing.span(
+                "llm.step", {"admitted": len(admitted), "live": live}, device=True
+            ):
+                if not self._step(admitted):
+                    return
 
-            # Prefill outside the lock (jit-compiled, prompt-sized work):
-            # submit/cancel stay responsive while prompts burn in.
-            prefilled = []
-            for seq in admitted:
-                tok, err = None, None
-                try:
+    def _step(self, admitted: List[_Seq]) -> bool:
+        """Prefills `admitted`, runs one decode step over every live slot
+        and emits its tokens. False: the engine failed and the loop ends."""
+        T = self.config.page_tokens
+        # Prefill outside the lock (jit-compiled, prompt-sized work):
+        # submit/cancel stay responsive while prompts burn in.
+        prefilled = []
+        for seq in admitted:
+            tok, err = None, None
+            clk = self._clk["prefill"]
+            clk["n"] += 1
+            clk["tokens"] += len(seq.prompt)
+            t0 = time.monotonic()
+            self._stage = ("prefill", t0)
+            try:
+                with _tracing.span(
+                    "llm.prefill",
+                    {
+                        "rid": seq.rid,
+                        "prompt_tokens": len(seq.prompt),
+                        "cached_tokens": seq.pages.cached_tokens,
+                    },
+                    device=True,
+                    parent=seq.trace,
+                ):
                     tok = self.model.prefill(
                         seq.prompt, seq.pages.pages, seq.pages.cached_tokens
                     )
-                except EngineFailedError as e:
-                    self._fail(e)
-                    return
-                except Exception as e:  # noqa: BLE001 - fail one request, not the loop
-                    err = e
-                prefilled.append((seq, tok, err))
-
-            with self._cond:
-                for seq, tok, err in prefilled:
-                    self._finalize_admission_locked(seq, tok, err)
-                batch = [s for s in self._slots if s is not None]
-                # Grow block tables for sequences crossing a page
-                # boundary this step; pool exhaustion here fail-fasts the
-                # one sequence (its pages recycle for the rest).
-                for seq in list(batch):
-                    if seq.write_pos() >= seq.pages.num_pages * T:
-                        try:
-                            self.alloc.extend(seq.pages)
-                        except KVPoolExhaustedError as e:
-                            batch.remove(seq)
-                            self._finish_locked(seq, "error", e)
-                if not batch:
-                    continue
-                tokens = [0] * len(self._slots)
-                positions = [-1] * len(self._slots)
-                tables: List[List[int]] = [[] for _ in self._slots]
-                for seq in batch:
-                    tokens[seq.slot] = seq.last_token
-                    positions[seq.slot] = seq.write_pos()
-                    tables[seq.slot] = seq.pages.pages
-
-            # Model step runs OUTSIDE the lock: submit/cancel stay
-            # responsive for the full decode latency.
-            t0 = time.monotonic()
-            try:
-                rule = _chaos_inject("serve.decode", self.name)
-                if rule is not None:
-                    if rule.action == "delay":
-                        time.sleep(rule.delay_s)
-                    elif rule.action == "kill":
-                        _chaos_kill("serve.decode", self.name)
-                    else:
-                        raise RayTpuError(
-                            f"chaos: injected decode fault ({self.name})"
-                        )
-                next_tokens = self.model.decode(tokens, positions, tables)
-                step_err: Optional[BaseException] = None
             except EngineFailedError as e:
                 self._fail(e)
-                return
-            except Exception as e:  # noqa: BLE001 - batch fail-fast, loop survives
-                next_tokens, step_err = None, e
+                return False
+            except Exception as e:  # noqa: BLE001 - fail one request, not the loop
+                err = e
+            finally:
+                self._timed("prefill", t0)
+            prefilled.append((seq, tok, err))
 
-            step_ms = (time.monotonic() - t0) * 1000.0
-            with self._cond:
-                if step_err is not None:
-                    # Fail-fast every sequence that was in the failed
-                    # step — never wedge: pages free, slots recycle, the
-                    # engine keeps serving whatever arrives next.
-                    logger.warning("decode step failed on %s: %r", self.name, step_err)
-                    for seq in batch:
-                        if not seq.finished:
-                            self._finish_locked(seq, "error", _typed(step_err))
-                    continue
-                self.decode_steps += 1
-                self._m_tpot.observe(step_ms)
+        with self._cond:
+            for seq, tok, err in prefilled:
+                self._finalize_admission_locked(seq, tok, err)
+            batch = [s for s in self._slots if s is not None]
+            # Grow block tables for sequences crossing a page
+            # boundary this step; pool exhaustion here fail-fasts the
+            # one sequence (its pages recycle for the rest).
+            for seq in list(batch):
+                if seq.write_pos() >= seq.pages.num_pages * T:
+                    try:
+                        self.alloc.extend(seq.pages)
+                    except KVPoolExhaustedError as e:
+                        batch.remove(seq)
+                        self._finish_locked(seq, "error", e)
+            if not batch:
+                return True
+            tokens = [0] * len(self._slots)
+            positions = [-1] * len(self._slots)
+            tables: List[List[int]] = [[] for _ in self._slots]
+            kv_tokens = 0
+            for seq in batch:
+                tokens[seq.slot] = seq.last_token
+                positions[seq.slot] = seq.write_pos()
+                tables[seq.slot] = seq.pages.pages
+                kv_tokens += positions[seq.slot] + 1
+
+        # Model step runs OUTSIDE the lock: submit/cancel stay
+        # responsive for the full decode latency.
+        t0 = time.monotonic()
+        self._stage = ("decode", t0)
+        try:
+            rule = _chaos_inject("serve.decode", self.name)
+            if rule is not None:
+                if rule.action == "delay":
+                    time.sleep(rule.delay_s)
+                elif rule.action == "kill":
+                    _chaos_kill("serve.decode", self.name)
+                else:
+                    raise RayTpuError(
+                        f"chaos: injected decode fault ({self.name})"
+                    )
+            with _tracing.span(
+                "llm.decode", {"live": len(batch), "kv_tokens": kv_tokens}, device=True
+            ):
+                next_tokens = self.model.decode(tokens, positions, tables)
+            step_err: Optional[BaseException] = None
+        except EngineFailedError as e:
+            self._fail(e)
+            return False
+        except Exception as e:  # noqa: BLE001 - batch fail-fast, loop survives
+            next_tokens, step_err = None, e
+        finally:
+            step_ms = self._timed("decode", t0) * 1000.0
+
+        with self._cond, _tracing.span("llm.emit", {"tokens": len(batch)}, device=True):
+            if step_err is not None:
+                # Fail-fast every sequence that was in the failed
+                # step — never wedge: pages free, slots recycle, the
+                # engine keeps serving whatever arrives next.
+                logger.warning("decode step failed on %s: %r", self.name, step_err)
                 for seq in batch:
-                    if seq.finished or seq.cancelled:
-                        continue
-                    tok = int(next_tokens[seq.slot])
-                    seq.last_token = tok
-                    self._emit_locked(seq, tok)
-                    if self._done_after_emit(seq, tok):
-                        self._finish_locked(seq, "done", "stop")
-                now = time.monotonic()
-                dt = now - self._t_window
-                if dt >= 0.5:
-                    self._m_tps.set(self._tok_window / dt)
-                    self._tok_window = 0
-                    self._t_window = now
+                    if not seq.finished:
+                        self._finish_locked(seq, "error", _typed(step_err))
+                return True
+            self.decode_steps += 1
+            self._clk["decode"]["n"] += 1
+            self._m_step.observe(step_ms)
+            for seq in batch:
+                if seq.finished or seq.cancelled:
+                    continue
+                tok = int(next_tokens[seq.slot])
+                seq.last_token = tok
+                self._emit_locked(seq, tok)
+                if self._done_after_emit(seq, tok):
+                    self._finish_locked(seq, "done", "stop")
+            now = time.monotonic()
+            dt = now - self._t_window
+            if dt >= 0.5:
+                self._m_tps.set(self._tok_window / dt)
+                self._tok_window = 0
+                self._t_window = now
+        return True
 
     # -------------------------------------------------------------- admin
 
@@ -446,7 +546,28 @@ class InferenceEngine:
                 "shed_total": self.shed_total,
                 "failed": repr(self.failed) if self.failed is not None else None,
                 "kv": self.alloc.stats(),
+                "clocks": self._clocks_locked(),
             }
+
+    def _clocks_locked(self) -> dict:
+        """Where the loop's time went since the engine started (seconds of
+        time.monotonic(), cumulative). queue_wait: submit -> slot, per
+        admitted request; first_token: submit -> first emit (the engine's
+        own TTFT); prefill / decode: inside model.prefill / model.decode
+        (prefill.tokens: prompt tokens of those calls, cached ones
+        included); loop.s: wall time of the loop, loop.idle_s the part of
+        it waiting with nothing to do. loop.s - idle_s - prefill.s -
+        decode.s is the engine's own host time. A stage in progress counts
+        up to now, so two calls bracket a window exactly."""
+        with self._clk_lock:
+            now = time.monotonic()
+            clk = {k: dict(v) for k, v in self._clk.items()}
+            stage = self._stage
+        if stage is not None:
+            clk[stage[0]]["s"] += max(0.0, now - stage[1])
+        t_start, t_end = self._t_loop
+        clk["loop"] = {"s": (t_end or now) - t_start, "idle_s": clk.pop("idle")["s"]}
+        return clk
 
     def close(self) -> None:
         with self._cond:

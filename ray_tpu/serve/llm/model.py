@@ -19,6 +19,7 @@ import math
 import threading
 from typing import Any, Dict, List, Optional, Sequence
 
+from ... import tracing as _tracing
 from ...exceptions import EngineFailedError
 from .kv_cache import TRASH_PAGE
 
@@ -166,9 +167,12 @@ class PagedLM:
 
     # --------------------------------------------------------------- steps
 
-    def _run_step(self, call):
+    def _run_step(self, call, span: str, attrs=None):
         """Runs one jitted step `call(kv) -> (tokens, new_kv)`, waits for
-        its tokens on the host and installs the new pool. The wait is
+        its tokens on the host and installs the new pool. `<span>.dispatch`
+        is the jitted call returning, `<span>.wait` the transfer of its
+        tokens (device annotations, so an idle gap of the chip can be put
+        down to one of them or to the caller's `.prep`). The wait is
         inside the try because dispatch is asynchronous: a device-side
         failure surfaces at the transfer, not at the call. If the step
         raised after the pool was donated into it, the pool buffer is
@@ -181,8 +185,10 @@ class PagedLM:
             if kv is None:
                 raise EngineFailedError("KV page pool was lost in an earlier failed step")
             try:
-                out, new_kv = call(kv)
-                host = np.asarray(out)
+                with _tracing.span(span + ".dispatch", attrs, device=True):
+                    out, new_kv = call(kv)
+                with _tracing.span(span + ".wait", attrs, device=True):
+                    host = np.asarray(out)
             except Exception as e:
                 if kv["k"].is_deleted() or kv["v"].is_deleted():
                     self.kv = None
@@ -201,11 +207,13 @@ class PagedLM:
         n_pages = max(1, -(-len(prompt) // T))
         bucket = self._bucket_pages(n_pages)
         S = bucket * T
-        toks = np.zeros((1, S), dtype=np.int32)
-        toks[0, : len(prompt)] = np.asarray(prompt, dtype=np.int32)
-        bt = np.full((bucket,), TRASH_PAGE, dtype=np.int32)
-        bt[: len(pages)] = np.asarray(pages, dtype=np.int32)
-        fn = self._get_prefill(bucket)
+        attrs = {"bucket_tokens": S}
+        with _tracing.span("llm.prefill.prep", attrs, device=True):
+            toks = np.zeros((1, S), dtype=np.int32)
+            toks[0, : len(prompt)] = np.asarray(prompt, dtype=np.int32)
+            bt = np.full((bucket,), TRASH_PAGE, dtype=np.int32)
+            bt[: len(pages)] = np.asarray(pages, dtype=np.int32)
+            fn = self._get_prefill(bucket)
         tok = self._run_step(
             lambda kv: fn(
                 self.params,
@@ -214,7 +222,9 @@ class PagedLM:
                 bt,
                 np.int32(len(prompt)),
                 np.int32(cached_tokens),
-            )
+            ),
+            "llm.prefill",
+            attrs,
         )
         return int(tok)
 
@@ -222,15 +232,16 @@ class PagedLM:
         import numpy as np
 
         B, P = self.max_slots, self.max_pages_per_seq
-        toks = np.zeros((B,), dtype=np.int32)
-        pos = np.full((B,), -1, dtype=np.int32)
-        bts = np.full((B, P), TRASH_PAGE, dtype=np.int32)
-        toks[: len(last_tokens)] = np.asarray(last_tokens, dtype=np.int32)
-        pos[: len(positions)] = np.asarray(positions, dtype=np.int32)
-        for i, row in enumerate(block_tables):
-            bts[i, : len(row)] = np.asarray(row, dtype=np.int32)
-        fn = self._get_decode()
-        out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts))
+        with _tracing.span("llm.decode.prep", device=True):
+            toks = np.zeros((B,), dtype=np.int32)
+            pos = np.full((B,), -1, dtype=np.int32)
+            bts = np.full((B, P), TRASH_PAGE, dtype=np.int32)
+            toks[: len(last_tokens)] = np.asarray(last_tokens, dtype=np.int32)
+            pos[: len(positions)] = np.asarray(positions, dtype=np.int32)
+            for i, row in enumerate(block_tables):
+                bts[i, : len(row)] = np.asarray(row, dtype=np.int32)
+            fn = self._get_decode()
+        out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode")
         return [int(t) for t in out]
 
 

@@ -112,7 +112,7 @@ class TrainSession:
 
         t0 = _time.perf_counter()
         try:
-            with tracing.maybe_span(f"train.phase.{name}", {"phase": name}):
+            with tracing.span(f"train.phase.{name}", {"phase": name}):
                 yield
         finally:
             dt = _time.perf_counter() - t0

@@ -24,6 +24,7 @@ from typing import Any, Callable, Dict, List, Optional, Set
 
 from .. import api
 from .. import exceptions as exc
+from .. import tracing as _tracing
 from ..core import runtime_base
 from ..core.placement_group import placement_group as create_pg
 from ..observability import goodput as _goodput
@@ -402,12 +403,15 @@ class JaxTrainer:
                     f"placement group for {ws}-worker gang not ready in 60s"
                 )
 
-        group = WorkerGroup(
-            ws,
-            resources_per_worker=sc.resources_per_worker,
-            placement_group=pg,
-            target_world_size=sc.num_workers,
-        )
+        # One-off set-up spans (the workers' actor_launch.* spans nest in
+        # the first; the second is each worker's first touch of its devices).
+        with _tracing.span("train.worker_group.start", {"world_size": ws}):
+            group = WorkerGroup(
+                ws,
+                resources_per_worker=sc.resources_per_worker,
+                placement_group=pg,
+                target_world_size=sc.num_workers,
+            )
         self._last_metrics: Dict[str, Any] = {}
         # Preemption awareness: subscribe to node_draining notices and
         # resolve which nodes host this gang — the supervisor half of
@@ -433,25 +437,26 @@ class JaxTrainer:
             # Either way the MeshSpec resolves IN the worker: an
             # accelerator belongs to one process, so the driver must never
             # initialize a jax backend to count devices.
-            if self._use_distributed(ws):
-                from .backend import JaxBackendConfig, coordinator_address
+            with _tracing.span("train.setup_mesh", {"world_size": ws}):
+                if self._use_distributed(ws):
+                    from .backend import JaxBackendConfig, coordinator_address
 
-                cfg = sc.backend or JaxBackendConfig()
-                coord = coordinator_address(cfg)
-                api.get(
-                    [
-                        w.setup_distributed.remote(
-                            coord,
-                            sc.mesh,
-                            cfg.platform,
-                            cfg.devices_per_worker,
-                            cfg.init_timeout_s,
-                        )
-                        for w in group.workers
-                    ]
-                )
-            else:
-                api.get([w.setup_mesh.remote(sc.mesh) for w in group.workers])
+                    cfg = sc.backend or JaxBackendConfig()
+                    coord = coordinator_address(cfg)
+                    api.get(
+                        [
+                            w.setup_distributed.remote(
+                                coord,
+                                sc.mesh,
+                                cfg.platform,
+                                cfg.devices_per_worker,
+                                cfg.init_timeout_s,
+                            )
+                            for w in group.workers
+                        ]
+                    )
+                else:
+                    api.get([w.setup_mesh.remote(sc.mesh) for w in group.workers])
 
             blob = cloudpickle.dumps(self._train_loop)
             config = dict(self._config)

@@ -665,10 +665,12 @@ SERVE_TOKENS_PER_S = Gauge(
     component="serve",
     tag_keys=("deployment",),
 )
-SERVE_TPOT = Histogram(
-    "raytpu_serve_tpot_ms",
-    "LLM engine time-per-output-token (decode step latency), by deployment",
+SERVE_DECODE_STEP = Histogram(
+    "raytpu_serve_decode_step_ms",
+    "LLM engine decode step wall time (one token for every live sequence; "
+    "NOT the gap a client sees between tokens), by deployment",
     component="serve",
+    boundaries=[1, 2, 5, 10, 20, 35, 50, 75, 100, 125, 150, 200, 250, 350, 500, 1000, 2500],
     tag_keys=("deployment",),
 )
 KV_PAGES_USED = Gauge(

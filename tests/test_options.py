@@ -175,6 +175,30 @@ def test_runtime_env_actor(rt_cluster):
     assert rt.get(a.read.remote(), timeout=30) == "yes"
 
 
+def test_gc_inside_the_ref_lock_does_not_deadlock(rt_cluster):
+    """Any allocation under the owner's ref-count lock can start a cyclic GC
+    pass on that thread; a collected ObjectRef's __del__ takes the lock again
+    (seen: _record_submission -> GC -> remove_local_ref hung this file)."""
+    import gc
+    import threading
+
+    from ray_tpu.core import runtime_base
+
+    runtime = runtime_base.current_runtime()
+    done = threading.Event()
+
+    def collect_under_the_lock():
+        cycle = [rt.put("x")]
+        cycle.append(cycle)  # only the cyclic collector can free the ref
+        del cycle
+        with runtime._ref_lock:
+            gc.collect()
+        done.set()
+
+    threading.Thread(target=collect_under_the_lock, daemon=True).start()
+    assert done.wait(timeout=20), "ObjectRef.__del__ deadlocked on _ref_lock during GC"
+
+
 def test_runtime_env_unsupported_field_raises(rt_cluster):
     """Keys with no registered plugin fail loudly at submission (conda and
     image_uri ARE supported since the plugin ABC landed)."""

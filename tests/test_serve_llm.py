@@ -8,6 +8,7 @@ scheduling logic fast; decode-vs-forward numerics live in
 test_models.py::test_paged_decode_matches_full_forward.
 """
 
+import os
 import threading
 import time
 
@@ -520,3 +521,171 @@ def test_serve_batch_handler_raise_still_fails_batch(rt):
         with pytest.raises(Exception, match="whole batch down"):
             r.result(timeout=30)
     serve.shutdown()
+
+
+# ------------------------------------------- stage clocks and spans (PR 24)
+
+
+def _clock_identity(clk):
+    assert clk["loop"]["s"] >= clk["loop"]["idle_s"] + clk["prefill"]["s"] + clk["decode"]["s"] - 1e-9
+
+
+def _flat(clk):
+    return {f"{k}.{f}": v for k, d in clk.items() for f, v in d.items()}
+
+
+@pytest.mark.parametrize("n_requests,step_delay_s", [(1, 0.0), (3, 0.003), (6, 0.0)])
+def test_engine_stage_clocks(n_requests, step_delay_s):
+    """stats()["clocks"]: monotone across calls, queue_wait.n = requests
+    admitted, and the loop's wall time covers idle + prefill + decode."""
+    eng = InferenceEngine(
+        StubModel(max_slots=2, step_delay_s=step_delay_s),
+        EngineConfig(page_tokens=4, pool_pages=64),
+        name=f"t-clocks-{n_requests}",
+    )
+    try:
+        before = eng.stats()["clocks"]
+        _clock_identity(before)
+        assert before["queue_wait"]["n"] == 0 and before["decode"]["n"] == 0
+        outs = []
+        threads = [
+            threading.Thread(target=lambda i=i: outs.append(_collect(eng, [i + 1, 2], 4)))
+            for i in range(n_requests)
+        ]
+        for t in threads:
+            t.start()
+        mid = eng.stats()["clocks"]  # taken while the loop may be inside a stage
+        for t in threads:
+            t.join(timeout=30)
+        assert len(outs) == n_requests
+        after = eng.stats()["clocks"]
+        later = eng.stats()["clocks"]
+    finally:
+        eng.close()
+    for a, b in ((before, mid), (mid, after), (after, later)):
+        _clock_identity(b)
+        fa, fb = _flat(a), _flat(b)
+        assert all(fb[k] >= fa[k] for k in fa), (fa, fb)
+    assert after["queue_wait"]["n"] == after["first_token"]["n"] == after["prefill"]["n"] == n_requests
+    assert after["prefill"]["tokens"] == 2 * n_requests
+    assert after["decode"]["n"] == eng.decode_steps >= 3
+    assert after["first_token"]["s"] >= after["queue_wait"]["s"] >= 0.0
+    if step_delay_s:
+        assert after["decode"]["s"] >= after["decode"]["n"] * step_delay_s
+    final = eng.stats()["clocks"]  # the loop has ended: its wall time stands still
+    assert final["loop"]["s"] == eng.stats()["clocks"]["loop"]["s"]
+
+
+def test_engine_thread_spans_carry_the_request_context():
+    """llm.queue / llm.prefill / llm.first_token are recorded on the engine
+    thread under the context submit() saw; llm.decode and llm.emit nest in
+    llm.step."""
+    from ray_tpu import tracing
+
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    eng = InferenceEngine(StubModel(), EngineConfig(page_tokens=4, pool_pages=16), name="t-spans")
+    try:
+        with tracing.span("request") as req:
+            gen = eng.generate([1, 2, 3], 3)
+        assert list(gen) == _stub_tokens([1, 2, 3], 3)
+    finally:
+        eng.close()
+        tracing.disable()
+    by = {}
+    for s in exp.spans:
+        by.setdefault(s["name"], []).append(s)
+    for name in ("llm.queue", "llm.prefill", "llm.first_token"):
+        (s,) = by[name]
+        assert s["trace_id"] == req["trace_id"] and s["parent_id"] == req["span_id"], name
+        assert s["attrs"]["rid"] == 1
+    assert by["llm.queue"][0]["attrs"] == {"rid": 1, "prompt_tokens": 3, "cached_tokens": 0, "waiting_ahead": 0}
+    assert by["llm.queue"][0]["t1_ns"] <= by["llm.first_token"][0]["t0_ns"]
+    steps = {s["span_id"]: s for s in by["llm.step"]}
+    assert len(steps) == len(by["llm.decode"]) == len(by["llm.emit"]) == 2
+    assert all(s["parent_id"] in steps for s in by["llm.decode"] + by["llm.emit"])
+    assert by["llm.step"][0]["attrs"] == {"admitted": 1, "live": 1}
+    assert by["llm.decode"][0]["attrs"] == {"live": 1, "kv_tokens": 4}
+
+
+@pytest.fixture(scope="module")
+def traced_stream():
+    """One streamed request through serve.run(llm_deployment(stub_model)) on
+    the cluster runtime with RAY_TPU_TRACING=1: every process's spans, read
+    back with collect() after serve.shutdown() killed the replica."""
+    import tempfile
+
+    import ray_tpu as rtpu
+    from ray_tpu import serve, tracing
+
+    mp = pytest.MonkeyPatch()
+    with tempfile.TemporaryDirectory() as d:
+        mp.setenv("RAY_TPU_TRACING", "1")
+        mp.setenv("RAY_TPU_TRACE_DIR", d)
+        rtpu.shutdown()
+        rtpu.init(num_cpus=4, num_workers=2)
+        tracing.enable()
+        try:
+            handle = _deploy_stub(serve, name="llm-traced")
+            tokens = list(handle.options(stream=True).remote([1, 2, 3], 5))
+        finally:
+            serve.shutdown()
+            rtpu.shutdown()
+            tracing.disable()
+            mp.undo()
+        yield {"tokens": tokens, "spans": tracing.collect(d), "driver_pid": os.getpid()}
+
+
+def _named(spans, prefix):
+    return [s for s in spans if s["name"].split(" ")[0] == prefix]
+
+
+def test_streamed_request_is_one_trace_from_handle_to_engine(traced_stream):
+    spans = traced_stream["spans"]
+    assert traced_stream["tokens"] == _stub_tokens([1, 2, 3], 5)
+    (request,) = [s for s in _named(spans, "serve.request") if s["attrs"]["stream"]]
+    trace = [s for s in spans if s["trace_id"] == request["trace_id"]]
+    ids = {s["span_id"] for s in trace}
+    names = {s["name"].split(" ")[0] for s in trace}
+    assert {"serve.request", "serve.stream.next", "serve.replica", "llm.queue", "llm.prefill",
+            "llm.first_token", "core.stream_next", "core.stream_item"} <= names, names
+    # Every parent resolves inside the trace; the request is its only root.
+    assert [s["name"] for s in trace if s["parent_id"] not in ids] == [request["name"]]
+    replica = next(s for s in trace if s["name"].startswith("serve.replica"))
+    assert replica["pid"] != request["pid"]
+    for name in ("llm.queue", "llm.prefill", "llm.first_token"):
+        (s,) = _named(trace, name)
+        assert s["parent_id"] == replica["span_id"] and s["pid"] == replica["pid"]
+    nexts = _named(trace, "serve.stream.next")
+    assert [s["attrs"]["index"] for s in nexts] == list(range(6))  # 5 tokens + the end
+    assert all(s["parent_id"] == request["span_id"] for s in nexts)
+
+
+def test_core_stream_hops_join_on_task_and_index(traced_stream):
+    spans = traced_stream["spans"]
+    hops = {}
+    for name in ("core.stream_item", "core.stream_ack", "core.stream_next"):
+        for s in _named(spans, name):
+            if s["attrs"].get("found") != "header":
+                hops.setdefault((s["attrs"]["task"], s["attrs"]["index"]), {})[name] = s
+    stream = {k: v for k, v in hops.items() if "core.stream_next" in v}
+    assert len({task for task, _ in stream}) == 1
+    assert sorted(i for _, i in stream) == list(range(5))
+    for hop in stream.values():
+        item, ack, nxt = hop["core.stream_item"], hop["core.stream_ack"], hop["core.stream_next"]
+        assert item["t0_ns"] <= item["t1_ns"] and item["t0_ns"] <= ack["t1_ns"] <= nxt["t1_ns"]
+        assert item["attrs"]["route"] == "inline" and item["attrs"]["reported"] == "direct"
+        assert ack["attrs"]["inline"] is True and ack["attrs"]["notified"] is False
+        assert nxt["attrs"]["found"] == "memstore"
+        assert nxt["attrs"]["waits"] >= 0 and nxt["attrs"]["remote_checks"] >= 0
+        assert ack["pid"] == nxt["pid"] == traced_stream["driver_pid"] != item["pid"]
+
+
+def test_replica_spans_survive_serve_shutdown(traced_stream):
+    """The replica is SIGKILLed by serve.shutdown(): its buffer (engine
+    thread included) is flushed in prepare_shutdown."""
+    spans = traced_stream["spans"]
+    replica_pid = next(s["pid"] for s in spans if s["name"].startswith("serve.replica"))
+    engine = [s for s in spans if s["name"] in ("llm.step", "llm.decode", "llm.emit")]
+    assert len(engine) == 3 * 4 and {s["pid"] for s in engine} == {replica_pid}
+    assert {s["name"] for s in _named(spans, "serve.run")} == {"serve.run"}

@@ -76,3 +76,130 @@ def test_nested_task_tree_parent_linked_spans(tmp_path, monkeypatch):
     out_ids = {s["attrs"].get("flow_out") for s in submits}
     for s in runs:
         assert s["attrs"].get("flow_in") in out_ids, s
+
+
+# ------------------------------------------------ hot-path contract (PR 24)
+def test_span_off_is_one_shared_noop_and_creates_no_file(tmp_path, monkeypatch):
+    monkeypatch.setenv("RAY_TPU_TRACE_DIR", str(tmp_path / "t"))
+    tracing.disable()
+    a, b = tracing.span("a"), tracing.span("b", {"k": 1})
+    assert a is b  # no generator, no dict: the same object every time
+    with a as sp:
+        assert sp is None
+    assert tracing.continue_context({"trace_id": "x", "span_id": "y"}, "c") is a
+    assert tracing.current_context() is None and tracing.inject_context() is None
+    tracing.flush()
+    assert not (tmp_path / "t").exists()
+
+
+def test_span_is_timed_on_the_monotonic_clock():
+    import time
+
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    try:
+        before = time.monotonic_ns()
+        with tracing.span("timed", {"k": 1}) as sp:
+            assert sp["attrs"] == {"k": 1}
+        after = time.monotonic_ns()
+    finally:
+        tracing.disable()
+    (s,) = exp.spans
+    assert before <= s["t0_ns"] <= s["t1_ns"] <= after
+    # start_us/end_us are the same two instants on the wall anchor, not a
+    # second clock read per span.
+    assert s["end_us"] - s["start_us"] == s["t1_ns"] // 1000 - s["t0_ns"] // 1000
+    assert abs(s["start_us"] - time.time() * 1e6) < 5e6
+
+
+def test_buffered_exporter_writes_on_disable_and_collect_reads_back(tmp_path, monkeypatch):
+    d = tmp_path / "spans"
+    monkeypatch.setenv("RAY_TPU_TRACE_DIR", str(d))
+    tracing.enable()
+    try:
+        for i in range(50):
+            with tracing.span(f"s{i}", {"i": i}):
+                pass
+        assert not d.exists() or not os.listdir(d)  # buffered: nothing on disk yet
+    finally:
+        tracing.disable()
+    spans = tracing.collect(str(d))
+    assert sorted(s["attrs"]["i"] for s in spans) == list(range(50))
+    assert all(s["t0_ns"] <= s["t1_ns"] and s["pid"] == os.getpid() for s in spans)
+
+
+def test_explicit_parent_and_recorded_span():
+    """What the engine thread uses: a span under a context captured on
+    another thread, and a span whose instants the caller took."""
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    try:
+        with tracing.span("request"):
+            ctx = tracing.current_context()
+        with tracing.span("elsewhere", parent=ctx):
+            with tracing.span("nested"):
+                pass
+        tracing.record_span("instant", 5_000, 5_000, {"rid": 1}, parent=ctx)
+    finally:
+        tracing.disable()
+    by = {s["name"]: s for s in exp.spans}
+    assert by["elsewhere"]["parent_id"] == by["request"]["span_id"]
+    assert by["nested"]["parent_id"] == by["elsewhere"]["span_id"]
+    assert by["instant"]["parent_id"] == by["request"]["span_id"]
+    assert len({s["trace_id"] for s in exp.spans}) == 1
+    assert by["instant"]["t0_ns"] == by["instant"]["t1_ns"] == 5_000
+
+
+@pytest.mark.parametrize("on", ["0", "1"])
+def test_device_span_does_not_import_jax(tmp_path, on):
+    """A device=True span in a process without jax (the driver) must not
+    import it, tracing on or off."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from ray_tpu import tracing\n"
+        "with tracing.span('llm.step', {'live': 1}, device=True):\n"
+        "    pass\n"
+        "tracing.disable()\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+    )
+    env = dict(os.environ, RAY_TPU_TRACING=on, RAY_TPU_TRACE_DIR=str(tmp_path))
+    subprocess.run([sys.executable, "-c", code], env=env, check=True, timeout=60)
+    assert len(tracing.collect(str(tmp_path))) == int(on)
+
+
+def test_device_span_enters_a_trace_annotation(monkeypatch):
+    """With jax imported, a device=True span is a TraceAnnotation when
+    tracing is off, and wraps one when it is on."""
+    import jax
+
+    entered = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            entered.append((self.name, self.kw))
+            return self
+
+        def __exit__(self, *a):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    tracing.disable()
+    with tracing.span("llm.decode", {"live": 2}, device=True):
+        pass
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    try:
+        with tracing.span("llm.decode", {"live": 3}, device=True):
+            pass
+        with tracing.span("not.device", {"live": 4}):
+            pass
+    finally:
+        tracing.disable()
+    assert entered == [("llm.decode", {"live": 2}), ("llm.decode", {"live": 3})]
+    assert [s["name"] for s in exp.spans] == ["llm.decode", "not.device"]
