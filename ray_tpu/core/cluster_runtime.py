@@ -34,6 +34,7 @@ import cloudpickle
 from .. import exceptions as exc
 from .. import tracing as _tracing
 from ..observability.logs import get_logger as _get_logger
+from ..utils import internal_metrics as imet
 from ..utils.config import CONFIG
 from .ids import ActorID, ObjectID, TaskID
 from .object_transport import StoredError
@@ -43,6 +44,13 @@ from .shm_store import SharedMemoryStore
 from .task_spec import ArgRef, TaskSpec, TaskType
 
 _log = _get_logger("driver")
+
+# stream_next on the direct path: seconds without an ack before the raylet
+# is asked. 2 s and not _get_one's 5 s: a stream's consumer expects its next
+# item soon, so silence that long is already unusual, the check costs one
+# RPC per blocked call per period, and it bounds how long a lost ack or a
+# dead producer node can go unseen.
+_STREAM_SILENCE_S = 2.0
 
 
 def _entry_from_spec(spec: TaskSpec) -> dict:
@@ -283,15 +291,19 @@ class ClusterRuntime(Runtime):
         self._cancelled_tids: set = set()
         # Fast-path completion wakeups: the worker's in-band ack marks the
         # outputs sealed, waking local get()s milliseconds before the
-        # batched raylet/GCS notification lands.
+        # batched raylet/GCS notification lands. _fast_lock guards the
+        # sets below (an RLock: a GC pass inside a locked section may run
+        # a generator's __del__ -> stream_done on the same thread).
         self._fast_pending: set = set()
-        self._fast_seal_cv = threading.Condition()
-        # Oids a local get()/wait() is CURRENTLY blocked on: acks notify
-        # the cv only when they deliver one of these. Unconditional
-        # notify_all at ack rate (10k+/s) would wake the consumer once per
-        # completion — on a single shared core that context-switch storm
-        # throttles the producer pipeline ~20x.
-        self._fast_waiting: set = set()
+        self._fast_lock = threading.RLock()
+        # Oids a local get()/wait()/stream_next() is CURRENTLY blocked on
+        # -> the events of those calls (_await_ack). An ack sets the events
+        # of the ids it delivers and no other: one shared condition with
+        # notify_all at ack rate (10k+/s) woke every blocked consumer once
+        # per completion — on a single shared core that context-switch
+        # storm throttles the producer pipeline ~20x, and with N live
+        # streams every token cost N wake-ups.
+        self._ack_waiters: Dict[str, List[threading.Event]] = {}
         # Owner memory store: small direct-task results live here, never
         # touching shm or the GCS directory (reference: the CoreWorker
         # in-memory store, src/ray/core_worker/store_provider/memory_store/).
@@ -301,6 +313,13 @@ class ClusterRuntime(Runtime):
         # discovered item oids (hex prefix == task id) are accepted into
         # the memory store even before adoption into _owned.
         self._stream_tasks: set = set()
+        # Their items whose ack said "sealed in a store" (not inline), until
+        # consumed: stream_next tells "not acked yet" (wait for the ack)
+        # from "acked, bytes on another node" (go to the raylet and pull).
+        self._stream_sealed: set = set()
+        self._m_stream_next = {
+            w: imet.STREAM_NEXT.labels(woken=w) for w in imet.STREAM_NEXT_WOKEN
+        }
         self._renv_cache: Dict[str, dict] = {}
         # Structured logging: the driver's own records land in the
         # session's log dir (observability/logs.py), and captured worker
@@ -330,7 +349,7 @@ class ClusterRuntime(Runtime):
             ).start()
 
     def _fast_register(self, entry: dict) -> None:
-        with self._fast_seal_cv:
+        with self._fast_lock:
             self._fast_pending.update(entry["return_ids"])
 
     def _fast_sealed(self, sealed: List[str], inline: Optional[dict] = None) -> bool:
@@ -372,18 +391,46 @@ class ClusterRuntime(Runtime):
                     except Exception:
                         memstore[h] = blob  # last resort: gets still work
                         self._memstore_bytes += len(blob)
-        with self._fast_seal_cv:
+        with self._fast_lock:
             self._fast_pending.difference_update(sealed)
             if inline:
                 self._fast_pending.difference_update(inline.keys())
-            waiting = self._fast_waiting
-            if waiting and (
-                any(h in waiting for h in sealed)
-                or (inline and any(h in waiting for h in inline))
-            ):
-                self._fast_seal_cv.notify_all()
-                return True
-        return False
+            if sealed and self._stream_tasks:
+                self._stream_sealed.update(
+                    h for h in sealed if h[:24] in self._stream_tasks
+                )
+            woke = False
+            waiters = self._ack_waiters
+            if waiters:
+                for h in (*sealed, *inline) if inline else sealed:
+                    for ev in waiters.get(h, ()):
+                        ev.set()
+                        woke = True
+        return woke
+
+    def _await_ack(self, hexes: List[str], timeout: float, landed) -> Optional[bool]:
+        """Blocks until a direct connection's ack delivers one of `hexes`
+        (True) or `timeout` seconds pass (False). `landed` is the caller's
+        arrival check, repeated under the lock before waiting (None if it
+        holds: no wait was made). _fast_sealed fills the memory store and
+        the sets before it looks for waiters under the same lock, so no
+        wake-up is lost."""
+        woke = threading.Event()
+        waiters = self._ack_waiters
+        with self._fast_lock:
+            if landed():
+                return None
+            for h in hexes:
+                waiters.setdefault(h, []).append(woke)
+        try:
+            return woke.wait(timeout)
+        finally:
+            with self._fast_lock:
+                for h in hexes:
+                    evs = waiters[h]
+                    evs.remove(woke)
+                    if not evs:
+                        del waiters[h]
 
     def _log_subscriber(self) -> None:
         """Re-prints captured worker output at the driver with
@@ -762,19 +809,13 @@ class ClusterRuntime(Runtime):
             if h in self._fast_pending:
                 # In flight on a direct connection: the completion ack wakes
                 # this wait — no RPC. After ~5s of true silence (wall time,
-                # not wakeups — ack storms wake every waiter constantly) we
-                # fall through to the raylet path as a safety net.
+                # not wakeups) we fall through to the raylet path as a
+                # safety net.
                 now = time.monotonic()
                 if fast_until is None:
                     fast_until = now + 5.0
                 if now < fast_until:
-                    with self._fast_seal_cv:
-                        if h in self._fast_pending:
-                            self._fast_waiting.add(h)
-                            try:
-                                self._fast_seal_cv.wait(timeout=0.05)
-                            finally:
-                                self._fast_waiting.discard(h)
+                    self._await_ack([h], 0.05, lambda: h not in self._fast_pending)
                     continue
             fast_until = None
             if h in self._memstore or self._store.contains(oid):
@@ -816,12 +857,10 @@ class ClusterRuntime(Runtime):
                 [h for h in hexes if self._store.contains(ObjectID.from_hex(h))]
             ) < num_returns:
                 # Direct tasks in flight: wait on the ack wakeup first.
-                with self._fast_seal_cv:
-                    self._fast_waiting.update(pending_fast)
-                    try:
-                        self._fast_seal_cv.wait(timeout=0.05)
-                    finally:
-                        self._fast_waiting.difference_update(pending_fast)
+                pending = self._fast_pending
+                self._await_ack(
+                    pending_fast, 0.05, lambda: any(h not in pending for h in pending_fast)
+                )
                 if deadline is not None and time.monotonic() >= deadline:
                     ready_h = mem_ready | {
                         h for h in hexes if self._store.contains(ObjectID.from_hex(h))
@@ -1030,67 +1069,113 @@ class ClusterRuntime(Runtime):
     def stream_next(self, task_id, index: int, timeout: Optional[float] = None):
         """Next item oid of a streaming task, or None at end of stream.
 
-        Items land incrementally (inline stream acks on the direct path,
-        seal notifications otherwise); the header at return index 0 closes
-        the stream with the item count."""
-        # core.stream_next: one span per call; `note` (its attrs, None with
-        # tracing off) counts what the call waited on and where it found
-        # the item. Joins core.stream_item / core.stream_ack on (task, index).
+        Items land incrementally; the header at return index 0 closes the
+        stream with the item count. What wakes a blocked call depends on
+        what it can observe. While the producing task is in flight on a
+        direct connection (its header id is in _fast_pending), every item
+        and the header arrive as acks on that connection, and the ack
+        wakes the call: no RPC, no poll. Otherwise (raylet-path task, or an
+        item known to be sealed on another node) the raylet's wait_objects
+        is the event-driven wait, from the first miss."""
+        # core.stream_next: one span per call; `note` (its attrs) counts
+        # what the call waited on, where it found the item and how its last
+        # wait ended. Joins core.stream_item / core.stream_ack on (task,
+        # index). raytpu_stream_next_total counts the same `woken`, always.
+        note = {"waits": 0, "remote_checks": 0, "woken": "none"}
         with _tracing.span("core.stream_next") as sp:
-            note = None
-            if sp is not None:
-                note = sp["attrs"]
-                note.update(task=task_id.hex()[:24], index=index, waits=0, remote_checks=0)
-            return self._stream_next(task_id, index, timeout, note)
+            try:
+                return self._stream_next(task_id, index, timeout, note)
+            finally:
+                self._m_stream_next[note["woken"]].inc()
+                if sp is not None:
+                    sp["attrs"].update(note, task=task_id.hex()[:24], index=index)
 
-    def _stream_next(self, task_id, index: int, timeout: Optional[float], note: Optional[dict]):
+    def _stream_next(self, task_id, index: int, timeout: Optional[float], note: dict):
         from .object_ref import STREAM_COUNT_KEY
 
         header_oid = task_id.object_id_for_return(0)
         item_oid = task_id.object_id_for_return(index + 1)
         h_item, h_header = item_oid.hex(), header_oid.hex()
+        memstore, pending = self._memstore, self._fast_pending
+        sealed = self._stream_sealed
         deadline = None if timeout is None else time.monotonic() + timeout
-        last_remote_check = 0.0
+        net_at: Optional[float] = None  # direct path: when the raylet net runs next
         while True:
-            if h_item in self._memstore or self._store.contains(item_oid):
-                if note is not None:
-                    note["found"] = "memstore" if h_item in self._memstore else "store"
+            if h_item in memstore or self._store.contains(item_oid):
+                note["found"] = "memstore" if h_item in memstore else "store"
                 self._adopt_stream_item(h_item)
+                if h_item in sealed:
+                    with self._fast_lock:
+                        sealed.discard(h_item)
                 return item_oid
-            if h_header in self._memstore or self._store.contains(header_oid):
+            # The item is known to exist but is not local (a large item made
+            # on another node; its ack, or the header's count, says so):
+            # only the raylet can pull it in, so no ack is waited for.
+            remote = h_item in sealed
+            if h_header in memstore or self._store.contains(header_oid):
                 hdr = self._get_one(header_oid, None)  # raises task errors
                 if index >= hdr.get(STREAM_COUNT_KEY, 0):
-                    if note is not None:
-                        note["found"] = "header"
+                    note["found"] = "header"
                     return None
-                # Item exists somewhere but is not local yet: fall through
-                # to the wait (the raylet path below pulls it in).
-            if deadline is not None and time.monotonic() >= deadline:
+                remote = True
+            remaining = None if deadline is None else deadline - time.monotonic()
+            if remaining is not None and remaining <= 0:
+                note["woken"] = "timeout"
                 raise exc.GetTimeoutError(
                     f"stream item {index} of {task_id.hex()[:12]} timed out"
                 )
-            now = time.monotonic()
-            if now - last_remote_check > 2.0:
-                # Periodic raylet-side wait: pulls items produced on other
-                # nodes and covers lost acks (same safety net as _get_one).
-                last_remote_check = now
-                if note is not None:
-                    note["remote_checks"] += 1
-                try:
-                    self._raylet.call(
-                        "wait_objects", [h_item, h_header], 1, 0.2, True, timeout=10.0
+            poll = CONFIG.object_wait_poll_s
+            if not remote and h_header in pending:
+                # In flight on a direct connection: the item's ack (or the
+                # header's, with the task's completion or failure) wakes
+                # this wait; no RPC while acks keep coming.
+                now = time.monotonic()
+                if net_at is None:  # this call's first miss on the direct path
+                    net_at = now + _STREAM_SILENCE_S
+                if now < net_at:
+                    to_net = net_at - now
+                    by_net = remaining is None or to_net <= remaining
+                    woke = self._await_ack(
+                        [h_item, h_header],
+                        to_net if by_net else remaining,
+                        lambda: h_item in memstore or h_item in sealed or h_header not in pending,
                     )
-                except Exception:  # lint: swallow-ok(advisory remote check; producer-death net below)
-                    pass
+                    if woke is not None:
+                        note["waits"] += 1
+                        if woke:
+                            note["woken"] = "ack"
+                        elif by_net:
+                            note["woken"] = "poll"  # a lost wake-up, or a slow producer
+                    continue
+                # Silence (wall time, not wake-ups): the periodic net for a
+                # lost ack or a dead producer node. Kept short, because an
+                # ack that lands meanwhile waits for this call to return.
+                net_at = now + _STREAM_SILENCE_S
+                poll = 0.05
+            if h_item in memstore or (not remote and h_header in memstore):
+                # The ack landed after the check at the loop top (the header
+                # leaves _fast_pending only after the memory store is
+                # filled): the raylet never hears of an inline object.
+                continue
+            if remaining is not None:
+                poll = max(0.05, min(poll, remaining))
+            # Event-driven wait on the local raylet (pulls remote copies
+            # in); returns as soon as a wanted object is local. A header
+            # that is here already would only answer for the item.
+            note["remote_checks"] += 1
+            note["woken"] = "raylet"
+            wanted = [h_item] if remote else [h_item, h_header]
+            try:
+                ready = self._raylet.call(
+                    "wait_objects", wanted, 1, poll, True, timeout=poll + 10.0
+                )
+            except Exception:  # lint: swallow-ok(advisory remote check; producer-death net below)
+                ready = None
+            if not ready:
                 # Producer-death safety net: the header's task record drives
                 # retry/reconstruct or raises ObjectLostError — without this
                 # a stream whose producing NODE died would block forever.
                 self._maybe_recover(header_oid)
-                continue
-            if note is not None:
-                note["waits"] += 1
-            with self._fast_seal_cv:
-                self._fast_seal_cv.wait(timeout=0.05)
 
     def _adopt_stream_item(self, h: str) -> None:
         """First sight of a dynamically-created stream item: this process
@@ -1105,8 +1190,11 @@ class ClusterRuntime(Runtime):
 
     def stream_done(self, task_id) -> None:
         prefix = task_id.hex()[:24]
-        with self._fast_seal_cv:
+        with self._fast_lock:
             self._stream_tasks.discard(prefix)
+            self._stream_sealed.difference_update(
+                [h for h in self._stream_sealed if h.startswith(prefix)]
+            )
         # Purge never-adopted inline items (consumer stopped early).
         for h in [k for k in self._memstore if k.startswith(prefix)]:
             with self._ref_lock:
@@ -1172,7 +1260,7 @@ class ClusterRuntime(Runtime):
         entry = _entry_from_spec(spec)
         spec.return_ids = [ObjectID.from_hex(h) for h in entry["return_ids"]]
         if entry.get("streaming"):
-            with self._fast_seal_cv:
+            with self._fast_lock:
                 # Keyed by the 12-byte task prefix (first 24 hex chars of
                 # any of the task's object ids).
                 self._stream_tasks.add(spec.task_id.hex()[:24])
@@ -1274,7 +1362,7 @@ class ClusterRuntime(Runtime):
         entry = _entry_from_spec(spec)
         spec.return_ids = [ObjectID.from_hex(h) for h in entry["return_ids"]]
         if entry.get("streaming"):
-            with self._fast_seal_cv:
+            with self._fast_lock:
                 self._stream_tasks.add(spec.task_id.hex()[:24])
         self._record_submission(entry, "actor_task")
         with _submit_span(entry):

@@ -570,6 +570,16 @@ FASTPATH_RTT = Histogram(
     "Direct-push round trip: owner send to completion ack",
     component="fastpath",
 )
+STREAM_NEXT_WOKEN = ("none", "ack", "raylet", "poll", "timeout")
+STREAM_NEXT = Counter(
+    "raytpu_stream_next_total",
+    "stream_next calls ended, by how the last wait ended: none (item already "
+    "there), ack (woken by the direct connection's ack), raylet (wait_objects), "
+    "poll (no ack for the whole silence period, yet the item was there: a lost "
+    "wake-up), timeout (the caller's deadline passed)",
+    component="fastpath",
+    tag_keys=("woken",),
+)
 # --- shm object store -----------------------------------------------------
 STORE_PUTS = Counter(
     "raytpu_store_puts_total",

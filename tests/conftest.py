@@ -63,6 +63,25 @@ def rt_local():
     rt.shutdown()
 
 
+@pytest.fixture(scope="session")
+def stream_next_counts():
+    """Reader of raytpu_stream_next_total by `woken`, as counted in this
+    process: the cumulative per-thread cells that a flush reports deltas of
+    (a lane's own _delta() would race the background flusher)."""
+    from ray_tpu.utils import internal_metrics as imet
+
+    lanes = {w: imet.STREAM_NEXT.labels(woken=w) for w in imet.STREAM_NEXT_WOKEN}
+
+    def read():
+        with imet._lock:
+            return {
+                w: lane._retired + sum(c[0] for _, c in lane._cells)
+                for w, lane in lanes.items()
+            }
+
+    return read
+
+
 @pytest.fixture
 def rt_cluster():
     """An initialized single-node multi-process cluster."""

@@ -609,7 +609,7 @@ def test_engine_thread_spans_carry_the_request_context():
 
 
 @pytest.fixture(scope="module")
-def traced_stream():
+def traced_stream(stream_next_counts):
     """One streamed request through serve.run(llm_deployment(stub_model)) on
     the cluster runtime with RAY_TPU_TRACING=1: every process's spans, read
     back with collect() after serve.shutdown() killed the replica."""
@@ -626,14 +626,18 @@ def traced_stream():
         rtpu.init(num_cpus=4, num_workers=2)
         tracing.enable()
         try:
-            handle = _deploy_stub(serve, name="llm-traced")
+            # 20 ms a decode step: the client is waiting when each token lands.
+            handle = _deploy_stub(serve, name="llm-traced", step_delay_s=0.02)
+            before = stream_next_counts()
             tokens = list(handle.options(stream=True).remote([1, 2, 3], 5))
+            woken = {k: v - before[k] for k, v in stream_next_counts().items()}
         finally:
             serve.shutdown()
             rtpu.shutdown()
             tracing.disable()
             mp.undo()
-        yield {"tokens": tokens, "spans": tracing.collect(d), "driver_pid": os.getpid()}
+        yield {"tokens": tokens, "spans": tracing.collect(d), "driver_pid": os.getpid(),
+               "woken": woken}
 
 
 def _named(spans, prefix):
@@ -675,10 +679,33 @@ def test_core_stream_hops_join_on_task_and_index(traced_stream):
         item, ack, nxt = hop["core.stream_item"], hop["core.stream_ack"], hop["core.stream_next"]
         assert item["t0_ns"] <= item["t1_ns"] and item["t0_ns"] <= ack["t1_ns"] <= nxt["t1_ns"]
         assert item["attrs"]["route"] == "inline" and item["attrs"]["reported"] == "direct"
-        assert ack["attrs"]["inline"] is True and ack["attrs"]["notified"] is False
-        assert nxt["attrs"]["found"] == "memstore"
-        assert nxt["attrs"]["waits"] >= 0 and nxt["attrs"]["remote_checks"] >= 0
+        assert ack["attrs"]["inline"] is True and nxt["attrs"]["found"] == "memstore"
+        # An inline item arrives as an ack on the direct connection: the raylet
+        # is never asked, and the call returns when the ack lands.
+        assert nxt["attrs"]["remote_checks"] == 0
+        assert nxt["t1_ns"] - ack["t1_ns"] < 50e6
+        if nxt["attrs"]["waits"]:
+            # The consumer was waiting: the ack woke it.
+            assert nxt["attrs"]["woken"] == "ack" and ack["attrs"]["notified"] is True
+        else:
+            # The ack landed before the call looked: nobody to notify.
+            assert nxt["attrs"]["woken"] == "none" and ack["attrs"]["notified"] is False
         assert ack["pid"] == nxt["pid"] == traced_stream["driver_pid"] != item["pid"]
+    assert sum(1 for hop in stream.values() if hop["core.stream_ack"]["attrs"]["notified"]) >= 3
+
+
+def test_stream_next_counter_matches_the_spans(traced_stream):
+    """raytpu_stream_next_total{woken} is always on and counts what the
+    core.stream_next spans say: one a call, the end of the stream included."""
+    nexts = _named(traced_stream["spans"], "core.stream_next")
+    assert len(nexts) == 6  # 5 tokens + the header
+    tally = {}
+    for s in nexts:
+        tally[s["attrs"]["woken"]] = tally.get(s["attrs"]["woken"], 0) + 1
+    woken = traced_stream["woken"]
+    assert set(woken) == {"none", "ack", "raylet", "poll", "timeout"}
+    assert {k: v for k, v in woken.items() if v} == tally
+    assert woken["raylet"] == woken["poll"] == woken["timeout"] == 0 and woken["ack"] >= 3
 
 
 def test_replica_spans_survive_serve_shutdown(traced_stream):
