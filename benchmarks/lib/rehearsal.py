@@ -1,5 +1,6 @@
-"""Shrinks a cell to the tiny widths of benchmarks/tests/tiny.json for the
-CPU rehearsal and the tests. Used by benchmarks/rehearse.py and
+"""Shrinks a cell for the CPU rehearsal and the tests: its architecture
+file's TINY widths, and the engine sizes and traffic of
+benchmarks/tests/tiny.json. Used by benchmarks/rehearse.py and
 benchmarks/tests only; run.py has no way to reach it."""
 
 from __future__ import annotations
@@ -20,8 +21,7 @@ def _merge(dst: dict, src: dict) -> None:
 def shrink(cell: Cell) -> Cell:
     tiny = load_json(os.path.join(cell.bench_dir, "tests", "tiny.json"))
     _merge(cell.config, tiny["config"])
-    if cell.config["num_key_value_heads"] > cell.config["num_attention_heads"]:
-        cell.config["num_key_value_heads"] = cell.config["num_attention_heads"]
+    _merge(cell.config, cell.arch.TINY)
     _merge(cell.traffic, tiny["traffic"][cell.traffic["runner"]])
     cell.allow_cpu = True
     return cell

@@ -157,7 +157,7 @@ def run_cell(cell: Cell, start: Callable[[Load, float], None], horizon_s: float)
 
     tr = cell.traffic
     assumed = {k: v["value"] for k, v in cell.config["assumed"].items()}
-    vocab = int(cell.config["vocab_size"])
+    vocab = cell.arch.vocab_size(cell.config)
     with driver.runtime(cell) as chips:
         app = llm_deployment(
             build, name=APP, model_kwargs={"bench": driver.worker_config(cell)},

@@ -1,13 +1,14 @@
 """Finds every piece of a cell by the names in BENCHMARK.json.
 
-    workloads[i].config   -> benchmarks/configs/<config>.json
+    workloads[i].config   -> benchmarks/configs/<config>.json      (names its architecture)
+    config["arch"]        -> benchmarks/archs/<arch>.py           (mapping, plain reference, counts)
     workloads[i].traffic  -> benchmarks/traffic/<traffic>.json   (names its runner)
     traffic["runner"]     -> benchmarks/runners/<runner>.py       (run(cell) -> evidence)
     per_layer[j].name     -> benchmarks/metrics/<name>.json       (names its reader)
     metric["reader"]      -> benchmarks/readers/<reader>.py       (read(evidence, args) -> value | None)
 
-Nothing here imports jax: the process that runs a cell's driver side must
-never open a backend (the chip belongs to the trainer's worker or the
+Nothing here opens a jax backend: the process that runs a cell's driver
+side must never hold the chip (it belongs to the trainer's worker or the
 serve replica).
 """
 
@@ -21,6 +22,12 @@ from typing import Any, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+
+# Keys of a configuration file that are the harness's own, and published
+# keys that carry no shape. Every other key must be read by the
+# configuration's architecture file (its PUBLISHED_KEYS).
+HARNESS_KEYS = frozenset({"source", "arch", "reduced_from", "stands_for", "fewer_layers_mean", "assumed"})
+SHAPELESS_KEYS = frozenset({"architectures", "model_type", "torch_dtype", "bos_token_id", "eos_token_id", "pad_token_id"})
 
 
 def load_json(path: str) -> Dict[str, Any]:
@@ -53,6 +60,10 @@ class Cell:
     allow_cpu: bool = False  # tests and rehearsals only; run.py never sets it
 
     @property
+    def arch(self):
+        return load_arch(self.config)
+
+    @property
     def out_prefix(self) -> str:
         return os.path.join(self.bench_dir, "out", f"{self.name}-{self.seed}")
 
@@ -71,7 +82,7 @@ def find_cell(name: str, root: str = ROOT) -> Cell:
     else:
         raise SystemExit(f"no workload {name!r} in BENCHMARK.json")
     configs = {c["name"]: c for c in spec["configs"]}
-    config = load_json(os.path.join(root, configs[w["config"]]["file"]))
+    config = load_config(os.path.join(root, configs[w["config"]]["file"]))
     traffic = load_json(os.path.join(bench_dir, "traffic", w["traffic"] + ".json"))
     return Cell(
         name=name,
@@ -84,6 +95,30 @@ def find_cell(name: str, root: str = ROOT) -> Cell:
         per_layer=[m for m in spec["per_layer"] if _in_cell(m, name)],
         bench_dir=bench_dir,
     )
+
+
+def load_arch(config: Dict[str, Any]):
+    """The architecture file a configuration names: the only place that
+    gives a model key a meaning."""
+    return importlib.import_module(f"benchmarks.archs.{config['arch']}")
+
+
+def load_config(path: str) -> Dict[str, Any]:
+    """A configuration file, refused unless its architecture maps it whole:
+    a published key that nothing reads would be a different model under
+    this model's name."""
+    config = load_json(path)
+    if "arch" not in config:
+        raise ValueError(f'{path}: names no architecture file ("arch": a module of benchmarks/archs/)')
+    arch = load_arch(config)
+    unread = sorted(set(config) - arch.PUBLISHED_KEYS - HARNESS_KEYS - SHAPELESS_KEYS)
+    if unread:
+        raise ValueError(
+            f"{path}: {', '.join(map(repr, unread))} is read neither by its architecture file "
+            f"benchmarks/archs/{config['arch']}.py (PUBLISHED_KEYS) nor by the harness: "
+            "a configuration is mapped whole or does not run"
+        )
+    return config
 
 
 def load_runner(cell: Cell):
@@ -102,50 +137,3 @@ def read_metric(cell: Cell, metric_name: str, evidence: Dict[str, Any]) -> Optio
     reader = importlib.import_module(f"benchmarks.readers.{mf['reader']}")
     value = reader.read(evidence, dict(mf.get("args", {}), cell=cell))
     return None if value is None else float(value)
-
-
-# ------------------------------------------------------------ model config
-
-
-def model_dims(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The sizes the benchmark's own arithmetic (FLOPs, bytes, reference)
-    needs, under short names, from the published keys of a config file."""
-    return {
-        "d": int(config["hidden_size"]),
-        "f": int(config["intermediate_size"]),
-        "h": int(config["num_attention_heads"]),
-        "kv": int(config["num_key_value_heads"]),
-        "hd": int(config.get("head_dim") or config["hidden_size"] // config["num_attention_heads"]),
-        "L": int(config["num_hidden_layers"]),
-        "V": int(config["vocab_size"]),
-        "theta": float(config["rope_theta"]),
-        "eps": float(config["rms_norm_eps"]),
-        "tied": bool(config.get("tie_word_embeddings", False)),
-        "bytes_per_param": {"bfloat16": 2, "float32": 4}[config.get("torch_dtype", "bfloat16")],
-    }
-
-
-def transformer_config(config: Dict[str, Any], **overrides):
-    """The program's TransformerConfig for a config file (imports jax;
-    call it only in the process that owns the chip)."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import transformer as tfm
-
-    m = model_dims(config)
-    if m["hd"] * m["h"] != m["d"]:
-        raise ValueError("TransformerConfig derives head_dim as d_model // n_heads")
-    if config.get("hidden_act", "silu") != "silu":
-        raise ValueError("only the gated-silu MLP is mapped")
-    assumed = {k: v["value"] for k, v in config.get("assumed", {}).items()}
-    kw = dict(
-        vocab_size=m["V"], d_model=m["d"], n_layers=m["L"], n_heads=m["h"], n_kv_heads=m["kv"],
-        d_ff=m["f"], max_seq_len=int(config["max_position_embeddings"]), rope_theta=m["theta"],
-        norm_eps=m["eps"], tie_embeddings=m["tied"],
-        dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[config.get("torch_dtype", "bfloat16")],
-        attn_impl=assumed.get("attn_impl", "full"),
-    )
-    if "remat_policy" in assumed:
-        kw["remat_policy"] = assumed["remat_policy"]
-    kw.update(overrides)
-    return tfm.TransformerConfig(**kw)

@@ -21,6 +21,7 @@ import time
 import zlib
 from typing import Any, Dict, List
 
+from . import spec
 from .worker_train import cache_everything, device_facts, find_xplane, memory_peak_bytes, seeded_key
 
 
@@ -40,8 +41,6 @@ class BenchModel:
         from ray_tpu.serve.llm.model import PagedLM
         from ray_tpu.utils import compile_cache
 
-        from . import spec
-
         t0 = time.monotonic()
         self._jax = jax
         self.conf = conf
@@ -49,8 +48,8 @@ class BenchModel:
         cache_everything()
         self.device = device_facts(jax.devices(), conf["allow_cpu"])
         t1 = time.monotonic()
-        self.m = spec.model_dims(conf["model"])
-        cfg = spec.transformer_config(conf["model"])
+        self.arch = spec.load_arch(conf["model"])
+        cfg = self.arch.model_config(conf["model"])
         eng = {k: v["value"] for k, v in conf["model"]["assumed"].items()}
         params = jax.jit(lambda k: tfm.init_params(k, cfg))(seeded_key(conf["seed"]))
         self.lm = PagedLM(
@@ -117,10 +116,11 @@ class BenchModel:
                 "compile": self.watch.snapshot(), "device": self.device}
 
     def _cmd_check(self, engine, prompts, served, which) -> Dict[str, Any]:
-        from . import reference
+        from . import correct
 
         margins = [
-            reference.served_token_margins(self.lm.params, p, s, self.m, which) for p, s in zip(prompts, served)
+            correct.served_token_margins(self.arch, self.lm.params, p, s, self.conf["model"], which)
+            for p, s in zip(prompts, served)
         ]
         return {"margins": margins}
 
@@ -146,6 +146,7 @@ class BenchModel:
             "memory_peak_bytes": memory_peak_bytes(self._jax.devices()),
             "engine": engine.stats(),
             "compile": self.watch.snapshot(),
+            "arch_file": os.path.relpath(self.arch.__file__, spec.ROOT),
         }
 
 

@@ -71,7 +71,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     from ray_tpu.train import zero
     from ray_tpu.utils import compile_cache
 
-    from . import reference, spec
+    from . import spec
 
     watch = compile_cache.watch()
     cache_everything()
@@ -83,8 +83,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     t_devices = time.monotonic()
 
     seq, per_chip = int(traffic["seq_len"]), int(traffic["batch_per_chip"])
-    cfg = spec.transformer_config(config["model"], max_seq_len=seq)
-    m = spec.model_dims(config["model"])
+    arch = spec.load_arch(config["model"])
+    cfg = arch.model_config(config["model"], max_seq_len=seq)
     lr = config["model"]["assumed"]["learning_rate"]["value"]
     zero_axis = "data" if n > 1 else None
     tx = optax.adamw(lr)
@@ -107,10 +107,8 @@ def train_loop(config: Dict[str, Any]) -> None:
 
     # Correctness, outside the window: the plain float32 reference on every
     # sequence of the batch, each chip taking its own, one at a time.
-    mf = reference.Frozen(m)
-
     def ref_loss(p, t):
-        per_seq = jax.lax.map(lambda s: reference.sequence_nll(p, s, mf), t)
+        per_seq = jax.lax.map(lambda s: arch.sequence_nll(p, s, config["model"]), t)
         return jax.lax.pmean(jnp.mean(per_seq), "data")
 
     ref = float(
@@ -184,4 +182,5 @@ def train_loop(config: Dict[str, Any]) -> None:
             "first_step_and_warmup": t_warm - t_ref,
         },
         "n_params": tfm.param_count(params),
+        "arch_file": os.path.relpath(arch.__file__, spec.ROOT),
     }})

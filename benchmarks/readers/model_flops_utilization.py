@@ -1,7 +1,6 @@
-"""Tokens/s x the model's FLOPs per token (benchmarks/lib/flops.py: forward +
-backward, no recomputation) / (chips x the chip's peak), in percent."""
+"""Tokens/s x the model's FLOPs per token (the cell's architecture file:
+forward + backward, no recomputation) / (chips x the chip's peak), in percent."""
 
-from ..lib import flops, spec
 from . import train_throughput
 from ._common import device_peaks
 
@@ -12,5 +11,5 @@ def read(evidence, args):
     if rate is None or peaks is None:
         return None
     cell = args["cell"]
-    per_token = flops.train_flops_per_token(spec.model_dims(cell.config), int(cell.traffic["seq_len"]))
+    per_token = cell.arch.train_flops_per_token(cell.config, int(cell.traffic["seq_len"]))
     return 100.0 * rate * per_token / peaks["bf16_flops_per_s"]

@@ -38,7 +38,7 @@ def _cells(names):
 def rehearse_cpu(names) -> int:
     """Runs each cell through run.main() in a process of its own: the same
     runner, worker code, readers and last line as on the chip, with the
-    configuration's widths replaced by the tiny ones in benchmarks/tests/tiny.json."""
+    configuration's widths replaced by its architecture file's TINY ones."""
     failed = 0
     for cell in _cells(names):
         env = dict(os.environ)
@@ -95,7 +95,6 @@ def aot_train(cell, batch_per_chip=None) -> dict:
     import optax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from benchmarks.lib import spec
     from ray_tpu.models import transformer as tfm
     from ray_tpu.train import zero
 
@@ -103,7 +102,7 @@ def aot_train(cell, batch_per_chip=None) -> dict:
     _force_mosaic()
     n = cell.chips
     mesh = Mesh(np.array(topo.devices[:n]), ("data",))
-    cfg = spec.transformer_config(cell.config, max_seq_len=cell.traffic["seq_len"])
+    cfg = cell.arch.model_config(cell.config, max_seq_len=cell.traffic["seq_len"])
     tx = optax.adamw(1e-4)
     zero_axis = "data" if n > 1 else None
     _init, step = tfm.build_train_step(cfg, tx, mesh, zero_axis=zero_axis)
@@ -143,13 +142,13 @@ def aot_serve(cell) -> list:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from benchmarks.lib import spec, traffic as traffic_lib
+    from benchmarks.lib import traffic as traffic_lib
     from ray_tpu.models import transformer as tfm
 
     topo = _topology()
     _force_mosaic()
     one = SingleDeviceSharding(topo.devices[0])
-    cfg = spec.transformer_config(cell.config)
+    cfg = cell.arch.model_config(cell.config)
     eng = {k: v["value"] for k, v in cell.config["assumed"].items()}
     T, P_, B, N = eng["page_tokens"], eng["max_pages_per_seq"], eng["max_slots"], eng["pool_pages"]
 

@@ -69,7 +69,7 @@ def main(argv=None, prepare=None, every_metric=False) -> int:
         "device": device,
     }
     facts = {k: evidence.get(k) for k in ("checks", "margins", "ticker_gaps", "load_facts") if evidence.get(k) is not None}
-    for k in ("loss", "setup_parts_s", "warmup", "n_params"):
+    for k in ("loss", "setup_parts_s", "warmup", "n_params", "arch_file"):
         if worker.get(k) is not None:
             facts[k] = worker[k]
     if cell.trace:

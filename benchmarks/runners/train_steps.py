@@ -37,7 +37,10 @@ def run(cell: Cell) -> Dict[str, Any]:
             raise result.error
         worker = dict(result.metrics["summary"])
         driver.wait_pid_gone(worker["pid"])
+    steps_ms = sorted((t1 - t0) * 1e3 for _name, t0, t1, _args in worker["spans"])
     return {
+        # a whole-host stall shows as ONE long step (PERF.md, PR 26): the facts line says so
+        "load_facts": {"steps": len(steps_ms), "step_ms_p50": steps_ms[len(steps_ms) // 2], "step_ms_longest": steps_ms[-3:]},
         "cell": cell,
         "worker": worker,
         "window": worker["window"],

@@ -4,6 +4,9 @@ import importlib
 import json
 import os
 import re
+import shutil
+import subprocess
+import sys
 import types
 
 import pytest
@@ -69,23 +72,103 @@ def test_cell_files_exist_and_load(name):
         importlib.import_module(f"benchmarks.readers.{spec.metric_file(cell, m['name'])['reader']}").read
 
 
+# Every key its architecture reads, as PUBLISHED (a reduced key too), for the configurations this test knows.
+PUBLISHED = {
+    "mistral-7b-v0.3-L4": dict(
+        hidden_size=4096, intermediate_size=14336, num_attention_heads=32, num_key_value_heads=8, head_dim=128,
+        num_hidden_layers=32, vocab_size=32768, max_position_embeddings=32768, rope_theta=1e6, rms_norm_eps=1e-5,
+        hidden_act="silu", sliding_window=None, tie_word_embeddings=False, torch_dtype="bfloat16"),
+    "deepseek-llm-7b-chat-L8": dict(
+        hidden_size=4096, intermediate_size=11008, num_attention_heads=32, num_key_value_heads=32, head_dim=128,
+        num_hidden_layers=30, vocab_size=102400, max_position_embeddings=4096, rope_theta=1e4, rms_norm_eps=1e-6,
+        hidden_act="silu", tie_word_embeddings=False, torch_dtype="bfloat16"),
+}
+
+
 @pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
-def test_config_keeps_published_widths_and_reduces_only_depth(conf):
-    cfg = spec.load_json(os.path.join(spec.ROOT, conf["file"]))
-    assert conf["reduced"] == ["num_hidden_layers"] == list(cfg["reduced_from"])
-    assert cfg["source"] == conf["source"] and conf["source"].startswith("https://huggingface.co/")
-    published = {
-        "mistral-7b-v0.3-L4": dict(hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
-                                   num_key_value_heads=8, vocab_size=32768, rope_theta=1e6, rms_norm_eps=1e-5),
-        "deepseek-llm-7b-chat-L8": dict(hidden_size=4096, intermediate_size=11008, num_attention_heads=32,
-                                        num_key_value_heads=32, vocab_size=102400, rope_theta=1e4, rms_norm_eps=1e-6,
-                                        max_position_embeddings=4096),
-    }[conf["name"]]
-    for k, v in published.items():
-        assert cfg[k] == v, k
+def test_config_keeps_its_published_values_and_states_its_cuts(conf):
+    """`reduced` may name any key whose published value `reduced_from`
+    records; every other key the architecture reads is as published. A
+    configuration this test does not know is held to the same form."""
+    cfg = spec.load_config(os.path.join(spec.ROOT, conf["file"]))
+    arch = spec.load_arch(cfg)
+    assert conf["reduced"] == list(cfg["reduced_from"]) and set(conf["reduced"]) <= arch.PUBLISHED_KEYS
+    assert cfg["source"] == conf["source"]
+    assert re.match(r"^https://huggingface\.co/[^/]+/[^/]+/blob/main/config\.json$", conf["source"])
     for k, v in cfg["assumed"].items():
         assert v.get("why"), f"assumed.{k} has no reason"
-    assert "fewer_layers_mean" in cfg
+    assert cfg["stands_for"]
+    if "num_hidden_layers" in cfg["reduced_from"]:
+        assert cfg["fewer_layers_mean"]
+    published = PUBLISHED.get(conf["name"])
+    if published is not None:
+        assert set(cfg) & arch.PUBLISHED_KEYS == set(published)
+        for k, v in published.items():
+            if k in cfg["reduced_from"]:
+                assert cfg["reduced_from"][k] == v and cfg[k] != v, k
+            else:
+                assert cfg[k] == v, k
+
+
+OLMOE = {  # catalog `OLMoE-1B-7B-0125-Instruct`: intermediate_size is ONE expert's width
+    "source": "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json", "arch": "dense_decoder",
+    "attention_bias": False, "clip_qkv": None, "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 1024,
+    "max_position_embeddings": 4096, "model_type": "olmoe", "norm_topk_prob": False, "num_attention_heads": 16,
+    "num_experts": 64, "num_experts_per_tok": 8, "num_hidden_layers": 16, "num_key_value_heads": 16, "rms_norm_eps": 1e-05,
+    "rope_scaling": None, "rope_theta": 10000, "tie_word_embeddings": False, "vocab_size": 50304,
+}
+
+
+def test_a_configuration_with_a_key_nothing_maps_is_refused(tmp_path):
+    """A routed-expert model under the dense architecture would run as a
+    dense 2048 x 1024 model and agree with a reference that is the same wrong
+    model. It does not run: finding the cell fails, before a runner, a
+    runtime or a backend exists, and says which key and which file."""
+    root = str(tmp_path)
+    os.makedirs(os.path.join(root, "benchmarks", "configs"))
+    shutil.copytree(os.path.join(spec.BENCH_DIR, "traffic"), os.path.join(root, "benchmarks", "traffic"))
+    bench = dict(BENCH, configs=[{"name": "olmoe", "source": OLMOE["source"], "file": "benchmarks/configs/olmoe.json",
+                                  "reduced": [], "why": "test"}],
+                 workloads=[dict(BENCH["workloads"][0], config="olmoe")])
+
+    def write(config):
+        for path, data in ((os.path.join(root, "BENCHMARK.json"), bench), (os.path.join(root, "benchmarks", "configs", "olmoe.json"), config)):
+            with open(path, "w") as f:
+                json.dump(data, f)
+
+    write(OLMOE)
+    with pytest.raises(ValueError) as refused:
+        spec.find_cell(bench["workloads"][0]["name"], root)
+    said = str(refused.value)
+    assert "'num_experts'" in said and "'num_experts_per_tok'" in said and "'norm_topk_prob'" in said
+    assert "benchmarks/archs/dense_decoder.py" in said and "olmoe.json" in said
+    probe = ("import sys; from benchmarks.lib import driver, spec\n"
+             "try: spec.find_cell(sys.argv[1], sys.argv[2])\n"
+             "except ValueError as e: print('refused', driver.backend_initialized(), e)")
+    alone = subprocess.run([sys.executable, "-c", probe, bench["workloads"][0]["name"], root], cwd=spec.ROOT,
+                           env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True, text=True, timeout=120)
+    assert alone.stdout.startswith("refused False ") and "'num_experts'" in alone.stdout, alone.stderr[-2000:]
+    write({k: v for k, v in OLMOE.items() if k not in ("num_experts", "num_experts_per_tok", "norm_topk_prob")})
+    cell = spec.find_cell(bench["workloads"][0]["name"], root)
+    assert cell.arch.matmul_params(cell.config) == 472_121_344  # the dense model those keys describe: not OLMoE's 6.9 B
+
+
+def test_the_counts_did_not_move():
+    """The architecture file's counts, as benchmarks/lib/flops.py gave them
+    before they moved (PR 26): pure functions of the configuration files."""
+    mistral = spec.find_cell("mistral7b-train-seq4k-1chip")
+    arch = mistral.arch
+    assert os.path.relpath(arch.__file__, spec.ROOT) == "benchmarks/archs/dense_decoder.py"
+    assert arch.matmul_params(mistral.config) == 1_006_632_960
+    assert arch.train_flops_per_token(mistral.config, 4096) == 6_442_450_944
+    assert arch.kernels(mistral.config, 3, 4096) == {
+        "fwd": (412_316_860_416, 253_231_104), "dq": (618_475_290_624, 355_467_264), "dkv": (824_633_720_832, 305_135_616)}
+    deepseek = spec.find_cell("dsllm7b-serve-chat-steady")
+    assert deepseek.arch is arch
+    assert arch.matmul_params(deepseek.config) == 2_038_431_744
+    assert arch.weight_bytes_per_decode_step(deepseek.config) == 4_076_863_488
+    assert arch.kv_bytes_per_token(deepseek.config) == 131_072
+    assert arch.decode_step_min_bytes(deepseek.config, 10, 1000) == arch.decode_step_min_bytes(deepseek.config, 1, 1000) == 4_207_935_488
 
 
 @pytest.mark.parametrize("name", [c for c in CELLS if "serve" in c])
@@ -118,7 +201,7 @@ def test_generator_is_deterministic_and_seed_changes_only_content(name):
     longer = traffic.generate(cell.traffic, 60)
     if a[0].due_s is not None:
         assert longer[: len(a)] == a  # a longer horizon extends the schedule, it does not reshuffle it
-    vocab = cell.config["vocab_size"]
+    vocab = cell.arch.vocab_size(cell.config)
     p1, p1b = traffic.prompt_tokens(a[3], 7, vocab), traffic.prompt_tokens(a[3], 7, vocab)
     p2 = traffic.prompt_tokens(a[3], 3000000019, vocab)
     assert p1 == p1b and p1 != p2 and len(p1) == len(p2) == a[3].prompt_tokens
@@ -130,7 +213,7 @@ def test_followup_turn_shares_its_sessions_prefix():
     reqs = traffic.generate(cell.traffic, 60)
     follow = next(r for r in reqs if len(r.segments) > 2)
     parent = next(r for r in reqs if r.idx < follow.idx and r.segments == follow.segments[: len(r.segments)])
-    vocab = cell.config["vocab_size"]
+    vocab = cell.arch.vocab_size(cell.config)
     a, b = traffic.prompt_tokens(parent, 5, vocab), traffic.prompt_tokens(follow, 5, vocab)
     assert b[: len(a)] == a and len(b) > len(a) + follow.segments[-1][1]  # parent + stand-in answer + new turn
     share = sum(1 for r in reqs if len(r.segments) > 2) / len(reqs)
