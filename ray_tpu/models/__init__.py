@@ -1,8 +1,7 @@
 """First-class TPU-native model implementations (net-new vs the reference,
 which delegates models to torch user code — SURVEY.md §2d/§6)."""
 
-from . import mlp, moe, transformer
-from .moe import EXPERT_RULES, MoEConfig, init_moe_params, moe_apply
+from . import mlp, transformer
 from .transformer import (
     TransformerConfig,
     flops_per_token,
@@ -17,7 +16,7 @@ from .transformer import (
 )
 
 __all__ = [
-    "mlp", "moe", "transformer", "EXPERT_RULES", "MoEConfig", "init_moe_params", "moe_apply", "TransformerConfig", "flops_per_token", "forward",
+    "mlp", "transformer", "TransformerConfig", "flops_per_token", "forward",
     "gpt_j_6b", "init_params", "llama2_7b", "llama2_13b", "next_token_loss",
     "param_count", "tiny",
 ]

@@ -72,6 +72,16 @@ class TransformerConfig:
     rotary_dim: Optional[int] = None
     norm_type: str = "rms"  # "rms" | "layer"
     rope_style: str = "half"  # "half" (llama rotate-half) | "interleaved" (GPT-J)
+    # Routed FFN (OLMoE family): n_experts > 0 replaces the dense MLP by
+    # n_experts SwiGLU experts of width d_ff each, of which every token
+    # takes its n_experts_per_tok most probable (dropless: no capacity, no
+    # dropped token). norm_topk_prob renormalises the chosen probabilities
+    # to sum to 1. qk_norm: an RMSNorm with its own scale over the WHOLE q
+    # and k projections, between the projections and rope.
+    n_experts: int = 0
+    n_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -119,7 +129,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     ray_tpu.parallel.sharding.TRANSFORMER_RULES (right-aligned for the
     leading n_layers dim)."""
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    L, d, f, v = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size
+    L, d, f, v, E = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_experts
+    if E and cfg.mlp_act != "swiglu":
+        raise ValueError("routed experts are SwiGLU")
     k = iter(jax.random.split(key, 16))
 
     def dense(key, shape, fan_in):
@@ -140,6 +152,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             "mlp_norm": {"scale": jnp.ones((L, d), cfg.dtype)},
             "mlp": (
                 {
+                    "w_gate": dense(next(k), (L, E, d, f), d),
+                    "w_up": dense(next(k), (L, E, d, f), d),
+                    "w_down": dense(next(k), (L, E, f, d), f),
+                    "router": dense(next(k), (L, d, E), d),
+                }
+                if E
+                else {
                     "w_gate": dense(next(k), (L, d, f), d),
                     "w_up": dense(next(k), (L, d, f), d),
                     "w_down": dense(next(k), (L, f, d), f),
@@ -153,6 +172,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         },
         "final_norm": {"scale": jnp.ones((d,), cfg.dtype)},
     }
+    if cfg.qk_norm:
+        params["blocks"]["attn"]["q_norm"] = {"scale": jnp.ones((L, nh * hd), cfg.dtype)}
+        params["blocks"]["attn"]["k_norm"] = {"scale": jnp.ones((L, nkv * hd), cfg.dtype)}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(k), (d, v), d)
     return params
@@ -182,6 +204,10 @@ def _ckpt(val, name: str):
 # kernel's o/lse (named in ops/flash_attention.py). Backward recomputes
 # only the norms, rope on nothing (q/k/v are saved post-rope), and the
 # gate/up MLP dots (~10% extra layer FLOPs) instead of the whole layer.
+# The routed FFN saves what the router decided (moe_route: probabilities,
+# chosen experts, the sort and its inverse, group sizes; a few MB) and the
+# rows sorted by expert (moe_xs_bf16): its backward recomputes the gate/up
+# grouped matmuls like the dense path, and never the top-k or the sorts.
 HOT_SAVE_NAMES = (
     "flash_o",
     "flash_lse",
@@ -191,6 +217,8 @@ HOT_SAVE_NAMES = (
     "attn_out_bf16",
     "mlp_in_bf16",
     "mlp_act_bf16",
+    "moe_route",
+    "moe_xs_bf16",
 )
 
 
@@ -291,18 +319,105 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
     return attention_reference(q, k, v, causal=True)
 
 
-def _layer(x, layer_params, cfg: TransformerConfig, cos, sin, mesh: Optional[Mesh]):
+def _qkv(h, ap, cfg: TransformerConfig):
+    """h [b, s, d] -> q [b, s, n_heads, hd], k, v [b, s, n_kv_heads, hd], before rope."""
+    b, s, _ = h.shape
+    hd = cfg.head_dim
+    q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
+    k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
+    v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
+    if cfg.qk_norm:
+        # over the whole projection, before the split into heads (OLMoE)
+        with jax.named_scope("attn.qk_norm"):
+            q = rms_norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
+            k = rms_norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
+    return (
+        q.reshape(b, s, cfg.n_heads, hd).astype(cfg.dtype),
+        k.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype),
+        v.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype),
+    )
+
+
+def _ffn(h, mp, cfg: TransformerConfig):
+    """The block's feed-forward, h [b, s, d] -> [b, s, d]: the one copy that
+    the train layer, prefill and decode share. Dense (SwiGLU or gelu) or
+    routed by cfg.n_experts."""
+    if cfg.n_experts:
+        return _routed_ffn(h, mp, cfg)
+    up = jnp.einsum("bsd,df->bsf", h, mp["w_up"], preferred_element_type=jnp.float32)
+    if cfg.mlp_act == "swiglu":
+        gate = jnp.einsum(
+            "bsd,df->bsf", h, mp["w_gate"], preferred_element_type=jnp.float32
+        )
+        act = (jax.nn.silu(gate) * up).astype(cfg.dtype)
+    else:
+        act = jax.nn.gelu(up).astype(cfg.dtype)
+    act = _ckpt(act, "mlp_act_bf16")
+    return jnp.einsum(
+        "bsf,fd->bsd", act, mp["w_down"], preferred_element_type=jnp.float32
+    ).astype(cfg.dtype)
+
+
+def _router_probs(x, router):
+    """x [n, d] -> the router's probabilities over all experts [n, E]; matmul
+    and softmax in float32 (and so is the top-k that reads them)."""
+    logits = jnp.einsum(
+        "nd,de->ne",
+        x.astype(jnp.float32),
+        router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def _tokens_per_expert(experts, n_experts: int):
+    """experts: any int array of expert ids -> how often each occurs [E]."""
+    return jnp.sum(experts.reshape(-1, 1) == jnp.arange(n_experts)[None, :], axis=0, dtype=jnp.int32)
+
+
+def _routed_ffn(h, mp, cfg: TransformerConfig):
+    """Dropless top-k mixture of SwiGLU experts. Every (token, chosen expert)
+    pair is one row: rows are sorted by expert, each expert multiplies its
+    own contiguous group (lax.ragged_dot: no capacity, no padding, n * k
+    rows whatever the imbalance), and the rows go back to token order,
+    weighted by the router's probabilities."""
+    b, s, d = h.shape
+    n, k, E = b * s, cfg.n_experts_per_tok, cfg.n_experts
+    x = h.reshape(n, d)
+    with jax.named_scope("moe.router"):
+        probs = _ckpt(_router_probs(x, mp["router"]), "moe_route")
+        top_e = _ckpt(lax.top_k(probs, k)[1], "moe_route")  # [n, k], most probable first
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+        if cfg.norm_topk_prob:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("moe.dispatch"):
+        flat_e = top_e.reshape(n * k)
+        order = _ckpt(jnp.argsort(flat_e), "moe_route")  # row r of the sorted is pair order[r]
+        inverse = _ckpt(jnp.argsort(order), "moe_route")  # pair p sits in sorted row inverse[p]
+        group_sizes = _ckpt(_tokens_per_expert(flat_e, E), "moe_route")
+        xs = _ckpt(jnp.take(x, order // k, axis=0), "moe_xs_bf16")
+    with jax.named_scope("moe.experts"):
+        # Results in the parameters' type (the kernel accumulates in float32):
+        # nothing fuses a convert into a grouped matmul, so float32 results
+        # would double the bytes of every [n * k, .] tensor here and make
+        # the backward products read float32 cotangents.
+        gate = lax.ragged_dot(xs, mp["w_gate"], group_sizes, preferred_element_type=cfg.dtype)
+        up = lax.ragged_dot(xs, mp["w_up"], group_sizes, preferred_element_type=cfg.dtype)
+        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
+        ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
+    with jax.named_scope("moe.combine"):
+        ys = jnp.take(ys, inverse, axis=0).reshape(n, k, d)
+        out = jnp.sum(ys.astype(jnp.float32) * top_p[..., None], axis=1)
+    return out.astype(cfg.dtype).reshape(b, s, d)
+
+
+def _layer(x, layer_params, cfg: TransformerConfig, cos, sin, mesh: Optional[Mesh], stats: bool = False):
     b, s, d = x.shape
     hd = cfg.head_dim
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
     h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
-    q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
-    k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
-    v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
-    q = q.reshape(b, s, cfg.n_heads, hd).astype(cfg.dtype)
-    k = k.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype)
-    v = v.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype)
+    q, k, v = _qkv(h, ap, cfg)
     q = _ckpt(apply_rope(q, cos, sin, cfg), "q_bf16")
     k = _ckpt(apply_rope(k, cos, sin, cfg), "k_bf16")
     v = _ckpt(v, "v_bf16")
@@ -323,21 +438,21 @@ def _layer(x, layer_params, cfg: TransformerConfig, cos, sin, mesh: Optional[Mes
         x = x + attn_out
         mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
     mlp_in = _ckpt(mlp_in, "mlp_in_bf16")
-    up = jnp.einsum(
-        "bsd,df->bsf", mlp_in, mp["w_up"], preferred_element_type=jnp.float32
-    )
-    if cfg.mlp_act == "swiglu":
-        gate = jnp.einsum(
-            "bsd,df->bsf", mlp_in, mp["w_gate"], preferred_element_type=jnp.float32
-        )
-        act = (jax.nn.silu(gate) * up).astype(cfg.dtype)
-    else:
-        act = jax.nn.gelu(up).astype(cfg.dtype)
-    act = _ckpt(act, "mlp_act_bf16")
-    mlp_out = jnp.einsum(
-        "bsf,fd->bsd", act, mp["w_down"], preferred_element_type=jnp.float32
-    ).astype(cfg.dtype)
-    return x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
+    mlp_out = _ffn(mlp_in, mp, cfg)
+    out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
+    if stats:  # routing_stats: what the router did with this layer's input
+        return out, _route_stats(mlp_in.reshape(b * s, d), mp["router"], cfg)
+    return out
+
+
+def _route_stats(x, router, cfg: TransformerConfig):
+    k = cfg.n_experts_per_tok
+    ranked, experts = lax.top_k(_router_probs(x, router), k + 1)
+    return {
+        "experts": experts[:, :k],
+        "tokens_per_expert": _tokens_per_expert(experts[:, :k], cfg.n_experts),
+        "gap": ranked[:, k - 1] - ranked[:, k],
+    }
 
 
 def forward_hidden(
@@ -423,6 +538,23 @@ def next_token_loss(
     return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
 
 
+@partial(jax.jit, static_argnames=("cfg",))
+def routing_stats(params: PyTree, tokens: jax.Array, cfg: TransformerConfig) -> Dict[str, jax.Array]:
+    """What the routers did with tokens [batch, seq], layer by layer (the
+    program's counter for the routed FFN; the forward runs as in training):
+    experts [L, batch*seq, k] each token's experts, most probable first;
+    tokens_per_expert [L, E] (every row sums to batch*seq*k: nothing is
+    dropped); gap [L, batch*seq] between the last probability a token took
+    and the first it left out (how close each token is to another choice)."""
+    cos, sin = rope_tables(cfg, tokens.shape[1])
+    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+
+    def scan_step(x, layer_params):
+        return _layer(x, layer_params, cfg, cos, sin, None, stats=True)
+
+    return lax.scan(scan_step, x, params["blocks"])[1]
+
+
 def build_train_step(
     cfg: TransformerConfig,
     tx,
@@ -498,14 +630,19 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     Attention is counted CAUSALLY (seq/2 average visible positions): the
     flash kernel skips fully-masked blocks, so charging full s^2 would
     inflate MFU by the skipped half. Per token per layer: QK^T + PV =
-    2 matmuls x 2 MAC-FLOPs x (seq/2) x d_model forward, x3 for fwd+bwd."""
+    2 matmuls x 2 MAC-FLOPs x (seq/2) x d_model forward, x3 for fwd+bwd.
+    A routed FFN counts the router and the n_experts_per_tok experts a
+    token passes through, not the experts it leaves alone."""
+    ffn = 3 * cfg.d_model * cfg.d_ff
+    if cfg.n_experts:
+        ffn = ffn * cfg.n_experts_per_tok + cfg.d_model * cfg.n_experts
     n_params = (
         cfg.vocab_size * cfg.d_model
         + cfg.n_layers
         * (
             2 * cfg.d_model * cfg.n_heads * cfg.head_dim
             + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
-            + 3 * cfg.d_model * cfg.d_ff
+            + ffn
         )
         + (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size)
     )
@@ -607,33 +744,6 @@ def _apply_rope_rows(x, cos, sin, cfg: TransformerConfig):
     return out.astype(x.dtype)
 
 
-def _mlp(h, mp, cfg: TransformerConfig):
-    up = jnp.einsum("bsd,df->bsf", h, mp["w_up"], preferred_element_type=jnp.float32)
-    if cfg.mlp_act == "swiglu":
-        gate = jnp.einsum(
-            "bsd,df->bsf", h, mp["w_gate"], preferred_element_type=jnp.float32
-        )
-        act = (jax.nn.silu(gate) * up).astype(cfg.dtype)
-    else:
-        act = jax.nn.gelu(up).astype(cfg.dtype)
-    return jnp.einsum(
-        "bsf,fd->bsd", act, mp["w_down"], preferred_element_type=jnp.float32
-    ).astype(cfg.dtype)
-
-
-def _qkv(h, ap, cfg: TransformerConfig):
-    b, s, _ = h.shape
-    hd = cfg.head_dim
-    q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
-    k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
-    v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
-    return (
-        q.reshape(b, s, cfg.n_heads, hd).astype(cfg.dtype),
-        k.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype),
-        v.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype),
-    )
-
-
 def forward_prefill(
     params: PyTree,
     tokens: jax.Array,
@@ -681,11 +791,11 @@ def forward_prefill(
         ).astype(cfg.dtype)
         if cfg.parallel_block:
             mlp_in = h
-            x = x + attn_out + _mlp(mlp_in, layer_params["mlp"], cfg)
+            x = x + attn_out + _ffn(mlp_in, layer_params["mlp"], cfg)
         else:
             x = x + attn_out
             mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
-            x = x + _mlp(mlp_in, layer_params["mlp"], cfg)
+            x = x + _ffn(mlp_in, layer_params["mlp"], cfg)
         return x, (kp, vp)
 
     x, (k_new, v_new) = lax.scan(
@@ -762,11 +872,11 @@ def forward_decode(
             "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
         ).astype(cfg.dtype)
         if cfg.parallel_block:
-            x = x + attn_out + _mlp(h, layer_params["mlp"], cfg)
+            x = x + attn_out + _ffn(h, layer_params["mlp"], cfg)
         else:
             x = x + attn_out
             mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
-            x = x + _mlp(mlp_in, layer_params["mlp"], cfg)
+            x = x + _ffn(mlp_in, layer_params["mlp"], cfg)
         return x, (kp, vp)
 
     x, (k_new, v_new) = lax.scan(

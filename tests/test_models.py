@@ -121,76 +121,6 @@ def test_mlp_learns_xor_ish():
     assert float(mlp.accuracy(p, batch)) == 1.0
 
 
-# ------------------------------------------------------------ round 3: MoE
-class TestMoE:
-    """Switch-style MoE with expert parallelism (models/moe.py)."""
-
-    def test_matches_per_token_expert_reference(self):
-        import jax
-        import jax.numpy as jnp
-        from ray_tpu.models.moe import MoEConfig, init_moe_params, moe_apply
-
-        cfg = MoEConfig(d_model=8, d_ff=16, n_experts=4)
-        params = init_moe_params(jax.random.PRNGKey(0), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 8))
-        y, aux = moe_apply(params, x, cfg, capacity=12)  # capacity >= all tokens
-
-        # Per-token reference: route each token to its argmax expert.
-        toks = np.asarray(x.reshape(-1, 8), np.float32)
-        router = np.asarray(params["router"], np.float32)
-        probs = jax.nn.softmax(jnp.asarray(toks @ router), axis=-1)
-        ref = np.zeros_like(toks)
-        for n in range(toks.shape[0]):
-            e = int(np.argmax(probs[n]))
-            h = jax.nn.gelu(jnp.asarray(toks[n] @ np.asarray(params["w_up"][e], np.float32)))
-            out = np.asarray(h @ np.asarray(params["w_down"][e], np.float32))
-            ref[n] = out * float(probs[n, e])
-        np.testing.assert_allclose(
-            np.asarray(y).reshape(-1, 8), ref, rtol=2e-4, atol=2e-5
-        )
-        assert np.isfinite(float(aux)) and float(aux) > 0
-
-    def test_overflow_tokens_pass_through(self):
-        import jax
-        import jax.numpy as jnp
-        from ray_tpu.models.moe import MoEConfig, init_moe_params, moe_apply
-
-        cfg = MoEConfig(d_model=4, d_ff=8, n_experts=2)
-        params = init_moe_params(jax.random.PRNGKey(0), cfg)
-        # Identical tokens all route to one expert; capacity 1 drops the rest.
-        x = jnp.ones((1, 5, 4))
-        y, _ = moe_apply(params, x, cfg, capacity=1)
-        # Dropped tokens are the identity residual.
-        np.testing.assert_allclose(np.asarray(y[0, -1]), np.ones(4), rtol=1e-5)
-
-    def test_expert_sharded_execution(self):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ray_tpu.models.moe import MoEConfig, init_moe_params, moe_apply
-        from ray_tpu.parallel.mesh import MeshSpec, build_mesh
-
-        mesh = build_mesh(MeshSpec(data=2, expert=4), devices=jax.devices("cpu")[:8])
-        cfg = MoEConfig(d_model=8, d_ff=16, n_experts=4)
-        params = init_moe_params(jax.random.PRNGKey(0), cfg)
-        sharded = {
-            "router": jax.device_put(params["router"], NamedSharding(mesh, P())),
-            "w_up": jax.device_put(params["w_up"], NamedSharding(mesh, P("expert"))),
-            "w_down": jax.device_put(params["w_down"], NamedSharding(mesh, P("expert"))),
-        }
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 8))
-        x = jax.device_put(x, NamedSharding(mesh, P(("data",))))
-
-        @jax.jit
-        def run(p, xx):
-            y, aux = moe_apply(p, xx, cfg)
-            return y, aux
-
-        y, aux = run(sharded, x)  # XLA compiles the expert all_to_all
-        y_ref, _ = moe_apply(params, np.asarray(x), cfg)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=2e-4, atol=2e-5)
-
-
 def test_paged_decode_matches_full_forward():
     """The serving decode path (paged KV cache, one compiled step per
     batch composition) must be NUMERICALLY the same model as training
