@@ -699,10 +699,12 @@ def forward_pipelined(
 # Inference substrate for serve/llm: the KV cache is a pool of FIXED-SIZE
 # pages shared by every sequence (vLLM's PagedAttention layout). Prefill
 # writes a sequence's k/v into the pages its block table names; decode
-# gathers those pages back, attends over them, and appends the new
-# position — all at static shapes ([B] slots, [B, P] block tables, [N]
-# pages), so ONE compiled decode step serves every batch composition and
-# the continuous-batching scheduler never triggers a recompile.
+# appends the new position and attends over the pages each slot holds
+# (ops/paged_attention.py; paged_attention_gather below for shapes that
+# kernel cannot tile) — all at static shapes ([B] slots, [B, P] block
+# tables, [N] pages), so ONE compiled decode step serves every batch
+# composition and the continuous-batching scheduler never triggers a
+# recompile.
 #
 # Page 0 is reserved as a trash page: masked writes (inactive slots,
 # positions beyond a sequence's length, shared prefix pages owned by the
@@ -717,8 +719,12 @@ def init_kv_pages(
     cfg: TransformerConfig, num_pages: int, page_tokens: int
 ) -> Dict[str, jax.Array]:
     """Allocates the paged KV pool: k/v of shape
-    [n_layers, num_pages, page_tokens, n_kv_heads, head_dim]."""
-    shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads, cfg.head_dim)
+    [n_layers, num_pages, page_tokens, n_kv_heads * head_dim]. A page is
+    tokens x (head, dim): the tile the decode kernel copies and multiplies
+    as it lies, so heads and dim are ONE axis of the stored array (split,
+    the device's tiled layout would put heads where the kernel needs
+    tokens, and every step would pay a relayout of the pool)."""
+    shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -762,6 +768,7 @@ def forward_prefill(
       shared prefix pages owned by the radix cache — identical content was
       already written by the original owner, so rewriting is skipped;
       attention still covers them because the full prompt is recomputed).
+      The radix cache shares whole pages, so it is a multiple of the page.
 
     Returns (last-position logits [1, vocab] fp32, updated kv_pages).
     """
@@ -770,10 +777,15 @@ def forward_prefill(
     cos, sin = rope_tables(cfg, S)
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
 
-    pos = jnp.arange(S)
-    writable = (pos >= write_from) & (pos < length)
-    dest_page = jnp.where(writable, block_table[pos // T], TRASH_PAGE)
-    dest_slot = pos % T
+    # Whole pages are written (a page is one contiguous tile of the pool; a
+    # token row cuts through 32 of them): every page that holds a position
+    # in [write_from, length). The rest of the last page receives the
+    # padding's k/v, which nothing reads (attention stops at the length and
+    # decode overwrites position by position); a write_from inside a page
+    # rewrites that page's head with the identical content it recomputed.
+    first = jnp.arange(S // T) * T
+    writable = (first + T > write_from) & (first < length)
+    dest_page = jnp.where(writable, block_table, TRASH_PAGE)
 
     def scan_step(x, inputs):
         layer_params, kp, vp = inputs
@@ -782,8 +794,8 @@ def forward_prefill(
         q, k, v = _qkv(h, ap, cfg)
         q = apply_rope(q, cos, sin, cfg)
         k = apply_rope(k, cos, sin, cfg)
-        kp = kp.at[dest_page, dest_slot].set(k[0])
-        vp = vp.at[dest_page, dest_slot].set(v[0])
+        kp = kp.at[dest_page].set(k[0].reshape(S // T, T, -1))
+        vp = vp.at[dest_page].set(v[0].reshape(S // T, T, -1))
         o = _attention(q, k, v, cfg, None)
         o = o.reshape(1, S, cfg.n_heads * cfg.head_dim)
         attn_out = jnp.einsum(
@@ -810,6 +822,39 @@ def forward_prefill(
     return logits, {"k": k_new, "v": v_new}
 
 
+def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int):
+    """The plain XLA expression of decode attention: gathers every slot's
+    WHOLE block table out of one layer's pages kp / vp
+    [pages, page_tokens, n_kv_heads * head_dim], casts it to float32 and
+    softmaxes the `P * T`-wide row under the length mask. q [B, n_heads,
+    head_dim], lengths [B] (>= 1). The parity reference of
+    ops/paged_attention.py and the path for shapes that kernel cannot tile
+    (the tiny CPU widths); its traffic is the table, not what is live."""
+    B, H, hd = q.shape
+    P, T = block_tables.shape[1], kp.shape[1]
+    kb = kp[block_tables].reshape(B, P * T, n_kv_heads, hd)
+    vb = vp[block_tables].reshape(B, P * T, n_kv_heads, hd)
+    if H != n_kv_heads:
+        kb = jnp.repeat(kb, H // n_kv_heads, axis=2)
+        vb = jnp.repeat(vb, H // n_kv_heads, axis=2)
+    scores = jnp.einsum(
+        "bhd,bshd->bhs", q.astype(jnp.float32), kb.astype(jnp.float32)
+    ) / math.sqrt(hd)
+    kv_mask = jnp.arange(P * T)[None, :] < lengths[:, None]  # [B, P*T]
+    scores = jnp.where(kv_mask[:, None, :], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhs,bshd->bhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
+
+
+def decode_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    """Which expression forward_decode attends with for this model and
+    page size: "paged_kernel" where ops/paged_attention.py can tile the
+    pool, else "xla_gather". Shapes decide, nothing else does."""
+    from ..ops.paged_attention import can_tile
+
+    return "paged_kernel" if can_tile(page_tokens, cfg.head_dim, cfg.dtype) else "xla_gather"
+
+
 def forward_decode(
     params: PyTree,
     tokens: jax.Array,
@@ -829,6 +874,8 @@ def forward_decode(
     slots write to the trash page and produce garbage logits the scheduler
     ignores. Shapes are static in B/P/N: one jit serves every batch mix.
     """
+    from ..ops.paged_attention import paged_attention
+
     B = tokens.shape[0]
     T = kv_pages["k"].shape[2]
     P = block_tables.shape[1]
@@ -843,30 +890,28 @@ def forward_decode(
     rows = jnp.arange(B)
     dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
     dest_slot = pos % T
-    rep = cfg.n_heads // cfg.n_kv_heads
-    kv_mask = jnp.arange(P * T)[None, :] <= pos[:, None]  # [B, P*T]
+    lengths = jnp.where(active, pos + 1, 0)
+    use_kernel = decode_attention_path(cfg, T) == "paged_kernel"
 
-    def scan_step(x, inputs):
-        layer_params, kp, vp = inputs
+    # The pool rides the layer scan as a CARRY: each layer appends into its
+    # own slice in place and the kernel reads the pool where it lies. As
+    # xs/ys (forward_prefill's way) every step copies the whole pool out of
+    # the stacked array and back in.
+    def scan_step(carry, inputs):
+        x, kp, vp = carry
+        layer, layer_params = inputs
         ap = layer_params["attn"]
         h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
         q, k, v = _qkv(h, ap, cfg)
         q = _apply_rope_rows(q[:, 0], cos, sin, cfg)  # [B, nh, hd]
         k = _apply_rope_rows(k[:, 0], cos, sin, cfg)  # [B, nkv, hd]
-        kp = kp.at[dest_page, dest_slot].set(k)
-        vp = vp.at[dest_page, dest_slot].set(v[:, 0])
-        # Gather AFTER the append so the new position attends to itself.
-        kb = kp[block_tables].reshape(B, P * T, cfg.n_kv_heads, cfg.head_dim)
-        vb = vp[block_tables].reshape(B, P * T, cfg.n_kv_heads, cfg.head_dim)
-        if rep > 1:
-            kb = jnp.repeat(kb, rep, axis=2)
-            vb = jnp.repeat(vb, rep, axis=2)
-        scores = jnp.einsum(
-            "bhd,bshd->bhs", q.astype(jnp.float32), kb.astype(jnp.float32)
-        ) / math.sqrt(cfg.head_dim)
-        scores = jnp.where(kv_mask[:, None, :], scores, -jnp.inf)
-        attn = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhs,bshd->bhd", attn, vb.astype(jnp.float32))
+        kp = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
+        vp = vp.at[layer, dest_page, dest_slot].set(v[:, 0].reshape(B, -1))
+        # Attend AFTER the append so the new position attends to itself.
+        if use_kernel:
+            o = paged_attention(q, kp, vp, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads)
+        else:
+            o = paged_attention_gather(q, kp[layer], vp[layer], block_tables, pos + 1, cfg.n_kv_heads)
         o = o.reshape(B, 1, cfg.n_heads * cfg.head_dim).astype(cfg.dtype)
         attn_out = jnp.einsum(
             "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
@@ -877,10 +922,12 @@ def forward_decode(
             x = x + attn_out
             mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
             x = x + _ffn(mlp_in, layer_params["mlp"], cfg)
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (k_new, v_new) = lax.scan(
-        scan_step, x, (params["blocks"], kv_pages["k"], kv_pages["v"])
+    (x, k_new, v_new), _ = lax.scan(
+        scan_step,
+        (x, kv_pages["k"], kv_pages["v"]),
+        (jnp.arange(cfg.n_layers), params["blocks"]),
     )
     x = _norm(x, params["final_norm"]["scale"], cfg)
     head = params.get("lm_head")

@@ -1,0 +1,246 @@
+"""Paged attention for decode as a pallas TPU kernel.
+
+One decode step attends one new token per slot over that slot's K/V, which
+lies scattered over the page pool (models/transformer.py init_kv_pages). The
+plain XLA expression (transformer.paged_attention_gather, this kernel's
+parity reference and the path for shapes it cannot tile) gathers the WHOLE
+block table of every slot out of the pool, casts it to float32 and softmaxes
+a `P * T`-wide row whatever the slots hold. This kernel moves what is live:
+
+- the pool stays in HBM as it lies, `[layers, pages, page_tokens,
+  n_kv_heads * head_dim]`; `block_tables`, the live `lengths` and the layer
+  index are scalar-prefetched, and slot `b` walks only its
+  `ceil(lengths[b] / page_tokens)` pages, `pages_per_block` at a time: one
+  DMA per page (a page is contiguous) into a double-buffered VMEM block, in
+  the pool's own dtype. A slot with length 0 walks nothing and returns zeros;
+- a page lands as `[page_tokens, n_kv_heads * head_dim]`: tokens on sublanes,
+  (head, dim) on lanes. All heads of a block go through the MXU at once as
+  `Q_bd [n_heads, n_kv_heads * head_dim] x K^T`, where row h of `Q_bd` holds
+  q_h in the lanes of its KV head and zeros elsewhere: the head -> KV head
+  index of GQA is that mask, K/V are never repeated. Every K/V tile passes
+  the MXU once, which is what a per-head product costs too (the MXU is bound
+  by loading K/V tiles, not by the rows of q), and the scores come out dense
+  `[n_heads, block]` instead of one sublane a head;
+- the same mathematics as the reference: K and V are read in the dtype they
+  are stored in, the q.K products accumulate in float32, running max, sum
+  and the output accumulator are float32, and the probabilities are rounded
+  to V's dtype for the P.V product, as ops/flash_attention.py does and no
+  lower. The read is bounded by the live length: scores past it are selected
+  away and V rows past it are zeroed before the product, so what lies in the
+  rest of a page (or in the trash page) never reaches the output;
+- ONE executable serves every batch mix and length: lengths and tables are
+  data, the page walk is a loop with a dynamic trip count.
+
+`interpret=True` (selected when this process's backend is not a TPU) runs the
+same kernel on the CPU for tests.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1e30
+KERNEL_NAME = "paged_attention_decode"
+# Bytes of one VMEM block of K (V has its own, both double-buffered): large
+# enough that the per-block softmax and accumulator update are small beside
+# the DMA, small enough for four of them in the default scoped VMEM.
+BLOCK_BYTES = 1 << 19
+
+
+def _auto_interpret() -> bool:
+    """True off-TPU: the flash kernels' rule, asked of their module at call
+    time (the package exports the function `flash_attention` under the
+    module's name), so that whoever steers it for an ahead-of-time compile
+    steers every kernel at once."""
+    return importlib.import_module("ray_tpu.ops.flash_attention")._auto_interpret()
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one VMEM tile: 8 for 4-byte types, 16 for 2-byte ones."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def can_tile(page_tokens: int, head_dim: int, dtype) -> bool:
+    """Whether the kernel can tile a pool: a head must fill whole 128-lane
+    tiles and a page whole sublane tiles of the pool's dtype, or a page's DMA
+    and a head's slice would cut through a tile (the tiny CPU widths:
+    head_dim 16, 8-token pages)."""
+    return head_dim % 128 == 0 and page_tokens % _sublanes(dtype) == 0
+
+
+def pick_pages_per_block(page_tokens: int, row_width: int, max_pages: int, dtype) -> int:
+    """Pages one VMEM block of K (or V) holds: BLOCK_BYTES of them."""
+    page_bytes = page_tokens * row_width * jnp.dtype(dtype).itemsize
+    return max(1, min(max_pages, BLOCK_BYTES // page_bytes))
+
+
+def _kernel(
+    layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
+    q_ref, k_hbm, v_hbm,  # [1, H, hd] VMEM; [L, N, T, F] HBM, twice
+    o_ref,  # [1, H, hd]
+    k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
+    *, scale, n_kv_heads, page_tokens, pages_per_block, max_pages,
+):
+    b = pl.program_id(0)
+    H, hd = q_ref.shape[1], q_ref.shape[2]
+    rep = H // n_kv_heads
+    F = n_kv_heads * hd
+    T, ppb = page_tokens, pages_per_block
+    bk = ppb * T
+    layer = layer_ref[0]
+    # Indices are clamped as an XLA gather clamps them: a length or a page
+    # index out of range must not become a DMA outside the pool.
+    length = jnp.minimum(lengths_ref[b], max_pages * T)
+    n_pages = (length + T - 1) // T
+    n_blocks = (n_pages + ppb - 1) // ppb
+    last_page = k_hbm.shape[1] - 1
+
+    def copies(blk, slot, act):
+        """Starts or awaits the DMAs of block `blk`'s live pages."""
+        for j in range(ppb):
+            pg = blk * ppb + j
+
+            @pl.when(pg < n_pages)
+            def _():
+                page = jnp.clip(tables_ref[b * max_pages + pg], 0, last_page)
+                rows = pl.ds(j * T, T)
+                for pool, buf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    act(pltpu.make_async_copy(pool.at[layer, page], buf.at[slot, rows], sems.at[s, slot]))
+
+    copies(0, 0, lambda c: c.start())
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    # Row h of q_bd: q_h in the lanes of KV head h // rep, zeros elsewhere.
+    q = q_ref[0]
+    q_tiled = q if n_kv_heads == 1 else jnp.concatenate([q] * n_kv_heads, axis=1)
+    row_kv = lax.broadcasted_iota(jnp.int32, (H, F), 0) // rep
+    lane_kv = lax.broadcasted_iota(jnp.int32, (H, F), 1) // hd
+    q_bd = jnp.where(row_kv == lane_kv, q_tiled, jnp.zeros_like(q_tiled))
+    exact = lax.Precision.HIGHEST if q.dtype == jnp.float32 else None
+
+    def body(blk, _):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            copies(blk + 1, 1 - slot, lambda c: c.start())
+
+        copies(blk, slot, lambda c: c.wait())
+        first = blk * bk
+
+        # Only a slot's last block holds rows past its length (stale VMEM or
+        # the rest of a page). p is 0 there, but 0 * NaN is NaN: zero V.
+        @pl.when(first + bk > length)
+        def _():
+            v = v_buf[slot]
+            live = first + lax.broadcasted_iota(jnp.int32, v.shape, 0) < length
+            v_buf[slot] = jnp.where(live, v, jnp.zeros_like(v))
+
+        s = lax.dot_general(
+            q_bd, k_buf[slot], (((1,), (1,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32,
+        ) * scale  # [H, bk]
+        tok = first + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(tok < length, s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
+            precision=exact, preferred_element_type=jnp.float32,
+        )  # [H, F]; row h is wanted in the lanes of its KV head only
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    lax.fori_loop(0, n_blocks, body, None)
+
+    out_kv = lax.broadcasted_iota(jnp.int32, (H, hd), 0) // rep
+    out = jnp.zeros((H, hd), jnp.float32)
+    for g in range(n_kv_heads):
+        out = jnp.where(out_kv == g, acc_scr[:, g * hd:(g + 1) * hd], out)
+    o_ref[0] = (out / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_attention(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    layer: jax.Array,
+    block_tables: jax.Array,
+    lengths: jax.Array,
+    *,
+    n_kv_heads: int,
+    pages_per_block: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Attention of one new token per slot over the slot's live pages.
+
+    q [B, n_heads, head_dim]; k_pages / v_pages the pool
+    [layers, pages, page_tokens, n_kv_heads * head_dim], read at `layer`
+    (int32 scalar) and never copied; block_tables [B, P] int32 page indices;
+    lengths [B] int32, positions [0, lengths[b]) are attended and 0 means an
+    inactive slot (output zeros). Returns [B, n_heads, head_dim] in q's dtype.
+    """
+    B, H, hd = q.shape
+    _, _, T, F = k_pages.shape
+    P = block_tables.shape[1]
+    if F != n_kv_heads * hd or H % n_kv_heads:
+        raise ValueError(f"pool width {F} is not n_kv_heads {n_kv_heads} x head_dim {hd} (n_heads {H})")
+    if not can_tile(T, hd, k_pages.dtype):
+        raise ValueError(
+            f"paged attention cannot tile head_dim {hd}, page_tokens {T}, {k_pages.dtype}: "
+            "use transformer.paged_attention_gather"
+        )
+    if pages_per_block is None:
+        pages_per_block = pick_pages_per_block(T, F, P, k_pages.dtype)
+    if interpret is None:
+        interpret = _auto_interpret()
+    bk = pages_per_block * T
+    kern = functools.partial(
+        _kernel, scale=1.0 / math.sqrt(hd), n_kv_heads=n_kv_heads, page_tokens=T,
+        pages_per_block=pages_per_block, max_pages=P,
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, bk, F), k_pages.dtype),
+                pltpu.VMEM((2, bk, F), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, F), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        lengths.astype(jnp.int32),
+        block_tables.astype(jnp.int32).reshape(-1),
+        q.astype(k_pages.dtype), k_pages, v_pages,
+    )

@@ -151,6 +151,7 @@ class InferenceEngine:
             "first_token": {"n": 0, "s": 0.0},
             "prefill": {"n": 0, "s": 0.0, "tokens": 0},
             "decode": {"n": 0, "s": 0.0},
+            "decode.kv_pages": {"live": 0, "table": 0},
             "idle": {"s": 0.0},
         }
         self._stage: Optional[tuple] = None  # (clock name, t0)
@@ -468,12 +469,14 @@ class InferenceEngine:
             tokens = [0] * len(self._slots)
             positions = [-1] * len(self._slots)
             tables: List[List[int]] = [[] for _ in self._slots]
-            kv_tokens = 0
+            kv_tokens = live_pages = 0
+            page_tokens = self.config.page_tokens
             for seq in batch:
                 tokens[seq.slot] = seq.last_token
                 positions[seq.slot] = seq.write_pos()
                 tables[seq.slot] = seq.pages.pages
                 kv_tokens += positions[seq.slot] + 1
+                live_pages += -(-(positions[seq.slot] + 1) // page_tokens)
 
         # Model step runs OUTSIDE the lock: submit/cancel stay
         # responsive for the full decode latency.
@@ -515,6 +518,9 @@ class InferenceEngine:
                 return True
             self.decode_steps += 1
             self._clk["decode"]["n"] += 1
+            pages = self._clk["decode.kv_pages"]
+            pages["live"] += live_pages
+            pages["table"] += len(self._slots) * self.model.max_pages_per_seq
             self._m_step.observe(step_ms)
             for seq in batch:
                 if seq.finished or seq.cancelled:
@@ -555,7 +561,10 @@ class InferenceEngine:
         admitted request; first_token: submit -> first emit (the engine's
         own TTFT); prefill / decode: inside model.prefill / model.decode
         (prefill.tokens: prompt tokens of those calls, cached ones
-        included); loop.s: wall time of the loop, loop.idle_s the part of
+        included); decode.kv_pages: over the completed decode steps, the
+        pages their live lengths cover (what a step must read) against
+        slots x pages a sequence (what a step that gathers the block
+        tables reads); loop.s: wall time of the loop, loop.idle_s the part of
         it waiting with nothing to do. loop.s - idle_s - prefill.s -
         decode.s is the engine's own host time. A stage in progress counts
         up to now, so two calls bracket a window exactly."""

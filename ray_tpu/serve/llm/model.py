@@ -108,8 +108,10 @@ class PagedLM:
         self._mu = threading.Lock()
 
     def describe(self) -> Dict[str, Any]:
-        """Which process and devices serve this model, and what compiling
-        cost so far (LLMServer.engine_stats() carries it out)."""
+        """Which process and devices serve this model, which expression the
+        decode executable attends with ("paged_kernel" or "xla_gather":
+        transformer.decode_attention_path), and what compiling cost so far
+        (LLMServer.engine_stats() carries it out)."""
         import os
 
         devs = self._jax.devices()
@@ -118,6 +120,7 @@ class PagedLM:
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
+            "decode_attention": self._tfm.decode_attention_path(self.cfg, self.page_tokens),
             "peak_bytes_in_use": [
                 (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs
             ],

@@ -1,0 +1,182 @@
+"""The paged-attention decode kernel (ray_tpu/ops/paged_attention.py) against
+the kept XLA expression (transformer.paged_attention_gather), in pallas
+interpret mode on the CPU at small tileable shapes: B 4, P 8, T 16,
+head_dim 128. The tiny CPU widths of tests/test_parity.py and
+test_models.py (head_dim 16, 8-token pages) cannot be tiled and stay on
+the XLA expression; the last tests hold that choice and what describe()
+says of it.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import transformer as tfm
+from ray_tpu.ops import paged_attention as pa
+from ray_tpu.serve.llm.kv_cache import TRASH_PAGE
+
+B, P, T, HD, N = 4, 8, 16, 128, 40
+
+# Lengths a slot can have: one token, a page boundary -1 / +0 / +1, a block
+# boundary (pages_per_block 2: 32 tokens), a full table, an inactive slot.
+LENGTHS = {
+    "one_and_page_boundary": (1, T - 1, T, T + 1),
+    "full_table_inactive_block_boundary": (P * T, 0, 2 * T + 1, P * T - 1),
+    "all_inactive_but_one": (0, 0, 5, 0),
+}
+HEADS = {"mha": (4, 4), "gqa4to1": (8, 2)}
+
+
+def _inputs(dtype, heads, lengths, seed=0):
+    """Pool of two layers, q, and block tables whose rows past a slot's
+    length point at the trash page (as the engine builds them)."""
+    H, G = heads
+    rng = np.random.default_rng(seed)
+    kp = jnp.asarray(rng.standard_normal((2, N, T, G * HD)), dtype)
+    vp = jnp.asarray(rng.standard_normal((2, N, T, G * HD)), dtype)
+    q = jnp.asarray(rng.standard_normal((B, H, HD)), dtype)
+    bt = np.full((B, P), TRASH_PAGE, np.int32)
+    free = iter(rng.permutation(np.arange(1, N)))
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // T)):
+            bt[b, j] = next(free)
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(lengths, jnp.int32)
+
+
+def _both(q, kp, vp, bt, lengths, G, layer=1, **kw):
+    out = pa.paged_attention(q, kp, vp, layer, bt, lengths, n_kv_heads=G, **kw)
+    ref = tfm.paged_attention_gather(q, kp[layer], vp[layer], bt, jnp.maximum(lengths, 1), G)
+    return np.asarray(out, np.float32), np.asarray(ref, np.float32)
+
+
+@pytest.mark.parametrize("lengths", LENGTHS.values(), ids=LENGTHS.keys())
+@pytest.mark.parametrize("heads", HEADS.values(), ids=HEADS.keys())
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_kernel_matches_the_xla_expression(dtype, heads, lengths):
+    """float32 pool: the same numbers to 1e-5 (float32 products, statistics
+    and accumulator; only the order of the sums differs). bf16 pool: K and V
+    are read as stored and the q.K products still accumulate in float32, so
+    the one thing the kernel rounds that the expression does not is the
+    probabilities, to V's dtype for the P.V product (flash_attention.py does
+    the same): at most 2^-9 of max|V|, plus the output's own bf16 rounding."""
+    q, kp, vp, bt, lens = _inputs(dtype, heads, lengths)
+    out, ref = _both(q, kp, vp, bt, lens, heads[1], pages_per_block=2)
+    live = np.asarray(lens) > 0
+    if dtype == jnp.float32:
+        tol = 1e-5
+    else:
+        tol = 2.0 ** -9 * float(jnp.max(jnp.abs(vp.astype(jnp.float32)))) + 2.0 ** -8 * np.abs(ref).max()
+    assert np.abs(out[live] - ref[live]).max() < tol
+    assert not out[~live].any(), "an inactive slot walks no page and returns zeros"
+
+
+def test_kernel_reads_nothing_past_the_live_length():
+    """Every position past each slot's length (the rest of its last page,
+    every page it does not hold, the trash page) is NaN: the output is the
+    same finite numbers, because the read is bounded by the length."""
+    lengths = (1, T + 3, 0, P * T - 5)
+    q, kp, vp, bt, lens = _inputs(jnp.float32, (4, 4), lengths)
+    clean, _ = _both(q, kp, vp, bt, lens, 4, pages_per_block=2)
+    held = np.zeros((N, T), bool)
+    for b, n in enumerate(lengths):
+        for t in range(n):
+            held[int(bt[b, t // T]), t % T] = True
+    poison = jnp.where(jnp.asarray(held)[None, :, :, None], kp, jnp.nan)
+    poison_v = jnp.where(jnp.asarray(held)[None, :, :, None], vp, jnp.nan)
+    assert bool(jnp.isnan(poison[1, TRASH_PAGE]).all())
+    got, _ = _both(q, poison, poison_v, bt, lens, 4, pages_per_block=2)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+
+
+def test_kernel_clamps_indices_like_a_gather():
+    """A length past the table and a page index past the pool are clamped
+    (what an XLA gather does with them), never a DMA outside the pool."""
+    q, kp, vp, bt, lens = _inputs(jnp.float32, (4, 4), (P * T, P * T, 3, 0))
+    want, _ = _both(q, kp, vp, bt.at[1, 0].set(N - 1), lens, 4, pages_per_block=3)
+    got, _ = _both(q, kp, vp, bt.at[1, 0].set(N + 7), lens.at[0].set(P * T + 40), 4, pages_per_block=3)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("ppb", [1, 3, 8])
+def test_kernel_block_size_does_not_change_the_result(ppb):
+    """pages_per_block is a tiling, not a parameter of the mathematics (3
+    does not divide the table: the last block of a full slot is short)."""
+    q, kp, vp, bt, lens = _inputs(jnp.float32, (8, 2), (P * T, 37, 0, 16))
+    out, ref = _both(q, kp, vp, bt, lens, 2, layer=0, pages_per_block=ppb)
+    live = np.asarray(lens) > 0
+    assert np.abs(out[live] - ref[live]).max() < 1e-5
+
+
+def test_one_decode_executable_serves_every_batch_mix():
+    """Three batch compositions and lengths (one slot; three slots across a
+    page boundary; the middle slot alone), ONE compile of the decode step,
+    and the served tokens are the full forward's."""
+    from ray_tpu.serve.llm.model import PagedLM
+
+    cfg = tfm.tiny(d_model=256, n_heads=2, n_kv_heads=1, attn_impl="naive", dtype=jnp.float32)
+    lm = PagedLM(cfg, seed=0, num_pages=24, page_tokens=T, max_slots=3, max_pages_per_seq=4)
+    assert lm.describe()["decode_attention"] == "paged_kernel"
+    prompts = {0: [3, 1, 4, 1, 5], 1: list(range(7, 7 + 15)), 2: [9] * 20}
+    pages = {0: [1, 2], 1: [3, 4], 2: [5, 6]}
+    seqs = {s: list(p) + [lm.prefill(p, pages[s][: -(-len(p) // T)], 0)] for s, p in prompts.items()}
+
+    def step(slots):
+        toks, poss, tabs = [0] * 3, [-1] * 3, [[] for _ in range(3)]
+        for s in slots:
+            toks[s], poss[s], tabs[s] = seqs[s][-1], len(seqs[s]) - 1, pages[s]
+        out = lm.decode(toks, poss, tabs)
+        for s in slots:
+            seqs[s].append(out[s])
+
+    step([0])
+    after_first = lm.describe()["compile"]["compiles"]
+    step([0, 1, 2])  # slot 1 writes position 16: its second page
+    step([1])
+    step([0, 2])
+    assert lm.describe()["compile"]["compiles"] == after_first
+    assert lm._decode_jit._cache_size() == 1
+    for s, p in prompts.items():
+        seq = list(p)
+        while len(seq) < len(seqs[s]):
+            logits = tfm.forward(lm.params, jnp.asarray([seq], jnp.int32), lm.cfg)
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert seq == seqs[s], s
+
+
+@pytest.mark.parametrize(
+    "head_dim,page_tokens,dtype,path",
+    [
+        (128, 16, jnp.bfloat16, "paged_kernel"),  # the serving cells, OLMoE, Mistral
+        (256, 16, jnp.bfloat16, "paged_kernel"),  # GPT-J
+        (128, 8, jnp.float32, "paged_kernel"),
+        (128, 8, jnp.bfloat16, "xla_gather"),  # a bf16 page of 8 tokens is half a tile
+        (16, 8, jnp.float32, "xla_gather"),  # tfm.tiny: tests/test_parity.py, test_models.py
+        (64, 16, jnp.bfloat16, "xla_gather"),
+    ],
+)
+def test_path_follows_the_shape(head_dim, page_tokens, dtype, path):
+    cfg = tfm.tiny(d_model=2 * head_dim, n_heads=2, n_kv_heads=2, dtype=dtype)
+    assert tfm.decode_attention_path(cfg, page_tokens) == path
+    assert pa.can_tile(page_tokens, head_dim, dtype) == (path == "paged_kernel")
+
+
+def test_tiny_widths_stay_on_the_xla_expression_and_describe_says_so():
+    from ray_tpu.serve.llm.model import PagedLM
+
+    lm = PagedLM(seed=0, num_pages=8, page_tokens=8, max_slots=2, max_pages_per_seq=2)
+    assert lm.describe()["decode_attention"] == "xla_gather"
+    q, kp, vp, bt, lens = _inputs(jnp.float32, (4, 4), (1, 1, 1, 1))
+    with pytest.raises(ValueError, match="cannot tile"):
+        pa.paged_attention(q[..., :16], kp[..., : 4 * 16], vp[..., : 4 * 16], 0, bt, lens, n_kv_heads=4)
+
+
+def test_interpret_follows_the_backend_like_the_flash_kernels(monkeypatch):
+    """Off a TPU the kernel is interpreted; an ahead-of-time compile for a
+    described TPU steers flash_attention._auto_interpret and gets both."""
+    import sys
+
+    assert jax.default_backend() == "cpu" and pa._auto_interpret() is True
+    monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"], "_auto_interpret", lambda: False)
+    assert pa._auto_interpret() is False

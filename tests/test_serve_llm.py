@@ -576,6 +576,45 @@ def test_engine_stage_clocks(n_requests, step_delay_s):
     assert final["loop"]["s"] == eng.stats()["clocks"]["loop"]["s"]
 
 
+@pytest.mark.parametrize("n_requests,max_new", [(1, 3), (2, 9), (5, 6)])
+def test_engine_counts_live_pages_against_the_table(n_requests, max_new):
+    """clocks["decode.kv_pages"]: `live` is the pages that the positions of
+    every completed decode step cover (what a paged-attention step reads),
+    `table` is slots x pages a sequence for each of those steps (what a
+    step that gathers the block tables reads)."""
+    T, slots, per_seq = 4, 2, 8
+    seen = []
+
+    class Recording(StubModel):
+        def decode(self, last_tokens, positions, block_tables):
+            seen.append([int(p) for p in positions])
+            return super().decode(last_tokens, positions, block_tables)
+
+    eng = InferenceEngine(
+        Recording(max_slots=slots, max_pages_per_seq=per_seq),
+        EngineConfig(page_tokens=T, pool_pages=64),
+        name=f"t-kv-pages-{n_requests}",
+    )
+    try:
+        assert eng.stats()["clocks"]["decode.kv_pages"] == {"live": 0, "table": 0}
+        threads = [
+            threading.Thread(target=lambda i=i: _collect(eng, [1] * (3 + 2 * i), max_new))
+            for i in range(n_requests)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        clk = eng.stats()["clocks"]
+    finally:
+        eng.close()
+    pages = clk["decode.kv_pages"]
+    assert len(seen) == clk["decode"]["n"] >= 1
+    assert pages["table"] == len(seen) * slots * per_seq
+    assert pages["live"] == sum(-(-(p + 1) // T) for step in seen for p in step if p >= 0)
+    assert 0 < pages["live"] <= pages["table"]
+
+
 def test_engine_thread_spans_carry_the_request_context():
     """llm.queue / llm.prefill / llm.first_token are recorded on the engine
     thread under the context submit() saw; llm.decode and llm.emit nest in
