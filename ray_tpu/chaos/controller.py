@@ -14,8 +14,7 @@ Design constraints, in order:
 1. **Disabled cost ~zero.** Every injection site calls
    ``maybe_inject(point, detail)``; with no controller armed that is one
    global load and a ``None`` check — the same budget class as the
-   always-on flight recorder. The bench_core chaos guard holds this to
-   <1% of task throughput.
+   always-on flight recorder.
 2. **Deterministic.** Each rule owns a ``random.Random`` seeded from
    (global seed, rule index), and fire decisions depend only on the
    rule's own hit counter — two runs with the same seed and the same

@@ -33,8 +33,7 @@ counted (``raytpu_net_partitions_total`` / ``raytpu_net_blocked_total``)
 so a campaign's telemetry proves the faults actually happened.
 
 Disarmed cost at the rpc sites: one module-global load + ``is None``
-check (same budget class as ``maybe_inject``), held <1% of task
-dispatch by the bench_core guard.
+check (same budget class as ``maybe_inject``).
 """
 
 from __future__ import annotations

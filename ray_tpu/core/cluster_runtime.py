@@ -36,6 +36,7 @@ from .. import tracing as _tracing
 from ..observability.logs import get_logger as _get_logger
 from ..utils import internal_metrics as imet
 from ..utils.config import CONFIG
+from . import proctree
 from .ids import ActorID, ObjectID, TaskID
 from .object_transport import StoredError
 from .rpc import RpcClient
@@ -205,7 +206,6 @@ class ClusterRuntime(Runtime):
         store: SharedMemoryStore,
         node_id: str,
         session_dir: Optional[str] = None,
-        procs: Optional[List[subprocess.Popen]] = None,
         driver: bool = True,
     ):
         self._gcs = gcs
@@ -213,7 +213,7 @@ class ClusterRuntime(Runtime):
         self._store = store
         self._node_id = node_id
         self._session_dir = session_dir
-        self._procs = procs or []
+        self._cluster: Optional["Cluster"] = None  # set by Cluster.runtime(): this driver owns the session
         self._driver = driver
         # Context identity (reference: runtime_context.py): workers override
         # _worker_id with their raylet-assigned id after attach.
@@ -1296,7 +1296,7 @@ class ClusterRuntime(Runtime):
             # create_actor_batch calls) — the old path paid a second,
             # serial driver->raylet RPC per actor. The span keeps the
             # historical gcs_register name so launch-breakdown tooling
-            # (bench_scale actor_launch_breakdown, ray-tpu timeline)
+            # (ray-tpu timeline)
             # reads old and new traces uniformly; it now covers the
             # whole registration+submit leg.
             with _tracing.span(
@@ -1534,30 +1534,25 @@ class ClusterRuntime(Runtime):
                 ch.close()
         except Exception:  # lint: swallow-ok(best-effort channel close during shutdown)
             pass
-        if self._driver and self._procs:
-            for node in self.nodes():
-                if not node.get("Alive"):
-                    # Drained/terminated nodes have no raylet behind their
-                    # socket; dialing them burns the full 20 s connect
-                    # timeout each (40 s teardowns in autoscaler e2e).
-                    continue
-                try:
-                    self._raylet_for(node["sock"]).call("stop", timeout=2.0)
-                except Exception:  # lint: swallow-ok(shutdown stop is best-effort; SIGKILL below)
-                    pass
+        if self._driver and self._cluster is not None:
+            # Raylets joined from elsewhere (start_worker_node) are not
+            # this cluster's children: ask them to end themselves, as
+            # their own host's `ray-tpu stop` would. The cluster's own
+            # daemons are ended, reaped and swept by Cluster.shutdown.
+            own = set(self._cluster._node_procs)
             try:
-                self._gcs.call("stop", timeout=2.0)
-            except Exception:  # lint: swallow-ok(shutdown stop is best-effort; SIGKILL below)
-                pass
-            time.sleep(0.1)
-            for p in self._procs:
-                if p.poll() is None:
-                    p.terminate()
-            for p in self._procs:
+                joined = [
+                    n for n in self.nodes() if n.get("Alive") and n["NodeID"] not in own
+                ]
+            except Exception as e:
+                _log.warning("no node table at shutdown (%r): joined nodes are not asked to stop", e)
+                joined = []
+            for node in joined:
                 try:
-                    p.wait(timeout=3.0)
-                except subprocess.TimeoutExpired:
-                    p.kill()
+                    self._raylet_for(node["sock"]).call("stop", timeout=proctree.DAEMON_STOP_S)
+                except Exception as e:
+                    _log.warning("joined node %s did not stop: %r", node["NodeID"][:12], e)
+            self._cluster.shutdown()
         self._store.close()
         self._gcs.close()
         self._raylet.close()
@@ -1576,23 +1571,9 @@ def _session_alive(session_dir: str) -> bool:
     candidates = [os.path.join(session_dir, "gcs.sock")]
     candidates += _glob.glob(os.path.join(session_dir, "raylet_*.sock"))
     for sock_path in candidates:
-        if os.path.exists(sock_path) and _uds_accepts(sock_path):
+        if os.path.exists(sock_path) and proctree.uds_accepts(sock_path):
             return True
     return False
-
-
-def _uds_accepts(sock_path: str) -> bool:
-    import socket
-
-    s = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-    s.settimeout(0.2)
-    try:
-        s.connect(sock_path)
-        return True
-    except OSError:
-        return False
-    finally:
-        s.close()
 
 
 def _spawn_logged_cmd(log_dir: str, name: str, cmd: List[str]) -> subprocess.Popen:
@@ -1728,7 +1709,8 @@ class Cluster:
         }
         with open(os.path.join(self.session_dir, "session.json"), "w") as f:
             json.dump(info, f)
-        atexit.register(self._cleanup)
+        self._shutdown_done = False
+        atexit.register(self.shutdown)
 
     def _read_announced(self, log_name: str, prefix: str, timeout: float = 10.0) -> str:
         """Reads a KEY=value announcement a daemon printed to its log
@@ -1829,24 +1811,24 @@ class Cluster:
             SharedMemoryStore(self._store_for(self.head_node_id)),
             self.head_node_id,
             session_dir=self.session_dir,
-            procs=self._procs,
         )
         rt._cluster = self
         return rt
 
-    def _cleanup(self):
-        for p in self._procs:
-            if p.poll() is None:
-                p.kill()
+    def shutdown(self) -> None:
+        """Ends the session: when this returns, no process of it is alive
+        (core/proctree.py). Also the `atexit` hook; safe to call twice."""
+        if self._shutdown_done:
+            return
+        self._shutdown_done = True
+        atexit.unregister(self.shutdown)
+        proctree.end_session(self.session_dir, self._procs)
         # Unlink tmpfs pool files (nothing reclaims /dev/shm automatically).
-        for node_id in list(self._node_procs) + [self.head_node_id]:
+        for path in self._store_paths.values():
             try:
-                os.unlink(self._store_for(node_id))
+                os.unlink(path)
             except OSError:
                 pass
-
-    def shutdown(self):
-        self._cleanup()
 
 
 def start_worker_node(

@@ -31,8 +31,7 @@ shard-count change could otherwise open.
 
 Shard count: `RAY_TPU_GCS_SHARDS` (CONFIG.gcs_shards, default 8).
 `RAY_TPU_GCS_SHARDS=1` degenerates to the pre-sharding design — one
-lock, one segment — and is the baseline the bench_core overhead guard
-pins the sharded path against.
+lock, one segment.
 """
 
 from __future__ import annotations

@@ -39,6 +39,7 @@ from ..observability.flight_recorder import record as _flight_record
 from ..observability.logs import get_logger as _get_logger
 from ..utils import internal_metrics as imet
 from ..utils.config import CONFIG
+from . import proctree
 from .heartbeat import HeartbeatCodec
 from .ids import ObjectID
 from .object_transport import StoredError
@@ -196,6 +197,7 @@ class RayletService(ChaosPartitionRpc):
 
         self._remote_raylets: Dict[str, RpcClient] = {}
         self._stop = threading.Event()
+        self._stopped = threading.Event()  # stop() has ended and reaped the children
         # Drain state (preemption notice received): new default-placement
         # work and lease grants are shed to other nodes while in-flight +
         # gang-pinned work finishes in the grace window.
@@ -2978,24 +2980,28 @@ class RayletService(ChaosPartitionRpc):
             return dict(self.total), dict(self.available)
 
     def stop(self) -> bool:
+        """Ends this raylet's children and returns when they are gone:
+        every worker and, through the pool manager, the zygote with its
+        parked pre-forks. Only then is `main` let out of its loop, so a
+        caller that has the reply (or sees the process exit) knows the
+        level below is empty (core/proctree.py)."""
         self._stop.set()
         # The trigger-bus forwarder wraps self.gcs; a publish after stop
         # (in-process raylets in tests) must not dial a dead GCS.
         from ..observability import postmortem as _postmortem
 
         _postmortem.disarm()
-        with self._workers_lock:
-            for w in self._workers.values():
+        try:
+            with self._workers_lock:
+                workers = list(self._workers.values())
+            for w in workers:
                 w.mailbox.put({"type": "stop"})
-        time.sleep(0.1)
-        with self._workers_lock:
-            for w in self._workers.values():
-                if w.proc.poll() is None:
-                    w.proc.terminate()
-        if self._pool is not None:
-            # Kills the zygote daemon; its parked pre-forks die with it
-            # via their PR_SET_PDEATHSIG tie.
-            self._pool.stop()
+            left = proctree.wait_gone([w.proc for w in workers], proctree.GRACE_S)
+            if self._pool is not None:
+                self._pool.stop()
+            proctree.end(left)
+        finally:
+            self._stopped.set()
         return True
 
 
@@ -3039,7 +3045,7 @@ def main(argv: List[str]) -> None:
         print(f"RAYLET_TCP_ADDRESS={tcp_server.address}", flush=True)  # console-output: bootstrap protocol read by _read_announced
     server = RpcServer(sock_path, service)
     try:
-        while not service._stop.wait(0.5):
+        while not service._stopped.wait(0.5):
             _tracing.flush()  # a raylet may be killed: keep its span file current
     finally:
         if tcp_server is not None:

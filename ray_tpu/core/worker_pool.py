@@ -3,10 +3,10 @@
 Re-design of the reference's worker-pool prestart (reference:
 worker_pool.h PrestartWorkers + the idle-pool sizing around
 kMaximumStartupConcurrency) as a standing control loop instead of the
-PR-1 one-shot boot prestart. The launch profile (bench_scale
-`actor_launch_breakdown`) pinned actor creation on worker_spawn — 17 ms
-p50 / 82 ms p90 against 1-3 ms for register/submit — so this module's
-job is to make sure a launch almost never pays a spawn synchronously:
+PR-1 one-shot boot prestart. Of an actor launch's spans
+(`actor_launch.*`, ray-tpu timeline) worker_spawn is the long one, so
+this module's job is to make sure a launch almost never pays a spawn
+synchronously:
 
 - **Tier 1 — live idle workers** (the raylet's `_idle` map): popped in
   microseconds at dispatch. The manager refills this pool ASYNCHRONOUSLY
@@ -35,6 +35,7 @@ from __future__ import annotations
 import collections
 import math
 import os
+import signal
 import subprocess
 import sys
 import threading
@@ -47,6 +48,7 @@ from ..observability.logs import get_logger as _get_logger
 from ..utils import internal_metrics as imet
 from ..utils import lock_order
 from ..utils.config import CONFIG
+from . import proctree
 from .zygote import ZygoteClient, ZygoteSpawnError
 
 _log = _get_logger("worker_pool")
@@ -128,11 +130,22 @@ class WorkerPoolManager:
         self._thread.start()
 
     def stop(self) -> None:
+        """Ends and reaps the zygote; returns when it is gone. Its exit is
+        what ends the parked pre-forks (their assignment pipes read EOF)."""
         self._stop.set()
         self._wake.set()
+        self._end_zygote()
+        if self._thread.is_alive():
+            # A maintenance round that was mid-boot has seen _stop (or its
+            # zygote die) and ends; the daemon it may just have started is
+            # ended like the first.
+            self._thread.join(proctree.CHILD_EXIT_S)
+            self._end_zygote()
+
+    def _end_zygote(self) -> None:
         proc = self._zygote_proc
-        if proc is not None and proc.poll() is None:
-            proc.kill()
+        if proc is not None:
+            proctree.end([proc], signal.SIGKILL)
 
     # ------------------------------------------------------- demand signal
     def note_demand(self, n: int = 1) -> None:
@@ -418,11 +431,7 @@ class WorkerPoolManager:
                     # / timed out under load): kill it before respawning
                     # or TWO daemons would race for the socket path and
                     # the old one's parked children would leak.
-                    proc.kill()
-                    try:
-                        proc.wait(timeout=5.0)
-                    except Exception:  # lint: swallow-ok(best-effort reap before respawn)
-                        pass
+                    self._end_zygote()
                 if self._boot_zygote():
                     with self._lock:
                         self._respawns += 1

@@ -237,14 +237,11 @@ def _pop_parked(req: dict) -> Optional[int]:
 
 
 def _kill_parked(pid: int, wfd: int) -> None:
-    """One parked child's teardown: close its assignment pipe (EOF ->
-    exit) with a SIGTERM belt for a child wedged outside the read."""
+    """One parked child's teardown: close its assignment pipe. The child
+    reads EOF and exits; one still between its fork and its read finds
+    the EOF waiting."""
     try:
         os.close(wfd)
-    except OSError:
-        pass
-    try:
-        os.kill(pid, signal.SIGTERM)
     except OSError:
         pass
 

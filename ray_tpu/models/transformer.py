@@ -249,7 +249,8 @@ def rope_tables(cfg: TransformerConfig, seq_len: int):
 
 
 def _rotate(x, cos, sin, interleave: bool):
-    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    # [s, r] (every row the same positions) or [b, s, r] -> over all heads
+    c, s = (t[None, :, None, :] if t.ndim == 2 else t[:, :, None, :] for t in (cos, sin))
     if interleave:
         # GPT-J convention: pairs are (even, odd) interleaved dims.
         x1, x2 = x[..., ::2], x[..., 1::2]
@@ -260,9 +261,11 @@ def _rotate(x, cos, sin, interleave: bool):
 
 
 def apply_rope(x, cos, sin, cfg: Optional[TransformerConfig] = None):
-    """x: [b, s, h, d]. Llama rotates the full head (rotate-half); GPT-J
-    rotates only the first rotary_dim dims, interleaved pairs, leaving the
-    rest pass-through."""
+    """x: [b, s, h, d]; cos / sin [s, rotary_dim/2] (the same positions in
+    every row: train, prefill) or [b, s, rotary_dim/2] (each row its own:
+    decode). Llama rotates the full head (rotate-half); GPT-J rotates only
+    the first rotary_dim dims, interleaved pairs, leaving the rest
+    pass-through."""
     rd = cfg.rotary_dim if cfg is not None else None
     interleave = cfg is not None and cfg.rope_style == "interleaved"
     xf = x.astype(jnp.float32)
@@ -411,9 +414,18 @@ def _routed_ffn(h, mp, cfg: TransformerConfig):
     return out.astype(cfg.dtype).reshape(b, s, d)
 
 
-def _layer(x, layer_params, cfg: TransformerConfig, cos, sin, mesh: Optional[Mesh], stats: bool = False):
+def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: bool = False):
+    """THE transformer block, x [b, s, d] -> [b, s, d]: norm, q/k/v, rope,
+    attention, output projection, residual, norm, feed-forward, residual.
+    What differs between training, prefill and decode is how q attends,
+    and the caller passes that: `attend(q, k, v) -> (o [b, s, n_heads,
+    head_dim], kept)`, with q and k after rope. `kept` is whatever the
+    caller wants back (the K/V pool it wrote k and v into; None in
+    training). Returns (out, kept), and what the router did with this
+    layer's input as a third if `stats`. The _ckpt names are the save
+    frontier of remat_policy="hot"; outside jax.checkpoint they are the
+    identity."""
     b, s, d = x.shape
-    hd = cfg.head_dim
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
     h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
@@ -421,8 +433,8 @@ def _layer(x, layer_params, cfg: TransformerConfig, cos, sin, mesh: Optional[Mes
     q = _ckpt(apply_rope(q, cos, sin, cfg), "q_bf16")
     k = _ckpt(apply_rope(k, cos, sin, cfg), "k_bf16")
     v = _ckpt(v, "v_bf16")
-    o = _attention(q, k, v, cfg, mesh)
-    o = o.reshape(b, s, cfg.n_heads * hd)
+    o, kept = attend(q, k, v)
+    o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     attn_out = _ckpt(
         jnp.einsum(
             "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
@@ -441,8 +453,22 @@ def _layer(x, layer_params, cfg: TransformerConfig, cos, sin, mesh: Optional[Mes
     mlp_out = _ffn(mlp_in, mp, cfg)
     out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
     if stats:  # routing_stats: what the router did with this layer's input
-        return out, _route_stats(mlp_in.reshape(b * s, d), mp["router"], cfg)
-    return out
+        return out, kept, _route_stats(mlp_in.reshape(b * s, d), mp["router"], cfg)
+    return out, kept
+
+
+def _attend_whole(cfg: TransformerConfig, mesh: Optional[Mesh]):
+    """The attention strategy of a whole sequence that keeps nothing:
+    training, `forward`, `routing_stats`."""
+    return lambda q, k, v: (_attention(q, k, v, cfg, mesh), None)
+
+
+def _logits(params: PyTree, x):
+    """Final-norm hidden states [..., d] -> logits [..., vocab] float32."""
+    head = params.get("lm_head")
+    if head is None:
+        head = params["embed"]["embedding"].T
+    return jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
 
 
 def _route_stats(x, router, cfg: TransformerConfig):
@@ -466,7 +492,7 @@ def forward_hidden(
     cos, sin = rope_tables(cfg, s)
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
 
-    body = partial(_layer, cfg=cfg, cos=cos, sin=sin, mesh=mesh)
+    body = partial(_block, cfg=cfg, cos=cos, sin=sin, attend=_attend_whole(cfg, mesh))
     if cfg.remat:
         if cfg.remat_policy == "dots":
             policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
@@ -492,10 +518,7 @@ def forward_hidden(
             policy = None
         body = jax.checkpoint(body, policy=policy)
 
-    def scan_step(x, layer_params):
-        return body(x, layer_params), None
-
-    x, _ = lax.scan(scan_step, x, params["blocks"])
+    x, _ = lax.scan(body, x, params["blocks"])
     return _norm(x, params["final_norm"]["scale"], cfg)
 
 
@@ -506,11 +529,7 @@ def forward(
     mesh: Optional[Mesh] = None,
 ) -> jax.Array:
     """tokens [batch, seq] int32 -> logits [batch, seq, vocab] float32."""
-    x = forward_hidden(params, tokens, cfg, mesh)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"]["embedding"].T
-    return jnp.einsum("bsd,dv->bsv", x, head, preferred_element_type=jnp.float32)
+    return _logits(params, forward_hidden(params, tokens, cfg, mesh))
 
 
 def next_token_loss(
@@ -550,7 +569,8 @@ def routing_stats(params: PyTree, tokens: jax.Array, cfg: TransformerConfig) -> 
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
 
     def scan_step(x, layer_params):
-        return _layer(x, layer_params, cfg, cos, sin, None, stats=True)
+        out, _, route = _block(x, layer_params, cfg, cos, sin, _attend_whole(cfg, None), stats=True)
+        return out, route
 
     return lax.scan(scan_step, x, params["blocks"])[1]
 
@@ -566,8 +586,7 @@ def build_train_step(
     """The standard data-parallel train step (fwd+bwd+optimizer), with the
     optimizer update optionally ZeRO-sharded over `zero_axis`
     (train/zero.py: reduce_scatter grads -> shard-local update ->
-    all_gather params; per-chip optimizer state ~1/N — the headroom the
-    7B-on-v5e-64 envelope needs, AOT_7B_r05).
+    all_gather params; per-chip optimizer state ~1/N).
 
     Returns `(init_state, step)`:
       init_state(rng) -> (params, opt_state)  [opt_state sharded when zero]
@@ -650,50 +669,6 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     return 6.0 * n_params + attn
 
 
-def forward_pipelined(
-    params: PyTree,
-    tokens: jax.Array,
-    cfg: TransformerConfig,
-    mesh,
-    *,
-    num_microbatches: int = 4,
-    stage_axis: str = "stage",
-) -> jax.Array:
-    """Pipeline-parallel forward: the layer stack splits into S stages
-    over the mesh's `stage` axis, microbatches stream through a GPipe
-    schedule, and autodiff of THIS function is the backward pipeline
-    (parallel/pipeline.py; reference: the compiled-graph PP substrate,
-    dag/compiled_dag_node.py:664 — inverted into one SPMD program).
-    Embedding/head run replicated outside the pipeline (they are
-    batch-local); only the homogeneous block stack is staged."""
-    from ..parallel.pipeline import pipeline_apply, split_stacked_layers
-
-    S = dict(zip(mesh.axis_names, mesh.devices.shape))[stage_axis]
-    b, s = tokens.shape
-    if b % num_microbatches:
-        raise ValueError(f"batch {b} not divisible into {num_microbatches} microbatches")
-    cos, sin = rope_tables(cfg, s)
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
-
-    stage_params = split_stacked_layers(params["blocks"], S)
-    mb = x.reshape(num_microbatches, b // num_microbatches, s, cfg.d_model)
-
-    def stage_fn(local_blocks, xin):
-        def step(h, layer_params):
-            return _layer(h, layer_params, cfg, cos, sin, None), None
-
-        out, _ = lax.scan(step, xin, local_blocks)
-        return out
-
-    y = pipeline_apply(stage_fn, stage_params, mb, mesh, axis=stage_axis, remat=cfg.remat)
-    x = y.reshape(b, s, cfg.d_model)
-    x = _norm(x, params["final_norm"]["scale"], cfg)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"]["embedding"].T
-    return jnp.einsum("bsd,dv->bsv", x, head, preferred_element_type=jnp.float32)
-
-
 # ------------------------------------------------------------ paged decode
 #
 # Inference substrate for serve/llm: the KV cache is a pool of FIXED-SIZE
@@ -726,28 +701,6 @@ def init_kv_pages(
     tokens, and every step would pay a relayout of the pool)."""
     shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def _apply_rope_rows(x, cos, sin, cfg: TransformerConfig):
-    """Rope for one position per batch row: x [B, h, d], cos/sin [B, rd/2]."""
-    c = cos[:, None, :].astype(jnp.float32)
-    s = sin[:, None, :].astype(jnp.float32)
-    xf = x.astype(jnp.float32)
-
-    def rot(xr):
-        if cfg.rope_style == "interleaved":
-            x1, x2 = xr[..., ::2], xr[..., 1::2]
-            o1, o2 = x1 * c - x2 * s, x2 * c + x1 * s
-            return jnp.stack([o1, o2], axis=-1).reshape(xr.shape)
-        x1, x2 = jnp.split(xr, 2, axis=-1)
-        return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-
-    rd = cfg.rotary_dim
-    if rd is not None and rd < x.shape[-1]:
-        out = jnp.concatenate([rot(xf[..., :rd]), xf[..., rd:]], axis=-1)
-    else:
-        out = rot(xf)
-    return out.astype(x.dtype)
 
 
 def forward_prefill(
@@ -789,37 +742,22 @@ def forward_prefill(
 
     def scan_step(x, inputs):
         layer_params, kp, vp = inputs
-        ap = layer_params["attn"]
-        h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
-        q, k, v = _qkv(h, ap, cfg)
-        q = apply_rope(q, cos, sin, cfg)
-        k = apply_rope(k, cos, sin, cfg)
-        kp = kp.at[dest_page].set(k[0].reshape(S // T, T, -1))
-        vp = vp.at[dest_page].set(v[0].reshape(S // T, T, -1))
-        o = _attention(q, k, v, cfg, None)
-        o = o.reshape(1, S, cfg.n_heads * cfg.head_dim)
-        attn_out = jnp.einsum(
-            "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
-        ).astype(cfg.dtype)
-        if cfg.parallel_block:
-            mlp_in = h
-            x = x + attn_out + _ffn(mlp_in, layer_params["mlp"], cfg)
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
-            x = x + _ffn(mlp_in, layer_params["mlp"], cfg)
-        return x, (kp, vp)
+
+        def attend(q, k, v):
+            pool = (
+                kp.at[dest_page].set(k[0].reshape(S // T, T, -1)),
+                vp.at[dest_page].set(v[0].reshape(S // T, T, -1)),
+            )
+            return _attention(q, k, v, cfg, None), pool
+
+        return _block(x, layer_params, cfg, cos, sin, attend)
 
     x, (k_new, v_new) = lax.scan(
         scan_step, x, (params["blocks"], kv_pages["k"], kv_pages["v"])
     )
     x = _norm(x, params["final_norm"]["scale"], cfg)
     h_last = jnp.take(x[0], jnp.maximum(length - 1, 0), axis=0)[None, :]
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"]["embedding"].T
-    logits = jnp.einsum("bd,dv->bv", h_last, head, preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
+    return _logits(params, h_last), {"k": k_new, "v": v_new}
 
 
 def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int):
@@ -883,8 +821,8 @@ def forward_decode(
     pos = jnp.maximum(positions, 0)
 
     cos_t, sin_t = rope_tables(cfg, P * T)
-    cos = jnp.take(cos_t, pos, axis=0)  # [B, rd/2]
-    sin = jnp.take(sin_t, pos, axis=0)
+    cos = jnp.take(cos_t, pos, axis=0)[:, None, :]  # [B, 1, rd/2]: each row its own position
+    sin = jnp.take(sin_t, pos, axis=0)[:, None, :]
 
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0)[:, None, :]  # [B,1,d]
     rows = jnp.arange(B)
@@ -900,28 +838,18 @@ def forward_decode(
     def scan_step(carry, inputs):
         x, kp, vp = carry
         layer, layer_params = inputs
-        ap = layer_params["attn"]
-        h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
-        q, k, v = _qkv(h, ap, cfg)
-        q = _apply_rope_rows(q[:, 0], cos, sin, cfg)  # [B, nh, hd]
-        k = _apply_rope_rows(k[:, 0], cos, sin, cfg)  # [B, nkv, hd]
-        kp = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
-        vp = vp.at[layer, dest_page, dest_slot].set(v[:, 0].reshape(B, -1))
-        # Attend AFTER the append so the new position attends to itself.
-        if use_kernel:
-            o = paged_attention(q, kp, vp, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads)
-        else:
-            o = paged_attention_gather(q, kp[layer], vp[layer], block_tables, pos + 1, cfg.n_kv_heads)
-        o = o.reshape(B, 1, cfg.n_heads * cfg.head_dim).astype(cfg.dtype)
-        attn_out = jnp.einsum(
-            "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
-        ).astype(cfg.dtype)
-        if cfg.parallel_block:
-            x = x + attn_out + _ffn(h, layer_params["mlp"], cfg)
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
-            x = x + _ffn(mlp_in, layer_params["mlp"], cfg)
+
+        def attend(q, k, v):
+            kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
+            vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
+            # Attend AFTER the append so the new position attends to itself.
+            if use_kernel:
+                o = paged_attention(q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads)
+            else:
+                o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads)
+            return o.astype(cfg.dtype), (kp_, vp_)
+
+        x, (kp, vp) = _block(x, layer_params, cfg, cos, sin, attend)
         return (x, kp, vp), None
 
     (x, k_new, v_new), _ = lax.scan(
@@ -930,30 +858,4 @@ def forward_decode(
         (jnp.arange(cfg.n_layers), params["blocks"]),
     )
     x = _norm(x, params["final_norm"]["scale"], cfg)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"]["embedding"].T
-    logits = jnp.einsum(
-        "bd,dv->bv", x[:, 0], head, preferred_element_type=jnp.float32
-    )
-    return logits, {"k": k_new, "v": v_new}
-
-
-def next_token_loss_pipelined(
-    params: PyTree,
-    tokens: jax.Array,
-    cfg: TransformerConfig,
-    mesh,
-    *,
-    num_microbatches: int = 4,
-) -> jax.Array:
-    """Pipelined counterpart of next_token_loss (grad through it IS the
-    backward pipeline)."""
-    logits = forward_pipelined(
-        params, tokens, cfg, mesh, num_microbatches=num_microbatches
-    )
-    targets = jnp.roll(tokens, -1, axis=1)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    m = jnp.ones_like(nll).at[:, -1].set(0.0)
-    return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+    return _logits(params, x[:, 0]), {"k": k_new, "v": v_new}

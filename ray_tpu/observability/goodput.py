@@ -44,8 +44,7 @@ CATEGORIES = (SETUP, PRODUCTIVE, CHECKPOINT, DRAIN_WAIT, RESTART_REWORK, DEGRADE
 
 # Peak bf16 FLOP/s per chip by generation, keyed by a substring of
 # jax's device_kind (public spec sheets; v5e: Google Cloud "TPU v5e", 197
-# TFLOP/s). The one table: bench.py reads it too, and a device_kind it
-# does not list is an error there, not a default.
+# TFLOP/s).
 PEAK_FLOPS_PER_CHIP = {
     "v6e": 918e12,
     "v5p": 459e12,

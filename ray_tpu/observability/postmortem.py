@@ -11,7 +11,7 @@ before anyone asked. This module closes the loop:
    death/fencing, cgraph execute timeout / exec-loop crash, chaos
    injection, collective typed timeout, job failure — call
    `publish_trigger("<kind>", detail)`. Disarmed cost is one global
-   load + None check (bench_core pins it under 1% of task throughput);
+   load + None check;
    armed, the call forwards to the GCS `report_trigger` RPC (or the
    in-process GcsService), best-effort and per-kind debounced so a
    trigger storm costs one RPC per kind per window, not one per fault.
@@ -149,8 +149,8 @@ def armed() -> bool:
 def publish_trigger(
     kind: str, detail: Any = None, source: Optional[str] = None
 ) -> Any:
-    """One anomaly trigger. Disarmed: a global load + None check and out
-    (the bench_core guard pins this path). Armed: per-kind debounced —
+    """One anomaly trigger. Disarmed: a global load + None check and out.
+    Armed: per-kind debounced —
     the window is set BEFORE the forward, so a trigger raised while
     delivering a trigger (e.g. a chaos net fault on the publish RPC
     itself) short-circuits instead of recursing — then forwarded

@@ -12,7 +12,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import signal
 import sys
 import time
 
@@ -32,7 +31,7 @@ def _detach_cluster(cluster) -> None:
     point the latest-session file here."""
     import atexit
 
-    atexit.unregister(cluster._cleanup)
+    atexit.unregister(cluster.shutdown)
     pids = [p.pid for p in cluster._procs]
     with open(os.path.join(cluster.session_dir, "pids.json"), "w") as f:
         json.dump(pids, f)
@@ -118,22 +117,10 @@ def cmd_stop(args) -> None:
             pids = json.load(f)
     except OSError:
         pids = []
-    from .core.rpc import RpcClient
+    from .core import proctree
+    from .core.zygote import PidHandle
 
-    try:
-        info = json.load(open(os.path.join(session, "session.json")))
-        RpcClient(info["gcs_sock"], connect_timeout=2.0).call("stop", timeout=2.0)
-    except Exception:  # lint: swallow-ok(graceful stop is best-effort; SIGKILL sweep follows)
-        pass
-    time.sleep(0.2)
-    killed = 0
-    for pid in pids:
-        try:
-            os.kill(pid, signal.SIGTERM)
-            killed += 1
-        except OSError:
-            pass
-    time.sleep(0.3)
+    proctree.end_session(session, [PidHandle(pid) for pid in pids])
     # Reclaim tmpfs pools + session state: nothing else unlinks them once
     # the CLI detached the cluster from the atexit cleanup.
     import glob
@@ -149,7 +136,7 @@ def cmd_stop(args) -> None:
         os.unlink(_SESSION_POINTER)
     except OSError:
         pass
-    print(f"stopped {killed} cluster processes")
+    print(f"stopped {len(pids)} cluster processes")
 
 
 def _connect(args):

@@ -63,8 +63,7 @@ class Replica:
         self._m_qdepth = imet.SERVE_QUEUE_DEPTH.labels(deployment=app_name)
         # Engine-bearing callables (serve/llm deployment.py) get a
         # graceful teardown before kill; one cached attr check is the
-        # whole cost for everyone else (pinned <1% by bench_core's
-        # serve-engine overhead guard).
+        # whole cost for everyone else.
         self._llm_engine = bool(getattr(self._callable, "__llm_engine__", False))
         # Streaming responses: generator outputs run in a background thread
         # into a bounded queue, pulled chunk-wise by the caller (reference:

@@ -18,7 +18,7 @@ replica gang's long-lived program) and exposes three surfaces:
 The deployment callable carries `__llm_engine__` so replica plumbing
 can recognize engine-bearing deployments without importing this module;
 non-LLM deployments never construct any of this (their disarmed cost is
-pinned <1% by bench_core's serve-engine guard).
+one cached attribute check in the replica).
 """
 
 from __future__ import annotations

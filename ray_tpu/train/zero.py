@@ -2,8 +2,7 @@
 
 Plain data parallelism keeps a FULL copy of the optimizer state on every
 chip — for adamw that is 2x the params in fp32-equivalent bytes, the
-single biggest slab of HBM after the params themselves (AOT_7B_r05:
-13.99/16 GB per v5e chip; optimizer sharding is the headroom). The
+single biggest slab of HBM after the params themselves. The
 ZeRO-1 fix: shard the optimizer state over the data axis, so each chip
 updates only its 1/N slice of the flattened parameter vector:
 

@@ -25,8 +25,7 @@ Design constraints (hot-path safe):
   declared per metric and bound with `.labels(**tags)` — call sites on
   hot paths cache the bound handle.
 - **Kill switch.** `RAY_TPU_INTERNAL_METRICS=0` turns every instrument
-  into a no-op and never starts the flusher (the bench overhead guard in
-  bench_core.py measures this toggle).
+  into a no-op and never starts the flusher.
 
 The flusher starts lazily on first *use* (not import): the zygote
 pre-imports the worker stack and must stay strictly single-threaded

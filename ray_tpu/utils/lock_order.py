@@ -195,8 +195,7 @@ class TrackedLock:
     and timeout acquires, and threading.Condition's lock protocol.
 
     The acquire/release fast path (no other lock held) is hand-inlined:
-    tier-1 arms this wrapper on the control-plane daemons, so its cost is
-    bounded by bench_core's lock_order_overhead guard (<2% tasks/s)."""
+    tier-1 arms this wrapper on the control-plane daemons."""
 
     _reentrant = False
     __slots__ = ("name", "_id", "_inner", "_acq", "_rel")

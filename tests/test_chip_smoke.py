@@ -130,12 +130,6 @@ def test_last_line_is_the_contract_object_and_nothing_more():
     assert "\n" not in line
 
 
-def test_bench_refuses_to_run_without_a_tpu():
-    proc = _run(["bench.py"], env_add={"JAX_PLATFORMS": "cpu"}, timeout=120)
-    assert proc.returncode not in (0, None)
-    assert "platform 'cpu'" in proc.stderr and "value" not in proc.stdout
-
-
 def test_lost_kv_pool_stops_the_engine_with_a_typed_error():
     """A jitted step that raises after its pool was donated leaves a deleted
     buffer: PagedLM must say so with EngineFailedError and the engine must

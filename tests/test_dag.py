@@ -138,8 +138,8 @@ def test_channel_compiled_dag_pipeline(rt_cluster):
         assert counted["n"] == 0, f"expected zero submissions, saw {counted['n']}"
 
         # Throughput comparison is advisory here (the shared 1-core box
-        # makes hard wall-clock ratios flaky); bench_core.py records the
-        # real number. The zero-submission assert above IS the contract.
+        # makes hard wall-clock ratios flaky). The zero-submission assert
+        # above IS the contract.
         legacy = dag.compile()
         t0 = _time.monotonic()
         legacy_refs = [legacy.execute(i) for i in range(n)]

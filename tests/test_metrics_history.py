@@ -734,24 +734,6 @@ def test_sparkline():
     assert len(line) == 4 and line[-1] == "█"
 
 
-def test_actor_launch_breakdown_unit():
-    from bench_scale import actor_launch_breakdown
-
-    spans = [
-        {"name": "actor_launch", "start_us": 0, "end_us": 10_000},
-        {"name": "actor_launch.gcs_register", "start_us": 0, "end_us": 2_000},
-        {"name": "actor_launch.gcs_register", "start_us": 0, "end_us": 4_000},
-        {"name": "actor_launch.worker_spawn", "start_us": 0, "end_us": 6_000},
-        {"name": "actor_launch.init", "start_us": 0, "end_us": None},  # open
-        {"name": "unrelated", "start_us": 0, "end_us": 1},
-    ]
-    bd = actor_launch_breakdown(spans)
-    assert bd["total"]["count"] == 1 and bd["total"]["max_ms"] == 10.0
-    assert bd["gcs_register"]["count"] == 2
-    assert bd["gcs_register"]["mean_ms"] == pytest.approx(3.0)
-    assert "init" not in bd and "unrelated" not in bd
-
-
 def test_sampling_profiler_json_and_perfetto_merge(tmp_path, monkeypatch):
     """The profiler's structured dumps flow into the Perfetto merge
     (satellite: profiler output finally has a consumer)."""

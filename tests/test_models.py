@@ -174,3 +174,75 @@ def test_paged_decode_matches_full_forward():
     sp2 = alloc.allocate(p2)
     assert sp2.cached_tokens == T  # radix hit on the committed page
     assert paged(p2, 5, sp2, slot=1) == gold(p2, 5)
+
+
+ONE_BLOCK = {
+    "dense_gqa": dict(),
+    "parallel_block": dict(
+        n_kv_heads=4, mlp_act="gelu", parallel_block=True, rotary_dim=8, norm_type="layer",
+        rope_style="interleaved",
+    ),
+    "routed_qk_norm": dict(
+        n_kv_heads=4, d_ff=32, n_experts=8, n_experts_per_tok=2, qk_norm=True,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", list(ONE_BLOCK))
+def test_train_prefill_and_decode_run_the_one_block(name, monkeypatch):
+    """`forward`, `forward_prefill` and `forward_decode` each trace
+    `_block` and nothing beside it (so a change to the block is a change to
+    all three, and the training step cannot drift from what is served), and
+    the paged pair computes what `forward` computes."""
+    cfg = tfm.tiny(attn_impl="naive", dtype=jnp.float32, **ONE_BLOCK[name])
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    traced = []
+    block = tfm._block
+    monkeypatch.setattr(
+        tfm, "_block", lambda *a, **kw: traced.append(1) or block(*a, **kw)
+    )
+
+    T, S, n = 8, 16, 11  # page, prefill bucket, prompt length
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S), 0, cfg.vocab_size, jnp.int32)
+    full = tfm.forward(params, tokens, cfg)
+    assert len(traced) == 1  # the layer scan traces its body once
+
+    kv = tfm.init_kv_pages(cfg, 8, T)
+    table = jnp.asarray([1, 2], jnp.int32)
+    prompt = tokens.at[0, n:].set(0)  # what lies beyond the length is padding
+    logits, kv = tfm.forward_prefill(
+        params, prompt, cfg, kv, table, jnp.int32(n), jnp.int32(0)
+    )
+    assert len(traced) == 2
+    np.testing.assert_allclose(logits[0], full[0, n - 1], rtol=2e-4, atol=2e-4)
+
+    # slot 0 takes the prompt's next token at position n; slot 1 is inactive
+    logits, kv = tfm.forward_decode(
+        params, jnp.asarray([tokens[0, n], 0], jnp.int32), jnp.asarray([n, -1], jnp.int32),
+        cfg, kv, jnp.asarray([[1, 2], [0, 0]], jnp.int32),
+    )
+    assert len(traced) == 3
+    np.testing.assert_allclose(logits[0], full[0, n], rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize(
+    "style,rotary_dim", [("half", None), ("interleaved", None), ("half", 8), ("interleaved", 8)]
+)
+def test_rope_per_row_tables_match_per_sequence_tables(style, rotary_dim):
+    """One rope for both table shapes: [s, r] (every row at positions 0..s)
+    and [b, s, r] (each row its own positions, decode's one position a row
+    among them)."""
+    cfg = tfm.tiny(rope_style=style, rotary_dim=rotary_dim)
+    b, s = 3, 12
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, cfg.n_heads, cfg.head_dim), jnp.float32)
+    cos, sin = tfm.rope_tables(cfg, s)
+    want = tfm.apply_rope(x, cos, sin, cfg)
+    rows = lambda t: jnp.broadcast_to(t, (b, *t.shape))
+    np.testing.assert_array_equal(tfm.apply_rope(x, rows(cos), rows(sin), cfg), want)
+    pos = jnp.asarray([0, 5, 11])  # one position a row, as decode takes them
+    one = tfm.apply_rope(
+        x[jnp.arange(b), pos][:, None], cos[pos][:, None, :], sin[pos][:, None, :], cfg
+    )
+    np.testing.assert_array_equal(one[:, 0], want[jnp.arange(b), pos])
+    if rotary_dim is not None:  # what lies beyond rotary_dim passes through
+        np.testing.assert_array_equal(want[..., rotary_dim:], x[..., rotary_dim:])
