@@ -669,17 +669,20 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     return 6.0 * n_params + attn
 
 
-# ------------------------------------------------------------ paged decode
+# ------------------------------------------------- paged prefill and decode
 #
 # Inference substrate for serve/llm: the KV cache is a pool of FIXED-SIZE
 # pages shared by every sequence (vLLM's PagedAttention layout). Prefill
-# writes a sequence's k/v into the pages its block table names; decode
-# appends the new position and attends over the pages each slot holds
-# (ops/paged_attention.py; paged_attention_gather below for shapes that
-# kernel cannot tile) — all at static shapes ([B] slots, [B, P] block
-# tables, [N] pages), so ONE compiled decode step serves every batch
-# composition and the continuous-batching scheduler never triggers a
-# recompile.
+# walks a prompt in fixed chunks and computes only the chunks that hold a
+# position the cache lacks: each writes its k/v into the pages its block
+# table names and attends over those pages, its own and everything below
+# it, where they lie. Decode appends the new position and attends over the
+# pages each slot holds. Both read the pool through ops/paged_attention.py
+# (the *_gather expressions below for shapes those kernels cannot tile) —
+# all at static shapes ([B] slots, [B, P] block tables, [N] pages), so ONE
+# compiled decode step serves every batch composition, one prefill
+# executable a bucket serves every hit and miss, and the
+# continuous-batching scheduler never triggers a recompile.
 #
 # Page 0 is reserved as a trash page: masked writes (inactive slots,
 # positions beyond a sequence's length, shared prefix pages owned by the
@@ -688,6 +691,14 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 # read — the attention mask stops at each sequence's length.
 
 TRASH_PAGE = 0
+# Positions one pass of the layers computes in forward_prefill. The weights
+# are read once a chunk, so a chunk must be worth their pass: 256 rows are
+# the v5e ridge (240 FLOP/byte). What a prefix hit still computes is whole
+# chunks, so it must not be larger than that. Chosen on the chip (PERF.md,
+# PR 31): 256 and 512 serve a miss-and-three-hits document alike (a miss
+# costs 11 % more, a hit 23 % less), 256 halves a short suffix's wait;
+# 1 024 is 6 % behind.
+PREFILL_CHUNK_TOKENS = 256
 
 
 def init_kv_pages(
@@ -695,12 +706,31 @@ def init_kv_pages(
 ) -> Dict[str, jax.Array]:
     """Allocates the paged KV pool: k/v of shape
     [n_layers, num_pages, page_tokens, n_kv_heads * head_dim]. A page is
-    tokens x (head, dim): the tile the decode kernel copies and multiplies
+    tokens x (head, dim): the tile the paged kernels copy and multiply
     as it lies, so heads and dim are ONE axis of the stored array (split,
     the device's tiled layout would put heads where the kernel needs
     tokens, and every step would pay a relayout of the pool)."""
     shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def prefill_chunk_pages(bucket_pages: int, page_tokens: int) -> int:
+    """Pages of one chunk of forward_prefill for a bucket of that many
+    pages: PREFILL_CHUNK_TOKENS' worth, or the whole of a smaller bucket
+    (the largest divisor of the bucket not above it, so chunks tile it)."""
+    from ..ops.paged_attention import largest_divisor
+
+    return largest_divisor(bucket_pages, max(1, PREFILL_CHUNK_TOKENS // page_tokens))
+
+
+def prefill_chunk_span(length, write_from, chunk_tokens: int, minimum=min, maximum=max):
+    """(first, stop) of the chunks forward_prefill computes: those holding a
+    position in [write_from, length), and always the one with the last
+    position, whose logits are the result even when the cache holds the
+    whole prompt. Python ints (PagedLM counts computed tokens with it), or
+    traced scalars with jnp's minimum / maximum."""
+    last = maximum(length - 1, 0)
+    return minimum(write_from, last) // chunk_tokens, last // chunk_tokens + 1
 
 
 def forward_prefill(
@@ -712,52 +742,107 @@ def forward_prefill(
     length: jax.Array,
     write_from: jax.Array,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill ONE sequence and write its k/v into the paged pool.
+    """Prefill ONE sequence: computes what the cache does not hold and
+    writes its k/v into the paged pool.
 
     tokens [1, S] (padded to a bucket; pad is arbitrary token ids),
     block_table [P] page indices covering positions [0, P*page_tokens),
     length: scalar, true prompt length (<= S),
-    write_from: scalar, first position to WRITE (positions below it sit in
-      shared prefix pages owned by the radix cache — identical content was
-      already written by the original owner, so rewriting is skipped;
-      attention still covers them because the full prompt is recomputed).
-      The radix cache shares whole pages, so it is a multiple of the page.
+    write_from: scalar, first position to COMPUTE: the pages below it
+      already hold this prompt's k/v (shared prefix pages the radix cache
+      matched, written by their owner) and are read, never recomputed into
+      and never rewritten. The radix cache shares whole pages, so it is a
+      multiple of the page; 0 is a miss, which runs the same loop.
+
+    The prompt is walked in chunks of `prefill_chunk_pages` pages with a
+    dynamic trip count (`prefill_chunk_span`): chunks wholly below
+    write_from and the bucket's padding chunks past the length are never
+    entered, so the work follows the uncached suffix, not the bucket. A
+    chunk passes through all layers; in each it writes its pages (whole
+    pages, at or above write_from) and its rows attend causally over
+    positions [0, chunk end) of the block table's pages. Rows of a chunk
+    below write_from are recomputed but not written: they read the owner's
+    k/v like every other row.
 
     Returns (last-position logits [1, vocab] fp32, updated kv_pages).
     """
+    from ..ops.paged_attention import paged_prefill_attention
+
     _, S = tokens.shape
     T = kv_pages["k"].shape[2]
-    cos, sin = rope_tables(cfg, S)
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    pages = prefill_chunk_pages(S // T, T)
+    C = pages * T
+    cos_t, sin_t = rope_tables(cfg, S)
+    use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
 
-    # Whole pages are written (a page is one contiguous tile of the pool; a
-    # token row cuts through 32 of them): every page that holds a position
-    # in [write_from, length). The rest of the last page receives the
-    # padding's k/v, which nothing reads (attention stops at the length and
-    # decode overwrites position by position); a write_from inside a page
-    # rewrites that page's head with the identical content it recomputed.
-    first = jnp.arange(S // T) * T
-    writable = (first + T > write_from) & (first < length)
-    dest_page = jnp.where(writable, block_table, TRASH_PAGE)
+    def chunk_step(c, carry):
+        kp, vp, _ = carry
+        c0 = c * C
+        cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
+        sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
+        x = jnp.take(params["embed"]["embedding"], lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), axis=0)
+        # Whole pages are written (a page is one contiguous tile of the
+        # pool; a token row cuts through 32 of them): every page of the
+        # chunk that holds a position in [write_from, length). The rest of
+        # the prompt's last page receives the padding's k/v, which nothing
+        # reads (attention stops at the length and decode overwrites
+        # position by position).
+        first = c0 + jnp.arange(pages) * T
+        writable = (first + T > write_from) & (first < length)
+        dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(block_table, c * pages, pages), TRASH_PAGE)
 
-    def scan_step(x, inputs):
-        layer_params, kp, vp = inputs
+        # The pool rides both loops as a carry, written in place (as in
+        # forward_decode): no copy of it is made a layer or a chunk.
+        def scan_step(carry, inputs):
+            x, kp, vp = carry
+            layer, layer_params = inputs
 
-        def attend(q, k, v):
-            pool = (
-                kp.at[dest_page].set(k[0].reshape(S // T, T, -1)),
-                vp.at[dest_page].set(v[0].reshape(S // T, T, -1)),
-            )
-            return _attention(q, k, v, cfg, None), pool
+            def attend(q, k, v):
+                kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
+                vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
+                # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
+                if use_kernel:
+                    o = paged_prefill_attention(q[0], kp_, vp_, layer, block_table, c0, length, n_kv_heads=cfg.n_kv_heads)
+                else:
+                    o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads)
+                return o[None].astype(cfg.dtype), (kp_, vp_)
 
-        return _block(x, layer_params, cfg, cos, sin, attend)
+            x, (kp, vp) = _block(x, layer_params, cfg, cos, sin, attend)
+            return (x, kp, vp), None
 
-    x, (k_new, v_new) = lax.scan(
-        scan_step, x, (params["blocks"], kv_pages["k"], kv_pages["v"])
-    )
-    x = _norm(x, params["final_norm"]["scale"], cfg)
-    h_last = jnp.take(x[0], jnp.maximum(length - 1, 0), axis=0)[None, :]
+        (x, kp, vp), _ = lax.scan(scan_step, (x, kp, vp), (jnp.arange(cfg.n_layers), params["blocks"]))
+        # the last position's row, if this is its chunk (the final one is)
+        return kp, vp, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0)
+
+    first, stop = prefill_chunk_span(length, write_from, C, jnp.minimum, jnp.maximum)
+    h_last = jnp.zeros((cfg.d_model,), cfg.dtype)
+    k_new, v_new, h_last = lax.fori_loop(first, stop, chunk_step, (kv_pages["k"], kv_pages["v"], h_last))
+    h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
     return _logits(params, h_last), {"k": k_new, "v": v_new}
+
+
+def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int):
+    """The plain XLA expression of prefill's chunk attention: gathers the
+    WHOLE block table [P] out of one layer's pages kp / vp
+    [pages, page_tokens, n_kv_heads * head_dim], casts it to float32 and
+    softmaxes each row of q [C, n_heads, head_dim] (row i is position
+    start + i) over the `P * T`-wide row under the causal mask. The parity
+    reference of ops/paged_attention.py's paged_prefill_attention and the
+    path for shapes that kernel cannot tile (the tiny CPU widths)."""
+    C, H, hd = q.shape
+    P, T = block_table.shape[0], kp.shape[1]
+    kb = kp[block_table].reshape(P * T, n_kv_heads, hd)
+    vb = vp[block_table].reshape(P * T, n_kv_heads, hd)
+    if H != n_kv_heads:
+        kb = jnp.repeat(kb, H // n_kv_heads, axis=1)
+        vb = jnp.repeat(vb, H // n_kv_heads, axis=1)
+    scores = jnp.einsum(
+        "qhd,shd->hqs", q.astype(jnp.float32), kb.astype(jnp.float32)
+    ) / math.sqrt(hd)
+    seen = jnp.arange(P * T)[None, :] <= start + jnp.arange(C)[:, None]  # [C, P*T]
+    scores = jnp.where(seen[None], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("hqs,shd->qhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
 
 
 def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int):
@@ -784,10 +869,10 @@ def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int):
     return jnp.einsum("bhs,bshd->bhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
 
 
-def decode_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
-    """Which expression forward_decode attends with for this model and
-    page size: "paged_kernel" where ops/paged_attention.py can tile the
-    pool, else "xla_gather". Shapes decide, nothing else does."""
+def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    """Which expression forward_prefill and forward_decode attend with for
+    this model and page size: "paged_kernel" where ops/paged_attention.py
+    can tile the pool, else "xla_gather". Shapes decide, nothing else does."""
     from ..ops.paged_attention import can_tile
 
     return "paged_kernel" if can_tile(page_tokens, cfg.head_dim, cfg.dtype) else "xla_gather"
@@ -829,12 +914,12 @@ def forward_decode(
     dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
     dest_slot = pos % T
     lengths = jnp.where(active, pos + 1, 0)
-    use_kernel = decode_attention_path(cfg, T) == "paged_kernel"
+    use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
 
     # The pool rides the layer scan as a CARRY: each layer appends into its
     # own slice in place and the kernel reads the pool where it lies. As
-    # xs/ys (forward_prefill's way) every step copies the whole pool out of
-    # the stacked array and back in.
+    # xs/ys every step would copy the whole pool out of the stacked array
+    # and back in.
     def scan_step(carry, inputs):
         x, kp, vp = carry
         layer, layer_params = inputs

@@ -1,4 +1,5 @@
-"""Paged attention for decode as a pallas TPU kernel.
+"""Paged attention as pallas TPU kernels: one new token a slot (decode), and a
+chunk of a prompt's rows over the prompt's own pages (prefill).
 
 One decode step attends one new token per slot over that slot's K/V, which
 lies scattered over the page pool (models/transformer.py init_kv_pages). The
@@ -31,8 +32,19 @@ a `P * T`-wide row whatever the slots hold. This kernel moves what is live:
 - ONE executable serves every batch mix and length: lengths and tables are
   data, the page walk is a loop with a dynamic trip count.
 
+`paged_prefill_attention` is its many-row sibling for forward_prefill's chunk
+loop: the rows of one chunk of ONE prompt, at positions `start + i`, attend
+causally over the pages the prompt's block table names, which hold the
+chunk's own K/V (written just before) and everything below it, computed by an
+earlier chunk or by whoever owns a shared prefix. The same page walk, DMAs
+and mathematics; what differs is that many rows share every K/V block, so
+each head multiplies its own `[rows, head_dim] x [head_dim, block]` (the
+block-diagonal trick would cost n_kv_heads times the FLOPs once the rows,
+not the K/V tiles, bound the MXU), and the mask is causal. Its parity
+reference is transformer.paged_prefill_attention_gather.
+
 `interpret=True` (selected when this process's backend is not a TPU) runs the
-same kernel on the CPU for tests.
+same kernels on the CPU for tests.
 """
 
 from __future__ import annotations
@@ -54,6 +66,25 @@ KERNEL_NAME = "paged_attention_decode"
 # enough that the per-block softmax and accumulator update are small beside
 # the DMA, small enough for four of them in the default scoped VMEM.
 BLOCK_BYTES = 1 << 19
+PREFILL_KERNEL_NAME = "paged_attention_prefill"
+# Prefill: rows of q one grid step holds (every K/V block is read once per
+# q block), the tokens of one VMEM block of K, all KV heads wide (fewer
+# where that would pass PREFILL_BLOCK_BYTES: a float32 pool), and the heads
+# unrolled in one turn of the loop over heads. The per-head running max, sum
+# and accumulator are rescaled once a block, which costs as much as the
+# block's own products at 64 tokens; past 256 a head's [rows, tokens] scores
+# no longer fit the registers. Unrolled heads overlap (one's products under
+# another's softmax) but each costs ~0.1 s of tracing in every bucket of
+# every process that serves. On the chip, DeepSeek MHA (PERF.md, PR 31;
+# tools/paged_attention_bench.py --prefill): 512 rows over 4 096 tokens,
+# all heads unrolled, 64 / 128 / 256 / 512 tokens a block 1 483 / 784 / 451
+# / 542 us; 256 rows, 256 tokens a block, 1 / 2 / 4 / 8 / 32 heads unrolled
+# 634 / 572 / 445 / 329 / 242 us against 0.4 / - / 0.7 / 1.0 / 4.2 s for a
+# bucket's first call with a warm compile cache (the parent's: 0.4).
+PREFILL_BLOCK_Q = 256
+PREFILL_BLOCK_BYTES = 1 << 21
+PREFILL_BLOCK_TOKENS = 256
+PREFILL_HEADS_UNROLLED = 4
 
 
 def _auto_interpret() -> bool:
@@ -244,3 +275,219 @@ def paged_attention(
         block_tables.astype(jnp.int32).reshape(-1),
         q.astype(k_pages.dtype), k_pages, v_pages,
     )
+
+
+def largest_divisor(n: int, limit: int) -> int:
+    """The largest divisor of n that is at most limit (at least 1)."""
+    return max(d for d in range(1, max(1, min(n, limit)) + 1) if n % d == 0)
+
+
+def pick_prefill_blocks(chunk_tokens: int, page_tokens: int, row_width: int, max_pages: int, dtype):
+    """(rows of q a grid step, pages a VMEM block of K) for a chunk: whole
+    pages both, the first a divisor of the chunk."""
+    block_q = page_tokens * largest_divisor(chunk_tokens // page_tokens, max(1, PREFILL_BLOCK_Q // page_tokens))
+    tokens = min(PREFILL_BLOCK_TOKENS, PREFILL_BLOCK_BYTES // (row_width * jnp.dtype(dtype).itemsize))
+    return block_q, max(1, min(max_pages, tokens // page_tokens))
+
+
+def _prefill_kernel(
+    layer_ref, start_ref, length_ref, table_ref,  # scalar prefetch (SMEM)
+    q_ref, k_hbm, v_hbm,  # [bq, H * hd] VMEM; [L, N, T, F] HBM, twice
+    o_ref,  # [bq, H * hd]
+    k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
+    *, scale, n_heads, n_kv_heads, page_tokens, pages_per_block, max_pages, heads_unrolled,
+):
+    bq = q_ref.shape[0]
+    hd = q_ref.shape[1] // n_heads
+    rep = n_heads // n_kv_heads
+    T, ppb = page_tokens, pages_per_block
+    bk = ppb * T
+    layer = layer_ref[0]
+    q_first = start_ref[0] + pl.program_id(0) * bq
+    # Keys these rows may see: up to the last row's own position (causal),
+    # and no page past the prompt's last (what the table names there is the
+    # trash page or someone else's). Rows wholly past the prompt see nothing.
+    length = jnp.minimum(length_ref[0], max_pages * T)
+    kv_end = jnp.minimum(q_first + bq, (length + T - 1) // T * T)
+    kv_end = jnp.where(q_first < length, kv_end, 0)
+    n_pages = (kv_end + T - 1) // T
+    n_blocks = (n_pages + ppb - 1) // ppb
+    last_page = k_hbm.shape[1] - 1
+
+    def copies(blk, slot, act):
+        """Starts or awaits the DMAs of block `blk`'s live pages: a loop,
+        where the decode kernel unrolls its few pages a block (16 and more
+        `pl.when`s, three times over, were most of the seconds it took to
+        trace this kernel, once a bucket in every process that serves)."""
+        def page_copy(j, _):
+            pg = blk * ppb + j
+
+            @pl.when(pg < n_pages)
+            def _():
+                page = jnp.clip(table_ref[pg], 0, last_page)
+                rows = pl.ds(pl.multiple_of(j * T, T), T)
+                for pool, buf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    act(pltpu.make_async_copy(pool.at[layer, page], buf.at[slot, rows], sems.at[s, slot]))
+
+        lax.fori_loop(0, ppb, page_copy, None)
+
+    copies(0, 0, lambda c: c.start())
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    exact = lax.Precision.HIGHEST if q_ref.dtype == jnp.float32 else None
+
+    @jax.jit  # traced once, inlined for each unrolled head: tracing it per head is most of a bucket's first call
+    def softmax_step(q, k, v, seen, m, l, acc):
+        """One head's online-softmax update over one K/V block: q [bq, hd],
+        k / v [bk, hd], running max and sum [bq, 128] (every lane the same),
+        accumulator [bq, hd]."""
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=exact, preferred_element_type=jnp.float32
+        ) * scale  # [bq, bk]
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), precision=exact, preferred_element_type=jnp.float32
+        )  # [bq, hd]
+        return jnp.broadcast_to(m_new, m.shape), jnp.broadcast_to(l_new, l.shape), acc * alpha + pv
+
+    def body(blk, _):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            copies(blk + 1, 1 - slot, lambda c: c.start())
+
+        copies(blk, slot, lambda c: c.wait())
+        first = blk * bk
+
+        # Rows of the last block past kv_end are stale VMEM: p is 0 there,
+        # but 0 * NaN is NaN, so V is zeroed (as the decode kernel does).
+        @pl.when(first + bk > kv_end)
+        def _():
+            v = v_buf[slot]
+            live = first + lax.broadcasted_iota(jnp.int32, v.shape, 0) < kv_end
+            v_buf[slot] = jnp.where(live, v, jnp.zeros_like(v))
+
+        q_pos = q_first + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        k_pos = first + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        seen = (k_pos <= q_pos) & (k_pos < kv_end)
+        # Heads in a loop, heads_unrolled of them a turn: unrolled, the
+        # compiler overlaps one head's products with another's softmax; all
+        # of them unrolled are seconds of program load in every process
+        # that serves.
+        def head(h, _):
+            lanes = pl.ds(pl.multiple_of(h * hd, 128), hd)
+            kv_lanes = pl.ds(pl.multiple_of(h // rep * hd, 128), hd)
+            m_scr[h], l_scr[h], acc_scr[:, lanes] = softmax_step(
+                q_ref[:, lanes], k_buf[slot, :, kv_lanes], v_buf[slot, :, kv_lanes], seen,
+                m_scr[h], l_scr[h], acc_scr[:, lanes],
+            )
+
+        def heads(i, _):
+            for j in range(heads_unrolled):
+                head(i * heads_unrolled + j, None)
+
+        lax.fori_loop(0, n_heads // heads_unrolled, heads, None)
+
+    lax.fori_loop(0, n_blocks, body, None)
+
+    def finish(h, _):
+        lanes = pl.ds(pl.multiple_of(h * hd, 128), hd)
+        o_ref[:, lanes] = (acc_scr[:, lanes] / jnp.maximum(l_scr[h, :, :1], 1e-30)).astype(o_ref.dtype)
+
+    lax.fori_loop(0, n_heads, finish, None)
+
+
+def paged_prefill_attention(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    layer: jax.Array,
+    block_table: jax.Array,
+    start: jax.Array,
+    length: jax.Array,
+    *,
+    n_kv_heads: int,
+    block_q: Optional[int] = None,
+    pages_per_block: Optional[int] = None,
+    heads_unrolled: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal attention of one chunk of one prompt over the prompt's pages.
+
+    q [C, n_heads, head_dim]: row i is the prompt's position `start + i`
+    (int32 scalar); k_pages / v_pages the pool [layers, pages, page_tokens,
+    n_kv_heads * head_dim], read at `layer` and never copied; block_table
+    [P] int32, page j holds positions [j * page_tokens, (j + 1) *
+    page_tokens), the chunk's own included; `length` the prompt's length:
+    no page past its last is read, and blocks of rows wholly past it return
+    zeros. Row i attends over positions [0, start + i]. Returns [C, n_heads,
+    head_dim] in q's dtype.
+    """
+    C, H, hd = q.shape
+    _, _, T, F = k_pages.shape
+    P = block_table.shape[0]
+    if F != n_kv_heads * hd or H % n_kv_heads:
+        raise ValueError(f"pool width {F} is not n_kv_heads {n_kv_heads} x head_dim {hd} (n_heads {H})")
+    if not can_tile(T, hd, k_pages.dtype) or C % T:
+        raise ValueError(
+            f"paged prefill attention cannot tile head_dim {hd}, page_tokens {T}, chunk {C}, {k_pages.dtype}: "
+            "use transformer.paged_prefill_attention_gather"
+        )
+    bq, ppb = pick_prefill_blocks(C, T, F, P, k_pages.dtype)
+    block_q = block_q or bq
+    pages_per_block = pages_per_block or ppb
+    if C % block_q:
+        raise ValueError(f"block_q {block_q} does not divide the chunk's {C} rows")
+    if interpret is None:
+        interpret = _auto_interpret()
+    bk = pages_per_block * T
+    item = jnp.dtype(k_pages.dtype).itemsize
+    # q and o blocks double-buffered by the pipeline, K and V by hand, the
+    # running max / sum / accumulator, and the scores of one head in flight.
+    vmem = 4 * block_q * H * hd * item + 4 * bk * F * item + block_q * H * (2 * 128 + hd) * 4 + 4 * block_q * bk * 4
+    kern = functools.partial(
+        _prefill_kernel, scale=1.0 / math.sqrt(hd), n_heads=H, n_kv_heads=n_kv_heads, page_tokens=T,
+        pages_per_block=pages_per_block, max_pages=P,
+        heads_unrolled=largest_divisor(H, heads_unrolled or PREFILL_HEADS_UNROLLED),
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(C // block_q,),
+            in_specs=[
+                pl.BlockSpec((block_q, H * hd), lambda i, *_: (i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((block_q, H * hd), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, bk, F), k_pages.dtype),
+                pltpu.VMEM((2, bk, F), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((H, block_q, 128), jnp.float32),
+                pltpu.VMEM((H, block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, H * hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((C, H * hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=min(100 << 20, max(32 << 20, vmem * 3 // 2))
+        ),
+        interpret=interpret,
+        name=PREFILL_KERNEL_NAME,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.asarray(start, jnp.int32).reshape(1),
+        jnp.asarray(length, jnp.int32).reshape(1),
+        block_table.astype(jnp.int32),
+        q.astype(k_pages.dtype).reshape(C, H * hd), k_pages, v_pages,
+    )
+    return out.reshape(C, H, hd)

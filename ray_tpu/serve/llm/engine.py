@@ -149,7 +149,7 @@ class InferenceEngine:
         self._clk = {
             "queue_wait": {"n": 0, "s": 0.0},
             "first_token": {"n": 0, "s": 0.0},
-            "prefill": {"n": 0, "s": 0.0, "tokens": 0},
+            "prefill": {"n": 0, "s": 0.0, "tokens": 0, "computed_tokens": 0},
             "decode": {"n": 0, "s": 0.0},
             "decode.kv_pages": {"live": 0, "table": 0},
             "idle": {"s": 0.0},
@@ -283,7 +283,9 @@ class InferenceEngine:
     def _pick_admissions_locked(self) -> List[_Seq]:
         """Pops waiting sequences into free slots up to the prefill token
         budget. Slots are reserved here (under the lock); the prefill
-        compute itself runs outside it."""
+        compute itself runs outside it. A sequence is charged its uncached
+        tokens, which is what its prefill computes (to the chunk: PagedLM
+        computes the chunks holding them and reads the cached ones)."""
         budget = self.config.prefill_token_budget
         admitted: List[_Seq] = []
         now = 0.0
@@ -427,20 +429,24 @@ class InferenceEngine:
             clk["tokens"] += len(seq.prompt)
             t0 = time.monotonic()
             self._stage = ("prefill", t0)
+            attrs = {
+                "rid": seq.rid,
+                "prompt_tokens": len(seq.prompt),
+                "cached_tokens": seq.pages.cached_tokens,
+            }
             try:
-                with _tracing.span(
-                    "llm.prefill",
-                    {
-                        "rid": seq.rid,
-                        "prompt_tokens": len(seq.prompt),
-                        "cached_tokens": seq.pages.cached_tokens,
-                    },
-                    device=True,
-                    parent=seq.trace,
-                ):
+                with _tracing.span("llm.prefill", attrs, device=True, parent=seq.trace):
                     tok = self.model.prefill(
                         seq.prompt, seq.pages.pages, seq.pages.cached_tokens
                     )
+                    # What the model says it computed (PagedLM: whole
+                    # chunks, padding included); a model that does not say
+                    # computed the uncached tokens.
+                    attrs["computed_tokens"] = getattr(
+                        tok, "computed_tokens", len(seq.prompt) - seq.pages.cached_tokens
+                    )
+                    clk["computed_tokens"] += attrs["computed_tokens"]
+                    tok = int(tok)
             except EngineFailedError as e:
                 self._fail(e)
                 return False
@@ -561,7 +567,9 @@ class InferenceEngine:
         admitted request; first_token: submit -> first emit (the engine's
         own TTFT); prefill / decode: inside model.prefill / model.decode
         (prefill.tokens: prompt tokens of those calls, cached ones
-        included); decode.kv_pages: over the completed decode steps, the
+        included; prefill.computed_tokens: positions their executables
+        computed, as the model reports them: whole chunks with their
+        padding, cached chunks not); decode.kv_pages: over the completed decode steps, the
         pages their live lengths cover (what a step must read) against
         slots x pages a sequence (what a step that gathers the block
         tables reads); loop.s: wall time of the loop, loop.idle_s the part of

@@ -10,7 +10,11 @@ PagedLM is the real path: one jitted decode step at static shapes
 ([max_slots] tokens, [max_slots, max_pages_per_seq] block tables, the
 whole page pool) serves every batch composition; prefill compiles per
 power-of-two page bucket, so compile count is O(log max_seq), not
-O(distinct prompt lengths).
+O(distinct prompt lengths). A bucket's one executable serves hit and miss
+alike: it walks the prompt in chunks and computes only those that hold a
+token the cache lacks (transformer.forward_prefill), so `cached_tokens`
+saves the work and not only the pages; what it did compute rides back on
+the token it returns (`PrefillToken.computed_tokens`).
 """
 
 from __future__ import annotations
@@ -22,6 +26,20 @@ from typing import Any, Dict, List, Optional, Sequence
 from ... import tracing as _tracing
 from ...exceptions import EngineFailedError
 from .kv_cache import TRASH_PAGE
+
+
+class PrefillToken(int):
+    """What `PagedLM.prefill` returns: the first generated token, an int to
+    every caller, which also says how many positions the prefill
+    executable computed for it (whole chunks: the bucket's or the chunk's
+    padding included, cached chunks not). The adapter protocol has one
+    return value and wrappers hand it on unopened, so this is where the
+    engine's `clocks.prefill.computed_tokens` reads it."""
+
+    def __new__(cls, token: int, computed_tokens: int):
+        self = super().__new__(cls, token)
+        self.computed_tokens = computed_tokens
+        return self
 
 
 class StubModel:
@@ -109,9 +127,9 @@ class PagedLM:
 
     def describe(self) -> Dict[str, Any]:
         """Which process and devices serve this model, which expression the
-        decode executable attends with ("paged_kernel" or "xla_gather":
-        transformer.decode_attention_path), and what compiling cost so far
-        (LLMServer.engine_stats() carries it out)."""
+        decode and prefill executables attend with ("paged_kernel" or
+        "xla_gather": transformer.paged_attention_path), and what compiling
+        cost so far (LLMServer.engine_stats() carries it out)."""
         import os
 
         devs = self._jax.devices()
@@ -120,7 +138,7 @@ class PagedLM:
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
-            "decode_attention": self._tfm.decode_attention_path(self.cfg, self.page_tokens),
+            "decode_attention": self._tfm.paged_attention_path(self.cfg, self.page_tokens),
             "peak_bytes_in_use": [
                 (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs
             ],
@@ -203,14 +221,19 @@ class PagedLM:
             self.kv = new_kv
             return host
 
-    def prefill(self, prompt: Sequence[int], pages: Sequence[int], cached_tokens: int) -> int:
+    def prefill(self, prompt: Sequence[int], pages: Sequence[int], cached_tokens: int) -> PrefillToken:
+        """The prompt's first generated token. Positions below
+        `cached_tokens` are read from `pages` (the radix cache matched
+        them); the chunks above are computed and written."""
         import numpy as np
 
         T = self.page_tokens
         n_pages = max(1, -(-len(prompt) // T))
         bucket = self._bucket_pages(n_pages)
         S = bucket * T
-        attrs = {"bucket_tokens": S}
+        chunk = self._tfm.prefill_chunk_pages(bucket, T) * T
+        first, stop = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk)
+        attrs = {"bucket_tokens": S, "computed_tokens": (stop - first) * chunk}
         with _tracing.span("llm.prefill.prep", attrs, device=True):
             toks = np.zeros((1, S), dtype=np.int32)
             toks[0, : len(prompt)] = np.asarray(prompt, dtype=np.int32)
@@ -229,7 +252,7 @@ class PagedLM:
             "llm.prefill",
             attrs,
         )
-        return int(tok)
+        return PrefillToken(tok, attrs["computed_tokens"])
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:
         import numpy as np

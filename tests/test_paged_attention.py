@@ -158,7 +158,7 @@ def test_one_decode_executable_serves_every_batch_mix():
 )
 def test_path_follows_the_shape(head_dim, page_tokens, dtype, path):
     cfg = tfm.tiny(d_model=2 * head_dim, n_heads=2, n_kv_heads=2, dtype=dtype)
-    assert tfm.decode_attention_path(cfg, page_tokens) == path
+    assert tfm.paged_attention_path(cfg, page_tokens) == path
     assert pa.can_tile(page_tokens, head_dim, dtype) == (path == "paged_kernel")
 
 
@@ -180,3 +180,77 @@ def test_interpret_follows_the_backend_like_the_flash_kernels(monkeypatch):
     assert jax.default_backend() == "cpu" and pa._auto_interpret() is True
     monkeypatch.setattr(sys.modules["ray_tpu.ops.flash_attention"], "_auto_interpret", lambda: False)
     assert pa._auto_interpret() is False
+
+
+# ------------------------------------------------- the prefill kernel
+#
+# One chunk of one prompt over the prompt's pages: C rows at positions
+# start.., a table of P = 8 pages. name: (chunk rows, start, length).
+PREFILL_CHUNKS = {
+    "whole_prompt_in_one_chunk": (P * T, 0, P * T),
+    "first_chunk": (2 * T, 0, 5 * T + 3),
+    "a_hits_last_chunk_ending_inside_it": (2 * T, 4 * T, 5 * T + 3),
+    "last_chunk_of_a_full_table": (4 * T, 4 * T, P * T),
+    "one_page_one_token": (T, 0, 1),
+}
+
+
+def _prefill_inputs(dtype, heads, C, length, seed=0):
+    H, G = heads
+    rng = np.random.default_rng(seed)
+    kp = jnp.asarray(rng.standard_normal((2, N, T, G * HD)), dtype)
+    vp = jnp.asarray(rng.standard_normal((2, N, T, G * HD)), dtype)
+    q = jnp.asarray(rng.standard_normal((C, H, HD)), dtype)
+    bt = np.full((P,), TRASH_PAGE, np.int32)
+    n = -(-length // T)
+    bt[:n] = rng.permutation(np.arange(1, N))[:n]
+    return q, kp, vp, jnp.asarray(bt)
+
+
+def _prefill_both(q, kp, vp, bt, start, length, G, layer=1, **kw):
+    """Kernel and expression, the rows that are positions of the prompt."""
+    out = pa.paged_prefill_attention(q, kp, vp, layer, bt, start, length, n_kv_heads=G, **kw)
+    ref = tfm.paged_prefill_attention_gather(q, kp[layer], vp[layer], bt, start, G)
+    rows = max(0, min(q.shape[0], length - start))
+    return np.asarray(out, np.float32)[:rows], np.asarray(ref, np.float32)[:rows]
+
+
+@pytest.mark.parametrize("chunk", PREFILL_CHUNKS.values(), ids=PREFILL_CHUNKS.keys())
+@pytest.mark.parametrize("heads", HEADS.values(), ids=HEADS.keys())
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_prefill_kernel_matches_the_xla_expression(dtype, heads, chunk):
+    """As for decode: float32 to 1e-5; a bf16 pool differs by the
+    probabilities' rounding to V's dtype and the output's own."""
+    C, start, length = chunk
+    q, kp, vp, bt = _prefill_inputs(dtype, heads, C, length)
+    out, ref = _prefill_both(q, kp, vp, bt, start, length, heads[1], block_q=min(C, 2 * T), pages_per_block=3)
+    if dtype == jnp.float32:
+        tol = 1e-5
+    else:
+        tol = 2.0 ** -9 * float(jnp.max(jnp.abs(vp.astype(jnp.float32)))) + 2.0 ** -8 * np.abs(ref).max()
+    assert out.shape[0] == min(C, length - start) and np.abs(out - ref).max() < tol
+
+
+def test_prefill_kernel_reads_no_page_past_the_prompts_last():
+    """Pages the prompt does not hold (the trash page its table names past
+    its length, every other page of the pool) are NaN: the prompt's rows
+    are the same finite numbers, and a block of rows wholly past the
+    length returns zeros."""
+    C, start, length = 4 * T, 2 * T, 3 * T + 5
+    q, kp, vp, bt = _prefill_inputs(jnp.float32, (4, 4), C, length)
+    clean, _ = _prefill_both(q, kp, vp, bt, start, length, 4, block_q=T, pages_per_block=3)
+    held = np.zeros((N,), bool)
+    held[np.asarray(bt[: -(-length // T)])] = True
+    poison_k = jnp.where(jnp.asarray(held)[None, :, None, None], kp, jnp.nan)
+    poison_v = jnp.where(jnp.asarray(held)[None, :, None, None], vp, jnp.nan)
+    out = pa.paged_prefill_attention(q, poison_k, poison_v, 1, bt, start, length, n_kv_heads=4, block_q=T, pages_per_block=3)
+    out = np.asarray(out)
+    np.testing.assert_array_equal(out[: length - start], clean)
+    assert np.isfinite(out).all() and not out[2 * T :].any()
+
+
+@pytest.mark.parametrize("block_q,ppb", [(T, 1), (2 * T, 8), (4 * T, 5)])
+def test_prefill_kernel_tiling_does_not_change_the_result(block_q, ppb):
+    q, kp, vp, bt = _prefill_inputs(jnp.float32, (8, 2), 4 * T, P * T)
+    out, ref = _prefill_both(q, kp, vp, bt, 4 * T, P * T, 2, layer=0, block_q=block_q, pages_per_block=ppb)
+    assert np.abs(out - ref).max() < 1e-5

@@ -122,3 +122,84 @@ def test_prefill_then_decode_through_the_paged_cache_matches_the_reference_logit
     assert worst(paged_logits(cfg, params, tokens, 13), want) <= TOLERANCE
     cfg16, params16, _ = seeded(arch, config, seed, jnp.bfloat16)
     assert worst(paged_logits(cfg16, params16, tokens, 13), want) > 10 * TOLERANCE
+
+
+# ------------------------------------------------- prefill: chunks, hit and miss
+#
+# forward_prefill walks a prompt in chunks and computes those that hold a
+# position the cache lacks. At the tiny widths a chunk is set to 16 tokens
+# (two pages), so that a 37-token prompt in a 64-token bucket has three
+# chunks and a fourth that is never entered. name: (prompt tokens, of which
+# the cache holds).
+CHUNK = 16
+PREFILL_CASES = {
+    "miss": (37, 0),
+    "hit_ending_inside_a_chunk": (30, 24),
+    "hit_whose_suffix_straddles_a_chunk_boundary": (37, 24),
+    "hit_below_the_first_chunk": (37, 8),
+    "whole_pages_fully_cached": (32, 32),
+    "bucket_smaller_than_a_chunk": (5, 0),
+}
+OWNER_PAGES, FRESH_PAGES = 1, 9  # the owner's table starts at page 1, a hit's own pages at page 9
+
+
+def prefill_hit_or_miss(cfg, params, tokens, length, cached, monkeypatch):
+    """The prompt's owner prefills it whole (ONE chunk, as a bucket was
+    computed before there were chunks) into pages 1..; then the case:
+    the same prompt, its first `cached` tokens in the owner's pages and its
+    own pages from page 9 on, in chunks of CHUNK. Returns (the case's
+    logits, its pool, its table, the owner's pool)."""
+    bucket = 1 << max(0, (-(-length // T) - 1).bit_length())
+    padded = jnp.zeros((1, bucket * T), jnp.int32).at[0, :length].set(tokens[:length])
+    owner_table = jnp.arange(OWNER_PAGES, OWNER_PAGES + bucket, dtype=jnp.int32)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", bucket * T)
+    _, owner_pool = tfm.forward_prefill(
+        params, padded, cfg, tfm.init_kv_pages(cfg, 20, T), owner_table, jnp.int32(length), jnp.int32(0))
+    shared = cached // T
+    table = jnp.concatenate([owner_table[:shared], jnp.arange(FRESH_PAGES, FRESH_PAGES + bucket - shared, dtype=jnp.int32)])
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", CHUNK)
+    assert tfm.prefill_chunk_pages(bucket, T) * T == min(CHUNK, bucket * T)
+    logits, pool = tfm.forward_prefill(params, padded, cfg, owner_pool, table, jnp.int32(length), jnp.int32(cached))
+    return logits[0], pool, table, owner_pool
+
+
+def long_tokens(cfg, seed):
+    return jax.random.randint(jax.random.fold_in(jax.random.PRNGKey(seed), 3), (40,), 0, cfg.vocab_size, jnp.int32)
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("case", PREFILL_CASES.values(), ids=PREFILL_CASES.keys())
+def test_chunked_prefill_matches_the_reference_logits_and_the_whole_prompts_pages(name, case, monkeypatch):
+    """Hit or miss, the last position's logits are the reference's, and
+    every page the case wrote holds what the owner's whole-prompt prefill
+    wrote for those positions (the positions below the length: the rest of
+    the last page is padding's)."""
+    length, cached = case
+    config, arch = tiny(name, torch_dtype="float32")
+    cfg, params, _ = seeded(arch, config, 0, jnp.float32)
+    tokens = long_tokens(cfg, 0)
+    assert_same_experts(arch, config, cfg, params, tokens[:length])
+    want = arch.logits_at(params, tokens[:length], jnp.asarray([length - 1]), config)[0]
+    logits, pool, table, owner_pool = prefill_hit_or_miss(cfg, params, tokens, length, cached, monkeypatch)
+    assert worst(logits, want) <= TOLERANCE
+    for j in range(cached // T, -(-length // T)):
+        live = min(T, length - j * T)
+        for kv in ("k", "v"):
+            assert worst(pool[kv][:, table[j], :live], owner_pool[kv][:, OWNER_PAGES + j, :live]) <= TOLERANCE, (kv, j)
+    # the control: the nearest precision below must fail
+    cfg16, params16, _ = seeded(arch, config, 0, jnp.bfloat16)
+    assert worst(prefill_hit_or_miss(cfg16, params16, tokens, length, cached, monkeypatch)[0], want) > 10 * TOLERANCE
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_a_hits_prefill_leaves_the_shared_pages_bytes_as_they_were(name, monkeypatch):
+    """A hit recomputes rows below `write_from` when its chunk starts below
+    it (here positions 16..23 of page 2), and chunked k/v are not bit-equal
+    to the owner's: no page of the owner's, shared or not, may be rewritten."""
+    config, arch = tiny(name, torch_dtype="float32")
+    cfg, params, _ = seeded(arch, config, 1, jnp.bfloat16)
+    _, pool, table, owner_pool = prefill_hit_or_miss(cfg, params, long_tokens(cfg, 1), 37, 24, monkeypatch)
+    owner = slice(OWNER_PAGES, FRESH_PAGES)
+    for kv in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(pool[kv][:, owner], np.float32), np.asarray(owner_pool[kv][:, owner], np.float32))
+        assert float(jnp.abs(pool[kv][:, table[3]].astype(jnp.float32)).max()) > 0, "the hit wrote its own pages"

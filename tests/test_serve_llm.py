@@ -568,12 +568,68 @@ def test_engine_stage_clocks(n_requests, step_delay_s):
         assert all(fb[k] >= fa[k] for k in fa), (fa, fb)
     assert after["queue_wait"]["n"] == after["first_token"]["n"] == after["prefill"]["n"] == n_requests
     assert after["prefill"]["tokens"] == 2 * n_requests
+    # a model that does not say what it computed computed the uncached tokens
+    assert after["prefill"]["computed_tokens"] == 2 * n_requests
     assert after["decode"]["n"] == eng.decode_steps >= 3
     assert after["first_token"]["s"] >= after["queue_wait"]["s"] >= 0.0
     if step_delay_s:
         assert after["decode"]["s"] >= after["decode"]["n"] * step_delay_s
     final = eng.stats()["clocks"]  # the loop has ended: its wall time stands still
     assert final["loop"]["s"] == eng.stats()["clocks"]["loop"]["s"]
+
+
+@pytest.mark.parametrize("suffix", [3, 9, 22], ids=["inside_a_chunk", "into_the_next_chunk", "two_whole_chunks"])
+def test_a_prefix_hit_through_the_engine_computes_less_and_serves_the_same_tokens(suffix, monkeypatch):
+    """Two requests that share a 24-token prefix, through InferenceEngine +
+    PagedLM with 16-token chunks: the second's prefill computes only the
+    chunks that hold its own tokens (`clocks.prefill.computed_tokens`, and
+    `computed_tokens` on its `llm.prefill` span), and both stream the tokens
+    the same requests get from an engine that has seen neither (cold)."""
+    import jax.numpy as jnp
+
+    from ray_tpu import tracing
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.serve.llm.model import PagedLM
+
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", 16)
+    T = 8
+    cfg = tfm.tiny(attn_impl="naive", dtype=jnp.float32, remat=False)
+    first = [(7 * i + 3) % cfg.vocab_size for i in range(29)]
+    second = first[:24] + [(5 * i + 1) % cfg.vocab_size for i in range(suffix)]
+
+    def engine(name):
+        lm = PagedLM(cfg, seed=0, num_pages=48, page_tokens=T, max_slots=2, max_pages_per_seq=8)
+        return InferenceEngine(lm, EngineConfig(page_tokens=T, pool_pages=48), name=name)
+
+    cold = []
+    for i, prompt in enumerate((first, second)):
+        eng = engine(f"t-cold-{suffix}-{i}")
+        try:
+            cold.append(_collect(eng, prompt, 5))
+        finally:
+            eng.close()
+
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    eng = engine(f"t-hit-{suffix}")
+    try:
+        assert _collect(eng, first, 5) == cold[0]
+        one = eng.stats()["clocks"]["prefill"]
+        assert _collect(eng, second, 5) == cold[1]
+        two = eng.stats()["clocks"]["prefill"]
+        kv = eng.stats()["kv"]
+    finally:
+        eng.close()
+        tracing.disable()
+    # the first is a miss: its bucket (32 tokens) in two chunks
+    assert one == dict(one, n=1, tokens=29, computed_tokens=32)
+    assert kv["prefix_hits"] == 3  # three whole pages of the second were the first's
+    computed = two["computed_tokens"] - one["computed_tokens"]
+    first_chunk, stop = 24 // 16, (len(second) - 1) // 16 + 1
+    assert computed == (stop - first_chunk) * 16 < len(second) == two["tokens"] - one["tokens"]
+    spans = [s["attrs"] for s in exp.spans if s["name"] == "llm.prefill"]
+    assert [(a["prompt_tokens"], a["cached_tokens"], a["computed_tokens"]) for a in spans] == [
+        (29, 0, 32), (len(second), 24, computed)]
 
 
 @pytest.mark.parametrize("n_requests,max_new", [(1, 3), (2, 9), (5, 6)])

@@ -66,6 +66,58 @@ def test_paged_attention_kernel_compiles_at_the_cells_widths(one_chip, heads, kv
     assert "tpu_custom_call" in text and pa.KERNEL_NAME in text
 
 
+@pytest.mark.parametrize(
+    "heads,kv_heads", [(32, 32), (32, 8), (16, 16)], ids=["deepseek_mha", "mistral_gqa", "olmoe"]
+)
+def test_paged_prefill_kernel_compiles_at_the_cells_widths(one_chip, heads, kv_heads):
+    """One chunk of forward_prefill (PREFILL_CHUNK_TOKENS rows) over a table
+    of 256 pages of 16 tokens, head_dim 128, with the blocks the program
+    picks: a Mosaic kernel with its name, inside the VMEM it asks for."""
+    sds = _sds(one_chip)
+    C, P, T, hd, N, L = tfm.PREFILL_CHUNK_TOKENS, 256, 16, 128, 1024, 8
+    pool = sds((L, N, T, kv_heads * hd), jnp.bfloat16)
+
+    def f(q, kp, vp, layer, bt, start, length):
+        return pa.paged_prefill_attention(q, kp, vp, layer, bt, start, length, n_kv_heads=kv_heads, interpret=False)
+
+    scalar = sds((), jnp.int32)
+    text = jax.jit(f).lower(
+        sds((C, heads, hd), jnp.bfloat16), pool, pool, scalar, sds((P,), jnp.int32), scalar, scalar
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and pa.PREFILL_KERNEL_NAME in text
+
+
+def test_prefill_executable_updates_the_pool_in_place(one_chip, mosaic):
+    """The prefill executable of the largest bucket at the serving widths
+    (2 layers, small vocab): the donated pool is aliased to the output and
+    no second pool is among the temporaries (before PR 31 the pool rode the
+    layer scan as xs / ys: 2.3-2.7 GiB of them at 8 layers)."""
+    sds = _sds(one_chip)
+    cfg = tfm.TransformerConfig(
+        vocab_size=1024, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=32, d_ff=1024, attn_impl="full"
+    )
+    P, T, N = 256, 16, 1024
+
+    def shapes(make):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))
+
+    params = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    kv = shapes(lambda: tfm.init_kv_pages(cfg, N, T))
+
+    def step(params, tokens, kv, table, length, write_from):
+        return tfm.forward_prefill(params, tokens, cfg, kv, table, length, write_from)
+
+    scalar = sds((), jnp.int32)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        params, sds((1, P * T), jnp.int32), kv, sds((P,), jnp.int32), scalar, scalar
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * cfg.n_layers * N * T * cfg.n_kv_heads * cfg.head_dim * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 2
+    assert pa.PREFILL_KERNEL_NAME in compiled.as_text()
+
+
 def test_decode_step_updates_the_pool_in_place(one_chip, mosaic):
     """The decode executable at the serving widths (2 layers, small vocab):
     the donated pool is aliased to the output and the step's temporaries are
@@ -75,7 +127,7 @@ def test_decode_step_updates_the_pool_in_place(one_chip, mosaic):
         vocab_size=1024, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=32, d_ff=1024, attn_impl="full"
     )
     B, P, T, N = 16, 256, 16, 1024
-    assert tfm.decode_attention_path(cfg, T) == "paged_kernel"
+    assert tfm.paged_attention_path(cfg, T) == "paged_kernel"
 
     def shapes(make):
         return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))

@@ -1,6 +1,8 @@
-"""The paged-attention decode kernel alone on the chip, against its own bytes.
+"""The paged-attention kernels alone on the chip: decode against its own
+bytes, prefill (`--prefill`) against its own operations.
 
     python3 tools/paged_attention_bench.py [--heads 32 --kv-heads 32] [--pages-per-block 4,8,16]
+    python3 tools/paged_attention_bench.py --prefill [--layouts 32:32,32:8,16:16] [--chunks 256,512,1024] [--block-q 256,512]
 
 For batch 4 / 16 and live lengths 256 / 1 024 / 4 096 (every slot at that
 length, pages scattered over the pool), times `ops/paged_attention.py`
@@ -8,9 +10,15 @@ length, pages scattered over the pool), times `ops/paged_attention.py`
 timed) and prints microseconds a call, the K/V bytes a call must read and
 the share of the chip's HBM peak that is (benchmarks/lib/peaks.json, keyed by
 device kind; an unknown kind is an error). Also the largest difference from
-transformer.paged_attention_gather on the same inputs. Refuses to run off a
-TPU: a CPU time is not a device number. A builder's tool; no test and no
-metric reads it.
+transformer.paged_attention_gather on the same inputs. `--prefill` times
+`paged_prefill_attention` for one chunk of a prompt (its rows the LAST of
+the live length, as a prefix hit's are) over live lengths 512 / 1 024 /
+2 560 / 4 096, at each head layout (DeepSeek MHA, Mistral GQA, OLMoE), and
+prints microseconds a call, the causal FLOPs, their share of the chip's
+bf16 peak, and the largest difference from
+transformer.paged_prefill_attention_gather. Refuses to run off a TPU: a
+CPU time is not a device number. A builder's tool; no test and no metric
+reads it.
 """
 
 from __future__ import annotations
@@ -25,6 +33,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def err_and_us(run, args, ref, reps, calls_a_jit):
+    """The largest difference of run(*args) from ref, and microseconds a
+    kernel call over `reps` runs of a jit that makes `calls_a_jit` calls."""
+    import jax
+    import jax.numpy as jnp
+
+    out = run(*args)
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = run(*args)
+    jax.block_until_ready(out)
+    return err, (time.perf_counter() - t0) / (reps * calls_a_jit) * 1e6
+
+
 def main(argv) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--heads", type=int, default=32)
@@ -36,6 +60,11 @@ def main(argv) -> int:
     ap.add_argument("--layers", type=int, default=8)
     ap.add_argument("--pages-per-block", default="")
     ap.add_argument("--reps", type=int, default=20)
+    ap.add_argument("--prefill", action="store_true")
+    ap.add_argument("--layouts", default="32:32,32:8,16:16")
+    ap.add_argument("--chunks", default="256,512,1024")
+    ap.add_argument("--block-q", default="")
+    ap.add_argument("--heads-unrolled", default="")
     a = ap.parse_args(argv)
 
     import jax
@@ -50,7 +79,10 @@ def main(argv) -> int:
         print(f"paged_attention_bench: needs a TPU, found {dev.platform}", file=sys.stderr)
         return 2
     with open(os.path.join(ROOT, "benchmarks", "lib", "peaks.json")) as f:
-        bw = json.load(f)["peaks"][dev.device_kind]["hbm_bytes_per_s"]
+        peaks = json.load(f)["peaks"][dev.device_kind]
+    if a.prefill:
+        return prefill(a, dev, peaks["bf16_flops_per_s"])
+    bw = peaks["hbm_bytes_per_s"]
     H, G, hd, T, P, N, L = a.heads, a.kv_heads, a.head_dim, a.page_tokens, a.max_pages, a.pool_pages, a.layers
     F = G * hd
     dtype = jnp.bfloat16
@@ -82,20 +114,70 @@ def main(argv) -> int:
                     _, os_ = jax.lax.scan(step, q, None, length=L)
                     return os_[0]
 
-                out = run(q, kp, vp, bt, lens)
-                err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
-                jax.block_until_ready(out)
-                t0 = time.perf_counter()
-                for _ in range(a.reps):
-                    out = run(q, kp, vp, bt, lens)
-                jax.block_until_ready(out)
-                us = (time.perf_counter() - t0) / (a.reps * L) * 1e6
+                err, us = err_and_us(run, (q, kp, vp, bt, lens), ref, a.reps, L)
                 nbytes = 2 * B * length * F * jnp.dtype(dtype).itemsize
                 print(json.dumps({
                     "batch": B, "live_length": length, "pages_per_block": ppb or pa.pick_pages_per_block(T, F, P, dtype),
                     "us_per_call": round(us, 1), "kv_bytes": nbytes, "hbm_peak_share_pct": round(100 * nbytes / bw / (us * 1e-6), 1),
                     "max_abs_diff_vs_gather": round(err, 5),
                 }), flush=True)
+    return 0
+
+
+def prefill(a, dev, peak_flops) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.ops import paged_attention as pa
+
+    hd, T, P, N, L = a.head_dim, a.page_tokens, a.max_pages, a.pool_pages, a.layers
+    dtype = jnp.bfloat16
+    key = jax.random.PRNGKey(0)
+    rng = np.random.default_rng(0)
+    bqs = [int(x) for x in a.block_q.split(",") if x] or [None]
+    ppbs = [int(x) for x in a.pages_per_block.split(",") if x] or [None]
+    unrolls = [int(x) for x in a.heads_unrolled.split(",") if x] or [None]
+    for layout in a.layouts.split(","):
+        H, G = (int(x) for x in layout.split(":"))
+        F = G * hd
+        kp = jax.random.normal(key, (1, N, T, F), dtype)
+        vp = jax.random.normal(jax.random.fold_in(key, 1), (1, N, T, F), dtype)
+        print(f"device {dev.device_kind}, peak {peak_flops / 1e12:.0f} TFLOP/s; heads {H}:{G} x {hd}, pages of {T}, pool {N} pages, {L} calls a jit")
+        for C in (int(x) for x in a.chunks.split(",")):
+            for length in (512, 1024, 2560, 4096):
+                if length < C:
+                    continue
+                start = length - C
+                bt = np.zeros((P,), np.int32)
+                bt[: length // T] = rng.permutation(np.arange(1, N))[: length // T]
+                bt = jnp.asarray(bt)
+                q = jax.random.normal(jax.random.fold_in(key, C * length), (C, H, hd), dtype)
+                ref = tfm.paged_prefill_attention_gather(q, kp[0], vp[0], bt, start, G).astype(jnp.float32)
+                for bq in bqs:
+                    if bq and C % bq:
+                        continue
+                    for ppb, unroll in ((p, u) for p in ppbs for u in unrolls):
+                        @jax.jit
+                        def run(q, kp, vp, bt):
+                            def step(q, _):
+                                o = pa.paged_prefill_attention(
+                                    q, kp, vp, 0, bt, start, length, n_kv_heads=G, block_q=bq, pages_per_block=ppb,
+                                    heads_unrolled=unroll)
+                                return q + (o * 1e-3).astype(q.dtype), o
+                            _, os_ = jax.lax.scan(step, q, None, length=L)
+                            return os_[0]
+
+                        err, us = err_and_us(run, (q, kp, vp, bt), ref, a.reps, L)
+                        flops = 4 * hd * H * sum(range(start + 1, length + 1))  # q.K and P.V, row i over i + 1 keys
+                        picked = pa.pick_prefill_blocks(C, T, F, P, dtype)
+                        print(json.dumps({
+                            "heads": layout, "chunk": C, "live_length": length, "block_q": bq or picked[0],
+                            "pages_per_block": ppb or picked[1], "heads_unrolled": unroll or pa.PREFILL_HEADS_UNROLLED, "us_per_call": round(us, 1), "causal_gflop": round(flops / 1e9, 2),
+                            "mxu_peak_share_pct": round(100 * flops / peak_flops / (us * 1e-6), 1),
+                            "max_abs_diff_vs_gather": round(err, 5),
+                        }), flush=True)
     return 0
 
 
