@@ -205,9 +205,9 @@ def _ckpt(val, name: str):
 # only the norms, rope on nothing (q/k/v are saved post-rope), and the
 # gate/up MLP dots (~10% extra layer FLOPs) instead of the whole layer.
 # The routed FFN saves what the router decided (moe_route: probabilities,
-# chosen experts, the sort and its inverse, group sizes; a few MB) and the
-# rows sorted by expert (moe_xs_bf16): its backward recomputes the gate/up
-# grouped matmuls like the dense path, and never the top-k or the sorts.
+# chosen experts, the sort and its inverse, group sizes; a few MB) and the rows
+# sorted by expert (moe_xs_bf16): its backward recomputes the grouped matmuls,
+# never the top-k or the sorts; rows move back by gathers through that sort.
 HOT_SAVE_NAMES = (
     "flash_o",
     "flash_lse",
@@ -382,8 +382,10 @@ def _routed_ffn(h, mp, cfg: TransformerConfig):
     """Dropless top-k mixture of SwiGLU experts. Every (token, chosen expert)
     pair is one row: rows are sorted by expert, each expert multiplies its
     own contiguous group (lax.ragged_dot: no capacity, no padding, n * k
-    rows whatever the imbalance), and the rows go back to token order,
-    weighted by the router's probabilities."""
+    rows whatever the imbalance), and the rows go back to token order under
+    the router's weights. Both row movements' backward gathers (ops/moe_rows)."""
+    from ..ops.moe_rows import combine_rows, dispatch_rows
+
     b, s, d = h.shape
     n, k, E = b * s, cfg.n_experts_per_tok, cfg.n_experts
     x = h.reshape(n, d)
@@ -398,7 +400,7 @@ def _routed_ffn(h, mp, cfg: TransformerConfig):
         order = _ckpt(jnp.argsort(flat_e), "moe_route")  # row r of the sorted is pair order[r]
         inverse = _ckpt(jnp.argsort(order), "moe_route")  # pair p sits in sorted row inverse[p]
         group_sizes = _ckpt(_tokens_per_expert(flat_e, E), "moe_route")
-        xs = _ckpt(jnp.take(x, order // k, axis=0), "moe_xs_bf16")
+        xs = _ckpt(dispatch_rows(x, order, inverse, k), "moe_xs_bf16")
     with jax.named_scope("moe.experts"):
         # Results in the parameters' type (the kernel accumulates in float32):
         # nothing fuses a convert into a grouped matmul, so float32 results
@@ -409,9 +411,7 @@ def _routed_ffn(h, mp, cfg: TransformerConfig):
         act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
         ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
     with jax.named_scope("moe.combine"):
-        ys = jnp.take(ys, inverse, axis=0).reshape(n, k, d)
-        out = jnp.sum(ys.astype(jnp.float32) * top_p[..., None], axis=1)
-    return out.astype(cfg.dtype).reshape(b, s, d)
+        return combine_rows(ys, top_p, order, inverse).reshape(b, s, d)
 
 
 def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: bool = False):

@@ -14,6 +14,7 @@ import pytest
 from benchmarks.archs import dense_decoder, olmoe
 from benchmarks.lib import spec
 from ray_tpu.models import transformer as tfm
+from ray_tpu.ops import moe_rows
 from ray_tpu.parallel import MeshSpec, build_mesh
 from ray_tpu.parallel.sharding import TRANSFORMER_RULES, spec_for_path
 
@@ -302,3 +303,113 @@ def test_zero_sharded_step_equals_the_plain_step():
     assert abs(out[0][0] - out[1][0]) <= 1e-5
     np.testing.assert_allclose(out[0][1], out[1][1], atol=1e-5)
     np.testing.assert_allclose(out[0][2], out[1][2], atol=1e-5)
+
+
+# ------------------------------------------------ the row movements' hand-written backward
+
+
+def plain_routed_ffn(h, mp, cfg):
+    """`tfm._routed_ffn` with plain `jnp.take` for both row movements: what
+    autodiff's backward (two scatter-adds) is the reference for."""
+    b, s, d = h.shape
+    n, k = b * s, cfg.n_experts_per_tok
+    x = h.reshape(n, d)
+    probs = tfm._router_probs(x, mp["router"])
+    top_e = jax.lax.top_k(probs, k)[1]
+    top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    flat_e = top_e.reshape(n * k)
+    order = jnp.argsort(flat_e)
+    inverse = jnp.argsort(order)
+    group_sizes = tfm._tokens_per_expert(flat_e, cfg.n_experts)
+    xs = jnp.take(x, order // k, axis=0)
+    gate = jax.lax.ragged_dot(xs, mp["w_gate"], group_sizes, preferred_element_type=cfg.dtype)
+    up = jax.lax.ragged_dot(xs, mp["w_up"], group_sizes, preferred_element_type=cfg.dtype)
+    act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
+    ys = jax.lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
+    ys = jnp.take(ys, inverse, axis=0).reshape(n, k, d)
+    out = jnp.sum(ys.astype(jnp.float32) * top_p[..., None], axis=1)
+    return out.astype(cfg.dtype).reshape(b, s, d)
+
+
+def _scalar(fn, cfg):
+    return lambda h, mp: jnp.sum(jnp.sin(3.0 * fn(h, mp, cfg).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("routing", ["random", "one-group"])
+@pytest.mark.parametrize("renorm", [False, True])
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_every_gradient_equals_autodiff_through_plain_takes(k, renorm, routing):
+    cfg = moe_cfg(norm_topk_prob=renorm).replace(n_experts=16, n_experts_per_tok=k)
+    h, mp = one_layer_ffn(20 + k, cfg)
+    if routing == "one-group":  # all-equal logits: every token takes experts 0..k-1, the worst imbalance
+        mp = dict(mp, router=jnp.zeros_like(mp["router"]))
+    np.testing.assert_array_equal(tfm._routed_ffn(h, mp, cfg), plain_routed_ffn(h, mp, cfg))
+    got = jax.grad(_scalar(tfm._routed_ffn, cfg), argnums=(0, 1))(h, mp)
+    want = jax.grad(_scalar(plain_routed_ffn, cfg), argnums=(0, 1))(h, mp)
+    assert set(got[1]) == {"router", "w_gate", "w_up", "w_down"}
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        if not (renorm and k == 1):  # a renormalised single weight is the constant 1: no router gradient
+            assert float(jnp.max(jnp.abs(w))) > 1e-3, path
+        np.testing.assert_allclose(g, w, atol=5e-6, err_msg=str(path))
+
+
+def _row_scatters(jaxpr, width):
+    """Every scatter of the (closed) jaxpr, sub-jaxprs included, whose
+    operand's rows are `width` wide."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if "scatter" in eqn.primitive.name and eqn.invars[0].aval.shape[-1:] == (width,):
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _row_scatters(sub, width)
+    return found
+
+
+def test_the_gradient_scatters_no_rows():
+    """Of `_routed_ffn` alone: dispatch and combine are permutations, so
+    their transposes are gathers. (The probabilities' `take_along_axis`
+    keeps its scatter into [n, E]; E is not D here.)"""
+    cfg = moe_cfg()
+    h, mp = one_layer_ffn(30, cfg)
+    ours = jax.make_jaxpr(jax.grad(_scalar(tfm._routed_ffn, cfg), argnums=(0, 1)))(h, mp)
+    plain = jax.make_jaxpr(jax.grad(_scalar(plain_routed_ffn, cfg), argnums=(0, 1)))(h, mp)
+    assert len(_row_scatters(plain.jaxpr, D)) == 2  # what the detector is for: autodiff's two
+    assert _row_scatters(ours.jaxpr, D) == []
+
+
+def test_bf16_dispatch_gradient_sums_the_k_copies_in_float32():
+    """dx of dispatch rounds once, after a float32 sum over a token's k
+    copies: at least as close to the exact sum as autodiff's scatter-add,
+    which rounds to bf16 after every row it adds."""
+    n, k, d = 64, 8, D
+    order = jnp.argsort(jax.random.randint(jax.random.PRNGKey(40), (n * k,), 0, E))
+    inverse = jnp.argsort(order)
+    x = jax.random.normal(jax.random.PRNGKey(41), (n, d), jnp.float32).astype(jnp.bfloat16)
+    dxs = jax.random.normal(jax.random.PRNGKey(42), (n * k, d), jnp.float32).astype(jnp.bfloat16)
+    got = jax.vjp(lambda x: moe_rows.dispatch_rows(x, order, inverse, k), x)[1](dxs)[0]
+    old = jax.vjp(lambda x: jnp.take(x, order // k, axis=0), x)[1](dxs)[0]
+    exact = np.zeros((n, d), np.float64)
+    np.add.at(exact, np.asarray(order) // k, np.asarray(dxs, np.float64))
+    assert got.dtype == old.dtype == jnp.bfloat16
+    err_got = np.abs(np.asarray(got, np.float64) - exact)
+    err_old = np.abs(np.asarray(old, np.float64) - exact)
+    assert err_got.max() <= err_old.max() and err_got.mean() <= err_old.mean()
+    # one rounding of the exact sum: half a bf16 ulp of the result
+    assert (err_got <= 2.0**-8 * np.abs(exact) + 1e-30).all()
+
+
+def test_remat_hot_gives_the_gradients_of_the_step_without_remat():
+    """Under remat_policy "hot" the hand-written backward reads the saved
+    `moe_route` indices and the recomputed sorted rows: same gradients."""
+    cfg = moe_cfg(attn_impl="naive")
+    params = tfm.init_params(jax.random.PRNGKey(50), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(51), (2, 16), 0, cfg.vocab_size)
+    want_loss, want = jax.value_and_grad(tfm.next_token_loss)(params, tokens, cfg)
+    hot = cfg.replace(remat=True, remat_policy="hot")
+    got_loss, got = jax.jit(jax.value_and_grad(lambda p, t: tfm.next_token_loss(p, t, hot)))(params, tokens)
+    assert abs(float(got_loss) - float(want_loss)) <= 1e-6
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, atol=1e-6, err_msg=str(path))
+    assert float(jnp.max(jnp.abs(want["blocks"]["mlp"]["router"]))) > 1e-4
