@@ -16,9 +16,10 @@ published description (pre-norm RMSNorm, rotary embeddings on rotate-half
 pairs, grouped-query causal attention, gated-SiLU MLP, untied head); it
 shares no code with ray_tpu/models/transformer.py and reads only the layout
 of the weights (stacked layers, [in, out] matrices). Weights are upcast one
-layer at a time so that it fits beside the engine. `correct` rests on it: by
-loss for training (`sequence_nll`), by the reference logit of each served
-token for serving (`lib/correct.served_token_margins` over `logits_at`).
+layer at a time so that it fits beside the engine. `correct` rests on it:
+`sequence_nll`, differentiated, is the reference that trains
+(`lib/correct.training_reference`); `logits_at` gives the reference logit of
+each served token (`lib/correct.served_margins`).
 
 The counts are the operations and bytes the algorithm needs, from shapes
 alone, kept with the benchmark so that no PR that claims a gain can change
@@ -143,21 +144,28 @@ def _attention(q, k, v):
     return jnp.concatenate(outs, axis=0).reshape(s, h * hd)
 
 
+def _layer(x, w, m: Dict):
+    """One pre-norm block on x [s, d]; `w` is the layer's weights as stored, upcast here."""
+    w = jax.tree_util.tree_map(lambda a: a.astype(F32), w)
+    hn = _rms_norm(x, w["attn_norm"]["scale"], m["eps"])
+    s = hn.shape[0]
+    q = _rope((hn @ w["attn"]["wq"]).reshape(s, m["h"], m["hd"]), m["theta"])
+    k = _rope((hn @ w["attn"]["wk"]).reshape(s, m["kv"], m["hd"]), m["theta"])
+    v = (hn @ w["attn"]["wv"]).reshape(s, m["kv"], m["hd"])
+    x = x + _attention(q, k, v) @ w["attn"]["wo"]
+    hn = _rms_norm(x, w["mlp_norm"]["scale"], m["eps"])
+    return x + (jax.nn.silu(hn @ w["mlp"]["w_gate"]) * (hn @ w["mlp"]["w_up"])) @ w["mlp"]["w_down"]
+
+
 def hidden_states(params, tokens, m: Dict):
-    """tokens [s] int32 -> final-norm hidden states [s, d], float32."""
+    """tokens [s] int32 -> final-norm hidden states [s, d], float32. Each
+    layer is a `jax.checkpoint`: the same numbers, and where the reference
+    is differentiated (lib/correct.training_reference) only one layer's
+    scores are alive in the backward pass."""
     with jax.default_matmul_precision("highest"):
         x = params["embed"]["embedding"][tokens].astype(F32)
-        blocks = params["blocks"]
         for layer in range(m["L"]):
-            w = jax.tree_util.tree_map(lambda a: a[layer].astype(F32), blocks)
-            hn = _rms_norm(x, w["attn_norm"]["scale"], m["eps"])
-            s = hn.shape[0]
-            q = _rope((hn @ w["attn"]["wq"]).reshape(s, m["h"], m["hd"]), m["theta"])
-            k = _rope((hn @ w["attn"]["wk"]).reshape(s, m["kv"], m["hd"]), m["theta"])
-            v = (hn @ w["attn"]["wv"]).reshape(s, m["kv"], m["hd"])
-            x = x + _attention(q, k, v) @ w["attn"]["wo"]
-            hn = _rms_norm(x, w["mlp_norm"]["scale"], m["eps"])
-            x = x + (jax.nn.silu(hn @ w["mlp"]["w_gate"]) * (hn @ w["mlp"]["w_up"])) @ w["mlp"]["w_down"]
+            x = jax.checkpoint(lambda x, w: _layer(x, w, m))(x, jax.tree_util.tree_map(lambda a: a[layer], params["blocks"]))
         return _rms_norm(x, params["final_norm"]["scale"], m["eps"])
 
 

@@ -176,37 +176,49 @@ def _router_weights(hn, router, m: Dict):
 
 def _experts(hn, weights, mlp):
     """sum over experts e of weights[:, e] * W_down[e](silu(hn W_gate[e]) * (hn W_up[e])),
-    one expert upcast at a time; `mlp` holds one layer's expert weights as stored."""
+    one expert upcast at a time; `mlp` holds one layer's expert weights as stored.
+    An expert's term is a `jax.checkpoint`: the same numbers, and where the
+    reference is differentiated no expert's activations or upcast weights are
+    kept for the backward pass (64 experts' would be 7 GB a layer)."""
+
+    @jax.checkpoint
+    def term(hn, w_gate, w_up, w_down, w_e):
+        return w_e[:, None] * ((jax.nn.silu(hn @ w_gate.astype(F32)) * (hn @ w_up.astype(F32))) @ w_down.astype(F32))
 
     def add_expert(acc, xs):
-        w_gate, w_up, w_down, w_e = xs
-        out = (jax.nn.silu(hn @ w_gate.astype(F32)) * (hn @ w_up.astype(F32))) @ w_down.astype(F32)
-        return acc + w_e[:, None] * out, None
+        return acc + term(hn, *xs), None
 
     acc, _ = jax.lax.scan(add_expert, jnp.zeros_like(hn), (mlp["w_gate"], mlp["w_up"], mlp["w_down"], weights.T))
     return acc
 
 
+def _layer(x, w, m: Dict):
+    """One block on x [s, d] -> (x, experts [s, k]); `w` is the layer's weights as stored, upcast where used."""
+    a = jax.tree_util.tree_map(lambda t: t.astype(F32), w["attn"])
+    hn = _rms_norm(x, w["attn_norm"]["scale"], m["eps"])
+    s = hn.shape[0]
+    q = _rms_norm(hn @ a["wq"], a["q_norm"]["scale"], m["eps"])  # the whole projection, then heads
+    k = _rms_norm(hn @ a["wk"], a["k_norm"]["scale"], m["eps"])
+    q = _rope(q.reshape(s, m["h"], m["hd"]), m["theta"])
+    k = _rope(k.reshape(s, m["kv"], m["hd"]), m["theta"])
+    v = (hn @ a["wv"]).reshape(s, m["kv"], m["hd"])
+    x = x + _attention(q, k, v) @ a["wo"]
+    hn = _rms_norm(x, w["mlp_norm"]["scale"], m["eps"])
+    weights, top_e = _router_weights(hn, w["mlp"]["router"], m)
+    return x + _experts(hn, weights, w["mlp"]), top_e
+
+
 def _layers(params, tokens, m: Dict):
-    """tokens [s] int32 -> (final-norm hidden states [s, d], experts [L, s, k]), float32."""
+    """tokens [s] int32 -> (final-norm hidden states [s, d], experts [L, s, k]), float32.
+    Each layer is a `jax.checkpoint`: the same numbers, and where the
+    reference is differentiated (lib/correct.training_reference) only one
+    layer's scores and expert activations are alive in the backward pass."""
     with jax.default_matmul_precision("highest"):
         x = params["embed"]["embedding"][tokens].astype(F32)
-        blocks, chosen = params["blocks"], []
+        chosen = []
         for layer in range(m["L"]):
-            w = jax.tree_util.tree_map(lambda a: a[layer], blocks)  # as stored; upcast where used
-            a = jax.tree_util.tree_map(lambda t: t.astype(F32), w["attn"])
-            hn = _rms_norm(x, w["attn_norm"]["scale"], m["eps"])
-            s = hn.shape[0]
-            q = _rms_norm(hn @ a["wq"], a["q_norm"]["scale"], m["eps"])  # the whole projection, then heads
-            k = _rms_norm(hn @ a["wk"], a["k_norm"]["scale"], m["eps"])
-            q = _rope(q.reshape(s, m["h"], m["hd"]), m["theta"])
-            k = _rope(k.reshape(s, m["kv"], m["hd"]), m["theta"])
-            v = (hn @ a["wv"]).reshape(s, m["kv"], m["hd"])
-            x = x + _attention(q, k, v) @ a["wo"]
-            hn = _rms_norm(x, w["mlp_norm"]["scale"], m["eps"])
-            weights, top_e = _router_weights(hn, w["mlp"]["router"], m)
+            x, top_e = jax.checkpoint(lambda x, w: _layer(x, w, m))(x, jax.tree_util.tree_map(lambda a: a[layer], params["blocks"]))
             chosen.append(top_e)
-            x = x + _experts(hn, weights, w["mlp"])
         return _rms_norm(x, params["final_norm"]["scale"], m["eps"]), jnp.stack(chosen)
 
 

@@ -2,11 +2,11 @@
 load through the streaming DeploymentHandle from this process, which never
 opens a jax backend. Shared by the open-loop and the closed-loop runner.
 
-Order of a run: deploy -> warm every executable (in the replica) -> a few
-probe requests through the whole path, checked against the reference ->
-start the load -> lead-in (served, not counted) -> window -> [traced runs: a
+Order of a run: deploy -> warm every executable (in the replica) -> start
+the load -> lead-in (served, not counted) -> window -> [traced runs: a
 traced segment, the load still running] -> wait for the window's first
-tokens -> cancel what is in flight -> collect spans -> shut down.
+tokens -> cancel what is in flight -> the reference over a sample of the
+requests the window finished (in the replica) -> collect spans -> shut down.
 """
 
 from __future__ import annotations
@@ -17,7 +17,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-from . import driver, stats, traffic as traffic_lib
+from . import correct, driver, stats, traffic as traffic_lib
 from .spec import Cell
 from .worker_serve import build, prompt_crc
 
@@ -175,18 +175,7 @@ def run_cell(cell: Cell, start: Callable[[Load, float], None], horizon_s: float)
             lo, hi = traffic_lib.prompt_length_range(tr)
             warm = call("warmup", min_prompt_tokens=lo, max_prompt_tokens=hi)
 
-            # Probes: whole path once (stream, engine, prefill, decode), then
-            # the reference's verdict on what was served.
             cor = tr["correctness"]
-            if not all(lo <= n <= hi for n in cor["probe_prompt_tokens"]):
-                raise ValueError("probe prompts must lie inside the mix's own length range (no extra bucket is warmed)")
-            probes = [
-                traffic_lib.segment_tokens(cell.seed, f"probe{i}", n, vocab).tolist()
-                for i, n in enumerate(cor["probe_prompt_tokens"])
-            ]
-            served = [[int(t) for t in stream.remote(p, cor["probe_new_tokens"])] for p in probes]
-            margins = call("check", prompts=probes, served=served, which=cor["check_tokens"])["margins"]
-
             load = Load(stream, traffic_lib.generate(tr, horizon_s), cell.seed, vocab)
             ticker = Ticker()
             ticker.start()
@@ -205,6 +194,14 @@ def run_cell(cell: Cell, start: Callable[[Load, float], None], horizon_s: float)
                 trace_path = call("trace_stop")["trace_path"]
             load.finish(w0, w1, float(tr["grace_s"]))
             ticker_gaps = ticker.stop()
+            # What the window served, against the reference: a sample of its
+            # finished requests drawn from the seed, the longest among them.
+            timeline = sorted(load.records, key=lambda r: r["idx"])
+            prompt_of = {r.idx: p for r, p in zip(load.requests, load.prompts)}
+            sample = correct.window_sample(timeline, cell.seed, int(cor["sample_requests"]))
+            pad_served = traffic_lib.max_answer_tokens(tr)
+            checked = call("check", requests=[{"prompt": prompt_of[r["idx"]], "served": r["tokens"]} for r in sample],
+                           pad_tokens=-(-(hi + pad_served) // 512) * 512, pad_served=pad_served, control=bool(cor.get("control")))
             worker = call("finish")
         finally:
             serve.shutdown()
@@ -212,13 +209,21 @@ def run_cell(cell: Cell, start: Callable[[Load, float], None], horizon_s: float)
 
     worker["trace_path"] = trace_path
     worker["warmup"] = warm
-    timeline = sorted(load.records, key=lambda r: r["idx"])
     write_timeline(cell, timeline, w0, w1, ticker_gaps)
     attempted, failed = stats.attempted_failed(timeline)
     shed = marks[1]["engine"]["shed_total"] - marks[0]["engine"]["shed_total"]
+    tol = cor["served_margin_tolerance"]
+    served_sample = {
+        "requests": [{"idx": r["idx"], "prompt_tokens": r["prompt_tokens"], "served_tokens": len(r["tokens"]), "margin_max": max(row["margins"])}
+                     for r, row in zip(sample, checked["rows"])],
+        "margins": correct.error_quantiles([x for row in checked["rows"] for x in row["margins"]]) if sample else None,
+        "limits": tol, "seconds": checked["seconds"],
+    }
+    if cor.get("control"):  # benchmarks/tools/control.py only
+        served_sample["control"] = correct.error_quantiles([x for row in checked["rows"] for x in row["control"]])
     checks = {
-        "served_tokens_within_logit_tolerance": all(x <= cor["logit_margin_tolerance"] for row in margins for x in row),
-        "probes_returned_all_tokens": all(len(s) == cor["probe_new_tokens"] for s in served),
+        "the_window_finished_requests": bool(sample),
+        "served_tokens_within_reference_margin": bool(sample) and correct.judge(served_sample["margins"], tol),
         "no_request_failed": failed == 0 and shed == 0,
         "engine_not_failed": worker["engine"]["failed"] is None,
     }
@@ -239,7 +244,8 @@ def run_cell(cell: Cell, start: Callable[[Load, float], None], horizon_s: float)
         "load_facts": load_facts,
         "cell": cell, "worker": worker, "window": [w0, w1], "spans": worker["spans"], "timeline": timeline,
         "marks": marks, "ticker_gaps": ticker_gaps, "attempted": attempted, "failed": failed + shed,
-        "correct": all(checks.values()), "checks": checks, "margins": margins,
+        "correct": all(checks.values()), "checks": checks, "served_sample": served_sample,
+        "compared": correct.compared_quantiles("served_margin", [served_sample["margins"]], tol) if sample else {},
     }
 
 

@@ -193,6 +193,11 @@ def prompt_length_range(traffic: Dict[str, Any]) -> Tuple[int, int]:
     return lo, hi
 
 
+def max_answer_tokens(traffic: Dict[str, Any]) -> int:
+    """The longest answer the FILE can ask for."""
+    return _bounds(traffic["answer_tokens"])[1]
+
+
 def bucket_pages(n_pages: int, max_pages_per_seq: int) -> int:
     """The benchmark's copy of PagedLM's bucket rule (power of two pages,
     capped), for the rehearsal and the tests; the replica warms up with the

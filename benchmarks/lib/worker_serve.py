@@ -7,9 +7,10 @@ the paged forward below it are the program's, untouched.
 The driver steers it through one extra method, `bench(cmd, **kw)`, which the
 builder attaches to the replica's LLMServer class in the replica process (the
 program has no control surface of its own yet; PERF.md lists it for the
-tracing issue): warm-up of every prefill bucket, the correctness check
-against the plain reference, window marks (CompileWatch and engine counters),
-start/stop of the device trace, and the spans at the end.
+tracing issue): warm-up of every prefill bucket, window marks (CompileWatch
+and engine counters), start/stop of the device trace, the plain reference
+over a sample of the window's finished requests once the load has stopped,
+and the spans at the end.
 """
 
 from __future__ import annotations
@@ -41,6 +42,8 @@ class BenchModel:
         from ray_tpu.serve.llm.model import PagedLM
         from ray_tpu.utils import compile_cache
 
+        from . import correct
+
         t0 = time.monotonic()
         self._jax = jax
         self.conf = conf
@@ -51,7 +54,7 @@ class BenchModel:
         self.arch = spec.load_arch(conf["model"])
         cfg = self.arch.model_config(conf["model"])
         eng = {k: v["value"] for k, v in conf["model"]["assumed"].items()}
-        params = jax.jit(lambda k: tfm.init_params(k, cfg))(seeded_key(conf["seed"]))
+        params = jax.jit(lambda k: correct.init_weights(tfm, cfg, k))(seeded_key(conf["seed"]))
         self.lm = PagedLM(
             cfg, params, num_pages=eng["pool_pages"], page_tokens=eng["page_tokens"],
             max_slots=eng["max_slots"], max_pages_per_seq=eng["max_pages_per_seq"],
@@ -62,6 +65,7 @@ class BenchModel:
         self.max_pages_per_seq = self.lm.max_pages_per_seq
         self.page_tokens = self.lm.page_tokens
         self.spans: List[list] = []
+        self.peak_bytes = None  # read by the check before it runs the reference
         self.tracing = False
         self._logdir = conf["out_prefix"] + "-trace"
         self.setup_parts_s = {"replica_start_to_devices": t1 - t0, "init_weights_and_pool": time.monotonic() - t1}
@@ -115,14 +119,24 @@ class BenchModel:
         return {"buckets": buckets, "warmup_s": time.monotonic() - t0, "setup_parts_s": self.setup_parts_s,
                 "compile": self.watch.snapshot(), "device": self.device}
 
-    def _cmd_check(self, engine, prompts, served, which) -> Dict[str, Any]:
+    def _cmd_check(self, engine, requests, pad_tokens: int, pad_served: int, control: bool) -> Dict[str, Any]:
+        """After the load has stopped and before `finish`, so that neither
+        setup_s nor the window sees it: the reference once over each sampled
+        request's prompt with the tokens it was served. Waits for the engine
+        to reap what was cancelled, and reads the peak before the reference
+        adds to it."""
         from . import correct
 
-        margins = [
-            correct.served_token_margins(self.arch, self.lm.params, p, s, self.conf["model"], which)
-            for p, s in zip(prompts, served)
-        ]
-        return {"margins": margins}
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 30.0:
+            st = engine.stats()
+            if not st["running"] and not st["waiting"]:
+                break
+            time.sleep(0.02)
+        self.peak_bytes = memory_peak_bytes(self._jax.devices())
+        rows = [correct.served_margins(self.arch, self.lm.params, r["prompt"], r["served"], self.conf["model"], pad_tokens, pad_served, control)
+                for r in requests]
+        return {"rows": rows, "seconds": time.monotonic() - t0}
 
     def _cmd_mark(self, engine) -> Dict[str, Any]:
         return {"t": time.monotonic(), "compile": self.watch.snapshot(), "engine": engine.stats()}
@@ -143,7 +157,7 @@ class BenchModel:
             "pid": os.getpid(),
             "device": self.device,
             "spans": self.spans,
-            "memory_peak_bytes": memory_peak_bytes(self._jax.devices()),
+            "memory_peak_bytes": self.peak_bytes or memory_peak_bytes(self._jax.devices()),
             "engine": engine.stats(),
             "compile": self.watch.snapshot(),
             "arch_file": os.path.relpath(self.arch.__file__, spec.ROOT),

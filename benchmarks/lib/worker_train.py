@@ -59,6 +59,49 @@ def find_xplane(logdir: str) -> str:
     return path
 
 
+class FirstSteps:
+    """What the program's first steps read, for `lib/correct.compare_training`:
+    each step's loss; after the first, the gradient's norms as the optimizer
+    got it (adam's first moment is then (1 - b1) x the gradient); after the
+    last, the norms of the parameters' change from those the seed makes
+    (`make_params`: made again for the subtraction and dropped, a few tenths
+    of a second, rather than held through the run)."""
+
+    STEPS = 3
+
+    def __init__(self, make_params):
+        import jax
+
+        from . import correct
+
+        self.make_params = make_params
+        self.losses: List[float] = []
+        self.grad_norms = self.change_norms = None
+        self._norms, self._change = jax.jit(correct.leaf_norms), jax.jit(correct.change_norms)
+        self._first_moment, self._b1 = correct.first_moment, correct.ADAMW["b1"]
+
+    def after_step(self, number: int, loss: float, params, opt_state) -> None:
+        import numpy as np
+
+        if number <= self.STEPS:
+            self.losses.append(loss)
+        if number == 1:
+            self.grad_norms = np.asarray(self._norms(self._first_moment(opt_state)), np.float64) / (1 - self._b1)
+        if number == self.STEPS:
+            self.change_norms = np.asarray(self._change(params, self.make_params()), np.float64)
+
+    def readings(self) -> Dict[str, Any]:
+        if self.change_norms is None:
+            raise RuntimeError(f"the traffic file's warmup_steps must give at least {self.STEPS} steps before the window")
+        return {"losses": self.losses, "grad_norms": self.grad_norms, "change_norms": self.change_norms}
+
+
+def leaf_names(init, key) -> List[str]:
+    import jax
+
+    return [jax.tree_util.keystr(path) for path, _leaf in jax.tree_util.tree_flatten_with_path(jax.eval_shape(init, key))[0]]
+
+
 def train_loop(config: Dict[str, Any]) -> None:
     t_enter = time.monotonic()
     import jax
@@ -71,7 +114,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     from ray_tpu.train import zero
     from ray_tpu.utils import compile_cache
 
-    from . import spec
+    from . import correct, spec
 
     watch = compile_cache.watch()
     cache_everything()
@@ -87,7 +130,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     cfg = arch.model_config(config["model"], max_seq_len=seq)
     lr = config["model"]["assumed"]["learning_rate"]["value"]
     zero_axis = "data" if n > 1 else None
-    tx = optax.adamw(lr)
+    tx = optax.adamw(lr, **correct.ADAMW)
     _init_state, step = tfm.build_train_step(cfg, tx, mesh, zero_axis=zero_axis)
 
     # Weights and batch: one jitted call each, from the seed, on the device,
@@ -95,7 +138,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     # program per random call: ~50 s cold, PERF.md).
     rep = NamedSharding(mesh, P())
     key = seeded_key(seed)
-    params = jax.jit(lambda k: tfm.init_params(k, cfg), out_shardings=rep)(key)
+    init = jax.jit(lambda k: correct.init_weights(tfm, cfg, k), out_shardings=rep)
+    params = init(key)
     if zero_axis is None:
         opt_state = jax.jit(tx.init, out_shardings=rep)(params)
     else:
@@ -105,16 +149,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         out_shardings=NamedSharding(mesh, P("data")),
     )(jax.random.fold_in(key, 1))
 
-    # Correctness, outside the window: the plain float32 reference on every
-    # sequence of the batch, each chip taking its own, one at a time.
-    def ref_loss(p, t):
-        per_seq = jax.lax.map(lambda s: arch.sequence_nll(p, s, config["model"]), t)
-        return jax.lax.pmean(jnp.mean(per_seq), "data")
-
-    ref = float(
-        jax.jit(jax.shard_map(ref_loss, mesh=mesh, in_specs=(P(), P("data")), out_specs=P()))(params, tokens)
-    )
-    t_ref = time.monotonic()
+    t_init = time.monotonic()
 
     def one_step(i, traced):
         nonlocal params, opt_state
@@ -125,9 +160,15 @@ def train_loop(config: Dict[str, Any]) -> None:
             jax.block_until_ready(loss)
         return t0, time.monotonic(), loss
 
+    # The first steps of the one compiled step and state that the window
+    # then drives on, through the window's own call: their losses, the first
+    # gradient's norms and the parameters' change are what `correct`
+    # compares with the reference that trains (after the window).
+    first = FirstSteps(lambda: init(key))
     warm: List[float] = []
     for i in range(1 + int(traffic["warmup_steps"])):  # the first call compiles or loads
         warm.append(float(one_step(i, False)[2]))
+        first.after_step(i + 1, warm[-1], params, opt_state)
     t_warm = time.monotonic()
 
     before = watch.snapshot()
@@ -155,12 +196,18 @@ def train_loop(config: Dict[str, Any]) -> None:
             jax.profiler.stop_trace()
         trace_path = find_xplane(logdir)
 
-    tol = traffic["correctness"]
-    checks = {
-        "step0_loss_matches_reference": abs(warm[0] - ref) <= tol["loss_abs_tolerance"],
-        "loss_fell_in_warmup": warm[-1] < warm[0],
-        "losses_finite": all(math.isfinite(x) for x in warm + losses),
-    }
+    # The reference that trains, after the window and the traced segment,
+    # with the peak read and the trained state freed, so that neither
+    # setup_s nor a metric sees it: the same batch, the same first steps.
+    peak_bytes = memory_peak_bytes(devices)
+    n_params = tfm.param_count(params)
+    t_check = time.monotonic()
+    params = opt_state = None
+    reference = correct.training_reference(arch, config["model"], mesh, lr, FirstSteps.STEPS)(lambda: init(key), tokens)
+    compared, training = correct.compare_training(first.readings(), reference, traffic["correctness"], leaf_names(init, key))
+    training["seconds"] = time.monotonic() - t_check
+    checks = {name: value <= limit for name, (value, limit) in compared.items()}
+    checks["losses_finite"] = all(math.isfinite(x) for x in warm + losses)
     train.report({"summary": {
         "pid": os.getpid(),
         "device": device,
@@ -172,15 +219,17 @@ def train_loop(config: Dict[str, Any]) -> None:
         "attempted": len(losses),
         "failed": sum(1 for x in losses if not math.isfinite(x)),
         "checks": checks,
-        "loss": {"step0": warm[0], "reference": ref, "after_warmup": warm[-1], "window_last": losses[-1]},
+        "compared": compared,
+        "training": training,
+        "loss": {"first_steps": warm, "reference": reference["losses"], "window_last": losses[-1]},
         "compile": {"before": before, "after": after},
-        "memory_peak_bytes": memory_peak_bytes(devices),
+        "memory_peak_bytes": peak_bytes,
         "trace_path": trace_path,
         "setup_parts_s": {
             "worker_start_to_devices": t_devices - t_enter,
-            "init_and_reference": t_ref - t_devices,
-            "first_step_and_warmup": t_warm - t_ref,
+            "init": t_init - t_devices,
+            "first_steps": t_warm - t_init,
         },
-        "n_params": tfm.param_count(params),
+        "n_params": n_params,
         "arch_file": os.path.relpath(arch.__file__, spec.ROOT),
     }})

@@ -68,7 +68,7 @@ def main(argv=None, prepare=None, every_metric=False) -> int:
         "metrics": metrics,
         "device": device,
     }
-    facts = {k: evidence.get(k) for k in ("checks", "margins", "ticker_gaps", "load_facts") if evidence.get(k) is not None}
+    facts = {k: evidence.get(k) for k in ("checks", "training", "served_sample", "ticker_gaps", "load_facts") if evidence.get(k) is not None}
     for k in ("loss", "setup_parts_s", "warmup", "n_params", "arch_file"):
         if worker.get(k) is not None:
             facts[k] = worker[k]
@@ -79,8 +79,13 @@ def main(argv=None, prepare=None, every_metric=False) -> int:
         if tr is not None:
             device["busy_s"], device["window_s"] = tr.busy_s(), tr.window_s()
             line["breakdown"] = {"device_ops": tr.top_ops(10), "idle_gaps": tr.idle_gaps_by_span(10)}
+    # Each number that `correct` compared, beside its limit: the line's last key, and stderr's last lines.
+    line["compared"] = {name: {"value": value, "limit": limit} for name, (value, limit) in evidence["compared"].items()}
     print("benchmark: facts " + json.dumps(facts, default=str), flush=True)
     print(json.dumps(line), flush=True)
+    print("benchmark: checks " + json.dumps(evidence["checks"]), file=sys.stderr, flush=True)
+    for name, c in line["compared"].items():
+        print(f"benchmark: compared {name} {c['value']!r} limit {c['limit']!r}", file=sys.stderr, flush=True)
     return 0
 
 

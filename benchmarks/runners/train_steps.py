@@ -49,4 +49,6 @@ def run(cell: Cell) -> Dict[str, Any]:
         "failed": worker["failed"],
         "correct": all(worker["checks"].values()),
         "checks": worker["checks"],
+        "training": worker["training"],
+        "compared": worker["compared"],
     }

@@ -10,7 +10,7 @@ runs the same over all three configurations (tests/test_parity.py).
 TOLERANCE: both sides compute in float32 (the reference at matmul precision
 "highest"), so they differ by float32 rounding through 2 layers of width 64.
 Read over 12 seeds x 3 configurations x both paths (PR 27, CPU; every norm's
-scale drawn from [0.5, 1.5], see `with_drawn_norm_scales`), on logits up to
+scale drawn from [0.5, 1.5], `lib/correct.draw_norm_scales`), on logits up to
 4.2 in size: the largest difference of the float32 program 4.2e-6 (Mistral),
 3.5e-6 (DeepSeek), 2.5e-6 (OLMoE); the smallest of the control, the same
 program and weights in bfloat16, 3.1e-2, 3.2e-2, 3.5e-2. 1e-4 is 24 times the
@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.lib import spec
+from benchmarks.lib import correct, spec
 from ray_tpu.models import transformer as tfm
 
 TOLERANCE = 1e-4
@@ -46,26 +46,12 @@ def tiny(name, **changed):
     return config, spec.load_arch(config)
 
 
-def seeded(arch, config, seed, dtype):
+def seeded(arch, config, seed, dtype, drawn=True):
     cfg = arch.model_config(config, dtype=dtype, remat=False)
     key = jax.random.PRNGKey(seed)
-    params = with_drawn_norm_scales(tfm.init_params(key, cfg), jax.random.fold_in(key, 2))
+    params = correct.init_weights(tfm, cfg, key) if drawn else tfm.init_params(key, cfg)
     tokens = jax.random.randint(jax.random.fold_in(key, 1), (19,), 0, cfg.vocab_size, jnp.int32)
     return cfg, params, tokens
-
-
-def with_drawn_norm_scales(params, key):
-    """init_params starts every norm's scale at 1, and an RMSNorm over a
-    fan-in-scaled projection with unit scale is nearly the identity: draw the
-    scales from [0.5, 1.5], so that a norm left out or misplaced (q/k-norm
-    over the heads instead of the whole projection) shows in the logits."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
-    drawn = [
-        jax.random.uniform(jax.random.fold_in(key, i), leaf.shape, jnp.float32, 0.5, 1.5).astype(leaf.dtype)
-        if "norm" in jax.tree_util.keystr(path) else leaf
-        for i, (path, leaf) in enumerate(leaves)
-    ]
-    return jax.tree_util.tree_unflatten(treedef, drawn)
 
 
 def worst(a, b):
@@ -123,3 +109,32 @@ def test_prefill_then_decode_through_the_paged_cache_matches_the_reference_logit
     assert worst(paged_logits(cfg, params, tokens, 13), want) <= TOLERANCE
     cfg16, params16, _ = seeded(arch, config, seed, jnp.bfloat16)
     assert worst(paged_logits(cfg16, params16, tokens, 13), want) > 10 * TOLERANCE
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_statistic_separates_the_program_from_its_nearest_wrong_models_once_norm_scales_are_drawn(seed):
+    """What a cell's `correct` reads (`lib/correct.py`: the per-position
+    relative error of the logits, its median over the positions), on the
+    bfloat16 program as the cells run it: the right program reads a few
+    hundredths, top-(k-1) and no q/k-norm several tenths. With every norm's
+    scale at 1, as `init_params` leaves them, a model WITHOUT q/k-norm is
+    under half as far off (0.07-0.15 against 0.27-0.41 at these widths, and
+    an RMSNorm over 2 048 fan-in-scaled outputs is nearer the identity
+    still): why both workers draw the scales."""
+    config, arch = tiny(CONFIGS[0], torch_dtype="float32")
+
+    def q50(cfg, params, tokens):
+        want = arch.logits_at(params, tokens, jnp.arange(tokens.shape[0]), config)
+        return correct.error_quantiles(correct.logit_relative_errors(tfm.forward(params, tokens[None], cfg)[0], want))["q50"]
+
+    cfg, params, tokens = seeded(arch, config, seed, jnp.bfloat16)
+    right = q50(cfg, params, tokens)
+    assert right < 0.03
+    assert q50(cfg.replace(n_experts_per_tok=cfg.n_experts_per_tok - 1), params, tokens) > 5 * right
+    no_qk_norm = q50(cfg.replace(qk_norm=False), params, tokens)
+    assert no_qk_norm > 5 * right
+    cfg, params, tokens = seeded(arch, config, seed, jnp.bfloat16, drawn=False)
+    assert q50(cfg, params, tokens) < 0.03 and q50(cfg.replace(qk_norm=False), params, tokens) < no_qk_norm / 2
+    assert correct.judge({"q50": right}, {"q50": 0.045}) and not correct.judge({"q50": float("nan")}, {"q50": 0.045})
+    with pytest.raises(ValueError):
+        correct.judge({"q50": right}, {})

@@ -7,10 +7,12 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 from benchmarks.lib import spec
 
 ENV = dict(os.environ, JAX_PLATFORMS="cpu")
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def rehearse_one(root, workload, trace, devices=1, facts=False):
@@ -58,6 +60,9 @@ def test_last_line_has_exactly_the_contracts_keys():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     assert line["correct"] is True and line["attempted"] > 0 and line["failed"] == 0
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {"loss_step1", "loss_step2", "loss_step3", "grad_norm_gap", "param_change_gap"}
+    assert all(set(c) == {"value", "limit"} and c["value"] <= c["limit"] for c in line["compared"].values())
     traced = rehearse_one(spec.ROOT, cell.name, 1)
     assert set(traced) - {"breakdown"} == LINE_KEYS
     assert set(traced["metrics"]) <= {m["name"] for m in cell.per_layer}
@@ -166,3 +171,125 @@ def test_a_new_architecture_is_added_with_new_files_only(tmp_path):
         assert facts["arch_file"] == "benchmarks/archs/gptj_sequential.py"
     for path, content in before.items():
         assert open(path, "rb").read() == content, f"{path} was edited"
+
+
+def test_a_routed_models_cells_are_not_correct_against_a_top_k_minus_1_reference(tmp_path):
+    """The blindness of a mean loss, shown and cured, and the proof that a
+    SERVED routed model can be admitted; new files only. A copy of
+    `archs/olmoe.py` whose reference routes each token to one expert fewer
+    stands for the nearest wrong model (the program and a reference that
+    differ by one expert a token, whichever side is wrong). Training cell at
+    TINY widths: the first step's loss alone passes it, the first gradient's
+    norms do not. Serving: an OLMoE configuration with engine sizes on a
+    closed-loop mix is `correct` against the right reference, by the tokens
+    its window served, and not against the wrong one."""
+    root = str(tmp_path)
+    before = copy_of_the_benchmark(root)
+    arch_src = open(os.path.join(spec.BENCH_DIR, "archs", "olmoe.py")).read()
+    top_k = 'top_p, top_e = jax.lax.top_k(probs, m["k"])'
+    assert arch_src.count(top_k) == 1
+    olmoe = spec.load_json(os.path.join(spec.BENCH_DIR, "configs", "olmoe-1b-7b-0125-L2.json"))
+    engine = spec.load_json(os.path.join(spec.BENCH_DIR, "configs", "deepseek-llm-7b-chat-L8.json"))["assumed"]
+    served = dict(olmoe, assumed=dict(engine, **olmoe["assumed"]))
+    files = {
+        "archs/olmoe_top_k_minus_1.py": arch_src.replace(top_k, top_k.replace('m["k"]', 'm["k"] - 1')),
+        "configs/olmoe-wrong-reference.json": dict(olmoe, arch="olmoe_top_k_minus_1"),
+        "configs/olmoe-served.json": served,
+        "configs/olmoe-served-wrong-reference.json": dict(served, arch="olmoe_top_k_minus_1"),
+        "traffic/olmoe-docqa.json": spec.load_json(os.path.join(spec.BENCH_DIR, "traffic", "docqa-batch.json")),
+    }
+    bench = spec.benchmark_json()
+    cells = {"olmoe-train-wrong": ("olmoe-wrong-reference", "train-fixed-batch-moe", ("train_tok_s_chip", "train_step_p50_ms.moe")),
+             "olmoe-serve": ("olmoe-served", "olmoe-docqa", ("serve_tok_s", "decode_batch_mean")),
+             "olmoe-serve-wrong": ("olmoe-served-wrong-reference", "olmoe-docqa", ("serve_tok_s", "decode_batch_mean"))}
+    for config in sorted({c for c, _t, _m in cells.values()}):
+        bench["configs"].append({"name": config, "source": olmoe["source"], "file": f"benchmarks/configs/{config}.json",
+                                 "reduced": ["num_hidden_layers"], "why": "test"})
+    for cell, (config, traffic, reported) in cells.items():
+        bench["workloads"].append({"name": cell, "config": config, "traffic": traffic, "chips": 1, "why": "test"})
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if m["name"] in reported:
+                m["workloads"].append(cell)
+    add_files(root, bench, files)
+
+    line, facts = rehearse_one(root, "olmoe-train-wrong", 0, facts=True)
+    assert line["correct"] is False, facts
+    assert facts["checks"]["loss_step1"] is True and facts["checks"]["grad_norm_gap"] is False, facts
+    line, facts = rehearse_one(root, "olmoe-serve", 0, facts=True)
+    assert line["correct"] is True and line["failed"] == 0, facts
+    assert facts["served_sample"]["margins"]["positions"] >= 100
+    line, facts = rehearse_one(root, "olmoe-serve-wrong", 0, facts=True)
+    assert line["correct"] is False and facts["checks"]["served_tokens_within_reference_margin"] is False, facts
+    for path, content in before.items():
+        assert open(path, "rb").read() == content, f"{path} was edited"
+
+
+STEP = "            params, opt_state, loss = step(params, opt_state, tokens)\n"
+FAULTS = {
+    # cell, devices, file, sound line, broken line, checks that must fail (every other must pass)
+    # the step's new state thrown away (it runs on copies: the step donates its arguments): the state never changes
+    "state_unchanged": ("mistral7b-train-seq4k-1chip", 1, "lib/worker_train.py", STEP,
+                        "            _p, _o, loss = step(*jax.tree_util.tree_map(jnp.copy, (params, opt_state)), tokens)\n",
+                        {"loss_step2", "loss_step3", "grad_norm_gap", "param_change_gap"}),
+    # half of the batch left out, the mean taken over the rest (the first half, twice)
+    "half_batch": ("mistral7b-train-seq4k-1chip", 1, "lib/worker_train.py", STEP,
+                   "            params, opt_state, loss = step(params, opt_state, jnp.concatenate([tokens[: tokens.shape[0] // 2]] * 2))\n",
+                   {"grad_norm_gap"}),
+    # the exchange between chips left out: every chip keeps its own slice of its own gradient
+    "exchange_left_out": ("mistral7b-train-seq4k-zero-4chip", 4, "lib/worker_train.py",
+                          "    _init_state, step = tfm.build_train_step(cfg, tx, mesh, zero_axis=zero_axis)\n",
+                          "    jax.lax.psum_scatter = lambda x, axis, scatter_dimension=0, tiled=True: n * jax.lax.dynamic_slice(\n"
+                          "        x, (jax.lax.axis_index(axis) * (x.shape[0] // n),), (x.shape[0] // n,))\n"
+                          "    _init_state, step = tfm.build_train_step(cfg, tx, mesh, zero_axis=zero_axis)\n",
+                          {"grad_norm_gap"}),
+    # every decoded token altered where it is produced, under the engine
+    "token_altered": ("dsllm7b-serve-docqa-batch", 1, "lib/worker_serve.py",
+                      "            out = self.lm.decode(last_tokens, positions, block_tables)\n",
+                      "            out = [(t + 1) % self.vocab for t in self.lm.decode(last_tokens, positions, block_tables)]\n",
+                      {"served_tokens_within_reference_margin"}),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_run_with_the_timed_path_broken_underneath_is_not_correct(tmp_path, fault):
+    """The rest of a run driven over a timed path with a fault planted in a
+    copy of the benchmark's worker: `correct` comes out false, by the checks
+    that are there to see that fault; no check of another kind fails (a
+    loss of a later step may: the fault moves it too)."""
+    cell, devices, rel, sound, broken, must_fail = FAULTS[fault]
+    root = str(tmp_path)
+    copy_of_the_benchmark(root)
+    shutil.copy(os.path.join(spec.ROOT, "BENCHMARK.json"), root)
+    path = os.path.join(root, "benchmarks", rel)
+    src = open(path).read()
+    assert src.count(sound) == 1
+    with open(path, "w") as f:
+        f.write(src.replace(sound, broken))
+    line, facts = rehearse_one(root, cell, 0, devices=devices, facts=True)
+    assert line["correct"] is False, facts
+    failed = {name for name, ok in facts["checks"].items() if not ok}
+    assert must_fail <= failed <= must_fail | {"loss_step1", "loss_step2", "loss_step3", "param_change_gap"}, facts
+
+
+def test_the_fp8_precision_control_in_the_programs_place_is_not_correct():
+    """The control of a served cell through the cell's own comparison, at TINY
+    widths (the chip's readings at published widths are in the traffic
+    files): `tools/control.py` runs the cell's runner, and the replica reads,
+    at every position of the window's sample, the margin of the token that the
+    reference with weights at fp8's 3 mantissa bits puts first. The program
+    passes the limit, the control does not. (A TRAINED cell's control is read
+    on the chip only: at these widths a bfloat16 program and an fp8-precision
+    reference lie equally far from the float32 one.)"""
+    env = dict(ENV, XLA_FLAGS="--xla_force_host_platform_device_count=1",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(spec.ROOT, ".jax_cache", "cpu_rehearsal"))
+    p = subprocess.run(
+        [sys.executable, os.path.join(spec.ROOT, "benchmarks", "tools", "control.py"), "--workload", "dsllm7b-serve-docqa-batch",
+         "--seeds", "5,3000000007", "--seconds", "2", "--tiny", "1"],
+        cwd=spec.ROOT, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = [json.loads(ln[len("control: "):]) for ln in p.stdout.splitlines() if ln.startswith("control: ")]
+    assert len(lines) == 2
+    for line in lines:
+        assert line["program"]["passes"] is True and line["control"]["passes"] is False, line
+        assert line["program"]["positions"] >= 100 and line["line"]["correct"] is True

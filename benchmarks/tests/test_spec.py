@@ -190,7 +190,8 @@ def test_warmed_prefill_buckets_cover_the_files_length_range(name):
     drawn = [r.prompt_tokens for r in traffic.generate(cell.traffic, 120)]
     assert lo <= min(drawn) and max(drawn) <= hi
     assert max(r.prompt_tokens + r.max_new_tokens for r in traffic.generate(cell.traffic, 120)) <= P * T
-    assert all(lo <= n <= hi for n in cell.traffic["correctness"]["probe_prompt_tokens"])
+    cor = cell.traffic["correctness"]
+    assert cor["sample_requests"] >= 2 and set(cor["served_margin_tolerance"]) <= {"q50", "q90", "q99", "q100"}
 
 
 @pytest.mark.parametrize("name", [c for c in CELLS if "serve" in c])
