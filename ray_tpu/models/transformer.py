@@ -23,6 +23,7 @@ fight the jit tracer.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -82,10 +83,37 @@ class TransformerConfig:
     n_experts_per_tok: int = 0
     norm_topk_prob: bool = False
     qk_norm: bool = False
+    # Trinity (afmoe) family, each off by default. d_head: a head's size where
+    # it is not d_model / n_heads. qk_norm_per_head: the q/k RMSNorm runs over
+    # each head's dims with one scale of head_dim. router_score "sigmoid":
+    # independent scores; the experts are chosen by score + a per-expert bias
+    # that only selects, weighted by the scores themselves (renormalised under
+    # norm_topk_prob) times route_scale. d_ff_shared: width of a SwiGLU expert
+    # that every token passes through beside the routed ones. The first
+    # n_dense_layers layers have a dense SwiGLU of width d_ff_dense in the
+    # routed FFN's place (a stacked group of their own, params["dense_blocks"]).
+    # windows: per layer, how many positions back a query sees (0: all of them);
+    # rope_layers: per layer, whether q and k are rotated. Both empty or as long
+    # as the stack; they ride the layer scans, so one compiled body serves every
+    # layer of a group. attn_gate: the attention output times
+    # sigmoid(h Wg) before Wo. post_norms: a norm on each sublayer's output
+    # before the residual add. embed_scale: embeddings times sqrt(d_model).
+    d_head: Optional[int] = None
+    qk_norm_per_head: bool = False
+    router_score: str = "softmax"  # "softmax" | "sigmoid"
+    route_scale: float = 1.0
+    d_ff_shared: int = 0
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0
+    windows: Tuple[int, ...] = ()
+    rope_layers: Tuple[bool, ...] = ()
+    attn_gate: bool = False
+    post_norms: bool = False
+    embed_scale: bool = False
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -127,21 +155,36 @@ def tiny(**overrides) -> TransformerConfig:
 def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     """Stacked-layer param pytree; paths match
     ray_tpu.parallel.sharding.TRANSFORMER_RULES (right-aligned for the
-    leading n_layers dim)."""
+    leading n_layers dim). `blocks` holds the stack, or, behind
+    cfg.n_dense_layers leading dense layers (`dense_blocks`), the rest of it."""
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    L, d, f, v, E = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_experts
+    d, v, E = cfg.d_model, cfg.vocab_size, cfg.n_experts
     if E and cfg.mlp_act != "swiglu":
         raise ValueError("routed experts are SwiGLU")
+    if cfg.router_score not in ("softmax", "sigmoid"):
+        raise ValueError(f"router_score {cfg.router_score!r} is neither softmax nor sigmoid")
+    for name in ("windows", "rope_layers"):
+        if len(getattr(cfg, name)) not in (0, cfg.n_layers):
+            raise ValueError(f"{name} has {len(getattr(cfg, name))} entries for {cfg.n_layers} layers")
+    if cfg.n_dense_layers and not (E and 0 < cfg.n_dense_layers < cfg.n_layers and cfg.d_ff_dense):
+        raise ValueError("leading dense layers go before routed ones and need d_ff_dense")
     k = iter(jax.random.split(key, 16))
+    # What this model has over the llama and OLMoE blocks draws from a stream
+    # of its own: theirs give the same weights for a key as before.
+    k2 = iter(jax.random.split(jax.random.fold_in(key, 7), 16))
 
-    def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(
-            cfg.dtype
-        )
+    def dense(key, shape, fan_in, dtype=cfg.dtype):
+        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
-    params = {
-        "embed": {"embedding": dense(next(k), (v, d), d)},
-        "blocks": {
+    def swiglu(k, L, f):
+        return {
+            "w_gate": dense(next(k), (L, d, f), d),
+            "w_up": dense(next(k), (L, d, f), d),
+            "w_down": dense(next(k), (L, f, d), f),
+        }
+
+    def blocks(k, L, routed: bool, f: int):
+        out = {
             "attn_norm": {"scale": jnp.ones((L, d), cfg.dtype)},
             "attn": {
                 "wq": dense(next(k), (L, d, nh * hd), d),
@@ -157,26 +200,42 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
                     "w_down": dense(next(k), (L, E, f, d), f),
                     "router": dense(next(k), (L, d, E), d),
                 }
-                if E
-                else {
-                    "w_gate": dense(next(k), (L, d, f), d),
-                    "w_up": dense(next(k), (L, d, f), d),
-                    "w_down": dense(next(k), (L, f, d), f),
-                }
+                if routed
+                else swiglu(k, L, f)
                 if cfg.mlp_act == "swiglu"
                 else {
                     "w_up": dense(next(k), (L, d, f), d),
                     "w_down": dense(next(k), (L, f, d), f),
                 }
             ),
-        },
+        }
+        if cfg.qk_norm:
+            qn, kn = (hd, hd) if cfg.qk_norm_per_head else (nh * hd, nkv * hd)
+            out["attn"]["q_norm"] = {"scale": jnp.ones((L, qn), cfg.dtype)}
+            out["attn"]["k_norm"] = {"scale": jnp.ones((L, kn), cfg.dtype)}
+        if cfg.attn_gate:
+            out["attn"]["wg"] = dense(next(k2), (L, d, nh * hd), d)
+        if cfg.post_norms:
+            out["post_attn_norm"] = {"scale": jnp.ones((L, d), cfg.dtype)}
+            out["post_mlp_norm"] = {"scale": jnp.ones((L, d), cfg.dtype)}
+        if routed and cfg.router_score == "sigmoid":
+            # A trained bias is non-zero; zeros would hide the term from every
+            # check. At this scale it changes about a third of a token's experts.
+            out["mlp"]["router_bias"] = dense(next(k2), (L, E), 100.0, jnp.float32)
+        if routed and cfg.d_ff_shared:
+            out["mlp"]["shared"] = swiglu(k2, L, cfg.d_ff_shared)
+        return out
+
+    nd = cfg.n_dense_layers
+    params = {
+        "embed": {"embedding": dense(next(k), (v, d), d)},
+        "blocks": blocks(k, cfg.n_layers - nd, bool(E), cfg.d_ff),
         "final_norm": {"scale": jnp.ones((d,), cfg.dtype)},
     }
-    if cfg.qk_norm:
-        params["blocks"]["attn"]["q_norm"] = {"scale": jnp.ones((L, nh * hd), cfg.dtype)}
-        params["blocks"]["attn"]["k_norm"] = {"scale": jnp.ones((L, nkv * hd), cfg.dtype)}
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(k), (d, v), d)
+    if nd:
+        params["dense_blocks"] = blocks(k2, nd, False, cfg.d_ff_dense)
     return params
 
 
@@ -277,7 +336,96 @@ def apply_rope(x, cos, sin, cfg: Optional[TransformerConfig] = None):
     return out.astype(x.dtype)
 
 
-def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
+# A layer without a window, where windows ride a scan as data: every key is
+# within reach (positions are int32 and far below it).
+NO_WINDOW = 1 << 30
+
+
+def _per_layer(cfg: TransformerConfig, first: int, n: int):
+    """(windows [n] int32, rope switches [n] bool) of layers [first, first +
+    n), the values that ride a group's scan beside its stacked weights; None
+    for what the config does not vary (nothing rides, the body is as before)."""
+    windows = rope = None
+    if cfg.windows:
+        w = jnp.asarray(cfg.windows[first:first + n], jnp.int32)
+        windows = jnp.where(w > 0, w, NO_WINDOW)
+    if cfg.rope_layers:
+        rope = jnp.asarray(cfg.rope_layers[first:first + n], bool)
+    return windows, rope
+
+
+def _window_scope(window):
+    """The `attn.window` scope around a paged attention call of a layer whose
+    window rides the scan; nothing for a config without windows."""
+    return jax.named_scope("attn.window") if window is not None else contextlib.nullcontext()
+
+
+def _rope_switch(cos, sin, rope_on):
+    """The rope tables of one layer: as given, or the identity rotation
+    where the layer's switch (a traced bool) is off."""
+    if rope_on is None:
+        return cos, sin
+    return jnp.where(rope_on, cos, 1.0), jnp.where(rope_on, sin, 0.0)
+
+
+def _layer_groups(params: PyTree, cfg: TransformerConfig):
+    """The stack as groups of alike layers, in order: (stacked weights, first
+    absolute layer, count). Each group is one scan through `_block`."""
+    nd = cfg.n_dense_layers
+    groups = [(params["dense_blocks"], 0, nd)] if nd else []
+    return groups + [(params["blocks"], nd, cfg.n_layers - nd)]
+
+
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+# Rows a group of XLA's grouped matmul pads to on the chip (`ragged_dot_tiling`
+# 512 x 512 x 512): a step of fewer rows does less work multiplying every
+# expert by every row (rows x E) than the grouped product's padding does
+# (a tile of 512 a touched expert); from 512 rows on the grouped one wins.
+GROUPED_TILE_ROWS = 512
+
+
+def _experts_in_place(blocks: PyTree):
+    """A group's stacked weights as (what rides its layer scan, the experts'
+    stack): a routed group's expert matrices `{name: [layers, E, ., .]}` stay
+    out of the scan's xs (as xs every step copies a layer's slice out of the
+    stack), and the serving steps hand `_block` the stack with the layer's
+    place in it (`experts=(stack, index)`), which `_routed_ffn` reads where
+    it lies. None for a dense group. Training scans the experts like every
+    other weight (its backward wants a layer's gradient, not a stack's)."""
+    mlp = blocks["mlp"]
+    if "router" not in mlp:
+        return blocks, None
+    riding = dict(blocks, mlp={name: w for name, w in mlp.items() if name not in EXPERT_WEIGHTS})
+    return riding, {name: mlp[name] for name in EXPERT_WEIGHTS}
+
+
+def _window_attention(q, k, v, window):
+    """Causal attention of a whole sequence in which query i sees key j iff
+    0 <= i - j < window (a traced scalar): the masked plain expression,
+    float32 softmax. q [b, s, h, d], k / v [b, s, kv, d]."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, rep, axis=2) if rep > 1 else t for t in (k, v))
+    s = q.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) / math.sqrt(q.shape[-1])
+    back = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    scores = jnp.where((back >= 0) & (back < window), scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh], window=None):
+    if window is not None:
+        # The flash, ring and ulysses kernels know `causal` only: a window
+        # layer that attended to everything would be silently another model.
+        if cfg.attn_impl != "naive":
+            raise ValueError(
+                f"attn_impl={cfg.attn_impl!r} computes no attention window; a config with `windows` "
+                "runs its whole-sequence forward with attn_impl='naive' (serving pages through ops/paged_attention.py)"
+            )
+        with jax.named_scope("attn.window"):
+            return _window_attention(q, k, v, window)
     if cfg.attn_impl == "full":
         # Fused pallas kernel (handles GQA internally; falls back to the
         # unfused path for untileable shapes). With a tensor axis in the
@@ -330,8 +478,11 @@ def _qkv(h, ap, cfg: TransformerConfig):
     k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
     if cfg.qk_norm:
-        # over the whole projection, before the split into heads (OLMoE)
+        # over the whole projection, before the split into heads (OLMoE), or
+        # over each head's dims with one scale for all heads (afmoe)
         with jax.named_scope("attn.qk_norm"):
+            if cfg.qk_norm_per_head:
+                q, k = q.reshape(b, s, cfg.n_heads, hd), k.reshape(b, s, cfg.n_kv_heads, hd)
             q = rms_norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
             k = rms_norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
     return (
@@ -341,12 +492,13 @@ def _qkv(h, ap, cfg: TransformerConfig):
     )
 
 
-def _ffn(h, mp, cfg: TransformerConfig):
+def _ffn(h, mp, cfg: TransformerConfig, experts=None):
     """The block's feed-forward, h [b, s, d] -> [b, s, d]: the one copy that
     the train layer, prefill and decode share. Dense (SwiGLU or gelu) or
-    routed by cfg.n_experts."""
-    if cfg.n_experts:
-        return _routed_ffn(h, mp, cfg)
+    routed where the layer's weights hold a router (a routed model's leading
+    dense layers do not); `experts` as `_routed_ffn` takes it."""
+    if "router" in mp:
+        return _routed_ffn(h, mp, cfg, experts=experts)
     up = jnp.einsum("bsd,df->bsf", h, mp["w_up"], preferred_element_type=jnp.float32)
     if cfg.mlp_act == "swiglu":
         gate = jnp.einsum(
@@ -361,16 +513,23 @@ def _ffn(h, mp, cfg: TransformerConfig):
     ).astype(cfg.dtype)
 
 
-def _router_probs(x, router):
-    """x [n, d] -> the router's probabilities over all experts [n, E]; matmul
-    and softmax in float32 (and so is the top-k that reads them)."""
+def _router_probs(x, mp, cfg: TransformerConfig):
+    """x [n, d] -> (the router's score of every expert [n, E], what the top-k
+    ranks [n, E]); matmul and scores in float32 (and so is the top-k that
+    reads them). Softmax: probabilities, ranked as they are. Sigmoid:
+    independent scores, ranked with the per-expert bias added, which selects
+    and never weighs."""
     logits = jnp.einsum(
         "nd,de->ne",
         x.astype(jnp.float32),
-        router.astype(jnp.float32),
+        mp["router"].astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    return jax.nn.softmax(logits, axis=-1)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        return scores, scores + mp["router_bias"].astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return probs, probs
 
 
 def _tokens_per_expert(experts, n_experts: int):
@@ -378,23 +537,72 @@ def _tokens_per_expert(experts, n_experts: int):
     return jnp.sum(experts.reshape(-1, 1) == jnp.arange(n_experts)[None, :], axis=0, dtype=jnp.int32)
 
 
-def _routed_ffn(h, mp, cfg: TransformerConfig):
+def _every_expert_ffn(x, experts, top_e, top_p, cfg: TransformerConfig):
+    """The routed experts' sum over few rows, x [n, d] -> [n, d] (a decode
+    batch, a prefill chunk; `experts` one layer's {name: [E, ., .]}): every
+    expert multiplies all n tokens (one batched matmul a projection) and a token's
+    result is the sum under the router's weights, which are exactly 0 for the
+    experts it did not choose. The same numbers as the grouped path up to the
+    order of a float32 sum. Why: a grouped matmul tiles 512 rows a group, so
+    at a handful of rows an expert it is bound by the padding's products and
+    its time follows how many experts the rows happened to touch; this reads
+    each expert's matrices once whatever the routing, n * E rows of products
+    where the grouped one pays 512 * the touched experts."""
+    E = cfg.n_experts
+    w_gate, w_up, w_down = (experts[name] for name in EXPERT_WEIGHTS)
+    with jax.named_scope("moe.experts"):
+        # E is a batch dimension of both operands: as a free dimension of the
+        # weights alone the product is x @ W[d, e * f], and the compiler copies
+        # the whole stack into that layout. Results in the parameters' type, as
+        # the grouped matmuls give them.
+        xe = jnp.broadcast_to(x, (E, *x.shape))
+        gate = jnp.einsum("end,edf->enf", xe, w_gate, preferred_element_type=cfg.dtype)
+        up = jnp.einsum("end,edf->enf", xe, w_up, preferred_element_type=cfg.dtype)
+        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
+        ys = jnp.einsum("enf,efd->end", act, w_down, preferred_element_type=cfg.dtype)
+    with jax.named_scope("moe.combine"):
+        weights = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32) * top_p[..., None].astype(jnp.float32), axis=1)  # [n, E]
+        return jnp.einsum("ne,end->nd", weights, ys.astype(jnp.float32)).astype(cfg.dtype)
+
+
+def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=None):
     """Dropless top-k mixture of SwiGLU experts. Every (token, chosen expert)
     pair is one row: rows are sorted by expert, each expert multiplies its
     own contiguous group (lax.ragged_dot: no capacity, no padding, n * k
     rows whatever the imbalance), and the rows go back to token order under
-    the router's weights. Both row movements' backward gathers (ops/moe_rows)."""
+    the router's weights. Both row movements' backward gathers (ops/moe_rows).
+    A shared expert (cfg.d_ff_shared) adds its SwiGLU of every token.
+    `experts` (the serving steps: `_experts_in_place`) is (the group's stack
+    {name: [layers, E, ., .]}, this layer's index in it) in place of the
+    layer's own matrices in `mp`; such a step multiplies every expert by
+    every row (`_every_expert_ffn`) while it has fewer rows than one group
+    of the grouped product pads to (GROUPED_TILE_ROWS). Returns out
+    [b, s, d], and with `counts` the rows each expert took [E] beside it."""
     from ..ops.moe_rows import combine_rows, dispatch_rows
 
     b, s, d = h.shape
     n, k, E = b * s, cfg.n_experts_per_tok, cfg.n_experts
     x = h.reshape(n, d)
     with jax.named_scope("moe.router"):
-        probs = _ckpt(_router_probs(x, mp["router"]), "moe_route")
-        top_e = _ckpt(lax.top_k(probs, k)[1], "moe_route")  # [n, k], most probable first
+        probs, ranked = _router_probs(x, mp, cfg)
+        probs = _ckpt(probs, "moe_route")
+        top_e = _ckpt(lax.top_k(ranked, k)[1], "moe_route")  # [n, k], most probable first
         top_p = jnp.take_along_axis(probs, top_e, axis=-1)
         if cfg.norm_topk_prob:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            top_p = top_p / (total + 1e-20 if cfg.router_score == "sigmoid" else total)
+        if cfg.route_scale != 1.0:
+            top_p = top_p * cfg.route_scale
+    if experts is not None:
+        stack, index = experts
+        layer = {name: lax.dynamic_index_in_dim(stack[name], index, 0, keepdims=False) for name in EXPERT_WEIGHTS}
+        if n < GROUPED_TILE_ROWS:
+            out = _every_expert_ffn(x, layer, top_e, top_p, cfg).reshape(b, s, d)
+            if "shared" in mp:
+                with jax.named_scope("moe.shared"):
+                    out = out + _ffn(h, mp["shared"], cfg)
+            return (out, _tokens_per_expert(top_e, E)) if counts else out
+        mp = dict(mp, **layer)
     with jax.named_scope("moe.dispatch"):
         flat_e = top_e.reshape(n * k)
         order = _ckpt(jnp.argsort(flat_e), "moe_route")  # row r of the sorted is pair order[r]
@@ -411,20 +619,27 @@ def _routed_ffn(h, mp, cfg: TransformerConfig):
         act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
         ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
     with jax.named_scope("moe.combine"):
-        return combine_rows(ys, top_p, order, inverse).reshape(b, s, d)
+        out = combine_rows(ys, top_p, order, inverse).reshape(b, s, d)
+    if "shared" in mp:
+        with jax.named_scope("moe.shared"):
+            out = out + _ffn(h, mp["shared"], cfg)
+    return (out, group_sizes) if counts else out
 
 
-def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: bool = False):
+def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str = "", experts=None):
     """THE transformer block, x [b, s, d] -> [b, s, d]: norm, q/k/v, rope,
     attention, output projection, residual, norm, feed-forward, residual.
     What differs between training, prefill and decode is how q attends,
     and the caller passes that: `attend(q, k, v) -> (o [b, s, n_heads,
     head_dim], kept)`, with q and k after rope. `kept` is whatever the
     caller wants back (the K/V pool it wrote k and v into; None in
-    training). Returns (out, kept), and what the router did with this
-    layer's input as a third if `stats`. The _ckpt names are the save
-    frontier of remat_policy="hot"; outside jax.checkpoint they are the
-    identity."""
+    training). Returns (out, kept), and as a third what the router did with
+    this layer's input if `stats` is "route" (`_route_stats`), or the rows
+    each expert took [E] if it is "experts" (None from a dense FFN).
+    `experts`: a routed layer's expert matrices where the caller keeps them
+    out of `layer_params` (`_experts_in_place`), as `_routed_ffn` takes them. The
+    _ckpt names are the save frontier of remat_policy="hot"; outside
+    jax.checkpoint they are the identity."""
     b, s, d = x.shape
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
@@ -435,12 +650,18 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: boo
     v = _ckpt(v, "v_bf16")
     o, kept = attend(q, k, v)
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
+    if cfg.attn_gate:
+        with jax.named_scope("attn.gate"):
+            gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
+            o = (o * jax.nn.sigmoid(gate)).astype(cfg.dtype)
     attn_out = _ckpt(
         jnp.einsum(
             "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
         ).astype(cfg.dtype),
         "attn_out_bf16",
     )
+    if cfg.post_norms:
+        attn_out = _norm(attn_out, layer_params["post_attn_norm"]["scale"], cfg)
 
     # Parallel block (GPT-J): MLP reads the SAME pre-norm as attention and
     # both sum into the residual; sequential (llama) re-norms after attn.
@@ -450,17 +671,29 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: boo
         x = x + attn_out
         mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
     mlp_in = _ckpt(mlp_in, "mlp_in_bf16")
-    mlp_out = _ffn(mlp_in, mp, cfg)
+    if stats == "experts" and "router" in mp:  # forward_decode: the experts this step's rows chose
+        mlp_out, rows_per_expert = _routed_ffn(mlp_in, mp, cfg, counts=True, experts=experts)
+    else:
+        mlp_out, rows_per_expert = _ffn(mlp_in, mp, cfg, experts), None
+    if cfg.post_norms:
+        mlp_out = _norm(mlp_out, layer_params["post_mlp_norm"]["scale"], cfg)
     out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-    if stats:  # routing_stats: what the router did with this layer's input
-        return out, kept, _route_stats(mlp_in.reshape(b * s, d), mp["router"], cfg)
+    if stats == "route":  # routing_stats: what the router did with this layer's input
+        return out, kept, _route_stats(mlp_in.reshape(b * s, d), mp, cfg)
+    if stats == "experts":
+        return out, kept, rows_per_expert
     return out, kept
 
 
-def _attend_whole(cfg: TransformerConfig, mesh: Optional[Mesh]):
+def _attend_whole(cfg: TransformerConfig, mesh: Optional[Mesh], window=None):
     """The attention strategy of a whole sequence that keeps nothing:
     training, `forward`, `routing_stats`."""
-    return lambda q, k, v: (_attention(q, k, v, cfg, mesh), None)
+    return lambda q, k, v: (_attention(q, k, v, cfg, mesh, window), None)
+
+
+def _embed(params: PyTree, tokens, cfg: TransformerConfig):
+    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    return x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype) if cfg.embed_scale else x
 
 
 def _logits(params: PyTree, x):
@@ -471,9 +704,9 @@ def _logits(params: PyTree, x):
     return jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
 
 
-def _route_stats(x, router, cfg: TransformerConfig):
+def _route_stats(x, mp, cfg: TransformerConfig):
     k = cfg.n_experts_per_tok
-    ranked, experts = lax.top_k(_router_probs(x, router), k + 1)
+    ranked, experts = lax.top_k(_router_probs(x, mp, cfg)[1], k + 1)
     return {
         "experts": experts[:, :k],
         "tokens_per_expert": _tokens_per_expert(experts[:, :k], cfg.n_experts),
@@ -490,9 +723,12 @@ def forward_hidden(
     """tokens [batch, seq] -> final-norm hidden states [batch, seq, d]."""
     b, s = tokens.shape
     cos, sin = rope_tables(cfg, s)
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    x = _embed(params, tokens, cfg)
 
-    body = partial(_block, cfg=cfg, cos=cos, sin=sin, attend=_attend_whole(cfg, mesh))
+    def body(x, xs):
+        window, rope_on, layer_params = xs
+        return _block(x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), _attend_whole(cfg, mesh, window))
+
     if cfg.remat:
         if cfg.remat_policy == "dots":
             policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
@@ -518,7 +754,8 @@ def forward_hidden(
             policy = None
         body = jax.checkpoint(body, policy=policy)
 
-    x, _ = lax.scan(body, x, params["blocks"])
+    for blocks, first, n in _layer_groups(params, cfg):
+        x, _ = lax.scan(body, x, (*_per_layer(cfg, first, n), blocks))
     return _norm(x, params["final_norm"]["scale"], cfg)
 
 
@@ -566,13 +803,21 @@ def routing_stats(params: PyTree, tokens: jax.Array, cfg: TransformerConfig) -> 
     dropped); gap [L, batch*seq] between the last probability a token took
     and the first it left out (how close each token is to another choice)."""
     cos, sin = rope_tables(cfg, tokens.shape[1])
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    x = _embed(params, tokens, cfg)
 
-    def scan_step(x, layer_params):
-        out, _, route = _block(x, layer_params, cfg, cos, sin, _attend_whole(cfg, None), stats=True)
+    def scan_step(x, xs):
+        window, rope_on, layer_params = xs
+        out, _, route = _block(
+            x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), _attend_whole(cfg, None, window), stats="route"
+        )
         return out, route
 
-    return lax.scan(scan_step, x, params["blocks"])[1]
+    def plain_step(x, xs):  # the leading dense layers route nothing
+        return _block(x, xs[2], cfg, *_rope_switch(cos, sin, xs[1]), _attend_whole(cfg, None, xs[0]))[0], None
+
+    for blocks, first, n in _layer_groups(params, cfg):
+        x, route = lax.scan(scan_step if "router" in blocks["mlp"] else plain_step, x, (*_per_layer(cfg, first, n), blocks))
+    return route
 
 
 def build_train_step(
@@ -650,22 +895,25 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     flash kernel skips fully-masked blocks, so charging full s^2 would
     inflate MFU by the skipped half. Per token per layer: QK^T + PV =
     2 matmuls x 2 MAC-FLOPs x (seq/2) x d_model forward, x3 for fwd+bwd.
-    A routed FFN counts the router and the n_experts_per_tok experts a
-    token passes through, not the experts it leaves alone."""
+    A routed FFN counts the router, the shared expert and the
+    n_experts_per_tok experts a token passes through, not the experts it
+    leaves alone; a window is not taken off the attention."""
     ffn = 3 * cfg.d_model * cfg.d_ff
     if cfg.n_experts:
-        ffn = ffn * cfg.n_experts_per_tok + cfg.d_model * cfg.n_experts
+        ffn = ffn * cfg.n_experts_per_tok + cfg.d_model * (cfg.n_experts + 3 * cfg.d_ff_shared)
+    nd = cfg.n_dense_layers
     n_params = (
         cfg.vocab_size * cfg.d_model
         + cfg.n_layers
         * (
-            2 * cfg.d_model * cfg.n_heads * cfg.head_dim
+            (3 if cfg.attn_gate else 2) * cfg.d_model * cfg.n_heads * cfg.head_dim
             + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
-            + ffn
         )
+        + (cfg.n_layers - nd) * ffn
+        + nd * 3 * cfg.d_model * cfg.d_ff_dense
         + (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size)
     )
-    attn = 12 * cfg.n_layers * cfg.d_model * (seq_len / 2)
+    attn = 12 * cfg.n_layers * cfg.n_heads * cfg.head_dim * (seq_len / 2)
     return 6.0 * n_params + attn
 
 
@@ -780,7 +1028,7 @@ def forward_prefill(
         c0 = c * C
         cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
         sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
-        x = jnp.take(params["embed"]["embedding"], lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), axis=0)
+        x = _embed(params, lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), cfg)
         # Whole pages are written (a page is one contiguous tile of the
         # pool; a token row cuts through 32 of them): every page of the
         # chunk that holds a position in [write_from, length). The rest of
@@ -793,24 +1041,31 @@ def forward_prefill(
 
         # The pool rides both loops as a carry, written in place (as in
         # forward_decode): no copy of it is made a layer or a chunk.
-        def scan_step(carry, inputs):
+        def scan_step(stack, first_layer, carry, inputs):
             x, kp, vp = carry
-            layer, layer_params = inputs
+            layer, window, rope_on, layer_params = inputs
 
             def attend(q, k, v):
                 kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
                 vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
                 # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
-                if use_kernel:
-                    o = paged_prefill_attention(q[0], kp_, vp_, layer, block_table, c0, length, n_kv_heads=cfg.n_kv_heads)
-                else:
-                    o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads)
+                with _window_scope(window):
+                    if use_kernel:
+                        o = paged_prefill_attention(
+                            q[0], kp_, vp_, layer, block_table, c0, length, n_kv_heads=cfg.n_kv_heads, window=window
+                        )
+                    else:
+                        o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window)
                 return o[None].astype(cfg.dtype), (kp_, vp_)
 
-            x, (kp, vp) = _block(x, layer_params, cfg, cos, sin, attend)
+            experts = None if stack is None else (stack, layer - first_layer)
+            x, (kp, vp) = _block(x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend, experts=experts)
             return (x, kp, vp), None
 
-        (x, kp, vp), _ = lax.scan(scan_step, (x, kp, vp), (jnp.arange(cfg.n_layers), params["blocks"]))
+        for blocks, first_layer, n in _layer_groups(params, cfg):
+            riding, stack = _experts_in_place(blocks)
+            xs = (jnp.arange(first_layer, first_layer + n), *_per_layer(cfg, first_layer, n), riding)
+            (x, kp, vp), _ = lax.scan(partial(scan_step, stack, first_layer), (x, kp, vp), xs)
         # the last position's row, if this is its chunk (the final one is)
         return kp, vp, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0)
 
@@ -821,12 +1076,13 @@ def forward_prefill(
     return _logits(params, h_last), {"k": k_new, "v": v_new}
 
 
-def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int):
+def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int, window=None):
     """The plain XLA expression of prefill's chunk attention: gathers the
     WHOLE block table [P] out of one layer's pages kp / vp
     [pages, page_tokens, n_kv_heads * head_dim], casts it to float32 and
     softmaxes each row of q [C, n_heads, head_dim] (row i is position
-    start + i) over the `P * T`-wide row under the causal mask. The parity
+    start + i) over the `P * T`-wide row under the causal mask, and under
+    `window` (a scalar) only the last `window` positions of it. The parity
     reference of ops/paged_attention.py's paged_prefill_attention and the
     path for shapes that kernel cannot tile (the tiny CPU widths)."""
     C, H, hd = q.shape
@@ -840,16 +1096,19 @@ def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: in
         "qhd,shd->hqs", q.astype(jnp.float32), kb.astype(jnp.float32)
     ) / math.sqrt(hd)
     seen = jnp.arange(P * T)[None, :] <= start + jnp.arange(C)[:, None]  # [C, P*T]
+    if window is not None:
+        seen &= jnp.arange(P * T)[None, :] > start + jnp.arange(C)[:, None] - window
     scores = jnp.where(seen[None], scores, -jnp.inf)
     attn = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("hqs,shd->qhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
 
 
-def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int):
+def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int, window=None):
     """The plain XLA expression of decode attention: gathers every slot's
     WHOLE block table out of one layer's pages kp / vp
     [pages, page_tokens, n_kv_heads * head_dim], casts it to float32 and
-    softmaxes the `P * T`-wide row under the length mask. q [B, n_heads,
+    softmaxes the `P * T`-wide row under the length mask, and under `window`
+    (a scalar) only its last `window` positions. q [B, n_heads,
     head_dim], lengths [B] (>= 1). The parity reference of
     ops/paged_attention.py and the path for shapes that kernel cannot tile
     (the tiny CPU widths); its traffic is the table, not what is live."""
@@ -864,6 +1123,8 @@ def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int):
         "bhd,bshd->bhs", q.astype(jnp.float32), kb.astype(jnp.float32)
     ) / math.sqrt(hd)
     kv_mask = jnp.arange(P * T)[None, :] < lengths[:, None]  # [B, P*T]
+    if window is not None:
+        kv_mask &= jnp.arange(P * T)[None, :] >= lengths[:, None] - window
     scores = jnp.where(kv_mask[:, None, :], scores, -jnp.inf)
     attn = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhs,bshd->bhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
@@ -885,6 +1146,7 @@ def forward_decode(
     cfg: TransformerConfig,
     kv_pages: Dict[str, jax.Array],
     block_tables: jax.Array,
+    stats: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step for the whole slot batch, paged attention.
 
@@ -896,6 +1158,9 @@ def forward_decode(
     [0, pos], returns (logits [B, vocab] fp32, updated kv_pages). Inactive
     slots write to the trash page and produce garbage logits the scheduler
     ignores. Shapes are static in B/P/N: one jit serves every batch mix.
+    With `stats`, a third: {"experts_touched": int32 scalar}, the distinct
+    experts that the step's B rows chose, summed over the routed layers
+    (each is a matrix triple the step has to read); 0 for a dense model.
     """
     from ..ops.paged_attention import paged_attention
 
@@ -909,7 +1174,7 @@ def forward_decode(
     cos = jnp.take(cos_t, pos, axis=0)[:, None, :]  # [B, 1, rd/2]: each row its own position
     sin = jnp.take(sin_t, pos, axis=0)[:, None, :]
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)[:, None, :]  # [B,1,d]
+    x = _embed(params, tokens, cfg)[:, None, :]  # [B,1,d]
     rows = jnp.arange(B)
     dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
     dest_slot = pos % T
@@ -920,27 +1185,38 @@ def forward_decode(
     # own slice in place and the kernel reads the pool where it lies. As
     # xs/ys every step would copy the whole pool out of the stacked array
     # and back in.
-    def scan_step(carry, inputs):
+    def scan_step(stack, first, carry, inputs):
         x, kp, vp = carry
-        layer, layer_params = inputs
+        layer, window, rope_on, layer_params = inputs
 
         def attend(q, k, v):
             kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
             vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
             # Attend AFTER the append so the new position attends to itself.
-            if use_kernel:
-                o = paged_attention(q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads)
-            else:
-                o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads)
+            with _window_scope(window):
+                if use_kernel:
+                    o = paged_attention(
+                        q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window
+                    )
+                else:
+                    o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window)
             return o.astype(cfg.dtype), (kp_, vp_)
 
-        x, (kp, vp) = _block(x, layer_params, cfg, cos, sin, attend)
-        return (x, kp, vp), None
+        x, (kp, vp), *rows_per_expert = _block(
+            x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend, stats="experts" if stats else "",
+            experts=None if stack is None else (stack, layer - first),
+        )
+        return (x, kp, vp), (rows_per_expert[0] if stats else None)
 
-    (x, k_new, v_new), _ = lax.scan(
-        scan_step,
-        (x, kv_pages["k"], kv_pages["v"]),
-        (jnp.arange(cfg.n_layers), params["blocks"]),
-    )
+    carry, touched = (x, kv_pages["k"], kv_pages["v"]), jnp.int32(0)
+    for blocks, first, n in _layer_groups(params, cfg):
+        riding, stack = _experts_in_place(blocks)
+        carry, rows_per_expert = lax.scan(
+            partial(scan_step, stack, first), carry, (jnp.arange(first, first + n), *_per_layer(cfg, first, n), riding)
+        )
+        if rows_per_expert is not None:  # [n, E] of a routed group
+            touched = touched + jnp.sum(rows_per_expert > 0, dtype=jnp.int32)
+    x, k_new, v_new = carry
     x = _norm(x, params["final_norm"]["scale"], cfg)
-    return _logits(params, x[:, 0]), {"k": k_new, "v": v_new}
+    out = _logits(params, x[:, 0]), {"k": k_new, "v": v_new}
+    return (*out, {"experts_touched": touched}) if stats else out

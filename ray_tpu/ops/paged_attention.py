@@ -114,8 +114,16 @@ def pick_pages_per_block(page_tokens: int, row_width: int, max_pages: int, dtype
     return max(1, min(max_pages, BLOCK_BYTES // page_bytes))
 
 
-def _kernel(
-    layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
+def _kernel(*refs, windowed: bool, **static):
+    """The decode kernel's two signatures: with a window, its scalar is the
+    last of the scalar-prefetch operands."""
+    (layer_ref, lengths_ref, tables_ref), rest = refs[:3], refs[3:]
+    window_ref, rest = (rest[0], rest[1:]) if windowed else (None, rest)
+    _decode_kernel(layer_ref, lengths_ref, tables_ref, window_ref, *rest, **static)
+
+
+def _decode_kernel(
+    layer_ref, lengths_ref, tables_ref, window_ref,  # scalar prefetch (SMEM); window_ref None: no window
     q_ref, k_hbm, v_hbm,  # [1, H, hd] VMEM; [L, N, T, F] HBM, twice
     o_ref,  # [1, H, hd]
     k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
@@ -132,13 +140,21 @@ def _kernel(
     # index out of range must not become a DMA outside the pool.
     length = jnp.minimum(lengths_ref[b], max_pages * T)
     n_pages = (length + T - 1) // T
-    n_blocks = (n_pages + ppb - 1) // ppb
+    # Under a window the walk starts at the page that holds the first visible
+    # key, `seen_from`: the pages below it are neither copied nor multiplied.
+    if window_ref is None:
+        seen_from = first_page = None
+        n_blocks = (n_pages + ppb - 1) // ppb
+    else:
+        seen_from = jnp.maximum(length - window_ref[0], 0)
+        first_page = seen_from // T
+        n_blocks = (n_pages - first_page + ppb - 1) // ppb
     last_page = k_hbm.shape[1] - 1
 
     def copies(blk, slot, act):
         """Starts or awaits the DMAs of block `blk`'s live pages."""
         for j in range(ppb):
-            pg = blk * ppb + j
+            pg = blk * ppb + j if first_page is None else first_page + blk * ppb + j
 
             @pl.when(pg < n_pages)
             def _():
@@ -168,7 +184,7 @@ def _kernel(
             copies(blk + 1, 1 - slot, lambda c: c.start())
 
         copies(blk, slot, lambda c: c.wait())
-        first = blk * bk
+        first = blk * bk if first_page is None else first_page * T + blk * bk
 
         # Only a slot's last block holds rows past its length (stale VMEM or
         # the rest of a page). p is 0 there, but 0 * NaN is NaN: zero V.
@@ -183,7 +199,7 @@ def _kernel(
             precision=exact, preferred_element_type=jnp.float32,
         ) * scale  # [H, bk]
         tok = first + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(tok < length, s, NEG_INF)
+        s = jnp.where(tok < length if seen_from is None else (tok < length) & (tok >= seen_from), s, NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -215,6 +231,7 @@ def paged_attention(
     lengths: jax.Array,
     *,
     n_kv_heads: int,
+    window: Optional[jax.Array] = None,
     pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
@@ -224,7 +241,10 @@ def paged_attention(
     [layers, pages, page_tokens, n_kv_heads * head_dim], read at `layer`
     (int32 scalar) and never copied; block_tables [B, P] int32 page indices;
     lengths [B] int32, positions [0, lengths[b]) are attended and 0 means an
-    inactive slot (output zeros). Returns [B, n_heads, head_dim] in q's dtype.
+    inactive slot (output zeros). `window` (int32 scalar, may be traced; None:
+    no window, the kernel as it was): only positions [lengths[b] - window,
+    lengths[b]) are attended, and the pages wholly below them are not read.
+    Returns [B, n_heads, head_dim] in q's dtype.
     """
     B, H, hd = q.shape
     _, _, T, F = k_pages.shape
@@ -242,13 +262,16 @@ def paged_attention(
         interpret = _auto_interpret()
     bk = pages_per_block * T
     kern = functools.partial(
-        _kernel, scale=1.0 / math.sqrt(hd), n_kv_heads=n_kv_heads, page_tokens=T,
+        _kernel, windowed=window is not None, scale=1.0 / math.sqrt(hd), n_kv_heads=n_kv_heads, page_tokens=T,
         pages_per_block=pages_per_block, max_pages=P,
     )
+    scalars = [jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1)]
+    if window is not None:
+        scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
@@ -269,12 +292,7 @@ def paged_attention(
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32).reshape(-1),
-        q.astype(k_pages.dtype), k_pages, v_pages,
-    )
+    )(*scalars, q.astype(k_pages.dtype), k_pages, v_pages)
 
 
 def largest_divisor(n: int, limit: int) -> int:
@@ -290,8 +308,15 @@ def pick_prefill_blocks(chunk_tokens: int, page_tokens: int, row_width: int, max
     return block_q, max(1, min(max_pages, tokens // page_tokens))
 
 
-def _prefill_kernel(
-    layer_ref, start_ref, length_ref, table_ref,  # scalar prefetch (SMEM)
+def _prefill_kernel(*refs, windowed: bool, **static):
+    """The prefill kernel's two signatures, as `_kernel`."""
+    head, rest = refs[:4], refs[4:]
+    window_ref, rest = (rest[0], rest[1:]) if windowed else (None, rest)
+    _prefill_chunk_kernel(*head, window_ref, *rest, **static)
+
+
+def _prefill_chunk_kernel(
+    layer_ref, start_ref, length_ref, table_ref, window_ref,  # scalar prefetch (SMEM); window_ref None: no window
     q_ref, k_hbm, v_hbm,  # [bq, H * hd] VMEM; [L, N, T, F] HBM, twice
     o_ref,  # [bq, H * hd]
     k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
@@ -311,7 +336,15 @@ def _prefill_kernel(
     kv_end = jnp.minimum(q_first + bq, (length + T - 1) // T * T)
     kv_end = jnp.where(q_first < length, kv_end, 0)
     n_pages = (kv_end + T - 1) // T
-    n_blocks = (n_pages + ppb - 1) // ppb
+    # Under a window the walk starts at the page that holds the first key the
+    # block's FIRST row sees; each row masks what lies below its own reach.
+    if window_ref is None:
+        window = first_page = None
+        n_blocks = (n_pages + ppb - 1) // ppb
+    else:
+        window = window_ref[0]
+        first_page = jnp.maximum(q_first - window + 1, 0) // T
+        n_blocks = jnp.maximum(n_pages - first_page + ppb - 1, 0) // ppb
     last_page = k_hbm.shape[1] - 1
 
     def copies(blk, slot, act):
@@ -320,7 +353,7 @@ def _prefill_kernel(
         `pl.when`s, three times over, were most of the seconds it took to
         trace this kernel, once a bucket in every process that serves)."""
         def page_copy(j, _):
-            pg = blk * ppb + j
+            pg = blk * ppb + j if first_page is None else first_page + blk * ppb + j
 
             @pl.when(pg < n_pages)
             def _():
@@ -364,7 +397,7 @@ def _prefill_kernel(
             copies(blk + 1, 1 - slot, lambda c: c.start())
 
         copies(blk, slot, lambda c: c.wait())
-        first = blk * bk
+        first = blk * bk if first_page is None else first_page * T + blk * bk
 
         # Rows of the last block past kv_end are stale VMEM: p is 0 there,
         # but 0 * NaN is NaN, so V is zeroed (as the decode kernel does).
@@ -377,6 +410,11 @@ def _prefill_kernel(
         q_pos = q_first + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = first + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         seen = (k_pos <= q_pos) & (k_pos < kv_end)
+        if window is not None:
+            # A row whose keys in this block are all out of reach adds
+            # garbage at weight exp(NEG_INF - NEG_INF) = 1; the first block
+            # that holds a key it sees rescales that by exp(NEG_INF - max) = 0.
+            seen &= k_pos > q_pos - window
         # Heads in a loop, heads_unrolled of them a turn: unrolled, the
         # compiler overlaps one head's products with another's softmax; all
         # of them unrolled are seconds of program load in every process
@@ -414,6 +452,7 @@ def paged_prefill_attention(
     length: jax.Array,
     *,
     n_kv_heads: int,
+    window: Optional[jax.Array] = None,
     block_q: Optional[int] = None,
     pages_per_block: Optional[int] = None,
     heads_unrolled: Optional[int] = None,
@@ -427,8 +466,10 @@ def paged_prefill_attention(
     [P] int32, page j holds positions [j * page_tokens, (j + 1) *
     page_tokens), the chunk's own included; `length` the prompt's length:
     no page past its last is read, and blocks of rows wholly past it return
-    zeros. Row i attends over positions [0, start + i]. Returns [C, n_heads,
-    head_dim] in q's dtype.
+    zeros. Row i attends over positions [0, start + i], under `window` (int32
+    scalar, may be traced; None: no window, the kernel as it was) over the
+    last `window` of them, and the pages wholly below the reach of a block's
+    first row are not read. Returns [C, n_heads, head_dim] in q's dtype.
     """
     C, H, hd = q.shape
     _, _, T, F = k_pages.shape
@@ -452,15 +493,18 @@ def paged_prefill_attention(
     # q and o blocks double-buffered by the pipeline, K and V by hand, the
     # running max / sum / accumulator, and the scores of one head in flight.
     vmem = 4 * block_q * H * hd * item + 4 * bk * F * item + block_q * H * (2 * 128 + hd) * 4 + 4 * block_q * bk * 4
+    scalars = [jnp.asarray(x, jnp.int32).reshape(1) for x in (layer, start, length)] + [block_table.astype(jnp.int32)]
+    if window is not None:
+        scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
     kern = functools.partial(
-        _prefill_kernel, scale=1.0 / math.sqrt(hd), n_heads=H, n_kv_heads=n_kv_heads, page_tokens=T,
+        _prefill_kernel, windowed=window is not None, scale=1.0 / math.sqrt(hd), n_heads=H, n_kv_heads=n_kv_heads, page_tokens=T,
         pages_per_block=pages_per_block, max_pages=P,
         heads_unrolled=largest_divisor(H, heads_unrolled or PREFILL_HEADS_UNROLLED),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             grid=(C // block_q,),
             in_specs=[
                 pl.BlockSpec((block_q, H * hd), lambda i, *_: (i, 0)),
@@ -483,11 +527,5 @@ def paged_prefill_attention(
         ),
         interpret=interpret,
         name=PREFILL_KERNEL_NAME,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        jnp.asarray(start, jnp.int32).reshape(1),
-        jnp.asarray(length, jnp.int32).reshape(1),
-        block_table.astype(jnp.int32),
-        q.astype(k_pages.dtype).reshape(C, H * hd), k_pages, v_pages,
-    )
+    )(*scalars, q.astype(k_pages.dtype).reshape(C, H * hd), k_pages, v_pages)
     return out.reshape(C, H, hd)

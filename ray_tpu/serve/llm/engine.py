@@ -527,6 +527,12 @@ class InferenceEngine:
             pages = self._clk["decode.kv_pages"]
             pages["live"] += live_pages
             pages["table"] += len(self._slots) * self.model.max_pages_per_seq
+            # What the model says its router and windows did with the step
+            # (PagedLM's DecodeTokens); a model that says nothing adds no clock.
+            for name, counts in getattr(next_tokens, "counters", {}).items():
+                clk = self._clk.setdefault(name, dict.fromkeys(counts, 0))
+                for key, n in counts.items():
+                    clk[key] += n
             self._m_step.observe(step_ms)
             for seq in batch:
                 if seq.finished or seq.cancelled:
@@ -572,7 +578,12 @@ class InferenceEngine:
         padding, cached chunks not); decode.kv_pages: over the completed decode steps, the
         pages their live lengths cover (what a step must read) against
         slots x pages a sequence (what a step that gathers the block
-        tables reads); loop.s: wall time of the loop, loop.idle_s the part of
+        tables reads); decode_window (a model with attention windows only):
+        kv_read, the K/V positions the steps' live rows see, each layer
+        clipped to its window, against kv_live, layers x their lengths;
+        decode_experts (a routed model only): touched, the distinct experts
+        the steps' rows chose, summed over routed layers, of `held` in as many
+        `steps`; loop.s: wall time of the loop, loop.idle_s the part of
         it waiting with nothing to do. loop.s - idle_s - prefill.s -
         decode.s is the engine's own host time. A stage in progress counts
         up to now, so two calls bracket a window exactly."""

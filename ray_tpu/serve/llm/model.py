@@ -42,6 +42,18 @@ class PrefillToken(int):
         return self
 
 
+class DecodeTokens(list):
+    """What `PagedLM.decode` returns for a model with routed experts or
+    attention windows: the slots' next tokens, a list to every caller, which
+    also carries what the step's router and windows did, as counters the
+    engine adds up under `stats()["clocks"]` by name (`PrefillToken`'s way
+    through wrappers). A model with neither returns a plain list."""
+
+    def __init__(self, tokens, counters: Dict[str, Dict[str, int]]):
+        super().__init__(tokens)
+        self.counters = counters
+
+
 class StubModel:
     """Deterministic, JAX-free model for scheduler/chaos tests and the
     engine's disarmed-cost bench: next token = (last + 1) % vocab.
@@ -160,10 +172,13 @@ class PagedLM:
             cfg, tfm = self.cfg, self._tfm
 
             def step(params, tokens, positions, kv, block_tables):
-                logits, kv = tfm.forward_decode(
-                    params, tokens, positions, cfg, kv, block_tables
+                logits, kv, *stats = tfm.forward_decode(
+                    params, tokens, positions, cfg, kv, block_tables, stats=bool(cfg.n_experts)
                 )
-                return self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32), kv
+                out = self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32)
+                if stats:  # a routed model: the experts the step touched ride behind the tokens, one transfer
+                    out = self._jnp.concatenate([out, stats[0]["experts_touched"][None]])
+                return out, kv
 
             self._decode_jit = self._jax.jit(step, donate_argnums=self._donate((3,)))
         return self._decode_jit
@@ -268,7 +283,19 @@ class PagedLM:
                 bts[i, : len(row)] = np.asarray(row, dtype=np.int32)
             fn = self._get_decode()
         out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode")
-        return [int(t) for t in out]
+        tokens, cfg, counters = [int(t) for t in out[:B]], self.cfg, {}
+        if cfg.n_experts:
+            # Every row of the step is routed, the inactive slots' too.
+            routed_layers = cfg.n_layers - cfg.n_dense_layers
+            counters["decode_experts"] = {"touched": int(out[B]), "held": routed_layers * cfg.n_experts, "steps": 1}
+        if any(cfg.windows):
+            live = pos[pos >= 0].astype(np.int64) + 1  # each live row's K/V length
+            reach = np.asarray([w or self._tfm.NO_WINDOW for w in cfg.windows], np.int64)
+            counters["decode_window"] = {
+                "kv_read": int(np.minimum(live[None, :], reach[:, None]).sum()),
+                "kv_live": int(cfg.n_layers * live.sum()),
+            }
+        return DecodeTokens(tokens, counters) if counters else tokens
 
 
 def tiny_paged_lm(**kw) -> PagedLM:

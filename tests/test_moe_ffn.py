@@ -314,7 +314,7 @@ def plain_routed_ffn(h, mp, cfg):
     b, s, d = h.shape
     n, k = b * s, cfg.n_experts_per_tok
     x = h.reshape(n, d)
-    probs = tfm._router_probs(x, mp["router"])
+    probs = tfm._router_probs(x, mp, cfg)[0]
     top_e = jax.lax.top_k(probs, k)[1]
     top_p = jnp.take_along_axis(probs, top_e, axis=-1)
     if cfg.norm_topk_prob:

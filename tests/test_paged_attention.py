@@ -254,3 +254,95 @@ def test_prefill_kernel_tiling_does_not_change_the_result(block_q, ppb):
     q, kp, vp, bt = _prefill_inputs(jnp.float32, (8, 2), 4 * T, P * T)
     out, ref = _prefill_both(q, kp, vp, bt, 4 * T, P * T, 2, layer=0, block_q=block_q, pages_per_block=ppb)
     assert np.abs(out - ref).max() < 1e-5
+
+
+# ------------------------------------------------- attention windows
+#
+# A window layer sees the last `window` positions only: both kernels start
+# their page walk at the page that holds the first visible key and mask
+# inside it, and the gather expressions mask the whole row. name: window,
+# against contexts of up to P * T = 128 positions in 16-token pages.
+WINDOWS = {
+    "shorter_than_a_page": 5,
+    "edge_inside_a_page": 2 * T + 5,
+    "a_whole_number_of_pages": 3 * T,
+    "equal_to_the_longest_context": P * T,
+    "longer_than_every_context": P * T + 9,
+    "no_window_as_data": tfm.NO_WINDOW,
+}
+
+
+def _window_tol(dtype, vp, ref):
+    if dtype == jnp.float32:
+        return 1e-5
+    return 2.0 ** -9 * float(jnp.max(jnp.abs(vp.astype(jnp.float32)))) + 2.0 ** -8 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize("window", WINDOWS.values(), ids=WINDOWS.keys())
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_windowed_decode_kernel_matches_the_windowed_expression(dtype, window):
+    """Ragged lengths on both sides of the window; the window rides as a
+    traced scalar, as it does in forward_decode's layer scan."""
+    lengths = (P * T, 2 * T + 6, 3, 0)
+    q, kp, vp, bt, lens = _inputs(dtype, (8, 2), lengths)
+    out = jax.jit(lambda w: pa.paged_attention(q, kp, vp, 1, bt, lens, n_kv_heads=2, window=w, pages_per_block=2))(jnp.int32(window))
+    ref = tfm.paged_attention_gather(q, kp[1], vp[1], bt, jnp.maximum(lens, 1), 2, window=window)
+    out, ref, live = np.asarray(out, np.float32), np.asarray(ref, np.float32), np.asarray(lens) > 0
+    assert np.abs(out[live] - ref[live]).max() < _window_tol(dtype, vp, ref)
+    assert not out[~live].any()
+    if window >= P * T:  # a window that reaches past every context is no window
+        full = np.asarray(pa.paged_attention(q, kp, vp, 1, bt, lens, n_kv_heads=2, pages_per_block=2), np.float32)
+        np.testing.assert_array_equal(out, full)
+
+
+def test_windowed_decode_kernel_reads_no_page_below_the_window():
+    """Every page wholly below a slot's window is NaN: the same finite
+    numbers come out, so those pages were neither copied nor multiplied."""
+    window, lengths = 2 * T + 5, (P * T, 5 * T + 1, 3, 0)
+    q, kp, vp, bt, lens = _inputs(jnp.float32, (8, 2), lengths)
+    clean = pa.paged_attention(q, kp, vp, 1, bt, lens, n_kv_heads=2, window=window, pages_per_block=2)
+    below = np.zeros((N,), bool)
+    for b, n in enumerate(lengths):
+        below[np.asarray(bt[b, : max(0, n - window) // T])] = True
+    assert below.sum() == 5 + 2
+    poison = [jnp.where(jnp.asarray(below)[None, :, None, None], jnp.nan, x) for x in (kp, vp)]
+    out = pa.paged_attention(q, *poison, 1, bt, lens, n_kv_heads=2, window=window, pages_per_block=2)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
+PREFILL_WINDOW_CHUNKS = {  # (chunk rows, start, length): the cached part ends before, inside and beyond the window
+    "first_chunk": (2 * T, 0, 5 * T + 3),
+    "later_chunk_ending_inside_it": (2 * T, 4 * T, 5 * T + 3),
+    "last_chunk_of_a_full_table": (4 * T, 4 * T, P * T),
+}
+
+
+@pytest.mark.parametrize("chunk", PREFILL_WINDOW_CHUNKS.values(), ids=PREFILL_WINDOW_CHUNKS.keys())
+@pytest.mark.parametrize("window", WINDOWS.values(), ids=WINDOWS.keys())
+def test_windowed_prefill_kernel_matches_the_windowed_expression(window, chunk):
+    """Blocks of rows smaller than the window and larger than it (block_q 32
+    against windows of 5 to 137): a row whose first K/V block lies wholly
+    below its own reach must come out as if it had never seen it."""
+    C, start, length = chunk
+    q, kp, vp, bt = _prefill_inputs(jnp.float32, (8, 2), C, length)
+    out = jax.jit(lambda w: pa.paged_prefill_attention(
+        q, kp, vp, 1, bt, start, length, n_kv_heads=2, window=w, block_q=min(C, 2 * T), pages_per_block=1))(jnp.int32(window))
+    ref = tfm.paged_prefill_attention_gather(q, kp[1], vp[1], bt, start, 2, window=window)
+    rows = min(C, length - start)
+    assert np.abs(np.asarray(out)[:rows] - np.asarray(ref)[:rows]).max() < 1e-5
+    if window >= P * T:
+        full = pa.paged_prefill_attention(q, kp, vp, 1, bt, start, length, n_kv_heads=2, block_q=min(C, 2 * T), pages_per_block=1)
+        np.testing.assert_array_equal(np.asarray(out)[:rows], np.asarray(full)[:rows])
+
+
+def test_windowed_prefill_kernel_reads_no_page_below_the_window():
+    C, start, length, window = 2 * T, 6 * T, P * T, T + 3
+    q, kp, vp, bt = _prefill_inputs(jnp.float32, (8, 2), C, length)
+    kw = dict(n_kv_heads=2, window=window, block_q=T, pages_per_block=2)
+    clean = pa.paged_prefill_attention(q, kp, vp, 1, bt, start, length, **kw)
+    below = np.zeros((N,), bool)
+    below[np.asarray(bt[: (start - window + 1) // T])] = True  # below the reach of the chunk's first row
+    assert below.sum() == 4
+    poison = [jnp.where(jnp.asarray(below)[None, :, None, None], jnp.nan, x) for x in (kp, vp)]
+    out = pa.paged_prefill_attention(q, *poison, 1, bt, start, length, **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))

@@ -36,7 +36,7 @@ from ray_tpu.models import transformer as tfm
 
 TOLERANCE = 1e-4
 T = 8  # page tokens
-CONFIGS = ["mistral-7b-v0.3-L4", "deepseek-llm-7b-chat-L8", "olmoe-1b-7b-0125-L2"]
+CONFIGS = ["mistral-7b-v0.3-L4", "deepseek-llm-7b-chat-L8", "olmoe-1b-7b-0125-L2", "trinity-mini-L5"]
 
 
 def tiny(name, **changed):
@@ -101,9 +101,10 @@ def paged_logits(cfg, params, tokens, prompt_len):
     logits, pages = tfm.forward_prefill(params, padded, cfg, pages, table[:n_prompt_pages], jnp.int32(prompt_len), jnp.int32(0))
     out = [logits[0]]
     tables = jnp.stack([jnp.zeros_like(table), table])
+    # one executable for the six steps: eager, every call compiles its layer scans again
+    decode = jax.jit(lambda toks, positions, pages: tfm.forward_decode(params, toks, positions, cfg, pages, tables))
     for pos in range(prompt_len, tokens.shape[0]):
-        step, pages = tfm.forward_decode(
-            params, jnp.asarray([0, tokens[pos]], jnp.int32), jnp.asarray([-1, pos], jnp.int32), cfg, pages, tables)
+        step, pages = decode(jnp.asarray([0, tokens[pos]], jnp.int32), jnp.asarray([-1, pos], jnp.int32), pages)
         out.append(step[1])
     return jnp.stack(out)
 

@@ -4,8 +4,9 @@ bytes, prefill (`--prefill`) against its own operations.
     python3 tools/paged_attention_bench.py [--heads 32 --kv-heads 32] [--pages-per-block 4,8,16]
     python3 tools/paged_attention_bench.py --prefill [--layouts 32:32,32:8,16:16] [--chunks 256,512,1024] [--block-q 256,512]
 
-For batch 4 / 16 and live lengths 256 / 1 024 / 4 096 (every slot at that
-length, pages scattered over the pool), times `ops/paged_attention.py`
+For batch 4 / 16 and live lengths 256 / 1 024 / 4 096 (`--batches`, `--lengths`;
+every slot at that length, pages scattered over the pool; `--window` gives the
+kernel an attention window and counts the bytes it leaves), times `ops/paged_attention.py`
 (one layer, `--layers` calls inside one jit so that dispatch is not what is
 timed) and prints microseconds a call, the K/V bytes a call must read and
 the share of the chip's HBM peak that is (benchmarks/lib/peaks.json, keyed by
@@ -40,7 +41,7 @@ def err_and_us(run, args, ref, reps, calls_a_jit):
     import jax.numpy as jnp
 
     out = run(*args)
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
+    err = float(jnp.max(jnp.abs(out[: ref.shape[0]].astype(jnp.float32) - ref)))
     jax.block_until_ready(out)
     t0 = time.perf_counter()
     for _ in range(reps):
@@ -59,6 +60,9 @@ def main(argv) -> int:
     ap.add_argument("--pool-pages", type=int, default=1024)
     ap.add_argument("--layers", type=int, default=8)
     ap.add_argument("--pages-per-block", default="")
+    ap.add_argument("--batches", default="4,16", help="decode: slots a call")
+    ap.add_argument("--lengths", default="256,1024,4096", help="decode: live length of every slot")
+    ap.add_argument("--window", type=int, default=0, help="decode: attention window (0: none); bytes are counted clipped to it")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--prefill", action="store_true")
     ap.add_argument("--layouts", default="32:32,32:8,16:16")
@@ -92,8 +96,9 @@ def main(argv) -> int:
     ppbs = [int(x) for x in a.pages_per_block.split(",") if x] or [None]
     print(f"device {dev.device_kind}, peak {bw / 1e9:.0f} GB/s; heads {H}:{G} x {hd}, pages of {T}, pool {N} pages, {L} calls a jit")
     rng = np.random.default_rng(0)
-    for B in (4, 16):
-        for length in (256, 1024, 4096):
+    window = jnp.int32(a.window) if a.window else None
+    for B in (int(x) for x in a.batches.split(",")):
+        for length in (int(x) for x in a.lengths.split(",")):
             n = -(-length // T)
             if B * n > N - 1:
                 # the pool cannot hold B slots of that length: slots share pages (the bytes read are the same)
@@ -104,20 +109,21 @@ def main(argv) -> int:
             bt[:, :n] = perm.reshape(B, n)
             bt, lens = jnp.asarray(bt), jnp.full((B,), length, jnp.int32)
             q = jax.random.normal(jax.random.fold_in(key, B * length), (B, H, hd), dtype)
-            ref = tfm.paged_attention_gather(q, kp[0], vp[0], bt, lens, G).astype(jnp.float32)
+            # the expression gathers every slot's whole table in float32: held to the first 4 slots
+            ref = tfm.paged_attention_gather(q[:4], kp[0], vp[0], bt[:4], lens[:4], G, window).astype(jnp.float32)
             for ppb in ppbs:
                 @jax.jit
                 def run(q, kp, vp, bt, lens):
                     def step(q, _):
-                        o = pa.paged_attention(q, kp, vp, 0, bt, lens, n_kv_heads=G, pages_per_block=ppb)
+                        o = pa.paged_attention(q, kp, vp, 0, bt, lens, n_kv_heads=G, window=window, pages_per_block=ppb)
                         return q + (o * 1e-3).astype(q.dtype), o
                     _, os_ = jax.lax.scan(step, q, None, length=L)
                     return os_[0]
 
                 err, us = err_and_us(run, (q, kp, vp, bt, lens), ref, a.reps, L)
-                nbytes = 2 * B * length * F * jnp.dtype(dtype).itemsize
+                nbytes = 2 * B * min(length, a.window or length) * F * jnp.dtype(dtype).itemsize
                 print(json.dumps({
-                    "batch": B, "live_length": length, "pages_per_block": ppb or pa.pick_pages_per_block(T, F, P, dtype),
+                    "batch": B, "live_length": length, "window": a.window, "pages_per_block": ppb or pa.pick_pages_per_block(T, F, P, dtype),
                     "us_per_call": round(us, 1), "kv_bytes": nbytes, "hbm_peak_share_pct": round(100 * nbytes / bw / (us * 1e-6), 1),
                     "max_abs_diff_vs_gather": round(err, 5),
                 }), flush=True)
