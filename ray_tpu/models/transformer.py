@@ -286,6 +286,16 @@ def rms_norm(x, scale, eps):
     return (x * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def _rms_norm_per_head(x, scale, eps, head_dim: int):
+    """rms_norm over each head's lanes of x [.., heads * head_dim] with one
+    scale [head_dim] for all heads. Only the statistic sees the heads: x
+    stays as its projection gave it."""
+    heads = x.shape[-1] // head_dim
+    sq = jnp.square(x.astype(jnp.float32)).reshape(*x.shape[:-1], heads, head_dim)
+    inv = jnp.repeat(lax.rsqrt(jnp.mean(sq, axis=-1) + eps), head_dim, axis=-1)
+    return (x * inv).astype(x.dtype) * jnp.tile(scale, heads)
+
+
 def layer_norm(x, scale, eps):
     """Mean-centering LayerNorm, scale-only (GPT-J's ln, bias unmodeled)."""
     xf = x.astype(jnp.float32)
@@ -319,15 +329,42 @@ def _rotate(x, cos, sin, interleave: bool):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def apply_rope(x, cos, sin, cfg: Optional[TransformerConfig] = None):
-    """x: [b, s, h, d]; cos / sin [s, rotary_dim/2] (the same positions in
-    every row: train, prefill) or [b, s, rotary_dim/2] (each row its own:
-    decode). Llama rotates the full head (rotate-half); GPT-J rotates only
-    the first rotary_dim dims, interleaved pairs, leaving the rest
-    pass-through."""
-    rd = cfg.rotary_dim if cfg is not None else None
-    interleave = cfg is not None and cfg.rope_style == "interleaved"
+def _rope_flat(xf, cos, sin, cfg: TransformerConfig):
+    """apply_rope's rotation of xf [b, s, heads * head_dim] float32, a
+    projection not split into heads: the head view's products and sum, lane
+    for lane. A lane's partner is `step` lanes to its right if it is the
+    first of its pair, else to its left (two rolls and a select; a pair never
+    leaves its head); a pair's angle stands on both its lanes, the identity
+    rotation beyond rotary_dim, the same in every head."""
+    hd = cfg.head_dim
+    rd = cfg.rotary_dim or hd
+    interleave = cfg.rope_style == "interleaved"
+    step = 1 if interleave else rd // 2
+
+    def per_lane(t, rest):
+        t = jnp.repeat(t, 2, axis=-1) if interleave else jnp.concatenate([t, t], axis=-1)
+        t = jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, hd - rd)], constant_values=rest)
+        t = jnp.tile(t, xf.shape[-1] // hd)
+        return t if t.ndim == 3 else t[None]
+
+    c, s = per_lane(cos, 1.0), per_lane(sin, 0.0)
+    first = (jnp.arange(xf.shape[-1]) % hd // step) % 2 == 0
+    right, left = jnp.roll(xf, -step, axis=-1), jnp.roll(xf, step, axis=-1)
+    return jnp.where(first, xf * c - right * s, xf * c + left * s)
+
+
+def apply_rope(x, cos, sin, cfg: TransformerConfig):
+    """x: [b, s, h, d], or [b, s, h * d], a projection not yet split into
+    heads (`_block` says which it passes, and why; the numbers are the same,
+    bit for bit); cos / sin [s, rotary_dim/2] (the same positions in every
+    row: train, prefill) or [b, s, rotary_dim/2] (each row its own: decode).
+    Llama rotates the full head (rotate-half); GPT-J rotates only the first
+    rotary_dim dims, interleaved pairs, leaving the rest pass-through."""
     xf = x.astype(jnp.float32)
+    if x.ndim == 3:
+        return _rope_flat(xf, cos, sin, cfg).astype(x.dtype)
+    rd = cfg.rotary_dim
+    interleave = cfg.rope_style == "interleaved"
     if rd is not None and rd < x.shape[-1]:
         rot = _rotate(xf[..., :rd], cos, sin, interleave)
         out = jnp.concatenate([rot, xf[..., rd:]], axis=-1)
@@ -470,10 +507,10 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh], window=Non
     return attention_reference(q, k, v, causal=True)
 
 
-def _qkv(h, ap, cfg: TransformerConfig):
-    """h [b, s, d] -> q [b, s, n_heads, hd], k, v [b, s, n_kv_heads, hd], before rope."""
-    b, s, _ = h.shape
-    hd = cfg.head_dim
+def _qkv(h, ap, cfg: TransformerConfig, split: bool):
+    """h [b, s, d] -> q, k, v before rope: split into heads ([b, s, n_heads,
+    hd], [b, s, n_kv_heads, hd]) or each as its projection gives it
+    ([b, s, n_heads * hd], [b, s, n_kv_heads * hd]); `_block` says which."""
     q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
@@ -481,15 +518,11 @@ def _qkv(h, ap, cfg: TransformerConfig):
         # over the whole projection, before the split into heads (OLMoE), or
         # over each head's dims with one scale for all heads (afmoe)
         with jax.named_scope("attn.qk_norm"):
-            if cfg.qk_norm_per_head:
-                q, k = q.reshape(b, s, cfg.n_heads, hd), k.reshape(b, s, cfg.n_kv_heads, hd)
-            q = rms_norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
-            k = rms_norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
-    return (
-        q.reshape(b, s, cfg.n_heads, hd).astype(cfg.dtype),
-        k.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype),
-        v.reshape(b, s, cfg.n_kv_heads, hd).astype(cfg.dtype),
-    )
+            norm = partial(_rms_norm_per_head, head_dim=cfg.head_dim) if cfg.qk_norm_per_head else rms_norm
+            q = norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
+            k = norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
+    view = (lambda t: t.reshape(*t.shape[:2], -1, cfg.head_dim)) if split else (lambda t: t)
+    return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype)
 
 
 def _ffn(h, mp, cfg: TransformerConfig, experts=None):
@@ -644,11 +677,19 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
     h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
-    q, k, v = _qkv(h, ap, cfg)
+    # Where q and k are split into heads decides which operand of their
+    # projections moves. Split before rope, the dot's result is head-shaped
+    # and the compiler lays the weight out for it: a slice and a transpose of
+    # `wq` / `wk` a layer. Split after rope, the weight is read where it lies
+    # in the stack and the result is what gets re-laid-out for `attend`. The
+    # smaller of the two should move: the weight while a call has at least
+    # as many rows as the weight has (a training batch), the result while
+    # it has fewer (a decode step, a prefill chunk).
+    q, k, v = _qkv(h, ap, cfg, split=b * s >= d)
     q = _ckpt(apply_rope(q, cos, sin, cfg), "q_bf16")
     k = _ckpt(apply_rope(k, cos, sin, cfg), "k_bf16")
     v = _ckpt(v, "v_bf16")
-    o, kept = attend(q, k, v)
+    o, kept = attend(*(t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)))
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     if cfg.attn_gate:
         with jax.named_scope("attn.gate"):

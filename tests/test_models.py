@@ -225,6 +225,34 @@ def test_train_prefill_and_decode_run_the_one_block(name, monkeypatch):
     np.testing.assert_allclose(logits[0], full[0, n], rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("heads", [4, 2, 1], ids=["mha", "gqa_kv", "one_head"])
+@pytest.mark.parametrize("per_row", [False, True], ids=["s_r", "b_s_r"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize(
+    "style,rotary_dim", [("half", None), ("interleaved", None), ("half", 8), ("interleaved", 8)]
+)
+def test_flat_rope_equals_the_head_view_bit_for_bit(style, rotary_dim, dtype, per_row, heads):
+    """apply_rope on a projection before its split into heads (what `_block`
+    passes while a call has fewer rows than the weight) against apply_rope
+    on the head view (a training batch): the same float32 products and sum,
+    so equal."""
+    cfg = tfm.tiny(rope_style=style, rotary_dim=rotary_dim)
+    b, s, hd = 3, 12, cfg.head_dim
+    x = jax.random.normal(jax.random.PRNGKey(heads), (b, s, heads * hd), jnp.float32).astype(dtype)
+    cos, sin = tfm.rope_tables(cfg, 64)
+    if per_row:  # each row its own positions, as decode takes them
+        pos = jax.random.randint(jax.random.PRNGKey(7), (b, s), 0, 64)
+        cos, sin = cos[pos], sin[pos]
+    else:
+        cos, sin = cos[:s], sin[:s]
+    want = tfm.apply_rope(x.reshape(b, s, heads, hd), cos, sin, cfg).reshape(x.shape)
+    # op by op: one compiled program may contract a product and the sum into
+    # one rounding, and need not choose the same product in both spellings
+    got = tfm.apply_rope(x, cos, sin, cfg)
+    assert got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
 @pytest.mark.parametrize(
     "style,rotary_dim", [("half", None), ("interleaved", None), ("half", 8), ("interleaved", 8)]
 )
@@ -234,7 +262,7 @@ def test_rope_per_row_tables_match_per_sequence_tables(style, rotary_dim):
     among them)."""
     cfg = tfm.tiny(rope_style=style, rotary_dim=rotary_dim)
     b, s = 3, 12
-    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, cfg.n_heads, cfg.head_dim), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, cfg.n_heads * cfg.head_dim), jnp.float32)
     cos, sin = tfm.rope_tables(cfg, s)
     want = tfm.apply_rope(x, cos, sin, cfg)
     rows = lambda t: jnp.broadcast_to(t, (b, *t.shape))
@@ -245,4 +273,30 @@ def test_rope_per_row_tables_match_per_sequence_tables(style, rotary_dim):
     )
     np.testing.assert_array_equal(one[:, 0], want[jnp.arange(b), pos])
     if rotary_dim is not None:  # what lies beyond rotary_dim passes through
-        np.testing.assert_array_equal(want[..., rotary_dim:], x[..., rotary_dim:])
+        heads = lambda t: t.reshape(b, s, cfg.n_heads, cfg.head_dim)
+        np.testing.assert_array_equal(heads(want)[..., rotary_dim:], heads(x)[..., rotary_dim:])
+
+
+@pytest.mark.parametrize("heads", [4, 1], ids=["heads", "one_head"])
+def test_per_head_rms_norm_on_the_flat_projection_equals_the_head_view(heads):
+    """The per-head qk-norm takes only its statistic from a head view: the
+    numbers of rms_norm over [.., heads, head_dim], bit for bit."""
+    hd, eps = 16, 1e-5
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 5, heads * hd), jnp.float32)
+    scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(4), (hd,), jnp.float32)
+    want = tfm.rms_norm(x.reshape(2, 5, heads, hd), scale, eps).reshape(x.shape)
+    np.testing.assert_array_equal(tfm._rms_norm_per_head(x, scale, eps, hd), want)
+
+
+@pytest.mark.parametrize("style,rotary_dim", [("half", None), ("interleaved", 8)])
+def test_block_gives_the_same_numbers_on_both_sides_of_its_row_rule(style, rotary_dim):
+    """`_block` splits q and k into heads before rope while a call has at
+    least d_model rows and after it while it has fewer: one sequence alone
+    (32 rows of a 64-wide model) against the same sequence in a batch of
+    four (128 rows)."""
+    cfg = tfm.tiny(rope_style=style, rotary_dim=rotary_dim)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
+    assert tokens[:1].size < cfg.d_model <= tokens.size
+    alone, batched = tfm.forward(params, tokens[:1], cfg), tfm.forward(params, tokens, cfg)
+    np.testing.assert_allclose(alone[0], batched[0], rtol=1e-5, atol=1e-5)

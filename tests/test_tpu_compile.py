@@ -8,6 +8,7 @@ guide): nothing here touches it at import. Nothing runs, so these tests say
 nothing about results or times.
 """
 
+import re
 import sys
 
 import jax
@@ -87,34 +88,58 @@ def test_paged_prefill_kernel_compiles_at_the_cells_widths(one_chip, heads, kv_h
     assert "tpu_custom_call" in text and pa.PREFILL_KERNEL_NAME in text
 
 
-def test_prefill_executable_updates_the_pool_in_place(one_chip, mosaic):
-    """The prefill executable of the largest bucket at the serving widths
-    (2 layers, small vocab): the donated pool is aliased to the output and
-    no second pool is among the temporaries (before PR 31 the pool rode the
-    layer scan as xs / ys: 2.3-2.7 GiB of them at 8 layers)."""
+SERVING = dict(vocab_size=1024, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=32, d_ff=1024, attn_impl="full")
+# Trinity's attention: GQA 32:4, heads narrower than d_model / n_heads, a qk-norm over each head
+GQA_PER_HEAD_NORM = dict(
+    SERVING, d_model=2048, n_kv_heads=4, d_head=128, qk_norm=True, qk_norm_per_head=True
+)
+SLOTS, TABLE_PAGES, PAGE_TOKENS, POOL_PAGES = 16, 256, 16, 1024
+
+
+def _compile_paged(one_chip, cfg, step: str):
+    """forward_decode (SLOTS slots) or forward_prefill (the bucket of
+    TABLE_PAGES pages) of `cfg`, pool donated, compiled for the described chip."""
     sds = _sds(one_chip)
-    cfg = tfm.TransformerConfig(
-        vocab_size=1024, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=32, d_ff=1024, attn_impl="full"
-    )
-    P, T, N = 256, 16, 1024
+    B, P, T, N = SLOTS, TABLE_PAGES, PAGE_TOKENS, POOL_PAGES
 
     def shapes(make):
         return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))
 
     params = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
     kv = shapes(lambda: tfm.init_kv_pages(cfg, N, T))
+    scalar = sds((), jnp.int32)
+    if step == "decode":
+        assert tfm.paged_attention_path(cfg, T) == "paged_kernel"
 
-    def step(params, tokens, kv, table, length, write_from):
+        def decode(params, tokens, positions, kv, bts):
+            return tfm.forward_decode(params, tokens, positions, cfg, kv, bts)
+
+        return jax.jit(decode, donate_argnums=(3,)).lower(
+            params, sds((B,), jnp.int32), sds((B,), jnp.int32), kv, sds((B, P), jnp.int32)
+        ).compile()
+
+    def prefill(params, tokens, kv, table, length, write_from):
         return tfm.forward_prefill(params, tokens, cfg, kv, table, length, write_from)
 
-    scalar = sds((), jnp.int32)
-    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+    return jax.jit(prefill, donate_argnums=(2,)).lower(
         params, sds((1, P * T), jnp.int32), kv, sds((P,), jnp.int32), scalar, scalar
     ).compile()
+
+
+def _pool_bytes(cfg):
+    return 2 * cfg.n_layers * POOL_PAGES * PAGE_TOKENS * cfg.n_kv_heads * cfg.head_dim * 2
+
+
+def test_prefill_executable_updates_the_pool_in_place(one_chip, mosaic):
+    """The prefill executable of the largest bucket at the serving widths
+    (2 layers, small vocab): the donated pool is aliased to the output and
+    no second pool is among the temporaries (before PR 31 the pool rode the
+    layer scan as xs / ys: 2.3-2.7 GiB of them at 8 layers)."""
+    cfg = tfm.TransformerConfig(**SERVING)
+    compiled = _compile_paged(one_chip, cfg, "prefill")
     mem = compiled.memory_analysis()
-    pool_bytes = 2 * cfg.n_layers * N * T * cfg.n_kv_heads * cfg.head_dim * 2
-    assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < pool_bytes // 2
+    assert mem.alias_size_in_bytes >= _pool_bytes(cfg)
+    assert mem.temp_size_in_bytes < _pool_bytes(cfg) // 2
     assert pa.PREFILL_KERNEL_NAME in compiled.as_text()
 
 
@@ -122,27 +147,40 @@ def test_decode_step_updates_the_pool_in_place(one_chip, mosaic):
     """The decode executable at the serving widths (2 layers, small vocab):
     the donated pool is aliased to the output and the step's temporaries are
     a small fraction of it, i.e. no gathered table and no second pool."""
-    sds = _sds(one_chip)
-    cfg = tfm.TransformerConfig(
-        vocab_size=1024, d_model=4096, n_layers=2, n_heads=32, n_kv_heads=32, d_ff=1024, attn_impl="full"
-    )
-    B, P, T, N = 16, 256, 16, 1024
-    assert tfm.paged_attention_path(cfg, T) == "paged_kernel"
-
-    def shapes(make):
-        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))
-
-    params = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-    kv = shapes(lambda: tfm.init_kv_pages(cfg, N, T))
-
-    def step(params, tokens, positions, kv, bts):
-        return tfm.forward_decode(params, tokens, positions, cfg, kv, bts)
-
-    compiled = jax.jit(step, donate_argnums=(3,)).lower(
-        params, sds((B,), jnp.int32), sds((B,), jnp.int32), kv, sds((B, P), jnp.int32)
-    ).compile()
+    cfg = tfm.TransformerConfig(**SERVING)
+    compiled = _compile_paged(one_chip, cfg, "decode")
     mem = compiled.memory_analysis()
-    pool_bytes = 2 * cfg.n_layers * N * T * cfg.n_kv_heads * cfg.head_dim * 2
-    assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < pool_bytes // 8
+    assert mem.alias_size_in_bytes >= _pool_bytes(cfg)
+    assert mem.temp_size_in_bytes < _pool_bytes(cfg) // 8
     assert pa.KERNEL_NAME in compiled.as_text()
+
+
+def _moved_attention_weights(text: str, cfg) -> list:
+    """The ops of a compiled program's text whose RESULT is an attention
+    weight of `cfg`, one layer's or the whole stack's, made by a `copy` or by
+    a fusion that slices: a weight moved before it is multiplied."""
+    d, q, kv = cfg.d_model, cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    weights = {f"{n},{a},{b}" for n in (1, cfg.n_layers) for a, b in ((d, q), (d, kv), (q, d))}
+    found = []
+    for name, dims in re.findall(r"%([\w.\-]+) = bf16\[([\d,]+)\]\S* (?:copy|fusion)\(", text):
+        if dims in weights and (name.startswith("copy") or "slice" in name):
+            found.append(f"{name} bf16[{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("widths", [SERVING, GQA_PER_HEAD_NORM], ids=["mha", "gqa_per_head_norm"])
+def test_paged_steps_read_the_attention_weights_where_they_lie(one_chip, mosaic, widths, step):
+    """In a call of fewer rows than the weight has, q and k keep the shape
+    their projections give them through qk-norm and rope (`_block` splits
+    them into heads for `attend` alone), so no dot has a head-shaped result
+    to lay its weight out for: the executables hold no
+    copy and no slice fusion that yields a layer's `wq` / `wk` / `wv` / `wo`
+    or a stack of them (before PR 39: a slice and a transpose of `wq` and
+    `wk` every decode layer-step, both stacks transposed once a prefill call:
+    2 x 64 MiB of temporaries at these 2 layers)."""
+    cfg = tfm.TransformerConfig(**widths)
+    compiled = _compile_paged(one_chip, cfg, step)
+    assert _moved_attention_weights(compiled.as_text(), cfg) == []
+    if step == "prefill":
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
