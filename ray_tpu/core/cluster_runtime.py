@@ -1560,14 +1560,24 @@ class ClusterRuntime(Runtime):
             cli.close()
 
 
+_SESSION_START_GRACE_S = 60.0
+
+
 def _session_alive(session_dir: str) -> bool:
     """A session is alive iff one of its daemon sockets accepts a
     connection: gcs.sock for a head session, raylet_*.sock for a
     worker-node session created by start_worker_node (which has no GCS —
     sweeping those by gcs.sock absence would destroy a LIVE joined node's
-    pool and socket)."""
+    pool and socket), or it is still starting: its directory changed within
+    the last minute (the pool file exists before the daemon's socket does,
+    and another process's sweep in that interval took the node for dead)."""
     import glob as _glob
 
+    try:
+        if time.time() - os.stat(session_dir).st_mtime < _SESSION_START_GRACE_S:
+            return True
+    except OSError:
+        pass
     candidates = [os.path.join(session_dir, "gcs.sock")]
     candidates += _glob.glob(os.path.join(session_dir, "raylet_*.sock"))
     for sock_path in candidates:

@@ -1872,6 +1872,25 @@ class RayletService(ChaosPartitionRpc):
                 chunks.extend(lines)
         return "\n".join(chunks)
 
+    def _exit_status(self, w: "_Worker") -> str:
+        """How a dead worker's process ended, in words: "exit code 1",
+        "killed by SIGKILL". A zygote-forked worker's status is the
+        daemon's to know (it is the parent); PidHandle.poll() says -1 for
+        every death."""
+        from .zygote import PidHandle
+
+        code = w.proc.poll()
+        if isinstance(w.proc, PidHandle):
+            code = self._pool.zygote_exit_code(w.proc.pid) if self._pool is not None else None
+        if code is None:
+            return "exit status unknown"
+        if code < 0:
+            try:
+                return f"killed by {signal.Signals(-code).name}"
+            except ValueError:
+                return f"killed by signal {-code}"
+        return f"exit code {code}"
+
     def _write_postmortem(self, w: "_Worker", tail: str) -> Optional[str]:
         """Pairs a dying worker's output tail with the flight dumps:
         `ray-tpu debug dump` output and the trace merge both sweep the
@@ -1889,6 +1908,7 @@ class RayletService(ChaosPartitionRpc):
                 "node_id": self.node_id,
                 "actor_id": w.actor_id,
                 "exit_code": w.proc.poll(),
+                "exit_status": self._exit_status(w),
                 "task": (w.busy_with or {}).get("desc"),
                 "tail": tail.splitlines(),
             }
@@ -2599,15 +2619,16 @@ class RayletService(ChaosPartitionRpc):
                     deliberate = a is not None and a.get("state") == "DEAD"
                 if entry is not None and entry.get("task_id") in self._cancelled:
                     deliberate = True
-                tail = ""
+                tail = status = ""
                 if not deliberate and w.proc.poll() not in (0, None):
                     tail = self._worker_log_tail(w.worker_id)
+                    status = self._exit_status(w)
                     self._write_postmortem(w, tail)
                     if entry is not None or w.actor_id is not None:
                         _log.warning(
-                            "worker %s died abnormally (exit %s, task=%s)",
+                            "worker %s died abnormally (%s, task=%s)",
                             w.worker_id,
-                            w.proc.poll(),
+                            status,
                             (entry or {}).get("desc"),
                         )
                         try:
@@ -2619,7 +2640,7 @@ class RayletService(ChaosPartitionRpc):
                                     "worker_id": w.worker_id,
                                     "actor_id": w.actor_id,
                                     "error": (
-                                        f"worker died (exit {w.proc.poll()})"
+                                        f"worker died ({status})"
                                         + (
                                             f" executing {entry.get('desc', 'task')}"
                                             if entry
@@ -2631,7 +2652,11 @@ class RayletService(ChaosPartitionRpc):
                             )
                         except Exception:  # lint: swallow-ok(postmortem report is best-effort; death handling below is the guarantee)
                             pass
-                tail_note = f"; last output:\n{tail[-2000:]}" if tail else ""
+                # What the owner reads in WorkerCrashedError / ActorDiedError:
+                # how the process ended, then the last lines it wrote.
+                tail_note = f" ({status})" if status else ""
+                if tail:
+                    tail_note += f"; last output:\n{tail[-2000:]}"
                 if entry is not None:
                     if entry["type"] == "task":
                         self._release_entry(entry)

@@ -233,6 +233,18 @@ class WorkerPoolManager:
             self._note_zygote_failure(e)
             raise ZygoteUnavailableError(f"zygote spawn failed: {e!r}") from e
 
+    def zygote_exit_code(self, pid: int) -> Optional[int]:
+        """How a zygote-forked worker ended (the daemon reaps them, so only
+        it knows); None when it cannot say. Never raises: this is asked
+        while a death is being reported."""
+        z = self._zygote
+        if z is None:
+            return None
+        try:
+            return z.exit_code(pid)
+        except Exception:  # lint: swallow-ok(the daemon may be gone too; the death is reported without the code)
+            return None
+
     def zygote_spawn_batch(self, specs: List[dict]) -> List[Tuple[int, bool]]:
         """N forks, one socket round trip (refill storms coalesce)."""
         self._chaos_spawn_point(f"batch:{len(specs)}")

@@ -138,6 +138,12 @@ def main(argv: List[str]) -> None:
     from ..observability.flight_recorder import install_crash_hooks
 
     install_crash_hooks("worker")
+    # A crash below Python (SIGSEGV, SIGABRT in a native library, a fatal
+    # runtime error) leaves every thread's stack on fd 2, the .err file whose
+    # tail the raylet puts into the error the owner sees.
+    import faulthandler
+
+    faulthandler.enable()
 
     # Our stdout/stderr fds are the per-worker capture files the raylet
     # opened at spawn. Line-buffer them: a task's print() must reach the

@@ -39,6 +39,7 @@ Protocol (one JSON line per request/reply over the UDS):
   {"pool": N}                   -> {"parked": N_now, "forked": K}
   {"stats": true}               -> {"parked": N, "pid": zygote_pid}
   {"reset": true}               -> {"drained": K}   (parked children exit)
+  {"exit_code": pid}            -> {"code": N | null}  (how a reaped child ended)
   {"stop": true}                -> (daemon exits; parked die via pdeathsig)
 """
 
@@ -49,7 +50,7 @@ import os
 import signal
 import socket
 import sys
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 
 # PR_SET_PDEATHSIG, pre-bound at import so set_pdeathsig() does no
@@ -82,14 +83,23 @@ def set_pdeathsig(sig: int = signal.SIGTERM) -> None:
         pass
 
 
+_EXIT_CODES: Dict[int, int] = {}  # pid -> exit code (negative: the signal), the last few hundred reaped
+_EXIT_CODES_KEPT = 256
+
+
 def _reap(signum, frame):
     """Collect any exited children so they don't linger as zombies (the
-    raylet detects death via os.kill(pid, 0) => ESRCH after the reap)."""
+    raylet detects death via os.kill(pid, 0) => ESRCH after the reap), and
+    remember how each ended: the raylet asks (`exit_code`) when it writes a
+    dead worker's post-mortem, since only the parent can know."""
     try:
         while True:
-            pid, _ = os.waitpid(-1, os.WNOHANG)
+            pid, status = os.waitpid(-1, os.WNOHANG)
             if pid == 0:
                 break
+            _EXIT_CODES[pid] = os.waitstatus_to_exitcode(status)
+            while len(_EXIT_CODES) > _EXIT_CODES_KEPT:
+                del _EXIT_CODES[next(iter(_EXIT_CODES))]
     except ChildProcessError:
         pass
 
@@ -289,6 +299,9 @@ def _handle(req: dict) -> Optional[dict]:
         return {"parked": len(_PARKED), "pid": os.getpid()}
     if req.get("reset"):
         return {"drained": _drain_parked()}
+    if "exit_code" in req:
+        _reap(None, None)  # a child that is a zombie this instant
+        return {"code": _EXIT_CODES.get(int(req["exit_code"]))}
     if "pool" in req:
         target = max(0, int(req["pool"]))
         forked = _fill_pool(target)
@@ -445,6 +458,12 @@ class ZygoteClient:
 
     def stats(self) -> dict:
         return self._request({"stats": True})
+
+    def exit_code(self, pid: int) -> Optional[int]:
+        """How a forked child ended, as Popen.returncode would say it
+        (negative: killed by that signal); None if the daemon never reaped
+        that pid or has forgotten it."""
+        return self._request({"exit_code": int(pid)}, timeout=2.0).get("code")
 
     def reset(self) -> int:
         """Drains every parked child (fence/teardown: no orphan

@@ -48,6 +48,12 @@ class ActorDiedError(ActorError):
         self.reason = reason
         super().__init__(f"Actor {actor_id_hex[:12]} died: {reason}")
 
+    def __reduce__(self):
+        # Default exception pickling re-inits with args=(message,): the id
+        # became the message's head and the reason its default, so a caller
+        # across the object plane read "died: actor died" whatever was known.
+        return (ActorDiedError, (self.actor_id_hex, self.reason))
+
 
 class ActorUnavailableError(ActorError):
     pass

@@ -45,6 +45,7 @@ from ... import tracing as _tracing
 from ...utils import internal_metrics as imet
 from ...utils import lock_order
 from .kv_cache import PagedKVAllocator, SeqPages
+from .model import StepTokens
 
 logger = logging.getLogger(__name__)
 
@@ -143,20 +144,25 @@ class InferenceEngine:
         self._tok_window = 0
         self._t_window = time.monotonic()
         # Stage clocks (stats()["clocks"]): seconds of time.monotonic(),
-        # cumulative, written by the loop thread where it already reads the
-        # clock or holds _cond. `_stage` names what the loop is inside right
-        # now, so stats() can count the part of it already spent.
+        # cumulative, written by the loop thread. The loop is in exactly one
+        # stage from its start to its stop (`_enter` leaves one and enters
+        # the next at one reading of the clock), so the stages' seconds add
+        # up to loop.s; `_stage` names the one it is in right now, so
+        # stats() can count the part of it already spent.
         self._clk = {
             "queue_wait": {"n": 0, "s": 0.0},
             "first_token": {"n": 0, "s": 0.0},
+            "admit": {"s": 0.0},
             "prefill": {"n": 0, "s": 0.0, "tokens": 0, "computed_tokens": 0},
+            "batch": {"s": 0.0},
             "decode": {"n": 0, "s": 0.0},
+            "emit": {"s": 0.0},
             "decode.kv_pages": {"live": 0, "table": 0},
             "idle": {"s": 0.0},
         }
-        self._stage: Optional[tuple] = None  # (clock name, t0)
         self._clk_lock = threading.Lock()
         self._t_loop = (time.monotonic(), None)  # loop start, loop end
+        self._stage: Optional[tuple] = ("admit", self._t_loop[0])  # (clock name, t0)
         self._thread = threading.Thread(
             target=self._loop, name=f"llm-engine-{name}", daemon=True
         )
@@ -374,39 +380,54 @@ class InferenceEngine:
         try:
             self._run()
         finally:
-            self._t_loop = (self._t_loop[0], time.monotonic())
+            self._enter(None)
 
-    def _timed(self, clock: str, t0: float) -> float:
-        """Closes the stage opened at t0: its seconds go to `clock`."""
-        dt = time.monotonic() - t0
+    def _enter(self, clock: Optional[str]) -> float:
+        """The loop leaves the stage it is in, whose seconds go to its
+        clock, and enters `clock` (None: the loop ends) at the same reading
+        of time.monotonic(), which it returns."""
+        now = time.monotonic()
         with self._clk_lock:  # stats() sees the stage open or its seconds, never both
-            self._stage = None
-            self._clk[clock]["s"] += dt
-        return dt
+            name, t0 = self._stage
+            self._clk[name]["s"] += now - t0
+            if clock is None:
+                self._stage, self._t_loop = None, (self._t_loop[0], now)
+            else:
+                self._stage = (clock, now)
+        return now
 
     def _run(self) -> None:
+        """From here to the return every instant of this thread lies under
+        one of three spans, and in one stage of the clocks: `llm.admit` (the
+        locked section that reaps cancels and fills free slots; the lock is
+        the one submit() and cancel() take), then `llm.idle` (nothing to
+        run: one wait for a submit, a cancel or the stop) or `llm.step`."""
         while True:
-            with self._cond:
-                self._drain_cancels_locked()
-                while (
-                    not self._stop
-                    and not self._waiting
-                    and not any(self._slots)
-                    and not self._cancels
-                ):
-                    self._m_tps.set(0.0)
-                    self._stage = ("idle", time.monotonic())
-                    self._cond.wait(timeout=1.0)
-                    self._timed("idle", self._stage[1])
-                if self._stop:
-                    for seq in list(self._by_rid.values()):
-                        self._finish_locked(seq, "error", RayTpuError("engine shut down"))
-                    return
-                self._drain_cancels_locked()
-                admitted = self._pick_admissions_locked()
-                live = sum(1 for s in self._slots if s is not None)
+            with _tracing.span("llm.admit", device=True) as sp:
+                self._enter("admit")  # inside the span: what lies between two spans is a hole in the record
+                with self._cond:
+                    self._drain_cancels_locked()
+                    stop = self._stop
+                    if stop:
+                        for seq in list(self._by_rid.values()):
+                            self._finish_locked(seq, "error", RayTpuError("engine shut down"))
+                    admitted = [] if stop else self._pick_admissions_locked()
+                    live = sum(1 for s in self._slots if s is not None)
+                    waiting = len(self._waiting)
+                _tracing.add_attrs(sp, waiting=waiting, admitted=len(admitted), live=live)
+            if stop:
+                return
             if not live:
-                continue  # woken by a cancel; nothing to run
+                # Nothing waits (it would have been admitted) and nothing
+                # runs. The test is made again under the lock the wait
+                # gives up: a submit between the two sections is not slept on.
+                with _tracing.span("llm.idle", device=True):
+                    self._enter("idle")
+                    with self._cond:
+                        if not (self._stop or self._waiting or self._cancels):
+                            self._m_tps.set(0.0)
+                            self._cond.wait(timeout=1.0)
+                continue
             # One iteration with work in it, from the admissions to the
             # last emit: prefills, one decode step, the emits.
             with _tracing.span(
@@ -427,8 +448,7 @@ class InferenceEngine:
             clk = self._clk["prefill"]
             clk["n"] += 1
             clk["tokens"] += len(seq.prompt)
-            t0 = time.monotonic()
-            self._stage = ("prefill", t0)
+            self._enter("prefill")
             attrs = {
                 "rid": seq.rid,
                 "prompt_tokens": len(seq.prompt),
@@ -452,11 +472,10 @@ class InferenceEngine:
                 return False
             except Exception as e:  # noqa: BLE001 - fail one request, not the loop
                 err = e
-            finally:
-                self._timed("prefill", t0)
             prefilled.append((seq, tok, err))
 
-        with self._cond:
+        self._enter("batch")
+        with _tracing.span("llm.batch", device=True) as sp, self._cond:
             for seq, tok, err in prefilled:
                 self._finalize_admission_locked(seq, tok, err)
             batch = [s for s in self._slots if s is not None]
@@ -470,9 +489,11 @@ class InferenceEngine:
                     except KVPoolExhaustedError as e:
                         batch.remove(seq)
                         self._finish_locked(seq, "error", e)
+            _tracing.add_attrs(sp, live=len(batch))
             if not batch:
                 return True
-            tokens = [0] * len(self._slots)
+            step = self.decode_steps + 1  # the ordinal it gets when it completes
+            tokens = StepTokens([0] * len(self._slots), step)
             positions = [-1] * len(self._slots)
             tables: List[List[int]] = [[] for _ in self._slots]
             kv_tokens = live_pages = 0
@@ -486,8 +507,7 @@ class InferenceEngine:
 
         # Model step runs OUTSIDE the lock: submit/cancel stay
         # responsive for the full decode latency.
-        t0 = time.monotonic()
-        self._stage = ("decode", t0)
+        t0 = self._enter("decode")
         try:
             rule = _chaos_inject("serve.decode", self.name)
             if rule is not None:
@@ -499,9 +519,13 @@ class InferenceEngine:
                     raise RayTpuError(
                         f"chaos: injected decode fault ({self.name})"
                     )
-            with _tracing.span(
-                "llm.decode", {"live": len(batch), "kv_tokens": kv_tokens}, device=True
-            ):
+            attrs = {
+                "live": len(batch),
+                "kv_tokens": kv_tokens,
+                "step": step,
+                "after_prefill": int(bool(admitted)),
+            }
+            with _tracing.span("llm.decode", attrs, device=True):
                 next_tokens = self.model.decode(tokens, positions, tables)
             step_err: Optional[BaseException] = None
         except EngineFailedError as e:
@@ -510,7 +534,7 @@ class InferenceEngine:
         except Exception as e:  # noqa: BLE001 - batch fail-fast, loop survives
             next_tokens, step_err = None, e
         finally:
-            step_ms = self._timed("decode", t0) * 1000.0
+            step_ms = (self._enter("emit") - t0) * 1000.0
 
         with self._cond, _tracing.span("llm.emit", {"tokens": len(batch)}, device=True):
             if step_err is not None:
@@ -571,11 +595,15 @@ class InferenceEngine:
         """Where the loop's time went since the engine started (seconds of
         time.monotonic(), cumulative). queue_wait: submit -> slot, per
         admitted request; first_token: submit -> first emit (the engine's
-        own TTFT); prefill / decode: inside model.prefill / model.decode
-        (prefill.tokens: prompt tokens of those calls, cached ones
-        included; prefill.computed_tokens: positions their executables
-        computed, as the model reports them: whole chunks with their
-        padding, cached chunks not); decode.kv_pages: over the completed decode steps, the
+        own TTFT); the loop's stages, each from its start to the next one's:
+        admit (the locked section at the top of an iteration: cancels
+        reaped, free slots filled), prefill / decode (inside model.prefill /
+        model.decode), batch (the locked section that finalizes admissions,
+        grows block tables and builds the step's inputs), emit (the step's
+        tokens to their sinks); prefill.tokens: prompt tokens of those calls,
+        cached ones included; prefill.computed_tokens: positions their
+        executables computed, as the model reports them: whole chunks with
+        their padding, cached chunks not; decode.kv_pages: over the completed decode steps, the
         pages their live lengths cover (what a step must read) against
         slots x pages a sequence (what a step that gathers the block
         tables reads); decode_window (a model with attention windows only):
@@ -584,16 +612,17 @@ class InferenceEngine:
         decode_experts (a routed model only): touched, the distinct experts
         the steps' rows chose, summed over routed layers, of `held` in as many
         `steps`; loop.s: wall time of the loop, loop.idle_s the part of
-        it waiting with nothing to do. loop.s - idle_s - prefill.s -
-        decode.s is the engine's own host time. A stage in progress counts
-        up to now, so two calls bracket a window exactly."""
+        it waiting with nothing to do. loop.s = idle_s + admit.s +
+        prefill.s + batch.s + decode.s + emit.s, and loop.s - idle_s -
+        prefill.s - decode.s is the engine's own host time. A stage in
+        progress counts up to now, so two calls bracket a window exactly."""
         with self._clk_lock:
             now = time.monotonic()
             clk = {k: dict(v) for k, v in self._clk.items()}
             stage = self._stage
+            t_start, t_end = self._t_loop
         if stage is not None:
             clk[stage[0]]["s"] += max(0.0, now - stage[1])
-        t_start, t_end = self._t_loop
         clk["loop"] = {"s": (t_end or now) - t_start, "idle_s": clk.pop("idle")["s"]}
         return clk
 

@@ -54,6 +54,18 @@ class DecodeTokens(list):
         self.counters = counters
 
 
+class StepTokens(list):
+    """What the engine hands `decode` as `last_tokens`: the slots' last
+    tokens, a list to every model, which also says which decode step of the
+    engine this is (`step`: its `decode_steps` ordinal), so that a model with
+    spans of its own around the executable carries the ordinal on them
+    (DecodeTokens' way through wrappers, in the other direction)."""
+
+    def __init__(self, tokens, step: int):
+        super().__init__(tokens)
+        self.step = step
+
+
 class StubModel:
     """Deterministic, JAX-free model for scheduler/chaos tests and the
     engine's disarmed-cost bench: next token = (last + 1) % vocab.
@@ -180,6 +192,9 @@ class PagedLM:
                     out = self._jnp.concatenate([out, stats[0]["experts_touched"][None]])
                 return out, kv
 
+            # The executable's name in a device trace (line `XLA Modules`:
+            # jit_llm_decode), where every jitted closure called `step` reads alike.
+            step.__name__ = "llm_decode"
             self._decode_jit = self._jax.jit(step, donate_argnums=self._donate((3,)))
         return self._decode_jit
 
@@ -194,6 +209,7 @@ class PagedLM:
                 )
                 return self._jnp.argmax(logits[0], axis=-1).astype(self._jnp.int32), kv
 
+            step.__name__ = f"llm_prefill_p{n_pages_bucket}"  # jit_llm_prefill_p<pages>, a bucket a name
             fn = self._jax.jit(step, donate_argnums=self._donate((2,)))
             self._prefill_jits[n_pages_bucket] = fn
         return fn
@@ -282,7 +298,9 @@ class PagedLM:
             for i, row in enumerate(block_tables):
                 bts[i, : len(row)] = np.asarray(row, dtype=np.int32)
             fn = self._get_decode()
-        out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode")
+        step = getattr(last_tokens, "step", None)  # the engine's StepTokens; a bare list from anyone else
+        attrs = None if step is None else {"step": step}
+        out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode", attrs)
         tokens, cfg, counters = [int(t) for t in out[:B]], self.cfg, {}
         if cfg.n_experts:
             # Every row of the step is routed, the inactive slots' too.

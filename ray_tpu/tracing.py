@@ -41,6 +41,7 @@ import atexit
 import collections
 import contextlib
 import contextvars
+import itertools
 import json
 import os
 import sys
@@ -247,7 +248,7 @@ def _annotation(name: str, attrs: Optional[Dict[str, Any]]):
 
 
 class _Span:
-    __slots__ = ("sp", "_token", "_ann")
+    __slots__ = ("sp", "_token", "_ann", "_n_attrs")
 
     def __init__(self, name, attrs, parent, ann):
         # Identity is fixed here so `parent` can be another thread's
@@ -262,6 +263,7 @@ class _Span:
             "attrs": attrs if attrs is not None else {},
         }
         self._ann = ann
+        self._n_attrs = len(self.sp["attrs"])  # what the annotation was built with
 
     def __enter__(self) -> dict:
         sp = self.sp
@@ -279,6 +281,11 @@ class _Span:
         sp = self.sp
         t1_ns = time.monotonic_ns()
         if self._ann is not None:
+            # attrs learned inside the span (add_attrs, or the caller's dict
+            # written to): a dict keeps its order, so they are the tail
+            late = dict(itertools.islice(sp["attrs"].items(), self._n_attrs, None))
+            if late:
+                self._ann.set_metadata(**late)
             self._ann.__exit__(exc_type, exc_val, tb)
         if exc_val is not None:
             sp["attrs"]["error"] = repr(exc_val)
@@ -313,13 +320,27 @@ def span(
     the same name and attrs, tracing on or off (off, the span IS the
     annotation): it costs a check of the profiler's level when no device
     trace is being taken and lands in the xplane file when one is. Only
-    for the few spans a device trace should see, and bound by nothing
-    (`as sp` is for the other spans)."""
+    for the few spans a device trace should see; what `as sp` binds there
+    is for `add_attrs` alone."""
     if not _on:
         if device:
             return _annotation(name, attrs) or _NO_SPAN
         return _NO_SPAN
     return _Span(name, attrs, parent or _ctx.get(), _annotation(name, attrs) if device else None)
+
+
+def add_attrs(sp, **attrs) -> None:
+    """Attributes a span learns only inside itself (what a locked section
+    found), given what `with span(...) as sp` bound: the span's dict with
+    tracing on (a `device=True` span hands them to its annotation as it
+    closes), the bare annotation with tracing off, None where there is
+    neither."""
+    if sp is None:
+        return
+    if isinstance(sp, dict):
+        sp["attrs"].update(attrs)
+    else:
+        sp.set_metadata(**attrs)
 
 
 def record_span(

@@ -150,6 +150,53 @@ def test_worker_death_no_retries_raises(rt_cluster):
         rt.get(die.remote(), timeout=30)
 
 
+@pytest.mark.parametrize("how,said", [("sigkill", "killed by SIGKILL"), ("exit3", "exit code 3")])
+def test_a_dead_actor_says_how_its_process_ended(rt_cluster, how, said):
+    """The error the caller sees names the signal or the exit code (the
+    zygote that reaped the worker knows it, the raylet asks), with the last
+    lines the process wrote, and keeps both across the object plane's pickle
+    (PERF.md section 7 (0): a replica died as "Actor ... died: actor died")."""
+    import pickle
+    import signal
+
+    from ray_tpu import exceptions as exc
+
+    @rt.remote
+    class Victim:
+        def pid(self):
+            return os.getpid()
+
+        def leave(self, code):
+            print("leaving on purpose", flush=True)
+            os._exit(code)
+
+    a = Victim.remote()
+    pid = rt.get(a.pid.remote(), timeout=30)
+    if how == "sigkill":
+        os.kill(pid, signal.SIGKILL)
+    else:
+        a.leave.remote(3)
+    seen = []
+
+    def died():
+        try:
+            rt.get(a.pid.remote(), timeout=5)
+        except exc.ActorDiedError as e:
+            seen.append(e)
+            return True
+        except Exception:  # what was in flight when the process went: the raylet's RuntimeError
+            return False
+        return False
+
+    assert _wait_for(died, timeout=30)
+    err = seen[-1]
+    assert said in str(err) and said in err.reason, str(err)
+    if how == "exit3":
+        assert "leaving on purpose" in err.reason
+    again = pickle.loads(pickle.dumps(err))
+    assert str(again) == str(err) and again.reason == err.reason and again.actor_id_hex == err.actor_id_hex
+
+
 def test_node_death_task_retried_elsewhere(two_node, tmp_path):
     cluster, runtime, spot_node = two_node
     marker = str(tmp_path / "slow_marker")

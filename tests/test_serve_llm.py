@@ -528,6 +528,9 @@ def test_serve_batch_handler_raise_still_fails_batch(rt):
 
 def _clock_identity(clk):
     assert clk["loop"]["s"] >= clk["loop"]["idle_s"] + clk["prefill"]["s"] + clk["decode"]["s"] - 1e-9
+    # the stages tile the loop's life: an equality, not a bound
+    stages = clk["loop"]["idle_s"] + sum(clk[k]["s"] for k in ("admit", "prefill", "batch", "decode", "emit"))
+    assert stages == pytest.approx(clk["loop"]["s"], rel=0.01, abs=1e-6)
 
 
 def _flat(clk):
@@ -537,7 +540,8 @@ def _flat(clk):
 @pytest.mark.parametrize("n_requests,step_delay_s", [(1, 0.0), (3, 0.003), (6, 0.0)])
 def test_engine_stage_clocks(n_requests, step_delay_s):
     """stats()["clocks"]: monotone across calls, queue_wait.n = requests
-    admitted, and the loop's wall time covers idle + prefill + decode."""
+    admitted, and the loop's wall time is idle + admit + prefill + batch +
+    decode + emit."""
     eng = InferenceEngine(
         StubModel(max_slots=2, step_delay_s=step_delay_s),
         EngineConfig(page_tokens=4, pool_pages=64),
@@ -700,7 +704,105 @@ def test_engine_thread_spans_carry_the_request_context():
     assert len(steps) == len(by["llm.decode"]) == len(by["llm.emit"]) == 2
     assert all(s["parent_id"] in steps for s in by["llm.decode"] + by["llm.emit"])
     assert by["llm.step"][0]["attrs"] == {"admitted": 1, "live": 1}
-    assert by["llm.decode"][0]["attrs"] == {"live": 1, "kv_tokens": 4}
+    assert by["llm.decode"][0]["attrs"] == {"live": 1, "kv_tokens": 4, "step": 1, "after_prefill": 1}
+    assert by["llm.decode"][1]["attrs"] == {"live": 1, "kv_tokens": 5, "step": 2, "after_prefill": 0}
+    assert all(s["parent_id"] in steps for s in by["llm.batch"])
+    assert [s["attrs"] for s in by["llm.batch"]] == [{"live": 1}] * 2
+    # the admit that found the request, beside the ones that found nothing
+    assert {"waiting": 0, "admitted": 1, "live": 1} in [s["attrs"] for s in by["llm.admit"]]
+
+
+class _SpannedStub(StubModel):
+    """A stub that puts PagedLM's decode spans around its step, to show what
+    reaches a model through a wrapper that hands `last_tokens` on unopened."""
+
+    def decode(self, last_tokens, positions, block_tables):
+        from ray_tpu import tracing
+
+        attrs = {"step": last_tokens.step}
+        with tracing.span("llm.decode.dispatch", attrs), tracing.span("llm.decode.wait", dict(attrs)):
+            return super().decode(list(last_tokens), positions, block_tables)
+
+
+def _spans_of_an_engines_life(n_requests, step_delay_s):
+    """An engine from start to stop with tracing on: one wait with nothing to
+    do, `n_requests` at once, close. (decode_steps, its llm.* spans)."""
+    from ray_tpu import tracing
+
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    eng = InferenceEngine(
+        _SpannedStub(max_slots=2, step_delay_s=step_delay_s),
+        EngineConfig(page_tokens=4, pool_pages=64),
+        name=f"t-tiles-{n_requests}",
+    )
+    try:
+        time.sleep(0.02)  # the loop finds nothing and waits: an llm.idle before any request
+        outs = []
+        threads = [
+            threading.Thread(target=lambda i=i: outs.append(_collect(eng, [i + 1, 2], 4)))
+            for i in range(n_requests)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert len(outs) == n_requests
+    finally:
+        eng.close()
+        tracing.disable()
+    return eng.decode_steps, [s for s in exp.spans if s["name"].startswith("llm.")]
+
+
+@pytest.mark.parametrize("n_requests,step_delay_s", [(1, 0.0), (3, 0.003), (6, 0.0)])
+def test_engine_loop_spans_tile_the_thread_from_start_to_stop(n_requests, step_delay_s):
+    """Every instant of the engine thread, from the loop's first span to its
+    last, lies under exactly one of llm.idle / llm.admit / llm.step; the
+    stages of a step nest in it; decode steps carry consecutive ordinals, the
+    same on the model's own spans."""
+    for _attempt in range(3):
+        decode_steps, spans = _spans_of_an_engines_life(n_requests, step_delay_s)
+        top = sorted(
+            (s for s in spans if s["name"] in ("llm.idle", "llm.admit", "llm.step")), key=lambda s: s["t0_ns"]
+        )
+        holes = [b["t0_ns"] - a["t1_ns"] for a, b in zip(top, top[1:])]
+        assert min(holes) >= 0  # none overlap
+        if max(holes) < 1_000_000:
+            break  # the loop leaves a clock read and a lock between two spans; a loaded machine can take the
+            # processor from the thread right there, which says nothing of the loop: one quiet life of three shows it
+    assert max(holes) < 1_000_000, sorted(zip(holes, (s["name"] for s in top)))[-3:]
+    (tid,) = {s["tid"] for s in spans if s["name"] == "llm.step"}
+    assert {s["tid"] for s in top} == {tid}
+    assert {s["name"] for s in top} == {"llm.idle", "llm.admit", "llm.step"}
+    assert top[0]["name"] == "llm.admit" and top[0]["attrs"] == {"waiting": 0, "admitted": 0, "live": 0}
+    assert top[-1]["name"] == "llm.admit"  # the one that saw the stop
+    for a, b in zip(top, top[1:]):
+        # an admit is followed by the wait or the step it decided on, and by nothing else
+        assert (a["name"] == "llm.admit") != (b["name"] == "llm.admit")
+        if a["name"] == "llm.admit":
+            assert b["name"] == ("llm.step" if a["attrs"]["live"] else "llm.idle")
+            if b["name"] == "llm.step":
+                assert b["attrs"] == {"admitted": a["attrs"]["admitted"], "live": a["attrs"]["live"]}
+    assert sum(s["attrs"]["admitted"] for s in top if s["name"] == "llm.admit") == n_requests
+
+    steps = [s for s in top if s["name"] == "llm.step"]
+    inner = ("llm.prefill", "llm.batch", "llm.decode", "llm.emit")
+    for name in inner:
+        for s in (s for s in spans if s["name"] == name):
+            (outer,) = [o for o in steps if o["t0_ns"] <= s["t0_ns"] and s["t1_ns"] <= o["t1_ns"]]
+            if name != "llm.prefill":  # a prefill's parent is its request
+                assert s["parent_id"] == outer["span_id"]
+    for o in steps:
+        got = [s["name"] for s in sorted(spans, key=lambda s: s["t0_ns"])
+               if s["name"] in inner and o["t0_ns"] <= s["t0_ns"] < o["t1_ns"]]
+        assert got == ["llm.prefill"] * o["attrs"]["admitted"] + ["llm.batch", "llm.decode", "llm.emit"]
+
+    decodes = sorted((s for s in spans if s["name"] == "llm.decode"), key=lambda s: s["t0_ns"])
+    assert [s["attrs"]["step"] for s in decodes] == list(range(1, decode_steps + 1))
+    assert [s["attrs"]["after_prefill"] for s in decodes] == [int(o["attrs"]["admitted"] > 0) for o in steps]
+    for part in ("llm.decode.dispatch", "llm.decode.wait"):
+        got = sorted((s for s in spans if s["name"] == part), key=lambda s: s["t0_ns"])
+        assert [s["attrs"]["step"] for s in got] == [s["attrs"]["step"] for s in decodes]
 
 
 @pytest.fixture(scope="module")

@@ -184,3 +184,26 @@ def test_paged_steps_read_the_attention_weights_where_they_lie(one_chip, mosaic,
     assert _moved_attention_weights(compiled.as_text(), cfg) == []
     if step == "prefill":
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("step,pages", [("decode", None), ("prefill", 2), ("prefill", 8)])
+def test_paged_executables_carry_their_names_into_the_module(one_chip, step, pages):
+    """PagedLM's jitted closures are named for what they are, so a device
+    trace's `XLA Modules` line tells decode from each prefill bucket
+    (before PR 40 both were `jit_step`): the lowered module and the program
+    compiled for the described chip carry `jit_llm_decode` /
+    `jit_llm_prefill_p<pages>`."""
+    from ray_tpu.serve.llm.model import PagedLM
+
+    lm = PagedLM(num_pages=16, page_tokens=4, max_slots=2, max_pages_per_seq=8)
+    sds = _sds(one_chip)
+    shaped = lambda tree: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params, kv, scalar = shaped(lm.params), shaped(lm.kv), sds((), jnp.int32)
+    if step == "decode":
+        name = "jit_llm_decode"
+        lowered = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 8), jnp.int32))
+    else:
+        name = f"jit_llm_prefill_p{pages}"
+        lowered = lm._get_prefill(pages).lower(params, sds((1, pages * 4), jnp.int32), kv, sds((pages,), jnp.int32), scalar, scalar)
+    assert f"module @{name} " in lowered.as_text()
+    assert f"HloModule {name}," in lowered.compile().as_text()

@@ -203,3 +203,43 @@ def test_device_span_enters_a_trace_annotation(monkeypatch):
         tracing.disable()
     assert entered == [("llm.decode", {"live": 2}), ("llm.decode", {"live": 3})]
     assert [s["name"] for s in exp.spans] == ["llm.decode", "not.device"]
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_add_attrs_reaches_the_span_and_its_annotation(monkeypatch, on):
+    """Attributes learned inside a device span land on the annotation
+    (set_metadata: directly with tracing off, as the span closes with it on)
+    and, with tracing on, in the exported span; where `as sp` bound nothing
+    it is a no-op."""
+    import jax
+
+    late = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            self.kw = kw
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *a):
+            return False
+
+        def set_metadata(self, **kw):
+            late.append(kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp) if on else tracing.disable()
+    try:
+        with tracing.span("llm.admit", {"early": 1}, device=True) as sp:
+            tracing.add_attrs(sp, admitted=2, live=3)
+        with tracing.span("llm.idle", device=True) as sp:
+            pass  # nothing learned: set_metadata is not called
+        with tracing.span("not.device") as sp:
+            tracing.add_attrs(sp, n=1)
+    finally:
+        tracing.disable()
+    assert late == [{"admitted": 2, "live": 3}]
+    if on:
+        assert [s["attrs"] for s in exp.spans] == [{"early": 1, "admitted": 2, "live": 3}, {}, {"n": 1}]
