@@ -962,8 +962,8 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 #
 # Inference substrate for serve/llm: the KV cache is a pool of FIXED-SIZE
 # pages shared by every sequence (vLLM's PagedAttention layout). Prefill
-# walks a prompt in fixed chunks and computes only the chunks that hold a
-# position the cache lacks: each writes its k/v into the pages its block
+# walks what the cache lacks of a prompt in fixed-size chunks, the first
+# starting where the cache ends: each writes its k/v into the pages its block
 # table names and attends over those pages, its own and everything below
 # it, where they lie. Decode appends the new position and attends over the
 # pages each slot holds. Both read the pool through ops/paged_attention.py
@@ -982,11 +982,12 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 TRASH_PAGE = 0
 # Positions one pass of the layers computes in forward_prefill. The weights
 # are read once a chunk, so a chunk must be worth their pass: 256 rows are
-# the v5e ridge (240 FLOP/byte). What a prefix hit still computes is whole
-# chunks, so it must not be larger than that. Chosen on the chip (PERF.md,
-# PR 31): 256 and 512 serve a miss-and-three-hits document alike (a miss
-# costs 11 % more, a hit 23 % less), 256 halves a short suffix's wait;
-# 1 024 is 6 % behind.
+# the v5e ridge (240 FLOP/byte). What a prefix hit still computes is its
+# uncached span rounded UP to chunks (they start where the cache ends), so
+# it must not be larger than that. Chosen on the chip (PERF.md, PR 31, with
+# chunks laid at multiples of their size): 256 and 512 serve a
+# miss-and-three-hits document alike (a miss costs 11 % more, a hit 23 %
+# less), 256 halves a short suffix's wait; 1 024 is 6 % behind.
 PREFILL_CHUNK_TOKENS = 256
 
 
@@ -1012,14 +1013,19 @@ def prefill_chunk_pages(bucket_pages: int, page_tokens: int) -> int:
     return largest_divisor(bucket_pages, max(1, PREFILL_CHUNK_TOKENS // page_tokens))
 
 
-def prefill_chunk_span(length, write_from, chunk_tokens: int, minimum=min, maximum=max):
-    """(first, stop) of the chunks forward_prefill computes: those holding a
-    position in [write_from, length), and always the one with the last
-    position, whose logits are the result even when the cache holds the
-    whole prompt. Python ints (PagedLM counts computed tokens with it), or
-    traced scalars with jnp's minimum / maximum."""
+def prefill_chunk_span(length, write_from, chunk_tokens: int, page_tokens: int, minimum=min, maximum=max):
+    """(anchor, count) of the chunks forward_prefill computes: chunk i covers
+    positions [anchor + i * chunk_tokens, anchor + (i + 1) * chunk_tokens).
+    The anchor is where the cache ends: the page of `write_from`, so the
+    count is the uncached span in chunks, rounded up:
+    ceil((length - anchor) / chunk_tokens). The last position's chunk is
+    always computed, its logits are the result: where the cache holds the
+    whole prompt the anchor is the last position's page. Python ints (PagedLM
+    counts computed tokens with it), or traced scalars with jnp's minimum /
+    maximum."""
     last = maximum(length - 1, 0)
-    return minimum(write_from, last) // chunk_tokens, last // chunk_tokens + 1
+    anchor = minimum(write_from, last) // page_tokens * page_tokens
+    return anchor, (last - anchor) // chunk_tokens + 1
 
 
 def forward_prefill(
@@ -1043,15 +1049,15 @@ def forward_prefill(
       and never rewritten. The radix cache shares whole pages, so it is a
       multiple of the page; 0 is a miss, which runs the same loop.
 
-    The prompt is walked in chunks of `prefill_chunk_pages` pages with a
-    dynamic trip count (`prefill_chunk_span`): chunks wholly below
-    write_from and the bucket's padding chunks past the length are never
-    entered, so the work follows the uncached suffix, not the bucket. A
-    chunk passes through all layers; in each it writes its pages (whole
-    pages, at or above write_from) and its rows attend causally over
-    positions [0, chunk end) of the block table's pages. Rows of a chunk
-    below write_from are recomputed but not written: they read the owner's
-    k/v like every other row.
+    The uncached span is walked in chunks of `prefill_chunk_pages` pages
+    with a dynamic trip count (`prefill_chunk_span`): the first chunk starts
+    at write_from, wherever in the bucket that page lies, and the last is
+    the one that holds the last position, so the work is the uncached
+    suffix rounded up to chunks, not the bucket. A chunk passes through all
+    layers; in each it writes its pages (whole pages, below the length) and
+    its rows attend causally over positions [0, chunk end) of the block
+    table's pages. No row below write_from is computed; the last chunk may
+    run past the bucket's end, over padding made here.
 
     Returns (last-position logits [1, vocab] fp32, updated kv_pages).
     """
@@ -1061,12 +1067,18 @@ def forward_prefill(
     T = kv_pages["k"].shape[2]
     pages = prefill_chunk_pages(S // T, T)
     C = pages * T
-    cos_t, sin_t = rope_tables(cfg, S)
+    # What a chunk slices is padded by a chunk: the last one starts at a
+    # page below the length, not at a multiple of C, and a dynamic slice
+    # that ran past the end would be moved back silently.
+    cos_t, sin_t = rope_tables(cfg, S + C)
+    tokens = jnp.pad(tokens, ((0, 0), (0, C)))
+    dest_table = jnp.pad(block_table, (0, pages), constant_values=TRASH_PAGE)
     use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+    anchor, n_chunks = prefill_chunk_span(length, write_from, C, T, jnp.minimum, jnp.maximum)
 
-    def chunk_step(c, carry):
+    def chunk_step(i, carry):
         kp, vp, _ = carry
-        c0 = c * C
+        c0 = anchor + i * C
         cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
         sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
         x = _embed(params, lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), cfg)
@@ -1078,7 +1090,7 @@ def forward_prefill(
         # position by position).
         first = c0 + jnp.arange(pages) * T
         writable = (first + T > write_from) & (first < length)
-        dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(block_table, c * pages, pages), TRASH_PAGE)
+        dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(dest_table, c0 // T, pages), TRASH_PAGE)
 
         # The pool rides both loops as a carry, written in place (as in
         # forward_decode): no copy of it is made a layer or a chunk.
@@ -1110,9 +1122,8 @@ def forward_prefill(
         # the last position's row, if this is its chunk (the final one is)
         return kp, vp, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0)
 
-    first, stop = prefill_chunk_span(length, write_from, C, jnp.minimum, jnp.maximum)
     h_last = jnp.zeros((cfg.d_model,), cfg.dtype)
-    k_new, v_new, h_last = lax.fori_loop(first, stop, chunk_step, (kv_pages["k"], kv_pages["v"], h_last))
+    k_new, v_new, h_last = lax.fori_loop(0, n_chunks, chunk_step, (kv_pages["k"], kv_pages["v"], h_last))
     h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
     return _logits(params, h_last), {"k": k_new, "v": v_new}
 

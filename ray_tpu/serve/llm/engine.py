@@ -602,8 +602,8 @@ class InferenceEngine:
         grows block tables and builds the step's inputs), emit (the step's
         tokens to their sinks); prefill.tokens: prompt tokens of those calls,
         cached ones included; prefill.computed_tokens: positions their
-        executables computed, as the model reports them: whole chunks with
-        their padding, cached chunks not; decode.kv_pages: over the completed decode steps, the
+        executables computed, as the model reports them: the uncached span
+        rounded up to whole chunks, cached positions not; decode.kv_pages: over the completed decode steps, the
         pages their live lengths cover (what a step must read) against
         slots x pages a sequence (what a step that gathers the block
         tables reads); decode_window (a model with attention windows only):

@@ -11,10 +11,10 @@ PagedLM is the real path: one jitted decode step at static shapes
 whole page pool) serves every batch composition; prefill compiles per
 power-of-two page bucket, so compile count is O(log max_seq), not
 O(distinct prompt lengths). A bucket's one executable serves hit and miss
-alike: it walks the prompt in chunks and computes only those that hold a
-token the cache lacks (transformer.forward_prefill), so `cached_tokens`
-saves the work and not only the pages; what it did compute rides back on
-the token it returns (`PrefillToken.computed_tokens`).
+alike: it walks what the cache lacks in chunks that start at `cached_tokens`
+(transformer.forward_prefill), so a hit computes its uncached span rounded
+up to chunks and nothing below it; what it did compute rides back on the
+token it returns (`PrefillToken.computed_tokens`).
 """
 
 from __future__ import annotations
@@ -31,8 +31,8 @@ from .kv_cache import TRASH_PAGE
 class PrefillToken(int):
     """What `PagedLM.prefill` returns: the first generated token, an int to
     every caller, which also says how many positions the prefill
-    executable computed for it (whole chunks: the bucket's or the chunk's
-    padding included, cached chunks not). The adapter protocol has one
+    executable computed for it (whole chunks from `cached_tokens` on: the
+    last chunk's padding included, cached positions not). The adapter protocol has one
     return value and wrappers hand it on unopened, so this is where the
     engine's `clocks.prefill.computed_tokens` reads it."""
 
@@ -255,7 +255,7 @@ class PagedLM:
     def prefill(self, prompt: Sequence[int], pages: Sequence[int], cached_tokens: int) -> PrefillToken:
         """The prompt's first generated token. Positions below
         `cached_tokens` are read from `pages` (the radix cache matched
-        them); the chunks above are computed and written."""
+        them); the rest is computed and written, in chunks from there on."""
         import numpy as np
 
         T = self.page_tokens
@@ -263,8 +263,8 @@ class PagedLM:
         bucket = self._bucket_pages(n_pages)
         S = bucket * T
         chunk = self._tfm.prefill_chunk_pages(bucket, T) * T
-        first, stop = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk)
-        attrs = {"bucket_tokens": S, "computed_tokens": (stop - first) * chunk}
+        _anchor, chunks = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk, T)
+        attrs = {"bucket_tokens": S, "computed_tokens": chunks * chunk}
         with _tracing.span("llm.prefill.prep", attrs, device=True):
             toks = np.zeros((1, S), dtype=np.int32)
             toks[0, : len(prompt)] = np.asarray(prompt, dtype=np.int32)

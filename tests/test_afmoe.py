@@ -125,7 +125,8 @@ def test_prefill_hit_or_miss_then_decode_past_the_window_in_a_64_slot_batch(monk
     again over those pages with `write_from` > 0, the cached part ending
     before (8 of 21), inside (24 of 37-51: the window reaches back to 21-35)
     and beyond (40 of 64, 32 of 44) the last position's window, in 16-token
-    chunks; then 35 teacher-forced decode steps of the whole 64-slot batch,
+    chunks that start at the cached part's end (8, 24 and 40 are no multiples
+    of 16; 64's second chunk and 9's only one run past their buckets); then 35 teacher-forced decode steps of the whole 64-slot batch,
     over two windows' worth and several page edges, every live row's logits
     against the reference's full forward."""
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", 2 * T)
@@ -152,6 +153,40 @@ def test_prefill_hit_or_miss_then_decode_past_the_window_in_a_64_slot_batch(monk
             assert worst(logits[slot], want[1 + step]) <= TOLERANCE, (slot, step)
         # 64 rows x 2 picks over 8 experts of each of 4 routed layers: counted from what the routed FFN grouped
         assert 4 <= int(stats["experts_touched"]) <= 4 * 8
+
+
+@pytest.mark.parametrize("case", [(60, 40), (51, 24), (56, 56)], ids=[
+    "last_chunk_past_the_bucket", "two_chunks_inside_the_bucket", "the_cache_holds_the_whole_prompt"])
+def test_a_hits_chunks_start_where_the_cache_ends_and_give_the_misss_logits(monkeypatch, case):
+    """A prompt's owner prefills it as a miss into its own pages; the same
+    prompt then hits the owner's first `cached` tokens (a page multiple, no
+    multiple of the 16-token chunk) and computes the rest into pages of its
+    own, in chunks laid from `cached` on: [40, 56) and [56, 72) of a
+    64-token bucket, the second over padding made inside the step. The
+    miss's logits; every page of the owner's byte for byte as it was; the
+    hit's own pages what the owner's hold there. With the whole prompt
+    cached the last position's page alone is computed and nothing written."""
+    length, cached = case
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", 2 * T)
+    cfg, params = seeded(7)
+    tokens = tokens_of(40, length)
+    bucket = 1 << (-(-length // T) - 1).bit_length()
+    padded = jnp.zeros((1, bucket * T), jnp.int32).at[0, :length].set(tokens)
+    prefill = jax.jit(lambda kv, table, write_from: tfm.forward_prefill(params, padded, cfg, kv, table, jnp.int32(length), write_from))
+    owner_table = jnp.arange(1, 1 + bucket, dtype=jnp.int32)
+    miss, owner_kv = prefill(tfm.init_kv_pages(cfg, 1 + 2 * bucket, T), owner_table, jnp.int32(0))
+    assert worst(miss[0], reference(afmoe, params, tokens, jnp.asarray([length - 1]))[0]) <= TOLERANCE
+    shared = cached // T
+    table = jnp.concatenate([owner_table[:shared], jnp.arange(1 + bucket, 1 + 2 * bucket - shared, dtype=jnp.int32)])
+    hit, kv = prefill(owner_kv, table, jnp.int32(cached))
+    assert worst(hit[0], miss[0]) <= TOLERANCE
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(kv[name][:, 1:1 + bucket]), np.asarray(owner_kv[name][:, 1:1 + bucket]))
+        for j in range(shared, -(-length // T)):
+            live = min(T, length - j * T)
+            assert worst(kv[name][:, table[j], :live], owner_kv[name][:, 1 + j, :live]) <= TOLERANCE, (name, j)
+        if cached == length:
+            assert not np.asarray(kv[name][:, 1 + bucket:]).any()
 
 
 @pytest.mark.parametrize("rows", [3, 64])

@@ -185,13 +185,18 @@ def test_interpret_follows_the_backend_like_the_flash_kernels(monkeypatch):
 # ------------------------------------------------- the prefill kernel
 #
 # One chunk of one prompt over the prompt's pages: C rows at positions
-# start.., a table of P = 8 pages. name: (chunk rows, start, length).
+# start.., a table of P = 8 pages. name: (chunk rows, start, length). A
+# hit's chunks start where the cache ends: `start` is a page multiple and
+# need be no multiple of the chunk nor of block_q (two pages here), and the
+# last chunk's rows may run past the table.
 PREFILL_CHUNKS = {
     "whole_prompt_in_one_chunk": (P * T, 0, P * T),
     "first_chunk": (2 * T, 0, 5 * T + 3),
     "a_hits_last_chunk_ending_inside_it": (2 * T, 4 * T, 5 * T + 3),
     "last_chunk_of_a_full_table": (4 * T, 4 * T, P * T),
     "one_page_one_token": (T, 0, 1),
+    "a_hits_chunk_starting_at_an_odd_page": (2 * T, 3 * T, 5 * T + 3),
+    "a_hits_chunk_starting_at_an_odd_page_and_running_past_the_table": (4 * T, 5 * T, P * T),
 }
 
 
@@ -314,6 +319,8 @@ PREFILL_WINDOW_CHUNKS = {  # (chunk rows, start, length): the cached part ends b
     "first_chunk": (2 * T, 0, 5 * T + 3),
     "later_chunk_ending_inside_it": (2 * T, 4 * T, 5 * T + 3),
     "last_chunk_of_a_full_table": (4 * T, 4 * T, P * T),
+    "chunk_starting_at_an_odd_page": (2 * T, 3 * T, 5 * T + 3),
+    "chunk_starting_at_an_odd_page_and_running_past_the_table": (4 * T, 5 * T, P * T),
 }
 
 

@@ -127,19 +127,23 @@ def test_prefill_then_decode_through_the_paged_cache_matches_the_reference_logit
 
 # ------------------------------------------------- prefill: chunks, hit and miss
 #
-# forward_prefill walks a prompt in chunks and computes those that hold a
-# position the cache lacks. At the tiny widths a chunk is set to 16 tokens
-# (two pages), so that a 37-token prompt in a 64-token bucket has three
-# chunks and a fourth that is never entered. name: (prompt tokens, of which
-# the cache holds).
+# forward_prefill walks what the cache lacks of a prompt in chunks, the
+# first starting where the cache ends. At the tiny widths a chunk is set to
+# 16 tokens (two pages), so that a 37-token prompt in a 64-token bucket is
+# three chunks as a miss and one, [24, 40), as a hit of 24 tokens (a page
+# multiple, not a chunk multiple: laid on multiples of 16 its 13 tokens
+# would touch two). name: (prompt tokens, of which the cache holds).
 CHUNK = 16
 PREFILL_CASES = {
     "miss": (37, 0),
-    "hit_ending_inside_a_chunk": (30, 24),
-    "hit_whose_suffix_straddles_a_chunk_boundary": (37, 24),
-    "hit_below_the_first_chunk": (37, 8),
+    "hit_whose_one_chunk_runs_past_the_bucket": (30, 24),
+    "hit_whose_suffix_lies_across_a_multiple_of_the_chunk": (37, 24),
+    "hit_of_one_page": (37, 8),
+    "hit_whose_second_chunk_runs_past_the_bucket": (60, 40),
     "whole_pages_fully_cached": (32, 32),
+    "whole_pages_fully_cached_at_a_multiple_of_the_chunk": (24, 24),
     "bucket_smaller_than_a_chunk": (5, 0),
+    "hit_in_a_bucket_smaller_than_a_chunk": (13, 8),
 }
 OWNER_PAGES, FRESH_PAGES = 1, 9  # the owner's table starts at page 1, a hit's own pages at page 9
 
@@ -165,7 +169,7 @@ def prefill_hit_or_miss(cfg, params, tokens, length, cached, monkeypatch):
 
 
 def long_tokens(cfg, seed):
-    return jax.random.randint(jax.random.fold_in(jax.random.PRNGKey(seed), 3), (40,), 0, cfg.vocab_size, jnp.int32)
+    return jax.random.randint(jax.random.fold_in(jax.random.PRNGKey(seed), 3), (64,), 0, cfg.vocab_size, jnp.int32)
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -193,14 +197,40 @@ def test_chunked_prefill_matches_the_reference_logits_and_the_whole_prompts_page
 
 
 @pytest.mark.parametrize("name", CONFIGS)
-def test_a_hits_prefill_leaves_the_shared_pages_bytes_as_they_were(name, monkeypatch):
-    """A hit recomputes rows below `write_from` when its chunk starts below
-    it (here positions 16..23 of page 2), and chunked k/v are not bit-equal
-    to the owner's: no page of the owner's, shared or not, may be rewritten."""
+@pytest.mark.parametrize("case", [(37, 24), (60, 40), (32, 32)], ids=["one_chunk", "last_chunk_past_the_bucket", "fully_cached"])
+def test_a_hits_prefill_leaves_the_shared_pages_bytes_as_they_were(name, case, monkeypatch):
+    """A hit's chunks start at `write_from` (a page multiple, here no chunk
+    multiple) and compute no row below it; the last may run past the bucket
+    (positions 64..71 of a 64-token one), where its pages go to the trash
+    page. Chunked k/v are not bit-equal to the owner's: no page of the
+    owner's, shared or not, may be rewritten, and where the cache holds the
+    whole prompt nothing but the trash page is."""
+    length, cached = case
     config, arch = tiny(name, torch_dtype="float32")
     cfg, params, _ = seeded(arch, config, 1, jnp.bfloat16)
-    _, pool, table, owner_pool = prefill_hit_or_miss(cfg, params, long_tokens(cfg, 1), 37, 24, monkeypatch)
+    _, pool, table, owner_pool = prefill_hit_or_miss(cfg, params, long_tokens(cfg, 1), length, cached, monkeypatch)
     owner = slice(OWNER_PAGES, FRESH_PAGES)
     for kv in ("k", "v"):
         np.testing.assert_array_equal(np.asarray(pool[kv][:, owner], np.float32), np.asarray(owner_pool[kv][:, owner], np.float32))
-        assert float(jnp.abs(pool[kv][:, table[3]].astype(jnp.float32)).max()) > 0, "the hit wrote its own pages"
+        if cached < length:
+            assert float(jnp.abs(pool[kv][:, table[cached // T]].astype(jnp.float32)).max()) > 0, "the hit wrote its own pages"
+        else:
+            assert float(jnp.abs(pool[kv][:, FRESH_PAGES:].astype(jnp.float32)).max()) == 0, "nothing to write"
+
+
+def test_the_chunk_span_on_python_ints_and_on_traced_scalars_is_the_uncached_span_in_chunks():
+    """`prefill_chunk_span` has two callers, the device loop (traced scalars)
+    and PagedLM's count of computed tokens (Python ints): one result. The
+    anchor is where the cache ends, or the last position's page where the
+    cache holds everything; the count is ceil((length - anchor) / chunk); a
+    miss walks the chunks it always walked."""
+    traced = jax.jit(lambda length, cached, chunk: tfm.prefill_chunk_span(length, cached, chunk, T, jnp.minimum, jnp.maximum), static_argnums=2)
+    grid = [(length, cached, chunk) for chunk in (T, 2 * T, 4 * T) for length in (1, 7, 8, 9, 31, 32, 33, 60, 64)
+            for cached in range(0, length + 1, T)]
+    for length, cached, chunk in grid:
+        anchor, count = tfm.prefill_chunk_span(length, cached, chunk, T)
+        assert (anchor, count) == tuple(int(x) for x in traced(jnp.int32(length), jnp.int32(cached), chunk)), (length, cached, chunk)
+        assert anchor == min(cached, (length - 1) // T * T) and count == -(-(length - anchor) // chunk)
+        assert anchor <= length - 1 < anchor + count * chunk
+        if cached == 0:
+            assert (anchor, count) == (0, (length - 1) // chunk + 1)

@@ -582,13 +582,16 @@ def test_engine_stage_clocks(n_requests, step_delay_s):
     assert final["loop"]["s"] == eng.stats()["clocks"]["loop"]["s"]
 
 
-@pytest.mark.parametrize("suffix", [3, 9, 22], ids=["inside_a_chunk", "into_the_next_chunk", "two_whole_chunks"])
+@pytest.mark.parametrize("suffix", [3, 9, 16, 22], ids=[
+    "under_a_chunk", "across_a_multiple_of_the_chunk", "exactly_a_chunk", "into_a_second_chunk"])
 def test_a_prefix_hit_through_the_engine_computes_less_and_serves_the_same_tokens(suffix, monkeypatch):
     """Two requests that share a 24-token prefix, through InferenceEngine +
-    PagedLM with 16-token chunks: the second's prefill computes only the
-    chunks that hold its own tokens (`clocks.prefill.computed_tokens`, and
-    `computed_tokens` on its `llm.prefill` span), and both stream the tokens
-    the same requests get from an engine that has seen neither (cold)."""
+    PagedLM with 16-token chunks: the second's prefill computes its uncached
+    span in chunks that start at the 24th token, ceil(suffix / 16) of them
+    (`clocks.prefill.computed_tokens`, and `computed_tokens` on its
+    `llm.prefill` span), whichever multiples of 16 the suffix lies across,
+    and both stream the tokens the same requests get from an engine that
+    has seen neither (cold)."""
     import jax.numpy as jnp
 
     from ray_tpu import tracing
@@ -629,8 +632,7 @@ def test_a_prefix_hit_through_the_engine_computes_less_and_serves_the_same_token
     assert one == dict(one, n=1, tokens=29, computed_tokens=32)
     assert kv["prefix_hits"] == 3  # three whole pages of the second were the first's
     computed = two["computed_tokens"] - one["computed_tokens"]
-    first_chunk, stop = 24 // 16, (len(second) - 1) // 16 + 1
-    assert computed == (stop - first_chunk) * 16 < len(second) == two["tokens"] - one["tokens"]
+    assert computed == -(-suffix // 16) * 16 < len(second) == two["tokens"] - one["tokens"]
     spans = [s["attrs"] for s in exp.spans if s["name"] == "llm.prefill"]
     assert [(a["prompt_tokens"], a["cached_tokens"], a["computed_tokens"]) for a in spans] == [
         (29, 0, 32), (len(second), 24, computed)]
