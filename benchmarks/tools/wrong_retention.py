@@ -1,0 +1,133 @@
+"""The wrong models a `brumby` cell's `correct` has to refuse, each a copy of
+`archs/brumby.py` with ONE line of its reference altered (the program and a
+reference that differ by one term, whichever side is wrong), and the precision
+control in the same form: the reference with every weight matrix at fp8's 3
+mantissa bits (`tools/control.py` reads that control from a second, rounded
+copy of the weights, which 7 GB of weights leave no room for beside themselves
+and the states). `tools/wrong_models.py` is this for `archs/afmoe.py`.
+
+    chiprun -- python3 benchmarks/tools/wrong_retention.py --workload brumby14b-serve-longgen-batch \\
+        --wrong degree_1,no_gate,no_normaliser --seed 2147483700 [--seconds 10]
+
+makes a copy of the benchmark under `.chipcheck/wrong/` (git-ignored) in which
+each named wrong model is a configuration and a cell of its own, new files
+only, runs each through that copy's `run.py`, and says per run what `correct`
+compared and decided. Lines go to stdout and chiprun_out/wrong_retention.jsonl.
+Never part of a check. The tests (`tests/test_power_retention.py`,
+`benchmarks/tests/test_brumby_cell.py`) use `source`, `load` and `add_cells`
+at TINY widths.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import subprocess
+import sys
+import types
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+ARCH = os.path.join(ROOT, "benchmarks", "archs", "brumby.py")
+
+# name: (the sound line, the line in its place)
+WRONG = {
+    "degree_1": ("        a = decay * jnp.square(scores)  # [M] degree 2\n", "        a = decay * scores\n"),
+    "no_gate": ('    log_g = jax.nn.log_sigmoid(hn @ _f32(a["wg"]))  # [M] one gate a K/V head, on the layer\'s normed input\n',
+                '    log_g = jnp.zeros((s, m["kv"]), F32)\n'),
+    "no_normaliser": ("        outs.append(num / (den + EPS))  # [M] the normaliser\n", "        outs.append(num)\n"),
+    "head_mod_kv": ("    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)  # query head i reads K/V head i // rep\n",
+                    "    k, v = jnp.tile(k, (1, rep, 1)), jnp.tile(v, (1, rep, 1))\n"),
+    "no_qk_norm": ('    q, k = _rms_norm(q, a["q_norm"]["scale"], m["eps"]), _rms_norm(k, a["k_norm"]["scale"], m["eps"])  # [M] each head\'s dims\n',
+                   "    q, k = q, k\n"),
+    "no_rope": ('    q, k = _rope(q, m["theta"]), _rope(k, m["theta"])  # [M]\n', "    q, k = q, k\n"),
+    "fp8_weights": ("    return w.astype(F32)\n", "    return jax.lax.reduce_precision(w, exponent_bits=8, mantissa_bits=3).astype(F32)\n"),
+}
+
+
+def source(name: str) -> str:
+    """`archs/brumby.py` with the wrong model's one line in place."""
+    src = open(ARCH).read()
+    sound, broken = WRONG[name]
+    if src.count(sound) != 1:
+        raise ValueError(f"archs/brumby.py holds the line of {name!r} {src.count(sound)} times, not once")
+    return src.replace(sound, broken)
+
+
+def load(name: str):
+    """The wrong model's architecture file as a module (never written to disk)."""
+    module = types.ModuleType(f"benchmarks.archs.brumby_{name}")
+    module.__package__ = "benchmarks.archs"
+    exec(compile(source(name), f"<brumby_{name}>", "exec"), module.__dict__)
+    return module
+
+
+def add_cells(root: str, workload: str, names) -> dict:
+    """In the checkout at `root`, adds for each wrong model its architecture
+    file, a configuration naming it and a cell like `workload`; returns
+    {name: cell}. New files and BENCHMARK.json entries only."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell = next(w for w in bench["workloads"] if w["name"] == workload)
+    conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    with open(os.path.join(root, conf["file"])) as f:
+        config = json.load(f)
+    cells = {}
+    for name in names:
+        arch, cname = f"brumby_{name}", f"{conf['name']}-{name}"
+        with open(os.path.join(root, "benchmarks", "archs", arch + ".py"), "w") as f:
+            f.write(source(name))
+        with open(os.path.join(root, "benchmarks", "configs", cname + ".json"), "w") as f:
+            json.dump(dict(config, arch=arch), f)
+        bench["configs"].append(dict(conf, name=cname, file=f"benchmarks/configs/{cname}.json"))
+        cells[name] = f"{workload}-{name}"
+        bench["workloads"].append(dict(cell, name=cells[name], config=cname))
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if workload in m.get("workloads", []) and m["name"] in ("serve_tok_s", "decode_batch_mean.retention"):
+                m["workloads"].append(cells[name])
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return cells
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--wrong", default=",".join(WRONG))
+    ap.add_argument("--seed", type=int, default=2147483700)
+    ap.add_argument("--seconds", type=float, default=10.0)
+    args = ap.parse_args()
+    names = args.wrong.split(",")
+    root = os.path.join(ROOT, ".chipcheck", "wrong")
+    shutil.rmtree(root, ignore_errors=True)
+    os.makedirs(root)
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), os.path.join(root, "benchmarks"), ignore=shutil.ignore_patterns("out", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    os.symlink(os.path.join(ROOT, "ray_tpu"), os.path.join(root, "ray_tpu"))
+    cells = add_cells(root, args.workload, names)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    for i, name in enumerate(names):
+        cmd = [sys.executable, os.path.join(root, "benchmarks", "run.py"), "--workload", cells[name], "--seed", str(args.seed + i),
+               "--seconds", str(args.seconds), "--trace", "0"]
+        p = subprocess.run(cmd, cwd=root, capture_output=True, text=True)
+        last = (p.stdout.strip().splitlines() or [""])[-1]
+        out = {"wrong": name, "seed": args.seed + i, "rc": p.returncode}
+        if p.returncode == 0 and last.startswith("{"):
+            line = json.loads(last)
+            facts = [ln for ln in p.stdout.splitlines() if ln.startswith("benchmark: facts ")]
+            sample = json.loads(facts[-1][len("benchmark: facts "):])["served_sample"]
+            out.update(correct=line["correct"], failed=line["failed"], compared=line["compared"], margins=sample["margins"],
+                       reference_seconds=sample["seconds"], serve_tok_s=line["metrics"].get("serve_tok_s", {}).get("value"))
+        else:
+            out["stderr"] = p.stderr[-1500:]
+        text = json.dumps(out)
+        print("wrong_retention: " + text, flush=True)
+        with open(os.path.join(ROOT, "chiprun_out", "wrong_retention.jsonl"), "a") as f:
+            f.write(text + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

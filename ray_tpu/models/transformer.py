@@ -110,6 +110,17 @@ class TransformerConfig:
     attn_gate: bool = False
     post_norms: bool = False
     embed_scale: bool = False
+    # Power retention (Brumby family) in softmax attention's place, off at 0;
+    # 2 is the one degree computed. A layer then attends by
+    # a_ts = exp(L_t - L_s) (q_t . k_s / sqrt(head_dim))^2, normalised by its
+    # own sum + RETENTION_EPS, L the running sum of the gate's log-sigmoid
+    # (`wg`, one logit a K/V head); no softmax, mask beyond s <= t or window.
+    # What a served sequence keeps of its past is then no K/V: a "page" of
+    # `init_kv_pages` is one sequence's whole recurrent state, float32, of a
+    # fixed size whatever its length (`retention_state_dim`), read and
+    # written whole every decode step, one page a sequence for its life,
+    # where a softmax config's page holds `page_tokens` positions of K/V.
+    retention_degree: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -168,6 +179,10 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             raise ValueError(f"{name} has {len(getattr(cfg, name))} entries for {cfg.n_layers} layers")
     if cfg.n_dense_layers and not (E and 0 < cfg.n_dense_layers < cfg.n_layers and cfg.d_ff_dense):
         raise ValueError("leading dense layers go before routed ones and need d_ff_dense")
+    if cfg.retention_degree not in (0, 2):
+        raise ValueError(f"retention_degree {cfg.retention_degree!r}: 0 (softmax attention) or 2 is computed")
+    if cfg.retention_degree and (cfg.attn_gate or any(cfg.windows) or nh % nkv or hd % 2):
+        raise ValueError("power retention has a gate of its own (`wg`: a logit a K/V head) and no window")
     k = iter(jax.random.split(key, 16))
     # What this model has over the llama and OLMoE blocks draws from a stream
     # of its own: theirs give the same weights for a key as before.
@@ -215,6 +230,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             out["attn"]["k_norm"] = {"scale": jnp.ones((L, kn), cfg.dtype)}
         if cfg.attn_gate:
             out["attn"]["wg"] = dense(next(k2), (L, d, nh * hd), d)
+        if cfg.retention_degree:
+            out["attn"]["wg"] = dense(next(k2), (L, d, nkv), d)
         if cfg.post_norms:
             out["post_attn_norm"] = {"scale": jnp.ones((L, d), cfg.dtype)}
             out["post_mlp_norm"] = {"scale": jnp.ones((L, d), cfg.dtype)}
@@ -310,11 +327,21 @@ def _norm(x, scale, cfg: TransformerConfig):
     return rms_norm(x, scale, cfg.norm_eps)
 
 
-def rope_tables(cfg: TransformerConfig, seq_len: int):
+def _rope_freqs(cfg: TransformerConfig):
     half = (cfg.rotary_dim or cfg.head_dim) // 2
-    freqs = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    return cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+
+def rope_tables(cfg: TransformerConfig, seq_len: int):
+    freqs = _rope_freqs(cfg)
     angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * freqs[None, :]
     return jnp.cos(angles), jnp.sin(angles)  # [seq, rotary_dim/2]
+
+
+def rope_at(cfg: TransformerConfig, positions):
+    """rope_tables' rows at the positions given [n]: cos, sin [n, rotary_dim/2]."""
+    angles = positions.astype(jnp.float32)[:, None] * _rope_freqs(cfg)[None, :]
+    return jnp.cos(angles), jnp.sin(angles)
 
 
 def _rotate(x, cos, sin, interleave: bool):
@@ -507,10 +534,138 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh], window=Non
     return attention_reference(q, k, v, causal=True)
 
 
+# --------------------------------------------------------- power retention
+#
+# A retention layer (cfg.retention_degree 2) attends by
+#     a_ts = exp(L_t - L_s) (q_t . k_s / sqrt(hd))^2        for s <= t,
+#     y_t  = sum_s a_ts v_s / (sum_s a_ts + RETENTION_EPS),
+# L the running sum of the gate's log-sigmoid, one gate a K/V head. With
+# phi(x) holding the products x_a x_b, phi(q) . phi(k) = (q . k)^2, so the
+# past of a sequence is one state a K/V head: S = sum_s exp(L_t - L_s) v_s
+# phi(k_s)^T [hd, D] and z = sum_s exp(L_t - L_s) phi(k_s) [D]. Three forms
+# of the same numbers: `retention_whole` (a whole sequence, nothing kept),
+# `retention_chunk` (a prefill chunk, from a state to a state) and
+# `retention_step` (one token a sequence; ops/power_retention.py is its
+# kernel). Everything here is float32, the matmuls at RETENTION_PRECISION:
+# a state is thousands of decayed additions into one array.
+#
+# The state's layout: S is stored TRANSPOSED, [hd (v's index), D], the pairs
+# on the minor axis, and phi's D = (hd / 2 + 1) * hd entries lie as hd / 2 + 1
+# rows of hd: row d holds w_d x_a x_((a + d) mod hd) for a = 0..hd-1, the
+# pairs at circular distance d. Every unordered pair {a, b} is held once
+# (w = sqrt(2): its two orders), the squares once (row 0, w = 1), and the
+# pairs at distance hd / 2 twice with w = 1 each (row hd / 2: a and a + hd/2
+# name the same pair). At hd 128: 65 rows of 128 lanes, 8 320 entries where
+# the unordered pairs are 8 256; a rotation of x by d lanes makes row d.
+
+RETENTION_EPS = 1e-6
+RETENTION_PRECISION = lax.Precision.HIGHEST
+
+
+def retention_state_dim(head_dim: int) -> int:
+    """Entries of phi(x) for x of head_dim dims, as the state holds them."""
+    return (head_dim // 2 + 1) * head_dim
+
+
+def retention_phi(x):
+    """x [..., hd] -> phi(x) [..., D] in x's dtype (float32 from every caller
+    here): phi(x) . phi(y) = (x . y)^2, in the layout above."""
+    hd = x.shape[-1]
+    twice = jnp.concatenate([x, x[..., : hd // 2]], axis=-1)
+    rows = [x * twice[..., d : d + hd] * (1.0 if d in (0, hd // 2) else math.sqrt(2.0)) for d in range(hd // 2 + 1)]
+    return jnp.concatenate(rows, axis=-1)
+
+
+def _retention_heads(q, k, v, log_g):
+    """float32 views a K/V head: q [.., kv, r, hd] scaled by 1 / sqrt(hd) (the
+    scale inside the power), k, v [.., kv, hd], log_g [.., kv]."""
+    hd, kv = q.shape[-1], k.shape[-2]
+    f32 = jnp.float32
+    q = q.astype(f32).reshape(*q.shape[:-2], kv, q.shape[-2] // kv, hd) / math.sqrt(hd)
+    return q, k.astype(f32), v.astype(f32), log_g.astype(f32)
+
+
+def retention_chunk(q, k, v, log_g, s_in, z_in, valid=None):
+    """One chunk of ONE sequence from the state before its first row to the
+    state after its last: q [c, n_heads, hd], k / v [c, n_kv_heads, hd],
+    log_g [c, n_kv_heads], s_in [n_kv_heads, hd, D], z_in [n_kv_heads, D] ->
+    (y [c, n_heads, hd] float32, s_out, z_out). The in-chunk pairs by the
+    quadratic expression, the earlier ones through phi(q)^T S_in. `valid` [c]
+    bool: rows past a prompt's length (padding: they follow every valid row)
+    neither decay the state nor enter it; their own outputs are arbitrary."""
+    c, H, hd = q.shape
+    q, k, v, log_g = _retention_heads(q, k, v, log_g)
+    dot = partial(jnp.einsum, precision=RETENTION_PRECISION)
+    if valid is not None:
+        log_g = jnp.where(valid[:, None], log_g, 0.0)
+    L = jnp.cumsum(log_g, axis=0)  # [c, kv]: the decay from the chunk's start to each row, that row's gate included
+    back = jnp.arange(c)[:, None] - jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(back >= 0, L.T[:, :, None] - L.T[:, None, :], -jnp.inf))  # [kv, t, s]
+    a = jnp.square(dot("tjrd,sjd->jrts", q, k)) * decay[:, None]
+    carried = jnp.exp(L)[:, :, None]  # [c, kv, 1]
+    pq = retention_phi(q)
+    num = dot("jrts,sje->tjre", a, v) + carried[..., None] * dot("tjrD,jeD->tjre", pq, s_in)
+    den = jnp.moveaxis(jnp.sum(a, axis=-1), -1, 0) + carried * dot("tjrD,jD->tjr", pq, z_in)
+    y = num / (den[..., None] + RETENTION_EPS)
+    # What each row leaves in the state at the chunk's end.
+    left = jnp.exp(L[-1][None, :] - L)
+    if valid is not None:
+        left = jnp.where(valid[:, None], left, 0.0)
+    pk = retention_phi(k)
+    s_out = jnp.exp(L[-1])[:, None, None] * s_in + dot("sje,sjD->jeD", v * left[..., None], pk)
+    z_out = jnp.exp(L[-1])[:, None] * z_in + dot("sj,sjD->jD", left, pk)
+    return y.reshape(c, H, hd), s_out, z_out
+
+
+def retention_step(q, k, v, log_g, s, z):
+    """One token a sequence: q [B, n_heads, hd], k / v [B, n_kv_heads, hd],
+    log_g [B, n_kv_heads], s [B, n_kv_heads, hd, D], z [B, n_kv_heads, D] ->
+    (y [B, n_heads, hd] float32, s_new, z_new): the state decayed by the
+    token's gate, the token added, then read by its query heads. The plain
+    expression; ops/power_retention.py is its one-pass kernel."""
+    B, H, hd = q.shape
+    q, k, v, log_g = _retention_heads(q, k, v, log_g)
+    dot = partial(jnp.einsum, precision=RETENTION_PRECISION)
+    g, pk, pq = jnp.exp(log_g), retention_phi(k), retention_phi(q)
+    s = g[..., None, None] * s + v[..., :, None] * pk[..., None, :]
+    z = g[..., None] * z + pk
+    y = dot("bjrD,bjeD->bjre", pq, s) / (dot("bjrD,bjD->bjr", pq, z)[..., None] + RETENTION_EPS)
+    return y.reshape(B, H, hd), s, z
+
+
+def retention_whole(q, k, v, log_g, chunk: Optional[int] = None):
+    """Whole sequences that keep nothing: q [b, s, n_heads, hd], k / v
+    [b, s, n_kv_heads, hd], log_g [b, s, n_kv_heads] -> y [b, s, n_heads, hd]
+    float32. `retention_chunk` from a zero state over chunks of `chunk` rows
+    (PREFILL_CHUNK_TOKENS), the last one padded."""
+    b, s, H, hd = q.shape
+    kv = k.shape[2]
+    c = min(chunk or PREFILL_CHUNK_TOKENS, s)
+    n = -(-s // c)
+    valid = (jnp.arange(n * c) < s).reshape(n, c)
+
+    def one(q, k, v, log_g):
+        def chunks(t):
+            return jnp.pad(t, [(0, n * c - s)] + [(0, 0)] * (t.ndim - 1)).reshape(n, c, *t.shape[1:])
+
+        def step(state, xs):
+            y, s_out, z_out = retention_chunk(*xs[:4], *state, valid=xs[4])
+            return (s_out, z_out), y
+
+        D = retention_state_dim(hd)
+        zero = (jnp.zeros((kv, hd, D), jnp.float32), jnp.zeros((kv, D), jnp.float32))
+        _, ys = lax.scan(step, zero, (chunks(q), chunks(k), chunks(v), chunks(log_g), valid))
+        return ys.reshape(n * c, H, hd)[:s]
+
+    return jax.vmap(one)(q, k, v, log_g)
+
+
 def _qkv(h, ap, cfg: TransformerConfig, split: bool):
     """h [b, s, d] -> q, k, v before rope: split into heads ([b, s, n_heads,
     hd], [b, s, n_kv_heads, hd]) or each as its projection gives it
-    ([b, s, n_heads * hd], [b, s, n_kv_heads * hd]); `_block` says which."""
+    ([b, s, n_heads * hd], [b, s, n_kv_heads * hd]); `_block` says which.
+    A fourth beside them: the retention gate's logits [b, s, n_kv_heads]
+    float32, None for a softmax config."""
     q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
@@ -522,7 +677,10 @@ def _qkv(h, ap, cfg: TransformerConfig, split: bool):
             q = norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
             k = norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
     view = (lambda t: t.reshape(*t.shape[:2], -1, cfg.head_dim)) if split else (lambda t: t)
-    return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype)
+    gate = None
+    if cfg.retention_degree:
+        gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
+    return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype), gate
 
 
 def _ffn(h, mp, cfg: TransformerConfig, experts=None):
@@ -664,9 +822,11 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     attention, output projection, residual, norm, feed-forward, residual.
     What differs between training, prefill and decode is how q attends,
     and the caller passes that: `attend(q, k, v) -> (o [b, s, n_heads,
-    head_dim], kept)`, with q and k after rope. `kept` is whatever the
-    caller wants back (the K/V pool it wrote k and v into; None in
-    training). Returns (out, kept), and as a third what the router did with
+    head_dim], kept)`, with q and k after rope; under power retention
+    `attend(q, k, v, log_g)`, log_g [b, s, n_kv_heads] float32 the gate's
+    log-sigmoid. `kept` is whatever the caller wants back (the K/V or state
+    pool it wrote into; None in training). Returns (out, kept), and as a
+    third what the router did with
     this layer's input if `stats` is "route" (`_route_stats`), or the rows
     each expert took [E] if it is "experts" (None from a dense FFN).
     `experts`: a routed layer's expert matrices where the caller keeps them
@@ -685,11 +845,12 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     # smaller of the two should move: the weight while a call has at least
     # as many rows as the weight has (a training batch), the result while
     # it has fewer (a decode step, a prefill chunk).
-    q, k, v = _qkv(h, ap, cfg, split=b * s >= d)
+    q, k, v, gate = _qkv(h, ap, cfg, split=b * s >= d)
     q = _ckpt(apply_rope(q, cos, sin, cfg), "q_bf16")
     k = _ckpt(apply_rope(k, cos, sin, cfg), "k_bf16")
     v = _ckpt(v, "v_bf16")
-    o, kept = attend(*(t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)))
+    heads = [t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)]
+    o, kept = attend(*heads) if gate is None else attend(*heads, jax.nn.log_sigmoid(gate))
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     if cfg.attn_gate:
         with jax.named_scope("attn.gate"):
@@ -729,6 +890,15 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
 def _attend_whole(cfg: TransformerConfig, mesh: Optional[Mesh], window=None):
     """The attention strategy of a whole sequence that keeps nothing:
     training, `forward`, `routing_stats`."""
+    if cfg.retention_degree:
+        # As with windows: the flash, ring and ulysses kernels are softmax
+        # attention, and a retention layer through them would be another model.
+        if cfg.attn_impl != "naive":
+            raise ValueError(
+                f"attn_impl={cfg.attn_impl!r} computes softmax attention; a config with `retention_degree` "
+                "runs its whole-sequence forward with attn_impl='naive' (`retention_whole`)"
+            )
+        return lambda q, k, v, log_g: (retention_whole(q, k, v, log_g).astype(q.dtype), None)
     return lambda q, k, v: (_attention(q, k, v, cfg, mesh, window), None)
 
 
@@ -938,7 +1108,10 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     2 matmuls x 2 MAC-FLOPs x (seq/2) x d_model forward, x3 for fwd+bwd.
     A routed FFN counts the router, the shared expert and the
     n_experts_per_tok experts a token passes through, not the experts it
-    leaves alone; a window is not taken off the attention."""
+    leaves alone; a window is not taken off the attention. A retention
+    layer counts its gate, and in attention's place the chunked form: every
+    query head reads the state (D x head_dim), every K/V head adds to it, and
+    the in-chunk pairs (half a chunk visible on average)."""
     ffn = 3 * cfg.d_model * cfg.d_ff
     if cfg.n_experts:
         ffn = ffn * cfg.n_experts_per_tok + cfg.d_model * (cfg.n_experts + 3 * cfg.d_ff_shared)
@@ -949,12 +1122,17 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
         * (
             (3 if cfg.attn_gate else 2) * cfg.d_model * cfg.n_heads * cfg.head_dim
             + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
+            + (cfg.d_model * cfg.n_kv_heads if cfg.retention_degree else 0)
         )
         + (cfg.n_layers - nd) * ffn
         + nd * 3 * cfg.d_model * cfg.d_ff_dense
         + (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size)
     )
     attn = 12 * cfg.n_layers * cfg.n_heads * cfg.head_dim * (seq_len / 2)
+    if cfg.retention_degree:
+        D, hd = retention_state_dim(cfg.head_dim), cfg.head_dim
+        visible = min(PREFILL_CHUNK_TOKENS, seq_len) / 2
+        attn = 6 * cfg.n_layers * ((cfg.n_heads + cfg.n_kv_heads) * D * hd + 2 * cfg.n_heads * hd * visible)
     return 6.0 * n_params + attn
 
 
@@ -999,9 +1177,33 @@ def init_kv_pages(
     tokens x (head, dim): the tile the paged kernels copy and multiply
     as it lies, so heads and dim are ONE axis of the stored array (split,
     the device's tiled layout would put heads where the kernel needs
-    tokens, and every step would pay a relayout of the pool)."""
+    tokens, and every step would pay a relayout of the pool).
+
+    Under power retention a page is ONE sequence's recurrent state and
+    `page_tokens` only bounds the positions it may be served (it shapes
+    nothing): `s` [n_layers, num_pages, n_kv_heads, head_dim, D] and `z`
+    [n_layers, num_pages, n_kv_heads, D], float32 (the layout: see
+    `retention_phi`). The pool is one tree either way: forward_prefill and
+    forward_decode take it and hand it back under whatever names it has."""
+    if cfg.retention_degree:
+        D = retention_state_dim(cfg.head_dim)
+        return {
+            "s": jnp.zeros((cfg.n_layers, num_pages, cfg.n_kv_heads, cfg.head_dim, D), jnp.float32),
+            "z": jnp.zeros((cfg.n_layers, num_pages, cfg.n_kv_heads, D), jnp.float32),
+        }
     shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def prefill_chunk_tokens(cfg: TransformerConfig, bucket_pages: int, page_tokens: int) -> Tuple[int, int]:
+    """(positions one chunk of forward_prefill computes, the granule its first
+    chunk is anchored to) for a bucket of that many pages. K/V pages: whole
+    pages (`prefill_chunk_pages`), anchored at a page's start. A state:
+    PREFILL_CHUNK_TOKENS positions inside its one page, anchored at the very
+    position the state has reached."""
+    if cfg.retention_degree:
+        return min(PREFILL_CHUNK_TOKENS, bucket_pages * page_tokens), 1
+    return prefill_chunk_pages(bucket_pages, page_tokens) * page_tokens, page_tokens
 
 
 def prefill_chunk_pages(bucket_pages: int, page_tokens: int) -> int:
@@ -1059,73 +1261,107 @@ def forward_prefill(
     table's pages. No row below write_from is computed; the last chunk may
     run past the bucket's end, over padding made here.
 
+    Under power retention (`cfg.retention_degree`) the pool holds states
+    (`init_kv_pages`), `block_table` is [1], the sequence's one slot, and
+    `write_from` is the position its state has reached: the chunks
+    (`prefill_chunk_tokens`: PREFILL_CHUNK_TOKENS rows inside the one page)
+    start exactly there, each from the state the one before it left in the
+    slot, the one at position 0 from nothing whatever the slot held; rows
+    past `length` leave the state as it was.
+
     Returns (last-position logits [1, vocab] fp32, updated kv_pages).
     """
     from ..ops.paged_attention import paged_prefill_attention
 
     _, S = tokens.shape
-    T = kv_pages["k"].shape[2]
-    pages = prefill_chunk_pages(S // T, T)
-    C = pages * T
+    state = bool(cfg.retention_degree)
+    names = ("s", "z") if state else ("k", "v")
+    T = S // block_table.shape[0] if state else kv_pages["k"].shape[2]
+    C, granule = prefill_chunk_tokens(cfg, S // T, T)
+    pages = C // T
     # What a chunk slices is padded by a chunk: the last one starts at a
     # page below the length, not at a multiple of C, and a dynamic slice
     # that ran past the end would be moved back silently.
     cos_t, sin_t = rope_tables(cfg, S + C)
     tokens = jnp.pad(tokens, ((0, 0), (0, C)))
-    dest_table = jnp.pad(block_table, (0, pages), constant_values=TRASH_PAGE)
-    use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
-    anchor, n_chunks = prefill_chunk_span(length, write_from, C, T, jnp.minimum, jnp.maximum)
+    dest_table = None if state else jnp.pad(block_table, (0, pages), constant_values=TRASH_PAGE)
+    use_kernel = not state and paged_attention_path(cfg, T) == "paged_kernel"
+    anchor, n_chunks = prefill_chunk_span(length, write_from, C, granule, jnp.minimum, jnp.maximum)
 
     def chunk_step(i, carry):
-        kp, vp, _ = carry
+        *pool, _ = carry
         c0 = anchor + i * C
         cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
         sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
         x = _embed(params, lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), cfg)
-        # Whole pages are written (a page is one contiguous tile of the
-        # pool; a token row cuts through 32 of them): every page of the
-        # chunk that holds a position in [write_from, length). The rest of
-        # the prompt's last page receives the padding's k/v, which nothing
-        # reads (attention stops at the length and decode overwrites
-        # position by position).
-        first = c0 + jnp.arange(pages) * T
-        writable = (first + T > write_from) & (first < length)
-        dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(dest_table, c0 // T, pages), TRASH_PAGE)
+        if state:
+            attend_in = partial(_state_chunk_attend, cfg, block_table[0], c0, c0 + jnp.arange(C) < length)
+        else:
+            # Whole pages are written (a page is one contiguous tile of the
+            # pool; a token row cuts through 32 of them): every page of the
+            # chunk that holds a position in [write_from, length). The rest of
+            # the prompt's last page receives the padding's k/v, which nothing
+            # reads (attention stops at the length and decode overwrites
+            # position by position).
+            first = c0 + jnp.arange(pages) * T
+            writable = (first + T > write_from) & (first < length)
+            dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(dest_table, c0 // T, pages), TRASH_PAGE)
+
+            def attend_in(layer, window, kp, vp):
+                def attend(q, k, v):
+                    kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
+                    vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
+                    # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
+                    with _window_scope(window):
+                        if use_kernel:
+                            o = paged_prefill_attention(
+                                q[0], kp_, vp_, layer, block_table, c0, length, n_kv_heads=cfg.n_kv_heads, window=window
+                            )
+                        else:
+                            o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window)
+                    return o[None].astype(cfg.dtype), (kp_, vp_)
+
+                return attend
 
         # The pool rides both loops as a carry, written in place (as in
         # forward_decode): no copy of it is made a layer or a chunk.
         def scan_step(stack, first_layer, carry, inputs):
-            x, kp, vp = carry
+            x, *pool = carry
             layer, window, rope_on, layer_params = inputs
-
-            def attend(q, k, v):
-                kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
-                vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
-                # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
-                with _window_scope(window):
-                    if use_kernel:
-                        o = paged_prefill_attention(
-                            q[0], kp_, vp_, layer, block_table, c0, length, n_kv_heads=cfg.n_kv_heads, window=window
-                        )
-                    else:
-                        o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window)
-                return o[None].astype(cfg.dtype), (kp_, vp_)
-
             experts = None if stack is None else (stack, layer - first_layer)
-            x, (kp, vp) = _block(x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend, experts=experts)
-            return (x, kp, vp), None
+            x, pool = _block(
+                x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend_in(layer, window, *pool), experts=experts
+            )
+            return (x, *pool), None
 
         for blocks, first_layer, n in _layer_groups(params, cfg):
             riding, stack = _experts_in_place(blocks)
             xs = (jnp.arange(first_layer, first_layer + n), *_per_layer(cfg, first_layer, n), riding)
-            (x, kp, vp), _ = lax.scan(partial(scan_step, stack, first_layer), (x, kp, vp), xs)
+            (x, *pool), _ = lax.scan(partial(scan_step, stack, first_layer), (x, *pool), xs)
         # the last position's row, if this is its chunk (the final one is)
-        return kp, vp, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0)
+        return (*pool, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0))
 
     h_last = jnp.zeros((cfg.d_model,), cfg.dtype)
-    k_new, v_new, h_last = lax.fori_loop(0, n_chunks, chunk_step, (kv_pages["k"], kv_pages["v"], h_last))
+    *pool, h_last = lax.fori_loop(0, n_chunks, chunk_step, (*(kv_pages[name] for name in names), h_last))
     h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
-    return _logits(params, h_last), {"k": k_new, "v": v_new}
+    return _logits(params, h_last), dict(zip(names, pool))
+
+
+def _state_chunk_attend(cfg: TransformerConfig, slot, c0, valid, layer, window, sp, zp):
+    """forward_prefill's `attend` of one layer under power retention: the
+    chunk at positions [c0, c0 + C) of the sequence whose state is page
+    `slot` of the pool sp / zp. It starts from what the slot holds, the
+    state after position c0 - 1, or from nothing where c0 is 0, whatever the
+    slot's last owner left there; `valid` [C]: the rows below the length."""
+
+    def attend(q, k, v, log_g):
+        s_in = jnp.where(c0 > 0, sp[layer, slot], 0.0)
+        z_in = jnp.where(c0 > 0, zp[layer, slot], 0.0)
+        with jax.named_scope("retention.chunk"):
+            y, s_out, z_out = retention_chunk(q[0], k[0], v[0], log_g[0], s_in, z_in, valid)
+        return y[None].astype(cfg.dtype), (sp.at[layer, slot].set(s_out), zp.at[layer, slot].set(z_out))
+
+    return attend
 
 
 def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int, window=None):
@@ -1213,54 +1449,67 @@ def forward_decode(
     With `stats`, a third: {"experts_touched": int32 scalar}, the distinct
     experts that the step's B rows chose, summed over the routed layers
     (each is a matrix triple the step has to read); 0 for a dense model.
+    Under power retention block_tables is [B, 1], each row's state slot: a
+    step decays, adds to and reads each active row's state of every layer in
+    place (`_state_step_attend`); inactive rows use the trash slot.
     """
     from ..ops.paged_attention import paged_attention
 
     B = tokens.shape[0]
-    T = kv_pages["k"].shape[2]
+    state = bool(cfg.retention_degree)
+    names = ("s", "z") if state else ("k", "v")
     P = block_tables.shape[1]
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
 
-    cos_t, sin_t = rope_tables(cfg, P * T)
-    cos = jnp.take(cos_t, pos, axis=0)[:, None, :]  # [B, 1, rd/2]: each row its own position
-    sin = jnp.take(sin_t, pos, axis=0)[:, None, :]
+    if state:  # no page says how many positions there are: the angles of each row's own
+        cos, sin = (t[:, None, :] for t in rope_at(cfg, pos))
+    else:
+        T = kv_pages["k"].shape[2]
+        cos_t, sin_t = rope_tables(cfg, P * T)
+        cos = jnp.take(cos_t, pos, axis=0)[:, None, :]  # [B, 1, rd/2]: each row its own position
+        sin = jnp.take(sin_t, pos, axis=0)[:, None, :]
 
     x = _embed(params, tokens, cfg)[:, None, :]  # [B,1,d]
-    rows = jnp.arange(B)
-    dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
-    dest_slot = pos % T
-    lengths = jnp.where(active, pos + 1, 0)
-    use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+    if state:
+        attend_in = partial(_state_step_attend, cfg, jnp.where(active, block_tables[:, 0], TRASH_PAGE), active)
+    else:
+        rows = jnp.arange(B)
+        dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
+        dest_slot = pos % T
+        lengths = jnp.where(active, pos + 1, 0)
+        use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+
+        def attend_in(layer, window, kp, vp):
+            def attend(q, k, v):
+                kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
+                vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
+                # Attend AFTER the append so the new position attends to itself.
+                with _window_scope(window):
+                    if use_kernel:
+                        o = paged_attention(
+                            q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window
+                        )
+                    else:
+                        o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window)
+                return o.astype(cfg.dtype), (kp_, vp_)
+
+            return attend
 
     # The pool rides the layer scan as a CARRY: each layer appends into its
     # own slice in place and the kernel reads the pool where it lies. As
     # xs/ys every step would copy the whole pool out of the stacked array
     # and back in.
     def scan_step(stack, first, carry, inputs):
-        x, kp, vp = carry
+        x, *pool = carry
         layer, window, rope_on, layer_params = inputs
-
-        def attend(q, k, v):
-            kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
-            vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
-            # Attend AFTER the append so the new position attends to itself.
-            with _window_scope(window):
-                if use_kernel:
-                    o = paged_attention(
-                        q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window
-                    )
-                else:
-                    o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window)
-            return o.astype(cfg.dtype), (kp_, vp_)
-
-        x, (kp, vp), *rows_per_expert = _block(
-            x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend, stats="experts" if stats else "",
-            experts=None if stack is None else (stack, layer - first),
+        x, pool, *rows_per_expert = _block(
+            x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend_in(layer, window, *pool),
+            stats="experts" if stats else "", experts=None if stack is None else (stack, layer - first),
         )
-        return (x, kp, vp), (rows_per_expert[0] if stats else None)
+        return (x, *pool), (rows_per_expert[0] if stats else None)
 
-    carry, touched = (x, kv_pages["k"], kv_pages["v"]), jnp.int32(0)
+    carry, touched = (x, *(kv_pages[name] for name in names)), jnp.int32(0)
     for blocks, first, n in _layer_groups(params, cfg):
         riding, stack = _experts_in_place(blocks)
         carry, rows_per_expert = lax.scan(
@@ -1268,7 +1517,27 @@ def forward_decode(
         )
         if rows_per_expert is not None:  # [n, E] of a routed group
             touched = touched + jnp.sum(rows_per_expert > 0, dtype=jnp.int32)
-    x, k_new, v_new = carry
+    x, *pool = carry
     x = _norm(x, params["final_norm"]["scale"], cfg)
-    out = _logits(params, x[:, 0]), {"k": k_new, "v": v_new}
+    out = _logits(params, x[:, 0]), dict(zip(names, pool))
     return (*out, {"experts_touched": touched}) if stats else out
+
+
+def _state_step_attend(cfg: TransformerConfig, slots, active, layer, window, sp, zp):
+    """forward_decode's `attend` of one layer under power retention: row b's
+    state is page slots[b] of the pool sp / zp (the trash page for a row
+    that is not `active`); each is decayed, takes its token and is read, in
+    place. The one-pass kernel where it can tile the state, else the plain
+    expression over a gathered copy."""
+    from ..ops.power_retention import can_tile, power_retention_decode
+
+    def attend(q, k, v, log_g):
+        q, k, v, log_g = q[:, 0], k[:, 0], v[:, 0], log_g[:, 0]
+        if can_tile(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim):
+            y, sp_, zp_ = power_retention_decode(q, k, v, log_g, sp, zp, layer, slots, active, eps=RETENTION_EPS)
+        else:
+            y, s_new, z_new = retention_step(q, k, v, log_g, sp[layer, slots], zp[layer, slots])
+            sp_, zp_ = sp.at[layer, slots].set(s_new), zp.at[layer, slots].set(z_new)
+        return y[:, None].astype(cfg.dtype), (sp_, zp_)
+
+    return attend

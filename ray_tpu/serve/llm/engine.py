@@ -127,6 +127,8 @@ class InferenceEngine:
                 "hits": imet.PREFIX_CACHE_HITS.labels(**labels),
                 "misses": imet.PREFIX_CACHE_MISSES.labels(**labels),
             },
+            # A model whose page is one sequence's recurrent state says so: no page of it is a prefix.
+            share_prefixes=getattr(model, "shares_prefix_pages", True),
         )
         self._rid = itertools.count(1)
         self._lock = lock_order.tracked_lock("serve.llm.engine")
@@ -466,6 +468,7 @@ class InferenceEngine:
                         tok, "computed_tokens", len(seq.prompt) - seq.pages.cached_tokens
                     )
                     clk["computed_tokens"] += attrs["computed_tokens"]
+                    self._add_counters(tok)
                     tok = int(tok)
             except EngineFailedError as e:
                 self._fail(e)
@@ -551,12 +554,7 @@ class InferenceEngine:
             pages = self._clk["decode.kv_pages"]
             pages["live"] += live_pages
             pages["table"] += len(self._slots) * self.model.max_pages_per_seq
-            # What the model says its router and windows did with the step
-            # (PagedLM's DecodeTokens); a model that says nothing adds no clock.
-            for name, counts in getattr(next_tokens, "counters", {}).items():
-                clk = self._clk.setdefault(name, dict.fromkeys(counts, 0))
-                for key, n in counts.items():
-                    clk[key] += n
+            self._add_counters(next_tokens)
             self._m_step.observe(step_ms)
             for seq in batch:
                 if seq.finished or seq.cancelled:
@@ -573,6 +571,15 @@ class InferenceEngine:
                 self._tok_window = 0
                 self._t_window = now
         return True
+
+    def _add_counters(self, result) -> None:
+        """What the model says its router, windows or states did with a step
+        (PagedLM's DecodeTokens and PrefillToken: `counters`), added up by
+        name; a model that says nothing adds no clock."""
+        for name, counts in getattr(result, "counters", {}).items():
+            clk = self._clk.setdefault(name, dict.fromkeys(counts, 0))
+            for key, n in counts.items():
+                clk[key] += n
 
     # -------------------------------------------------------------- admin
 
@@ -611,7 +618,11 @@ class InferenceEngine:
         clipped to its window, against kv_live, layers x their lengths;
         decode_experts (a routed model only): touched, the distinct experts
         the steps' rows chose, summed over routed layers, of `held` in as many
-        `steps`; loop.s: wall time of the loop, loop.idle_s the part of
+        `steps`; decode_state (a model whose cache is a recurrent state only):
+        the bytes of state the steps read and wrote (live rows x layers x 2 x
+        a state) and the state slots live, summed over `steps`; prefill_state
+        (the same): the chunks its prefills computed and how many of them were
+        carried a state in by their predecessor; loop.s: wall time of the loop, loop.idle_s the part of
         it waiting with nothing to do. loop.s = idle_s + admit.s +
         prefill.s + batch.s + decode.s + emit.s, and loop.s - idle_s -
         prefill.s - decode.s is the engine's own host time. A stage in

@@ -58,15 +58,18 @@ class PagedKVAllocator:
     allocate. `metrics` is an optional dict of pre-bound instrument
     handles ({"hits", "misses", "used", "total"}) so the allocator stays
     importable without pulling a deployment label in here.
+    `share_prefixes` False: no page is indexed or matched (a model whose page
+    is one sequence's recurrent state, not positions of K/V: serve/llm/model.py).
     """
 
-    def __init__(self, num_pages: int, page_tokens: int, metrics: Optional[dict] = None):
+    def __init__(self, num_pages: int, page_tokens: int, metrics: Optional[dict] = None, share_prefixes: bool = True):
         if num_pages < 2:
             raise ValueError(f"pool needs >= 2 pages (1 is the trash page), got {num_pages}")
         if page_tokens < 1:
             raise ValueError(f"page_tokens must be >= 1, got {page_tokens}")
         self.page_tokens = page_tokens
         self.num_pages = num_pages
+        self.share_prefixes = share_prefixes
         self._lock = lock_order.tracked_lock("serve.llm.kv")
         self._free: List[int] = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._ref: Dict[int, int] = {}
@@ -135,7 +138,7 @@ class PagedKVAllocator:
             # Walk the radix index over FULL pages of the prompt.
             matched: List[int] = []
             key: _PrefixKey = ()
-            n_full = len(tokens) // self.page_tokens
+            n_full = len(tokens) // self.page_tokens if self.share_prefixes else 0
             for i in range(n_full):
                 chunk = tuple(tokens[i * self.page_tokens:(i + 1) * self.page_tokens])
                 key = (key, chunk)
@@ -187,6 +190,8 @@ class PagedKVAllocator:
     def commit(self, seq: SeqPages, tokens) -> None:
         """Indexes `seq`'s full prompt pages so later prompts can share
         them. Called after prefill (the pages now hold real k/v)."""
+        if not self.share_prefixes:
+            return
         tokens = list(tokens)
         with self._lock:
             key: _PrefixKey = ()

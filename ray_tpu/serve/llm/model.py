@@ -34,20 +34,23 @@ class PrefillToken(int):
     executable computed for it (whole chunks from `cached_tokens` on: the
     last chunk's padding included, cached positions not). The adapter protocol has one
     return value and wrappers hand it on unopened, so this is where the
-    engine's `clocks.prefill.computed_tokens` reads it."""
+    engine's `clocks.prefill.computed_tokens` reads it; `counters`, as
+    `DecodeTokens` carries them (a state model's chunks: `prefill_state`)."""
 
-    def __new__(cls, token: int, computed_tokens: int):
+    def __new__(cls, token: int, computed_tokens: int, counters: Optional[Dict[str, Dict[str, int]]] = None):
         self = super().__new__(cls, token)
         self.computed_tokens = computed_tokens
+        self.counters = counters or {}
         return self
 
 
 class DecodeTokens(list):
-    """What `PagedLM.decode` returns for a model with routed experts or
-    attention windows: the slots' next tokens, a list to every caller, which
-    also carries what the step's router and windows did, as counters the
-    engine adds up under `stats()["clocks"]` by name (`PrefillToken`'s way
-    through wrappers). A model with neither returns a plain list."""
+    """What `PagedLM.decode` returns for a model with routed experts,
+    attention windows or a recurrent state: the slots' next tokens, a list to
+    every caller, which also carries what the step's router, windows or
+    states did, as counters the engine adds up under `stats()["clocks"]` by
+    name (`PrefillToken`'s way through wrappers). A model with none of them
+    returns a plain list."""
 
     def __init__(self, tokens, counters: Dict[str, Dict[str, int]]):
         super().__init__(tokens)
@@ -110,6 +113,17 @@ class PagedLM:
     prefill/decode steps; the engine owns the page bookkeeping and passes
     block tables in. Greedy sampling runs inside the jit (argmax) so only
     int32 tokens cross the host boundary per step.
+
+    What a page is depends on the model's cache. Softmax attention: a page
+    holds `page_tokens` positions of K/V, a sequence's block table grows by
+    a page as it fills, and full prompt pages are shared by prefix. Power
+    retention (`cfg.retention_degree`): a page is ONE sequence's whole
+    recurrent state, of a fixed size whatever its length; `page_tokens` is
+    the positions a sequence may reach, `max_pages_per_seq` is 1, so the
+    allocator hands a sequence exactly one page for its life, `pages[0]` /
+    `block_tables[i][0]` is its state's slot, and nothing of it is shared:
+    every prompt is computed whole (`shares_prefix_pages` is False, which
+    the engine asks of a model that has it).
     """
 
     def __init__(
@@ -142,27 +156,48 @@ class PagedLM:
         self.page_tokens = page_tokens
         self.max_slots = max_slots
         self.max_pages_per_seq = max_pages_per_seq
+        self.state_cache = bool(cfg.retention_degree)
+        if self.state_cache and max_pages_per_seq != 1:
+            raise ValueError(f"a retention model's page is a sequence's whole state: max_pages_per_seq is 1, not {max_pages_per_seq}")
         self.kv = tfm.init_kv_pages(cfg, num_pages, page_tokens)
+        # One page over all layers: page_tokens positions of K/V, or one sequence's whole state.
+        self.page_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.kv)) // num_pages
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
         # One lock around every jitted call: the engine loop is the only
         # steady-state caller, but tests poke prefill directly.
         self._mu = threading.Lock()
 
+    @property
+    def shares_prefix_pages(self) -> bool:
+        """Whether a full page of one prompt may serve another (the engine asks)."""
+        return not self.state_cache
+
     def describe(self) -> Dict[str, Any]:
-        """Which process and devices serve this model, which expression the
-        decode and prefill executables attend with ("paged_kernel" or
-        "xla_gather": transformer.paged_attention_path), and what compiling
-        cost so far (LLMServer.engine_stats() carries it out)."""
+        """Which process and devices serve this model, what its cache is
+        (`cache`: "kv_pages", a page `page_tokens` positions of K/V, or
+        "state", a page one sequence's whole recurrent state; the bytes of a
+        page over all layers either way), which expression the decode and
+        prefill executables attend with ("paged_kernel" or "xla_gather":
+        transformer.paged_attention_path; a state model's decode
+        "retention_kernel" or "xla_step": ops/power_retention.can_tile), and
+        what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
 
         devs = self._jax.devices()
+        if self.state_cache:
+            from ...ops.power_retention import can_tile
+
+            attention = "retention_kernel" if can_tile(self.cfg.n_heads, self.cfg.n_kv_heads, self.cfg.head_dim) else "xla_step"
+        else:
+            attention = self._tfm.paged_attention_path(self.cfg, self.page_tokens)
         return {
             "pid": os.getpid(),
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
-            "decode_attention": self._tfm.paged_attention_path(self.cfg, self.page_tokens),
+            "cache": {"kind": "state" if self.state_cache else "kv_pages", "page_bytes": self.page_bytes},
+            "decode_attention": attention,
             "peak_bytes_in_use": [
                 (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs
             ],
@@ -194,7 +229,8 @@ class PagedLM:
 
             # The executable's name in a device trace (line `XLA Modules`:
             # jit_llm_decode), where every jitted closure called `step` reads alike.
-            step.__name__ = "llm_decode"
+            # A state model's executables under names of their own.
+            step.__name__ = "llm_decode_state" if self.state_cache else "llm_decode"
             self._decode_jit = self._jax.jit(step, donate_argnums=self._donate((3,)))
         return self._decode_jit
 
@@ -209,7 +245,8 @@ class PagedLM:
                 )
                 return self._jnp.argmax(logits[0], axis=-1).astype(self._jnp.int32), kv
 
-            step.__name__ = f"llm_prefill_p{n_pages_bucket}"  # jit_llm_prefill_p<pages>, a bucket a name
+            # jit_llm_prefill_p<pages>, a bucket a name (a state model's: jit_llm_prefill_state_p1)
+            step.__name__ = ("llm_prefill_state_p" if self.state_cache else "llm_prefill_p") + str(n_pages_bucket)
             fn = self._jax.jit(step, donate_argnums=self._donate((2,)))
             self._prefill_jits[n_pages_bucket] = fn
         return fn
@@ -242,7 +279,7 @@ class PagedLM:
                 with _tracing.span(span + ".wait", attrs, device=True):
                     host = np.asarray(out)
             except Exception as e:
-                if kv["k"].is_deleted() or kv["v"].is_deleted():
+                if any(leaf.is_deleted() for leaf in self._jax.tree_util.tree_leaves(kv)):
                     self.kv = None
                     raise EngineFailedError(
                         "jitted step failed after the KV page pool was donated "
@@ -262,8 +299,10 @@ class PagedLM:
         n_pages = max(1, -(-len(prompt) // T))
         bucket = self._bucket_pages(n_pages)
         S = bucket * T
-        chunk = self._tfm.prefill_chunk_pages(bucket, T) * T
-        _anchor, chunks = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk, T)
+        if self.state_cache and cached_tokens:
+            raise ValueError("a retention model's state is no prefix another prompt can share: cached_tokens is 0")
+        chunk, granule = self._tfm.prefill_chunk_tokens(self.cfg, bucket, T)
+        _anchor, chunks = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk, granule)
         attrs = {"bucket_tokens": S, "computed_tokens": chunks * chunk}
         with _tracing.span("llm.prefill.prep", attrs, device=True):
             toks = np.zeros((1, S), dtype=np.int32)
@@ -283,7 +322,9 @@ class PagedLM:
             "llm.prefill",
             attrs,
         )
-        return PrefillToken(tok, attrs["computed_tokens"])
+        # A state model: the chunks that started from the state their predecessor left (all but a prompt's first).
+        counters = {"prefill_state": {"chunks": chunks, "carried_in": chunks - 1}} if self.state_cache else None
+        return PrefillToken(tok, attrs["computed_tokens"], counters)
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:
         import numpy as np
@@ -313,6 +354,10 @@ class PagedLM:
                 "kv_read": int(np.minimum(live[None, :], reach[:, None]).sum()),
                 "kv_live": int(cfg.n_layers * live.sum()),
             }
+        if self.state_cache:
+            # Every live row's state of every layer is read once and written once.
+            live = int((pos >= 0).sum())
+            counters["decode_state"] = {"bytes": 2 * live * self.page_bytes, "live_slots": live, "steps": 1}
         return DecodeTokens(tokens, counters) if counters else tokens
 
 

@@ -1,0 +1,409 @@
+"""Power retention (Brumby-14B-Base, `model_type: brumby`) on the normal path,
+at `archs/brumby.TINY` widths on the CPU, float32, seeded random weights with
+every norm's scale drawn: the three forms of the layer (quadratic, chunked
+through a state, one token a step), the whole-sequence forward and the paged
+path whose page is a sequence's state against the plain reference of
+`benchmarks/archs/brumby.py`, the wrong models, the engine, the kernel in
+interpret mode, and what the other models keep.
+
+TOLERANCE is tests/test_parity.py's: both sides compute in float32, the
+reference at matmul precision "highest". Read over these cases (PR 42, CPU):
+the largest difference 7e-6 on logits up to 4 in size.
+"""
+
+import functools
+import hashlib
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.archs import brumby
+from benchmarks.lib import correct, rehearsal, spec
+from benchmarks.tools import wrong_retention
+from ray_tpu.models import transformer as tfm
+from ray_tpu.ops import power_retention
+from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine
+from ray_tpu.serve.llm.kv_cache import PagedKVAllocator
+from ray_tpu.serve.llm.model import DecodeTokens, PagedLM
+
+TOLERANCE = 1e-4
+CHUNK = 16  # PREFILL_CHUNK_TOKENS in these tests: a 70-token prompt walks five chunks
+CONFIG = dict(brumby.TINY, rope_theta=1000000, rms_norm_eps=1e-6, torch_dtype="float32")
+CONFIG.pop("assumed")
+PAGE = 128  # positions a sequence may reach
+
+
+@pytest.fixture(autouse=True)
+def small_chunks_at_highest_precision(request, monkeypatch):
+    if "lower_to_the_text_they_did" in request.node.name:  # (f) reads the program as it ships
+        yield
+        return
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", CHUNK)
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@functools.lru_cache(maxsize=None)
+def seeded(seed):
+    cfg = brumby.model_config(CONFIG, remat=False)
+    return cfg, correct.init_weights(tfm, cfg, jax.random.PRNGKey(seed))
+
+
+def tokens_of(seed, n):
+    return jax.random.randint(jax.random.fold_in(jax.random.PRNGKey(seed), 1), (n,), 1, CONFIG["vocab_size"], jnp.int32)
+
+
+def reference(arch, params, tokens, positions):
+    return correct.reference_logits(arch, params, tokens, positions, CONFIG)
+
+
+def worst(a, b):
+    return float(jnp.max(jnp.abs(a - b)))
+
+
+# ------------------------------------------------- (a) the layer's three forms
+
+H, KV, HD = 4, 2, 16
+
+
+def layer_inputs(seed, n, gates):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v = (jax.random.normal(ks[i], (n, heads, HD)) for i, heads in enumerate((H, KV, KV)))
+    if gates == "near_one":  # l in [-0.01, 0]: the past never fades, so a lost carry shows at every later position
+        log_g = -0.01 * jax.random.uniform(ks[3], (n, KV))
+    else:
+        log_g = jax.nn.log_sigmoid(jax.random.normal(ks[3], (n, KV)))
+    return q, k, v, log_g
+
+
+def quadratic(q, k, v, log_g):
+    """ISSUE 42's step 3 as it stands: every pair s <= t, no state."""
+    n = q.shape[0]
+    r = H // KV
+    k, v, L = jnp.repeat(k, r, 1), jnp.repeat(v, r, 1), jnp.repeat(jnp.cumsum(log_g, 0), r, 1).T
+    scores = jnp.einsum("thd,shd->hts", q, k) / np.sqrt(HD)
+    seen = np.arange(n)[:, None] >= np.arange(n)[None, :]
+    a = jnp.where(seen, jnp.exp(jnp.where(seen, L[:, :, None] - L[:, None, :], 0.0)) * scores**2, 0.0)
+    return jnp.einsum("hts,shd->thd", a, v) / (jnp.sum(a, -1).T[..., None] + tfm.RETENTION_EPS)
+
+
+def test_phi_of_q_dot_phi_of_k_is_the_square_of_q_dot_k():
+    for hd in (2, 16, 128):
+        x, y = jax.random.normal(jax.random.PRNGKey(hd), (2, 7, hd))
+        px, py = tfm.retention_phi(x), tfm.retention_phi(y)
+        assert px.shape == (7, tfm.retention_state_dim(hd)) and tfm.retention_state_dim(hd) == (hd // 2 + 1) * hd
+        # (x . y)^2 cancels where x . y is small beside |x| |y|: the float32 products are held to the terms' size
+        size = np.sum(np.asarray(x) ** 2, -1) * np.sum(np.asarray(y) ** 2, -1)
+        assert np.all(np.abs(np.sum(np.asarray(px * py, np.float64), -1) - np.sum(np.asarray(x * y, np.float64), -1) ** 2) <= 1e-6 * size)
+
+
+@pytest.mark.parametrize("gates", ["near_one", "seeded"])
+@pytest.mark.parametrize("form", ["whole_in_chunks_of_16", "whole_in_chunks_of_100", "one_token_a_step", "chunks_from_an_arbitrary_position"])
+def test_the_three_forms_give_one_result_over_1k_tokens(form, gates):
+    n = 1000
+    q, k, v, log_g = layer_inputs(3, n, gates)
+    want = quadratic(q, k, v, log_g)
+    zero = (jnp.zeros((KV, HD, tfm.retention_state_dim(HD))), jnp.zeros((KV, tfm.retention_state_dim(HD))))
+    if form.startswith("whole"):
+        got = tfm.retention_whole(q[None], k[None], v[None], log_g[None], chunk=int(form.rsplit("_", 1)[1]))[0]
+    elif form == "one_token_a_step":
+        def step(state, x):
+            y, s, z = tfm.retention_step(*(t[None] for t in x), state[0][None], state[1][None])
+            return (s[0], z[0]), y[0]
+
+        got = jax.lax.scan(step, zero, (q, k, v, log_g))[1]
+    else:  # chunk borders wherever a cache happened to end: 0, 37, 37 + 256, 900, with padding past the end
+        state, outs, borders = zero, [], [0, 37, 293, 900, n]
+        for a, b in zip(borders, borders[1:]):
+            pad = lambda t: jnp.pad(t[a:b], [(0, 5)] + [(0, 0)] * (t.ndim - 1), constant_values=3.0)  # noqa: E731
+            y, *state = tfm.retention_chunk(pad(q), pad(k), pad(v), pad(log_g), *state, valid=jnp.arange(b - a + 5) < b - a)
+            outs.append(y[: b - a])
+        got = jnp.concatenate(outs)
+    # phi(q) . S sums terms of both signs far larger than (q . k)^2 where q . k is small: read 6e-5 of the outputs' size
+    scale = float(jnp.max(jnp.abs(want)))
+    assert worst(got, want) <= 2e-4 * max(scale, 1.0), (worst(got, want), scale)
+
+
+def test_a_lost_carry_shows_with_gates_near_one():
+    """The check above has teeth: the chunked form with the state dropped at one border is far off."""
+    q, k, v, log_g = layer_inputs(3, 64, "near_one")
+    want = quadratic(q, k, v, log_g)
+    D = tfm.retention_state_dim(HD)
+    zero = (jnp.zeros((KV, HD, D)), jnp.zeros((KV, D)))
+    second_half = tfm.retention_chunk(q[32:], k[32:], v[32:], log_g[32:], *zero)[0]
+    assert worst(second_half, want[32:]) > 0.05
+
+
+# --------------------------------------------- (b) parity with the reference
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_forward_matches_the_reference_at_every_position(seed):
+    cfg, params = seeded(seed)
+    tokens = tokens_of(seed, 70)
+    got = jax.jit(lambda p, t: tfm.forward(p, t, cfg))(params, tokens[None])[0]
+    assert worst(got, reference(brumby, params, tokens, np.arange(70))) <= TOLERANCE
+
+
+def test_a_retention_config_on_the_flash_path_is_refused_loudly():
+    cfg, params = seeded(0)
+    for impl in ("full", "ring", "ulysses"):
+        with pytest.raises(ValueError, match="retention"):
+            tfm.forward(params, tokens_of(0, 16)[None], cfg.replace(attn_impl=impl))
+    with pytest.raises(ValueError, match="retention_degree"):
+        tfm.init_params(jax.random.PRNGKey(0), cfg.replace(retention_degree=3))
+
+
+def state_lm(cfg, params, slots=3):
+    return PagedLM(cfg, params, num_pages=slots + 1, page_tokens=PAGE, max_slots=slots, max_pages_per_seq=1)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_prefill_then_decode_through_the_state_matches_the_reference_logits(seed):
+    """A 41-token prompt prefilled in three chunks into slot 2 of a pool whose
+    slots hold another sequence's leftovers, then nine tokens teacher-forced
+    through decode steps beside an inactive row: every logit the reference's."""
+    cfg, params = seeded(seed)
+    tokens = tokens_of(seed + 10, 50)
+    want = reference(brumby, params, tokens, np.arange(50))
+    lm = state_lm(cfg, params)
+    # What a slot's last owner left must not leak. NaN, not numbers: gates drawn from a seed forget within a few
+    # tokens, and a leftover that fades before the prompt ends would pass unseen.
+    lm.kv = jax.tree_util.tree_map(lambda a: a + jnp.nan, lm.kv)
+    n = 41
+    padded = jnp.zeros((1, PAGE), jnp.int32).at[0, :n].set(tokens[:n])
+    logits, kv = jax.jit(lambda p, t, kv: tfm.forward_prefill(p, t, cfg, kv, jnp.array([2]), n, 0))(params, padded, lm.kv)
+    assert worst(logits[0], want[n - 1]) <= TOLERANCE
+    decode = jax.jit(lambda p, t, pos, kv: tfm.forward_decode(p, t, pos, cfg, kv, jnp.array([[0], [2], [0]])))
+    for i in range(n, 50):
+        logits, kv = decode(params, jnp.array([0, tokens[i], 0]), jnp.array([-1, i, -1]), kv)
+        assert worst(logits[1], want[i]) <= TOLERANCE, i
+    # slots 1 and 3 were nobody's: untouched
+    assert all(bool(jnp.all(jnp.isnan(kv[name][:, 1]))) and bool(jnp.all(jnp.isfinite(kv[name][:, 2]))) for name in ("s", "z"))
+
+
+@pytest.mark.parametrize("case", [(70, 32), (70, 23), (37, 36)], ids=["at_a_chunk_border", "at_an_arbitrary_position", "one_token_left"])
+def test_a_prefill_that_starts_from_the_slots_state_gives_the_whole_prompts_logits(case):
+    """`write_from` w > 0: the slot's state holds positions [0, w) and the
+    chunks start at w, wherever that lies; w = 0 starts from nothing."""
+    length, w = case
+    cfg, params = seeded(3)
+    tokens = tokens_of(20, length)
+    want = reference(brumby, params, tokens, np.array([w - 1, length - 1]))
+    lm = state_lm(cfg, params)
+    prefill = jax.jit(lambda p, t, kv, n, w: tfm.forward_prefill(p, t, cfg, kv, jnp.array([1]), n, w))
+    padded = jnp.zeros((1, PAGE), jnp.int32).at[0, :length].set(tokens)
+    first, kv = prefill(params, padded, lm.kv, w, 0)
+    last, kv = prefill(params, padded, kv, length, w)
+    assert worst(first[0], want[0]) <= TOLERANCE and worst(last[0], want[1]) <= TOLERANCE
+    whole, kv_whole = prefill(params, padded, lm.kv, length, 0)
+    assert worst(whole[0], want[1]) <= TOLERANCE
+    for name in ("s", "z"):
+        np.testing.assert_allclose(kv[name][:, 1], kv_whole[name][:, 1], rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------- (c) the wrong models
+
+
+def served_margins(arch, params, tokens, served):
+    logits = reference(arch, params, tokens, np.arange(len(tokens)))
+    return np.asarray(jnp.max(logits, -1) - jnp.take_along_axis(logits, served[:, None], axis=-1)[:, 0])
+
+
+@functools.lru_cache(maxsize=None)
+def served_by_the_program():
+    cfg, params = seeded(4)
+    out = []
+    for seed in (40, 41, 42):
+        tokens = tokens_of(seed, 70)
+        served = jnp.argmax(jax.jit(lambda p, t: tfm.forward(p, t, cfg))(params, tokens[None])[0], -1)
+        out.append((tokens, served, served_margins(brumby, params, tokens, served)))
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(wrong_retention.WRONG))
+def test_each_wrong_model_separates_from_the_right_one_by_the_served_margins(name):
+    """The float32 program's greedy tokens over 3 sequences of 70: against the
+    right reference every margin is 0 to rounding; against each wrong model's
+    (one line of the reference altered, and the fp8-precision control) the
+    99th percentile is far over any limit between."""
+    _cfg, params = seeded(4)
+    wrong = wrong_retention.load(name)
+    right = correct.error_quantiles(np.concatenate([m for _t, _s, m in served_by_the_program()]))
+    wrong = correct.error_quantiles(np.concatenate([served_margins(wrong, params, tokens, served) for tokens, served, _m in served_by_the_program()]))
+    assert right["q100"] <= 1e-3
+    assert wrong["q99"] > 0.02 and wrong["q99"] > 20 * max(right["q100"], 1e-3), (right, wrong)
+
+
+# ----------------------------------------------------------- (d) the engine
+
+
+def _collect(engine, prompt, n):
+    return list(engine.generate(prompt, n))
+
+
+def greedy(cfg, params, prompt, n):
+    """An engine-free greedy loop: the whole-sequence forward at one padded length."""
+    fwd = jax.jit(lambda p, t: tfm.forward(p, t, cfg))
+    tokens = np.zeros((1, len(prompt) + n), np.int32)
+    tokens[0, : len(prompt)] = prompt
+    for i in range(len(prompt), len(prompt) + n):
+        tokens[0, i] = int(jnp.argmax(fwd(params, jnp.asarray(tokens))[0, i - 1]))
+    return tokens[0, len(prompt):].tolist()
+
+
+def test_the_engine_serves_sequences_that_come_and_go_the_tokens_each_is_served_alone():
+    """Four prompts over two slots (so two wait, and each takes the slot and
+    the state another left), of different lengths and answer lengths, at
+    once: each gets the tokens of an engine-free greedy loop. No page of the
+    model ever enters the prefix index, the same prompt twice is computed
+    twice, and the state counters add up."""
+    cfg, params = seeded(5)
+    prompts = [[int(t) for t in tokens_of(50 + i, n)] for i, n in enumerate((45, 18, 33, 45))]
+    prompts[3] = prompts[0]  # the same prompt again: no hit
+    answers = (12, 20, 7, 12)
+    want = [greedy(cfg, params, p, n) for p, n in zip(prompts, answers)]
+    # a waiting sequence holds its page, its state's slot, from submit on: four pages and the trash page, two rows a step
+    lm = PagedLM(cfg, params, num_pages=5, page_tokens=PAGE, max_slots=2, max_pages_per_seq=1)
+    eng = InferenceEngine(lm, EngineConfig(page_tokens=PAGE, pool_pages=5, prefill_token_budget=64), name="t-retention")
+    got = [None] * 4
+
+    def client(i):
+        got[i] = _collect(eng, prompts[i], answers[i])
+
+    try:
+        threads = [threading.Thread(target=client, args=(i,)) for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    assert got == want
+    assert stats["kv"]["indexed_pages"] == 0 and stats["kv"]["prefix_hits"] == 0 and stats["kv"]["used_pages"] == 0
+    clocks = stats["clocks"]
+    chunks = sum(-(-len(p) // CHUNK) for p in prompts)
+    assert clocks["prefill_state"] == {"chunks": chunks, "carried_in": chunks - 4}
+    assert clocks["prefill"]["computed_tokens"] == chunks * CHUNK
+    state = clocks["decode_state"]
+    assert state["steps"] == clocks["decode"]["n"] and state["live_slots"] == sum(answers) - 4
+    assert state["bytes"] == state["live_slots"] * 2 * lm.page_bytes
+    D = tfm.retention_state_dim(cfg.head_dim)
+    assert lm.page_bytes == cfg.n_layers * cfg.n_kv_heads * D * (cfg.head_dim + 1) * 4
+    said = lm.describe()
+    assert said["cache"] == {"kind": "state", "page_bytes": lm.page_bytes} and said["decode_attention"] == "xla_step"
+    out = lm.decode([1], [3], [[1]])
+    assert isinstance(out, DecodeTokens) and set(out.counters) == {"decode_state"}
+
+
+def test_a_slot_reused_after_release_gives_the_tokens_of_a_fresh_engine():
+    cfg, params = seeded(6)
+    first, second = ([int(t) for t in tokens_of(60 + i, n)] for i, n in enumerate((40, 25)))
+
+    def serve(prompts):
+        eng = InferenceEngine(state_lm(cfg, params, slots=1), EngineConfig(page_tokens=PAGE, pool_pages=2), name="t-retention-reuse")
+        try:
+            return [_collect(eng, p, 10) for p in prompts]
+        finally:
+            eng.close()
+
+    assert serve([first, second])[1] == serve([second])[0]
+
+
+def test_no_page_of_a_state_model_is_shared_and_a_cached_prefix_is_refused():
+    """The engine asks the model (`shares_prefix_pages`); an allocator told so
+    neither indexes nor matches, even a prompt that fills its page; and
+    PagedLM refuses a prefill that claims cached tokens."""
+    alloc = PagedKVAllocator(4, 8, share_prefixes=False)
+    seq = alloc.allocate(list(range(8)))
+    alloc.commit(seq, list(range(8)))
+    again = alloc.allocate(list(range(8)))
+    assert again.cached_tokens == 0 and again.pages != seq.pages and alloc.stats()["indexed_pages"] == 0
+    cfg, params = seeded(0)
+    lm = state_lm(cfg, params)
+    assert lm.shares_prefix_pages is False and PagedLM(max_slots=2).shares_prefix_pages is True
+    with pytest.raises(ValueError, match="cached_tokens"):
+        lm.prefill([1, 2, 3], [1], 2)
+    with pytest.raises(ValueError, match="max_pages_per_seq"):
+        PagedLM(cfg, params, num_pages=4, page_tokens=PAGE, max_slots=2, max_pages_per_seq=2)
+
+
+def test_a_dense_paged_model_keeps_no_state_clock():
+    eng = InferenceEngine(PagedLM(max_slots=2), EngineConfig(), name="t-dense-no-state")
+    try:
+        _collect(eng, [1, 2, 3], 4)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    assert not {"decode_state", "prefill_state"} & set(stats["clocks"])
+    assert PagedLM(max_slots=2).describe()["cache"]["kind"] == "kv_pages"
+
+
+# ------------------------------------------------------------ (e) the kernel
+
+
+@pytest.mark.parametrize("heads", [(10, 2), (4, 4)], ids=["five_query_heads_a_kv_head", "one"])
+def test_the_decode_kernel_in_interpret_mode_is_retention_step_in_place(heads):
+    """Three rows against a pool of two layers and four slots: the live rows'
+    states advance as `retention_step` advances them, their outputs are its,
+    and every other layer and slot is bit for bit what it was."""
+    n_heads, n_kv = heads
+    hd, L, N, B = 128, 2, 4, 3
+    assert power_retention.can_tile(n_heads, n_kv, hd) and not power_retention.can_tile(4, 2, 16)
+    assert not power_retention.can_tile(12, 2, 128)  # six query heads a K/V head do not fit the tile
+    D = tfm.retention_state_dim(hd)
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    q, k, v = (jax.random.normal(ks[i], (B, h, hd)) for i, h in enumerate((n_heads, n_kv, n_kv)))
+    log_g = jax.nn.log_sigmoid(jax.random.normal(ks[3], (B, n_kv)))
+    s, z = jax.random.normal(ks[4], (L, N, n_kv, hd, D)), jnp.abs(jax.random.normal(ks[5], (L, N, n_kv, D)))
+    slots, live, layer = jnp.array([2, 1, 3]), jnp.array([True, False, True]), 1
+    y, s2, z2 = jax.jit(lambda *a: power_retention.power_retention_decode(*a, eps=tfm.RETENTION_EPS, interpret=True))(
+        q, k, v, log_g, s, z, layer, slots, live)
+    want_y, want_s, want_z = tfm.retention_step(q, k, v, log_g, s[layer, slots], z[layer, slots])
+    for b in (0, 2):
+        assert worst(y[b], want_y[b]) <= 1e-4 * float(jnp.max(jnp.abs(want_y[b])))
+        np.testing.assert_allclose(s2[layer, slots[b]], want_s[b], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(z2[layer, slots[b]], want_z[b], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(s2[0], s[0])
+    np.testing.assert_array_equal(s2[1, 1], s[1, 1])  # the slot of the row that is not live
+    np.testing.assert_array_equal(z2[1, 1], z[1, 1])
+
+
+# ------------------------------------------- (f) what the other models keep
+
+# sha256 (first 16 hex digits) at the parent commit (PR 41) of each accepted configuration at its architecture's
+# TINY widths: init_params' leaves (paths, dtypes, numbers), and the lowered text of forward_decode and forward_prefill.
+PARENT = {
+    "mistral7b-train-seq4k-1chip": ("00146abe9d7f8cbe", "5b205ddfdad764ec", "acca1b330f25d98a"),
+    "dsllm7b-serve-chat-steady": ("00146abe9d7f8cbe", "c7f2192c2a02f3b2", "114685abbe26da57"),
+    "olmoe-train-seq4k-1chip": ("9fc2e6536f721f59", "125d9f6811579117", "cd4027dbb914c125"),
+    "trinitymini-serve-agent-turns": ("dd98a4937de87222", "cd393207dca6cc51", "ec3e421d2af401fa"),
+}
+
+
+def _digest(text: bytes) -> str:
+    return hashlib.sha256(text).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("cell_name", sorted(PARENT))
+def test_the_accepted_architectures_draw_the_weights_and_lower_to_the_text_they_did(cell_name):
+    """`retention_degree` is off for every accepted configuration: `init_params`
+    draws the same leaves, and the paged executables lower to the same text,
+    letter for letter, as before the state's path existed."""
+    cell = rehearsal.shrink(spec.find_cell(cell_name))
+    cfg = cell.arch.model_config(cell.config)
+    assert cfg.retention_degree == 0
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tfm.init_params(jax.random.PRNGKey(5), cfg))[0]:
+        h.update(jax.tree_util.keystr(path).encode() + str(leaf.dtype).encode() + np.asarray(leaf.astype(jnp.float32)).tobytes())
+    T, N, B, P = 16, 8, 4, 4
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    kv = jax.eval_shape(lambda: tfm.init_kv_pages(cfg, N, T))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    decode = jax.jit(lambda p, t, pos, kv, bt: tfm.forward_decode(p, t, pos, cfg, kv, bt)).lower(params, i32(B), i32(B), kv, i32(B, P))
+    prefill = jax.jit(lambda p, t, kv, bt, n, w: tfm.forward_prefill(p, t, cfg, kv, bt, n, w)).lower(params, i32(1, P * T), kv, i32(P), i32(), i32())
+    assert (h.hexdigest()[:16], _digest(decode.as_text().encode()), _digest(prefill.as_text().encode())) == PARENT[cell_name]

@@ -207,3 +207,73 @@ def test_paged_executables_carry_their_names_into_the_module(one_chip, step, pag
         lowered = lm._get_prefill(pages).lower(params, sds((1, pages * 4), jnp.int32), kv, sds((pages,), jnp.int32), scalar, scalar)
     assert f"module @{name} " in lowered.as_text()
     assert f"HloModule {name}," in lowered.compile().as_text()
+
+
+# ------------------------------------------------ power retention (PR 42)
+
+# Brumby-14B-Base's widths at 2 layers and a small vocabulary: GQA 40:8 x 128, retention of degree 2.
+RETENTION = dict(
+    vocab_size=2048, d_model=5120, n_layers=2, n_heads=40, n_kv_heads=8, d_head=128, d_ff=17408, max_seq_len=32768,
+    rope_theta=1e6, norm_eps=1e-6, qk_norm=True, qk_norm_per_head=True, retention_degree=2, attn_impl="naive", remat=False,
+)
+STATE_SLOTS, STATE_PAGE_TOKENS = 32, 4096
+
+
+def _compile_state(one_chip, step: str):
+    """forward_decode (32 rows) or forward_prefill (the one bucket: a page of
+    4 096 positions) of the retention widths over a pool of 33 states, donated."""
+    cfg = tfm.TransformerConfig(**RETENTION)
+    sds = _sds(one_chip)
+    shapes = lambda make: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))  # noqa: E731
+    params = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    kv = shapes(lambda: tfm.init_kv_pages(cfg, STATE_SLOTS + 1, STATE_PAGE_TOKENS))
+    B, scalar = STATE_SLOTS, sds((), jnp.int32)
+    if step == "decode":
+        return cfg, jax.jit(lambda p, t, pos, kv, bts: tfm.forward_decode(p, t, pos, cfg, kv, bts), donate_argnums=(3,)).lower(
+            params, sds((B,), jnp.int32), sds((B,), jnp.int32), kv, sds((B, 1), jnp.int32)).compile()
+    return cfg, jax.jit(lambda p, t, kv, bt, n, w: tfm.forward_prefill(p, t, cfg, kv, bt, n, w), donate_argnums=(2,)).lower(
+        params, sds((1, STATE_PAGE_TOKENS), jnp.int32), kv, sds((1,), jnp.int32), scalar, scalar).compile()
+
+
+def _state_pool_bytes(cfg):
+    D = tfm.retention_state_dim(cfg.head_dim)
+    return cfg.n_layers * (STATE_SLOTS + 1) * cfg.n_kv_heads * D * (cfg.head_dim + 1) * 4
+
+
+def test_retention_decode_step_is_one_kernel_a_layer_over_the_pool_in_place(one_chip, mosaic):
+    """The decode step at Brumby's widths: the state update is the Mosaic
+    kernel under its name, the donated pool of states (2.3 GB at 2 layers) is
+    aliased to the output, and no copy of it, nor a gathered batch of states,
+    is among the temporaries."""
+    from ray_tpu.ops import power_retention
+
+    cfg, compiled = _compile_state(one_chip, "decode")
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert power_retention.can_tile(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+    assert power_retention.KERNEL_NAME in text and "tpu_custom_call" in text
+    assert mem.alias_size_in_bytes >= _state_pool_bytes(cfg)
+    assert mem.temp_size_in_bytes < 64 * 2**20
+
+
+def test_retention_prefill_bucket_updates_the_pool_in_place(one_chip, mosaic):
+    """The one prefill bucket (a page of 4 096 positions walked in 256-row
+    chunks): the pool aliased, and the temporaries (phi of a chunk's queries
+    and keys, one sequence's state in and out) far under the pool."""
+    cfg, compiled = _compile_state(one_chip, "prefill")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _state_pool_bytes(cfg)
+    assert mem.temp_size_in_bytes < 2**30
+
+
+def test_a_state_models_executables_carry_names_of_their_own(one_chip):
+    from ray_tpu.serve.llm.model import PagedLM
+
+    cfg = tfm.tiny(attn_impl="naive", dtype=jnp.float32, retention_degree=2, n_kv_heads=2)
+    lm = PagedLM(cfg, num_pages=3, page_tokens=32, max_slots=2, max_pages_per_seq=1)
+    sds = _sds(one_chip)
+    shaped = lambda tree: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params, kv, scalar = shaped(lm.params), shaped(lm.kv), sds((), jnp.int32)
+    decode = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 1), jnp.int32))
+    prefill = lm._get_prefill(1).lower(params, sds((1, 32), jnp.int32), kv, sds((1,), jnp.int32), scalar, scalar)
+    assert "module @jit_llm_decode_state " in decode.as_text() and "module @jit_llm_prefill_state_p1 " in prefill.as_text()
+    assert "HloModule jit_llm_decode_state," in decode.compile().as_text()
