@@ -19,6 +19,20 @@ sink to the blocking iterator the serve streaming path consumes. A
 dropped consumer cancels the request: its pages and slot are reclaimed
 within one decode step (the cancel queue drains at the top of every loop
 iteration).
+
+The engine decides a step at once and may deliver it late. What a token
+means for the schedule (counts, done-ness, the slot and the pages given
+back) is settled under the lock the moment the step's result is there; the
+sink calls are queued in order and made by the loop thread outside the lock.
+For a model that announces its launches (model.py: the `launched` callable
+on StepTokens / PromptTokens, which PagedLM calls between its dispatch and
+its wait) a decode step's sink calls are made from that hook of the NEXT
+executable, so the streams they wake run while the chip works and not in
+front of the dispatch. Everything else (a prefill's first token, an error, a
+done with nothing left to launch) goes out at once, behind whatever is
+queued: a stream sees `tok ... tok, done | error` in the order decided. A
+model that announces nothing is delivered to at the end of each step, as
+ever; which of the two a model is, the engine learns from the hook firing.
 """
 
 from __future__ import annotations
@@ -45,7 +59,7 @@ from ... import tracing as _tracing
 from ...utils import internal_metrics as imet
 from ...utils import lock_order
 from .kv_cache import PagedKVAllocator, SeqPages
-from .model import StepTokens
+from .model import PromptTokens, StepTokens
 
 logger = logging.getLogger(__name__)
 
@@ -138,6 +152,13 @@ class InferenceEngine:
         self._by_rid: Dict[int, _Seq] = {}
         self._cancels: Deque[int] = collections.deque()
         self._stop = False
+        # Sink calls decided and not yet made, (seq, event, payload) in the
+        # order a stream must see them; the loop thread's alone.
+        self._pending: List[tuple] = []
+        # Whether the model announces its launches: its hook fired and no
+        # call since has come back without it. Only then is a step's
+        # delivery left for the next launch.
+        self._launches = False
         # Set once by _fail(): the model lost state it cannot rebuild.
         self.failed: Optional[EngineFailedError] = None
         self.shed_total = 0
@@ -159,6 +180,7 @@ class InferenceEngine:
             "batch": {"s": 0.0},
             "decode": {"n": 0, "s": 0.0},
             "emit": {"s": 0.0},
+            "deliver": {"n": 0, "under_step": 0},
             "decode.kv_pages": {"live": 0, "table": 0},
             "idle": {"s": 0.0},
         }
@@ -181,7 +203,7 @@ class InferenceEngine:
     ) -> int:
         """Reserves pages and enqueues; raises BackpressureError (shed)
         when the queue or the page pool cannot take the request."""
-        prompt = [int(t) for t in prompt]
+        prompt = PromptTokens((int(t) for t in prompt), self._launched)
         if not prompt:
             raise ValueError("empty prompt")
         max_new = int(max_new_tokens or self.config.max_new_tokens)
@@ -271,10 +293,7 @@ class InferenceEngine:
             seq.slot = None
         self._by_rid.pop(seq.rid, None)
         self.alloc.release(seq.pages)
-        try:
-            seq.sink(event, payload)
-        except Exception:  # lint: swallow-ok(sink owner gone; request is already torn down)
-            pass
+        self._pending.append((seq, event, payload))
 
     def _drain_cancels_locked(self) -> None:
         while self._cancels:
@@ -353,13 +372,61 @@ class InferenceEngine:
         seq.n_out += 1
         self.tokens_emitted += 1
         self._tok_window += 1
-        try:
-            seq.sink("tok", int(tok))
-        except Exception:
-            # lint: swallow-ok(consumer gone mid-emit; cancellation frees
-            # the sequence on the next iteration)
-            seq.cancelled = True
-            self._cancels.append(seq.rid)
+        self._pending.append((seq, "tok", int(tok)))
+
+    def _deliver(self, under_step: bool = False) -> None:
+        """Makes the queued sink calls, in the order they were decided. The
+        loop thread, outside the lock: a `put` wakes a stream's thread, which
+        may call cancel() or submit() at once."""
+        pending, self._pending = self._pending, []
+        for seq, event, payload in pending:
+            try:
+                seq.sink(event, payload)
+            except Exception:
+                # lint: swallow-ok(consumer gone: a token's sequence is
+                # cancelled and freed on the next iteration, a finished one
+                # is already torn down)
+                self.cancel(seq.rid)
+        clk = self._clk["deliver"]
+        clk["n"] += len(pending)
+        if under_step:
+            clk["under_step"] += len(pending)
+
+    def _emit(self, under_step: bool = False) -> None:
+        """A decode step's deliveries under a span of their own: a child of
+        `llm.step` when made at once, of `llm.prefill` / `llm.decode` when
+        made from the launch hook."""
+        tokens = sum(1 for _seq, event, _payload in self._pending if event == "tok")
+        with _tracing.span("llm.emit", {"tokens": tokens, "under_step": int(under_step)}, device=True):
+            self._deliver(under_step)
+
+    def _deliver_unless(self, launching: bool) -> None:
+        """Delivers what is queued now, unless an executable is about to be
+        launched (`launching`) by a model that says when it has been: then
+        its hook delivers, under the step in flight."""
+        if self._pending and not (launching and self._launches):
+            self._deliver()
+
+    def _launched(self) -> None:
+        """The model's hook (StepTokens / PromptTokens `launched`): the
+        executable this call runs has been dispatched and the thread is about
+        to block for its result. The loop thread, inside model.prefill /
+        model.decode, no lock of the engine held. The deliveries' seconds go
+        to the `emit` clock, not to the stage they interrupt."""
+        self._launches = True
+        if self._pending:
+            stage = self._stage[0]
+            self._enter("emit")
+            self._emit(under_step=True)
+            self._enter(stage)
+
+    def _returned(self) -> None:
+        """A model call came back. With deliveries still queued its hook did
+        not fire: this model announces nothing (until it does), and what was
+        left for its launch goes out now."""
+        if self._pending:
+            self._launches = False
+            self._deliver()
 
     def _done_after_emit(self, seq: _Seq, tok: int) -> bool:
         if seq.n_out >= seq.max_new:
@@ -377,6 +444,7 @@ class InferenceEngine:
             self._stop = True
             for seq in list(self._by_rid.values()):
                 self._finish_locked(seq, "error", err)
+        self._deliver()
 
     def _loop(self) -> None:
         try:
@@ -416,6 +484,8 @@ class InferenceEngine:
                     admitted = [] if stop else self._pick_admissions_locked()
                     live = sum(1 for s in self._slots if s is not None)
                     waiting = len(self._waiting)
+                # With something live the step below launches an executable; with nothing, or at the stop, none will be.
+                self._deliver_unless(launching=bool(live) and not stop)
                 _tracing.add_attrs(sp, waiting=waiting, admitted=len(admitted), live=live)
             if stop:
                 return
@@ -440,7 +510,18 @@ class InferenceEngine:
 
     def _step(self, admitted: List[_Seq]) -> bool:
         """Prefills `admitted`, runs one decode step over every live slot
-        and emits its tokens. False: the engine failed and the loop ends."""
+        and emits its tokens. False: the engine failed and the loop ends.
+
+        Emitting has two halves. Deciding (`llm.decide`, under the lock, the
+        moment the result is there): each token's count, its sequence's
+        done-ness, the slot and pages of a finished one given back, so the
+        next `llm.admit` finds them free. Delivering (`llm.emit`, outside the
+        lock): the sink calls, queued in that order. For a model that
+        announces nothing the second follows the first at once. For one that
+        announces its launches the step's deliveries wait for the next
+        executable's launch and are made from its hook, under `llm.prefill` or
+        `llm.decode` (`_launched`); a prefill's first token, a failed step's
+        errors and whatever finds nothing left to launch go out at once."""
         T = self.config.page_tokens
         # Prefill outside the lock (jit-compiled, prompt-sized work):
         # submit/cancel stay responsive while prompts burn in.
@@ -470,6 +551,7 @@ class InferenceEngine:
                     clk["computed_tokens"] += attrs["computed_tokens"]
                     self._add_counters(tok)
                     tok = int(tok)
+                self._returned()
             except EngineFailedError as e:
                 self._fail(e)
                 return False
@@ -478,35 +560,40 @@ class InferenceEngine:
             prefilled.append((seq, tok, err))
 
         self._enter("batch")
-        with _tracing.span("llm.batch", device=True) as sp, self._cond:
-            for seq, tok, err in prefilled:
-                self._finalize_admission_locked(seq, tok, err)
-            batch = [s for s in self._slots if s is not None]
-            # Grow block tables for sequences crossing a page
-            # boundary this step; pool exhaustion here fail-fasts the
-            # one sequence (its pages recycle for the rest).
-            for seq in list(batch):
-                if seq.write_pos() >= seq.pages.num_pages * T:
-                    try:
-                        self.alloc.extend(seq.pages)
-                    except KVPoolExhaustedError as e:
-                        batch.remove(seq)
-                        self._finish_locked(seq, "error", e)
-            _tracing.add_attrs(sp, live=len(batch))
-            if not batch:
-                return True
-            step = self.decode_steps + 1  # the ordinal it gets when it completes
-            tokens = StepTokens([0] * len(self._slots), step)
-            positions = [-1] * len(self._slots)
-            tables: List[List[int]] = [[] for _ in self._slots]
-            kv_tokens = live_pages = 0
-            page_tokens = self.config.page_tokens
-            for seq in batch:
-                tokens[seq.slot] = seq.last_token
-                positions[seq.slot] = seq.write_pos()
-                tables[seq.slot] = seq.pages.pages
-                kv_tokens += positions[seq.slot] + 1
-                live_pages += -(-(positions[seq.slot] + 1) // page_tokens)
+        with _tracing.span("llm.batch", device=True) as sp:
+            with self._cond:
+                for seq, tok, err in prefilled:
+                    self._finalize_admission_locked(seq, tok, err)
+                batch = [s for s in self._slots if s is not None]
+                # Grow block tables for sequences crossing a page
+                # boundary this step; pool exhaustion here fail-fasts the
+                # one sequence (its pages recycle for the rest).
+                for seq in list(batch):
+                    if seq.write_pos() >= seq.pages.num_pages * T:
+                        try:
+                            self.alloc.extend(seq.pages)
+                        except KVPoolExhaustedError as e:
+                            batch.remove(seq)
+                            self._finish_locked(seq, "error", e)
+                _tracing.add_attrs(sp, live=len(batch))
+                step = self.decode_steps + 1  # the ordinal it gets when it completes
+                tokens = StepTokens([0] * len(self._slots), step, self._launched)
+                positions = [-1] * len(self._slots)
+                tables: List[List[int]] = [[] for _ in self._slots]
+                kv_tokens = live_pages = 0
+                page_tokens = self.config.page_tokens
+                for seq in batch:
+                    tokens[seq.slot] = seq.last_token
+                    positions[seq.slot] = seq.write_pos()
+                    tables[seq.slot] = seq.pages.pages
+                    kv_tokens += positions[seq.slot] + 1
+                    live_pages += -(-(positions[seq.slot] + 1) // page_tokens)
+            # A first token goes out at once, behind whatever a prefill that
+            # raised before its launch left queued; the last step's tokens
+            # with no prefill since ride under the decode launched below.
+            self._deliver_unless(launching=bool(batch) and not prefilled)
+        if not batch:
+            return True
 
         # Model step runs OUTSIDE the lock: submit/cancel stay
         # responsive for the full decode latency.
@@ -539,7 +626,9 @@ class InferenceEngine:
         finally:
             step_ms = (self._enter("emit") - t0) * 1000.0
 
-        with self._cond, _tracing.span("llm.emit", {"tokens": len(batch)}, device=True):
+        if step_err is None:
+            self._returned()
+        with _tracing.span("llm.decide", {"tokens": len(batch)}, device=True), self._cond:
             if step_err is not None:
                 # Fail-fast every sequence that was in the failed
                 # step — never wedge: pages free, slots recycle, the
@@ -548,29 +637,41 @@ class InferenceEngine:
                 for seq in batch:
                     if not seq.finished:
                         self._finish_locked(seq, "error", _typed(step_err))
-                return True
-            self.decode_steps += 1
-            self._clk["decode"]["n"] += 1
-            pages = self._clk["decode.kv_pages"]
-            pages["live"] += live_pages
-            pages["table"] += len(self._slots) * self.model.max_pages_per_seq
-            self._add_counters(next_tokens)
-            self._m_step.observe(step_ms)
-            for seq in batch:
-                if seq.finished or seq.cancelled:
-                    continue
-                tok = int(next_tokens[seq.slot])
-                seq.last_token = tok
-                self._emit_locked(seq, tok)
-                if self._done_after_emit(seq, tok):
-                    self._finish_locked(seq, "done", "stop")
-            now = time.monotonic()
-            dt = now - self._t_window
-            if dt >= 0.5:
-                self._m_tps.set(self._tok_window / dt)
-                self._tok_window = 0
-                self._t_window = now
+            else:
+                self._decide_locked(batch, next_tokens, live_pages, step_ms)
+        # A failed step's errors at once, behind what its missing launch left
+        # queued. A step's tokens at once to a model that announces nothing;
+        # else they wait for the next launch (`_launched`), or for the
+        # `llm.admit` that finds nothing left to launch.
+        if step_err is not None or not self._launches:
+            self._emit()
         return True
+
+    def _decide_locked(self, batch: List[_Seq], next_tokens, live_pages: int, step_ms: float) -> None:
+        """A completed decode step's bookkeeping: its counters, and for each
+        sequence its token, whether it is done, and its slot and pages back
+        if so. The sink calls are queued, not made."""
+        self.decode_steps += 1
+        self._clk["decode"]["n"] += 1
+        pages = self._clk["decode.kv_pages"]
+        pages["live"] += live_pages
+        pages["table"] += len(self._slots) * self.model.max_pages_per_seq
+        self._add_counters(next_tokens)
+        self._m_step.observe(step_ms)
+        for seq in batch:
+            if seq.finished or seq.cancelled:
+                continue
+            tok = int(next_tokens[seq.slot])
+            seq.last_token = tok
+            self._emit_locked(seq, tok)
+            if self._done_after_emit(seq, tok):
+                self._finish_locked(seq, "done", "stop")
+        now = time.monotonic()
+        dt = now - self._t_window
+        if dt >= 0.5:
+            self._m_tps.set(self._tok_window / dt)
+            self._tok_window = 0
+            self._t_window = now
 
     def _add_counters(self, result) -> None:
         """What the model says its router, windows or states did with a step
@@ -606,8 +707,12 @@ class InferenceEngine:
         admit (the locked section at the top of an iteration: cancels
         reaped, free slots filled), prefill / decode (inside model.prefill /
         model.decode), batch (the locked section that finalizes admissions,
-        grows block tables and builds the step's inputs), emit (the step's
-        tokens to their sinks); prefill.tokens: prompt tokens of those calls,
+        grows block tables and builds the step's inputs), emit (a completed
+        step decided under the lock, and the sink calls wherever they are
+        made: those made from a launch's hook are taken out of the prefill /
+        decode they interrupt); deliver: the sink calls made (n) and those of
+        them made from a launch's hook, under a step in flight (under_step);
+        prefill.tokens: prompt tokens of those calls,
         cached ones included; prefill.computed_tokens: positions their
         executables computed, as the model reports them: the uncached span
         rounded up to whole chunks, cached positions not; decode.kv_pages: over the completed decode steps, the

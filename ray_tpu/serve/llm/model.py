@@ -6,6 +6,18 @@ with `prefill(prompt, pages, cached_tokens) -> token` and
 geometry attributes — so the scheduler is testable without JAX and the
 JAX path stays a thin adapter over models/transformer.py.
 
+The protocol's arguments and results are plain lists and ints to a model
+that wants no more. What else the two sides tell each other rides on them as
+attributes, so a wrapper that hands them on unopened carries it through.
+Model to engine: `PrefillToken.computed_tokens`, `DecodeTokens.counters`.
+Engine to model: `StepTokens.step`, and on `StepTokens` and `PromptTokens`
+alike `launched`, a callable the model may call once its executable has
+been dispatched and before it blocks for the result. The engine uses that
+instant to make the sink calls of the step before, so the streams they wake
+run while the chip works (engine.py, `_launched`). A model that ignores it
+loses nothing: the engine sees that no launch was announced and delivers
+every step at the end of that step, as it always did.
+
 PagedLM is the real path: one jitted decode step at static shapes
 ([max_slots] tokens, [max_slots, max_pages_per_seq] block tables, the
 whole page pool) serves every batch composition; prefill compiles per
@@ -62,11 +74,28 @@ class StepTokens(list):
     tokens, a list to every model, which also says which decode step of the
     engine this is (`step`: its `decode_steps` ordinal), so that a model with
     spans of its own around the executable carries the ordinal on them
-    (DecodeTokens' way through wrappers, in the other direction)."""
+    (DecodeTokens' way through wrappers, in the other direction), and carries
+    `launched`: a callable of no arguments for the model to call on the
+    calling thread once the step's executable has been dispatched, before it
+    waits for the result (None from a caller with nothing to do then). It
+    returns quickly and does not raise. A model that calls it must call it
+    in every `decode` and `prefill` that launches; a call that comes back
+    without it is taken as the end of that. A model that never calls it is
+    served exactly as one without the attribute."""
 
-    def __init__(self, tokens, step: int):
+    def __init__(self, tokens, step: int, launched=None):
         super().__init__(tokens)
         self.step = step
+        self.launched = launched
+
+
+class PromptTokens(list):
+    """What the engine hands `prefill` as `prompt`: the prompt's tokens, a
+    list to every model, with the same `launched` as StepTokens carries."""
+
+    def __init__(self, tokens, launched=None):
+        super().__init__(tokens)
+        self.launched = launched
 
 
 class StubModel:
@@ -256,12 +285,14 @@ class PagedLM:
 
     # --------------------------------------------------------------- steps
 
-    def _run_step(self, call, span: str, attrs=None):
+    def _run_step(self, call, span: str, attrs=None, launched=None):
         """Runs one jitted step `call(kv) -> (tokens, new_kv)`, waits for
         its tokens on the host and installs the new pool. `<span>.dispatch`
         is the jitted call returning, `<span>.wait` the transfer of its
         tokens (device annotations, so an idle gap of the chip can be put
-        down to one of them or to the caller's `.prep`). The wait is
+        down to one of them or to the caller's `.prep`); between the two the
+        caller's `launched`, if it gave one, is told that the chip has work
+        and this thread is about to block (StepTokens). The wait is
         inside the try because dispatch is asynchronous: a device-side
         failure surfaces at the transfer, not at the call. If the step
         raised after the pool was donated into it, the pool buffer is
@@ -276,6 +307,8 @@ class PagedLM:
             try:
                 with _tracing.span(span + ".dispatch", attrs, device=True):
                     out, new_kv = call(kv)
+                if launched is not None:
+                    launched()
                 with _tracing.span(span + ".wait", attrs, device=True):
                     host = np.asarray(out)
             except Exception as e:
@@ -321,6 +354,7 @@ class PagedLM:
             ),
             "llm.prefill",
             attrs,
+            getattr(prompt, "launched", None),  # the engine's PromptTokens; a bare list from anyone else
         )
         # A state model: the chunks that started from the state their predecessor left (all but a prompt's first).
         counters = {"prefill_state": {"chunks": chunks, "carried_in": chunks - 1}} if self.state_cache else None
@@ -341,7 +375,9 @@ class PagedLM:
             fn = self._get_decode()
         step = getattr(last_tokens, "step", None)  # the engine's StepTokens; a bare list from anyone else
         attrs = None if step is None else {"step": step}
-        out = self._run_step(lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode", attrs)
+        out = self._run_step(
+            lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode", attrs, getattr(last_tokens, "launched", None)
+        )
         tokens, cfg, counters = [int(t) for t in out[:B]], self.cfg, {}
         if cfg.n_experts:
             # Every row of the step is routed, the inactive slots' too.

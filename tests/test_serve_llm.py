@@ -526,6 +526,37 @@ def test_serve_batch_handler_raise_still_fails_batch(rt):
 # ------------------------------------------- stage clocks and spans (PR 24)
 
 
+class _LaunchingStub(StubModel):
+    """A stub that announces its launches the way PagedLM does: it calls the
+    `launched` its argument carries, then waits for its 'result'. `watch`, if
+    given, is called as watch(kind, step, when) around every hook
+    ("before" / "after"), on the engine's thread."""
+
+    watch = None
+
+    def _announce(self, carrier, kind, step=None):
+        if self.watch is not None:
+            self.watch(kind, step, "before")
+        carrier.launched()
+        if self.watch is not None:
+            self.watch(kind, step, "after")
+
+    def prefill(self, prompt, pages, cached_tokens):
+        self._announce(prompt, "prefill")
+        return super().prefill(prompt, pages, cached_tokens)
+
+    def decode(self, last_tokens, positions, block_tables):
+        from ray_tpu import tracing
+
+        attrs = {"step": last_tokens.step}
+        with tracing.span("llm.decode.dispatch", attrs):
+            pass
+        self._announce(last_tokens, "decode", last_tokens.step)
+        with tracing.span("llm.decode.wait", dict(attrs)):
+            return super().decode(list(last_tokens), positions, block_tables)
+
+
+
 def _clock_identity(clk):
     assert clk["loop"]["s"] >= clk["loop"]["idle_s"] + clk["prefill"]["s"] + clk["decode"]["s"] - 1e-9
     # the stages tile the loop's life: an equality, not a bound
@@ -537,15 +568,18 @@ def _flat(clk):
     return {f"{k}.{f}": v for k, d in clk.items() for f, v in d.items()}
 
 
+@pytest.mark.parametrize("announces", [False, True], ids=["silent", "announces"])
 @pytest.mark.parametrize("n_requests,step_delay_s", [(1, 0.0), (3, 0.003), (6, 0.0)])
-def test_engine_stage_clocks(n_requests, step_delay_s):
+def test_engine_stage_clocks(n_requests, step_delay_s, announces):
     """stats()["clocks"]: monotone across calls, queue_wait.n = requests
     admitted, and the loop's wall time is idle + admit + prefill + batch +
-    decode + emit."""
+    decode + emit, whether the deliveries are made at the end of their step
+    or from the next launch's hook; deliver.n counts every sink call."""
+    model_cls = _LaunchingStub if announces else StubModel
     eng = InferenceEngine(
-        StubModel(max_slots=2, step_delay_s=step_delay_s),
+        model_cls(max_slots=2, step_delay_s=step_delay_s),
         EngineConfig(page_tokens=4, pool_pages=64),
-        name=f"t-clocks-{n_requests}",
+        name=f"t-clocks-{n_requests}-{int(announces)}",
     )
     try:
         before = eng.stats()["clocks"]
@@ -578,6 +612,12 @@ def test_engine_stage_clocks(n_requests, step_delay_s):
     assert after["first_token"]["s"] >= after["queue_wait"]["s"] >= 0.0
     if step_delay_s:
         assert after["decode"]["s"] >= after["decode"]["n"] * step_delay_s
+    # 4 tokens and a done a request, each one sink call
+    assert after["deliver"]["n"] == 5 * n_requests
+    if announces:  # all but the first tokens, and what found nothing left to launch
+        assert 2 * n_requests <= after["deliver"]["under_step"] <= 4 * n_requests
+    else:
+        assert after["deliver"]["under_step"] == 0
     final = eng.stats()["clocks"]  # the loop has ended: its wall time stands still
     assert final["loop"]["s"] == eng.stats()["clocks"]["loop"]["s"]
 
@@ -726,7 +766,7 @@ class _SpannedStub(StubModel):
             return super().decode(list(last_tokens), positions, block_tables)
 
 
-def _spans_of_an_engines_life(n_requests, step_delay_s):
+def _spans_of_an_engines_life(n_requests, step_delay_s, model_cls=_SpannedStub):
     """An engine from start to stop with tracing on: one wait with nothing to
     do, `n_requests` at once, close. (decode_steps, its llm.* spans)."""
     from ray_tpu import tracing
@@ -734,9 +774,9 @@ def _spans_of_an_engines_life(n_requests, step_delay_s):
     exp = tracing.InMemoryExporter()
     tracing.enable(exp)
     eng = InferenceEngine(
-        _SpannedStub(max_slots=2, step_delay_s=step_delay_s),
+        model_cls(max_slots=2, step_delay_s=step_delay_s),
         EngineConfig(page_tokens=4, pool_pages=64),
-        name=f"t-tiles-{n_requests}",
+        name=f"t-tiles-{n_requests}-{model_cls.__name__}",
     )
     try:
         time.sleep(0.02)  # the loop finds nothing and waits: an llm.idle before any request
@@ -756,14 +796,17 @@ def _spans_of_an_engines_life(n_requests, step_delay_s):
     return eng.decode_steps, [s for s in exp.spans if s["name"].startswith("llm.")]
 
 
+@pytest.mark.parametrize("model_cls", [_SpannedStub, _LaunchingStub], ids=["silent", "announces"])
 @pytest.mark.parametrize("n_requests,step_delay_s", [(1, 0.0), (3, 0.003), (6, 0.0)])
-def test_engine_loop_spans_tile_the_thread_from_start_to_stop(n_requests, step_delay_s):
+def test_engine_loop_spans_tile_the_thread_from_start_to_stop(n_requests, step_delay_s, model_cls):
     """Every instant of the engine thread, from the loop's first span to its
     last, lies under exactly one of llm.idle / llm.admit / llm.step; the
     stages of a step nest in it; decode steps carry consecutive ordinals, the
-    same on the model's own spans."""
+    same on the model's own spans. A step's deliveries (llm.emit) close the
+    step of a model that announces nothing, and lie between the dispatch and
+    the wait of the next launch of one that announces it."""
     for _attempt in range(3):
-        decode_steps, spans = _spans_of_an_engines_life(n_requests, step_delay_s)
+        decode_steps, spans = _spans_of_an_engines_life(n_requests, step_delay_s, model_cls)
         top = sorted(
             (s for s in spans if s["name"] in ("llm.idle", "llm.admit", "llm.step")), key=lambda s: s["t0_ns"]
         )
@@ -788,7 +831,8 @@ def test_engine_loop_spans_tile_the_thread_from_start_to_stop(n_requests, step_d
     assert sum(s["attrs"]["admitted"] for s in top if s["name"] == "llm.admit") == n_requests
 
     steps = [s for s in top if s["name"] == "llm.step"]
-    inner = ("llm.prefill", "llm.batch", "llm.decode", "llm.emit")
+    announces = model_cls is _LaunchingStub
+    inner = ("llm.prefill", "llm.batch", "llm.decode", "llm.decide") + (() if announces else ("llm.emit",))
     for name in inner:
         for s in (s for s in spans if s["name"] == name):
             (outer,) = [o for o in steps if o["t0_ns"] <= s["t0_ns"] and s["t1_ns"] <= o["t1_ns"]]
@@ -797,7 +841,22 @@ def test_engine_loop_spans_tile_the_thread_from_start_to_stop(n_requests, step_d
     for o in steps:
         got = [s["name"] for s in sorted(spans, key=lambda s: s["t0_ns"])
                if s["name"] in inner and o["t0_ns"] <= s["t0_ns"] < o["t1_ns"]]
-        assert got == ["llm.prefill"] * o["attrs"]["admitted"] + ["llm.batch", "llm.decode", "llm.emit"]
+        assert got == ["llm.prefill"] * o["attrs"]["admitted"] + list(inner[1:])
+    emits = [s for s in spans if s["name"] == "llm.emit"]
+    assert [s["attrs"]["under_step"] for s in emits] == [int(announces)] * len(emits)
+    if announces:
+        # each under the prefill or the decode whose launch made it; a decode's after its dispatch, before its wait
+        launches = {s["span_id"]: s for s in spans if s["name"] in ("llm.prefill", "llm.decode")}
+        part = {(s["name"], s["attrs"]["step"]): s for s in spans if s["name"] in ("llm.decode.dispatch", "llm.decode.wait")}
+        assert 0 < len(emits) <= decode_steps and sum(s["attrs"]["tokens"] for s in emits) <= 3 * n_requests
+        for s in emits:
+            over = launches[s["parent_id"]]
+            assert over["t0_ns"] <= s["t0_ns"] and s["t1_ns"] <= over["t1_ns"]
+            if over["name"] == "llm.decode":
+                k = over["attrs"]["step"]
+                assert part["llm.decode.dispatch", k]["t1_ns"] <= s["t0_ns"] and s["t1_ns"] <= part["llm.decode.wait", k]["t0_ns"]
+    else:
+        assert len(emits) == decode_steps
 
     decodes = sorted((s for s in spans if s["name"] == "llm.decode"), key=lambda s: s["t0_ns"])
     assert [s["attrs"]["step"] for s in decodes] == list(range(1, decode_steps + 1))
@@ -805,6 +864,280 @@ def test_engine_loop_spans_tile_the_thread_from_start_to_stop(n_requests, step_d
     for part in ("llm.decode.dispatch", "llm.decode.wait"):
         got = sorted((s for s in spans if s["name"] == part), key=lambda s: s["t0_ns"])
         assert [s["attrs"]["step"] for s in got] == [s["attrs"]["step"] for s in decodes]
+
+
+# ------------------------------- a step decided at once, delivered late (PR 43)
+
+
+class _Streams:
+    """Sinks that keep what each stream saw, in order, and who was running."""
+
+    def __init__(self):
+        self.events = {}
+        self.threads = set()
+
+    def sink(self, tag):
+        got = self.events.setdefault(tag, [])
+
+        def sink(ev, val):
+            self.threads.add(threading.current_thread().name)
+            got.append((ev, val))
+
+        return sink
+
+    def tokens(self, tag):
+        return [v for ev, v in self.events[tag] if ev == "tok"]
+
+    def counts(self):
+        return {tag: sum(1 for ev, _v in got if ev == "tok") for tag, got in self.events.items()}
+
+    def ended(self, tag):
+        return bool(self.events[tag]) and self.events[tag][-1][0] != "tok"
+
+
+def _watched_engine(model, name, **cfg):
+    """(engine, streams, seen): `seen` gets (kind, step, when, tokens a stream so far) at every hook of `model`."""
+    streams, seen = _Streams(), []
+    model.watch = lambda kind, step, when: seen.append((kind, step, when, streams.counts()))
+    eng = InferenceEngine(model, EngineConfig(page_tokens=4, pool_pages=64, **cfg), name=name)
+    return eng, streams, seen
+
+
+def _assert_stream_shape(events, prompt, n_tokens, last):
+    """`n_tokens` right tokens in order, then exactly one closing event."""
+    assert [ev for ev, _v in events] == ["tok"] * n_tokens + [last[0]], events
+    assert [v for ev, v in events[:-1]] == _stub_tokens(prompt, n_tokens)
+    if last[0] == "done":
+        assert events[-1] == last
+    else:
+        assert isinstance(events[-1][1], last[1]), events[-1]
+
+
+def test_a_steps_tokens_reach_their_streams_under_the_next_launch():
+    """A model that announces its launches: the tokens of step N are at their
+    streams when the hook of step N+1 returns, and not before it is called:
+    they were held for the launch, not delivered in front of the dispatch. A
+    prefill's first token is there before the decode after it is dispatched."""
+    eng, streams, seen = _watched_engine(_LaunchingStub(max_slots=4, step_delay_s=0.002), "t-under")
+    try:
+        prompts = {tag: [tag + 1, 2] for tag in range(3)}
+        for tag, prompt in prompts.items():
+            eng.submit(prompt, 6, sink=streams.sink(tag))
+        assert _wait_for(lambda: all(streams.ended(tag) for tag in prompts))
+        clk = eng.stats()["clocks"]
+    finally:
+        eng.close()
+    for tag, prompt in prompts.items():
+        _assert_stream_shape(streams.events[tag], prompt, 6, ("done", "stop"))
+    assert streams.threads == {"llm-engine-t-under"}
+    decodes = [(step, when, counts) for kind, step, when, counts in seen if kind == "decode"]
+    assert [step for step, when, _c in decodes if when == "before"] == list(range(1, eng.decode_steps + 1))
+    first_token_at = {}  # tag -> the first decode step that ran with it
+    for step, when, counts in decodes:
+        for tag, n in counts.items():
+            if n == 0:
+                continue  # submitted, not yet prefilled
+            k = first_token_at.setdefault(tag, step)
+            if step - k >= 5:
+                continue  # done: its last token went out with its `done`
+            # the first token and one of every step before this one; step - 1's only once the hook has run
+            assert n == 1 + (step - k) - (1 if when == "before" and step > k else 0), (tag, step, when, counts)
+    assert clk["deliver"]["n"] == 3 * 7 == sum(len(e) for e in streams.events.values())
+    # every stream: 4 of its 5 decode tokens under a later launch, the first token and the last with `done` not
+    assert clk["deliver"]["under_step"] == 3 * 4
+
+
+def test_a_model_that_announces_nothing_is_delivered_to_at_the_end_of_each_step():
+    """StubModel calls no hook: at the entry of decode step N + 1 every stream
+    holds the tokens of all N steps before it, as it always did."""
+    streams, seen = _Streams(), []
+
+    class Watching(StubModel):
+        def decode(self, last_tokens, positions, block_tables):
+            assert callable(last_tokens.launched)  # offered, and ignored
+            seen.append((last_tokens.step, streams.counts()))
+            return super().decode(last_tokens, positions, block_tables)
+
+    eng = InferenceEngine(Watching(max_slots=4), EngineConfig(page_tokens=4, pool_pages=64), name="t-silent")
+    try:
+        for tag in range(3):
+            eng.submit([tag + 1, 2], 5, sink=streams.sink(tag))
+        assert _wait_for(lambda: all(streams.ended(tag) for tag in range(3)))
+        clk = eng.stats()["clocks"]
+    finally:
+        eng.close()
+    start = {}
+    for step, counts in seen:
+        for tag, n in counts.items():
+            if n:
+                assert n == 1 + step - start.setdefault(tag, step), (tag, step, counts)
+    assert clk["deliver"] == {"n": 3 * 6, "under_step": 0}
+
+
+def test_a_model_that_stops_announcing_is_not_waited_for():
+    """The hook fires for a while and then no more (a wrapper that stopped
+    handing it on): the first step that comes back without it has its
+    predecessor's tokens delivered on return, and from then on every step is
+    delivered at its end."""
+    streams, seen = _Streams(), []
+
+    class Fading(_LaunchingStub):
+        def decode(self, last_tokens, positions, block_tables):
+            seen.append((last_tokens.step, streams.counts()[0]))
+            if last_tokens.step <= 3:
+                return super().decode(last_tokens, positions, block_tables)
+            return StubModel.decode(self, last_tokens, positions, block_tables)
+
+    eng = InferenceEngine(Fading(), EngineConfig(page_tokens=4, pool_pages=64), name="t-fading")
+    try:
+        eng.submit([1, 2], 9, sink=streams.sink(0))
+        assert _wait_for(lambda: streams.ended(0))
+        clk = eng.stats()["clocks"]
+    finally:
+        eng.close()
+    _assert_stream_shape(streams.events[0], [1, 2], 9, ("done", "stop"))
+    # tokens at the stream at the entry of steps 1..8: held one step back while announced (1-4), then level
+    assert seen == [(1, 1), (2, 1), (3, 2), (4, 3), (5, 5), (6, 6), (7, 7), (8, 8)]
+    assert clk["deliver"] == {"n": 10, "under_step": 2}
+
+
+@pytest.mark.parametrize("case", ["length", "eos", "cancel_mid_step", "decode_raises_before_launch",
+                                  "decode_raises_after_launch", "prefill_raises", "pool_lost", "close"])
+def test_a_stream_sees_its_tokens_then_one_closing_event(case):
+    """Whatever ends a stream of a model that announces its launches, the
+    stream sees its tokens in order, all that were decided, and then one
+    closing event; nothing is left queued and every page comes back."""
+    from ray_tpu.exceptions import EngineFailedError
+
+    gate, at_gate = threading.Event(), threading.Event()
+
+    class Model(_LaunchingStub):
+        def prefill(self, prompt, pages, cached_tokens):
+            if case == "prefill_raises" and list(prompt) == [9, 9]:
+                raise ValueError("this prompt cannot be prefilled")
+            return super().prefill(prompt, pages, cached_tokens)
+
+        def decode(self, last_tokens, positions, block_tables):
+            step = last_tokens.step
+            if step == 3 and case == "decode_raises_before_launch":
+                raise ValueError("before the launch: step 2's tokens are still queued")
+            if step == 3 and case == "pool_lost":
+                raise EngineFailedError("the pool is gone")
+            out = super().decode(last_tokens, positions, block_tables)
+            if step == 3 and case == "decode_raises_after_launch":
+                raise ValueError("after the launch: step 2's tokens are out")
+            if step == 3 and case in ("cancel_mid_step", "close"):
+                at_gate.set()  # step 3 in flight, its launch announced
+                assert gate.wait(10)
+            return out
+
+    prompt = [1, 2]
+    toks = _stub_tokens(prompt, 8)
+    eos = toks[3] if case == "eos" else None
+    eng, streams, _seen = _watched_engine(Model(max_slots=4), f"t-ends-{case}", eos_token=eos)
+    try:
+        rid = eng.submit(prompt, 6, sink=streams.sink("a"))
+        if case == "prefill_raises":
+            assert _wait_for(lambda: streams.counts()["a"] >= 2)
+            eng.submit([9, 9], 3, sink=streams.sink("b"))
+            assert _wait_for(lambda: streams.ended("b"))
+            _assert_stream_shape(streams.events["b"], [9, 9], 0, ("error", RayTpuError))
+        if case in ("cancel_mid_step", "close"):
+            assert at_gate.wait(10)
+            assert streams.counts()["a"] == 3  # the first token, steps 1 and 2: step 3 is in flight
+            if case == "cancel_mid_step":
+                eng.cancel(rid)
+                gate.set()
+            else:
+                closer = threading.Thread(target=eng.close)
+                closer.start()
+                assert _wait_for(lambda: eng._stop)
+                gate.set()
+                closer.join(10)
+        assert _wait_for(lambda: streams.ended("a"))
+        assert _wait_for(lambda: eng.alloc.used_pages() == 0)
+        clk = eng.stats()["clocks"]
+        assert not eng._pending
+    finally:
+        eng.close()
+    want = {
+        "length": (6, ("done", "stop")),
+        "eos": (4, ("done", "stop")),
+        # cancelled while step 3 ran: its token is not the stream's any more
+        "cancel_mid_step": (3, ("done", "cancelled")),
+        "decode_raises_before_launch": (3, ("error", RayTpuError)),
+        "decode_raises_after_launch": (3, ("error", RayTpuError)),
+        "prefill_raises": (6, ("done", "stop")),
+        "pool_lost": (3, ("error", EngineFailedError)),
+        # step 3 completed before the loop saw the stop: its token is decided, and delivered before the error
+        "close": (4, ("error", RayTpuError)),
+    }[case]
+    _assert_stream_shape(streams.events["a"], prompt, *want)
+    assert clk["deliver"]["n"] == sum(len(e) for e in streams.events.values())
+    assert (eng.failed is not None) == (case == "pool_lost")
+
+
+def test_a_sink_that_raises_under_the_next_launch_cancels_its_sequence():
+    """A consumer that is gone shows when its token is delivered, which for a
+    model that announces its launches is a step later: the sequence is
+    cancelled then and its pages come back; the others are served on."""
+    streams = _Streams()
+    good = streams.sink("good")
+
+    def gone(ev, val):
+        if ev == "tok" and val == _stub_tokens([3, 4], 3)[2]:
+            raise ConnectionError("consumer went away")
+
+    eng = InferenceEngine(_LaunchingStub(max_slots=2), EngineConfig(page_tokens=4, pool_pages=64), name="t-gone")
+    try:
+        eng.submit([1, 2], 12, sink=good)
+        eng.submit([3, 4], 30, sink=gone)
+        assert _wait_for(lambda: streams.ended("good"))
+        assert _wait_for(lambda: eng.alloc.used_pages() == 0)
+        assert eng.decode_steps < 20  # the second did not run its 30
+    finally:
+        eng.close()
+    _assert_stream_shape(streams.events["good"], [1, 2], 12, ("done", "stop"))
+
+
+def test_paged_lm_announces_its_launches_between_dispatch_and_wait():
+    """PagedLM calls the `launched` of the prompt and of the step's tokens
+    once each, after its jitted call has returned and before it reads the
+    result; through the engine every token but the first and last of a
+    stream is delivered from there, and the tokens are what they were."""
+    from ray_tpu import tracing
+    from ray_tpu.serve.llm.model import PagedLM, PromptTokens, StepTokens
+
+    lm = PagedLM(num_pages=32, page_tokens=16, max_slots=2, max_pages_per_seq=4)
+    calls = []
+    exp = tracing.InMemoryExporter()
+    tracing.enable(exp)
+    try:
+        first = lm.prefill(PromptTokens([5, 6, 7], lambda: calls.append("prefill")), [1], 0)
+        lm.decode(StepTokens([int(first), 0], 1, lambda: calls.append("decode")), [3, -1], [[1], []])
+        plain = lm.prefill([5, 6, 7], [2], 0)  # a bare list: nothing to call
+        assert int(plain) == int(first) and calls == ["prefill", "decode"]
+        eng = InferenceEngine(lm, EngineConfig(page_tokens=16, pool_pages=32), name="t-paged-hook")
+        try:
+            a, b = _collect(eng, [5, 6, 7], 5), _collect(eng, [5, 6, 7], 5)
+            clk = eng.stats()["clocks"]
+        finally:
+            eng.close()
+    finally:
+        tracing.disable()
+    assert a == b and a[0] == int(first)
+    assert clk["deliver"] == {"n": 12, "under_step": 6}  # a stream: tokens 2-4 of 5; the first, the last and `done` at once
+    _clock_identity(clk)
+    by = {}
+    for s in exp.spans:
+        by.setdefault(s["name"], []).append(s)
+    waits = sorted(by["llm.decode.wait"] + by["llm.prefill.wait"], key=lambda s: s["t0_ns"])
+    dispatches = sorted(by["llm.decode.dispatch"] + by["llm.prefill.dispatch"], key=lambda s: s["t0_ns"])
+    for e in by["llm.emit"]:
+        assert e["attrs"]["under_step"] == 1
+        d = max((d for d in dispatches if d["t1_ns"] <= e["t0_ns"]), key=lambda d: d["t1_ns"])
+        w = min((w for w in waits if w["t0_ns"] >= e["t1_ns"]), key=lambda w: w["t0_ns"])
+        assert d["name"].rsplit(".", 1)[0] == w["name"].rsplit(".", 1)[0] and not [x for x in dispatches if d["t1_ns"] < x["t0_ns"] < w["t0_ns"]]
 
 
 @pytest.fixture(scope="module")
