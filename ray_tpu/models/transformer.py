@@ -121,10 +121,38 @@ class TransformerConfig:
     # written whole every decode step, one page a sequence for its life,
     # where a softmax config's page holds `page_tokens` positions of K/V.
     retention_degree: int = 0
+    # One chip's share of an expert-parallel deployment's experts, off at 0
+    # (all held): the experts [first_expert, first_expert + n_experts_held) of
+    # every routed layer are here. The router keeps its n_experts outputs and
+    # its top-k over all of them, renormalised over the k chosen whether held
+    # or not; the expert stacks are [layers, held, ., .] and a token's result
+    # is the sum over its chosen experts that are held. What the absent ones
+    # would add is left out: that partial sum goes on to the next layer.
+    n_experts_held: int = 0
+    first_expert: int = 0
+    # Solar-Open2 family: the stack is periods of one softmax-attention layer
+    # (this config's heads, gate and all) followed by kda_per_period Kimi
+    # Delta Attention layers (ops/kda.py: n_heads heads of head_dim key and
+    # value channels, a short convolution of kda_conv taps on q, k and v, a
+    # per-channel decay through a thin pair of matrices of rank head_dim, a
+    # delta rule with beta in (0, 2), an output norm a head and a thin gate).
+    # Off at 0. The periods are one scan whose body is the period's layers in
+    # order: `blocks` holds the softmax layers [periods, ...], `kda_blocks`
+    # the others [periods, kda_per_period, ...]. Served, a sequence has two
+    # caches: K/V pages of the softmax layers alone, and of every KDA layer a
+    # float32 state a head with the convolution's tail, of a fixed size, in
+    # one of `state_slots` slots (`init_kv_pages`; slot 0 is trash).
+    kda_per_period: int = 0
+    kda_conv: int = 4
+    state_slots: int = 0
 
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     def replace(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -183,6 +211,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         raise ValueError(f"retention_degree {cfg.retention_degree!r}: 0 (softmax attention) or 2 is computed")
     if cfg.retention_degree and (cfg.attn_gate or any(cfg.windows) or nh % nkv or hd % 2):
         raise ValueError("power retention has a gate of its own (`wg`: a logit a K/V head) and no window")
+    held = cfg.experts_held
+    if not 0 <= cfg.first_expert <= E - held:
+        raise ValueError(f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are not among the router's {E}")
+    per = cfg.kda_per_period
+    if per and (cfg.n_layers % (per + 1) or any(cfg.windows) or any(cfg.rope_layers) or not cfg.rope_layers
+                or cfg.n_dense_layers or cfg.retention_degree or cfg.parallel_block):
+        raise ValueError("a KDA stack is whole periods of (one softmax layer, kda_per_period KDA layers), no rope (rope_layers all off), window or dense layer")
     k = iter(jax.random.split(key, 16))
     # What this model has over the llama and OLMoE blocks draws from a stream
     # of its own: theirs give the same weights for a key as before.
@@ -191,54 +226,81 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     def dense(key, shape, fan_in, dtype=cfg.dtype):
         return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
+    # L below: a stack's leading shape, (layers,) or (periods, layers a period).
     def swiglu(k, L, f):
         return {
-            "w_gate": dense(next(k), (L, d, f), d),
-            "w_up": dense(next(k), (L, d, f), d),
-            "w_down": dense(next(k), (L, f, d), f),
+            "w_gate": dense(next(k), (*L, d, f), d),
+            "w_up": dense(next(k), (*L, d, f), d),
+            "w_down": dense(next(k), (*L, f, d), f),
         }
 
-    def blocks(k, L, routed: bool, f: int):
+    def kda_attn(k, L):
+        """A KDA layer's mixer (ops/kda.py). `a_log` and `dt_bias` as the
+        family draws them, so that a seeded state remembers some tens of
+        tokens: a state not carried over a chunk's border, or not cleared,
+        shows in the logits."""
+        n, f32 = nh * hd, jnp.float32
+        dt = jnp.exp(jax.random.uniform(next(k), (*L, n), f32, math.log(0.001), math.log(0.1)))
+        return {
+            "wq": dense(next(k), (*L, d, n), d),
+            "wk": dense(next(k), (*L, d, n), d),
+            "wv": dense(next(k), (*L, d, n), d),
+            "wo": dense(next(k), (*L, n, d), n),
+            **{"conv_" + name: dense(next(k), (*L, n, cfg.kda_conv), cfg.kda_conv) for name in "qkv"},
+            "w_fa": dense(next(k), (*L, d, hd), d),
+            "w_fb": dense(next(k), (*L, hd, n), hd),
+            "a_log": jnp.log(jax.random.uniform(next(k), (*L, nh), f32, 1.0, 16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+            "w_b": dense(next(k), (*L, d, nh), d),
+            "o_norm": {"scale": jnp.ones((*L, hd), cfg.dtype)},
+            "w_ga": dense(next(k), (*L, d, hd), d),
+            "w_gb": dense(next(k), (*L, hd, n), hd),
+            "b_g": dense(next(k), (*L, n), 4.0),
+        }
+
+    def blocks(k, k2, L, routed: bool, f: int, kda: bool = False):
+        """A stack of alike layers; k2: the stream of what a family has over the llama and OLMoE blocks."""
+        L = (L,) if isinstance(L, int) else L
         out = {
-            "attn_norm": {"scale": jnp.ones((L, d), cfg.dtype)},
-            "attn": {
-                "wq": dense(next(k), (L, d, nh * hd), d),
-                "wk": dense(next(k), (L, d, nkv * hd), d),
-                "wv": dense(next(k), (L, d, nkv * hd), d),
-                "wo": dense(next(k), (L, nh * hd, d), nh * hd),
+            "attn_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
+            "attn": kda_attn(k, L) if kda else {
+                "wq": dense(next(k), (*L, d, nh * hd), d),
+                "wk": dense(next(k), (*L, d, nkv * hd), d),
+                "wv": dense(next(k), (*L, d, nkv * hd), d),
+                "wo": dense(next(k), (*L, nh * hd, d), nh * hd),
             },
-            "mlp_norm": {"scale": jnp.ones((L, d), cfg.dtype)},
+            "mlp_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
             "mlp": (
                 {
-                    "w_gate": dense(next(k), (L, E, d, f), d),
-                    "w_up": dense(next(k), (L, E, d, f), d),
-                    "w_down": dense(next(k), (L, E, f, d), f),
-                    "router": dense(next(k), (L, d, E), d),
+                    "w_gate": dense(next(k), (*L, held, d, f), d),
+                    "w_up": dense(next(k), (*L, held, d, f), d),
+                    "w_down": dense(next(k), (*L, held, f, d), f),
+                    "router": dense(next(k), (*L, d, E), d),
                 }
                 if routed
                 else swiglu(k, L, f)
                 if cfg.mlp_act == "swiglu"
                 else {
-                    "w_up": dense(next(k), (L, d, f), d),
-                    "w_down": dense(next(k), (L, f, d), f),
+                    "w_up": dense(next(k), (*L, d, f), d),
+                    "w_down": dense(next(k), (*L, f, d), f),
                 }
             ),
         }
-        if cfg.qk_norm:
+        if cfg.qk_norm and not kda:
             qn, kn = (hd, hd) if cfg.qk_norm_per_head else (nh * hd, nkv * hd)
-            out["attn"]["q_norm"] = {"scale": jnp.ones((L, qn), cfg.dtype)}
-            out["attn"]["k_norm"] = {"scale": jnp.ones((L, kn), cfg.dtype)}
-        if cfg.attn_gate:
-            out["attn"]["wg"] = dense(next(k2), (L, d, nh * hd), d)
+            out["attn"]["q_norm"] = {"scale": jnp.ones((*L, qn), cfg.dtype)}
+            out["attn"]["k_norm"] = {"scale": jnp.ones((*L, kn), cfg.dtype)}
+        if cfg.attn_gate and not kda:
+            out["attn"]["wg"] = dense(next(k2), (*L, d, nh * hd), d)
         if cfg.retention_degree:
-            out["attn"]["wg"] = dense(next(k2), (L, d, nkv), d)
+            out["attn"]["wg"] = dense(next(k2), (*L, d, nkv), d)
         if cfg.post_norms:
-            out["post_attn_norm"] = {"scale": jnp.ones((L, d), cfg.dtype)}
-            out["post_mlp_norm"] = {"scale": jnp.ones((L, d), cfg.dtype)}
+            out["post_attn_norm"] = {"scale": jnp.ones((*L, d), cfg.dtype)}
+            out["post_mlp_norm"] = {"scale": jnp.ones((*L, d), cfg.dtype)}
         if routed and cfg.router_score == "sigmoid":
             # A trained bias is non-zero; zeros would hide the term from every
             # check. At this scale it changes about a third of a token's experts.
-            out["mlp"]["router_bias"] = dense(next(k2), (L, E), 100.0, jnp.float32)
+            out["mlp"]["router_bias"] = dense(next(k2), (*L, E), 100.0, jnp.float32)
         if routed and cfg.d_ff_shared:
             out["mlp"]["shared"] = swiglu(k2, L, cfg.d_ff_shared)
         return out
@@ -246,13 +308,16 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     nd = cfg.n_dense_layers
     params = {
         "embed": {"embedding": dense(next(k), (v, d), d)},
-        "blocks": blocks(k, cfg.n_layers - nd, bool(E), cfg.d_ff),
+        "blocks": blocks(k, k2, (cfg.n_layers - nd) // (per + 1), bool(E), cfg.d_ff),
         "final_norm": {"scale": jnp.ones((d,), cfg.dtype)},
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(k), (d, v), d)
     if nd:
-        params["dense_blocks"] = blocks(k2, nd, False, cfg.d_ff_dense)
+        params["dense_blocks"] = blocks(k2, k2, nd, False, cfg.d_ff_dense)
+    if per:  # from streams of their own: the softmax layers draw as any stack of theirs would
+        k3, k4 = (iter(jax.random.split(jax.random.fold_in(key, salt), 32)) for salt in (11, 13))
+        params["kda_blocks"] = blocks(k3, k4, (cfg.n_layers // (per + 1), per), bool(E), cfg.d_ff, kda=True)
     return params
 
 
@@ -448,6 +513,15 @@ EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 # expert by every row (rows x E) than the grouped product's padding does
 # (a tile of 512 a touched expert); from 512 rows on the grouped one wins.
 GROUPED_TILE_ROWS = 512
+
+
+def _layer_of(stack, index):
+    """One layer's slice of a stacked weight, read where it lies: `index` a
+    traced scalar, or (period, layer in it) of a stack [periods, layers a
+    period, ...]."""
+    for i in index if isinstance(index, tuple) else (index,):
+        stack = lax.dynamic_index_in_dim(stack, i, 0, keepdims=False)
+    return stack
 
 
 def _experts_in_place(blocks: PyTree):
@@ -738,21 +812,25 @@ def _every_expert_ffn(x, experts, top_e, top_p, cfg: TransformerConfig):
     at a handful of rows an expert it is bound by the padding's products and
     its time follows how many experts the rows happened to touch; this reads
     each expert's matrices once whatever the routing, n * E rows of products
-    where the grouped one pays 512 * the touched experts."""
-    E = cfg.n_experts
+    where the grouped one pays 512 * the touched experts. Under a share
+    (`cfg.n_experts_held`) `experts` holds the held ones and a token's weights
+    are the held columns of the [n, E] matrix."""
+    E, held = cfg.n_experts, cfg.experts_held
     w_gate, w_up, w_down = (experts[name] for name in EXPERT_WEIGHTS)
     with jax.named_scope("moe.experts"):
         # E is a batch dimension of both operands: as a free dimension of the
         # weights alone the product is x @ W[d, e * f], and the compiler copies
         # the whole stack into that layout. Results in the parameters' type, as
         # the grouped matmuls give them.
-        xe = jnp.broadcast_to(x, (E, *x.shape))
+        xe = jnp.broadcast_to(x, (held, *x.shape))
         gate = jnp.einsum("end,edf->enf", xe, w_gate, preferred_element_type=cfg.dtype)
         up = jnp.einsum("end,edf->enf", xe, w_up, preferred_element_type=cfg.dtype)
         act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
         ys = jnp.einsum("enf,efd->end", act, w_down, preferred_element_type=cfg.dtype)
     with jax.named_scope("moe.combine"):
         weights = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32) * top_p[..., None].astype(jnp.float32), axis=1)  # [n, E]
+        if held != E:  # one chip's share: the held experts' columns, the others' terms left out
+            weights = weights[:, cfg.first_expert : cfg.first_expert + held]
         return jnp.einsum("ne,end->nd", weights, ys.astype(jnp.float32)).astype(cfg.dtype)
 
 
@@ -768,7 +846,8 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
     layer's own matrices in `mp`; such a step multiplies every expert by
     every row (`_every_expert_ffn`) while it has fewer rows than one group
     of the grouped product pads to (GROUPED_TILE_ROWS). Returns out
-    [b, s, d], and with `counts` the rows each expert took [E] beside it."""
+    [b, s, d], and with `counts` the rows each of the router's E experts was
+    chosen by [E] beside it (under a share: held or not)."""
     from ..ops.moe_rows import combine_rows, dispatch_rows
 
     b, s, d = h.shape
@@ -786,7 +865,7 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
             top_p = top_p * cfg.route_scale
     if experts is not None:
         stack, index = experts
-        layer = {name: lax.dynamic_index_in_dim(stack[name], index, 0, keepdims=False) for name in EXPERT_WEIGHTS}
+        layer = {name: _layer_of(stack[name], index) for name in EXPERT_WEIGHTS}
         if n < GROUPED_TILE_ROWS:
             out = _every_expert_ffn(x, layer, top_e, top_p, cfg).reshape(b, s, d)
             if "shared" in mp:
@@ -794,11 +873,21 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
                     out = out + _ffn(h, mp["shared"], cfg)
             return (out, _tokens_per_expert(top_e, E)) if counts else out
         mp = dict(mp, **layer)
+    held, rows_per_expert = cfg.experts_held, None
     with jax.named_scope("moe.dispatch"):
         flat_e = top_e.reshape(n * k)
+        if held != E:
+            # One chip's share: a pick of an expert that is not held is dropped
+            # before the sort: it weighs nothing and sorts behind every group
+            # (expert `held`, which no group is), where the grouped products
+            # leave its row alone.
+            rows_per_expert = _tokens_per_expert(flat_e, E)
+            here = (top_e >= cfg.first_expert) & (top_e < cfg.first_expert + held)
+            top_p = jnp.where(here, top_p, 0.0)
+            flat_e = jnp.where(here, top_e - cfg.first_expert, held).reshape(n * k)
         order = _ckpt(jnp.argsort(flat_e), "moe_route")  # row r of the sorted is pair order[r]
         inverse = _ckpt(jnp.argsort(order), "moe_route")  # pair p sits in sorted row inverse[p]
-        group_sizes = _ckpt(_tokens_per_expert(flat_e, E), "moe_route")
+        group_sizes = _ckpt(_tokens_per_expert(flat_e, held), "moe_route")
         xs = _ckpt(dispatch_rows(x, order, inverse, k), "moe_xs_bf16")
     with jax.named_scope("moe.experts"):
         # Results in the parameters' type (the kernel accumulates in float32):
@@ -809,12 +898,58 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
         up = lax.ragged_dot(xs, mp["w_up"], group_sizes, preferred_element_type=cfg.dtype)
         act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
         ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
+        if held != E:  # rows behind the last group: whatever the product left there is not a number to weigh
+            ys = jnp.where((jnp.arange(n * k) < jnp.sum(group_sizes))[:, None], ys, 0)
     with jax.named_scope("moe.combine"):
         out = combine_rows(ys, top_p, order, inverse).reshape(b, s, d)
     if "shared" in mp:
         with jax.named_scope("moe.shared"):
             out = out + _ffn(h, mp["shared"], cfg)
-    return (out, group_sizes) if counts else out
+    return (out, group_sizes if rows_per_expert is None else rows_per_expert) if counts else out
+
+
+def _kda_mixer(h, ap, cfg: TransformerConfig, attend):
+    """A KDA layer's mixer on its normed input h [b, s, d] -> (its output
+    [b, s, d], kept): the projections, the decay and beta (float32), then the
+    caller's `attend(q, k, v, g, beta, conv) -> (o [b, s, n_heads, head_dim]
+    float32, kept)`, which owns what a sequence keeps: q, k, v [b, s, n_heads *
+    head_dim] are the projections BEFORE the convolution, in the parameters'
+    type (what a tail stores), g [b, s, n_heads, head_dim], beta [b, s,
+    n_heads], conv the three convolutions' weights; then the output norm a
+    head, the thin gate and `wo`."""
+    from ..ops import kda
+
+    def proj(x, w):
+        return jnp.einsum("bsd,dk->bsk", x, w, preferred_element_type=jnp.float32)
+
+    q, k, v = (proj(h, ap[name]).astype(cfg.dtype) for name in ("wq", "wk", "wv"))
+    with jax.named_scope("kda.gates"):
+        f = proj(proj(h, ap["w_fa"]).astype(cfg.dtype), ap["w_fb"])
+        g, beta = kda.gates(f, ap["a_log"], ap["dt_bias"], proj(h, ap["w_b"]))
+    o, kept = attend(q, k, v, g, beta, tuple(ap["conv_" + name] for name in "qkv"))
+    with jax.named_scope("kda.out"):
+        gate = proj(proj(h, ap["w_ga"]).astype(cfg.dtype), ap["w_gb"]) + ap["b_g"].astype(jnp.float32)
+        o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * ap["o_norm"]["scale"].astype(jnp.float32)
+        y = (o.reshape(*gate.shape) * jax.nn.sigmoid(gate)).astype(cfg.dtype)
+    return _ckpt(proj(y, ap["wo"]).astype(cfg.dtype), "attn_out_bf16"), kept
+
+
+def _kda_inputs(cfg: TransformerConfig, q, k, v, conv, tails, n_valid=None):
+    """What the recurrence reads, from the projections of ONE sequence's rows
+    in order, q, k, v [c, n_heads * head_dim]: the short convolutions from
+    `tails` (`_tail_shape`: 3 x (K - 1) x n_heads * head_dim entries), a sequence's slot of the pool
+    (q's, k's and v's rows before these; zeros at a sequence's start), SiLU,
+    the split into heads, the l2 norms of q and k -> (q, k, v [c, n_heads,
+    head_dim] float32, the tails after the first `n_valid` rows: all of them
+    if None)."""
+    from ..ops import kda
+
+    with jax.named_scope("kda.conv"):
+        tails = tails.reshape(3, cfg.kda_conv - 1, cfg.n_heads * cfg.head_dim)
+        ys, new_tails = zip(*(kda.short_conv(x, w, tail, n_valid) for x, w, tail in zip((q, k, v), conv, tails)))
+        q, k, v = (y.reshape(y.shape[0], cfg.n_heads, cfg.head_dim) for y in ys)
+        q, k = kda.qk_norms(q, k)
+    return q, k, v, jnp.stack(new_tails).reshape(_tail_shape(cfg))
 
 
 def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str = "", experts=None):
@@ -837,6 +972,9 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
     h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
+    if "conv_q" in ap:  # a KDA layer: a mixer of its own around the caller's `attend`
+        attn_out, kept = _kda_mixer(h, ap, cfg, attend)
+        return _block_ffn(x, attn_out, kept, h, layer_params, cfg, stats, experts)
     # Where q and k are split into heads decides which operand of their
     # projections moves. Split before rope, the dot's result is head-shaped
     # and the compiler lays the weight out for it: a slice and a transpose of
@@ -846,8 +984,13 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     # as many rows as the weight has (a training batch), the result while
     # it has fewer (a decode step, a prefill chunk).
     q, k, v, gate = _qkv(h, ap, cfg, split=b * s >= d)
-    q = _ckpt(apply_rope(q, cos, sin, cfg), "q_bf16")
-    k = _ckpt(apply_rope(k, cos, sin, cfg), "k_bf16")
+    rotates = not cfg.rope_layers or any(cfg.rope_layers)  # where no layer does, nothing to switch and nothing computed
+    if not rotates:
+        # Rope's elementwise pass is also what keeps the split into heads off the projections: with nothing between
+        # them the compiler gives the dot a head-shaped result and re-lays `wq` out for it, a transpose of 67 MB a step.
+        q, k = lax.optimization_barrier((q, k))
+    q = _ckpt(apply_rope(q, cos, sin, cfg) if rotates else q, "q_bf16")
+    k = _ckpt(apply_rope(k, cos, sin, cfg) if rotates else k, "k_bf16")
     v = _ckpt(v, "v_bf16")
     heads = [t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)]
     o, kept = attend(*heads) if gate is None else attend(*heads, jax.nn.log_sigmoid(gate))
@@ -862,6 +1005,15 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
         ).astype(cfg.dtype),
         "attn_out_bf16",
     )
+    return _block_ffn(x, attn_out, kept, h, layer_params, cfg, stats, experts)
+
+
+def _block_ffn(x, attn_out, kept, h, layer_params, cfg: TransformerConfig, stats: str, experts):
+    """`_block` from the mixer's output on: the residual, the feed-forward
+    and its residual; `h` the mixer's normed input (a parallel block's
+    feed-forward reads it too)."""
+    b, s, d = x.shape
+    mp = layer_params["mlp"]
     if cfg.post_norms:
         attn_out = _norm(attn_out, layer_params["post_attn_norm"]["scale"], cfg)
 
@@ -899,7 +1051,55 @@ def _attend_whole(cfg: TransformerConfig, mesh: Optional[Mesh], window=None):
                 "runs its whole-sequence forward with attn_impl='naive' (`retention_whole`)"
             )
         return lambda q, k, v, log_g: (retention_whole(q, k, v, log_g).astype(q.dtype), None)
+    if cfg.kda_per_period and cfg.attn_impl != "naive":
+        raise ValueError(
+            f"attn_impl={cfg.attn_impl!r}: a config with `kda_per_period` runs its whole-sequence forward "
+            "with attn_impl='naive' (`_kda_attend_whole` beside the plain softmax expression)"
+        )
     return lambda q, k, v: (_attention(q, k, v, cfg, mesh, window), None)
+
+
+def _kda_attend_whole(cfg: TransformerConfig):
+    """A KDA layer's `attend` over whole sequences that keep nothing: each
+    from zero tails and a zero state, the chunked form."""
+    from ..ops import kda
+
+    def one(q, k, v, g, beta, conv):
+        zeros = jnp.zeros(_tail_shape(cfg), q.dtype)
+        q, k, v, _ = _kda_inputs(cfg, q, k, v, conv, zeros)
+        with jax.named_scope("kda.chunk"):
+            return kda.kda_chunk(q, k, v, g, beta, jnp.zeros((cfg.n_heads, cfg.head_dim, cfg.head_dim), jnp.float32))[0]
+
+    return lambda q, k, v, g, beta, conv: (jax.vmap(one, in_axes=(0, 0, 0, 0, 0, None))(q, k, v, g, beta, conv), None)
+
+
+def _scan_periods(params: PyTree, cfg: TransformerConfig, step, carry, in_place: bool):
+    """The stack of a config with `kda_per_period` as ONE scan over its
+    periods, whose body is a period's layers in published order: the softmax
+    layer, then an inner scan over the KDA layers. `step(kind, carry, index,
+    layer_params, experts) -> (carry, y)`: kind "attn" or "kda"; index the
+    layer's place among the layers of its kind (its layer of the K/V pool, or
+    of the state pool); experts as `_routed_ffn` takes them where the expert
+    stacks stay out of the scans' xs (`in_place`: the serving steps), else
+    None. Returns (carry, (the softmax layers' ys [periods, ...], the KDA
+    layers' [periods, kda_per_period, ...]))."""
+    per = cfg.kda_per_period
+    (attn_riding, attn_stack), (kda_riding, kda_stack) = (
+        _experts_in_place(params[name]) if in_place else (params[name], None) for name in ("blocks", "kda_blocks")
+    )
+
+    def period(carry, xs):
+        p, attn_params, kda_params = xs
+        carry, y_attn = step("attn", carry, p, attn_params, None if attn_stack is None else (attn_stack, p))
+
+        def kda_layer(carry, xs):
+            j, layer_params = xs
+            return step("kda", carry, p * per + j, layer_params, None if kda_stack is None else (kda_stack, (p, j)))
+
+        carry, y_kda = lax.scan(kda_layer, carry, (jnp.arange(per), kda_params))
+        return carry, (y_attn, y_kda)
+
+    return lax.scan(period, carry, (jnp.arange(cfg.n_layers // (per + 1)), attn_riding, kda_riding))
 
 
 def _embed(params: PyTree, tokens, cfg: TransformerConfig):
@@ -965,6 +1165,16 @@ def forward_hidden(
             policy = None
         body = jax.checkpoint(body, policy=policy)
 
+    if cfg.kda_per_period:
+        attends = {"attn": _attend_whole(cfg, mesh), "kda": _kda_attend_whole(cfg)}
+
+        def layer(kind, x, layer_params):
+            return _block(x, layer_params, cfg, cos, sin, attends[kind])[0]
+
+        if cfg.remat:
+            layer = jax.checkpoint(layer, policy=policy, static_argnums=(0,))
+        x, _ = _scan_periods(params, cfg, lambda kind, x, _i, layer_params, _e: (layer(kind, x, layer_params), None), x, in_place=False)
+        return _norm(x, params["final_norm"]["scale"], cfg)
     for blocks, first, n in _layer_groups(params, cfg):
         x, _ = lax.scan(body, x, (*_per_layer(cfg, first, n), blocks))
     return _norm(x, params["final_norm"]["scale"], cfg)
@@ -1013,6 +1223,8 @@ def routing_stats(params: PyTree, tokens: jax.Array, cfg: TransformerConfig) -> 
     tokens_per_expert [L, E] (every row sums to batch*seq*k: nothing is
     dropped); gap [L, batch*seq] between the last probability a token took
     and the first it left out (how close each token is to another choice)."""
+    if cfg.kda_per_period:
+        raise ValueError("routing_stats walks groups of alike layers; a KDA stack's routers are counted by its decode step")
     cos, sin = rope_tables(cfg, tokens.shape[1])
     x = _embed(params, tokens, cfg)
 
@@ -1111,24 +1323,32 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     leaves alone; a window is not taken off the attention. A retention
     layer counts its gate, and in attention's place the chunked form: every
     query head reads the state (D x head_dim), every K/V head adds to it, and
-    the in-chunk pairs (half a chunk visible on average)."""
+    the in-chunk pairs (half a chunk visible on average). Under a share of
+    the experts a token counts its picks that fall on held ones."""
     ffn = 3 * cfg.d_model * cfg.d_ff
     if cfg.n_experts:
-        ffn = ffn * cfg.n_experts_per_tok + cfg.d_model * (cfg.n_experts + 3 * cfg.d_ff_shared)
+        # under a share, the part of a token's n_experts_per_tok picks that falls on held experts
+        ffn = ffn * cfg.n_experts_per_tok * cfg.experts_held / cfg.n_experts + cfg.d_model * (cfg.n_experts + 3 * cfg.d_ff_shared)
     nd = cfg.n_dense_layers
+    n_kda = cfg.n_layers // (cfg.kda_per_period + 1) * cfg.kda_per_period
+    hd, wide = cfg.head_dim, cfg.n_heads * cfg.head_dim
     n_params = (
         cfg.vocab_size * cfg.d_model
-        + cfg.n_layers
+        + (cfg.n_layers - n_kda)
         * (
             (3 if cfg.attn_gate else 2) * cfg.d_model * cfg.n_heads * cfg.head_dim
             + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
             + (cfg.d_model * cfg.n_kv_heads if cfg.retention_degree else 0)
         )
+        # a KDA layer: q, k, v, o; the decay's and the gate's thin pairs; beta
+        + n_kda * (4 * cfg.d_model * wide + 2 * (cfg.d_model * hd + hd * wide) + cfg.d_model * cfg.n_heads)
         + (cfg.n_layers - nd) * ffn
         + nd * 3 * cfg.d_model * cfg.d_ff_dense
         + (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size)
     )
-    attn = 12 * cfg.n_layers * cfg.n_heads * cfg.head_dim * (seq_len / 2)
+    attn = 12 * (cfg.n_layers - n_kda) * cfg.n_heads * cfg.head_dim * (seq_len / 2)
+    # a KDA layer in the chunked form: the state read and added to (2 x d_k x d_v a head each way) and the in-chunk pairs
+    attn += 6 * n_kda * cfg.n_heads * (2 * hd * hd + 2 * hd * min(64, seq_len) / 2)
     if cfg.retention_degree:
         D, hd = retention_state_dim(cfg.head_dim), cfg.head_dim
         visible = min(PREFILL_CHUNK_TOKENS, seq_len) / 2
@@ -1169,8 +1389,18 @@ TRASH_PAGE = 0
 PREFILL_CHUNK_TOKENS = 256
 
 
+def _tail_shape(cfg: TransformerConfig) -> Tuple[int, int]:
+    """The shape a sequence's convolution tails are stored in, one KDA layer's:
+    the 3 x (kda_conv - 1) rows of n_heads * head_dim as whole (16, 128) tiles
+    where they make such (a slot is then whole tiles of the pool, and a
+    prefill's write of one is no masked update of sixteen slots' tiles), else
+    as one row."""
+    total = 3 * (cfg.kda_conv - 1) * cfg.n_heads * cfg.head_dim
+    return (16, total // 16) if total % (16 * 128) == 0 else (1, total)
+
+
 def init_kv_pages(
-    cfg: TransformerConfig, num_pages: int, page_tokens: int
+    cfg: TransformerConfig, num_pages: int, page_tokens: int, state_slots: Optional[int] = None
 ) -> Dict[str, jax.Array]:
     """Allocates the paged KV pool: k/v of shape
     [n_layers, num_pages, page_tokens, n_kv_heads * head_dim]. A page is
@@ -1184,7 +1414,29 @@ def init_kv_pages(
     nothing): `s` [n_layers, num_pages, n_kv_heads, head_dim, D] and `z`
     [n_layers, num_pages, n_kv_heads, D], float32 (the layout: see
     `retention_phi`). The pool is one tree either way: forward_prefill and
-    forward_decode take it and hand it back under whatever names it has."""
+    forward_decode take it and hand it back under whatever names it has.
+
+    A KDA stack (`cfg.kda_per_period`) has both kinds of cache in the one
+    tree: `k` / `v` as above over its softmax layers ALONE, and over its KDA
+    layers alone `s` [kda layers, state_slots, n_heads, head_dim, head_dim]
+    float32, a head's state, with `tail` [kda layers, state_slots,
+    *_tail_shape], the 3 x (kda_conv - 1) rows of the q, k and v projections
+    that the short convolution still needs (q's, k's, v's, each oldest
+    first), in the parameters' type. A sequence holds
+    K/V pages as it grows and ONE state slot for its life (`state_slots`, or
+    `cfg.state_slots`; slot 0 is the trash slot, as page 0 is the trash page)."""
+    if cfg.kda_per_period:
+        periods = cfg.n_layers // (cfg.kda_per_period + 1)
+        slots, n_kda, wide = state_slots or cfg.state_slots, periods * cfg.kda_per_period, cfg.n_heads * cfg.head_dim
+        if slots < 2:
+            raise ValueError("a KDA stack's pool has a trash slot and at least one state slot: state_slots >= 2")
+        shape = (periods, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
+        return {
+            "k": jnp.zeros(shape, cfg.dtype),
+            "v": jnp.zeros(shape, cfg.dtype),
+            "s": jnp.zeros((n_kda, slots, cfg.n_heads, cfg.head_dim, cfg.head_dim), jnp.float32),
+            "tail": jnp.zeros((n_kda, slots, *_tail_shape(cfg)), cfg.dtype),
+        }
     if cfg.retention_degree:
         D = retention_state_dim(cfg.head_dim)
         return {
@@ -1238,6 +1490,7 @@ def forward_prefill(
     block_table: jax.Array,
     length: jax.Array,
     write_from: jax.Array,
+    slot=TRASH_PAGE,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Prefill ONE sequence: computes what the cache does not hold and
     writes its k/v into the paged pool.
@@ -1269,13 +1522,21 @@ def forward_prefill(
     slot, the one at position 0 from nothing whatever the slot held; rows
     past `length` leave the state as it was.
 
+    A KDA stack (`cfg.kda_per_period`) writes both caches: its softmax layers'
+    k/v into the pages of `block_table` as above, its KDA layers' states and
+    tails into state slot `slot` (a scalar; the trash slot from a caller that
+    names none), each chunk from what the one before it left there and the
+    one at position 0 from zeros WITHOUT reading what the slot held. A state
+    at a page's border is not kept, so nothing of it is a prefix: write_from
+    is 0.
+
     Returns (last-position logits [1, vocab] fp32, updated kv_pages).
     """
     from ..ops.paged_attention import paged_prefill_attention
 
     _, S = tokens.shape
-    state = bool(cfg.retention_degree)
-    names = ("s", "z") if state else ("k", "v")
+    state, hybrid = bool(cfg.retention_degree), bool(cfg.kda_per_period)
+    names = ("s", "z") if state else ("k", "v", "s", "tail") if hybrid else ("k", "v")
     T = S // block_table.shape[0] if state else kv_pages["k"].shape[2]
     C, granule = prefill_chunk_tokens(cfg, S // T, T)
     pages = C // T
@@ -1334,7 +1595,18 @@ def forward_prefill(
             )
             return (x, *pool), None
 
-        for blocks, first_layer, n in _layer_groups(params, cfg):
+        def period_step(kind, carry, index, layer_params, experts):
+            x, kp, vp, sp, tp = carry
+            if kind == "attn":
+                attend = _states_ride(attend_in(index, None, kp, vp), sp, tp)
+            else:
+                attend = _kda_chunk_attend(cfg, slot, c0, c0 + jnp.arange(C) < length, index, kp, vp, sp, tp)
+            x, pool = _block(x, layer_params, cfg, cos, sin, attend, experts=experts)
+            return (x, *pool), None
+
+        if hybrid:
+            (x, *pool), _ = _scan_periods(params, cfg, period_step, (x, *pool), in_place=True)
+        for blocks, first_layer, n in () if hybrid else _layer_groups(params, cfg):
             riding, stack = _experts_in_place(blocks)
             xs = (jnp.arange(first_layer, first_layer + n), *_per_layer(cfg, first_layer, n), riding)
             (x, *pool), _ = lax.scan(partial(scan_step, stack, first_layer), (x, *pool), xs)
@@ -1345,6 +1617,36 @@ def forward_prefill(
     *pool, h_last = lax.fori_loop(0, n_chunks, chunk_step, (*(kv_pages[name] for name in names), h_last))
     h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
     return _logits(params, h_last), dict(zip(names, pool))
+
+
+def _states_ride(attend, sp, tp):
+    """A KDA stack's softmax layer: the serving steps' K/V `attend`, handing
+    back the whole pool, the state leaves behind the K/V it wrote."""
+
+    def with_states(q, k, v):
+        o, kv = attend(q, k, v)
+        return o, (*kv, sp, tp)
+
+    return with_states
+
+
+def _kda_chunk_attend(cfg: TransformerConfig, slot, c0, valid, layer, kp, vp, sp, tp):
+    """forward_prefill's `attend` of one KDA layer (`layer`: its place in the
+    state pool sp / tp): the chunk at positions [c0, c0 + C) of the sequence
+    whose state slot is `slot`, from the state and tails the slot holds, or
+    from zeros where c0 is 0, whatever the slot's last owner left there;
+    `valid` [C]: the rows below the length. The K/V pool rides through."""
+    from ..ops import kda
+
+    def attend(q, k, v, g, beta, conv):
+        state_in = jnp.where(c0 > 0, sp[layer, slot], jnp.zeros((), sp.dtype))
+        tails_in = jnp.where(c0 > 0, tp[layer, slot], jnp.zeros((), tp.dtype))
+        q, k, v, tails_out = _kda_inputs(cfg, q[0], k[0], v[0], conv, tails_in, jnp.sum(valid))
+        with jax.named_scope("kda.chunk"):
+            o, state_out = kda.kda_chunk(q, k, v, g[0], beta[0], state_in, valid)
+        return o[None], (kp, vp, sp.at[layer, slot].set(state_out), tp.at[layer, slot].set(tails_out))
+
+    return attend
 
 
 def _state_chunk_attend(cfg: TransformerConfig, slot, c0, valid, layer, window, sp, zp):
@@ -1452,12 +1754,20 @@ def forward_decode(
     Under power retention block_tables is [B, 1], each row's state slot: a
     step decays, adds to and reads each active row's state of every layer in
     place (`_state_step_attend`); inactive rows use the trash slot.
+    A KDA stack (`cfg.kda_per_period`) steps both caches: its softmax layers
+    append to and read the pages of `block_tables`, and row i's KDA state and
+    tails are state slot i + 1 of the pool (a decode row IS the engine's slot,
+    given at admission and kept for the sequence's life; inactive rows use
+    the trash slot 0): `_kda_step_attend`. With `stats` under a share of the
+    experts (`cfg.n_experts_held`), `experts_touched` counts the held ones
+    and a second counter, `held_picks`, how many of the rows' choices (B x
+    n_experts_per_tok a routed layer) fell on them.
     """
     from ..ops.paged_attention import paged_attention
 
     B = tokens.shape[0]
-    state = bool(cfg.retention_degree)
-    names = ("s", "z") if state else ("k", "v")
+    state, hybrid = bool(cfg.retention_degree), bool(cfg.kda_per_period)
+    names = ("s", "z") if state else ("k", "v", "s", "tail") if hybrid else ("k", "v")
     P = block_tables.shape[1]
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
@@ -1509,18 +1819,62 @@ def forward_decode(
         )
         return (x, *pool), (rows_per_expert[0] if stats else None)
 
-    carry, touched = (x, *(kv_pages[name] for name in names)), jnp.int32(0)
-    for blocks, first, n in _layer_groups(params, cfg):
+    def period_step(kind, carry, index, layer_params, experts):
+        x, kp, vp, sp, tp = carry
+        if kind == "attn":
+            attend = _states_ride(attend_in(index, None, kp, vp), sp, tp)
+        else:
+            attend = _kda_step_attend(cfg, jnp.where(active, jnp.arange(B) + 1, TRASH_PAGE), active, index, kp, vp, sp, tp)
+        x, pool, *rows_per_expert = _block(x, layer_params, cfg, cos, sin, attend, stats="experts" if stats else "", experts=experts)
+        return (x, *pool), (rows_per_expert[0] if stats else None)
+
+    carry, touched, held_picks = (x, *(kv_pages[name] for name in names)), jnp.int32(0), jnp.int32(0)
+    share = cfg.experts_held != cfg.n_experts
+    here = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
+    if hybrid:
+        carry, rows_per_expert = _scan_periods(params, cfg, period_step, carry, in_place=True)
+        if stats:  # [periods, E] and [periods, kda_per_period, E]: the held experts' columns
+            rows = jnp.concatenate([t.reshape(-1, cfg.n_experts)[:, here] for t in rows_per_expert])
+            touched, held_picks = jnp.sum(rows > 0, dtype=jnp.int32), jnp.sum(rows, dtype=jnp.int32)
+    for blocks, first, n in () if hybrid else _layer_groups(params, cfg):
         riding, stack = _experts_in_place(blocks)
         carry, rows_per_expert = lax.scan(
             partial(scan_step, stack, first), carry, (jnp.arange(first, first + n), *_per_layer(cfg, first, n), riding)
         )
         if rows_per_expert is not None:  # [n, E] of a routed group
+            if share:
+                rows_per_expert = rows_per_expert[:, here]
+                held_picks = held_picks + jnp.sum(rows_per_expert, dtype=jnp.int32)
             touched = touched + jnp.sum(rows_per_expert > 0, dtype=jnp.int32)
     x, *pool = carry
     x = _norm(x, params["final_norm"]["scale"], cfg)
     out = _logits(params, x[:, 0]), dict(zip(names, pool))
+    if stats and share:
+        return (*out, {"experts_touched": touched, "held_picks": held_picks})
     return (*out, {"experts_touched": touched}) if stats else out
+
+
+def _kda_step_attend(cfg: TransformerConfig, slots, active, layer, kp, vp, sp, tp):
+    """forward_decode's `attend` of one KDA layer (`layer`: its place in the
+    state pool sp / tp): row b's state and tails are slot slots[b] (the trash
+    slot for a row that is not `active`); each takes its token through the
+    short convolutions and the delta rule and is read, in place. The one-pass
+    kernel where it can tile the state, else the plain expression over a
+    gathered copy. The K/V pool rides through."""
+    from ..ops import kda
+
+    def attend(q, k, v, g, beta, conv):
+        ins = jax.vmap(partial(_kda_inputs, cfg), in_axes=(0, 0, 0, None, 0))(q, k, v, conv, tp[layer, slots])
+        q, k, v = (t[:, 0] for t in ins[:3])
+        with jax.named_scope("kda.step"):
+            if kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim):
+                o, sp_ = kda.kda_decode(q, k, v, g[:, 0], beta[:, 0], sp, layer, slots, active)
+            else:
+                o, s_new = kda.kda_step(q, k, v, g[:, 0], beta[:, 0], sp[layer, slots])
+                sp_ = sp.at[layer, slots].set(s_new)
+        return o[:, None], (kp, vp, sp_, tp.at[layer, slots].set(ins[3]))
+
+    return attend
 
 
 def _state_step_attend(cfg: TransformerConfig, slots, active, layer, window, sp, zp):

@@ -324,6 +324,7 @@ class InferenceEngine:
             self._waiting.popleft()
             slot = self._slots.index(None)
             seq.slot = slot
+            seq.prompt.slot = slot  # its decode row from here on: a model with a fixed state a sequence keeps it by that row
             self._slots[slot] = seq
             budget -= new_tokens
             admitted.append(seq)
@@ -727,7 +728,12 @@ class InferenceEngine:
         the bytes of state the steps read and wrote (live rows x layers x 2 x
         a state) and the state slots live, summed over `steps`; prefill_state
         (the same): the chunks its prefills computed and how many of them were
-        carried a state in by their predecessor; loop.s: wall time of the loop, loop.idle_s the part of
+        carried a state in by their predecessor; a KDA stack (states AND K/V
+        pages) counts both of those and decode_kv beside them: the K/V bytes
+        and positions its steps' live rows read in their softmax layers;
+        decode_experts.picks / held_picks: the steps' rows x choices a routed
+        layer, and how many of them fell on experts held here (all, unless
+        the model holds one chip's share of them); loop.s: wall time of the loop, loop.idle_s the part of
         it waiting with nothing to do. loop.s = idle_s + admit.s +
         prefill.s + batch.s + decode.s + emit.s, and loop.s - idle_s -
         prefill.s - decode.s is the engine's own host time. A stage in

@@ -10,8 +10,11 @@ The protocol's arguments and results are plain lists and ints to a model
 that wants no more. What else the two sides tell each other rides on them as
 attributes, so a wrapper that hands them on unopened carries it through.
 Model to engine: `PrefillToken.computed_tokens`, `DecodeTokens.counters`.
-Engine to model: `StepTokens.step`, and on `StepTokens` and `PromptTokens`
-alike `launched`, a callable the model may call once its executable has
+Engine to model: `StepTokens.step`; `PromptTokens.slot`, the decode row the
+engine gave the sequence at admission and keeps for it until it finishes
+(row i of every later `decode`: a model that keeps a fixed state a sequence
+beside its pages keeps it by that row; None from any other caller); and on
+`StepTokens` and `PromptTokens` alike `launched`, a callable the model may call once its executable has
 been dispatched and before it blocks for the result. The engine uses that
 instant to make the sink calls of the step before, so the streams they wake
 run while the chip works (engine.py, `_launched`). A model that ignores it
@@ -91,11 +94,14 @@ class StepTokens(list):
 
 class PromptTokens(list):
     """What the engine hands `prefill` as `prompt`: the prompt's tokens, a
-    list to every model, with the same `launched` as StepTokens carries."""
+    list to every model, with the same `launched` as StepTokens carries and
+    `slot`, the decode row the engine admitted the sequence to (set at
+    admission, before the prefill; None until then)."""
 
-    def __init__(self, tokens, launched=None):
+    def __init__(self, tokens, launched=None, slot=None):
         super().__init__(tokens)
         self.launched = launched
+        self.slot = slot
 
 
 class StubModel:
@@ -152,7 +158,16 @@ class PagedLM:
     allocator hands a sequence exactly one page for its life, `pages[0]` /
     `block_tables[i][0]` is its state's slot, and nothing of it is shared:
     every prompt is computed whole (`shares_prefix_pages` is False, which
-    the engine asks of a model that has it).
+    the engine asks of a model that has it). A KDA stack
+    (`cfg.kda_per_period`) has both: K/V pages of its softmax layers, handed
+    out by the allocator as for any softmax model, and a fixed state a KDA
+    layer (a float32 matrix a head and the short convolution's tail) in one
+    of `max_slots + 1` state slots, which no allocator hands out: decode row
+    i's state is slot i + 1, a prefill writes the slot of the row the engine
+    admitted its prompt to (`PromptTokens.slot`), and slot 0 is the trash
+    slot, which a caller's bare list writes exactly as its pages are the
+    trash page. A page's K/V could be shared by prefix but the state at its
+    border is not kept, so such a model shares nothing either.
     """
 
     def __init__(
@@ -186,11 +201,15 @@ class PagedLM:
         self.max_slots = max_slots
         self.max_pages_per_seq = max_pages_per_seq
         self.state_cache = bool(cfg.retention_degree)
+        self.hybrid_cache = bool(cfg.kda_per_period)
         if self.state_cache and max_pages_per_seq != 1:
             raise ValueError(f"a retention model's page is a sequence's whole state: max_pages_per_seq is 1, not {max_pages_per_seq}")
-        self.kv = tfm.init_kv_pages(cfg, num_pages, page_tokens)
+        self.kv = tfm.init_kv_pages(cfg, num_pages, page_tokens, max_slots + 1)  # the slots: a KDA stack's alone
+        slotted = ("s", "tail") if self.hybrid_cache else ()
         # One page over all layers: page_tokens positions of K/V, or one sequence's whole state.
-        self.page_bytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(self.kv)) // num_pages
+        self.page_bytes = sum(leaf.nbytes for name, leaf in self.kv.items() if name not in slotted) // num_pages
+        # A KDA stack's other cache: one sequence's states and tails over all its KDA layers.
+        self.state_bytes = sum(self.kv[name].nbytes for name in slotted) // (max_slots + 1)
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
         # One lock around every jitted call: the engine loop is the only
@@ -200,16 +219,20 @@ class PagedLM:
     @property
     def shares_prefix_pages(self) -> bool:
         """Whether a full page of one prompt may serve another (the engine asks)."""
-        return not self.state_cache
+        return not (self.state_cache or self.hybrid_cache)
 
     def describe(self) -> Dict[str, Any]:
         """Which process and devices serve this model, what its cache is
         (`cache`: "kv_pages", a page `page_tokens` positions of K/V, or
         "state", a page one sequence's whole recurrent state; the bytes of a
-        page over all layers either way), which expression the decode and
+        page over all layers either way; or "state+kv_pages", a KDA stack's
+        two: `page_bytes` of a page of its softmax layers' K/V and
+        `state_bytes` of one sequence's fixed states and tails over its KDA
+        layers), which expression the decode and
         prefill executables attend with ("paged_kernel" or "xla_gather":
         transformer.paged_attention_path; a state model's decode
-        "retention_kernel" or "xla_step": ops/power_retention.can_tile), and
+        "retention_kernel" or "xla_step": ops/power_retention.can_tile; a KDA
+        stack's `decode_state`: "kda_kernel" or "xla_step": ops/kda.can_tile), and
         what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
 
@@ -220,13 +243,22 @@ class PagedLM:
             attention = "retention_kernel" if can_tile(self.cfg.n_heads, self.cfg.n_kv_heads, self.cfg.head_dim) else "xla_step"
         else:
             attention = self._tfm.paged_attention_path(self.cfg, self.page_tokens)
+        cache = {"kind": "state" if self.state_cache else "kv_pages", "page_bytes": self.page_bytes}
+        extra = {}
+        if self.hybrid_cache:
+            from ...ops import kda
+
+            cache = {"kind": "state+kv_pages", "state_bytes": self.state_bytes, "page_bytes": self.page_bytes}
+            tiles = kda.can_tile(self.cfg.n_heads, self.cfg.head_dim, self.cfg.head_dim)
+            extra = {"decode_state": "kda_kernel" if tiles else "xla_step"}
         return {
             "pid": os.getpid(),
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
-            "cache": {"kind": "state" if self.state_cache else "kv_pages", "page_bytes": self.page_bytes},
+            "cache": cache,
             "decode_attention": attention,
+            **extra,
             "peak_bytes_in_use": [
                 (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs
             ],
@@ -253,13 +285,13 @@ class PagedLM:
                 )
                 out = self._jnp.argmax(logits, axis=-1).astype(self._jnp.int32)
                 if stats:  # a routed model: the experts the step touched ride behind the tokens, one transfer
-                    out = self._jnp.concatenate([out, stats[0]["experts_touched"][None]])
+                    out = self._jnp.concatenate([out, *(count[None] for count in stats[0].values())])
                 return out, kv
 
             # The executable's name in a device trace (line `XLA Modules`:
             # jit_llm_decode), where every jitted closure called `step` reads alike.
-            # A state model's executables under names of their own.
-            step.__name__ = "llm_decode_state" if self.state_cache else "llm_decode"
+            # A state model's executables, and a KDA stack's, under names of their own.
+            step.__name__ = "llm_decode_state" if self.state_cache else "llm_decode_hybrid" if self.hybrid_cache else "llm_decode"
             self._decode_jit = self._jax.jit(step, donate_argnums=self._donate((3,)))
         return self._decode_jit
 
@@ -268,14 +300,15 @@ class PagedLM:
         if fn is None:
             cfg, tfm = self.cfg, self._tfm
 
-            def step(params, tokens, kv, block_table, length, write_from):
+            def step(params, tokens, kv, block_table, length, write_from, *slot):
                 logits, kv = tfm.forward_prefill(
-                    params, tokens, cfg, kv, block_table, length, write_from
+                    params, tokens, cfg, kv, block_table, length, write_from, *slot
                 )
                 return self._jnp.argmax(logits[0], axis=-1).astype(self._jnp.int32), kv
 
-            # jit_llm_prefill_p<pages>, a bucket a name (a state model's: jit_llm_prefill_state_p1)
-            step.__name__ = ("llm_prefill_state_p" if self.state_cache else "llm_prefill_p") + str(n_pages_bucket)
+            # jit_llm_prefill_p<pages>, a bucket a name (a state model's: jit_llm_prefill_state_p1; a KDA stack's: _hybrid_p<pages>)
+            kind = "llm_prefill_state_p" if self.state_cache else "llm_prefill_hybrid_p" if self.hybrid_cache else "llm_prefill_p"
+            step.__name__ = kind + str(n_pages_bucket)
             fn = self._jax.jit(step, donate_argnums=self._donate((2,)))
             self._prefill_jits[n_pages_bucket] = fn
         return fn
@@ -332,8 +365,11 @@ class PagedLM:
         n_pages = max(1, -(-len(prompt) // T))
         bucket = self._bucket_pages(n_pages)
         S = bucket * T
-        if self.state_cache and cached_tokens:
-            raise ValueError("a retention model's state is no prefix another prompt can share: cached_tokens is 0")
+        if (self.state_cache or self.hybrid_cache) and cached_tokens:
+            raise ValueError("a model with a recurrent state keeps none at a page's border, so no prefix is shared: cached_tokens is 0")
+        # A KDA stack: the state slot of the decode row the engine admitted this prompt to; the trash slot for a bare list.
+        row = getattr(prompt, "slot", None)
+        slot = (np.int32(TRASH_PAGE if row is None else row + 1),) if self.hybrid_cache else ()
         chunk, granule = self._tfm.prefill_chunk_tokens(self.cfg, bucket, T)
         _anchor, chunks = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk, granule)
         attrs = {"bucket_tokens": S, "computed_tokens": chunks * chunk}
@@ -351,13 +387,14 @@ class PagedLM:
                 bt,
                 np.int32(len(prompt)),
                 np.int32(cached_tokens),
+                *slot,
             ),
             "llm.prefill",
             attrs,
             getattr(prompt, "launched", None),  # the engine's PromptTokens; a bare list from anyone else
         )
         # A state model: the chunks that started from the state their predecessor left (all but a prompt's first).
-        counters = {"prefill_state": {"chunks": chunks, "carried_in": chunks - 1}} if self.state_cache else None
+        counters = {"prefill_state": {"chunks": chunks, "carried_in": chunks - 1}} if self.state_cache or self.hybrid_cache else None
         return PrefillToken(tok, attrs["computed_tokens"], counters)
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:
@@ -382,7 +419,12 @@ class PagedLM:
         if cfg.n_experts:
             # Every row of the step is routed, the inactive slots' too.
             routed_layers = cfg.n_layers - cfg.n_dense_layers
-            counters["decode_experts"] = {"touched": int(out[B]), "held": routed_layers * cfg.n_experts, "steps": 1}
+            picks = routed_layers * B * cfg.n_experts_per_tok
+            counters["decode_experts"] = {
+                "touched": int(out[B]), "held": routed_layers * cfg.experts_held, "steps": 1,
+                # of the rows' choices, those that fell on experts held here: all of them unless this is a share
+                "picks": picks, "held_picks": int(out[B + 1]) if len(out) > B + 1 else picks,
+            }
         if any(cfg.windows):
             live = pos[pos >= 0].astype(np.int64) + 1  # each live row's K/V length
             reach = np.asarray([w or self._tfm.NO_WINDOW for w in cfg.windows], np.int64)
@@ -390,10 +432,15 @@ class PagedLM:
                 "kv_read": int(np.minimum(live[None, :], reach[:, None]).sum()),
                 "kv_live": int(cfg.n_layers * live.sum()),
             }
-        if self.state_cache:
+        if self.state_cache or self.hybrid_cache:
             # Every live row's state of every layer is read once and written once.
             live = int((pos >= 0).sum())
-            counters["decode_state"] = {"bytes": 2 * live * self.page_bytes, "live_slots": live, "steps": 1}
+            one = self.state_bytes if self.hybrid_cache else self.page_bytes
+            counters["decode_state"] = {"bytes": 2 * live * one, "live_slots": live, "steps": 1}
+        if self.hybrid_cache:
+            # The K/V the live rows' softmax layers read: every position up to their own, a page's bytes / page_tokens each.
+            kv_tokens = int((pos[pos >= 0].astype(np.int64) + 1).sum())
+            counters["decode_kv"] = {"bytes": kv_tokens * self.page_bytes // self.page_tokens, "tokens": kv_tokens, "steps": 1}
         return DecodeTokens(tokens, counters) if counters else tokens
 
 

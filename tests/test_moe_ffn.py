@@ -413,3 +413,68 @@ def test_remat_hot_gives_the_gradients_of_the_step_without_remat():
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, atol=1e-6, err_msg=str(path))
     assert float(jnp.max(jnp.abs(want["blocks"]["mlp"]["router"]))) > 1e-4
+
+
+# ------------------------------------------- one chip's share of the experts
+
+
+def share_of(full_cfg, mp_full, rank, held):
+    """Rank `rank`'s config and its slice of a full layer's weights: the
+    router and its bias whole, the expert stacks' rows [rank x held, ...)."""
+    cfg = full_cfg.replace(n_experts_held=held, first_expert=rank * held)
+    here = slice(rank * held, (rank + 1) * held)
+    return cfg, dict(mp_full, **{name: mp_full[name][here] for name in tfm.EXPERT_WEIGHTS})
+
+
+@pytest.mark.parametrize("router", ["softmax_renormalised", "sigmoid_with_a_selecting_bias_and_a_shared_expert"])
+@pytest.mark.parametrize("form", ["grouped", "every_expert"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(form, router):
+    """The model-configs guide's share test. A layer of 16 experts, top-4, cut
+    eight ways (2 experts a rank): every rank routes over all 16, renormalises
+    over the 4 chosen whether held or not, and computes its own experts' part;
+    the eight parts, the shared expert counted once, are the uncut layer's
+    result. Both forms of the routed FFN: the grouped one (training's, and a
+    serving step's of 512 rows and more) and the every-expert one (a decode
+    step's), which must also agree with each other share by share."""
+    kw = dict(norm_topk_prob=True) if router.startswith("softmax") else dict(router_score="sigmoid", norm_topk_prob=True, route_scale=1.7, d_ff_shared=F)
+    full = tfm.tiny(n_kv_heads=4, d_ff=F, n_experts=16, n_experts_per_tok=4, dtype=jnp.float32, **kw)
+    mp = jax.tree_util.tree_map(lambda a: a[0], tfm.init_params(jax.random.PRNGKey(3), full)["blocks"]["mlp"])
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, D), jnp.float32)
+
+    def ffn(cfg, mp):
+        if form == "grouped":
+            return tfm._routed_ffn(h, mp, cfg)
+        stack = {name: mp[name][None] for name in tfm.EXPERT_WEIGHTS}
+        riding = {name: w for name, w in mp.items() if name not in tfm.EXPERT_WEIGHTS}
+        return tfm._routed_ffn(h, riding, cfg, experts=(stack, jnp.int32(0)))
+
+    with jax.default_matmul_precision("highest"):
+        whole = ffn(full, mp)
+        shared = tfm._ffn(h, mp["shared"], full) if "shared" in mp else 0.0
+        parts = [ffn(*share_of(full, mp, rank, 2)) for rank in range(8)]
+        other = [tfm._routed_ffn(h, share_of(full, mp, rank, 2)[1], share_of(full, mp, rank, 2)[0]) for rank in (0, 5)]
+    np.testing.assert_allclose(sum(parts) - 7 * shared, whole, rtol=2e-5, atol=2e-6)
+    assert float(jnp.max(jnp.abs(parts[0] - shared))) > 1e-3  # a share is a part, not nothing
+    for rank, grouped in zip((0, 5), other):
+        np.testing.assert_allclose(parts[rank], grouped, rtol=2e-5, atol=2e-6)
+
+
+def test_a_share_keeps_the_routers_width_and_its_gradients_reach_only_the_held_experts():
+    full = moe_cfg(norm_topk_prob=True)
+    cfg = full.replace(n_experts_held=2, first_expert=4)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    mlp = params["blocks"]["mlp"]
+    assert mlp["router"].shape == (2, D, E) and mlp["w_gate"].shape == (2, 2, D, F) and mlp["w_down"].shape == (2, 2, F, D)
+    with pytest.raises(ValueError, match="not among the router's"):
+        tfm.init_params(jax.random.PRNGKey(0), full.replace(n_experts_held=4, first_expert=6))
+    mp = jax.tree_util.tree_map(lambda a: a[0], mlp)
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 7, D), jnp.float32)
+    out, counts = tfm._routed_ffn(h, mp, cfg, counts=True)
+    assert counts.shape == (E,) and int(counts.sum()) == 14 * K  # every pick of the router's, held or not
+    grads = jax.grad(lambda mp: jnp.sum(tfm._routed_ffn(h, mp, cfg) ** 2))(mp)
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree_util.tree_leaves(grads))
+    picked = np.asarray(counts[4:6]) > 0
+    assert np.array_equal(np.asarray(jnp.any(grads["w_down"] != 0, axis=(1, 2))), picked)
+    # the held share of a token's picks is what flops_per_token counts: 2 of 8 experts, so a quarter of k
+    dense_part = tfm.flops_per_token(cfg.replace(n_experts_per_tok=0), 128)
+    assert tfm.flops_per_token(cfg, 128) - dense_part == pytest.approx((tfm.flops_per_token(full, 128) - tfm.flops_per_token(full.replace(n_experts_per_tok=0), 128)) / 4)

@@ -1248,3 +1248,56 @@ def test_replica_spans_survive_serve_shutdown(traced_stream):
     engine = [s for s in spans if s["name"] in ("llm.step", "llm.decode", "llm.emit")]
     assert len(engine) == 3 * 4 and {s["pid"] for s in engine} == {replica_pid}
     assert {s["name"] for s in _named(spans, "serve.run")} == {"serve.run"}
+
+
+# ----------------------------------------- the slot on the admitted prompt
+
+
+class _SlotWatchingStub(StubModel):
+    """Notes the `slot` each prompt carried into `prefill` and the rows that
+    were live in each `decode`: what a model with a fixed state a sequence
+    (PagedLM over a KDA stack) keeps that state by."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.prefilled, self.live_rows = [], []
+
+    def prefill(self, prompt, pages, cached_tokens):
+        self.prefilled.append((getattr(prompt, "slot", "absent"), tuple(pages)))
+        return super().prefill(prompt, pages, cached_tokens)
+
+    def decode(self, last_tokens, positions, block_tables):
+        self.live_rows.append({i: tuple(block_tables[i]) for i, p in enumerate(positions) if p >= 0})
+        return super().decode(last_tokens, positions, block_tables)
+
+
+@pytest.mark.parametrize("n_requests", [2, 5], ids=["as_many_as_slots", "more_than_twice_the_slots"])
+def test_the_engine_writes_the_decode_row_onto_the_prompt_it_admits(n_requests):
+    """Engine to model: `PromptTokens.slot` is the row the sequence decodes in
+    from its prefill to its end. Every prefill carries one; the row whose
+    block table holds a prefill's pages is that slot in every later step; a
+    waiting request gets the slot of the one that left."""
+    model = _SlotWatchingStub(max_slots=2, step_delay_s=0.002)
+    eng = InferenceEngine(model, EngineConfig(page_tokens=4, pool_pages=64), name="t-slot")
+    try:
+        threads = [threading.Thread(target=_collect, args=(eng, [i + 1, i + 2, i + 3], 6 + i)) for i in range(n_requests)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        eng.close()
+    assert len(model.prefilled) == n_requests and all(slot in (0, 1) for slot, _pages in model.prefilled)
+    for slot, pages in model.prefilled:
+        rows = [step for step in model.live_rows if any(table[: len(pages)] == pages for table in step.values())]
+        assert rows and all(step.get(slot, ())[: len(pages)] == pages for step in rows), (slot, pages)
+    assert {slot for slot, _ in model.prefilled} == {0, 1}
+
+
+def test_a_bare_list_has_no_slot_and_the_prompt_type_carries_one():
+    from ray_tpu.serve.llm.model import PromptTokens
+
+    assert PromptTokens([1, 2]).slot is None and PromptTokens([1], slot=3).slot == 3 and list(PromptTokens((1, 2))) == [1, 2]
+    model = _SlotWatchingStub()
+    model.prefill([1, 2, 3], [1], 0)
+    assert model.prefilled == [("absent", (1,))]

@@ -13,6 +13,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_tpu.models import transformer as tfm
@@ -277,3 +278,80 @@ def test_a_state_models_executables_carry_names_of_their_own(one_chip):
     prefill = lm._get_prefill(1).lower(params, sds((1, 32), jnp.int32), kv, sds((1,), jnp.int32), scalar, scalar)
     assert "module @jit_llm_decode_state " in decode.as_text() and "module @jit_llm_prefill_state_p1 " in prefill.as_text()
     assert "HloModule jit_llm_decode_state," in decode.compile().as_text()
+
+
+# ------------------------------------------------ a KDA stack (PR 44)
+
+# Solar-Open2's widths at one period, 8 of 64 experts held and a small vocabulary: GQA 64:8 x 128 gated without rope,
+# three KDA layers of 64 heads x 128 x 128, experts of width 1280.
+KDA_STACK = dict(
+    vocab_size=2048, d_model=4096, n_layers=4, n_heads=64, n_kv_heads=8, d_head=128, d_ff=1280, n_experts=64, n_experts_per_tok=8,
+    norm_topk_prob=True, router_score="sigmoid", d_ff_shared=1280, n_experts_held=8, first_expert=8, kda_per_period=3, attn_gate=True,
+    rope_layers=(False,) * 4, max_seq_len=8192, attn_impl="naive", remat=False,
+)
+KDA_SLOTS, KDA_PAGES, KDA_PAGE_TOKENS, KDA_TABLE = 16, 129, 128, 8
+
+
+def _compile_kda(one_chip, step: str):
+    """forward_decode (16 rows) or forward_prefill (a bucket of 4 pages) of
+    the KDA stack over a pool of 129 K/V pages and 17 state slots, donated."""
+    cfg = tfm.TransformerConfig(**KDA_STACK)
+    sds = _sds(one_chip)
+    shapes = lambda make: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))  # noqa: E731
+    params = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    kv = shapes(lambda: tfm.init_kv_pages(cfg, KDA_PAGES, KDA_PAGE_TOKENS, KDA_SLOTS + 1))
+    B, scalar = KDA_SLOTS, sds((), jnp.int32)
+    if step == "decode":
+        return cfg, kv, jax.jit(lambda p, t, pos, kv, bts: tfm.forward_decode(p, t, pos, cfg, kv, bts), donate_argnums=(3,)).lower(
+            params, sds((B,), jnp.int32), sds((B,), jnp.int32), kv, sds((B, KDA_TABLE), jnp.int32)).compile()
+    return cfg, kv, jax.jit(lambda p, t, kv, bt, n, w, slot: tfm.forward_prefill(p, t, cfg, kv, bt, n, w, slot), donate_argnums=(2,)).lower(
+        params, sds((1, 4 * KDA_PAGE_TOKENS), jnp.int32), kv, sds((4,), jnp.int32), scalar, scalar, scalar).compile()
+
+
+def _pool_nbytes(kv):
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(kv))
+
+
+def test_kda_decode_step_is_one_kernel_a_layer_over_both_pools_in_place(one_chip, mosaic):
+    """The decode step at Solar-Open2's widths: the state update is the Mosaic
+    kernel under its name beside the paged-attention kernel, both pools (K/V
+    pages and 17 x 12.6 MB of states) are aliased to the output, no copy of
+    either is among the temporaries, the expert stacks are read where they
+    lie, and `wq` is not re-laid-out for a head-shaped result (with no rope
+    between the projection and the split, that takes a barrier: `_block`)."""
+    from ray_tpu.ops import kda
+
+    cfg, kv, compiled = _compile_kda(one_chip, "decode")
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim)
+    assert kda.KERNEL_NAME in text and pa.KERNEL_NAME in text and text.count("tpu_custom_call") == 2
+    assert mem.alias_size_in_bytes >= _pool_nbytes(kv)
+    assert mem.temp_size_in_bytes < 64 * 2**20
+    big_copies = [m for m in re.findall(r"= (\w+)\[([\d,]+)\]\S* copy\(", text) if np.prod([int(n) for n in m[1].split(",")]) > 2**22]
+    assert not big_copies, big_copies
+
+
+def test_kda_prefill_bucket_updates_both_pools_in_place(one_chip, mosaic):
+    """A prefill bucket of four pages walked in 256-row chunks: both pools
+    aliased; the chunked form's [rows, rows, heads, channels] decays are fused
+    into the sums over channels that consume them and never stored (a
+    sub-chunk's would be 134 MB)."""
+    cfg, kv, compiled = _compile_kda(one_chip, "prefill")
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert pa.PREFILL_KERNEL_NAME in text
+    assert mem.alias_size_in_bytes >= _pool_nbytes(kv)
+    assert mem.temp_size_in_bytes < 128 * 2**20
+
+
+def test_a_kda_stacks_executables_carry_names_of_their_own(one_chip):
+    from ray_tpu.serve.llm.model import PagedLM
+
+    cfg = tfm.tiny(attn_impl="naive", dtype=jnp.float32, n_layers=4, kda_per_period=3, rope_layers=(False,) * 4, n_kv_heads=2)
+    lm = PagedLM(cfg, num_pages=9, page_tokens=8, max_slots=2, max_pages_per_seq=4)
+    sds = _sds(one_chip)
+    shaped = lambda tree: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params, kv, scalar = shaped(lm.params), shaped(lm.kv), sds((), jnp.int32)
+    decode = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 4), jnp.int32))
+    prefill = lm._get_prefill(2).lower(params, sds((1, 16), jnp.int32), kv, sds((2,), jnp.int32), scalar, scalar, scalar)
+    assert "module @jit_llm_decode_hybrid " in decode.as_text() and "module @jit_llm_prefill_hybrid_p2 " in prefill.as_text()
+    assert "HloModule jit_llm_decode_hybrid," in decode.compile().as_text()
