@@ -443,7 +443,7 @@ def decode_step_bytes(config: Dict[str, Any], live_seqs: int, kv_tokens: int, ex
 def decode_step_min_bytes(config: Dict[str, Any], live_seqs: int, kv_tokens: int) -> float:
     """`decode_step_bytes` with the experts a uniform router is expected to
     touch. The cell's roofline reads the program's own count instead
-    (`readers/trace_decode_roofline_counted.py`): an expectation that overstates
+    (`readers/trace_modules.step_bytes`, `_counted.py`): an expectation that overstates
     the bytes can read past 100 %."""
     m = dims(config)
     return decode_step_bytes(config, live_seqs, kv_tokens, (m["L"] - m["dense"]) * experts_touched(config, live_seqs))

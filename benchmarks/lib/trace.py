@@ -209,19 +209,6 @@ class Trace:
 
     # ------------------------------------------------------------- spans
 
-    def span_device_seconds(self, span_name: str) -> List[Tuple[Dict, float]]:
-        """For each host span of that name: its args and the device-busy
-        seconds inside it (first chip). The host waits for the step's
-        result inside the span, so this is that step's device time."""
-        if not self.chips:
-            return []
-        busy = self._busy(self.chips[0])
-        return [
-            (s["args"], measure(clip(busy, s["start"], s["end"])))
-            for s in self.spans
-            if s["name"] == span_name
-        ]
-
     def idle_gaps_by_span(self, k: int = 10) -> List[List]:
         """Idle seconds of the first chip inside the window, summed by the
         host span that covers each gap's midpoint."""

@@ -1,13 +1,14 @@
 """scale x delta(sum) / delta(count) between the marks at the window's two
 ends: the mean of a quantity the program accumulates as {n, s} (seconds per
-event -> scale 1000 gives ms). `sum` and `count` are dotted paths into the
-engine's stats() (e.g. clocks.queue_wait.s). None where the program has no
+event -> scale 1000 gives ms). `sum` and `count` are paths into the engine's
+stats(): dotted (clocks.queue_wait.s), or a list of keys where a key holds a
+dot itself (["clocks", "decode.kv_pages", "live"]). None where the program has no
 such counter (a parent commit without it) or counted nothing in the window."""
 
 
 def lookup(d, path):
-    """The value at a dotted path, or None where any part of it is absent."""
-    for part in path.split("."):
+    """The value at a path (dotted, or a list of keys), or None where any part of it is absent."""
+    for part in path if isinstance(path, (list, tuple)) else path.split("."):
         if not isinstance(d, dict) or part not in d:
             return None
         d = d[part]

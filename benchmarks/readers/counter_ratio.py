@@ -1,19 +1,15 @@
 """100 x delta(numerator) / delta(sum of denominator counters) between the
-marks at the window's two ends. Counters are dotted paths into the engine's
-stats() (e.g. kv.prefix_hits)."""
+marks at the window's two ends. Counters are paths into the engine's stats():
+dotted (kv.prefix_hits), or a list of keys where a key holds a dot itself
+(["clocks", "decode.kv_pages", "live"]). None where the program has no such
+counter (a parent commit without it) or the denominator counted nothing."""
 
-
-def _get(d, path):
-    for part in path.split("."):
-        d = d[part]
-    return d
+from .counter_mean import deltas
 
 
 def read(evidence, args):
-    marks = evidence.get("marks")
-    if not marks:
+    d = deltas(evidence, [args["numerator"]] + list(args["denominator"]))
+    if d is None:
         return None
-    a, b = marks[0]["engine"], marks[-1]["engine"]
-    num = _get(b, args["numerator"]) - _get(a, args["numerator"])
-    den = sum(_get(b, p) - _get(a, p) for p in args["denominator"])
-    return None if den <= 0 else 100.0 * num / den
+    den = sum(d[1:])
+    return None if den <= 0 else 100.0 * d[0] / den

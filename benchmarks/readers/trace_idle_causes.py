@@ -6,8 +6,9 @@ traced window, so that they add up to `serve_device_idle_pct`.
   no_work    under `llm.idle`, and under an `llm.admit` that admitted nothing
              with nothing live: no request was there. A higher rate fills it.
   in_flight  between the start of an `llm.*.dispatch` and the end of its
-             `.wait`, less the device's busy time: launch latency, gaps inside
-             the executable, the result's transfer and the host's wake-up.
+             `.wait` (a decode step's: the wait that carries its `step`), less
+             the device's busy time: launch latency, gaps inside the
+             executable, the result's transfer and the host's wake-up.
              What dispatching step N+1 before reading step N hides.
   host       under `llm.admit` with work, `llm.batch`, `llm.emit`,
              `llm.*.prep` and the rest of `llm.step`: the host working while
@@ -45,16 +46,30 @@ HOST_SPANS = ("llm.admit", "llm.batch", "llm.emit", "llm.prefill.prep", "llm.dec
 
 
 def in_flight_intervals(spans: List[Dict]) -> List[tl.Interval]:
-    """(dispatch start, end of the next `.wait` of the same executable) for
-    every `llm.<x>.dispatch`; a dispatch whose wait the trace lost ends itself."""
+    """(dispatch start, end of ITS wait) for every `llm.<x>.dispatch`: a
+    dispatch that carries a `step` (decode, PR 40) is closed by the `.wait`
+    of that step, wherever the host makes it (with two steps in flight the
+    next wait in time is the step before's); one without (a prefill: one at a
+    time) by the next `.wait` without a step. A dispatch whose wait the
+    trace lost ends itself; a step's wait whose dispatch the trace lost (the
+    trace began with that step in flight) has been in flight from the start."""
     out = []
     for kind in ("llm.prefill", "llm.decode"):
+        dispatches = [s for s in spans if s["name"] == kind + ".dispatch"]
         waits = [s for s in spans if s["name"] == kind + ".wait"]
+        of_step = {s["args"]["step"]: s for s in waits if "step" in s["args"]}
+        in_time = [s for s in waits if "step" not in s["args"]]
         j = 0
-        for d in (s for s in spans if s["name"] == kind + ".dispatch"):
-            while j < len(waits) and waits[j]["start"] < d["start"]:
-                j += 1
-            out.append((d["start"], waits[j]["end"] if j < len(waits) else d["end"]))
+        for d in dispatches:
+            if "step" in d["args"]:
+                w = of_step.get(d["args"]["step"])
+            else:
+                while j < len(in_time) and in_time[j]["start"] < d["start"]:
+                    j += 1
+                w = in_time[j] if j < len(in_time) else None
+            out.append((d["start"], d["end"] if w is None else w["end"]))
+        launched = {d["args"].get("step") for d in dispatches}
+        out += [(float("-inf"), w["end"]) for n, w in of_step.items() if n not in launched]
     return tl.union(out)
 
 

@@ -6,11 +6,13 @@ reader opens worker["trace_path"] itself; the device side (op intervals,
 skew, window) and the interval arithmetic are lib/trace.py's, by import.
 
 args.stat:
-  "median_sum_ms"         for each `within` span, the summed duration of the
-                          `spans` events that start inside it; the median, ms
-  "idle_unexplained_pct"  100 x device-idle seconds (first chip, traced
-                          window) whose midpoint lies under no llm.* span /
-                          all device-idle seconds there
+  "median_sum_ms"  for each `within` span, the summed duration of the `spans`
+                   events that start inside it; the median, ms
+
+`idle_by_innermost_span` is the run's `breakdown.idle_gaps` (run.py) and the
+builder's report (tools/record_engine_trace.py); the share of the idle under no
+span at all is `trace_idle_causes`' hole, the three causes' difference from
+the idle share, and no metric of its own since PR 45.
 
 None where the trace holds no llm.* event (a program without those spans)."""
 
@@ -21,9 +23,9 @@ from typing import Dict, List, Optional
 
 from ..lib import trace as tl
 from ..lib.stats import percentile
-from ._common import trace_of
 
 PREFIX = "llm."
+NO_SPAN = "(no llm.* span)"  # idle_by_innermost_span's name for what no program span covers
 
 
 def program_spans(path: str) -> List[Dict]:
@@ -64,12 +66,19 @@ def device_idle(tr) -> List[tl.Interval]:
 
 def idle_by_innermost_span(tr, spans: List[Dict]) -> Dict[str, float]:
     """Idle seconds summed by the SHORTEST llm.* span over each gap's midpoint
-    (the innermost one: llm.decode.wait inside llm.decode inside llm.step)."""
+    (the innermost one: llm.decode.wait inside llm.decode inside llm.step).
+    One pass over gaps and spans in order of time: `over` holds the spans that
+    have begun and not ended at the midpoint in hand."""
     totals: Dict[str, float] = {}
+    spans = sorted(spans, key=lambda s: s["start"])
+    over, j = [], 0
     for a, b in device_idle(tr):
         mid = (a + b) / 2
-        over = [s for s in spans if s["start"] <= mid < s["end"]]
-        owner = min(over, key=lambda s: s["end"] - s["start"])["name"] if over else "(no llm.* span)"
+        while j < len(spans) and spans[j]["start"] <= mid:
+            over.append(spans[j])
+            j += 1
+        over = [s for s in over if mid < s["end"]]
+        owner = min(over, key=lambda s: s["end"] - s["start"])["name"] if over else NO_SPAN
         totals[owner] = totals.get(owner, 0.0) + (b - a)
     return totals
 
@@ -86,11 +95,4 @@ def read(evidence, args):
             for o in spans if o["name"] == args["within"]
         ]
         return percentile(sums, 50) if sums else None
-    if stat == "idle_unexplained_pct":
-        tr = trace_of(evidence)
-        if tr is None:
-            return None
-        totals = idle_by_innermost_span(tr, spans)
-        idle = sum(totals.values())
-        return None if idle <= 0 else 100.0 * totals.get("(no llm.* span)", 0.0) / idle
     raise ValueError(f"unknown stat {stat!r}")

@@ -28,6 +28,22 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
+def idle_gaps(evidence, tr, k: int = 10):
+    """The first chip's idle seconds by what the host was doing: by the
+    program's innermost `llm.*` span where its loop lies under spans from start
+    to stop (`llm.admit` events, PR 40: then nearly no gap is left unnamed), by
+    the benchmark's own `bench.*` spans where it does not (a training cell, an
+    older program), whose catch-all is everything between two model calls."""
+    from benchmarks.lib.trace import UNATTRIBUTED
+    from benchmarks.readers import trace_program_spans as tps
+
+    spans = tps.spans_of(evidence) or []
+    if not any(s["name"] == "llm.admit" for s in spans):
+        return tr.idle_gaps_by_span(k)
+    totals = tps.idle_by_innermost_span(tr, spans)
+    return [[UNATTRIBUTED if n == tps.NO_SPAN else n, s] for n, s in sorted(totals.items(), key=lambda kv: -kv[1])[:k]]
+
+
 def main(argv=None, prepare=None, every_metric=False) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -78,7 +94,7 @@ def main(argv=None, prepare=None, every_metric=False) -> int:
         tr = trace_of(evidence)
         if tr is not None:
             device["busy_s"], device["window_s"] = tr.busy_s(), tr.window_s()
-            line["breakdown"] = {"device_ops": tr.top_ops(10), "idle_gaps": tr.idle_gaps_by_span(10)}
+            line["breakdown"] = {"device_ops": tr.top_ops(10), "idle_gaps": idle_gaps(evidence, tr)}
     # Each number that `correct` compared, beside its limit: the line's last key, and stderr's last lines.
     line["compared"] = {name: {"value": value, "limit": limit} for name, (value, limit) in evidence["compared"].items()}
     print("benchmark: facts " + json.dumps(facts, default=str), flush=True)

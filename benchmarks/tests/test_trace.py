@@ -60,10 +60,12 @@ def test_flash_kernels_are_found_and_told_apart(t):
     assert 0 < t.op_seconds(FLASH) < t.busy_s()
 
 
-def test_decode_spans_get_their_device_time(t):
-    per = t.span_device_seconds("bench.decode")
-    assert len(per) == 4 and all(a["live"] == 1 for a, _s in per)
-    assert all(1.5e-5 < s < 3e-5 for _a, s in per)  # module events are ~22.5 us each
+def test_decode_spans_are_one_a_step_with_the_steps_sizes(t):
+    """The contract of a `bench.decode` span (benchmarks/README.md): one executed decode step with that step's
+    `live` and `kv_tokens`; nothing says when the host waits, so no reader clips device time to it any more (PR 45).
+    The device's own line says how long a step ran: readers/trace_modules.py."""
+    decodes = [s for s in t.spans if s["name"] == "bench.decode"]
+    assert len(decodes) == 4 and all(s["args"]["live"] == 1 and s["args"]["kv_tokens"] > 0 for s in decodes)
 
 
 def test_breakdown(t):

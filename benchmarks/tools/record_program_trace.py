@@ -87,10 +87,8 @@ def main() -> int:
     print("llm events", collections.Counter(s["name"] for s in spans), flush=True)
     print("window_s", tr.window_s(), "busy_s", tr.busy_s(), "skew_s", tr.skew_s, flush=True)
     print("idle by span", tps.idle_by_innermost_span(tr, spans), flush=True)
-    for args in ({"stat": "median_sum_ms", "within": "llm.decode", "spans": ["llm.decode.prep", "llm.decode.dispatch"]},
-                 {"stat": "idle_unexplained_pct"}):
-        ev = {"worker": {"trace_path": dst}}
-        print(args["stat"], tps.read(ev, args), flush=True)
+    args = {"stat": "median_sum_ms", "within": "llm.decode", "spans": ["llm.decode.prep", "llm.decode.dispatch"]}
+    print(args["stat"], tps.read({"worker": {"trace_path": dst}}, args), flush=True)
     first = [s for s in spans if s["name"] in ("llm.step", "llm.decode", "llm.prefill")][:6]
     print("first events", [(s["name"], s["args"]) for s in first], flush=True)
     shutil.rmtree(logdir, ignore_errors=True)
