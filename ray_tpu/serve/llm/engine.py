@@ -33,6 +33,26 @@ done with nothing left to launch) goes out at once, behind whatever is
 queued: a stream sees `tok ... tok, done | error` in the order decided. A
 model that announces nothing is delivered to at the end of each step, as
 ever; which of the two a model is, the engine learns from the hook firing.
+
+Two decode steps in flight. A model whose `decode` can launch without
+waiting (it returns a result that is not read yet: model.py, PendingTokens,
+to a StepTokens that asks with `deferred`) is launched step N+1 before step
+N is read. Greedy decoding leaves a row's next input token on the device,
+so step N+1 is built from counts the host has: a row with a step of its own
+in flight runs at its next position, is marked -1 in the host's token vector
+(the model takes its token from step N's output, on the device), and is left
+out if step N reaches its `max_new`. Only the token's VALUE needs the read:
+for the sink calls and for done-ness by `eos_token`. Step N is read, decided
+(`llm.decide`) and delivered (`llm.emit`) from the hook of the launch behind
+it, step N+1's or a prefill's, while that executable runs; with nothing
+left to launch it is read at once. Never more than two steps are in flight,
+and one only behind a prefill (its first token is read on the host and goes
+into the next step's host vector). Done-ness by `eos_token` is therefore
+known one step late: such a row computes one dead step, whose token is
+dropped when it is decided (`_decide_locked`: the sequence is finished). A
+model that returns a plain list (StubModel, or any adapter that waits) has
+one step in flight and the order above, unchanged; which of the two a model
+is, the engine learns from what `decode` returned.
 """
 
 from __future__ import annotations
@@ -91,7 +111,7 @@ class _Seq:
     __slots__ = (
         "rid", "prompt", "max_new", "pages", "sink", "slot",
         "last_token", "n_out", "cancelled", "finished", "t_submit", "t_first",
-        "trace", "waiting_ahead",
+        "trace", "waiting_ahead", "ahead",
     )
 
     def __init__(self, rid: int, prompt: List[int], max_new: int, pages: SeqPages, sink: Sink):
@@ -111,12 +131,27 @@ class _Seq:
         # spans the engine thread records for this request parent to it.
         self.trace = _tracing.current_context()
         self.waiting_ahead = 0
+        # Decode steps launched with this sequence in them and not read yet: 0 or 1.
+        self.ahead = 0
 
     def write_pos(self) -> int:
-        """Cache position the NEXT decode step writes (last emitted
-        token's k/v): prompt positions [0, len) are prefilled, generated
-        token i lands at len(prompt) + i."""
-        return len(self.prompt) + self.n_out - 1
+        """Cache position the NEXT decode step to be launched writes (the
+        k/v of its input token): prompt positions [0, len) are prefilled,
+        generated token i lands at len(prompt) + i, and a step in flight
+        (`ahead`) has taken the position before."""
+        return len(self.prompt) + self.n_out - 1 + self.ahead
+
+
+class _Flight:
+    """A decode step launched and not read yet: its launch ordinal, the
+    sequences it ran (as launched: one of them may have finished since), its
+    result still on its way (PendingTokens), the pages its live lengths cover
+    and the instant of its launch."""
+
+    __slots__ = ("step", "batch", "result", "live_pages", "t0")
+
+    def __init__(self, step: int, batch: List[_Seq], result, live_pages: int, t0: float):
+        self.step, self.batch, self.result, self.live_pages, self.t0 = step, batch, result, live_pages, t0
 
 
 class InferenceEngine:
@@ -159,11 +194,16 @@ class InferenceEngine:
         # call since has come back without it. Only then is a step's
         # delivery left for the next launch.
         self._launches = False
+        # The decode step launched and not yet read (a model that defers: _Flight), and whether the step
+        # last read failed: a step launched behind a failed one is dropped unread.
+        self._flight: Optional[_Flight] = None
+        self._flight_failed = False
         # Set once by _fail(): the model lost state it cannot rebuild.
         self.failed: Optional[EngineFailedError] = None
         self.shed_total = 0
         self.tokens_emitted = 0
-        self.decode_steps = 0
+        self.decode_steps = 0  # completed (read and decided)
+        self.decode_launches = 0  # launched: a step's ordinal on its spans is its launch's
         self._tok_window = 0
         self._t_window = time.monotonic()
         # Stage clocks (stats()["clocks"]): seconds of time.monotonic(),
@@ -178,7 +218,7 @@ class InferenceEngine:
             "admit": {"s": 0.0},
             "prefill": {"n": 0, "s": 0.0, "tokens": 0, "computed_tokens": 0},
             "batch": {"s": 0.0},
-            "decode": {"n": 0, "s": 0.0},
+            "decode": {"n": 0, "s": 0.0, "chained": 0},
             "emit": {"s": 0.0},
             "deliver": {"n": 0, "under_step": 0},
             "decode.kv_pages": {"live": 0, "table": 0},
@@ -411,15 +451,62 @@ class InferenceEngine:
     def _launched(self) -> None:
         """The model's hook (StepTokens / PromptTokens `launched`): the
         executable this call runs has been dispatched and the thread is about
-        to block for its result. The loop thread, inside model.prefill /
-        model.decode, no lock of the engine held. The deliveries' seconds go
-        to the `emit` clock, not to the stage they interrupt."""
+        to block for its result, or to return it unread. The loop thread,
+        inside model.prefill / model.decode, no lock of the engine or of the
+        model held. A decode step still in flight is read, decided and
+        delivered here, under the executable just launched; else what the
+        step before left queued is delivered. The deliveries' seconds go to
+        the `emit` clock, not to the stage they interrupt."""
         self._launches = True
-        if self._pending:
+        if self._flight is not None:
+            self._land(under_step=True)
+        elif self._pending:
             stage = self._stage[0]
             self._enter("emit")
             self._emit(under_step=True)
             self._enter(stage)
+
+    def _land(self, under_step: bool) -> None:
+        """Reads the decode step in flight (the wait lies in the model's
+        `llm.decode.wait`, with the `step` of the step read; its seconds go to
+        the `decode` clock), decides it and makes its sink calls (the `emit`
+        clock), then goes back to the stage it interrupted. `under_step`: a
+        newer executable runs meanwhile. A read that fails fails the step's
+        batch fast, as a step that raised does, and marks the step launched
+        behind it to be dropped; a pool lost there stops the engine."""
+        flight, self._flight = self._flight, None
+        stage = self._stage[0]
+        self._enter("decode")
+        tokens, err = None, None
+        try:
+            tokens = flight.result.resolve()
+        except EngineFailedError as e:
+            self._enter(stage)
+            self._fail(e)
+            return
+        except Exception as e:  # noqa: BLE001 - batch fail-fast, loop survives
+            err = e
+        step_ms = (self._enter("emit") - flight.t0) * 1000.0
+        self._flight_failed = err is not None
+        self._decide(flight.batch, tokens, err, flight.live_pages, step_ms)
+        self._emit(under_step)
+        self._enter(stage)
+
+    def _decide(self, batch: List[_Seq], tokens, err: Optional[BaseException], live_pages: int, step_ms: float) -> None:
+        """`llm.decide`, under the lock: a decode step that came back is
+        decided (`_decide_locked`), one that raised fails every sequence that
+        was in it, never wedging: pages free, slots recycle, the engine keeps
+        serving whatever arrives next."""
+        with _tracing.span("llm.decide", {"tokens": len(batch)}, device=True), self._cond:
+            for seq in batch:
+                seq.ahead = 0  # read: before a token is counted, so that `write_pos` moves by one
+            if err is None:
+                self._decide_locked(batch, tokens, live_pages, step_ms)
+                return
+            logger.warning("decode step failed on %s: %r", self.name, err)
+            for seq in batch:
+                if not seq.finished:
+                    self._finish_locked(seq, "error", _typed(err))
 
     def _returned(self) -> None:
         """A model call came back. With deliveries still queued its hook did
@@ -440,6 +527,7 @@ class InferenceEngine:
         Carrying on would answer each of them from a deleted buffer while
         the replica looked alive."""
         logger.error("engine %s failed and stopped: %s", self.name, err)
+        self._flight = None  # what was launched is never read
         with self._cond:
             self.failed = err
             self._stop = True
@@ -472,7 +560,9 @@ class InferenceEngine:
         one of three spans, and in one stage of the clocks: `llm.admit` (the
         locked section that reaps cancels and fills free slots; the lock is
         the one submit() and cancel() take), then `llm.idle` (nothing to
-        run: one wait for a submit, a cancel or the stop) or `llm.step`."""
+        run: one wait for a submit, a cancel or the stop) or `llm.step`
+        (something live, or a step in flight whose rows were all cancelled:
+        it is read there, with nothing to launch)."""
         while True:
             with _tracing.span("llm.admit", device=True) as sp:
                 self._enter("admit")  # inside the span: what lies between two spans is a hole in the record
@@ -490,7 +580,7 @@ class InferenceEngine:
                 _tracing.add_attrs(sp, waiting=waiting, admitted=len(admitted), live=live)
             if stop:
                 return
-            if not live:
+            if not live and self._flight is None:
                 # Nothing waits (it would have been admitted) and nothing
                 # runs. The test is made again under the lock the wait
                 # gives up: a submit between the two sections is not slept on.
@@ -510,10 +600,11 @@ class InferenceEngine:
                     return
 
     def _step(self, admitted: List[_Seq]) -> bool:
-        """Prefills `admitted`, runs one decode step over every live slot
-        and emits its tokens. False: the engine failed and the loop ends.
+        """Prefills `admitted`, launches one decode step over every live slot
+        and settles the step before it, or this one if the model waited for
+        it. False: the engine failed and the loop ends.
 
-        Emitting has two halves. Deciding (`llm.decide`, under the lock, the
+        Settling has two halves. Deciding (`llm.decide`, under the lock, the
         moment the result is there): each token's count, its sequence's
         done-ness, the slot and pages of a finished one given back, so the
         next `llm.admit` finds them free. Delivering (`llm.emit`, outside the
@@ -522,11 +613,12 @@ class InferenceEngine:
         announces its launches the step's deliveries wait for the next
         executable's launch and are made from its hook, under `llm.prefill` or
         `llm.decode` (`_launched`); a prefill's first token, a failed step's
-        errors and whatever finds nothing left to launch go out at once."""
+        errors and whatever finds nothing left to launch go out at once. For
+        one that also returns its result unread, the step is read there too:
+        wait, decide and deliver all lie under the launch behind it (`_land`)."""
         T = self.config.page_tokens
         # Prefill outside the lock (jit-compiled, prompt-sized work):
         # submit/cancel stay responsive while prompts burn in.
-        prefilled = []
         for seq in admitted:
             tok, err = None, None
             clk = self._clk["prefill"]
@@ -558,14 +650,21 @@ class InferenceEngine:
                 return False
             except Exception as e:  # noqa: BLE001 - fail one request, not the loop
                 err = e
-            prefilled.append((seq, tok, err))
+            if self.failed is not None:
+                return False  # the step read under this prefill had lost the pool
+            # Its first token is decided and goes out now, behind whatever a prefill that raised before its
+            # launch left queued: not behind the other prefills of this iteration (streams that arrive
+            # together are admitted together, and each would wait for the last one's prompt).
+            self._enter("batch")
+            with self._cond:
+                self._finalize_admission_locked(seq, tok, err)
+            self._deliver()
 
         self._enter("batch")
         with _tracing.span("llm.batch", device=True) as sp:
             with self._cond:
-                for seq, tok, err in prefilled:
-                    self._finalize_admission_locked(seq, tok, err)
-                batch = [s for s in self._slots if s is not None]
+                # Every live sequence but one that the step in flight takes to its `max_new`: its last token is on its way.
+                batch = [s for s in self._slots if s is not None and s.n_out + s.ahead < s.max_new]
                 # Grow block tables for sequences crossing a page
                 # boundary this step; pool exhaustion here fail-fasts the
                 # one sequence (its pages recycle for the rest).
@@ -577,28 +676,32 @@ class InferenceEngine:
                             batch.remove(seq)
                             self._finish_locked(seq, "error", e)
                 _tracing.add_attrs(sp, live=len(batch))
-                step = self.decode_steps + 1  # the ordinal it gets when it completes
-                tokens = StepTokens([0] * len(self._slots), step, self._launched)
+                step = self.decode_launches + 1  # the launch's ordinal: two in flight do not share one
+                tokens = StepTokens([0] * len(self._slots), step, self._launched, deferred=True)
                 positions = [-1] * len(self._slots)
                 tables: List[List[int]] = [[] for _ in self._slots]
                 kv_tokens = live_pages = 0
                 page_tokens = self.config.page_tokens
                 for seq in batch:
-                    tokens[seq.slot] = seq.last_token
+                    # A row of the step in flight: its token is that step's output, which only the device has yet.
+                    tokens[seq.slot] = -1 if seq.ahead else seq.last_token
                     positions[seq.slot] = seq.write_pos()
                     tables[seq.slot] = seq.pages.pages
                     kv_tokens += positions[seq.slot] + 1
                     live_pages += -(-(positions[seq.slot] + 1) // page_tokens)
-            # A first token goes out at once, behind whatever a prefill that
-            # raised before its launch left queued; the last step's tokens
-            # with no prefill since ride under the decode launched below.
-            self._deliver_unless(launching=bool(batch) and not prefilled)
+            # The last step's tokens, with no prefill since (its hook would have
+            # delivered them), ride under the decode launched below.
+            self._deliver_unless(launching=bool(batch))
         if not batch:
-            return True
+            if self._flight is not None:
+                self._land(under_step=False)  # nothing to launch behind it: read now
+            return self.failed is None
 
         # Model step runs OUTSIDE the lock: submit/cancel stay
         # responsive for the full decode latency.
         t0 = self._enter("decode")
+        chained = self._flight is not None
+        self._flight_failed = False
         try:
             rule = _chaos_inject("serve.decode", self.name)
             if rule is not None:
@@ -619,27 +722,32 @@ class InferenceEngine:
             with _tracing.span("llm.decode", attrs, device=True):
                 next_tokens = self.model.decode(tokens, positions, tables)
             step_err: Optional[BaseException] = None
+            self.decode_launches += 1
+            self._clk["decode"]["chained"] += int(chained)
         except EngineFailedError as e:
             self._fail(e)
             return False
         except Exception as e:  # noqa: BLE001 - batch fail-fast, loop survives
             next_tokens, step_err = None, e
-        finally:
-            step_ms = (self._enter("emit") - t0) * 1000.0
+        if self._flight is not None and self.failed is None:
+            # No hook read it: the launch raised before it, or the model defers and announces nothing.
+            self._land(under_step=step_err is None)
+        if self.failed is not None:
+            return False  # the step read under this launch had lost the pool
+        if step_err is None and hasattr(next_tokens, "resolve"):
+            # Launched and not waited for: read from the hook of the next launch. Behind a step
+            # whose read failed it ran on that step's pool and tokens: dropped unread.
+            if not self._flight_failed:
+                self._flight = _Flight(step, batch, next_tokens, live_pages, t0)
+                for seq in batch:
+                    seq.ahead = 1
+            self._returned()
+            return True
 
+        step_ms = (self._enter("emit") - t0) * 1000.0
         if step_err is None:
             self._returned()
-        with _tracing.span("llm.decide", {"tokens": len(batch)}, device=True), self._cond:
-            if step_err is not None:
-                # Fail-fast every sequence that was in the failed
-                # step — never wedge: pages free, slots recycle, the
-                # engine keeps serving whatever arrives next.
-                logger.warning("decode step failed on %s: %r", self.name, step_err)
-                for seq in batch:
-                    if not seq.finished:
-                        self._finish_locked(seq, "error", _typed(step_err))
-            else:
-                self._decide_locked(batch, next_tokens, live_pages, step_ms)
+        self._decide(batch, next_tokens, step_err, live_pages, step_ms)
         # A failed step's errors at once, behind what its missing launch left
         # queued. A step's tokens at once to a model that announces nothing;
         # else they wait for the next launch (`_launched`), or for the
@@ -707,11 +815,18 @@ class InferenceEngine:
         own TTFT); the loop's stages, each from its start to the next one's:
         admit (the locked section at the top of an iteration: cancels
         reaped, free slots filled), prefill / decode (inside model.prefill /
-        model.decode), batch (the locked section that finalizes admissions,
-        grows block tables and builds the step's inputs), emit (a completed
+        model.decode), batch (the locked sections that finalize an admission
+        behind its prefill and send its first token, and the one that grows
+        block tables and builds the step's inputs), emit (a completed
         step decided under the lock, and the sink calls wherever they are
         made: those made from a launch's hook are taken out of the prefill /
-        decode they interrupt); deliver: the sink calls made (n) and those of
+        decode they interrupt; a step read from such a hook has its wait
+        booked to decode and its decide and sink calls to emit); decode.n:
+        the decode steps completed (read and decided), decode.chained: the
+        decode steps dispatched while the decode step before them was still
+        unread (two in flight: a model that defers; 0 for one that waits;
+        a step behind a prefill is not, the prefill's hook read the step
+        before it); deliver: the sink calls made (n) and those of
         them made from a launch's hook, under a step in flight (under_step);
         prefill.tokens: prompt tokens of those calls,
         cached ones included; prefill.computed_tokens: positions their

@@ -19,11 +19,18 @@ been dispatched and before it blocks for the result. The engine uses that
 instant to make the sink calls of the step before, so the streams they wake
 run while the chip works (engine.py, `_launched`). A model that ignores it
 loses nothing: the engine sees that no launch was announced and delivers
-every step at the end of that step, as it always did.
+every step at the end of that step, as it always did. And
+`StepTokens.deferred`: the engine takes a decode step's result unread
+(`PendingTokens`, which resolves on its first read) from a model that can
+launch without waiting, and then launches the next step before it reads this
+one, a continuing row's token taken on the device from this step's output
+(-1 in the host's vector). A model that returns its list is served one step
+deep, as ever.
 
 PagedLM is the real path: one jitted decode step at static shapes
 ([max_slots] tokens, [max_slots, max_pages_per_seq] block tables, the
-whole page pool) serves every batch composition; prefill compiles per
+whole page pool, the step before's output vector) serves every batch
+composition and every caller, deferring or not; prefill compiles per
 power-of-two page bucket, so compile count is O(log max_seq), not
 O(distinct prompt lengths). A bucket's one executable serves hit and miss
 alike: it walks what the cache lacks in chunks that start at `cached_tokens`
@@ -84,12 +91,55 @@ class StepTokens(list):
     returns quickly and does not raise. A model that calls it must call it
     in every `decode` and `prefill` that launches; a call that comes back
     without it is taken as the end of that. A model that never calls it is
-    served exactly as one without the attribute."""
+    served exactly as one without the attribute.
 
-    def __init__(self, tokens, step: int, launched=None):
+    `deferred`: the caller takes a result that is not read yet (PendingTokens)
+    and reads it when it needs the tokens' values. Such a caller may put -1
+    in the row of a sequence that continues from the decode step launched
+    before this one: the row's token is then taken on the device, from that
+    step's output. A model that cannot launch without waiting ignores the
+    field, returns its list, and is never handed a -1: the caller marks a row
+    only behind a step whose result came back pending."""
+
+    def __init__(self, tokens, step: int, launched=None, deferred: bool = False):
         super().__init__(tokens)
         self.step = step
         self.launched = launched
+        self.deferred = deferred
+
+
+class PendingTokens:
+    """What `PagedLM.decode` returns to a caller that asked for it
+    (`StepTokens.deferred`): a launched step's result, still on its way. It
+    is read once, at the first look at it (`resolve()`, or any read as a
+    list: a length, an index, an iteration, `counters`), which waits for the
+    step's execution to end; from then on it is the list (or DecodeTokens)
+    that a caller who did not ask is returned at once."""
+
+    def __init__(self, read):
+        self._read, self._tokens = read, None
+
+    def resolve(self) -> List[int]:
+        if self._read is not None:
+            self._tokens = self._read()  # a read that raises is made again by the next look
+            self._read = None
+        return self._tokens
+
+    @property
+    def counters(self) -> Dict[str, Dict[str, int]]:
+        return getattr(self.resolve(), "counters", {})
+
+    def __len__(self):
+        return len(self.resolve())
+
+    def __iter__(self):
+        return iter(self.resolve())
+
+    def __getitem__(self, i):
+        return self.resolve()[i]
+
+    def __repr__(self):
+        return "PendingTokens(<in flight>)" if self._read is not None else f"PendingTokens({self._tokens!r})"
 
 
 class PromptTokens(list):
@@ -212,8 +262,17 @@ class PagedLM:
         self.state_bytes = sum(self.kv[name].nbytes for name in slotted) // (max_slots + 1)
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
-        # One lock around every jitted call: the engine loop is the only
-        # steady-state caller, but tests poke prefill directly.
+        # The last decode step's result vector, on the device: the next step's
+        # operand, from which a row marked -1 takes its token (StepTokens
+        # `deferred`). Until a step has run, zeros of that vector's shape (the
+        # slots' tokens, and behind them a routed model's counters:
+        # forward_decode's `stats`, one, or two under a share of the experts),
+        # so that every call, the first and a bare list's too, runs one executable.
+        n_counts = (2 if cfg.experts_held != cfg.n_experts else 1) if cfg.n_experts else 0
+        self._prev = jnp.zeros((max_slots + n_counts,), jnp.int32)
+        # One lock around every launch (a jitted call and the pool it leaves
+        # installed): the engine loop is the only steady-state caller, but
+        # tests poke prefill directly. No result is waited for under it.
         self._mu = threading.Lock()
 
     @property
@@ -279,7 +338,9 @@ class PagedLM:
         if self._decode_jit is None:
             cfg, tfm = self.cfg, self._tfm
 
-            def step(params, tokens, positions, kv, block_tables):
+            def step(params, tokens, positions, kv, block_tables, prev):
+                # A row that continues from the step before (-1 from the host) takes the token that step left here.
+                tokens = self._jnp.where(tokens >= 0, tokens, prev[: tokens.shape[0]])
                 logits, kv, *stats = tfm.forward_decode(
                     params, tokens, positions, cfg, kv, block_tables, stats=bool(cfg.n_experts)
                 )
@@ -318,21 +379,14 @@ class PagedLM:
 
     # --------------------------------------------------------------- steps
 
-    def _run_step(self, call, span: str, attrs=None, launched=None):
-        """Runs one jitted step `call(kv) -> (tokens, new_kv)`, waits for
-        its tokens on the host and installs the new pool. `<span>.dispatch`
-        is the jitted call returning, `<span>.wait` the transfer of its
-        tokens (device annotations, so an idle gap of the chip can be put
-        down to one of them or to the caller's `.prep`); between the two the
-        caller's `launched`, if it gave one, is told that the chip has work
-        and this thread is about to block (StepTokens). The wait is
-        inside the try because dispatch is asynchronous: a device-side
-        failure surfaces at the transfer, not at the call. If the step
-        raised after the pool was donated into it, the pool buffer is
-        deleted and nothing can be served any more — that is an
-        EngineFailedError, not a per-request error."""
-        import numpy as np
-
+    def _launch(self, call, span: str, attrs=None, chain: bool = False):
+        """Dispatches one jitted step `call(kv) -> (out, new_kv)` and installs
+        the new pool, which the next launch takes whether or not this one's
+        result has been read: the device runs one stream in launch order.
+        `<span>.dispatch` is the jitted call returning. The copy of `out` to
+        the host is asked for here, so that it travels when this execution
+        ends and is not queued behind a newer one's. Returns (out, the pool
+        given in), for `_read`. `chain`: `out` is the next decode step's `prev`."""
         with self._mu:
             kv = self.kv
             if kv is None:
@@ -340,20 +394,51 @@ class PagedLM:
             try:
                 with _tracing.span(span + ".dispatch", attrs, device=True):
                     out, new_kv = call(kv)
-                if launched is not None:
-                    launched()
-                with _tracing.span(span + ".wait", attrs, device=True):
-                    host = np.asarray(out)
             except Exception as e:
-                if any(leaf.is_deleted() for leaf in self._jax.tree_util.tree_leaves(kv)):
-                    self.kv = None
-                    raise EngineFailedError(
-                        "jitted step failed after the KV page pool was donated "
-                        f"to it; the pool is gone ({type(e).__name__}: {e})"
-                    ) from e
+                self._pool_lost(kv, e)
                 raise
             self.kv = new_kv
-            return host
+            if chain:
+                self._prev = out
+        out.copy_to_host_async()
+        return out, kv
+
+    def _read(self, out, kv, span: str, attrs=None):
+        """Waits for a launched step's tokens on the host: `<span>.wait`, the
+        transfer of `out` (device annotations both, so an idle gap of the chip
+        can be put down to one of them or to the caller's `.prep`). Dispatch
+        is asynchronous: a device-side failure surfaces here, not at the
+        call. Takes no lock: a step may be read from the hook of a newer
+        launch (engine.py)."""
+        import numpy as np
+
+        try:
+            with _tracing.span(span + ".wait", attrs, device=True):
+                return np.asarray(out)
+        except Exception as e:
+            self._pool_lost(kv, e)
+            raise
+
+    def _pool_lost(self, kv, e: BaseException) -> None:
+        """If a step raised after the pool `kv` was donated into it, the pool
+        buffer is deleted and nothing can be served any more: that is an
+        EngineFailedError, not a per-request error."""
+        if any(leaf.is_deleted() for leaf in self._jax.tree_util.tree_leaves(kv)):
+            self.kv = None
+            raise EngineFailedError(
+                "jitted step failed after the KV page pool was donated "
+                f"to it; the pool is gone ({type(e).__name__}: {e})"
+            ) from e
+
+    def _run_step(self, call, span: str, attrs=None, launched=None):
+        """Runs one jitted step `call(kv) -> (tokens, new_kv)` and waits for
+        its tokens on the host. Between the launch and the wait the caller's
+        `launched`, if it gave one, is told that the chip has work and this
+        thread is about to block (StepTokens)."""
+        out, kv = self._launch(call, span, attrs)
+        if launched is not None:
+            launched()
+        return self._read(out, kv, span, attrs)
 
     def prefill(self, prompt: Sequence[int], pages: Sequence[int], cached_tokens: int) -> PrefillToken:
         """The prompt's first generated token. Positions below
@@ -398,6 +483,14 @@ class PagedLM:
         return PrefillToken(tok, attrs["computed_tokens"], counters)
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:
+        """The slots' next tokens. One executable whatever the caller: it
+        takes the host's tokens and, in a row the host marks -1, the token the
+        decode step before left on the device (`self._prev`). A caller's bare
+        list, and a StepTokens that does not ask, is served its list: dispatch,
+        the `launched` hook, the wait. A StepTokens that asks (`deferred`) is
+        returned a PendingTokens behind the hook, and the wait
+        (`llm.decode.wait`, carrying the `step` of the step READ) is made
+        where the caller first reads it."""
         import numpy as np
 
         B, P = self.max_slots, self.max_pages_per_seq
@@ -412,9 +505,19 @@ class PagedLM:
             fn = self._get_decode()
         step = getattr(last_tokens, "step", None)  # the engine's StepTokens; a bare list from anyone else
         attrs = None if step is None else {"step": step}
-        out = self._run_step(
-            lambda kv: fn(self.params, toks, pos, kv, bts), "llm.decode", attrs, getattr(last_tokens, "launched", None)
-        )
+        out, kv = self._launch(lambda kv: fn(self.params, toks, pos, kv, bts, self._prev), "llm.decode", attrs, chain=True)
+        result = PendingTokens(lambda: self._step_tokens(self._read(out, kv, "llm.decode", attrs), pos))
+        launched = getattr(last_tokens, "launched", None)
+        if launched is not None:
+            launched()
+        return result if getattr(last_tokens, "deferred", False) else result.resolve()
+
+    def _step_tokens(self, out, pos) -> List[int]:
+        """A decode step's result vector as the protocol's list, with what the
+        step's router, windows or states did (`pos`: the positions it ran at)."""
+        import numpy as np
+
+        B = self.max_slots
         tokens, cfg, counters = [int(t) for t in out[:B]], self.cfg, {}
         if cfg.n_experts:
             # Every row of the step is routed, the inactive slots' too.

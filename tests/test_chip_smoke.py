@@ -147,7 +147,7 @@ def test_lost_kv_pool_stops_the_engine_with_a_typed_error():
 
         real = model._get_decode()
 
-        def donated_then_failed(params, toks, pos, kv, bts):
+        def donated_then_failed(params, toks, pos, kv, bts, prev):
             kv["k"].delete()  # what donation does to the argument buffer
             raise RuntimeError("RESOURCE_EXHAUSTED: injected")
 

@@ -202,7 +202,7 @@ def test_paged_executables_carry_their_names_into_the_module(one_chip, step, pag
     params, kv, scalar = shaped(lm.params), shaped(lm.kv), sds((), jnp.int32)
     if step == "decode":
         name = "jit_llm_decode"
-        lowered = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 8), jnp.int32))
+        lowered = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 8), jnp.int32), sds(lm._prev.shape, jnp.int32))
     else:
         name = f"jit_llm_prefill_p{pages}"
         lowered = lm._get_prefill(pages).lower(params, sds((1, pages * 4), jnp.int32), kv, sds((pages,), jnp.int32), scalar, scalar)
@@ -274,7 +274,7 @@ def test_a_state_models_executables_carry_names_of_their_own(one_chip):
     sds = _sds(one_chip)
     shaped = lambda tree: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
     params, kv, scalar = shaped(lm.params), shaped(lm.kv), sds((), jnp.int32)
-    decode = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 1), jnp.int32))
+    decode = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 1), jnp.int32), sds(lm._prev.shape, jnp.int32))
     prefill = lm._get_prefill(1).lower(params, sds((1, 32), jnp.int32), kv, sds((1,), jnp.int32), scalar, scalar)
     assert "module @jit_llm_decode_state " in decode.as_text() and "module @jit_llm_prefill_state_p1 " in prefill.as_text()
     assert "HloModule jit_llm_decode_state," in decode.compile().as_text()
@@ -351,7 +351,7 @@ def test_a_kda_stacks_executables_carry_names_of_their_own(one_chip):
     sds = _sds(one_chip)
     shaped = lambda tree: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
     params, kv, scalar = shaped(lm.params), shaped(lm.kv), sds((), jnp.int32)
-    decode = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 4), jnp.int32))
+    decode = lm._get_decode().lower(params, sds((2,), jnp.int32), sds((2,), jnp.int32), kv, sds((2, 4), jnp.int32), sds(lm._prev.shape, jnp.int32))
     prefill = lm._get_prefill(2).lower(params, sds((1, 16), jnp.int32), kv, sds((2,), jnp.int32), scalar, scalar, scalar)
     assert "module @jit_llm_decode_hybrid " in decode.as_text() and "module @jit_llm_prefill_hybrid_p2 " in prefill.as_text()
     assert "HloModule jit_llm_decode_hybrid," in decode.compile().as_text()

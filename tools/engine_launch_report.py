@@ -16,7 +16,14 @@ every decode execution after its dispatch, as
 benchmarks/tools/record_engine_trace.py does, so the fastest launch reads 0),
 `emit_under_ms` (the `llm.emit` spans that lie between the dispatch and the
 end of the wait: deliveries made under the step in flight, PR 43; 0 on a
-program that delivers in front of the dispatch), p50 and p90, and `n`.
+program that delivers in front of the dispatch), `result_ms` (the end of the
+wait less the execution's end: the result's way back, and how late the host
+came for it), p50 and p90, and `n`.
+A decode step's dispatch and wait are the ones that carry its `step`, wherever
+they lie: since PR 46 the engine reads a step from the hook of the launch
+behind it, so the `llm.decode.wait` INSIDE a step's `llm.decode` is the step
+before's, and a step's limits for the join are its flight
+(`trace_modules.step_flights`), not its span.
 `emit` counts the `llm.emit` spans and their seconds, all and those marked
 `under_step`, and lists the first dozen made at once with the engine spans
 around them; `spans` gives every `llm.*` span's count, p50, p90 and sum.
@@ -51,7 +58,16 @@ def report(path: str) -> dict:
     if os.path.isdir(path):
         (path,) = glob.glob(os.path.join(path, "plugins", "profile", "*", "*.xplane.pb"))
     ev = {"worker": {"trace_path": path}}
-    spans, mods = tps.spans_of(ev) or [], tm.executions_of(ev)
+    # The device's clock against the host's, as the readers settle it: lib/trace.py's skew, then a decode
+    # execution belongs to the step of its ordinal and a prefill's to the span it starts in (readers/trace_modules.join).
+    tr = tm.trace_of(ev)
+    return summarize(tps.spans_of(ev) or [], tm.executions_of(ev), tr.skew_s if tr is not None else 0.0)
+
+
+def summarize(spans, mods, skew: float = 0.0) -> dict:
+    """The report from the program's `llm.*` spans (sorted by start) and the device's executions."""
+    from benchmarks.readers import trace_modules as tm
+
     named = collections.defaultdict(list)
     for s in spans:
         named[s["name"]].append(s)
@@ -62,16 +78,16 @@ def report(path: str) -> dict:
         v, k = named[name], starts.get(name, [])
         return v[bisect.bisect_left(k, a): bisect.bisect_left(k, b)]
 
-    # The device's clock against the host's, as the readers settle it: lib/trace.py's skew, then an execution
-    # belongs to the span it starts in (readers/trace_modules.join).
-    tr = tm.trace_of(ev)
-    skew = tr.skew_s if tr is not None else 0.0
-
     def launches(span_name, module_prefix, keep=lambda s: True):
         """(engine span, its dispatch, its wait, its execution) for every `span_name` that has one of each."""
         out = []
-        for s, m in tm.join([s for s in named[span_name] if keep(s)], [m for m in mods if m["name"].startswith(module_prefix)], skew):
-            d, w = inside(span_name + ".dispatch", s["start"], s["end"]), inside(span_name + ".wait", s["start"], s["end"])
+        parts = {part: {s["args"]["step"]: s for s in named[f"{span_name}.{part}"] if "step" in s["args"]} for part in ("dispatch", "wait")}
+        kept, executions = [s for s in named[span_name] if keep(s)], [m for m in mods if m["name"].startswith(module_prefix)]
+        for s, m in tm.join(kept, executions, skew, tm.step_flights(spans, span_name)):
+            if "step" in s["args"]:  # by the ordinal: the wait may lie under a later launch
+                d, w = ([parts[part][s["args"]["step"]]] if s["args"]["step"] in parts[part] else [] for part in ("dispatch", "wait"))
+            else:
+                d, w = inside(span_name + ".dispatch", s["start"], s["end"]), inside(span_name + ".wait", s["start"], s["end"])
             if len(d) == 1 and len(w) == 1:
                 out.append((s, d[0], w[0], m))
         return out
@@ -87,6 +103,7 @@ def report(path: str) -> dict:
         r["dispatch_ms"].append((d["end"] - d["start"]) * 1e3)
         r["launch_ms"].append((m["start"] + shift - d["start"]) * 1e3)
         r["emit_under_ms"].append(sum(e["end"] - e["start"] for e in inside("llm.emit", d["end"], w["end"])) * 1e3)
+        r["result_ms"].append((w["end"] - m["end"] - shift) * 1e3)
 
     out = {"device_clock_shift_s": shift}
     rows = collections.defaultdict(lambda: collections.defaultdict(list))
