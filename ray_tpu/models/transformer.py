@@ -508,11 +508,21 @@ def _layer_groups(params: PyTree, cfg: TransformerConfig):
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
-# Rows a group of XLA's grouped matmul pads to on the chip (`ragged_dot_tiling`
-# 512 x 512 x 512): a step of fewer rows does less work multiplying every
-# expert by every row (rows x E) than the grouped product's padding does
-# (a tile of 512 a touched expert); from 512 rows on the grouped one wins.
-GROUPED_TILE_ROWS = 512
+# The serving steps' cut between the routed FFN's two forms, in rows of the
+# call. The every-expert product does `rows` FLOPs a weight byte: under the
+# chip's ridge (v5e: 197 TFLOP/s / 819 GB/s = 240) it costs the experts' bytes
+# whatever the router chose, above it the FLOPs of products the router weighs
+# with 0. The grouped product (ops/grouped_matmul.py) costs the touched
+# experts' bytes and a sort of the rows. `lax.ragged_dot`, the training path's,
+# serves no such call: on the chip it pads every group to 512 rows
+# (`ragged_dot_tiling`). Measured on the chip at 64 / 128 / 256 rows, both
+# served shapes (PERF.md §6, PR 47).
+GROUPED_FROM_ROWS = 128
+
+
+def experts_grouped_at(rows: int) -> bool:
+    """Whether a serving call of that many rows takes the grouped product."""
+    return rows >= GROUPED_FROM_ROWS
 
 
 def _layer_of(stack, index):
@@ -844,8 +854,9 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
     `experts` (the serving steps: `_experts_in_place`) is (the group's stack
     {name: [layers, E, ., .]}, this layer's index in it) in place of the
     layer's own matrices in `mp`; such a step multiplies every expert by
-    every row (`_every_expert_ffn`) while it has fewer rows than one group
-    of the grouped product pads to (GROUPED_TILE_ROWS). Returns out
+    every row (`_every_expert_ffn`) while its rows are few
+    (`experts_grouped_at`), and from there on each expert by its own rows
+    where the stack lies (ops/grouped_matmul.py). Returns out
     [b, s, d], and with `counts` the rows each of the router's E experts was
     chosen by [E] beside it (under a share: held or not)."""
     from ..ops.moe_rows import combine_rows, dispatch_rows
@@ -863,16 +874,14 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
             top_p = top_p / (total + 1e-20 if cfg.router_score == "sigmoid" else total)
         if cfg.route_scale != 1.0:
             top_p = top_p * cfg.route_scale
-    if experts is not None:
+    if experts is not None and not experts_grouped_at(n):
         stack, index = experts
         layer = {name: _layer_of(stack[name], index) for name in EXPERT_WEIGHTS}
-        if n < GROUPED_TILE_ROWS:
-            out = _every_expert_ffn(x, layer, top_e, top_p, cfg).reshape(b, s, d)
-            if "shared" in mp:
-                with jax.named_scope("moe.shared"):
-                    out = out + _ffn(h, mp["shared"], cfg)
-            return (out, _tokens_per_expert(top_e, E)) if counts else out
-        mp = dict(mp, **layer)
+        out = _every_expert_ffn(x, layer, top_e, top_p, cfg).reshape(b, s, d)
+        if "shared" in mp:
+            with jax.named_scope("moe.shared"):
+                out = out + _ffn(h, mp["shared"], cfg)
+        return (out, _tokens_per_expert(top_e, E)) if counts else out
     held, rows_per_expert = cfg.experts_held, None
     with jax.named_scope("moe.dispatch"):
         flat_e = top_e.reshape(n * k)
@@ -890,14 +899,17 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
         group_sizes = _ckpt(_tokens_per_expert(flat_e, held), "moe_route")
         xs = _ckpt(dispatch_rows(x, order, inverse, k), "moe_xs_bf16")
     with jax.named_scope("moe.experts"):
-        # Results in the parameters' type (the kernel accumulates in float32):
-        # nothing fuses a convert into a grouped matmul, so float32 results
-        # would double the bytes of every [n * k, .] tensor here and make
-        # the backward products read float32 cotangents.
-        gate = lax.ragged_dot(xs, mp["w_gate"], group_sizes, preferred_element_type=cfg.dtype)
-        up = lax.ragged_dot(xs, mp["w_up"], group_sizes, preferred_element_type=cfg.dtype)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
-        ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
+        if experts is not None:
+            ys = _grouped_experts(xs, *experts, group_sizes)
+        else:
+            # Results in the parameters' type (the kernel accumulates in float32):
+            # nothing fuses a convert into a grouped matmul, so float32 results
+            # would double the bytes of every [n * k, .] tensor here and make
+            # the backward products read float32 cotangents.
+            gate = lax.ragged_dot(xs, mp["w_gate"], group_sizes, preferred_element_type=cfg.dtype)
+            up = lax.ragged_dot(xs, mp["w_up"], group_sizes, preferred_element_type=cfg.dtype)
+            act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
+            ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
         if held != E:  # rows behind the last group: whatever the product left there is not a number to weigh
             ys = jnp.where((jnp.arange(n * k) < jnp.sum(group_sizes))[:, None], ys, 0)
     with jax.named_scope("moe.combine"):
@@ -906,6 +918,22 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
         with jax.named_scope("moe.shared"):
             out = out + _ffn(h, mp["shared"], cfg)
     return (out, group_sizes if rows_per_expert is None else rows_per_expert) if counts else out
+
+
+def _grouped_experts(xs, stack, index, group_sizes):
+    """The experts' SwiGLU of rows sorted by expert, xs [m, d] -> [m, d], each
+    expert's matrices read where they lie in the group's stack {name:
+    [layers, E, ., .]} at the layer `index` (as `_layer_of` takes it): the
+    leading axes of a stack of periods are merged, which moves nothing."""
+    from ..ops import grouped_matmul as gm
+
+    if isinstance(index, tuple):
+        lead = stack["w_gate"].shape[: len(index)]
+        index = jnp.ravel_multi_index(index, lead, mode="clip")
+        stack = {name: w.reshape(-1, *w.shape[len(lead) :]) for name, w in stack.items()}
+    plan = gm.visits(group_sizes, xs.shape[0])
+    act = gm.grouped_swiglu(xs, stack["w_gate"], stack["w_up"], index, plan)
+    return gm.grouped_matmul(act, stack["w_down"], index, plan)
 
 
 def _kda_mixer(h, ap, cfg: TransformerConfig, attend):

@@ -843,7 +843,10 @@ class InferenceEngine:
         the bytes of state the steps read and wrote (live rows x layers x 2 x
         a state) and the state slots live, summed over `steps`; prefill_state
         (the same): the chunks its prefills computed and how many of them were
-        carried a state in by their predecessor; a KDA stack (states AND K/V
+        carried a state in by their predecessor; prefill_experts (a routed
+        model only): the rows its prefills' chunks handed their routed layers'
+        experts (chunk rows x routed layers, over `chunks`) and those of them
+        that went through the grouped product; a KDA stack (states AND K/V
         pages) counts both of those and decode_kv beside them: the K/V bytes
         and positions its steps' live rows read in their softmax layers;
         decode_experts.picks / held_picks: the steps' rows x choices a routed

@@ -478,8 +478,14 @@ class PagedLM:
             attrs,
             getattr(prompt, "launched", None),  # the engine's PromptTokens; a bare list from anyone else
         )
-        # A state model: the chunks that started from the state their predecessor left (all but a prompt's first).
-        counters = {"prefill_state": {"chunks": chunks, "carried_in": chunks - 1}} if self.state_cache or self.hybrid_cache else None
+        counters = {}
+        if self.state_cache or self.hybrid_cache:
+            # A state model: the chunks that started from the state their predecessor left (all but a prompt's first).
+            counters["prefill_state"] = {"chunks": chunks, "carried_in": chunks - 1}
+        if self.cfg.n_experts:
+            # A routed model: the rows its routed layers' experts were handed, and those of them sorted to their own experts.
+            rows = chunks * chunk * (self.cfg.n_layers - self.cfg.n_dense_layers)
+            counters["prefill_experts"] = {"rows": rows, "grouped_rows": rows if self._tfm.experts_grouped_at(chunk) else 0, "chunks": chunks}
         return PrefillToken(tok, attrs["computed_tokens"], counters)
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:

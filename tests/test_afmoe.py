@@ -189,28 +189,34 @@ def test_a_hits_chunks_start_where_the_cache_ends_and_give_the_misss_logits(monk
             assert not np.asarray(kv[name][:, 1 + bucket:]).any()
 
 
-@pytest.mark.parametrize("rows", [3, 64])
-def test_a_serving_step_picks_its_expert_products_by_its_rows(monkeypatch, rows):
+@pytest.mark.parametrize("share", [False, True], ids=["all_held", "a_share_of_four_experts_from_the_third"])
+@pytest.mark.parametrize("rows", [3, 64, tfm.GROUPED_FROM_ROWS - 1, tfm.GROUPED_FROM_ROWS, tfm.GROUPED_FROM_ROWS + 40])
+def test_a_serving_step_picks_its_expert_products_by_its_rows(rows, share):
     """A serving step hands `_routed_ffn` the group's expert stack and the
-    layer's place in it: under GROUPED_TILE_ROWS rows it multiplies every
-    expert by every row, from there on it groups the rows as training does.
-    Both give what the layer's own matrices give grouped, the same experts counted."""
+    layer's place in it: under GROUPED_FROM_ROWS rows it multiplies every
+    expert by every row, from there on each expert by its own rows where the
+    stack lies (ops/grouped_matmul.py), and never through `lax.ragged_dot`.
+    Both give what the layer's own matrices give grouped as training groups
+    them, the same experts counted; under a share (Solar-Open2's way: 4 of
+    the 8 experts held) the held experts' part of it."""
     cfg, params = seeded(6)
     riding, stack = tfm._experts_in_place(params["blocks"])
     assert tfm._experts_in_place(params["dense_blocks"]) == (params["dense_blocks"], None)
     assert set(stack) == set(tfm.EXPERT_WEIGHTS) and not set(riding["mlp"]) & set(stack)
+    if share:
+        cfg = cfg.replace(n_experts_held=4, first_expert=2)
+        stack = {name: w[:, 2:6] for name, w in stack.items()}
     layer = 1
     of_layer = functools.partial(jax.tree_util.tree_map, lambda w: w[layer])
     h = jax.random.normal(jax.random.PRNGKey(rows), (rows, 1, cfg.d_model), jnp.float32)
-    want, want_counts = tfm._routed_ffn(h, of_layer(params["blocks"]["mlp"]), cfg, counts=True)
-    for tile_rows, grouped in ((tfm.GROUPED_TILE_ROWS, False), (rows, True)):
-        monkeypatch.setattr(tfm, "GROUPED_TILE_ROWS", tile_rows)
-        # a function of its own each time: a trace is kept by the function traced
-        served = lambda h: tfm._routed_ffn(h, of_layer(riding["mlp"]), cfg, counts=True, experts=(stack, jnp.int32(layer)))
-        assert ("ragged_dot" in str(jax.make_jaxpr(served)(h))) == grouped
-        out, counts = served(h)
-        assert worst(out, want) <= TOLERANCE
-        np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    want, want_counts = tfm._routed_ffn(h, dict(of_layer(riding["mlp"]), **of_layer(stack)), cfg, counts=True)
+    served = lambda h: tfm._routed_ffn(h, of_layer(riding["mlp"]), cfg, counts=True, experts=(stack, jnp.int32(layer)))
+    text = str(jax.make_jaxpr(served)(h))
+    assert "ragged_dot" not in text
+    assert ("pallas_call" in text) == tfm.experts_grouped_at(rows) == (rows >= tfm.GROUPED_FROM_ROWS)
+    out, counts = served(h)
+    assert worst(out, want) <= TOLERANCE
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
 
 
 # ------------------------------------------------------- (c) the wrong models
@@ -295,8 +301,52 @@ def test_the_engine_serves_it_with_prefix_hits_and_counts_experts_and_windows(mo
     # one live row a step at lengths 46..64 and 60..78: five of six layers see 16 positions, one all of them
     live = list(range(46, 46 + 19)) + list(range(60, 60 + 19))
     assert clocks["decode_window"] == {"kv_live": 6 * sum(live), "kv_read": sum(5 * WINDOW + n for n in live)}
+    # 16-row chunks over 4 routed layers, under the cut: every row through the every-expert product
+    computed = clocks["prefill"]["computed_tokens"]
+    assert clocks["prefill_experts"] == {"rows": 4 * computed, "grouped_rows": 0, "chunks": computed // (2 * T)}
     out = lm.decode([1], [3], [[1]])
     assert isinstance(out, DecodeTokens) and len(out) == 4 and set(out.counters) == {"decode_experts", "decode_window"}
+
+
+def test_a_prompt_whose_chunk_reaches_the_cut_is_served_through_the_grouped_product():
+    """A prompt of 130 tokens lands in a bucket of 32 pages whose chunk is
+    PREFILL_CHUNK_TOKENS = 256 rows, at and above GROUPED_FROM_ROWS: its four
+    routed layers sort the chunk's 512 (row, expert) pairs to their experts
+    (ops/grouped_matmul.py, interpreted) and the decode steps behind it
+    multiply every expert. The tokens of an engine-free greedy loop, and the
+    counter that says which rows went which way."""
+    assert tfm.experts_grouped_at(tfm.PREFILL_CHUNK_TOKENS) and not tfm.experts_grouped_at(2)
+    cfg, params = seeded(8)
+    prompt = [int(t) for t in tokens_of(32, 130)]
+    lm = PagedLM(cfg, params, num_pages=48, page_tokens=T, max_slots=2, max_pages_per_seq=40)
+    eng = InferenceEngine(lm, EngineConfig(page_tokens=T, pool_pages=48, prefill_token_budget=256), name="t-afmoe-grouped")
+    try:
+        assert _collect(eng, prompt, 6) == greedy(cfg, params, prompt, 6)
+        clocks = eng.stats()["clocks"]
+    finally:
+        eng.close()
+    assert clocks["prefill"]["computed_tokens"] == 256
+    assert clocks["prefill_experts"] == {"rows": 4 * 256, "grouped_rows": 4 * 256, "chunks": 1}
+
+
+def test_a_256_row_chunk_lowers_to_two_grouped_kernels_a_routed_group_and_a_decode_step_to_none():
+    """The traced text of the two serving steps: a decode step of 64 rows
+    holds no call of the grouped kernels and the every-expert products
+    [E, rows, f]; a prefill bucket whose chunk is 256 rows holds the fused
+    gate-up kernel and the down kernel once each (the routed group is one
+    scan body), no `ragged_dot`, and no [E, rows, f] product of every expert."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    cfg, params = seeded(0)
+    E, f, pages = cfg.n_experts, cfg.d_ff, 32
+    kv = tfm.init_kv_pages(cfg, 1 + pages, T)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    decode = str(jax.make_jaxpr(lambda t, pos, kv, bt: tfm.forward_decode(params, t, pos, cfg, kv, bt))(i32(SLOTS), i32(SLOTS), kv, i32(SLOTS, pages)))
+    prefill = str(jax.make_jaxpr(lambda t, kv, bt, n: tfm.forward_prefill(params, t, cfg, kv, bt, n, 0))(i32(1, pages * T), kv, i32(pages), jnp.int32(130)))
+    names = (gm.SWIGLU_KERNEL_NAME, gm.MATMUL_KERNEL_NAME)
+    assert [decode.count(f"name={name}\n") for name in names] == [0, 0] and f"[{E},{SLOTS},{f}]" in decode
+    assert [prefill.count(f"name={name}\n") for name in names] == [1, 1]
+    assert "ragged_dot" not in prefill + decode and f"[{E},{tfm.PREFILL_CHUNK_TOKENS},{f}]" not in prefill
 
 
 def test_a_dense_models_decode_returns_a_plain_list_and_no_new_clock():

@@ -332,6 +332,8 @@ def test_twice_as_many_requests_as_slots_queue_and_each_is_served_the_tokens_it_
     clocks = stats["clocks"]
     chunks = sum(-(-len(p) // CHUNK) for p in prompts)
     assert clocks["prefill_state"] == {"chunks": chunks, "carried_in": chunks - 4}
+    # every layer of a KDA stack is routed; chunks of CHUNK rows, under the cut: none through the grouped product
+    assert clocks["prefill_experts"] == {"rows": chunks * CHUNK * cfg.n_layers, "grouped_rows": 0, "chunks": chunks}
     state, kv, experts = clocks["decode_state"], clocks["decode_kv"], clocks["decode_experts"]
     assert state["steps"] == kv["steps"] == experts["steps"] == clocks["decode"]["n"]
     assert state["live_slots"] == sum(answers) - 4 and state["bytes"] == state["live_slots"] * 2 * lm.state_bytes

@@ -427,19 +427,22 @@ def share_of(full_cfg, mp_full, rank, held):
 
 
 @pytest.mark.parametrize("router", ["softmax_renormalised", "sigmoid_with_a_selecting_bias_and_a_shared_expert"])
-@pytest.mark.parametrize("form", ["grouped", "every_expert"])
+@pytest.mark.parametrize("form", ["grouped", "every_expert", "grouped_in_place"])
 def test_the_eight_shares_add_up_to_the_uncut_layer(form, router):
     """The model-configs guide's share test. A layer of 16 experts, top-4, cut
     eight ways (2 experts a rank): every rank routes over all 16, renormalises
     over the 4 chosen whether held or not, and computes its own experts' part;
     the eight parts, the shared expert counted once, are the uncut layer's
-    result. Both forms of the routed FFN: the grouped one (training's, and a
-    serving step's of 512 rows and more) and the every-expert one (a decode
-    step's), which must also agree with each other share by share."""
+    result. All three forms of the routed FFN: training's grouped one
+    (`lax.ragged_dot`), a decode step's every-expert one, and a prefill
+    chunk's, grouped on the stack in place (ops/grouped_matmul.py: a call of
+    GROUPED_FROM_ROWS rows), which must also agree with training's share by share."""
     kw = dict(norm_topk_prob=True) if router.startswith("softmax") else dict(router_score="sigmoid", norm_topk_prob=True, route_scale=1.7, d_ff_shared=F)
     full = tfm.tiny(n_kv_heads=4, d_ff=F, n_experts=16, n_experts_per_tok=4, dtype=jnp.float32, **kw)
     mp = jax.tree_util.tree_map(lambda a: a[0], tfm.init_params(jax.random.PRNGKey(3), full)["blocks"]["mlp"])
-    h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, D), jnp.float32)
+    rows = tfm.GROUPED_FROM_ROWS // 2 if form == "grouped_in_place" else 9
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, rows, D), jnp.float32)
+    assert tfm.experts_grouped_at(2 * rows) == (form == "grouped_in_place")
 
     def ffn(cfg, mp):
         if form == "grouped":

@@ -187,6 +187,41 @@ def test_paged_steps_read_the_attention_weights_where_they_lie(one_chip, mosaic,
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
+# Trinity-Mini's routed FFN (128 experts of width 1024 over d 2048, top-8 by sigmoid, a shared expert) behind one dense
+# layer, two routed layers, GQA 32:4 x 128 and a small vocabulary.
+ROUTED = dict(
+    vocab_size=2048, d_model=2048, n_layers=3, n_heads=32, n_kv_heads=4, d_head=128, d_ff=1024, n_experts=128, n_experts_per_tok=8,
+    norm_topk_prob=True, router_score="sigmoid", route_scale=2.826, d_ff_shared=1024, n_dense_layers=1, d_ff_dense=6144,
+    max_seq_len=8192, attn_impl="naive", remat=False,
+)
+
+
+def _copies_over(text, elements):
+    """(dtype, dims) of the compiled text's `copy` results of more than that many elements."""
+    return [m for m in re.findall(r"= (\w+)\[([\d,]+)\]\S* copy\(", text) if np.prod([int(n) for n in m[1].split(",")]) > elements]
+
+
+def test_a_routed_models_chunk_takes_the_grouped_kernels_on_the_stack_in_place_and_its_decode_step_none(one_chip, mosaic):
+    """At Trinity-Mini's widths: the prefill bucket's 256-row chunk holds the
+    fused gate-up kernel and the down kernel once each (the routed layers are
+    one scan body), reads the experts where they lie (no copy of a layer's
+    [128, 2048, 1024] slice, 537 MB, which a kernel handed `_layer_of`'s
+    slice would make every layer of every chunk) and holds no product of
+    every expert by every row; the 16-row decode step holds neither kernel
+    and keeps its every-expert products."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    cfg = tfm.TransformerConfig(**ROUTED)
+    E, f, rows = cfg.n_experts, cfg.d_ff, tfm.PREFILL_CHUNK_TOKENS
+    assert tfm.experts_grouped_at(rows) and not tfm.experts_grouped_at(SLOTS)
+    prefill, decode = (_compile_paged(one_chip, cfg, step).as_text() for step in ("prefill", "decode"))
+    for name in (gm.SWIGLU_KERNEL_NAME, gm.MATMUL_KERNEL_NAME):
+        assert len(re.findall(rf"custom_call_target=\"tpu_custom_call\".*{name}", prefill)) == 1, name
+        assert name not in decode
+    assert not _copies_over(prefill, 2**24)
+    assert f"[{E},{rows},{f}]" not in prefill and f"[{E},{SLOTS},{f}]" in decode
+
+
 @pytest.mark.parametrize("step,pages", [("decode", None), ("prefill", 2), ("prefill", 8)])
 def test_paged_executables_carry_their_names_into_the_module(one_chip, step, pages):
     """PagedLM's jitted closures are named for what they are, so a device
@@ -327,8 +362,7 @@ def test_kda_decode_step_is_one_kernel_a_layer_over_both_pools_in_place(one_chip
     assert kda.KERNEL_NAME in text and pa.KERNEL_NAME in text and text.count("tpu_custom_call") == 2
     assert mem.alias_size_in_bytes >= _pool_nbytes(kv)
     assert mem.temp_size_in_bytes < 64 * 2**20
-    big_copies = [m for m in re.findall(r"= (\w+)\[([\d,]+)\]\S* copy\(", text) if np.prod([int(n) for n in m[1].split(",")]) > 2**22]
-    assert not big_copies, big_copies
+    assert not _copies_over(text, 2**22)
 
 
 def test_kda_prefill_bucket_updates_both_pools_in_place(one_chip, mosaic):
@@ -341,6 +375,16 @@ def test_kda_prefill_bucket_updates_both_pools_in_place(one_chip, mosaic):
     assert pa.PREFILL_KERNEL_NAME in text
     assert mem.alias_size_in_bytes >= _pool_nbytes(kv)
     assert mem.temp_size_in_bytes < 128 * 2**20
+    # The chunk's expert products are the two grouped kernels of each of the period's two scan bodies (the softmax
+    # layer's, the KDA layers'), on the stacks where they lie: no layer's [held, 4096, 1280] slice (84 MB) is copied
+    # out for them, and no product of every held expert by every row [held, 256, 1280] is left.
+    from ray_tpu.ops import grouped_matmul as gm
+
+    assert tfm.experts_grouped_at(tfm.PREFILL_CHUNK_TOKENS)
+    assert len(re.findall(rf"custom_call_target=\"tpu_custom_call\".*{gm.SWIGLU_KERNEL_NAME}", text)) == 2
+    assert len(re.findall(rf"custom_call_target=\"tpu_custom_call\".*{gm.MATMUL_KERNEL_NAME}", text)) == 2
+    assert not _copies_over(text, 2**22)
+    assert f"[{cfg.experts_held},{tfm.PREFILL_CHUNK_TOKENS},{cfg.d_ff}]" not in text
 
 
 def test_a_kda_stacks_executables_carry_names_of_their_own(one_chip):
