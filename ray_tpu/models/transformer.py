@@ -25,9 +25,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,11 +116,8 @@ class TransformerConfig:
     # a_ts = exp(L_t - L_s) (q_t . k_s / sqrt(head_dim))^2, normalised by its
     # own sum + RETENTION_EPS, L the running sum of the gate's log-sigmoid
     # (`wg`, one logit a K/V head); no softmax, mask beyond s <= t or window.
-    # What a served sequence keeps of its past is then no K/V: a "page" of
-    # `init_kv_pages` is one sequence's whole recurrent state, float32, of a
-    # fixed size whatever its length (`retention_state_dim`), read and
-    # written whole every decode step, one page a sequence for its life,
-    # where a softmax config's page holds `page_tokens` positions of K/V.
+    # What a served sequence keeps of its past is then no K/V but one state,
+    # read and written whole every decode step: the "retention" row of `KINDS`.
     retention_degree: int = 0
     # One chip's share of an expert-parallel deployment's experts, off at 0
     # (all held): the experts [first_expert, first_expert + n_experts_held) of
@@ -139,9 +137,8 @@ class TransformerConfig:
     # Off at 0. The periods are one scan whose body is the period's layers in
     # order: `blocks` holds the softmax layers [periods, ...], `kda_blocks`
     # the others [periods, kda_per_period, ...]. Served, a sequence has two
-    # caches: K/V pages of the softmax layers alone, and of every KDA layer a
-    # float32 state a head with the convolution's tail, of a fixed size, in
-    # one of `state_slots` slots (`init_kv_pages`; slot 0 is trash).
+    # caches: K/V pages of the softmax layers alone and, in one of
+    # `state_slots` slots, what the "kda" row of `KINDS` keeps of the others.
     kda_per_period: int = 0
     kda_conv: int = 4
     state_slots: int = 0
@@ -497,12 +494,48 @@ def _rope_switch(cos, sin, rope_on):
     return jnp.where(rope_on, cos, 1.0), jnp.where(rope_on, sin, 0.0)
 
 
-def _layer_groups(params: PyTree, cfg: TransformerConfig):
-    """The stack as groups of alike layers, in order: (stacked weights, first
-    absolute layer, count). Each group is one scan through `_block`."""
-    nd = cfg.n_dense_layers
-    groups = [(params["dense_blocks"], 0, nd)] if nd else []
-    return groups + [(params["blocks"], nd, cfg.n_layers - nd)]
+class StackMember(NamedTuple):
+    """One kind of layer within a repeat of a segment of the stack: `layers`
+    alike layers in a row, their weights stacked in `params[tree]` ([repeats,
+    ...], or [repeats, layers, ...] where a repeat has several); `first`: where
+    the first of them lies in that kind's cache leaves (`KINDS`)."""
+
+    kind: str
+    tree: str
+    layers: int
+    first: int
+
+    def place(self, repeat, j=None):
+        """The place in its kind's cache leaves of layer `j` of repeat `repeat`."""
+        at = repeat if self.layers == 1 else repeat * self.layers + j
+        return self.first + at if self.first else at  # no `0 + at` in a traced body
+
+
+def stack_plan(cfg: TransformerConfig) -> Tuple[Tuple[int, Tuple[StackMember, ...]], ...]:
+    """The layer stack in published order, from the config alone: segments
+    (repeats, members), each the members' layers in order, `repeats` times.
+    Alike layers are one segment of one member (a routed model's leading dense
+    layers a segment of their own); a periodic pattern is one segment whose
+    members are a period. `_walk_stack` walks it, `cache_layout` reads what a
+    served sequence keeps from it; a new pattern is a new return value here."""
+    per, nd = cfg.kda_per_period, cfg.n_dense_layers
+    if per:
+        return ((cfg.n_layers // (per + 1), (StackMember("softmax", "blocks", 1, 0), StackMember("kda", "kda_blocks", per, 0))),)
+    kind = "retention" if cfg.retention_degree else "softmax"
+    dense = ((nd, (StackMember(kind, "dense_blocks", 1, 0),)),) if nd else ()
+    return (*dense, (cfg.n_layers - nd, (StackMember(kind, "blocks", 1, nd),)))
+
+
+class LayerPlace(NamedTuple):
+    """Where `_walk_stack` is, as a layer's callback sees it: `layer`, the
+    layer's place among the layers of its kind (its layer of that kind's cache
+    leaves; None where the walk keeps nothing: training), and the values that
+    ride the scan for this layer (`_per_layer`; None for what the config does
+    not vary)."""
+
+    layer: Any
+    window: Any
+    rope_on: Any
 
 
 EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -744,12 +777,12 @@ def retention_whole(q, k, v, log_g, chunk: Optional[int] = None):
     return jax.vmap(one)(q, k, v, log_g)
 
 
-def _qkv(h, ap, cfg: TransformerConfig, split: bool):
+def _qkv(h, ap, cfg: TransformerConfig, split: bool, gated: bool = False):
     """h [b, s, d] -> q, k, v before rope: split into heads ([b, s, n_heads,
     hd], [b, s, n_kv_heads, hd]) or each as its projection gives it
     ([b, s, n_heads * hd], [b, s, n_kv_heads * hd]); `_block` says which.
     A fourth beside them: the retention gate's logits [b, s, n_kv_heads]
-    float32, None for a softmax config."""
+    float32 (`gated`: a retention layer), else None."""
     q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
@@ -762,7 +795,7 @@ def _qkv(h, ap, cfg: TransformerConfig, split: bool):
             k = norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
     view = (lambda t: t.reshape(*t.shape[:2], -1, cfg.head_dim)) if split else (lambda t: t)
     gate = None
-    if cfg.retention_degree:
+    if gated:
         gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
     return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype), gate
 
@@ -980,18 +1013,19 @@ def _kda_inputs(cfg: TransformerConfig, q, k, v, conv, tails, n_valid=None):
     return q, k, v, jnp.stack(new_tails).reshape(_tail_shape(cfg))
 
 
-def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str = "", experts=None):
+def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str = "", experts=None, kind: str = "softmax"):
     """THE transformer block, x [b, s, d] -> [b, s, d]: norm, q/k/v, rope,
     attention, output projection, residual, norm, feed-forward, residual.
     What differs between training, prefill and decode is how q attends,
-    and the caller passes that: `attend(q, k, v) -> (o [b, s, n_heads,
-    head_dim], kept)`, with q and k after rope; under power retention
-    `attend(q, k, v, log_g)`, log_g [b, s, n_kv_heads] float32 the gate's
-    log-sigmoid. `kept` is whatever the caller wants back (the K/V or state
-    pool it wrote into; None in training). Returns (out, kept), and as a
-    third what the router did with
-    this layer's input if `stats` is "route" (`_route_stats`), or the rows
-    each expert took [E] if it is "experts" (None from a dense FFN).
+    and the caller passes that, one of the three forms of the layer's `kind`
+    (a row of `KINDS`): `attend(q, k, v) -> (o [b, s, n_heads, head_dim],
+    kept)`, with q and k after rope; a "retention" layer's `attend(q, k, v,
+    log_g)`, log_g [b, s, n_kv_heads] float32 the gate's log-sigmoid; a "kda"
+    layer's as `_kda_mixer` calls it. `kept` is whatever the caller wants back
+    (the cache leaves it wrote into; None in training). Returns (out, kept),
+    and as a third what the router did with this layer's input if `stats` is
+    "route" (`_route_stats`), or the rows each expert took [E] if it is
+    "experts" (None from a dense FFN).
     `experts`: a routed layer's expert matrices where the caller keeps them
     out of `layer_params` (`_experts_in_place`), as `_routed_ffn` takes them. The
     _ckpt names are the save frontier of remat_policy="hot"; outside
@@ -1000,7 +1034,7 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
     h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
-    if "conv_q" in ap:  # a KDA layer: a mixer of its own around the caller's `attend`
+    if kind == "kda":  # a mixer of its own around the caller's `attend`
         attn_out, kept = _kda_mixer(h, ap, cfg, attend)
         return _block_ffn(x, attn_out, kept, h, layer_params, cfg, stats, experts)
     # Where q and k are split into heads decides which operand of their
@@ -1011,7 +1045,7 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     # smaller of the two should move: the weight while a call has at least
     # as many rows as the weight has (a training batch), the result while
     # it has fewer (a decode step, a prefill chunk).
-    q, k, v, gate = _qkv(h, ap, cfg, split=b * s >= d)
+    q, k, v, gate = _qkv(h, ap, cfg, split=b * s >= d, gated=kind == "retention")
     rotates = not cfg.rope_layers or any(cfg.rope_layers)  # where no layer does, nothing to switch and nothing computed
     if not rotates:
         # Rope's elementwise pass is also what keeps the split into heads off the projections: with nothing between
@@ -1067,30 +1101,30 @@ def _block_ffn(x, attn_out, kept, h, layer_params, cfg: TransformerConfig, stats
     return out, kept
 
 
-def _attend_whole(cfg: TransformerConfig, mesh: Optional[Mesh], window=None):
-    """The attention strategy of a whole sequence that keeps nothing:
-    training, `forward`, `routing_stats`."""
-    if cfg.retention_degree:
-        # As with windows: the flash, ring and ulysses kernels are softmax
-        # attention, and a retention layer through them would be another model.
-        if cfg.attn_impl != "naive":
-            raise ValueError(
-                f"attn_impl={cfg.attn_impl!r} computes softmax attention; a config with `retention_degree` "
-                "runs its whole-sequence forward with attn_impl='naive' (`retention_whole`)"
-            )
-        return lambda q, k, v, log_g: (retention_whole(q, k, v, log_g).astype(q.dtype), None)
-    if cfg.kda_per_period and cfg.attn_impl != "naive":
+def _naive_only(cfg: TransformerConfig, kind: str, expression: str):
+    """As with windows: the flash, ring and ulysses kernels are softmax
+    attention, and a layer of another kind through them would be another model."""
+    if cfg.attn_impl != "naive":
         raise ValueError(
-            f"attn_impl={cfg.attn_impl!r}: a config with `kda_per_period` runs its whole-sequence forward "
-            "with attn_impl='naive' (`_kda_attend_whole` beside the plain softmax expression)"
+            f"attn_impl={cfg.attn_impl!r} computes softmax attention; a config with {kind} layers "
+            f"runs its whole-sequence forward with attn_impl='naive' (`{expression}`)"
         )
-    return lambda q, k, v: (_attention(q, k, v, cfg, mesh, window), None)
 
 
-def _kda_attend_whole(cfg: TransformerConfig):
-    """A KDA layer's `attend` over whole sequences that keep nothing: each
-    from zero tails and a zero state, the chunked form."""
+def _softmax_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
+    return lambda q, k, v: (_attention(q, k, v, cfg, mesh, where.window), None)
+
+
+def _retention_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
+    _naive_only(cfg, "retention", "retention_whole")
+    return lambda q, k, v, log_g: (retention_whole(q, k, v, log_g).astype(q.dtype), None)
+
+
+def _kda_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
+    """Each sequence from zero tails and a zero state, the chunked form."""
     from ..ops import kda
+
+    _naive_only(cfg, "kda", "_kda_whole")
 
     def one(q, k, v, g, beta, conv):
         zeros = jnp.zeros(_tail_shape(cfg), q.dtype)
@@ -1101,33 +1135,68 @@ def _kda_attend_whole(cfg: TransformerConfig):
     return lambda q, k, v, g, beta, conv: (jax.vmap(one, in_axes=(0, 0, 0, 0, 0, None))(q, k, v, g, beta, conv), None)
 
 
-def _scan_periods(params: PyTree, cfg: TransformerConfig, step, carry, in_place: bool):
-    """The stack of a config with `kda_per_period` as ONE scan over its
-    periods, whose body is a period's layers in published order: the softmax
-    layer, then an inner scan over the KDA layers. `step(kind, carry, index,
-    layer_params, experts) -> (carry, y)`: kind "attn" or "kda"; index the
-    layer's place among the layers of its kind (its layer of the K/V pool, or
-    of the state pool); experts as `_routed_ffn` takes them where the expert
-    stacks stay out of the scans' xs (`in_place`: the serving steps), else
-    None. Returns (carry, (the softmax layers' ys [periods, ...], the KDA
-    layers' [periods, kda_per_period, ...]))."""
-    per = cfg.kda_per_period
-    (attn_riding, attn_stack), (kda_riding, kda_stack) = (
-        _experts_in_place(params[name]) if in_place else (params[name], None) for name in ("blocks", "kda_blocks")
-    )
+def _walk_stack(params: PyTree, cfg: TransformerConfig, step, carry, in_place: bool):
+    """THE walk of the layer stack: `stack_plan(cfg)`'s segments in order,
+    each layer through `step(kind, carry, where, layer_params, experts) ->
+    (carry, y)`: `where` a `LayerPlace`, `experts` as `_routed_ffn` takes them.
+    A segment of single alike layers is one `lax.scan` over them, the layer's
+    place and its `_per_layer` values riding as xs beside its weights. Any
+    other is a scan over its repeats whose body takes each member in order,
+    directly where it has one layer and through an inner scan where it has
+    several. `in_place` (the serving steps) keeps a routed stack's expert
+    matrices out of the scans' xs (`_experts_in_place`) and hands `step` the
+    stack with the layer's index in it; training scans them like every other
+    weight, and no place rides its scans of single layers (nothing is kept:
+    LayerPlace.layer is None there). Returns (carry, a segment's ys each: a
+    tuple of its members' stacked ys, [repeats, ...] or [repeats, layers, ...])."""
+    segments_ys, first_layer = [], 0
+    for repeats, members in stack_plan(cfg):
+        trees = [_experts_in_place(params[m.tree]) if in_place else (params[m.tree], None) for m in members]
+        if len(members) == 1 and members[0].layers == 1:
+            (member,), ((riding, stack),) = members, trees
 
-    def period(carry, xs):
-        p, attn_params, kda_params = xs
-        carry, y_attn = step("attn", carry, p, attn_params, None if attn_stack is None else (attn_stack, p))
+            def layer(carry, xs, member=member, stack=stack):
+                at, window, rope_on, layer_params = xs
+                experts = None if stack is None else (stack, at - member.first)
+                return step(member.kind, carry, LayerPlace(at, window, rope_on), layer_params, experts)
 
-        def kda_layer(carry, xs):
-            j, layer_params = xs
-            return step("kda", carry, p * per + j, layer_params, None if kda_stack is None else (kda_stack, (p, j)))
+            at = jnp.arange(member.first, member.first + repeats) if in_place else None
+            carry, ys = lax.scan(layer, carry, (at, *_per_layer(cfg, first_layer, repeats), riding))
+            ys = (ys,)
+        else:
 
-        carry, y_kda = lax.scan(kda_layer, carry, (jnp.arange(per), kda_params))
-        return carry, (y_attn, y_kda)
+            def repeat(carry, xs, members=members, trees=trees):
+                r, *riding = xs
+                ys = []
+                for m, (_, stack), layer_params in zip(members, trees, riding):
+                    if m.layers == 1:
+                        carry, y = step(m.kind, carry, LayerPlace(m.place(r), None, None), layer_params, None if stack is None else (stack, r))
+                    else:
 
-    return lax.scan(period, carry, (jnp.arange(cfg.n_layers // (per + 1)), attn_riding, kda_riding))
+                        def layer(carry, xs, m=m, stack=stack):
+                            j, layer_params = xs
+                            return step(m.kind, carry, LayerPlace(m.place(r, j), None, None), layer_params, None if stack is None else (stack, (r, j)))
+
+                        carry, y = lax.scan(layer, carry, (jnp.arange(m.layers), layer_params))
+                    ys.append(y)
+                return carry, tuple(ys)
+
+            carry, ys = lax.scan(repeat, carry, (jnp.arange(repeats), *(riding for riding, _ in trees)))
+        segments_ys.append(ys)
+        first_layer += repeats * sum(m.layers for m in members)
+    return carry, segments_ys
+
+
+def _in_stack_order(plan, segments_ys):
+    """`_walk_stack`'s ys as one tree of [layers, ...] leaves in published
+    order, over the segments that gave any (a dense segment's routers: none)."""
+    tree_map, parts = jax.tree_util.tree_map, []
+    for (repeats, members), ys in zip(plan, segments_ys):
+        if jax.tree_util.tree_leaves(ys):
+            # each member's [repeats, its layers, ...], side by side a repeat, then the repeats in a row
+            ys = [tree_map(lambda t, m=m: t.reshape(repeats, m.layers, *t.shape[1 + (m.layers > 1) :]), y) for m, y in zip(members, ys)]
+            parts.append(tree_map(lambda *ts: jnp.concatenate(ts, axis=1).reshape(-1, *ts[0].shape[2:]), *ys))
+    return tree_map(lambda *ts: jnp.concatenate(ts), *parts)
 
 
 def _embed(params: PyTree, tokens, cfg: TransformerConfig):
@@ -1164,9 +1233,9 @@ def forward_hidden(
     cos, sin = rope_tables(cfg, s)
     x = _embed(params, tokens, cfg)
 
-    def body(x, xs):
-        window, rope_on, layer_params = xs
-        return _block(x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), _attend_whole(cfg, mesh, window))
+    def layer(kind, x, where, layer_params):
+        attend = KINDS[kind].whole(cfg, mesh, where)
+        return _block(x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), attend, kind=kind)[0]
 
     if cfg.remat:
         if cfg.remat_policy == "dots":
@@ -1191,20 +1260,9 @@ def forward_hidden(
             )
         else:
             policy = None
-        body = jax.checkpoint(body, policy=policy)
+        layer = jax.checkpoint(layer, policy=policy, static_argnums=(0,))
 
-    if cfg.kda_per_period:
-        attends = {"attn": _attend_whole(cfg, mesh), "kda": _kda_attend_whole(cfg)}
-
-        def layer(kind, x, layer_params):
-            return _block(x, layer_params, cfg, cos, sin, attends[kind])[0]
-
-        if cfg.remat:
-            layer = jax.checkpoint(layer, policy=policy, static_argnums=(0,))
-        x, _ = _scan_periods(params, cfg, lambda kind, x, _i, layer_params, _e: (layer(kind, x, layer_params), None), x, in_place=False)
-        return _norm(x, params["final_norm"]["scale"], cfg)
-    for blocks, first, n in _layer_groups(params, cfg):
-        x, _ = lax.scan(body, x, (*_per_layer(cfg, first, n), blocks))
+    x, _ = _walk_stack(params, cfg, lambda kind, x, where, layer_params, _experts: (layer(kind, x, where, layer_params), None), x, in_place=False)
     return _norm(x, params["final_norm"]["scale"], cfg)
 
 
@@ -1251,24 +1309,19 @@ def routing_stats(params: PyTree, tokens: jax.Array, cfg: TransformerConfig) -> 
     tokens_per_expert [L, E] (every row sums to batch*seq*k: nothing is
     dropped); gap [L, batch*seq] between the last probability a token took
     and the first it left out (how close each token is to another choice)."""
-    if cfg.kda_per_period:
-        raise ValueError("routing_stats walks groups of alike layers; a KDA stack's routers are counted by its decode step")
     cos, sin = rope_tables(cfg, tokens.shape[1])
     x = _embed(params, tokens, cfg)
 
-    def scan_step(x, xs):
-        window, rope_on, layer_params = xs
-        out, _, route = _block(
-            x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), _attend_whole(cfg, None, window), stats="route"
+    def layer(kind, x, where, layer_params, _experts):
+        routed = "router" in layer_params["mlp"]  # a routed model's leading dense layers route nothing
+        out, _, *route = _block(
+            x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), KINDS[kind].whole(cfg, None, where),
+            stats="route" if routed else "", kind=kind,
         )
-        return out, route
+        return out, (route[0] if routed else None)
 
-    def plain_step(x, xs):  # the leading dense layers route nothing
-        return _block(x, xs[2], cfg, *_rope_switch(cos, sin, xs[1]), _attend_whole(cfg, None, xs[0]))[0], None
-
-    for blocks, first, n in _layer_groups(params, cfg):
-        x, route = lax.scan(scan_step if "router" in blocks["mlp"] else plain_step, x, (*_per_layer(cfg, first, n), blocks))
-    return route
+    _, segments = _walk_stack(params, cfg, layer, x, in_place=False)
+    return _in_stack_order(stack_plan(cfg), segments)
 
 
 def build_train_step(
@@ -1427,273 +1480,6 @@ def _tail_shape(cfg: TransformerConfig) -> Tuple[int, int]:
     return (16, total // 16) if total % (16 * 128) == 0 else (1, total)
 
 
-def init_kv_pages(
-    cfg: TransformerConfig, num_pages: int, page_tokens: int, state_slots: Optional[int] = None
-) -> Dict[str, jax.Array]:
-    """Allocates the paged KV pool: k/v of shape
-    [n_layers, num_pages, page_tokens, n_kv_heads * head_dim]. A page is
-    tokens x (head, dim): the tile the paged kernels copy and multiply
-    as it lies, so heads and dim are ONE axis of the stored array (split,
-    the device's tiled layout would put heads where the kernel needs
-    tokens, and every step would pay a relayout of the pool).
-
-    Under power retention a page is ONE sequence's recurrent state and
-    `page_tokens` only bounds the positions it may be served (it shapes
-    nothing): `s` [n_layers, num_pages, n_kv_heads, head_dim, D] and `z`
-    [n_layers, num_pages, n_kv_heads, D], float32 (the layout: see
-    `retention_phi`). The pool is one tree either way: forward_prefill and
-    forward_decode take it and hand it back under whatever names it has.
-
-    A KDA stack (`cfg.kda_per_period`) has both kinds of cache in the one
-    tree: `k` / `v` as above over its softmax layers ALONE, and over its KDA
-    layers alone `s` [kda layers, state_slots, n_heads, head_dim, head_dim]
-    float32, a head's state, with `tail` [kda layers, state_slots,
-    *_tail_shape], the 3 x (kda_conv - 1) rows of the q, k and v projections
-    that the short convolution still needs (q's, k's, v's, each oldest
-    first), in the parameters' type. A sequence holds
-    K/V pages as it grows and ONE state slot for its life (`state_slots`, or
-    `cfg.state_slots`; slot 0 is the trash slot, as page 0 is the trash page)."""
-    if cfg.kda_per_period:
-        periods = cfg.n_layers // (cfg.kda_per_period + 1)
-        slots, n_kda, wide = state_slots or cfg.state_slots, periods * cfg.kda_per_period, cfg.n_heads * cfg.head_dim
-        if slots < 2:
-            raise ValueError("a KDA stack's pool has a trash slot and at least one state slot: state_slots >= 2")
-        shape = (periods, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
-        return {
-            "k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "s": jnp.zeros((n_kda, slots, cfg.n_heads, cfg.head_dim, cfg.head_dim), jnp.float32),
-            "tail": jnp.zeros((n_kda, slots, *_tail_shape(cfg)), cfg.dtype),
-        }
-    if cfg.retention_degree:
-        D = retention_state_dim(cfg.head_dim)
-        return {
-            "s": jnp.zeros((cfg.n_layers, num_pages, cfg.n_kv_heads, cfg.head_dim, D), jnp.float32),
-            "z": jnp.zeros((cfg.n_layers, num_pages, cfg.n_kv_heads, D), jnp.float32),
-        }
-    shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def prefill_chunk_tokens(cfg: TransformerConfig, bucket_pages: int, page_tokens: int) -> Tuple[int, int]:
-    """(positions one chunk of forward_prefill computes, the granule its first
-    chunk is anchored to) for a bucket of that many pages. K/V pages: whole
-    pages (`prefill_chunk_pages`), anchored at a page's start. A state:
-    PREFILL_CHUNK_TOKENS positions inside its one page, anchored at the very
-    position the state has reached."""
-    if cfg.retention_degree:
-        return min(PREFILL_CHUNK_TOKENS, bucket_pages * page_tokens), 1
-    return prefill_chunk_pages(bucket_pages, page_tokens) * page_tokens, page_tokens
-
-
-def prefill_chunk_pages(bucket_pages: int, page_tokens: int) -> int:
-    """Pages of one chunk of forward_prefill for a bucket of that many
-    pages: PREFILL_CHUNK_TOKENS' worth, or the whole of a smaller bucket
-    (the largest divisor of the bucket not above it, so chunks tile it)."""
-    from ..ops.paged_attention import largest_divisor
-
-    return largest_divisor(bucket_pages, max(1, PREFILL_CHUNK_TOKENS // page_tokens))
-
-
-def prefill_chunk_span(length, write_from, chunk_tokens: int, page_tokens: int, minimum=min, maximum=max):
-    """(anchor, count) of the chunks forward_prefill computes: chunk i covers
-    positions [anchor + i * chunk_tokens, anchor + (i + 1) * chunk_tokens).
-    The anchor is where the cache ends: the page of `write_from`, so the
-    count is the uncached span in chunks, rounded up:
-    ceil((length - anchor) / chunk_tokens). The last position's chunk is
-    always computed, its logits are the result: where the cache holds the
-    whole prompt the anchor is the last position's page. Python ints (PagedLM
-    counts computed tokens with it), or traced scalars with jnp's minimum /
-    maximum."""
-    last = maximum(length - 1, 0)
-    anchor = minimum(write_from, last) // page_tokens * page_tokens
-    return anchor, (last - anchor) // chunk_tokens + 1
-
-
-def forward_prefill(
-    params: PyTree,
-    tokens: jax.Array,
-    cfg: TransformerConfig,
-    kv_pages: Dict[str, jax.Array],
-    block_table: jax.Array,
-    length: jax.Array,
-    write_from: jax.Array,
-    slot=TRASH_PAGE,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill ONE sequence: computes what the cache does not hold and
-    writes its k/v into the paged pool.
-
-    tokens [1, S] (padded to a bucket; pad is arbitrary token ids),
-    block_table [P] page indices covering positions [0, P*page_tokens),
-    length: scalar, true prompt length (<= S),
-    write_from: scalar, first position to COMPUTE: the pages below it
-      already hold this prompt's k/v (shared prefix pages the radix cache
-      matched, written by their owner) and are read, never recomputed into
-      and never rewritten. The radix cache shares whole pages, so it is a
-      multiple of the page; 0 is a miss, which runs the same loop.
-
-    The uncached span is walked in chunks of `prefill_chunk_pages` pages
-    with a dynamic trip count (`prefill_chunk_span`): the first chunk starts
-    at write_from, wherever in the bucket that page lies, and the last is
-    the one that holds the last position, so the work is the uncached
-    suffix rounded up to chunks, not the bucket. A chunk passes through all
-    layers; in each it writes its pages (whole pages, below the length) and
-    its rows attend causally over positions [0, chunk end) of the block
-    table's pages. No row below write_from is computed; the last chunk may
-    run past the bucket's end, over padding made here.
-
-    Under power retention (`cfg.retention_degree`) the pool holds states
-    (`init_kv_pages`), `block_table` is [1], the sequence's one slot, and
-    `write_from` is the position its state has reached: the chunks
-    (`prefill_chunk_tokens`: PREFILL_CHUNK_TOKENS rows inside the one page)
-    start exactly there, each from the state the one before it left in the
-    slot, the one at position 0 from nothing whatever the slot held; rows
-    past `length` leave the state as it was.
-
-    A KDA stack (`cfg.kda_per_period`) writes both caches: its softmax layers'
-    k/v into the pages of `block_table` as above, its KDA layers' states and
-    tails into state slot `slot` (a scalar; the trash slot from a caller that
-    names none), each chunk from what the one before it left there and the
-    one at position 0 from zeros WITHOUT reading what the slot held. A state
-    at a page's border is not kept, so nothing of it is a prefix: write_from
-    is 0.
-
-    Returns (last-position logits [1, vocab] fp32, updated kv_pages).
-    """
-    from ..ops.paged_attention import paged_prefill_attention
-
-    _, S = tokens.shape
-    state, hybrid = bool(cfg.retention_degree), bool(cfg.kda_per_period)
-    names = ("s", "z") if state else ("k", "v", "s", "tail") if hybrid else ("k", "v")
-    T = S // block_table.shape[0] if state else kv_pages["k"].shape[2]
-    C, granule = prefill_chunk_tokens(cfg, S // T, T)
-    pages = C // T
-    # What a chunk slices is padded by a chunk: the last one starts at a
-    # page below the length, not at a multiple of C, and a dynamic slice
-    # that ran past the end would be moved back silently.
-    cos_t, sin_t = rope_tables(cfg, S + C)
-    tokens = jnp.pad(tokens, ((0, 0), (0, C)))
-    dest_table = None if state else jnp.pad(block_table, (0, pages), constant_values=TRASH_PAGE)
-    use_kernel = not state and paged_attention_path(cfg, T) == "paged_kernel"
-    anchor, n_chunks = prefill_chunk_span(length, write_from, C, granule, jnp.minimum, jnp.maximum)
-
-    def chunk_step(i, carry):
-        *pool, _ = carry
-        c0 = anchor + i * C
-        cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
-        sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
-        x = _embed(params, lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), cfg)
-        if state:
-            attend_in = partial(_state_chunk_attend, cfg, block_table[0], c0, c0 + jnp.arange(C) < length)
-        else:
-            # Whole pages are written (a page is one contiguous tile of the
-            # pool; a token row cuts through 32 of them): every page of the
-            # chunk that holds a position in [write_from, length). The rest of
-            # the prompt's last page receives the padding's k/v, which nothing
-            # reads (attention stops at the length and decode overwrites
-            # position by position).
-            first = c0 + jnp.arange(pages) * T
-            writable = (first + T > write_from) & (first < length)
-            dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(dest_table, c0 // T, pages), TRASH_PAGE)
-
-            def attend_in(layer, window, kp, vp):
-                def attend(q, k, v):
-                    kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
-                    vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
-                    # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
-                    with _window_scope(window):
-                        if use_kernel:
-                            o = paged_prefill_attention(
-                                q[0], kp_, vp_, layer, block_table, c0, length, n_kv_heads=cfg.n_kv_heads, window=window
-                            )
-                        else:
-                            o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window)
-                    return o[None].astype(cfg.dtype), (kp_, vp_)
-
-                return attend
-
-        # The pool rides both loops as a carry, written in place (as in
-        # forward_decode): no copy of it is made a layer or a chunk.
-        def scan_step(stack, first_layer, carry, inputs):
-            x, *pool = carry
-            layer, window, rope_on, layer_params = inputs
-            experts = None if stack is None else (stack, layer - first_layer)
-            x, pool = _block(
-                x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend_in(layer, window, *pool), experts=experts
-            )
-            return (x, *pool), None
-
-        def period_step(kind, carry, index, layer_params, experts):
-            x, kp, vp, sp, tp = carry
-            if kind == "attn":
-                attend = _states_ride(attend_in(index, None, kp, vp), sp, tp)
-            else:
-                attend = _kda_chunk_attend(cfg, slot, c0, c0 + jnp.arange(C) < length, index, kp, vp, sp, tp)
-            x, pool = _block(x, layer_params, cfg, cos, sin, attend, experts=experts)
-            return (x, *pool), None
-
-        if hybrid:
-            (x, *pool), _ = _scan_periods(params, cfg, period_step, (x, *pool), in_place=True)
-        for blocks, first_layer, n in () if hybrid else _layer_groups(params, cfg):
-            riding, stack = _experts_in_place(blocks)
-            xs = (jnp.arange(first_layer, first_layer + n), *_per_layer(cfg, first_layer, n), riding)
-            (x, *pool), _ = lax.scan(partial(scan_step, stack, first_layer), (x, *pool), xs)
-        # the last position's row, if this is its chunk (the final one is)
-        return (*pool, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0))
-
-    h_last = jnp.zeros((cfg.d_model,), cfg.dtype)
-    *pool, h_last = lax.fori_loop(0, n_chunks, chunk_step, (*(kv_pages[name] for name in names), h_last))
-    h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
-    return _logits(params, h_last), dict(zip(names, pool))
-
-
-def _states_ride(attend, sp, tp):
-    """A KDA stack's softmax layer: the serving steps' K/V `attend`, handing
-    back the whole pool, the state leaves behind the K/V it wrote."""
-
-    def with_states(q, k, v):
-        o, kv = attend(q, k, v)
-        return o, (*kv, sp, tp)
-
-    return with_states
-
-
-def _kda_chunk_attend(cfg: TransformerConfig, slot, c0, valid, layer, kp, vp, sp, tp):
-    """forward_prefill's `attend` of one KDA layer (`layer`: its place in the
-    state pool sp / tp): the chunk at positions [c0, c0 + C) of the sequence
-    whose state slot is `slot`, from the state and tails the slot holds, or
-    from zeros where c0 is 0, whatever the slot's last owner left there;
-    `valid` [C]: the rows below the length. The K/V pool rides through."""
-    from ..ops import kda
-
-    def attend(q, k, v, g, beta, conv):
-        state_in = jnp.where(c0 > 0, sp[layer, slot], jnp.zeros((), sp.dtype))
-        tails_in = jnp.where(c0 > 0, tp[layer, slot], jnp.zeros((), tp.dtype))
-        q, k, v, tails_out = _kda_inputs(cfg, q[0], k[0], v[0], conv, tails_in, jnp.sum(valid))
-        with jax.named_scope("kda.chunk"):
-            o, state_out = kda.kda_chunk(q, k, v, g[0], beta[0], state_in, valid)
-        return o[None], (kp, vp, sp.at[layer, slot].set(state_out), tp.at[layer, slot].set(tails_out))
-
-    return attend
-
-
-def _state_chunk_attend(cfg: TransformerConfig, slot, c0, valid, layer, window, sp, zp):
-    """forward_prefill's `attend` of one layer under power retention: the
-    chunk at positions [c0, c0 + C) of the sequence whose state is page
-    `slot` of the pool sp / zp. It starts from what the slot holds, the
-    state after position c0 - 1, or from nothing where c0 is 0, whatever the
-    slot's last owner left there; `valid` [C]: the rows below the length."""
-
-    def attend(q, k, v, log_g):
-        s_in = jnp.where(c0 > 0, sp[layer, slot], 0.0)
-        z_in = jnp.where(c0 > 0, zp[layer, slot], 0.0)
-        with jax.named_scope("retention.chunk"):
-            y, s_out, z_out = retention_chunk(q[0], k[0], v[0], log_g[0], s_in, z_in, valid)
-        return y[None].astype(cfg.dtype), (sp.at[layer, slot].set(s_out), zp.at[layer, slot].set(z_out))
-
-    return attend
-
-
 def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int, window=None):
     """The plain XLA expression of prefill's chunk attention: gathers the
     WHOLE block table [P] out of one layer's pages kp / vp
@@ -1757,6 +1543,444 @@ def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
     return "paged_kernel" if can_tile(page_tokens, cfg.head_dim, cfg.dtype) else "xla_gather"
 
 
+# ---- the three kinds of layer, each with what a served sequence keeps of it
+#
+# A kind's three forms are `attend` factories of one calling convention.
+# `whole(cfg, mesh, where)`: a whole sequence that keeps nothing (training,
+# `forward`, `routing_stats`). `chunk(cfg, ctx)` (forward_prefill: one chunk of
+# one sequence) and `step(cfg, ctx)` (forward_decode: one token a row) are
+# called once, outside the layer scans, with the operands of the chunk or the
+# step (`ctx`: forward_prefill's block_table, dest_table, length, write_from,
+# slot, rows = a chunk's positions, page_tokens, c0 = the chunk's first
+# position; forward_decode's block_tables, pos, active, page_tokens), work out
+# what all the kind's layers share, and hand back `attend_in(where, pool) ->
+# attend` for each of them. `where`: the layer's `LayerPlace`; `pool`: the
+# WHOLE cache tree {leaf name: array}, of which a kind reads and writes its own
+# leaves, in place, and hands them back as `_block`'s `kept`, in its row's
+# `names` order (the walker puts them back into the tree). Some lines below
+# are held to their letter: benchmarks/tests/test_*_cell.py plant their faults
+# by replacing them (a chunk's `s_in` / `state_in` / `tails_in` and what it
+# stores, the pools' `"s": jnp.zeros(...)`).
+#
+# "softmax": K/V pages. A page holds `page_tokens` positions of one layer's k
+# or v, [layers, pages, page_tokens, n_kv_heads * head_dim]: tokens x (head,
+# dim) is the tile the paged kernels copy and multiply as it lies, so heads
+# and dim are ONE axis of the stored array (split, the device's tiled layout
+# would put heads where the kernel needs tokens, and every step would pay a
+# relayout of the pool). A sequence's block table grows by a page as it
+# fills, and a full page of one prompt may serve another.
+
+
+def _kv_leaves(cfg: TransformerConfig, num_pages: int, page_tokens: int):
+    shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
+    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _kv_chunk(cfg: TransformerConfig, ctx):
+    """The chunk's rows write their k/v into the pages `block_table` names and
+    attend causally over positions [0, chunk end) of the table's pages. Whole
+    pages are written (a page is one contiguous tile of the pool; a token row
+    cuts through 32 of them): every page of the chunk that holds a position in
+    [write_from, length); those below are another owner's and go to the trash
+    page. The rest of the prompt's last page receives the padding's k/v, which
+    nothing reads (attention stops at the length and decode overwrites
+    position by position)."""
+    from ..ops.paged_attention import paged_prefill_attention
+
+    T, c0, block_table = ctx["page_tokens"], ctx["c0"], ctx["block_table"]
+    pages = ctx["rows"] // T
+    first = c0 + jnp.arange(pages) * T
+    writable = (first + T > ctx["write_from"]) & (first < ctx["length"])
+    dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(ctx["dest_table"], c0 // T, pages), TRASH_PAGE)
+    use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+
+    def attend_in(where: LayerPlace, pool):
+        layer, window, kp, vp = where.layer, where.window, pool["k"], pool["v"]
+
+        def attend(q, k, v):
+            kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
+            vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
+            # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
+            with _window_scope(window):
+                if use_kernel:
+                    o = paged_prefill_attention(q[0], kp_, vp_, layer, block_table, c0, ctx["length"], n_kv_heads=cfg.n_kv_heads, window=window)
+                else:
+                    o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window)
+            return o[None].astype(cfg.dtype), (kp_, vp_)
+
+        return attend
+
+    return attend_in
+
+
+def _kv_step(cfg: TransformerConfig, ctx):
+    """Each active row appends its k/v at its position (an inactive one to
+    the trash page) and attends over positions [0, pos] of the pages its
+    block table names."""
+    from ..ops.paged_attention import paged_attention
+
+    T, pos, active, block_tables = ctx["page_tokens"], ctx["pos"], ctx["active"], ctx["block_tables"]
+    B = pos.shape[0]
+    rows = jnp.arange(B)
+    dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
+    dest_slot = pos % T
+    lengths = jnp.where(active, pos + 1, 0)
+    use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+
+    def attend_in(where: LayerPlace, pool):
+        layer, window, kp, vp = where.layer, where.window, pool["k"], pool["v"]
+
+        def attend(q, k, v):
+            kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
+            vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
+            # Attend AFTER the append so the new position attends to itself.
+            with _window_scope(window):
+                if use_kernel:
+                    o = paged_attention(q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window)
+                else:
+                    o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window)
+            return o.astype(cfg.dtype), (kp_, vp_)
+
+        return attend
+
+    return attend_in
+
+
+# "retention": a page is ONE sequence's whole recurrent state, float32, of a
+# fixed size whatever its length (the layout: see `retention_phi`): `s`
+# [layers, pages, n_kv_heads, head_dim, D] and `z` [layers, pages, n_kv_heads,
+# D]. `page_tokens` only bounds the positions a sequence may be served (it
+# shapes nothing); the block table is one entry, the sequence's one page for
+# its life, and `write_from` is the position its state has reached: a
+# prefill's chunks (PREFILL_CHUNK_TOKENS rows inside the one page) start
+# exactly there, each from the state the one before it left in the page, the
+# one at position 0 from nothing whatever the page held; rows past `length`
+# leave the state as it was. Nothing of a state is a prefix of another prompt.
+
+
+def _retention_leaves(cfg: TransformerConfig, num_pages: int, page_tokens: int):
+    D = retention_state_dim(cfg.head_dim)
+    return {
+        "s": jnp.zeros((cfg.n_layers, num_pages, cfg.n_kv_heads, cfg.head_dim, D), jnp.float32),
+        "z": jnp.zeros((cfg.n_layers, num_pages, cfg.n_kv_heads, D), jnp.float32),
+    }
+
+
+def _retention_chunk(cfg: TransformerConfig, ctx):
+    """The chunk at positions [c0, c0 + rows) of the sequence whose state is
+    page `slot`. It starts from what the page holds, the state after
+    position c0 - 1, or from nothing where c0 is 0, whatever the page's last
+    owner left there; `valid` [rows]: the rows below the length."""
+    slot, c0 = ctx["block_table"][0], ctx["c0"]
+    valid = c0 + jnp.arange(ctx["rows"]) < ctx["length"]
+
+    def attend_in(where: LayerPlace, pool):
+        layer, sp, zp = where.layer, pool["s"], pool["z"]
+
+        def attend(q, k, v, log_g):
+            s_in = jnp.where(c0 > 0, sp[layer, slot], 0.0)
+            z_in = jnp.where(c0 > 0, zp[layer, slot], 0.0)
+            with jax.named_scope("retention.chunk"):
+                y, s_out, z_out = retention_chunk(q[0], k[0], v[0], log_g[0], s_in, z_in, valid)
+            return y[None].astype(cfg.dtype), (sp.at[layer, slot].set(s_out), zp.at[layer, slot].set(z_out))
+
+        return attend
+
+    return attend_in
+
+
+def _retention_step(cfg: TransformerConfig, ctx):
+    """Row b's state is page slots[b] (the trash page for a row that is not
+    active); each is decayed, takes its token and is read, in place. The
+    one-pass kernel where it can tile the state, else the plain expression
+    over a gathered copy."""
+    from ..ops.power_retention import can_tile, power_retention_decode
+
+    active = ctx["active"]
+    slots = jnp.where(active, ctx["block_tables"][:, 0], TRASH_PAGE)
+
+    def attend_in(where: LayerPlace, pool):
+        layer, sp, zp = where.layer, pool["s"], pool["z"]
+
+        def attend(q, k, v, log_g):
+            q, k, v, log_g = q[:, 0], k[:, 0], v[:, 0], log_g[:, 0]
+            if can_tile(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim):
+                y, sp_, zp_ = power_retention_decode(q, k, v, log_g, sp, zp, layer, slots, active, eps=RETENTION_EPS)
+            else:
+                y, s_new, z_new = retention_step(q, k, v, log_g, sp[layer, slots], zp[layer, slots])
+                sp_, zp_ = sp.at[layer, slots].set(s_new), zp.at[layer, slots].set(z_new)
+            return y[:, None].astype(cfg.dtype), (sp_, zp_)
+
+        return attend
+
+    return attend_in
+
+
+def _retention_decode_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    from ..ops.power_retention import can_tile
+
+    return "retention_kernel" if can_tile(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) else "xla_step"
+
+
+# "kda": a state SLOT. Of every KDA layer a sequence keeps `s` [layers, slots,
+# n_heads, head_dim, head_dim] float32, a head's state, and `tail` [layers,
+# slots, *_tail_shape], the 3 x (kda_conv - 1) rows of the q, k and v
+# projections that the short convolution still needs (q's, k's, v's, each
+# oldest first), in the parameters' type: of a fixed size, in ONE slot for the
+# sequence's life, which no allocator hands out: decode row i's is slot i + 1
+# (a decode row IS the engine's slot, given at admission), a prefill writes
+# the slot it is told (`slot`; the trash slot 0 from a caller that names
+# none), each chunk from what the one before it left there and the one at
+# position 0 from zeros WITHOUT reading what the slot held. A state at a
+# page's border is not kept, so nothing of it is a prefix: write_from is 0.
+
+
+def _kda_leaves(cfg: TransformerConfig, slots: int, page_tokens: int):
+    n_kda = cfg.n_layers
+    if slots < 2:
+        raise ValueError("a KDA stack's pool has a trash slot and at least one state slot: state_slots >= 2")
+    return {
+        "s": jnp.zeros((n_kda, slots, cfg.n_heads, cfg.head_dim, cfg.head_dim), jnp.float32),
+        "tail": jnp.zeros((n_kda, slots, *_tail_shape(cfg)), cfg.dtype),
+    }
+
+
+def _kda_chunk(cfg: TransformerConfig, ctx):
+    from ..ops import kda
+
+    slot, c0 = ctx["slot"], ctx["c0"]
+
+    def attend_in(where: LayerPlace, pool):
+        layer, sp, tp = where.layer, pool["s"], pool["tail"]
+        valid = c0 + jnp.arange(ctx["rows"]) < ctx["length"]  # the rows below the length
+
+        def attend(q, k, v, g, beta, conv):
+            state_in = jnp.where(c0 > 0, sp[layer, slot], jnp.zeros((), sp.dtype))
+            tails_in = jnp.where(c0 > 0, tp[layer, slot], jnp.zeros((), tp.dtype))
+            q, k, v, tails_out = _kda_inputs(cfg, q[0], k[0], v[0], conv, tails_in, jnp.sum(valid))
+            with jax.named_scope("kda.chunk"):
+                o, state_out = kda.kda_chunk(q, k, v, g[0], beta[0], state_in, valid)
+            return o[None], (sp.at[layer, slot].set(state_out), tp.at[layer, slot].set(tails_out))
+
+        return attend
+
+    return attend_in
+
+
+def _kda_step(cfg: TransformerConfig, ctx):
+    """Each row takes its token through the short convolutions and the delta
+    rule and is read, in place. The one-pass kernel where it can tile the
+    state, else the plain expression over a gathered copy."""
+    from ..ops import kda
+
+    active = ctx["active"]
+
+    def attend_in(where: LayerPlace, pool):
+        layer, sp, tp = where.layer, pool["s"], pool["tail"]
+        slots = jnp.where(active, jnp.arange(active.shape[0]) + 1, TRASH_PAGE)
+
+        def attend(q, k, v, g, beta, conv):
+            ins = jax.vmap(partial(_kda_inputs, cfg), in_axes=(0, 0, 0, None, 0))(q, k, v, conv, tp[layer, slots])
+            q, k, v = (t[:, 0] for t in ins[:3])
+            with jax.named_scope("kda.step"):
+                if kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim):
+                    o, sp_ = kda.kda_decode(q, k, v, g[:, 0], beta[:, 0], sp, layer, slots, active)
+                else:
+                    o, s_new = kda.kda_step(q, k, v, g[:, 0], beta[:, 0], sp[layer, slots])
+                    sp_ = sp.at[layer, slots].set(s_new)
+            return o[:, None], (sp_, tp.at[layer, slots].set(ins[3]))
+
+        return attend
+
+    return attend_in
+
+
+def _kda_decode_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    from ..ops import kda
+
+    return "kda_kernel" if kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim) else "xla_step"
+
+
+class LayerKind(NamedTuple):
+    """A row of `KINDS`: what a served sequence keeps of a layer of this kind
+    and the layer's three forms. A new kind is a row here, its kernels and
+    plain expressions under ops/, its architecture file under
+    benchmarks/archs/, and a `stack_plan` that places it."""
+
+    names: Tuple[str, ...]  # its cache leaves, in the pool's order
+    indexed: str  # what their second axis counts: "page" (PagedKVAllocator hands them out; a block table names them) | "slot" (one a decode row)
+    state: bool  # a recurrent state of a fixed size (nothing of it is kept at a page's border), not the positions' k/v
+    leaves: Callable  # (cfg with n_layers the layers of this kind, pages or slots, page_tokens) -> {name: zeros}
+    whole: Callable
+    chunk: Callable
+    step: Callable
+    decode_path: Callable  # (cfg, page_tokens) -> which expression `step` runs, for PagedLM.describe
+
+
+KINDS = {
+    "softmax": LayerKind(("k", "v"), "page", False, _kv_leaves, _softmax_whole, _kv_chunk, _kv_step, paged_attention_path),
+    "retention": LayerKind(("s", "z"), "page", True, _retention_leaves, _retention_whole, _retention_chunk, _retention_step, _retention_decode_path),
+    "kda": LayerKind(("s", "tail"), "slot", True, _kda_leaves, _kda_whole, _kda_chunk, _kda_step, _kda_decode_path),
+}
+
+
+class CacheLayout(NamedTuple):
+    """What a served sequence keeps of its past under one config, from
+    `stack_plan` and `KINDS`: the one place that says so. `init_kv_pages`,
+    the paged forwards and serve/llm's PagedLM read it."""
+
+    kinds: Tuple[Tuple[str, int], ...]  # (kind, layers of it), in the order the stack first has them
+    names: Tuple[str, ...]  # the pool's leaves in their order: the order they ride the scans in
+    indexed: Dict[str, str]  # leaf -> "page" | "slot"
+    state: bool  # a sequence keeps a recurrent state: then no full page of one prompt may serve another
+    kv: bool  # a page holds `page_tokens` positions, so that a block table grows with its sequence
+
+
+@functools.lru_cache(maxsize=None)
+def cache_layout(cfg: TransformerConfig) -> CacheLayout:
+    layers: Dict[str, int] = {}
+    for repeats, members in stack_plan(cfg):
+        for m in members:
+            layers[m.kind] = layers.get(m.kind, 0) + repeats * m.layers
+    rows = [KINDS[kind] for kind in layers]
+    indexed = {name: row.indexed for row in rows for name in row.names}
+    return CacheLayout(tuple(layers.items()), tuple(indexed), indexed, any(row.state for row in rows), not all(row.state for row in rows))
+
+
+def decode_paths(cfg: TransformerConfig, page_tokens: int) -> Dict[str, str]:
+    """Which expression a decode step runs over the pages (`decode_attention`)
+    and over the state slots (`decode_state`): PagedLM.describe."""
+    keys = {"page": "decode_attention", "slot": "decode_state"}
+    return {keys[KINDS[kind].indexed]: KINDS[kind].decode_path(cfg, page_tokens) for kind, _ in cache_layout(cfg).kinds}
+
+
+def init_kv_pages(
+    cfg: TransformerConfig, num_pages: int, page_tokens: int, state_slots: Optional[int] = None
+) -> Dict[str, jax.Array]:
+    """Allocates the paged pool, one tree whatever the model keeps:
+    `cache_layout(cfg)`'s leaves, each kind's over its own layers ALONE (a
+    KDA stack's `k` / `v` over its softmax layers, `s` / `tail` over its KDA
+    layers). forward_prefill and forward_decode take the tree and hand it
+    back under the same names. Page 0 is the trash page and slot 0 the trash
+    slot (`state_slots`, or `cfg.state_slots`: of the slot-indexed leaves)."""
+    pool = {}
+    for kind, layers in cache_layout(cfg).kinds:
+        row = KINDS[kind]
+        pool.update(row.leaves(cfg.replace(n_layers=layers), num_pages if row.indexed == "page" else state_slots or cfg.state_slots, page_tokens))
+    return pool
+
+
+def prefill_chunk_tokens(cfg: TransformerConfig, bucket_pages: int, page_tokens: int) -> Tuple[int, int]:
+    """(positions one chunk of forward_prefill computes, the granule its first
+    chunk is anchored to) for a bucket of that many pages. K/V pages: whole
+    pages (`prefill_chunk_pages`), anchored at a page's start. A state alone:
+    PREFILL_CHUNK_TOKENS positions inside its one page, anchored at the very
+    position the state has reached."""
+    if not cache_layout(cfg).kv:
+        return min(PREFILL_CHUNK_TOKENS, bucket_pages * page_tokens), 1
+    return prefill_chunk_pages(bucket_pages, page_tokens) * page_tokens, page_tokens
+
+
+def prefill_chunk_pages(bucket_pages: int, page_tokens: int) -> int:
+    """Pages of one chunk of forward_prefill for a bucket of that many
+    pages: PREFILL_CHUNK_TOKENS' worth, or the whole of a smaller bucket
+    (the largest divisor of the bucket not above it, so chunks tile it)."""
+    from ..ops.paged_attention import largest_divisor
+
+    return largest_divisor(bucket_pages, max(1, PREFILL_CHUNK_TOKENS // page_tokens))
+
+
+def prefill_chunk_span(length, write_from, chunk_tokens: int, page_tokens: int, minimum=min, maximum=max):
+    """(anchor, count) of the chunks forward_prefill computes: chunk i covers
+    positions [anchor + i * chunk_tokens, anchor + (i + 1) * chunk_tokens).
+    The anchor is where the cache ends: the page of `write_from`, so the
+    count is the uncached span in chunks, rounded up:
+    ceil((length - anchor) / chunk_tokens). The last position's chunk is
+    always computed, its logits are the result: where the cache holds the
+    whole prompt the anchor is the last position's page. Python ints (PagedLM
+    counts computed tokens with it), or traced scalars with jnp's minimum /
+    maximum."""
+    last = maximum(length - 1, 0)
+    anchor = minimum(write_from, last) // page_tokens * page_tokens
+    return anchor, (last - anchor) // chunk_tokens + 1
+
+
+def forward_prefill(
+    params: PyTree,
+    tokens: jax.Array,
+    cfg: TransformerConfig,
+    kv_pages: Dict[str, jax.Array],
+    block_table: jax.Array,
+    length: jax.Array,
+    write_from: jax.Array,
+    slot=TRASH_PAGE,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Prefill ONE sequence: computes what the cache does not hold and
+    writes it into the paged pool.
+
+    tokens [1, S] (padded to a bucket; pad is arbitrary token ids),
+    block_table [P] page indices covering positions [0, P*page_tokens),
+    length: scalar, true prompt length (<= S),
+    write_from: scalar, first position to COMPUTE: the pages below it
+      already hold this prompt's k/v (shared prefix pages the radix cache
+      matched, written by their owner) and are read, never recomputed into
+      and never rewritten. The radix cache shares whole pages, so it is a
+      multiple of the page; 0 is a miss, which runs the same loop.
+    slot: scalar, the sequence's state slot, of a model that keeps one
+      (`KINDS`; the trash slot from a caller that names none).
+
+    The uncached span is walked in chunks (`prefill_chunk_tokens`) with a
+    dynamic trip count (`prefill_chunk_span`): the first chunk starts
+    at write_from, wherever in the bucket that page lies, and the last is
+    the one that holds the last position, so the work is the uncached
+    suffix rounded up to chunks, not the bucket. A chunk passes through all
+    layers, each through its kind's `chunk` form (`KINDS`), which writes
+    what the layer keeps of it. No row below write_from is computed; the
+    last chunk may run past the bucket's end, over padding made here.
+
+    Returns (last-position logits [1, vocab] fp32, updated kv_pages).
+    """
+    layout = cache_layout(cfg)
+    _, S = tokens.shape
+    T = kv_pages["k"].shape[2] if layout.kv else S // block_table.shape[0]
+    C, granule = prefill_chunk_tokens(cfg, S // T, T)
+    # What a chunk slices is padded by a chunk: the last one starts at a
+    # page below the length, not at a multiple of C, and a dynamic slice
+    # that ran past the end would be moved back silently.
+    cos_t, sin_t = rope_tables(cfg, S + C)
+    tokens = jnp.pad(tokens, ((0, 0), (0, C)))
+    # A table that pages positions is sliced a chunk at a time, so it too is padded by a chunk.
+    dest_table = jnp.pad(block_table, (0, C // T), constant_values=TRASH_PAGE) if layout.kv else None
+    call = dict(block_table=block_table, dest_table=dest_table, length=length, write_from=write_from, slot=slot, rows=C, page_tokens=T)
+    anchor, n_chunks = prefill_chunk_span(length, write_from, C, granule, jnp.minimum, jnp.maximum)
+
+    def chunk_step(i, carry):
+        *pool, _ = carry
+        c0 = anchor + i * C
+        cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
+        sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
+        x = _embed(params, lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), cfg)
+        ctx = dict(call, c0=c0)
+        attend_in = {kind: KINDS[kind].chunk(cfg, ctx) for kind, _ in layout.kinds}
+
+        # The pool rides both loops as a carry, written in place (as in
+        # forward_decode): no copy of it is made a layer or a chunk.
+        def layer(kind, carry, where, layer_params, experts):
+            x, pool = carry[0], dict(zip(layout.names, carry[1:]))
+            x, own = _block(x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), attend_in[kind](where, pool), experts=experts, kind=kind)
+            pool.update(zip(KINDS[kind].names, own))
+            return (x, *pool.values()), None
+
+        (x, *pool), _ = _walk_stack(params, cfg, layer, (x, *pool), in_place=True)
+        # the last position's row, if this is its chunk (the final one is)
+        return (*pool, jnp.take(x[0], jnp.clip(length - 1 - c0, 0, C - 1), axis=0))
+
+    h_last = jnp.zeros((cfg.d_model,), cfg.dtype)
+    *pool, h_last = lax.fori_loop(0, n_chunks, chunk_step, (*(kv_pages[name] for name in layout.names), h_last))
+    h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
+    return _logits(params, h_last), dict(zip(layout.names, pool))
+
+
 def forward_decode(
     params: PyTree,
     tokens: jax.Array,
@@ -1766,160 +1990,66 @@ def forward_decode(
     block_tables: jax.Array,
     stats: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step for the whole slot batch, paged attention.
+    """One decode step for the whole slot batch, each layer through its
+    kind's `step` form (`KINDS`).
 
     tokens [B] int32 (last emitted token per slot; ignored when inactive),
     positions [B] int32 (index the new token occupies; -1 => inactive slot),
     block_tables [B, P] page indices per slot (trash page for unused rows).
 
-    Appends each active slot's k/v at `positions`, attends over positions
-    [0, pos], returns (logits [B, vocab] fp32, updated kv_pages). Inactive
-    slots write to the trash page and produce garbage logits the scheduler
-    ignores. Shapes are static in B/P/N: one jit serves every batch mix.
+    Every active row takes its token at `positions` into what it keeps (its
+    pages, its state) and attends over positions [0, pos]; returns (logits
+    [B, vocab] fp32, updated kv_pages). Inactive slots write to the trash
+    page or slot and produce garbage logits the scheduler ignores. Shapes
+    are static in B/P/N: one jit serves every batch mix.
     With `stats`, a third: {"experts_touched": int32 scalar}, the distinct
     experts that the step's B rows chose, summed over the routed layers
     (each is a matrix triple the step has to read); 0 for a dense model.
-    Under power retention block_tables is [B, 1], each row's state slot: a
-    step decays, adds to and reads each active row's state of every layer in
-    place (`_state_step_attend`); inactive rows use the trash slot.
-    A KDA stack (`cfg.kda_per_period`) steps both caches: its softmax layers
-    append to and read the pages of `block_tables`, and row i's KDA state and
-    tails are state slot i + 1 of the pool (a decode row IS the engine's slot,
-    given at admission and kept for the sequence's life; inactive rows use
-    the trash slot 0): `_kda_step_attend`. With `stats` under a share of the
-    experts (`cfg.n_experts_held`), `experts_touched` counts the held ones
-    and a second counter, `held_picks`, how many of the rows' choices (B x
-    n_experts_per_tok a routed layer) fell on them.
+    Under a share of the experts (`cfg.n_experts_held`) it counts the held
+    ones, and a second counter, `held_picks`, how many of the rows' choices
+    (B x n_experts_per_tok a routed layer) fell on them.
     """
-    from ..ops.paged_attention import paged_attention
-
-    B = tokens.shape[0]
-    state, hybrid = bool(cfg.retention_degree), bool(cfg.kda_per_period)
-    names = ("s", "z") if state else ("k", "v", "s", "tail") if hybrid else ("k", "v")
+    layout = cache_layout(cfg)
     P = block_tables.shape[1]
     active = positions >= 0
     pos = jnp.maximum(positions, 0)
 
-    if state:  # no page says how many positions there are: the angles of each row's own
-        cos, sin = (t[:, None, :] for t in rope_at(cfg, pos))
-    else:
+    if layout.kv:
         T = kv_pages["k"].shape[2]
         cos_t, sin_t = rope_tables(cfg, P * T)
         cos = jnp.take(cos_t, pos, axis=0)[:, None, :]  # [B, 1, rd/2]: each row its own position
         sin = jnp.take(sin_t, pos, axis=0)[:, None, :]
+    else:  # no page says how many positions there are: the angles of each row's own
+        T = None
+        cos, sin = (t[:, None, :] for t in rope_at(cfg, pos))
 
     x = _embed(params, tokens, cfg)[:, None, :]  # [B,1,d]
-    if state:
-        attend_in = partial(_state_step_attend, cfg, jnp.where(active, block_tables[:, 0], TRASH_PAGE), active)
-    else:
-        rows = jnp.arange(B)
-        dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
-        dest_slot = pos % T
-        lengths = jnp.where(active, pos + 1, 0)
-        use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+    ctx = dict(block_tables=block_tables, pos=pos, active=active, page_tokens=T)
+    attend_in = {kind: KINDS[kind].step(cfg, ctx) for kind, _ in layout.kinds}
 
-        def attend_in(layer, window, kp, vp):
-            def attend(q, k, v):
-                kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
-                vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
-                # Attend AFTER the append so the new position attends to itself.
-                with _window_scope(window):
-                    if use_kernel:
-                        o = paged_attention(
-                            q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window
-                        )
-                    else:
-                        o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window)
-                return o.astype(cfg.dtype), (kp_, vp_)
-
-            return attend
-
-    # The pool rides the layer scan as a CARRY: each layer appends into its
-    # own slice in place and the kernel reads the pool where it lies. As
+    # The pool rides the layer scans as a CARRY: each layer writes its own
+    # slice in place and the kernels read the pool where it lies. As
     # xs/ys every step would copy the whole pool out of the stacked array
     # and back in.
-    def scan_step(stack, first, carry, inputs):
-        x, *pool = carry
-        layer, window, rope_on, layer_params = inputs
-        x, pool, *rows_per_expert = _block(
-            x, layer_params, cfg, *_rope_switch(cos, sin, rope_on), attend_in(layer, window, *pool),
-            stats="experts" if stats else "", experts=None if stack is None else (stack, layer - first),
+    def layer(kind, carry, where, layer_params, experts):
+        x, pool = carry[0], dict(zip(layout.names, carry[1:]))
+        x, own, *rows_per_expert = _block(
+            x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), attend_in[kind](where, pool),
+            stats="experts" if stats else "", experts=experts, kind=kind,
         )
-        return (x, *pool), (rows_per_expert[0] if stats else None)
+        pool.update(zip(KINDS[kind].names, own))
+        return (x, *pool.values()), (rows_per_expert[0] if stats else None)
 
-    def period_step(kind, carry, index, layer_params, experts):
-        x, kp, vp, sp, tp = carry
-        if kind == "attn":
-            attend = _states_ride(attend_in(index, None, kp, vp), sp, tp)
-        else:
-            attend = _kda_step_attend(cfg, jnp.where(active, jnp.arange(B) + 1, TRASH_PAGE), active, index, kp, vp, sp, tp)
-        x, pool, *rows_per_expert = _block(x, layer_params, cfg, cos, sin, attend, stats="experts" if stats else "", experts=experts)
-        return (x, *pool), (rows_per_expert[0] if stats else None)
-
-    carry, touched, held_picks = (x, *(kv_pages[name] for name in names)), jnp.int32(0), jnp.int32(0)
+    (x, *pool), segments = _walk_stack(params, cfg, layer, (x, *(kv_pages[name] for name in layout.names)), in_place=True)
+    touched, held_picks = jnp.int32(0), jnp.int32(0)
     share = cfg.experts_held != cfg.n_experts
-    here = slice(cfg.first_expert, cfg.first_expert + cfg.experts_held)
-    if hybrid:
-        carry, rows_per_expert = _scan_periods(params, cfg, period_step, carry, in_place=True)
-        if stats:  # [periods, E] and [periods, kda_per_period, E]: the held experts' columns
-            rows = jnp.concatenate([t.reshape(-1, cfg.n_experts)[:, here] for t in rows_per_expert])
-            touched, held_picks = jnp.sum(rows > 0, dtype=jnp.int32), jnp.sum(rows, dtype=jnp.int32)
-    for blocks, first, n in () if hybrid else _layer_groups(params, cfg):
-        riding, stack = _experts_in_place(blocks)
-        carry, rows_per_expert = lax.scan(
-            partial(scan_step, stack, first), carry, (jnp.arange(first, first + n), *_per_layer(cfg, first, n), riding)
-        )
-        if rows_per_expert is not None:  # [n, E] of a routed group
-            if share:
-                rows_per_expert = rows_per_expert[:, here]
-                held_picks = held_picks + jnp.sum(rows_per_expert, dtype=jnp.int32)
-            touched = touched + jnp.sum(rows_per_expert > 0, dtype=jnp.int32)
-    x, *pool = carry
+    for rows_per_expert in (y for ys in segments for y in ys if y is not None):  # [layers, E], or [repeats, layers, E], of routed layers
+        if share:  # the held experts' columns
+            rows_per_expert = rows_per_expert[..., cfg.first_expert : cfg.first_expert + cfg.experts_held]
+            held_picks = held_picks + jnp.sum(rows_per_expert, dtype=jnp.int32)
+        touched = touched + jnp.sum(rows_per_expert > 0, dtype=jnp.int32)
     x = _norm(x, params["final_norm"]["scale"], cfg)
-    out = _logits(params, x[:, 0]), dict(zip(names, pool))
+    out = _logits(params, x[:, 0]), dict(zip(layout.names, pool))
     if stats and share:
         return (*out, {"experts_touched": touched, "held_picks": held_picks})
     return (*out, {"experts_touched": touched}) if stats else out
-
-
-def _kda_step_attend(cfg: TransformerConfig, slots, active, layer, kp, vp, sp, tp):
-    """forward_decode's `attend` of one KDA layer (`layer`: its place in the
-    state pool sp / tp): row b's state and tails are slot slots[b] (the trash
-    slot for a row that is not `active`); each takes its token through the
-    short convolutions and the delta rule and is read, in place. The one-pass
-    kernel where it can tile the state, else the plain expression over a
-    gathered copy. The K/V pool rides through."""
-    from ..ops import kda
-
-    def attend(q, k, v, g, beta, conv):
-        ins = jax.vmap(partial(_kda_inputs, cfg), in_axes=(0, 0, 0, None, 0))(q, k, v, conv, tp[layer, slots])
-        q, k, v = (t[:, 0] for t in ins[:3])
-        with jax.named_scope("kda.step"):
-            if kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim):
-                o, sp_ = kda.kda_decode(q, k, v, g[:, 0], beta[:, 0], sp, layer, slots, active)
-            else:
-                o, s_new = kda.kda_step(q, k, v, g[:, 0], beta[:, 0], sp[layer, slots])
-                sp_ = sp.at[layer, slots].set(s_new)
-        return o[:, None], (kp, vp, sp_, tp.at[layer, slots].set(ins[3]))
-
-    return attend
-
-
-def _state_step_attend(cfg: TransformerConfig, slots, active, layer, window, sp, zp):
-    """forward_decode's `attend` of one layer under power retention: row b's
-    state is page slots[b] of the pool sp / zp (the trash page for a row
-    that is not `active`); each is decayed, takes its token and is read, in
-    place. The one-pass kernel where it can tile the state, else the plain
-    expression over a gathered copy."""
-    from ..ops.power_retention import can_tile, power_retention_decode
-
-    def attend(q, k, v, log_g):
-        q, k, v, log_g = q[:, 0], k[:, 0], v[:, 0], log_g[:, 0]
-        if can_tile(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim):
-            y, sp_, zp_ = power_retention_decode(q, k, v, log_g, sp, zp, layer, slots, active, eps=RETENTION_EPS)
-        else:
-            y, s_new, z_new = retention_step(q, k, v, log_g, sp[layer, slots], zp[layer, slots])
-            sp_, zp_ = sp.at[layer, slots].set(s_new), zp.at[layer, slots].set(z_new)
-        return y[:, None].astype(cfg.dtype), (sp_, zp_)
-
-    return attend

@@ -199,25 +199,24 @@ class PagedLM:
     block tables in. Greedy sampling runs inside the jit (argmax) so only
     int32 tokens cross the host boundary per step.
 
-    What a page is depends on the model's cache. Softmax attention: a page
-    holds `page_tokens` positions of K/V, a sequence's block table grows by
-    a page as it fills, and full prompt pages are shared by prefix. Power
-    retention (`cfg.retention_degree`): a page is ONE sequence's whole
-    recurrent state, of a fixed size whatever its length; `page_tokens` is
-    the positions a sequence may reach, `max_pages_per_seq` is 1, so the
-    allocator hands a sequence exactly one page for its life, `pages[0]` /
-    `block_tables[i][0]` is its state's slot, and nothing of it is shared:
-    every prompt is computed whole (`shares_prefix_pages` is False, which
-    the engine asks of a model that has it). A KDA stack
-    (`cfg.kda_per_period`) has both: K/V pages of its softmax layers, handed
-    out by the allocator as for any softmax model, and a fixed state a KDA
-    layer (a float32 matrix a head and the short convolution's tail) in one
-    of `max_slots + 1` state slots, which no allocator hands out: decode row
-    i's state is slot i + 1, a prefill writes the slot of the row the engine
+    What a page is depends on what the model's layers keep, and
+    `transformer.cache_layout(cfg)` (`self.layout`) is the one place that
+    says: this class names no model family. K/V pages (`layout.kv`): a page
+    holds `page_tokens` positions, a sequence's block table grows by a page
+    as it fills, and full prompt pages are shared by prefix. A recurrent
+    state (`layout.state`) is of a fixed size whatever the sequence's length
+    and nothing of it is kept at a page's border, so such a model shares
+    nothing: every prompt is computed whole (`shares_prefix_pages` is False,
+    which the engine asks of a model that has it). Where a state is all the
+    model keeps, a page is ONE sequence's whole state: `page_tokens` is the
+    positions a sequence may reach, `max_pages_per_seq` is 1, so the
+    allocator hands a sequence exactly one page for its life and `pages[0]` /
+    `block_tables[i][0]` names it. Leaves indexed by slot (`layout.indexed`)
+    lie in `max_slots + 1` state slots, which no allocator hands out: decode
+    row i's is slot i + 1, a prefill writes the slot of the row the engine
     admitted its prompt to (`PromptTokens.slot`), and slot 0 is the trash
     slot, which a caller's bare list writes exactly as its pages are the
-    trash page. A page's K/V could be shared by prefix but the state at its
-    border is not kept, so such a model shares nothing either.
+    trash page.
     """
 
     def __init__(
@@ -250,15 +249,18 @@ class PagedLM:
         self.page_tokens = page_tokens
         self.max_slots = max_slots
         self.max_pages_per_seq = max_pages_per_seq
-        self.state_cache = bool(cfg.retention_degree)
-        self.hybrid_cache = bool(cfg.kda_per_period)
-        if self.state_cache and max_pages_per_seq != 1:
-            raise ValueError(f"a retention model's page is a sequence's whole state: max_pages_per_seq is 1, not {max_pages_per_seq}")
-        self.kv = tfm.init_kv_pages(cfg, num_pages, page_tokens, max_slots + 1)  # the slots: a KDA stack's alone
-        slotted = ("s", "tail") if self.hybrid_cache else ()
+        self.layout = layout = tfm.cache_layout(cfg)
+        # What the benchmark's readers know a cache by: describe()["cache"]["kind"], and the executables' names' suffix.
+        self.cache_kind, self._suffix = {
+            (False, True): ("kv_pages", ""), (True, False): ("state", "_state"), (True, True): ("state+kv_pages", "_hybrid"),
+        }[layout.state, layout.kv]
+        if not layout.kv and max_pages_per_seq != 1:
+            raise ValueError(f"a page of a model that keeps only a state is a sequence's whole state: max_pages_per_seq is 1, not {max_pages_per_seq}")
+        self.kv = tfm.init_kv_pages(cfg, num_pages, page_tokens, max_slots + 1)
+        slotted = [name for name, indexed in layout.indexed.items() if indexed == "slot"]
         # One page over all layers: page_tokens positions of K/V, or one sequence's whole state.
         self.page_bytes = sum(leaf.nbytes for name, leaf in self.kv.items() if name not in slotted) // num_pages
-        # A KDA stack's other cache: one sequence's states and tails over all its KDA layers.
+        # The slots' leaves: what one sequence keeps there over all their layers.
         self.state_bytes = sum(self.kv[name].nbytes for name in slotted) // (max_slots + 1)
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
@@ -278,46 +280,32 @@ class PagedLM:
     @property
     def shares_prefix_pages(self) -> bool:
         """Whether a full page of one prompt may serve another (the engine asks)."""
-        return not (self.state_cache or self.hybrid_cache)
+        return not self.layout.state
 
     def describe(self) -> Dict[str, Any]:
         """Which process and devices serve this model, what its cache is
         (`cache`: "kv_pages", a page `page_tokens` positions of K/V, or
         "state", a page one sequence's whole recurrent state; the bytes of a
-        page over all layers either way; or "state+kv_pages", a KDA stack's
-        two: `page_bytes` of a page of its softmax layers' K/V and
-        `state_bytes` of one sequence's fixed states and tails over its KDA
-        layers), which expression the decode and
-        prefill executables attend with ("paged_kernel" or "xla_gather":
-        transformer.paged_attention_path; a state model's decode
-        "retention_kernel" or "xla_step": ops/power_retention.can_tile; a KDA
-        stack's `decode_state`: "kda_kernel" or "xla_step": ops/kda.can_tile), and
+        page over all layers either way; or "state+kv_pages", both:
+        `page_bytes` of a page of K/V and `state_bytes` of what one sequence
+        keeps in its state slot), which expression the decode and prefill
+        executables run over the pages (`decode_attention`: "paged_kernel" or
+        "xla_gather", transformer.paged_attention_path; "retention_kernel" or
+        "xla_step" over a state) and over the state slots (`decode_state`:
+        "kda_kernel" or "xla_step"): each kind's own answer, `KINDS`), and
         what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
 
         devs = self._jax.devices()
-        if self.state_cache:
-            from ...ops.power_retention import can_tile
-
-            attention = "retention_kernel" if can_tile(self.cfg.n_heads, self.cfg.n_kv_heads, self.cfg.head_dim) else "xla_step"
-        else:
-            attention = self._tfm.paged_attention_path(self.cfg, self.page_tokens)
-        cache = {"kind": "state" if self.state_cache else "kv_pages", "page_bytes": self.page_bytes}
-        extra = {}
-        if self.hybrid_cache:
-            from ...ops import kda
-
-            cache = {"kind": "state+kv_pages", "state_bytes": self.state_bytes, "page_bytes": self.page_bytes}
-            tiles = kda.can_tile(self.cfg.n_heads, self.cfg.head_dim, self.cfg.head_dim)
-            extra = {"decode_state": "kda_kernel" if tiles else "xla_step"}
+        paths = self._tfm.decode_paths(self.cfg, self.page_tokens)
+        cache = {"kind": self.cache_kind, **({"state_bytes": self.state_bytes} if self.state_bytes else {}), "page_bytes": self.page_bytes}
         return {
             "pid": os.getpid(),
             "platform": devs[0].platform,
             "device_kind": devs[0].device_kind,
             "device_count": len(devs),
             "cache": cache,
-            "decode_attention": attention,
-            **extra,
+            **paths,
             "peak_bytes_in_use": [
                 (d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs
             ],
@@ -351,8 +339,8 @@ class PagedLM:
 
             # The executable's name in a device trace (line `XLA Modules`:
             # jit_llm_decode), where every jitted closure called `step` reads alike.
-            # A state model's executables, and a KDA stack's, under names of their own.
-            step.__name__ = "llm_decode_state" if self.state_cache else "llm_decode_hybrid" if self.hybrid_cache else "llm_decode"
+            # Each cache layout's executables under names of their own (llm_decode_state, llm_decode_hybrid).
+            step.__name__ = "llm_decode" + self._suffix
             self._decode_jit = self._jax.jit(step, donate_argnums=self._donate((3,)))
         return self._decode_jit
 
@@ -367,9 +355,8 @@ class PagedLM:
                 )
                 return self._jnp.argmax(logits[0], axis=-1).astype(self._jnp.int32), kv
 
-            # jit_llm_prefill_p<pages>, a bucket a name (a state model's: jit_llm_prefill_state_p1; a KDA stack's: _hybrid_p<pages>)
-            kind = "llm_prefill_state_p" if self.state_cache else "llm_prefill_hybrid_p" if self.hybrid_cache else "llm_prefill_p"
-            step.__name__ = kind + str(n_pages_bucket)
+            # jit_llm_prefill_p<pages>, a bucket a name (jit_llm_prefill_state_p1, jit_llm_prefill_hybrid_p<pages>)
+            step.__name__ = f"llm_prefill{self._suffix}_p{n_pages_bucket}"
             fn = self._jax.jit(step, donate_argnums=self._donate((2,)))
             self._prefill_jits[n_pages_bucket] = fn
         return fn
@@ -450,11 +437,11 @@ class PagedLM:
         n_pages = max(1, -(-len(prompt) // T))
         bucket = self._bucket_pages(n_pages)
         S = bucket * T
-        if (self.state_cache or self.hybrid_cache) and cached_tokens:
+        if self.layout.state and cached_tokens:
             raise ValueError("a model with a recurrent state keeps none at a page's border, so no prefix is shared: cached_tokens is 0")
-        # A KDA stack: the state slot of the decode row the engine admitted this prompt to; the trash slot for a bare list.
+        # Leaves in state slots: the slot of the decode row the engine admitted this prompt to; the trash slot for a bare list.
         row = getattr(prompt, "slot", None)
-        slot = (np.int32(TRASH_PAGE if row is None else row + 1),) if self.hybrid_cache else ()
+        slot = (np.int32(TRASH_PAGE if row is None else row + 1),) if "slot" in self.layout.indexed.values() else ()
         chunk, granule = self._tfm.prefill_chunk_tokens(self.cfg, bucket, T)
         _anchor, chunks = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk, granule)
         attrs = {"bucket_tokens": S, "computed_tokens": chunks * chunk}
@@ -479,8 +466,8 @@ class PagedLM:
             getattr(prompt, "launched", None),  # the engine's PromptTokens; a bare list from anyone else
         )
         counters = {}
-        if self.state_cache or self.hybrid_cache:
-            # A state model: the chunks that started from the state their predecessor left (all but a prompt's first).
+        if self.layout.state:
+            # The chunks that started from the state their predecessor left (all but a prompt's first).
             counters["prefill_state"] = {"chunks": chunks, "carried_in": chunks - 1}
         if self.cfg.n_experts:
             # A routed model: the rows its routed layers' experts were handed, and those of them sorted to their own experts.
@@ -541,13 +528,12 @@ class PagedLM:
                 "kv_read": int(np.minimum(live[None, :], reach[:, None]).sum()),
                 "kv_live": int(cfg.n_layers * live.sum()),
             }
-        if self.state_cache or self.hybrid_cache:
-            # Every live row's state of every layer is read once and written once.
+        if self.layout.state:
+            # Every live row's state of every layer is read once and written once: its slot's, or its one page.
             live = int((pos >= 0).sum())
-            one = self.state_bytes if self.hybrid_cache else self.page_bytes
-            counters["decode_state"] = {"bytes": 2 * live * one, "live_slots": live, "steps": 1}
-        if self.hybrid_cache:
-            # The K/V the live rows' softmax layers read: every position up to their own, a page's bytes / page_tokens each.
+            counters["decode_state"] = {"bytes": 2 * live * (self.state_bytes or self.page_bytes), "live_slots": live, "steps": 1}
+        if self.layout.state and self.layout.kv:
+            # The K/V the live rows read beside their states: every position up to their own, a page's bytes / page_tokens each.
             kv_tokens = int((pos[pos >= 0].astype(np.int64) + 1).sum())
             counters["decode_kv"] = {"bytes": kv_tokens * self.page_bytes // self.page_tokens, "tokens": kv_tokens, "steps": 1}
         return DecodeTokens(tokens, counters) if counters else tokens

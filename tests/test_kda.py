@@ -403,3 +403,32 @@ def test_counts_at_the_published_widths():
     assert abs((solar_open2.matmul_params(whole) + 196608 * 4096) / 1e9 - 250.3) < 0.2
     # the program's count takes the embedding for a matmul (6 N), the architecture file's does not (a gather)
     assert abs((tfm.flops_per_token(cfg, 4096) - 6 * 24576 * 4096) / solar_open2.train_flops_per_token(config, 4096) - 1) < 0.01
+
+
+def test_routing_stats_walks_a_kda_stack_in_published_order_and_agrees_with_the_references_router():
+    """`routing_stats` walks the stack's plan like every other forward: a KDA
+    stack's routers come back [n_layers, ...] in published order (the softmax
+    layer of a period, then its KDA layers), every (token, choice) pair
+    counted, the same experts as the plain reference's router chooses on the
+    reference's own hidden states."""
+    cfg, params = seeded(3)
+    n, k, m = 24, cfg.n_experts_per_tok, solar_open2.dims(CONFIG)
+    tokens = tokens_of(3, n)
+    stats = tfm.routing_stats(params, tokens[None], cfg)
+    assert stats["experts"].shape == (cfg.n_layers, n, k) and stats["gap"].shape == (cfg.n_layers, n)
+    np.testing.assert_array_equal(np.asarray(stats["tokens_per_expert"]).sum(-1), np.full(cfg.n_layers, n * k))
+    x = params["embed"]["embedding"][tokens].astype(jnp.float32)
+    for layer in range(cfg.n_layers):
+        p, j = divmod(layer, m["per"])
+        group, index = ("blocks", (p,)) if j == 0 else ("kda_blocks", (p, j - 1))
+        stacks = {name: params[group]["mlp"][name] for name in tfm.EXPERT_WEIGHTS}
+        w = jax.tree_util.tree_map(lambda a: a[index], dict(params[group], mlp={name: a for name, a in params[group]["mlp"].items() if name not in stacks}))
+        mixer = solar_open2._kda_mixer if j else solar_open2._gqa_mixer
+        mid = x + mixer(solar_open2._rms_norm(x, w["attn_norm"]["scale"], m["eps"]), w["attn"], m)
+        chosen = np.asarray(solar_open2._router_weights(solar_open2._rms_norm(mid, w["mlp_norm"]["scale"], m["eps"]), w["mlp"], m) > 0)
+        got = np.zeros_like(chosen)
+        np.put_along_axis(got, np.asarray(stats["experts"][layer]), True, axis=-1)
+        clear = np.asarray(stats["gap"][layer]) > 1e-5  # a token whose k-th and (k+1)-th scores all but tie may fall either way
+        assert clear.sum() >= n - 2
+        np.testing.assert_array_equal(got[clear], chosen[clear], err_msg=f"layer {layer}")
+        x = solar_open2._layer(x, w, m, stacks, index)

@@ -13,12 +13,15 @@ the largest difference 7e-6 on logits up to 4 in size.
 
 import functools
 import hashlib
+import math
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from benchmarks.archs import brumby
 from benchmarks.lib import correct, rehearsal, spec
@@ -28,6 +31,7 @@ from ray_tpu.ops import power_retention
 from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine
 from ray_tpu.serve.llm.kv_cache import PagedKVAllocator
 from ray_tpu.serve.llm.model import DecodeTokens, PagedLM
+from ray_tpu.train import zero
 
 TOLERANCE = 1e-4
 CHUNK = 16  # PREFILL_CHUNK_TOKENS in these tests: a 70-token prompt walks five chunks
@@ -375,13 +379,38 @@ def test_the_decode_kernel_in_interpret_mode_is_retention_step_in_place(heads):
 
 # ------------------------------------------- (f) what the other models keep
 
-# sha256 (first 16 hex digits) at the parent commit (PR 41) of each accepted configuration at its architecture's
-# TINY widths: init_params' leaves (paths, dtypes, numbers), and the lowered text of forward_decode and forward_prefill.
+# sha256 (first 16 hex digits), taken at the parent commit of PR 48 and PR 49 (28daf63, before the four forwards walked one plan),
+# of each configuration of BENCHMARK.json at its architecture's TINY widths: init_params' leaves (paths, dtypes, numbers),
+# and the lowered text of forward_decode, of forward_prefill, of forward_decode with `stats` as PagedLM asks it of a routed
+# model, of the whole-sequence `forward`, of the training loss's gradient where the configuration has a training cell, and
+# for Mistral of the ZeRO train step over four devices (the four-chip cell's program, where PR 48's benchmark run failed).
+# The first four cells' first three digests are what PR 42 recorded before the state's path existed.
 PARENT = {
-    "mistral7b-train-seq4k-1chip": ("00146abe9d7f8cbe", "5b205ddfdad764ec", "acca1b330f25d98a"),
-    "dsllm7b-serve-chat-steady": ("00146abe9d7f8cbe", "c7f2192c2a02f3b2", "114685abbe26da57"),
-    "olmoe-train-seq4k-1chip": ("9fc2e6536f721f59", "125d9f6811579117", "cd4027dbb914c125"),
-    "trinitymini-serve-agent-turns": ("dd98a4937de87222", "cd393207dca6cc51", "ec3e421d2af401fa"),
+    "mistral7b-train-seq4k-1chip": dict(
+        params="00146abe9d7f8cbe", decode="5b205ddfdad764ec", prefill="acca1b330f25d98a", forward="cab927e22fe03603", grad="90e3cc1409de622b",
+        zero_step="dc207b56dddcc81b"),
+    "dsllm7b-serve-chat-steady": dict(
+        params="00146abe9d7f8cbe", decode="c7f2192c2a02f3b2", prefill="114685abbe26da57", forward="1e18187b873812c1"),
+    "olmoe-train-seq4k-1chip": dict(
+        params="9fc2e6536f721f59", decode="125d9f6811579117", prefill="cd4027dbb914c125", decode_stats="dd2fac39bb166a76",
+        forward="e26fcc5eff7c9a50", grad="1b95257862785e87"),
+    "trinitymini-serve-agent-turns": dict(
+        params="dd98a4937de87222", decode="cd393207dca6cc51", prefill="ec3e421d2af401fa", decode_stats="46c3d2dbe563e6ee",
+        forward="bb34c9f1b88a8e6a"),
+    "brumby14b-serve-longgen-batch": dict(
+        params="0dbcfc63962591b0", decode="75b94718751bcdc8", prefill="3153248bbbc0868d", forward="304722bdd829cd50"),
+    "solaropen2-serve-reasoning-batch": dict(
+        params="fa24a5892e238707", decode="4940be13c58a600f", prefill="1e82441d7698dd38", decode_stats="19e20912f9c8350d",
+        forward="2c99e380bd5aba4c"),
+}
+
+# The digests that the refactor (PR 48's, landed by PR 49) itself moved, (cell, program) -> (the digest since, why), PARENT keeping what they were. For each,
+# the chip's optimised program was compared at published widths (v5e, compiled here; CHANGES.md, PR 49): but for the Mosaic
+# kernels' serialised bodies (transformer.py's line numbers) the same instructions in the same order, the same bytes.
+MOVED = {
+    ("trinitymini-serve-agent-turns", "decode"): ("41882a5b82c625bb", "the experts' index (layer - first) is taken before the layer's rope switch, as prefill took it: two scalar ops change places"),
+    ("trinitymini-serve-agent-turns", "decode_stats"): ("0f9d81c8ca7b3ff3", "as decode"),
+    ("solaropen2-serve-reasoning-batch", "decode_stats"): ("d44acfaf0881fb72", "the step's two counters are summed a member of a segment, as every other routed model's, not over one concatenation"),
 }
 
 
@@ -389,21 +418,92 @@ def _digest(text: bytes) -> str:
     return hashlib.sha256(text).hexdigest()[:16]
 
 
+def _zero_step_on_four_devices(cfg):
+    """The four-chip training cell's program (benchmarks/lib/worker_train.py: `build_train_step(..., zero_axis="data")`
+    over data=4), lowered on four of conftest's CPU devices: the step with its optimizer state sharded as
+    `zero.init_opt_state` shards it and the batch split over the axis."""
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    tx = optax.adamw(1e-3)
+    _init, step = tfm.build_train_step(cfg, tx, mesh, zero_axis="data", donate=False)
+    abstract = lambda tree: jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding), tree)  # noqa: E731
+    params = jax.device_put(tfm.init_params(jax.random.PRNGKey(0), cfg), NamedSharding(mesh, PartitionSpec()))
+    opt_state = zero.init_opt_state(tx, params, mesh, "data")
+    tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec("data")))
+    return step.lower(abstract(params), abstract(opt_state), tokens)
+
+
 @pytest.mark.parametrize("cell_name", sorted(PARENT))
 def test_the_accepted_architectures_draw_the_weights_and_lower_to_the_text_they_did(cell_name):
-    """`retention_degree` is off for every accepted configuration: `init_params`
-    draws the same leaves, and the paged executables lower to the same text,
-    letter for letter, as before the state's path existed."""
+    """A refactor of the forwards leaves every configuration's programs what
+    they were: `init_params` draws the same leaves, and the paged executables,
+    the whole-sequence forward and the training gradient lower to the same
+    text, letter for letter, as at the commit the digests were taken at. Each
+    cache with the operands it needs: a retention model's block table is one
+    page a sequence, a KDA stack's pool takes its state slots and its prefill
+    the sequence's slot."""
     cell = rehearsal.shrink(spec.find_cell(cell_name))
     cfg = cell.arch.model_config(cell.config)
-    assert cfg.retention_degree == 0
     h = hashlib.sha256()
     for path, leaf in jax.tree_util.tree_flatten_with_path(tfm.init_params(jax.random.PRNGKey(5), cfg))[0]:
         h.update(jax.tree_util.keystr(path).encode() + str(leaf.dtype).encode() + np.asarray(leaf.astype(jnp.float32)).tobytes())
-    T, N, B, P = 16, 8, 4, 4
+    T, N, B, S = 16, 8, 4, 64
+    P = 1 if cfg.retention_degree else 4
     params = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-    kv = jax.eval_shape(lambda: tfm.init_kv_pages(cfg, N, T))
+    kv = jax.eval_shape(lambda: tfm.init_kv_pages(cfg, N, T, B + 1))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    decode = jax.jit(lambda p, t, pos, kv, bt: tfm.forward_decode(p, t, pos, cfg, kv, bt)).lower(params, i32(B), i32(B), kv, i32(B, P))
-    prefill = jax.jit(lambda p, t, kv, bt, n, w: tfm.forward_prefill(p, t, cfg, kv, bt, n, w)).lower(params, i32(1, P * T), kv, i32(P), i32(), i32())
-    assert (h.hexdigest()[:16], _digest(decode.as_text().encode()), _digest(prefill.as_text().encode())) == PARENT[cell_name]
+    slot = (i32(),) if cfg.kda_per_period else ()
+
+    def decode(stats):
+        return jax.jit(lambda p, t, pos, kv, bt: tfm.forward_decode(p, t, pos, cfg, kv, bt, stats=stats)).lower(params, i32(B), i32(B), kv, i32(B, P))
+
+    lowered = {
+        "decode": lambda: decode(False),
+        "prefill": lambda: jax.jit(lambda p, t, kv, bt, n, w, *s: tfm.forward_prefill(p, t, cfg, kv, bt, n, w, *s)).lower(
+            params, i32(1, S), kv, i32(P), i32(), i32(), *slot),
+        "decode_stats": lambda: decode(True),
+        "forward": lambda: jax.jit(lambda p, t: tfm.forward(p, t, cfg)).lower(params, i32(2, 32)),
+        "grad": lambda: jax.jit(jax.grad(lambda p, t: tfm.next_token_loss(p, t, cfg))).lower(params, i32(2, 32)),
+        "zero_step": lambda: _zero_step_on_four_devices(cfg),
+    }
+    want = {**PARENT[cell_name], **{program: since for (name, program), (since, _why) in MOVED.items() if name == cell_name}}
+    assert ("decode_stats" in want) == bool(cfg.n_experts) and ("grad" in want) == (cell.traffic["runner"] == "train_steps")
+    got = {"params": h.hexdigest()[:16], **{name: _digest(lowered[name]().as_text().encode()) for name in want if name != "params"}}
+    assert got == want
+
+
+# What each configuration of BENCHMARK.json caches, as the benchmark's readers know it: describe()["cache"]["kind"], whether a
+# full page may serve another prompt, the decode executable's name, and the pool's leaves in order with their indexing.
+CACHES = {
+    "mistral7b-train-seq4k-1chip": ("kv_pages", True, "llm_decode", {"k": "page", "v": "page"}),
+    "dsllm7b-serve-chat-steady": ("kv_pages", True, "llm_decode", {"k": "page", "v": "page"}),
+    "olmoe-train-seq4k-1chip": ("kv_pages", True, "llm_decode", {"k": "page", "v": "page"}),
+    "trinitymini-serve-agent-turns": ("kv_pages", True, "llm_decode", {"k": "page", "v": "page"}),
+    "brumby14b-serve-longgen-batch": ("state", False, "llm_decode_state", {"s": "page", "z": "page"}),
+    "solaropen2-serve-reasoning-batch": ("state+kv_pages", False, "llm_decode_hybrid", {"k": "page", "v": "page", "s": "slot", "tail": "slot"}),
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(CACHES))
+def test_one_layout_says_what_a_model_caches_and_paged_lm_reads_it(cell_name):
+    """`cache_layout(cfg)` is the pool: its leaves are `init_kv_pages`' keys
+    in order, each indexed by page or by slot as its second axis says; and
+    what PagedLM tells the engine and the benchmark of the cache (the kind, the
+    prefix sharing, the executables' names, describe()'s keys) is what it
+    told them before it read the layout."""
+    kind, shares, decode_name, indexed = CACHES[cell_name]
+    cell = rehearsal.shrink(spec.find_cell(cell_name))
+    cfg = cell.arch.model_config(cell.config)
+    layout = tfm.cache_layout(cfg)
+    pages, slots = 6, 3
+    pool = tfm.init_kv_pages(cfg, pages, 16, slots)  # not through eval_shape: a dict comes back from it with its keys sorted
+    assert list(pool) == list(layout.names) == list(indexed) and layout.indexed == indexed
+    assert {name: leaf.shape[1] for name, leaf in pool.items()} == {name: {"page": pages, "slot": slots}[by] for name, by in indexed.items()}
+    assert sum(layers for _, layers in layout.kinds) == cfg.n_layers and (layout.state, layout.kv) == (not shares, "k" in indexed)
+    lm = PagedLM(cfg, num_pages=pages, page_tokens=16, max_slots=slots - 1, max_pages_per_seq=4 if layout.kv else 1)
+    described = lm.describe()
+    assert described["cache"]["kind"] == kind and lm.shares_prefix_pages is shares
+    assert set(described["cache"]) == {"kind", "page_bytes"} | ({"state_bytes"} if "slot" in indexed.values() else set())
+    assert set(described) == {"pid", "platform", "device_kind", "device_count", "cache", "decode_attention", "peak_bytes_in_use", "compile"} | (
+        {"decode_state"} if "slot" in indexed.values() else set())
+    assert lm._get_decode().__name__ == decode_name and lm._get_prefill(2 if layout.kv else 1).__name__ == decode_name.replace("decode", "prefill") + ("_p2" if layout.kv else "_p1")
+    assert lm.page_bytes == sum(math.prod(pool[name].shape) * pool[name].dtype.itemsize for name, by in indexed.items() if by == "page") // pages
