@@ -142,6 +142,27 @@ class TransformerConfig:
     kda_per_period: int = 0
     kda_conv: int = 4
     state_slots: int = 0
+    # Latent attention (the DeepSeek-V2 / V3 family's MLA) in softmax
+    # attention's place, off at kv_lora_rank 0. A head's query is q_lora_rank
+    # -> (qk_nope_dim | qk_rope_dim) behind an RMSNorm; keys and values come
+    # from ONE latent a position, c_kv (kv_lora_rank, behind an RMSNorm), which
+    # `w_uk` / `w_uv` expand into a head's qk_nope_dim key part and v_head_dim
+    # value; one rotated key part of qk_rope_dim is shared by all heads. What
+    # a served sequence keeps is `[c_kv | k_r]` a position: the "latent" row of
+    # `KINDS`. d_head is the query head, qk_nope_dim + qk_rope_dim.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # Rope frequency scaling, off when empty: ("yarn", factor, original
+    # context, beta_fast, beta_slow, mscale, mscale_all_dim), see `_rope_freqs`.
+    rope_scaling: Tuple = ()
+    # Group-limited selection of a sigmoid router's experts (`_router_probs`),
+    # off at n_group 1: the experts lie in n_group groups, of which a token
+    # keeps the topk_group best before its top-k.
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -215,6 +236,15 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     if per and (cfg.n_layers % (per + 1) or any(cfg.windows) or any(cfg.rope_layers) or not cfg.rope_layers
                 or cfg.n_dense_layers or cfg.retention_degree or cfg.parallel_block):
         raise ValueError("a KDA stack is whole periods of (one softmax layer, kda_per_period KDA layers), no rope (rope_layers all off), window or dense layer")
+    if cfg.kv_lora_rank and not (cfg.q_lora_rank and cfg.qk_nope_dim and cfg.qk_rope_dim and cfg.v_head_dim
+                                 and cfg.head_dim == cfg.qk_nope_dim + cfg.qk_rope_dim and cfg.qk_rope_dim % 2 == 0):
+        raise ValueError("latent attention needs q_lora_rank, qk_nope_dim, qk_rope_dim (even), v_head_dim, and d_head their query head qk_nope_dim + qk_rope_dim")
+    if cfg.kv_lora_rank and (cfg.retention_degree or per or cfg.attn_gate or cfg.qk_norm or any(cfg.windows) or cfg.rope_layers):
+        raise ValueError("a latent-attention stack has no window, gate, q/k-norm, rope switch, retention or KDA layer")
+    if cfg.rope_scaling and (cfg.rope_scaling[0] != "yarn" or len(cfg.rope_scaling) != 7):
+        raise ValueError(f"rope_scaling {cfg.rope_scaling!r}: ('yarn', factor, original context, beta_fast, beta_slow, mscale, mscale_all_dim) is computed")
+    if cfg.n_group > 1 and (cfg.router_score != "sigmoid" or E % cfg.n_group or not 0 < cfg.topk_group <= cfg.n_group or E // cfg.n_group < 2):
+        raise ValueError("group-limited selection: a sigmoid router whose experts divide into n_group groups of at least two, topk_group of them kept")
     k = iter(jax.random.split(key, 16))
     # What this model has over the llama and OLMoE blocks draws from a stream
     # of its own: theirs give the same weights for a key as before.
@@ -255,12 +285,28 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             "b_g": dense(next(k), (*L, n), 4.0),
         }
 
+    def latent_attn(k, L):
+        """A latent-attention layer's mixer (`_mla_mixer`): the up-projections
+        a head, `w_uk` [heads, qk_nope_dim, kv_lora_rank] and `w_uv` [heads,
+        kv_lora_rank, v_head_dim], as the absorbed form multiplies them."""
+        c, r = cfg.kv_lora_rank, cfg.q_lora_rank
+        return {
+            "wq_a": dense(next(k), (*L, d, r), d),
+            "q_a_norm": {"scale": jnp.ones((*L, r), cfg.dtype)},
+            "wq_b": dense(next(k), (*L, r, nh * hd), r),
+            "wkv_a": dense(next(k), (*L, d, c + cfg.qk_rope_dim), d),
+            "kv_a_norm": {"scale": jnp.ones((*L, c), cfg.dtype)},
+            "w_uk": dense(next(k), (*L, nh, cfg.qk_nope_dim, c), c),
+            "w_uv": dense(next(k), (*L, nh, c, cfg.v_head_dim), c),
+            "wo": dense(next(k), (*L, nh * cfg.v_head_dim, d), nh * cfg.v_head_dim),
+        }
+
     def blocks(k, k2, L, routed: bool, f: int, kda: bool = False):
         """A stack of alike layers; k2: the stream of what a family has over the llama and OLMoE blocks."""
         L = (L,) if isinstance(L, int) else L
         out = {
             "attn_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
-            "attn": kda_attn(k, L) if kda else {
+            "attn": kda_attn(k, L) if kda else latent_attn(k, L) if cfg.kv_lora_rank else {
                 "wq": dense(next(k), (*L, d, nh * hd), d),
                 "wk": dense(next(k), (*L, d, nkv * hd), d),
                 "wv": dense(next(k), (*L, d, nkv * hd), d),
@@ -297,7 +343,14 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         if routed and cfg.router_score == "sigmoid":
             # A trained bias is non-zero; zeros would hide the term from every
             # check. At this scale it changes about a third of a token's experts.
-            out["mlp"]["router_bias"] = dense(next(k2), (*L, E), 100.0, jnp.float32)
+            # A group-limited router draws it at a tenth of that (one spacing
+            # of the candidates' scores around the last pick): the bias is
+            # there to level the experts' load, it also moves a group's score,
+            # and at 0.1 an expert two deviations down is picked by under one
+            # row of a 256-row chunk, so which experts of a chip's share a
+            # chunk touched, and the bytes its grouped products read, followed
+            # the seed (PERF.md §6, PR 50). The ungrouped routers keep theirs.
+            out["mlp"]["router_bias"] = dense(next(k2), (*L, E), 100.0 if cfg.n_group == 1 else 1e4, jnp.float32)
         if routed and cfg.d_ff_shared:
             out["mlp"]["shared"] = swiglu(k2, L, cfg.d_ff_shared)
         return out
@@ -389,21 +442,57 @@ def _norm(x, scale, cfg: TransformerConfig):
     return rms_norm(x, scale, cfg.norm_eps)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor m(s, a) = 0.1 a ln s + 1 (1 at a factor of 1 or less)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def _rope_freqs(cfg: TransformerConfig):
-    half = (cfg.rotary_dim or cfg.head_dim) // 2
-    return cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    """The rotated pairs' frequencies [rotary_dim / 2]: theta^(-2i / dim), and
+    under YaRN (`cfg.rope_scaling`) each divided by `factor` to the degree
+    that its pair turns fewer than beta_slow times over the original context,
+    not at all where it turns more than beta_fast times, linearly between
+    (pair indices `low` .. `high`, from the number of turns n a pair of index
+    d makes: d(n) = dim ln(original / (2 pi n)) / (2 ln theta))."""
+    half = (cfg.qk_rope_dim or cfg.rotary_dim or cfg.head_dim) // 2
+    freqs = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if not cfg.rope_scaling:
+        return freqs
+    _, factor, original, beta_fast, beta_slow, _, _ = cfg.rope_scaling
+
+    def pair_of(turns: float) -> float:
+        return 2 * half * math.log(original / (2 * math.pi * turns)) / (2 * math.log(cfg.rope_theta))
+
+    low = min(max(math.floor(pair_of(beta_fast)), 0), half - 1)
+    high = min(max(math.ceil(pair_of(beta_slow)), 0), half - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def _rope_magnitude(cfg: TransformerConfig) -> float:
+    """What YaRN multiplies cos and sin by: m(factor, mscale) / m(factor, mscale_all_dim); 1 without scaling."""
+    if not cfg.rope_scaling:
+        return 1.0
+    _, factor, _, _, _, mscale, mscale_all_dim = cfg.rope_scaling
+    return _yarn_mscale(factor, mscale) / _yarn_mscale(factor, mscale_all_dim)
+
+
+def _cos_sin(cfg: TransformerConfig, angles):
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    m = _rope_magnitude(cfg)
+    return (cos, sin) if m == 1.0 else (cos * m, sin * m)
 
 
 def rope_tables(cfg: TransformerConfig, seq_len: int):
     freqs = _rope_freqs(cfg)
     angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * freqs[None, :]
-    return jnp.cos(angles), jnp.sin(angles)  # [seq, rotary_dim/2]
+    return _cos_sin(cfg, angles)  # [seq, rotary_dim/2]
 
 
 def rope_at(cfg: TransformerConfig, positions):
     """rope_tables' rows at the positions given [n]: cos, sin [n, rotary_dim/2]."""
     angles = positions.astype(jnp.float32)[:, None] * _rope_freqs(cfg)[None, :]
-    return jnp.cos(angles), jnp.sin(angles)
+    return _cos_sin(cfg, angles)
 
 
 def _rotate(x, cos, sin, interleave: bool):
@@ -521,7 +610,7 @@ def stack_plan(cfg: TransformerConfig) -> Tuple[Tuple[int, Tuple[StackMember, ..
     per, nd = cfg.kda_per_period, cfg.n_dense_layers
     if per:
         return ((cfg.n_layers // (per + 1), (StackMember("softmax", "blocks", 1, 0), StackMember("kda", "kda_blocks", per, 0))),)
-    kind = "retention" if cfg.retention_degree else "softmax"
+    kind = "retention" if cfg.retention_degree else "latent" if cfg.kv_lora_rank else "softmax"
     dense = ((nd, (StackMember(kind, "dense_blocks", 1, 0),)),) if nd else ()
     return (*dense, (cfg.n_layers - nd, (StackMember(kind, "blocks", 1, nd),)))
 
@@ -835,9 +924,23 @@ def _router_probs(x, mp, cfg: TransformerConfig):
     )
     if cfg.router_score == "sigmoid":
         scores = jax.nn.sigmoid(logits)
-        return scores, scores + mp["router_bias"].astype(jnp.float32)
+        ranked = scores + mp["router_bias"].astype(jnp.float32)
+        return scores, (_keep_best_groups(ranked, cfg) if cfg.n_group > 1 else ranked)
     probs = jax.nn.softmax(logits, axis=-1)
     return probs, probs
+
+
+def _keep_best_groups(ranked, cfg: TransformerConfig):
+    """Group-limited selection: ranked [n, E] as n_group groups of E / n_group
+    neighbours; a group's score is the sum of its two largest entries, the
+    topk_group best groups stay as they are and every entry of the others
+    becomes -inf, which no top-k takes."""
+    G = cfg.n_group
+    with jax.named_scope("moe.route.groups"):
+        groups = ranked.reshape(ranked.shape[0], G, -1)
+        group_score = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)  # [n, G]
+        kept = jnp.sum(jax.nn.one_hot(lax.top_k(group_score, cfg.topk_group)[1], G, dtype=jnp.int32), axis=1) > 0  # [n, G]
+        return jnp.where(kept[:, :, None], groups, -jnp.inf).reshape(ranked.shape)
 
 
 def _tokens_per_expert(experts, n_experts: int):
@@ -995,6 +1098,58 @@ def _kda_mixer(h, ap, cfg: TransformerConfig, attend):
     return _ckpt(proj(y, ap["wo"]).astype(cfg.dtype), "attn_out_bf16"), kept
 
 
+def latent_softmax_scale(cfg: TransformerConfig) -> float:
+    """What a latent-attention layer multiplies its scores by: 1 / sqrt of the
+    query head (qk_nope_dim + qk_rope_dim), and under YaRN m(factor,
+    mscale_all_dim)^2, the family's correction of the softmax's temperature
+    for the stretched context."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.rope_scaling:
+        scale *= _yarn_mscale(cfg.rope_scaling[1], cfg.rope_scaling[6]) ** 2
+    return scale
+
+
+def _mla_mixer(h, ap, cfg: TransformerConfig, cos, sin, attend):
+    """A latent-attention layer's mixer on its normed input h [b, s, d] ->
+    (its output [b, s, d], kept): the query through its low-rank pair and the
+    norm between, split into a head's `q_nope` and rotated `q_rope`; ONE
+    down-projection a position into the normed latent `c_kv` and the rotated
+    key part `k_r`, which all heads share; then the caller's `attend(q_nope
+    [b, s, n_heads, qk_nope_dim], q_rope [b, s, n_heads, qk_rope_dim], c_kv
+    [b, s, kv_lora_rank], k_r [b, s, qk_rope_dim], w_uk, w_uv) -> (o [b, s,
+    n_heads, v_head_dim], kept)`, which owns what a sequence keeps and in
+    which form the up-projections are applied (expanded onto c_kv, or
+    absorbed into the query and the output); then `wo`."""
+    b, s, _ = h.shape
+    c, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    interleave = cfg.rope_style == "interleaved"
+
+    def proj(x, w):
+        return jnp.einsum("bsd,dk->bsk", x, w, preferred_element_type=jnp.float32).astype(cfg.dtype)
+
+    def rotate(x):  # [b, s, heads, qk_rope_dim]
+        return _rotate(x.astype(jnp.float32), cos, sin, interleave).astype(cfg.dtype)
+
+    with jax.named_scope("attn.mla.q"):
+        c_q = rms_norm(proj(h, ap["wq_a"]), ap["q_a_norm"]["scale"], cfg.norm_eps)
+        q = proj(c_q, ap["wq_b"])
+        if b * s < cfg.d_model:
+            # As `_block` says of `wq`: with nothing between the projection and its split into heads the compiler gives
+            # the dot a head-shaped result and re-lays `wq_b` out for it, a transpose of 75 MB a layer in every step
+            # and chunk; while a call has fewer rows than the weight, the result is what should move.
+            q = lax.optimization_barrier(q)
+        q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+        q_nope, q_rope = q[..., :nope], rotate(q[..., nope:])
+    with jax.named_scope("attn.mla.kv_down"):
+        kv = proj(h, ap["wkv_a"])
+        c_kv = rms_norm(kv[..., :c], ap["kv_a_norm"]["scale"], cfg.norm_eps)
+        k_r = rotate(kv[:, :, None, c:])[:, :, 0]
+    o, kept = attend(q_nope, q_rope, c_kv, k_r, ap["w_uk"], ap["w_uv"])
+    with jax.named_scope("attn.mla.out"):
+        out = proj(o.reshape(b, s, cfg.n_heads * cfg.v_head_dim), ap["wo"])
+    return _ckpt(out, "attn_out_bf16"), kept
+
+
 def _kda_inputs(cfg: TransformerConfig, q, k, v, conv, tails, n_valid=None):
     """What the recurrence reads, from the projections of ONE sequence's rows
     in order, q, k, v [c, n_heads * head_dim]: the short convolutions from
@@ -1021,7 +1176,7 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     (a row of `KINDS`): `attend(q, k, v) -> (o [b, s, n_heads, head_dim],
     kept)`, with q and k after rope; a "retention" layer's `attend(q, k, v,
     log_g)`, log_g [b, s, n_kv_heads] float32 the gate's log-sigmoid; a "kda"
-    layer's as `_kda_mixer` calls it. `kept` is whatever the caller wants back
+    layer's as `_kda_mixer` calls it, a "latent" layer's as `_mla_mixer` does. `kept` is whatever the caller wants back
     (the cache leaves it wrote into; None in training). Returns (out, kept),
     and as a third what the router did with this layer's input if `stats` is
     "route" (`_route_stats`), or the rows each expert took [E] if it is
@@ -1034,8 +1189,8 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     ap, mp = layer_params["attn"], layer_params["mlp"]
 
     h = _norm(x, layer_params["attn_norm"]["scale"], cfg)
-    if kind == "kda":  # a mixer of its own around the caller's `attend`
-        attn_out, kept = _kda_mixer(h, ap, cfg, attend)
+    if kind in ("kda", "latent"):  # a mixer of its own around the caller's `attend`
+        attn_out, kept = _kda_mixer(h, ap, cfg, attend) if kind == "kda" else _mla_mixer(h, ap, cfg, cos, sin, attend)
         return _block_ffn(x, attn_out, kept, h, layer_params, cfg, stats, experts)
     # Where q and k are split into heads decides which operand of their
     # projections moves. Split before rope, the dot's result is head-shaped
@@ -1133,6 +1288,27 @@ def _kda_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
             return kda.kda_chunk(q, k, v, g, beta, jnp.zeros((cfg.n_heads, cfg.head_dim, cfg.head_dim), jnp.float32))[0]
 
     return lambda q, k, v, g, beta, conv: (jax.vmap(one, in_axes=(0, 0, 0, 0, 0, None))(q, k, v, g, beta, conv), None)
+
+
+def _latent_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
+    """The expanded form over whole sequences: every position's latent becomes
+    every head's key part and value, and a head attends with `[k_nope | k_r]`,
+    the masked plain expression, float32 softmax."""
+    _naive_only(cfg, "latent", "_latent_whole")
+    scale = latent_softmax_scale(cfg)
+
+    def attend(q_nope, q_rope, c_kv, k_r, w_uk, w_uv):
+        s = q_nope.shape[1]
+        with jax.named_scope("attn.mla.expand"):
+            k_nope = jnp.einsum("bsc,hnc->bshn", c_kv, w_uk, preferred_element_type=jnp.float32).astype(cfg.dtype)
+            v = jnp.einsum("bsc,hcv->bshv", c_kv, w_uv, preferred_element_type=jnp.float32).astype(cfg.dtype)
+        scores = jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope, preferred_element_type=jnp.float32)
+        scores = (scores + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_r, preferred_element_type=jnp.float32)) * scale
+        scores = jnp.where(jnp.arange(s)[:, None] >= jnp.arange(s)[None, :], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bkhv->bqhv", probs, v, preferred_element_type=jnp.float32).astype(cfg.dtype), None
+
+    return attend
 
 
 def _walk_stack(params: PyTree, cfg: TransformerConfig, step, carry, in_place: bool):
@@ -1405,7 +1581,10 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     layer counts its gate, and in attention's place the chunked form: every
     query head reads the state (D x head_dim), every K/V head adds to it, and
     the in-chunk pairs (half a chunk visible on average). Under a share of
-    the experts a token counts its picks that fall on held ones."""
+    the experts a token counts its picks that fall on held ones. A latent
+    layer counts its low-rank pairs and up-projections and the expanded
+    form's pairs (a head's keys qk_nope_dim + qk_rope_dim wide, its values
+    v_head_dim)."""
     ffn = 3 * cfg.d_model * cfg.d_ff
     if cfg.n_experts:
         # under a share, the part of a token's n_experts_per_tok picks that falls on held experts
@@ -1413,21 +1592,26 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     nd = cfg.n_dense_layers
     n_kda = cfg.n_layers // (cfg.kda_per_period + 1) * cfg.kda_per_period
     hd, wide = cfg.head_dim, cfg.n_heads * cfg.head_dim
+    mixer = (
+        (3 if cfg.attn_gate else 2) * cfg.d_model * cfg.n_heads * cfg.head_dim
+        + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
+        + (cfg.d_model * cfg.n_kv_heads if cfg.retention_degree else 0)
+    )
+    if cfg.kv_lora_rank:
+        mixer = (
+            cfg.d_model * (cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_dim) + cfg.q_lora_rank * wide
+            + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim) + cfg.n_heads * cfg.v_head_dim * cfg.d_model
+        )
     n_params = (
         cfg.vocab_size * cfg.d_model
-        + (cfg.n_layers - n_kda)
-        * (
-            (3 if cfg.attn_gate else 2) * cfg.d_model * cfg.n_heads * cfg.head_dim
-            + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
-            + (cfg.d_model * cfg.n_kv_heads if cfg.retention_degree else 0)
-        )
+        + (cfg.n_layers - n_kda) * mixer
         # a KDA layer: q, k, v, o; the decay's and the gate's thin pairs; beta
         + n_kda * (4 * cfg.d_model * wide + 2 * (cfg.d_model * hd + hd * wide) + cfg.d_model * cfg.n_heads)
         + (cfg.n_layers - nd) * ffn
         + nd * 3 * cfg.d_model * cfg.d_ff_dense
         + (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size)
     )
-    attn = 12 * (cfg.n_layers - n_kda) * cfg.n_heads * cfg.head_dim * (seq_len / 2)
+    attn = 6 * (cfg.n_layers - n_kda) * cfg.n_heads * (cfg.head_dim + (cfg.v_head_dim or cfg.head_dim)) * (seq_len / 2)
     # a KDA layer in the chunked form: the state read and added to (2 x d_k x d_v a head each way) and the in-chunk pairs
     attn += 6 * n_kda * cfg.n_heads * (2 * hd * hd + 2 * hd * min(64, seq_len) / 2)
     if cfg.retention_degree:
@@ -1543,7 +1727,7 @@ def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
     return "paged_kernel" if can_tile(page_tokens, cfg.head_dim, cfg.dtype) else "xla_gather"
 
 
-# ---- the three kinds of layer, each with what a served sequence keeps of it
+# ---- the four kinds of layer, each with what a served sequence keeps of it
 #
 # A kind's three forms are `attend` factories of one calling convention.
 # `whole(cfg, mesh, where)`: a whole sequence that keeps nothing (training,
@@ -1560,7 +1744,7 @@ def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
 # `names` order (the walker puts them back into the tree). Some lines below
 # are held to their letter: benchmarks/tests/test_*_cell.py plant their faults
 # by replacing them (a chunk's `s_in` / `state_in` / `tails_in` and what it
-# stores, the pools' `"s": jnp.zeros(...)`).
+# stores, the pools' `"s": jnp.zeros(...)`, a latent chunk's `lp_ = lp.at[...]`).
 #
 # "softmax": K/V pages. A page holds `page_tokens` positions of one layer's k
 # or v, [layers, pages, page_tokens, n_kv_heads * head_dim]: tokens x (head,
@@ -1576,6 +1760,27 @@ def _kv_leaves(cfg: TransformerConfig, num_pages: int, page_tokens: int):
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
+def _chunk_dest_pages(ctx):
+    """(pages of a prefill chunk, where each is written): a page of the chunk
+    that holds a position in [write_from, length) goes where the block table
+    says; one wholly below `write_from` is another owner's, and one wholly
+    past the length nobody's: both to the trash page."""
+    T, c0 = ctx["page_tokens"], ctx["c0"]
+    pages = ctx["rows"] // T
+    first = c0 + jnp.arange(pages) * T
+    writable = (first + T > ctx["write_from"]) & (first < ctx["length"])
+    return pages, jnp.where(writable, lax.dynamic_slice_in_dim(ctx["dest_table"], c0 // T, pages), TRASH_PAGE)
+
+
+def _step_dest(ctx):
+    """(page, slot in it, length after the append) of each row of a decode
+    step: an active row appends at its position, an inactive one (length 0)
+    to the trash page."""
+    T, pos, active = ctx["page_tokens"], ctx["pos"], ctx["active"]
+    dest_page = jnp.where(active, ctx["block_tables"][jnp.arange(pos.shape[0]), pos // T], TRASH_PAGE)
+    return dest_page, pos % T, jnp.where(active, pos + 1, 0)
+
+
 def _kv_chunk(cfg: TransformerConfig, ctx):
     """The chunk's rows write their k/v into the pages `block_table` names and
     attend causally over positions [0, chunk end) of the table's pages. Whole
@@ -1588,10 +1793,7 @@ def _kv_chunk(cfg: TransformerConfig, ctx):
     from ..ops.paged_attention import paged_prefill_attention
 
     T, c0, block_table = ctx["page_tokens"], ctx["c0"], ctx["block_table"]
-    pages = ctx["rows"] // T
-    first = c0 + jnp.arange(pages) * T
-    writable = (first + T > ctx["write_from"]) & (first < ctx["length"])
-    dest_page = jnp.where(writable, lax.dynamic_slice_in_dim(ctx["dest_table"], c0 // T, pages), TRASH_PAGE)
+    pages, dest_page = _chunk_dest_pages(ctx)
     use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
 
     def attend_in(where: LayerPlace, pool):
@@ -1619,12 +1821,9 @@ def _kv_step(cfg: TransformerConfig, ctx):
     block table names."""
     from ..ops.paged_attention import paged_attention
 
-    T, pos, active, block_tables = ctx["page_tokens"], ctx["pos"], ctx["active"], ctx["block_tables"]
+    T, pos, block_tables = ctx["page_tokens"], ctx["pos"], ctx["block_tables"]
     B = pos.shape[0]
-    rows = jnp.arange(B)
-    dest_page = jnp.where(active, block_tables[rows, pos // T], TRASH_PAGE)
-    dest_slot = pos % T
-    lengths = jnp.where(active, pos + 1, 0)
+    dest_page, dest_slot, lengths = _step_dest(ctx)
     use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
 
     def attend_in(where: LayerPlace, pool):
@@ -1801,6 +2000,118 @@ def _kda_decode_path(cfg: TransformerConfig, page_tokens: int) -> str:
     return "kda_kernel" if kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim) else "xla_step"
 
 
+# "latent": a page holds `page_tokens` positions of one layer's `[c_kv | k_r]`,
+# the normed latent and the rotated key part that all heads share, padded with
+# zeros to whole lane tiles: `ckv` [layers, pages, page_tokens, row_width]
+# (ops/latent_attention.py). A page of positions like a K/V page: the block
+# table, the prefix index and the suffix prefill serve it as they serve those.
+# Both serving forms are ABSORBED: `w_uk` is applied to the query and `w_uv`
+# to the output, and a head attends in latent space over the rows as they lie
+# (one key/value row for all heads; nothing is expanded). A chunk's absorbed
+# pairs cost 3.4 x the expanded form's, which would expand every cached
+# position a chunk that reads it (PERF.md §6, PR 50, says where that pays).
+
+
+def latent_row_width(cfg: TransformerConfig) -> int:
+    from ..ops.latent_attention import row_width
+
+    return row_width(cfg.kv_lora_rank, cfg.qk_rope_dim)
+
+
+def latent_position_bytes(cfg: TransformerConfig) -> int:
+    """What ONE cached position of ONE latent layer must be read as: `[c_kv |
+    k_r]` without the padding to whole lane tiles that the pages store."""
+    return (cfg.kv_lora_rank + cfg.qk_rope_dim) * jnp.dtype(cfg.dtype).itemsize
+
+
+def _latent_leaves(cfg: TransformerConfig, num_pages: int, page_tokens: int):
+    return {"ckv": jnp.zeros((cfg.n_layers, num_pages, page_tokens, latent_row_width(cfg)), cfg.dtype)}
+
+
+def _latent_rows(cfg: TransformerConfig, c_kv, k_r):
+    """What the pages keep of positions: `[c_kv | k_r | zeros]` [..., row_width]."""
+    pad = latent_row_width(cfg) - cfg.kv_lora_rank - cfg.qk_rope_dim
+    return jnp.concatenate([c_kv, k_r, jnp.zeros((*c_kv.shape[:-1], pad), c_kv.dtype)], axis=-1)
+
+
+def _absorbed_queries(cfg: TransformerConfig, q_nope, q_rope, w_uk):
+    """A head's query in latent space beside its rope part, as a row of the
+    pages is laid out: `[q_nope W_UK | q_rope | zeros]` [..., n_heads, row_width]."""
+    with jax.named_scope("attn.mla.absorb"):
+        q_lat = jnp.einsum("...hn,hnc->...hc", q_nope, w_uk, preferred_element_type=jnp.float32).astype(cfg.dtype)
+    return _latent_rows(cfg, q_lat, q_rope)
+
+
+def _absorbed_output(cfg: TransformerConfig, o_lat, w_uv):
+    """A head's output in latent space through its `w_uv`: [..., n_heads, kv_lora_rank] -> [..., n_heads, v_head_dim]."""
+    with jax.named_scope("attn.mla.absorb"):
+        return jnp.einsum("...hc,hcv->...hv", o_lat, w_uv, preferred_element_type=jnp.float32).astype(cfg.dtype)
+
+
+def _latent_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    from ..ops.latent_attention import can_tile
+
+    return "latent_kernel" if can_tile(page_tokens, cfg.n_heads, cfg.kv_lora_rank, cfg.dtype) else "xla_gather"
+
+
+def _latent_chunk(cfg: TransformerConfig, ctx):
+    """The chunk's rows write their latent rows into the pages `block_table`
+    names (whole pages, as `_kv_chunk` writes K/V and by its rule) and attend
+    causally, absorbed, over positions [0, chunk end) of the table's pages."""
+    from ..ops.latent_attention import latent_prefill_attention_gather, paged_latent_prefill_attention
+
+    T, c0, block_table = ctx["page_tokens"], ctx["c0"], ctx["block_table"]
+    pages, dest_page = _chunk_dest_pages(ctx)
+    use_kernel = _latent_path(cfg, T) == "latent_kernel"
+    how = dict(scale=latent_softmax_scale(cfg), v_width=cfg.kv_lora_rank)
+
+    def attend_in(where: LayerPlace, pool):
+        layer, lp = where.layer, pool["ckv"]
+
+        def attend(q_nope, q_rope, c_kv, k_r, w_uk, w_uv):
+            lp_ = lp.at[layer, dest_page].set(_latent_rows(cfg, c_kv[0], k_r[0]).reshape(pages, T, -1))
+            q = _absorbed_queries(cfg, q_nope[0], q_rope[0], w_uk)
+            # Attend AFTER the write: the chunk's rows read their own latent rows from the pages.
+            if use_kernel:
+                o_lat = paged_latent_prefill_attention(q, lp_, layer, block_table, c0, ctx["length"], **how)
+            else:
+                o_lat = latent_prefill_attention_gather(q, lp_[layer], block_table, c0, **how)
+            return _absorbed_output(cfg, o_lat, w_uv)[None], (lp_,)
+
+        return attend
+
+    return attend_in
+
+
+def _latent_step(cfg: TransformerConfig, ctx):
+    """Each active row appends its latent row at its position (an inactive one
+    to the trash page) and attends, absorbed, over positions [0, pos] of the
+    pages its block table names."""
+    from ..ops.latent_attention import latent_attention_gather, paged_latent_attention
+
+    T, pos, block_tables = ctx["page_tokens"], ctx["pos"], ctx["block_tables"]
+    dest_page, dest_slot, lengths = _step_dest(ctx)
+    use_kernel = _latent_path(cfg, T) == "latent_kernel"
+    how = dict(scale=latent_softmax_scale(cfg), v_width=cfg.kv_lora_rank)
+
+    def attend_in(where: LayerPlace, pool):
+        layer, lp = where.layer, pool["ckv"]
+
+        def attend(q_nope, q_rope, c_kv, k_r, w_uk, w_uv):
+            lp_ = lp.at[layer, dest_page, dest_slot].set(_latent_rows(cfg, c_kv[:, 0], k_r[:, 0]))
+            q = _absorbed_queries(cfg, q_nope[:, 0], q_rope[:, 0], w_uk)
+            # Attend AFTER the append so the new position attends to itself.
+            if use_kernel:
+                o_lat = paged_latent_attention(q, lp_, layer, block_tables, lengths, **how)
+            else:
+                o_lat = latent_attention_gather(q, lp_[layer], block_tables, pos + 1, **how)
+            return _absorbed_output(cfg, o_lat, w_uv)[:, None], (lp_,)
+
+        return attend
+
+    return attend_in
+
+
 class LayerKind(NamedTuple):
     """A row of `KINDS`: what a served sequence keeps of a layer of this kind
     and the layer's three forms. A new kind is a row here, its kernels and
@@ -1821,6 +2132,7 @@ KINDS = {
     "softmax": LayerKind(("k", "v"), "page", False, _kv_leaves, _softmax_whole, _kv_chunk, _kv_step, paged_attention_path),
     "retention": LayerKind(("s", "z"), "page", True, _retention_leaves, _retention_whole, _retention_chunk, _retention_step, _retention_decode_path),
     "kda": LayerKind(("s", "tail"), "slot", True, _kda_leaves, _kda_whole, _kda_chunk, _kda_step, _kda_decode_path),
+    "latent": LayerKind(("ckv",), "page", False, _latent_leaves, _latent_whole, _latent_chunk, _latent_step, _latent_path),
 }
 
 
@@ -1834,6 +2146,7 @@ class CacheLayout(NamedTuple):
     indexed: Dict[str, str]  # leaf -> "page" | "slot"
     state: bool  # a sequence keeps a recurrent state: then no full page of one prompt may serve another
     kv: bool  # a page holds `page_tokens` positions, so that a block table grows with its sequence
+    paged: Optional[str]  # a leaf whose pages hold positions ([layers, pages, page_tokens, ...]: its third axis says how many); None without one
 
 
 @functools.lru_cache(maxsize=None)
@@ -1844,7 +2157,8 @@ def cache_layout(cfg: TransformerConfig) -> CacheLayout:
             layers[m.kind] = layers.get(m.kind, 0) + repeats * m.layers
     rows = [KINDS[kind] for kind in layers]
     indexed = {name: row.indexed for row in rows for name in row.names}
-    return CacheLayout(tuple(layers.items()), tuple(indexed), indexed, any(row.state for row in rows), not all(row.state for row in rows))
+    paged = next((row.names[0] for row in rows if not row.state), None)
+    return CacheLayout(tuple(layers.items()), tuple(indexed), indexed, any(row.state for row in rows), paged is not None, paged)
 
 
 def decode_paths(cfg: TransformerConfig, page_tokens: int) -> Dict[str, str]:
@@ -1942,7 +2256,7 @@ def forward_prefill(
     """
     layout = cache_layout(cfg)
     _, S = tokens.shape
-    T = kv_pages["k"].shape[2] if layout.kv else S // block_table.shape[0]
+    T = kv_pages[layout.paged].shape[2] if layout.kv else S // block_table.shape[0]
     C, granule = prefill_chunk_tokens(cfg, S // T, T)
     # What a chunk slices is padded by a chunk: the last one starts at a
     # page below the length, not at a multiple of C, and a dynamic slice
@@ -2015,7 +2329,7 @@ def forward_decode(
     pos = jnp.maximum(positions, 0)
 
     if layout.kv:
-        T = kv_pages["k"].shape[2]
+        T = kv_pages[layout.paged].shape[2]
         cos_t, sin_t = rope_tables(cfg, P * T)
         cos = jnp.take(cos_t, pos, axis=0)[:, None, :]  # [B, 1, rd/2]: each row its own position
         sin = jnp.take(sin_t, pos, axis=0)[:, None, :]

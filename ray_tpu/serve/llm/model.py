@@ -262,6 +262,8 @@ class PagedLM:
         self.page_bytes = sum(leaf.nbytes for name, leaf in self.kv.items() if name not in slotted) // num_pages
         # The slots' leaves: what one sequence keeps there over all their layers.
         self.state_bytes = sum(self.kv[name].nbytes for name in slotted) // (max_slots + 1)
+        # Layers whose pages hold latent rows (transformer.KINDS["latent"]): their counters below.
+        self._latent_layers = dict(layout.kinds).get("latent", 0)
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
         # The last decode step's result vector, on the device: the next step's
@@ -290,8 +292,9 @@ class PagedLM:
         `page_bytes` of a page of K/V and `state_bytes` of what one sequence
         keeps in its state slot), which expression the decode and prefill
         executables run over the pages (`decode_attention`: "paged_kernel" or
-        "xla_gather", transformer.paged_attention_path; "retention_kernel" or
-        "xla_step" over a state) and over the state slots (`decode_state`:
+        "xla_gather", transformer.paged_attention_path; "latent_kernel" or
+        "xla_gather" over latent pages; "retention_kernel" or "xla_step" over
+        a state) and over the state slots (`decode_state`:
         "kda_kernel" or "xla_step"): each kind's own answer, `KINDS`), and
         what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
@@ -473,6 +476,12 @@ class PagedLM:
             # A routed model: the rows its routed layers' experts were handed, and those of them sorted to their own experts.
             rows = chunks * chunk * (self.cfg.n_layers - self.cfg.n_dense_layers)
             counters["prefill_experts"] = {"rows": rows, "grouped_rows": rows if self._tfm.experts_grouped_at(chunk) else 0, "chunks": chunks}
+        if self._latent_layers:
+            # The (query, key) pairs of the call's computed rows below the length, a latent layer each (every one
+            # attended absorbed: transformer._latent_chunk is the one serving form).
+            n, first = len(prompt), min(_anchor, len(prompt))
+            pairs = self._latent_layers * (n * (n + 1) - first * (first + 1)) // 2
+            counters["prefill_latent"] = {"pairs": pairs, "calls": 1}
         return PrefillToken(tok, attrs["computed_tokens"], counters)
 
     def decode(self, last_tokens, positions, block_tables) -> List[int]:
@@ -536,6 +545,12 @@ class PagedLM:
             # The K/V the live rows read beside their states: every position up to their own, a page's bytes / page_tokens each.
             kv_tokens = int((pos[pos >= 0].astype(np.int64) + 1).sum())
             counters["decode_kv"] = {"bytes": kv_tokens * self.page_bytes // self.page_tokens, "tokens": kv_tokens, "steps": 1}
+        if self._latent_layers:
+            # The latent rows the live rows read: every position up to their own, in every latent layer, unpadded.
+            positions = int((pos[pos >= 0].astype(np.int64) + 1).sum())
+            counters["decode_latent"] = {
+                "bytes": positions * self._latent_layers * self._tfm.latent_position_bytes(cfg), "positions": positions, "steps": 1,
+            }
         return DecodeTokens(tokens, counters) if counters else tokens
 
 

@@ -481,3 +481,82 @@ def test_a_share_keeps_the_routers_width_and_its_gradients_reach_only_the_held_e
     # the held share of a token's picks is what flops_per_token counts: 2 of 8 experts, so a quarter of k
     dense_part = tfm.flops_per_token(cfg.replace(n_experts_per_tok=0), 128)
     assert tfm.flops_per_token(cfg, 128) - dense_part == pytest.approx((tfm.flops_per_token(full, 128) - tfm.flops_per_token(full.replace(n_experts_per_tok=0), 128)) / 4)
+
+
+# ------------------------------------------------ group-limited selection
+
+
+def grouped_router_cfg(**kw):
+    """The published router's shape at a toy width: 256 experts in 8 groups of 32, 4 groups kept, top-8."""
+    return tfm.tiny(n_kv_heads=4, d_ff=16, n_experts=256, n_experts_per_tok=8, router_score="sigmoid", norm_topk_prob=True,
+                    route_scale=2.5, d_ff_shared=16, n_group=8, topk_group=4, dtype=jnp.float32, **kw)
+
+
+def loop_selection(ranked, n_group, topk_group, k):
+    """Group-limited selection written out for ONE token: a group's score is
+    the sum of its two largest entries; the best groups stay (the lower index
+    on a tie); the k largest entries among them, best first."""
+    per = len(ranked) // n_group
+    scores = [sum(sorted(ranked[g * per:(g + 1) * per])[-2:]) for g in range(n_group)]
+    kept = sorted(range(n_group), key=lambda g: (-scores[g], g))[:topk_group]
+    allowed = [e for g in kept for e in range(g * per, (g + 1) * per)]
+    return sorted(allowed, key=lambda e: (-ranked[e], e))[:k]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_group_limited_selection_is_the_loop_written_one_where_plain_top_k_differs(seed):
+    cfg = grouped_router_cfg()
+    mp = jax.tree_util.tree_map(lambda a: a[0], tfm.init_params(jax.random.PRNGKey(seed), cfg)["blocks"]["mlp"])
+    x = jax.random.normal(jax.random.PRNGKey(seed + 50), (24, D), jnp.float32)
+    scores, ranked = tfm._router_probs(x, mp, cfg)
+    plain_scores, plain = tfm._router_probs(x, mp, cfg.replace(n_group=1, topk_group=1))
+    np.testing.assert_array_equal(np.asarray(scores), np.asarray(plain_scores))  # the groups select; they never weigh
+    got = np.asarray(jax.lax.top_k(ranked, 8)[1])
+    want = np.asarray([loop_selection([float(v) for v in row], 8, 4, 8) for row in np.asarray(plain)])
+    np.testing.assert_array_equal(got, want)
+    unlimited = np.asarray(jax.lax.top_k(plain, 8)[1])
+    assert (np.sort(unlimited, -1) != np.sort(want, -1)).any(axis=-1).sum() >= 12  # plain top-8 would choose otherwise for most tokens
+    assert all(len({e // 32 for e in row}) <= 4 for row in got)
+
+
+def test_one_group_is_todays_router_bit_for_bit():
+    """n_group 1: `_router_probs` returns what it returned, and the routed FFN lowers to the text it lowered to."""
+    cfg = moe_cfg(router_score="sigmoid", norm_topk_prob=True, d_ff_shared=F)
+    assert (cfg.n_group, cfg.topk_group) == (1, 1)
+    h, mp = one_layer_ffn(0, cfg)
+    scores, ranked = tfm._router_probs(h.reshape(-1, D), mp, cfg)
+    logits = jnp.einsum("nd,de->ne", h.reshape(-1, D), mp["router"], precision=jax.lax.Precision.HIGHEST)
+    np.testing.assert_array_equal(np.asarray(scores), np.asarray(jax.nn.sigmoid(logits)))
+    np.testing.assert_array_equal(np.asarray(ranked), np.asarray(jax.nn.sigmoid(logits) + mp["router_bias"]))
+    text = jax.jit(lambda h, mp: tfm._routed_ffn(h, mp, cfg)).lower(h, mp).as_text()
+    assert "moe.route.groups" not in text and text.count("top_k") + text.count("topk") <= 2
+    grouped = jax.jit(lambda h, mp: tfm._routed_ffn(h, mp, cfg.replace(n_group=2, topk_group=1))).lower(h, mp).as_text(debug_info=True)
+    assert "moe.route.groups" in grouped
+    with pytest.raises(ValueError, match="group-limited selection"):
+        tfm.init_params(jax.random.PRNGKey(0), moe_cfg(n_group=2))  # a softmax router has no groups
+
+
+@pytest.mark.parametrize("form", ["grouped", "every_expert"])
+def test_the_sixteen_shares_of_a_group_limited_layer_add_up_to_the_uncut_layer(form):
+    """The model-configs guide's share test at the published router's shape:
+    256 experts in 8 groups, 4 kept, top-8, cut sixteen ways (16 experts a
+    rank, half a group): every rank routes over all 256 in their groups,
+    renormalises over the 8 chosen whether held or not, and computes its own
+    experts' part; the sixteen parts, the shared expert counted once, are the
+    uncut layer's result."""
+    full = grouped_router_cfg()
+    mp = jax.tree_util.tree_map(lambda a: a[0], tfm.init_params(jax.random.PRNGKey(3), full)["blocks"]["mlp"])
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, D), jnp.float32)
+
+    def ffn(cfg, mp):
+        if form == "grouped":
+            return tfm._routed_ffn(h, mp, cfg)
+        stack = {name: mp[name][None] for name in tfm.EXPERT_WEIGHTS}
+        riding = {name: w for name, w in mp.items() if name not in tfm.EXPERT_WEIGHTS}
+        return tfm._routed_ffn(h, riding, cfg, experts=(stack, jnp.int32(0)))
+
+    with jax.default_matmul_precision("highest"):
+        whole, shared = ffn(full, mp), tfm._ffn(h, mp["shared"], full)
+        parts = [ffn(*share_of(full, mp, rank, 16)) for rank in range(16)]
+    np.testing.assert_allclose(sum(parts) - 15 * shared, whole, rtol=2e-5, atol=2e-6)
+    assert sum(float(jnp.max(jnp.abs(p - shared))) > 1e-3 for p in parts) >= 8  # shares are parts, not nothing

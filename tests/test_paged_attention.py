@@ -353,3 +353,73 @@ def test_windowed_prefill_kernel_reads_no_page_below_the_window():
     poison = [jnp.where(jnp.asarray(below)[None, :, None, None], jnp.nan, x) for x in (kp, vp)]
     out = pa.paged_prefill_attention(q, *poison, 1, bt, start, length, **kw)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
+# ------------------------------------------------- the latent kernels
+
+
+def _latent_inputs(dtype, lengths, seed=0, heads=16, c=128, rope=64):
+    """A latent pool of two layers (rows [c_kv | k_r] padded to whole lane
+    tiles), absorbed queries of the same width, and block tables as `_inputs` builds them."""
+    from ray_tpu.ops import latent_attention as la
+
+    W = la.row_width(c, rope)
+    rng = np.random.default_rng(seed)
+    pool = np.zeros((2, N, T, W), np.float32)
+    pool[..., : c + rope] = rng.standard_normal((2, N, T, c + rope))
+    q = np.zeros((B, heads, W), np.float32)
+    q[..., : c + rope] = rng.standard_normal((B, heads, c + rope))
+    bt = np.full((B, P), TRASH_PAGE, np.int32)
+    free = iter(rng.permutation(np.arange(1, N)))
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // T)):
+            bt[b, j] = next(free)
+    return jnp.asarray(q, dtype), jnp.asarray(pool, dtype), jnp.asarray(bt), jnp.asarray(lengths, jnp.int32), c
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lengths", sorted(LENGTHS))
+def test_latent_decode_kernel_is_its_gather_expression(lengths, dtype, tol):
+    """`paged_latent_attention` in interpret mode against
+    `latent_attention_gather`: one key/value row for 16 heads, the value a
+    prefix of the key; an inactive slot returns zeros."""
+    from ray_tpu.ops import latent_attention as la
+
+    q, pool, bt, n, c = _latent_inputs(dtype, LENGTHS[lengths])
+    got = la.paged_latent_attention(q, pool, jnp.int32(1), bt, n, scale=0.11, v_width=c, pages_per_block=2)
+    want = la.latent_attention_gather(q, pool[1], bt, jnp.maximum(n, 1), scale=0.11, v_width=c)
+    live = np.asarray(n) > 0
+    assert got.shape == (B, 16, c) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live], atol=tol, rtol=tol)
+    assert not np.asarray(got, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("start,length,positions", [(0, 20, 4), (32, 64, 4), (48, 70, 2), (64, 8 * 16, 8), (16, 17, 4)])
+def test_latent_prefill_kernel_is_its_gather_expression(start, length, positions):
+    """`paged_latent_prefill_attention` against `latent_prefill_attention_gather`
+    at a `start` on a page's border: rows below the length agree, blocks of
+    rows wholly past it return zeros, and nothing is NaN whatever stale rows a
+    block's buffer held."""
+    from ray_tpu.ops import latent_attention as la
+
+    _q, pool, bt, _n, c = _latent_inputs(jnp.float32, (P * T, 0, 0, 0), seed=3)
+    C = 32
+    q = jnp.asarray(np.random.default_rng(4).standard_normal((C, 16, pool.shape[-1])), jnp.float32)
+    got = la.paged_latent_prefill_attention(q, pool, jnp.int32(0), bt[0], jnp.int32(start), jnp.int32(length), scale=0.11, v_width=c,
+                                            positions_per_block=positions, pages_per_block=2)
+    want = la.latent_prefill_attention_gather(q, pool[0], bt[0], start, scale=0.11, v_width=c)
+    rows = max(0, min(C, length - start))
+    np.testing.assert_allclose(np.asarray(got)[:rows], np.asarray(want)[:rows], atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    past = -(-rows // positions) * positions  # the first block of rows wholly past the length
+    assert not np.asarray(got)[past:].any()
+
+
+def test_latent_kernels_refuse_what_they_cannot_tile_and_the_model_then_gathers():
+    from ray_tpu.ops import latent_attention as la
+
+    assert la.row_width(512, 64) == 640 and la.row_width(32, 8) == 128
+    assert la.can_tile(128, 128, 512, jnp.bfloat16) and not la.can_tile(8, 4, 32, jnp.float32)
+    q, pool, bt, n, _c = _latent_inputs(jnp.float32, LENGTHS["all_inactive_but_one"], heads=4, c=32, rope=8)
+    with pytest.raises(ValueError, match="gather expression"):
+        la.paged_latent_attention(q, pool, jnp.int32(0), bt, n, scale=1.0, v_width=32)

@@ -399,3 +399,69 @@ def test_a_kda_stacks_executables_carry_names_of_their_own(one_chip):
     prefill = lm._get_prefill(2).lower(params, sds((1, 16), jnp.int32), kv, sds((2,), jnp.int32), scalar, scalar, scalar)
     assert "module @jit_llm_decode_hybrid " in decode.as_text() and "module @jit_llm_prefill_hybrid_p2 " in prefill.as_text()
     assert "HloModule jit_llm_decode_hybrid," in decode.compile().as_text()
+
+
+# ------------------------------------------------ latent attention (PR 50)
+
+# dots.vlm1.inst's language model at published widths, one dense and one routed layer, 16 of 256 experts held, a small vocabulary.
+LATENT = dict(
+    vocab_size=2048, d_model=7168, n_layers=2, n_heads=128, n_kv_heads=128, d_head=192, d_ff=2048, max_seq_len=163840, norm_eps=1e-6,
+    kv_lora_rank=512, q_lora_rank=1536, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, rope_scaling=("yarn", 40.0, 4096.0, 32.0, 1.0, 1.0, 1.0),
+    n_experts=256, n_experts_per_tok=8, norm_topk_prob=True, router_score="sigmoid", route_scale=2.5, d_ff_shared=2048,
+    n_dense_layers=1, d_ff_dense=18432, n_experts_held=16, n_group=8, topk_group=4, attn_impl="naive", remat=False,
+)
+LATENT_SLOTS, LATENT_TABLE, LATENT_PAGE, LATENT_POOL = 32, 196, 128, 512
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_latent_kernels_compile_at_the_cells_widths(one_chip, kernel):
+    """32 slots x 196 pages of 128 positions, 128 heads over rows of 640 lanes
+    of which 512 are the value: the new cell's shapes, as Mosaic kernels with
+    their names."""
+    from ray_tpu.ops import latent_attention as la
+
+    sds = _sds(one_chip)
+    B, P, T, H, W, c = LATENT_SLOTS, LATENT_TABLE, LATENT_PAGE, 128, 640, 512
+    pool, scalar = sds((5, LATENT_POOL, T, W), jnp.bfloat16), sds((), jnp.int32)
+    if kernel == "decode":
+        f = lambda q, pool, layer, bt, n: la.paged_latent_attention(q, pool, layer, bt, n, scale=0.135, v_width=c, interpret=False)  # noqa: E731
+        text = jax.jit(f).lower(sds((B, H, W), jnp.bfloat16), pool, scalar, sds((B, P), jnp.int32), sds((B,), jnp.int32)).compile().as_text()
+        assert la.KERNEL_NAME in text
+    else:
+        f = lambda q, pool, layer, bt, start, n: la.paged_latent_prefill_attention(q, pool, layer, bt, start, n, scale=0.135, v_width=c, interpret=False)  # noqa: E731
+        text = jax.jit(f).lower(sds((256, H, W), jnp.bfloat16), pool, scalar, sds((P,), jnp.int32), scalar, scalar).compile().as_text()
+        assert la.PREFILL_KERNEL_NAME in text
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_a_latent_stacks_steps_run_the_latent_kernels_over_the_pool_in_place(one_chip, mosaic, step):
+    """The decode step and the largest prefill bucket of a latent stack at
+    published widths: the latent kernel of that step by name and not the
+    other's nor the softmax kernels', the donated pool aliased to the output
+    with no second pool among the temporaries, and no copy of a weight: a
+    decode step moves nothing of 4 M elements or more (`w_uk`, `w_uv`, `wq_b`,
+    `wo` and the expert stacks are read where they lie: without the barrier in
+    `_mla_mixer` it re-laid `wq_b` out, 75 MB a layer a step); a chunk moves
+    its own rows (the absorbed queries and outputs, 256 x 128 x 512) and
+    nothing as large as `wq_b`."""
+    from ray_tpu.ops import latent_attention as la
+
+    cfg = tfm.TransformerConfig(**LATENT)
+    sds = _sds(one_chip)
+    B, P, T, N = LATENT_SLOTS, LATENT_TABLE, LATENT_PAGE, LATENT_POOL
+    shapes = lambda make: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))  # noqa: E731
+    params, kv, scalar = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)), shapes(lambda: tfm.init_kv_pages(cfg, N, T)), sds((), jnp.int32)
+    assert tfm.decode_paths(cfg, T) == {"decode_attention": "latent_kernel"} and kv["ckv"].shape == (2, N, T, 640)
+    if step == "decode":
+        f = lambda params, tokens, positions, kv, bts: tfm.forward_decode(params, tokens, positions, cfg, kv, bts)  # noqa: E731
+        compiled = jax.jit(f, donate_argnums=(3,)).lower(params, sds((B,), jnp.int32), sds((B,), jnp.int32), kv, sds((B, P), jnp.int32)).compile()
+        here, other = la.KERNEL_NAME, la.PREFILL_KERNEL_NAME
+    else:
+        f = lambda params, tokens, kv, table, length, write_from: tfm.forward_prefill(params, tokens, cfg, kv, table, length, write_from)  # noqa: E731
+        compiled = jax.jit(f, donate_argnums=(2,)).lower(params, sds((1, P * T), jnp.int32), kv, sds((P,), jnp.int32), scalar, scalar).compile()
+        here, other = la.PREFILL_KERNEL_NAME, la.KERNEL_NAME
+    text, mem, pool_bytes = compiled.as_text(), compiled.memory_analysis(), 2 * N * T * 640 * 2
+    assert here in text and other not in text and pa.KERNEL_NAME not in text and pa.PREFILL_KERNEL_NAME not in text
+    assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < pool_bytes * (1 if step == "decode" else 4)
+    assert not _copies_over(text, 2**22 if step == "decode" else 2**25)

@@ -3,6 +3,7 @@ bytes, prefill (`--prefill`) against its own operations.
 
     python3 tools/paged_attention_bench.py [--heads 32 --kv-heads 32] [--pages-per-block 4,8,16]
     python3 tools/paged_attention_bench.py --prefill [--layouts 32:32,32:8,16:16] [--chunks 256,512,1024] [--block-q 256,512]
+    python3 tools/paged_attention_bench.py --latent [--batches 8,32] [--lengths 8192,16384,24576] [--pages-per-block 2,4,8] [--positions 2,4,8]
 
 For batch 4 / 16 and live lengths 256 / 1 024 / 4 096 (`--batches`, `--lengths`;
 every slot at that length, pages scattered over the pool; `--window` gives the
@@ -18,7 +19,12 @@ the live length, as a prefix hit's are) over live lengths 512 / 1 024 /
 prints microseconds a call, the causal FLOPs, their share of the chip's
 bf16 peak, and the largest difference from
 transformer.paged_prefill_attention_gather. Refuses to run off a TPU: a
-CPU time is not a device number. A builder's tool; no test and no metric
+CPU time is not a device number. `--latent` times the latent kernels of
+`ops/latent_attention.py` (128 heads over rows of 640 lanes, the value the
+first 512; pages of 128): the decode kernel over rows x context, against
+`max(FLOPs / peak FLOP/s, bytes / peak bytes/s)` of the absorbed step (242
+FLOP a byte: the ridge), and one 256-row chunk's kernel behind that context,
+against its absorbed FLOPs. A builder's tool; no test and no metric
 reads it.
 """
 
@@ -69,6 +75,8 @@ def main(argv) -> int:
     ap.add_argument("--chunks", default="256,512,1024")
     ap.add_argument("--block-q", default="")
     ap.add_argument("--heads-unrolled", default="")
+    ap.add_argument("--latent", action="store_true")
+    ap.add_argument("--positions", default="", help="latent prefill: positions of a chunk a grid step")
     a = ap.parse_args(argv)
 
     import jax
@@ -84,6 +92,8 @@ def main(argv) -> int:
         return 2
     with open(os.path.join(ROOT, "benchmarks", "lib", "peaks.json")) as f:
         peaks = json.load(f)["peaks"][dev.device_kind]
+    if a.latent:
+        return latent(a, dev, peaks)
     if a.prefill:
         return prefill(a, dev, peaks["bf16_flops_per_s"])
     bw = peaks["hbm_bytes_per_s"]
@@ -184,6 +194,78 @@ def prefill(a, dev, peak_flops) -> int:
                             "mxu_peak_share_pct": round(100 * flops / peak_flops / (us * 1e-6), 1),
                             "max_abs_diff_vs_gather": round(err, 5),
                         }), flush=True)
+    return 0
+
+
+def latent(a, dev, peaks) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import latent_attention as la
+
+    H, c, rope, T, P, N, L, C = 128, 512, 64, 128, 196, 2048, a.layers, 256
+    W, dtype, scale = la.row_width(c, rope), jnp.bfloat16, 0.135
+    key, rng = jax.random.PRNGKey(0), np.random.default_rng(0)
+    pool = jax.random.normal(key, (1, N, T, W), dtype).at[..., c + rope :].set(0)
+    ppbs = [int(x) for x in a.pages_per_block.split(",") if x] or [None]
+    positions = [int(x) for x in a.positions.split(",") if x] or [None]
+    batches = [int(x) for x in (a.batches if a.batches != "4,16" else "8,32").split(",")]
+    lengths = [int(x) for x in (a.lengths if a.lengths != "256,1024,4096" else "8192,16384,24576").split(",")]
+    print(f"device {dev.device_kind}, peaks {peaks['bf16_flops_per_s'] / 1e12:.0f} TFLOP/s, {peaks['hbm_bytes_per_s'] / 1e9:.0f} GB/s; "
+          f"{H} heads over rows of {W} lanes ({c} the value), pages of {T}, pool {N} pages, {L} calls a jit")
+    pair = 2.0 * H * (c + rope + c)  # FLOPs of one (query position, cached position) pair, absorbed
+    for length in lengths:
+        n = -(-length // T)
+        for B in batches:
+            # the pool cannot hold B slots of that length: slots share pages (the bytes read are the same)
+            bt = np.zeros((B, P), np.int32)
+            bt[:, :n] = np.stack([rng.permutation(np.arange(1, N))[:n] for _ in range(B)])
+            bt, lens = jnp.asarray(bt), jnp.full((B,), length, jnp.int32)
+            q = jax.random.normal(jax.random.fold_in(key, B * length), (B, H, W), dtype)
+            ref = la.latent_attention_gather(q[:2], pool[0], bt[:2], lens[:2], scale=scale, v_width=c).astype(jnp.float32)
+            for ppb in ppbs:
+                @jax.jit
+                def run(q, pool, bt, lens):
+                    def step(q, _):
+                        o = la.paged_latent_attention(q, pool, 0, bt, lens, scale=scale, v_width=c, pages_per_block=ppb)
+                        return q.at[..., :c].add((o * 1e-3).astype(q.dtype)), o
+                    return jax.lax.scan(step, q, None, length=L)[1][0]
+
+                try:
+                    err, us = err_and_us(run, (q, pool, bt, lens), ref, a.reps, L)
+                except Exception as e:  # noqa: BLE001 - a block the compiler refuses is a line of the sweep, not its end
+                    print(json.dumps({"kernel": la.KERNEL_NAME, "rows": B, "context": length, "pages_per_block": ppb, "refused": f"{type(e).__name__}: {e}"[:200]}), flush=True)
+                    continue
+                flops, nbytes = B * length * pair, B * length * (c + rope) * 2
+                least = max(flops / peaks["bf16_flops_per_s"], nbytes / peaks["hbm_bytes_per_s"])
+                print(json.dumps({"kernel": la.KERNEL_NAME, "rows": B, "context": length, "pages_per_block": ppb or max(1, la.BLOCK_TOKENS // T),
+                                  "us_per_call": round(us, 1), "gflop": round(flops / 1e9, 2), "latent_bytes": nbytes,
+                                  "roofline_pct": round(100 * least / (us * 1e-6), 1), "max_abs_diff_vs_gather": round(err, 5)}), flush=True)
+        # one chunk of C rows, the last of the context (a prefix hit's suffix; a miss's last chunk)
+        start = (length - C) // T * T
+        table = np.zeros((P,), np.int32)
+        table[:n] = rng.permutation(np.arange(1, N))[:n]
+        table = jnp.asarray(table)
+        q = jax.random.normal(jax.random.fold_in(key, length), (C, H, W), dtype)
+        ref = la.latent_prefill_attention_gather(q[:8], pool[0], table, start, scale=scale, v_width=c).astype(jnp.float32)
+        for R, ppb in ((r, p) for r in positions for p in ppbs):
+            @jax.jit
+            def run(q, pool, table):
+                def step(q, _):
+                    o = la.paged_latent_prefill_attention(q, pool, 0, table, start, start + C, scale=scale, v_width=c, positions_per_block=R, pages_per_block=ppb)
+                    return q.at[..., :c].add((o * 1e-3).astype(q.dtype)), o
+                return jax.lax.scan(step, q, None, length=L)[1][0]
+
+            try:
+                err, us = err_and_us(run, (q, pool, table), ref, a.reps, L)
+            except Exception as e:  # noqa: BLE001
+                print(json.dumps({"kernel": la.PREFILL_KERNEL_NAME, "context": start + C, "positions_per_block": R, "pages_per_block": ppb, "refused": f"{type(e).__name__}: {e}"[:200]}), flush=True)
+                continue
+            flops = pair * sum(range(start + 1, start + C + 1))
+            print(json.dumps({"kernel": la.PREFILL_KERNEL_NAME, "chunk": C, "context": start + C, "positions_per_block": R or la.PREFILL_POSITIONS,
+                              "pages_per_block": ppb or max(1, la.BLOCK_TOKENS // T), "us_per_call": round(us, 1), "absorbed_gflop": round(flops / 1e9, 1),
+                              "mxu_peak_share_pct": round(100 * flops / peaks["bf16_flops_per_s"] / (us * 1e-6), 1), "max_abs_diff_vs_gather": round(err, 5)}), flush=True)
     return 0
 
 
