@@ -2,8 +2,8 @@
 
 Continuous batching + paged KV cache + prefix reuse:
 
-- kv_cache:  free-list page allocator, refcounted pages, hashed-prefix
-             radix index (shared system prompts cost one physical copy);
+- kv_cache:  free-list page allocator, refcounted pages, exact prefix
+             trie (shared system prompts cost one physical copy);
 - engine:    resident continuous-batching loop (token-level join/leave,
              prefill admission against a token budget, typed
              reject-with-backpressure shedding);

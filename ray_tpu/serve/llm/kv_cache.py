@@ -5,15 +5,28 @@ is a pool of fixed-size pages; this module owns the BOOKKEEPING: which
 pages are free, which sequence holds which pages (its block table), and
 which full pages hold content that future prompts can share.
 
-Prefix reuse is a hashed-prefix radix index (vLLM's automatic prefix
-caching, SGLang's RadixAttention): each FULL page of a prompt is keyed
-by the chain (parent_key, tokens-in-page), so two prompts that share a
-system prefix resolve to the same physical pages and the shared prefix
-costs one physical copy. Pages are refcounted; when the last holder
-releases an indexed page it parks on an eviction LRU with its content
-intact — a later identical prefix revives it for free, while allocation
-pressure evicts from the LRU's cold end before declaring the pool
-exhausted.
+Prefix reuse is an exact radix index (vLLM's automatic prefix caching,
+SGLang's RadixAttention), kept as a trie over FULL pages of prompts: a
+node is one link of a content chain, named by a small integer, and
+`(parent node's number, tokens-in-page)` -> node is interned in one dict
+(the root is 0; numbers come from a counter and are never reused). Two
+prompts that share a system prefix walk the same nodes and resolve to
+the same physical pages, so the shared prefix costs one physical copy,
+and a page's lookup hashes that page's tokens and nothing of the pages
+under it: a prompt's walk is linear in its length. A node holds at most
+one page (the indexed copy of that content). It lives while it holds a
+page or has a child and is pruned, upwards, when it has neither: a
+chain whose root was evicted keeps its children addressable for the
+prompt that recommits the root, and a chain with no page left in it
+prunes to nothing. No hash or digest stands in for a comparison: the
+dict compares the page's tokens on every hit (Python's hash of ints is
+not randomised, hash(-1) == hash(-2), and a collision taken for a match
+would serve one request another's K/V).
+
+Pages are refcounted; when the last holder releases an indexed page it
+parks on an eviction LRU with its content intact — a later identical
+prefix revives it for free, while allocation pressure evicts from the
+LRU's cold end before declaring the pool exhausted.
 
 Sizing knobs (read by the engine, documented in README):
   RAY_TPU_KV_PAGE_TOKENS  tokens per page        (default 16)
@@ -23,6 +36,8 @@ Sizing knobs (read by the engine, documented in README):
 from __future__ import annotations
 
 import collections
+import itertools
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -33,7 +48,20 @@ from ...utils import lock_order
 # allocator never hands it out.
 TRASH_PAGE = 0
 
-_PrefixKey = Tuple  # nested (parent_key, tokens_tuple); () is the root
+_Link = Tuple[int, Tuple[int, ...]]  # (parent node's number, tokens-in-page)
+
+
+class _Node:
+    """One link of a content chain in the prefix trie."""
+
+    __slots__ = ("id", "link", "parent", "page", "children")
+
+    def __init__(self, id: int, link: Optional[_Link], parent: "Optional[_Node]"):
+        self.id = id
+        self.link = link  # its key in the allocator's dict; None for the root
+        self.parent = parent
+        self.page: Optional[int] = None  # the indexed copy of this content, if any
+        self.children = 0
 
 
 @dataclass
@@ -45,6 +73,10 @@ class SeqPages:
     pages: List[int]
     cached_tokens: int  # prompt positions covered by shared prefix pages
     released: bool = field(default=False, repr=False)
+    # Where allocate's walk of the index ended: the node of the last matched
+    # page (None: the root). The sequence holds the matched pages, so their
+    # nodes are alive and commit resumes from here.
+    node: Optional[_Node] = field(default=None, repr=False, compare=False)
 
     @property
     def num_pages(self) -> int:
@@ -52,7 +84,7 @@ class SeqPages:
 
 
 class PagedKVAllocator:
-    """Free-list page allocator + refcounts + hashed-prefix radix index.
+    """Free-list page allocator + refcounts + exact prefix trie.
 
     Thread-safe: the engine loop extends/releases while submitters
     allocate. `metrics` is an optional dict of pre-bound instrument
@@ -73,14 +105,19 @@ class PagedKVAllocator:
         self._lock = lock_order.tracked_lock("serve.llm.kv")
         self._free: List[int] = list(range(num_pages - 1, TRASH_PAGE, -1))
         self._ref: Dict[int, int] = {}
-        # prefix index: key -> page, and the reverse map for eviction
-        self._index: Dict[_PrefixKey, int] = {}
-        self._page_key: Dict[int, _PrefixKey] = {}
+        # prefix trie: (parent's number, tokens-in-page) -> node, and the
+        # node of each indexed page for eviction
+        self._root = _Node(0, None, None)
+        self._links: Dict[_Link, _Node] = {}
+        self._node_ids = itertools.count(1)
+        self._page_node: Dict[int, _Node] = {}
         # zero-ref indexed pages, oldest-released first (eviction order)
         self._evictable: "collections.OrderedDict[int, None]" = collections.OrderedDict()
         self._metrics = metrics or {}
         self.prefix_hits = 0
         self.prefix_misses = 0
+        self.index_s = 0.0  # spent under the lock in allocate's and commit's walks
+        self.index_calls = 0  # allocate calls that walked
         g = self._metrics.get("total")
         if g is not None:
             g.set(self.total_pages)
@@ -110,19 +147,20 @@ class PagedKVAllocator:
             return self._free.pop()
         if self._evictable:
             page, _ = self._evictable.popitem(last=False)  # coldest first
-            key = self._page_key.pop(page, None)
-            if key is not None:
-                self._index.pop(key, None)
+            node = self._page_node.pop(page)
+            node.page = None
+            while node.page is None and not node.children and node.parent is not None:
+                del self._links[node.link]
+                node = node.parent
+                node.children -= 1
             return page
         return None
 
     def _return_page_locked(self, page: int) -> None:
-        key = self._page_key.get(page)
-        if key is not None and self._index.get(key) == page:
+        if page in self._page_node:
             # Content stays addressable: park on the LRU, revive on match.
             self._evictable[page] = None
         else:
-            self._page_key.pop(page, None)
             self._free.append(page)
 
     def allocate(self, tokens) -> SeqPages:
@@ -132,20 +170,26 @@ class PagedKVAllocator:
         pool — after evicting every cold cached page — still cannot hold
         the prompt. Nothing is reserved on failure.
         """
-        tokens = list(tokens)
+        tokens = tuple(tokens)
         need = self.pages_for(len(tokens))
+        T = self.page_tokens
         with self._lock:
-            # Walk the radix index over FULL pages of the prompt.
+            # Walk the trie over FULL pages of the prompt, to the first link
+            # that is absent or holds no page.
             matched: List[int] = []
-            key: _PrefixKey = ()
-            n_full = len(tokens) // self.page_tokens if self.share_prefixes else 0
-            for i in range(n_full):
-                chunk = tuple(tokens[i * self.page_tokens:(i + 1) * self.page_tokens])
-                key = (key, chunk)
-                page = self._index.get(key)
-                if page is None:
-                    break
-                matched.append(page)
+            node = self._root
+            n_full = len(tokens) // T if self.share_prefixes else 0
+            if n_full:
+                t0 = time.perf_counter()
+                links = self._links
+                for i in range(n_full):
+                    child = links.get((node.id, tokens[i * T:(i + 1) * T]))
+                    if child is None or child.page is None:
+                        break
+                    matched.append(child.page)
+                    node = child
+                self.index_s += time.perf_counter() - t0
+                self.index_calls += 1
             fresh_needed = need - len(matched)
             free_now = len(self._free) + len(self._evictable)
             # Matched evictable pages are revived, not consumed from the
@@ -172,7 +216,7 @@ class PagedKVAllocator:
             self.prefix_hits += len(matched)
             self.prefix_misses += fresh_needed
             self._observe_locked(hits=len(matched), misses=fresh_needed)
-            return SeqPages(pages=matched + fresh, cached_tokens=len(matched) * self.page_tokens)
+            return SeqPages(pages=matched + fresh, cached_tokens=len(matched) * T, node=node)
 
     def extend(self, seq: SeqPages) -> int:
         """Appends one decode-growth page to `seq`'s block table."""
@@ -189,24 +233,37 @@ class PagedKVAllocator:
 
     def commit(self, seq: SeqPages, tokens) -> None:
         """Indexes `seq`'s full prompt pages so later prompts can share
-        them. Called after prefill (the pages now hold real k/v)."""
+        them. Called after prefill (the pages now hold real k/v), with the
+        tokens `allocate` was given: the walk resumes behind the pages that
+        call matched, which `seq` has held since."""
         if not self.share_prefixes:
             return
-        tokens = list(tokens)
+        tokens = tuple(tokens)
+        T = self.page_tokens
         with self._lock:
-            key: _PrefixKey = ()
-            for i in range(len(tokens) // self.page_tokens):
-                chunk = tuple(tokens[i * self.page_tokens:(i + 1) * self.page_tokens])
-                key = (key, chunk)
+            t0 = time.perf_counter()
+            links = self._links
+            if seq.released or seq.node is None:
+                first, node = 0, self._root  # its pages may be gone: the whole walk
+            else:
+                first, node = seq.cached_tokens // T, seq.node
+            for i in range(first, len(tokens) // T):
+                link = (node.id, tokens[i * T:(i + 1) * T])
                 page = seq.pages[i]
-                cur = self._index.get(key)
-                if cur is None and page not in self._page_key:
-                    self._index[key] = page
-                    self._page_key[page] = key
+                child = links.get(link)
+                cur = None if child is None else child.page
+                if cur is None and page not in self._page_node:
+                    if child is None:
+                        child = links[link] = _Node(next(self._node_ids), link, node)
+                        node.children += 1
+                    child.page = page
+                    self._page_node[page] = child
                 elif cur != page:
                     # A concurrent twin committed the same content first;
                     # ours stays private and frees normally.
                     break
+                node = child
+            self.index_s += time.perf_counter() - t0
 
     def release(self, seq: SeqPages) -> None:
         """Drops `seq`'s references. Idempotent — the cancel path and the
@@ -246,7 +303,10 @@ class PagedKVAllocator:
                 "used_pages": len(self._ref),
                 "free_pages": len(self._free),
                 "evictable_pages": len(self._evictable),
-                "indexed_pages": len(self._page_key),
+                "indexed_pages": len(self._page_node),
                 "prefix_hits": self.prefix_hits,
                 "prefix_misses": self.prefix_misses,
+                "index_s": self.index_s,
+                "index_calls": self.index_calls,
+                "index_nodes": len(self._links),
             }

@@ -8,7 +8,9 @@ scheduling logic fast; decode-vs-forward numerics live in
 test_models.py::test_paged_decode_matches_full_forward.
 """
 
+import collections
 import os
+import random
 import threading
 import time
 
@@ -127,6 +129,256 @@ def test_allocator_commit_concurrent_twin_keeps_private_pages():
     s3 = a.allocate(p)
     assert s3.pages == s1.pages  # the committed winner is the shared copy
     a.release(s3)
+
+
+class _NestedTupleIndex:
+    """The allocator as it stood before the trie, kept here as a plain
+    reference model: page i of a prompt is keyed by the nested tuple
+    (key of page i-1, tokens of page i), () for the root. Independent of
+    ray_tpu.serve.llm.kv_cache: the equivalence test holds the allocator
+    to what this returns, call by call."""
+
+    def __init__(self, num_pages, page_tokens):
+        self.T, self.total = page_tokens, num_pages - 1
+        self.free = list(range(num_pages - 1, 0, -1))
+        self.ref, self.index, self.page_key = {}, {}, {}
+        self.evictable = collections.OrderedDict()
+        self.hits = self.misses = self.evictions = 0
+
+    def _keys(self, tokens):
+        key = ()
+        for i in range(len(tokens) // self.T):
+            key = (key, tuple(tokens[i * self.T:(i + 1) * self.T]))
+            yield i, key
+
+    def _take(self):
+        if self.free:
+            return self.free.pop()
+        page, _ = self.evictable.popitem(last=False)
+        del self.index[self.page_key.pop(page)]
+        self.evictions += 1
+        return page
+
+    def allocate(self, tokens):
+        """(pages, cached_tokens), or ("exhausted", needed, free, total) with nothing reserved."""
+        matched = []
+        for _, key in self._keys(tokens):
+            if key not in self.index:
+                break
+            matched.append(self.index[key])
+        fresh = max(1, -(-len(tokens) // self.T)) - len(matched)
+        free = len(self.free) + len(self.evictable) - sum(p in self.evictable for p in matched)
+        if fresh > free:
+            return ("exhausted", fresh, free, self.total)
+        for p in matched:
+            self.evictable.pop(p, None)
+            self.ref[p] = self.ref.get(p, 0) + 1
+        pages = matched + [self._take() for _ in range(fresh)]
+        for p in pages[len(matched):]:
+            self.ref[p] = 1
+        self.hits, self.misses = self.hits + len(matched), self.misses + fresh
+        return pages, len(matched) * self.T
+
+    def extend(self, pages):
+        if not self.free and not self.evictable:
+            return ("exhausted", 1, 0, self.total)
+        pages.append(self._take())
+        self.ref[pages[-1]] = 1
+        return pages[-1]
+
+    def commit(self, pages, tokens):
+        for i, key in self._keys(tokens):
+            cur = self.index.get(key)
+            if cur is None and pages[i] not in self.page_key:
+                self.index[key], self.page_key[pages[i]] = pages[i], key
+            elif cur != pages[i]:
+                break
+
+    def release(self, pages):
+        for p in pages:
+            self.ref[p] -= 1
+            if not self.ref[p]:
+                del self.ref[p]
+                if p in self.page_key:
+                    self.evictable[p] = None
+                else:
+                    self.free.append(p)
+
+    def stats(self):
+        return {"total_pages": self.total, "used_pages": len(self.ref), "free_pages": len(self.free),
+                "evictable_pages": len(self.evictable), "indexed_pages": len(self.page_key),
+                "prefix_hits": self.hits, "prefix_misses": self.misses}
+
+
+def _exhausted(call):
+    try:
+        return call()
+    except KVPoolExhaustedError as e:
+        return ("exhausted", e.needed_pages, e.free_pages, e.total_pages)
+
+
+@pytest.mark.parametrize("num_pages", [12, 24])
+@pytest.mark.parametrize("page_tokens", [2, 4])
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_allocator_returns_what_the_nested_tuple_index_returned(seed, page_tokens, num_pages):
+    rng = random.Random(seed * 1000 + page_tokens * 100 + num_pages)
+    a, ref = PagedKVAllocator(num_pages=num_pages, page_tokens=page_tokens), _NestedTupleIndex(num_pages, page_tokens)
+    # a few shared documents over a small alphabet, -1 and -2 (equal hashes) in it
+    docs = [[rng.choice([-2, -1, 0, 1]) for _ in range(page_tokens * rng.randint(2, 7))] for _ in range(4)]
+    live = []  # (SeqPages, the reference's page list, tokens)
+    outcomes = collections.Counter()
+    for _ in range(400):
+        op = rng.random()
+        if op < 0.4 or not live:
+            doc = rng.choice(docs)
+            tokens = doc[:rng.randint(1, len(doc))] + [rng.randint(2, 5) for _ in range(rng.randint(0, 2 * page_tokens))]
+            got, want = _exhausted(lambda: a.allocate(tokens)), ref.allocate(tokens)
+            if want[0] == "exhausted":
+                assert got == want
+                outcomes["exhausted"] += 1
+            else:
+                assert (got.pages, got.cached_tokens) == want
+                live.append((got, want[0], tokens))
+                outcomes["hit" if want[1] else "miss"] += 1
+        elif op < 0.65:
+            seq, pages, tokens = rng.choice(live)
+            a.commit(seq, tokens)
+            ref.commit(pages, tokens)
+        elif op < 0.75:
+            seq, pages, _ = rng.choice(live)
+            assert _exhausted(lambda: a.extend(seq)) == ref.extend(pages)
+        else:
+            seq, pages, _ = live.pop(rng.randrange(len(live)))
+            a.release(seq)
+            ref.release(pages)
+        for seq, pages, _ in live:
+            assert seq.pages == pages
+        want = ref.stats()
+        got = a.stats()
+        assert {k: got[k] for k in want} == want
+        assert a._free == ref.free
+        assert list(a._evictable) == list(ref.evictable)  # the eviction order
+        assert got["index_nodes"] >= got["indexed_pages"]
+    assert ref.evictions and outcomes["hit"] and outcomes["miss"] and outcomes["exhausted"], (ref.evictions, outcomes)
+
+
+def test_allocator_chain_whose_root_was_evicted_matches_its_children_once_the_root_is_recommitted():
+    a = PagedKVAllocator(num_pages=7, page_tokens=2)  # 6 usable pages
+    doc = [1, 2, 3, 4, 5, 6]
+    s1 = a.allocate(doc)
+    a.commit(s1, doc)
+    a.release(s1)  # parked root first: the LRU evicts the chain's root before its leaves
+    stranger = a.allocate([9] * 8)  # 3 free pages + the coldest of the LRU
+    assert stranger.pages[-1] == s1.pages[0]
+    assert a.stats()["indexed_pages"] == 2 and a.stats()["index_nodes"] == 3  # the root's node lives by its child
+    a.release(stranger)
+    s2 = a.allocate(doc)  # the walk stops at the link that holds no page
+    assert s2.cached_tokens == 0 and not set(s2.pages) & set(s1.pages[1:])
+    a.commit(s2, doc)  # gives the root a page; the old children win, ours stay private
+    assert a.stats()["indexed_pages"] == 3 and a.stats()["index_nodes"] == 3
+    s3 = a.allocate(doc + [7])
+    assert s3.pages[:3] == [s2.pages[0]] + s1.pages[1:] and s3.cached_tokens == 6
+    for s in (s2, s3):
+        a.release(s)
+
+
+def test_allocator_prompts_whose_tokens_differ_only_by_equal_hashes_share_no_page():
+    assert hash(-1) == hash(-2) and hash((-1, 7)) == hash((-2, 7))
+    a = PagedKVAllocator(num_pages=16, page_tokens=2)
+    p1, p2 = [-1, 7, 3, 4, 5, 6], [-2, 7, 3, 4, 5, 6]
+    s1 = a.allocate(p1)
+    a.commit(s1, p1)
+    s2 = a.allocate(p2)
+    assert s2.cached_tokens == 0 and not set(s1.pages) & set(s2.pages)
+    a.commit(s2, p2)
+    # equal pages under parents that differ are two contents: the chain is the key
+    s3, s4 = a.allocate(p1), a.allocate(p2)
+    assert (s3.pages, s3.cached_tokens) == (s1.pages, 6) and (s4.pages, s4.cached_tokens) == (s2.pages, 6)
+    assert a.stats()["index_nodes"] == 6
+
+
+def test_allocator_index_prunes_to_nothing_when_no_page_is_left_in_it():
+    a = PagedKVAllocator(num_pages=9, page_tokens=2)
+    prompts = [[1, 2, 3, 4, 5], [1, 2, 3, 4, 6, 7], [1, 2, 8, 9], [5, 5]]
+    for p in prompts:
+        s = a.allocate(p)
+        a.commit(s, p)
+        a.release(s)
+    assert a.stats()["index_nodes"] == a.stats()["indexed_pages"] == 5  # (1,2) (3,4) (6,7) (8,9) (5,5)
+    stranger = a.allocate(list(range(100, 116)))  # the whole pool, committed by nobody
+    assert a.stats()["index_nodes"] == a.stats()["indexed_pages"] == 0 and not a._links
+    a.release(stranger)
+    assert a.free_pages() == 8
+
+
+class _CountedToken(int):
+    """A token that counts how often it is hashed."""
+
+    hashed = 0
+
+    def __hash__(self):
+        _CountedToken.hashed += 1
+        return int.__hash__(self)
+
+
+def test_allocator_hashes_a_prompts_tokens_a_bounded_number_of_times():
+    T, n = 4, 64
+    prompt = [_CountedToken(t % 7) for t in range(T * n + 1)]  # 64 full pages and a tail
+    a, ref = PagedKVAllocator(num_pages=4 * n, page_tokens=T), _NestedTupleIndex(4 * n, T)
+    _CountedToken.hashed = 0
+    s1 = a.allocate(prompt)
+    a.commit(s1, prompt)
+    first = _CountedToken.hashed
+    s2 = a.allocate(prompt)  # a hit of 64 pages
+    a.commit(s2, prompt)
+    total = _CountedToken.hashed
+    assert s2.cached_tokens == T * n
+    assert total - first <= 2 * len(prompt) and total <= 4 * len(prompt), (first, total)
+    # the nested-tuple key hashes the whole chain under a page at every lookup
+    _CountedToken.hashed = 0
+    for _ in range(2):
+        ref.commit(ref.allocate(prompt)[0], prompt)
+    assert _CountedToken.hashed >= 16 * total, (_CountedToken.hashed, total)
+
+
+def test_allocator_counts_its_index_walks():
+    a = PagedKVAllocator(num_pages=16, page_tokens=4)
+    assert {"index_s", "index_calls", "index_nodes"} <= set(a.stats())
+    assert (a.stats()["index_s"], a.stats()["index_calls"], a.stats()["index_nodes"]) == (0.0, 0, 0)
+    short = a.allocate([1, 2, 3])  # no full page: nothing to walk
+    a.commit(short, [1, 2, 3])
+    assert a.stats()["index_calls"] == 0 and a.stats()["index_nodes"] == 0
+    s = a.allocate(list(range(9)))
+    after_allocate = a.stats()
+    assert after_allocate["index_calls"] == 1 and after_allocate["index_s"] > 0
+    a.commit(s, list(range(9)))
+    st = a.stats()
+    assert st["index_calls"] == 1 and st["index_s"] > after_allocate["index_s"] and st["index_nodes"] == 2
+    unshared = PagedKVAllocator(num_pages=16, page_tokens=4, share_prefixes=False)
+    s = unshared.allocate(list(range(9)))
+    unshared.commit(s, list(range(9)))
+    st = unshared.stats()
+    assert (st["index_s"], st["index_calls"], st["index_nodes"]) == (0.0, 0, 0)
+
+
+def test_engine_stats_carry_the_index_counters_and_counter_mean_reads_them():
+    from benchmarks.readers import counter_mean
+
+    eng = InferenceEngine(StubModel(max_slots=2, max_pages_per_seq=8), EngineConfig(page_tokens=4, pool_pages=32))
+    try:
+        marks = [{"engine": eng.stats()}]
+        for _ in range(3):
+            _collect(eng, list(range(10)), 2)
+        marks.append({"engine": eng.stats()})
+    finally:
+        eng.close()
+    kv = marks[-1]["engine"]["kv"]
+    assert kv["index_calls"] == 3 and kv["index_s"] > 0 and kv["index_nodes"] == 2
+    args = {"sum": "kv.index_s", "count": "kv.index_calls", "scale": 1000}
+    assert counter_mean.read({"marks": marks}, args) == pytest.approx(1000 * kv["index_s"] / 3)
+    # a program without the counter (a parent commit): nothing, and no raise
+    without = [{"engine": {"kv": {k: v for k, v in m["engine"]["kv"].items() if not k.startswith("index_")}}} for m in marks]
+    assert counter_mean.read({"marks": without}, args) is None
 
 
 # ---------------------------------------------------------------- engine
