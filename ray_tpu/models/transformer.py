@@ -751,8 +751,9 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh], window=Non
 # phi(k_s)^T [hd, D] and z = sum_s exp(L_t - L_s) phi(k_s) [D]. Three forms
 # of the same numbers: `retention_whole` (a whole sequence, nothing kept),
 # `retention_chunk` (a prefill chunk, from a state to a state) and
-# `retention_step` (one token a sequence; ops/power_retention.py is its
-# kernel). Everything here is float32, the matmuls at RETENTION_PRECISION:
+# `retention_step` (one token a sequence); ops/power_retention.py holds the
+# last two as kernels over the paged pool, of which these are the parity
+# references. Everything here is float32, the matmuls at RETENTION_PRECISION:
 # a state is thousands of decayed additions into one array.
 #
 # The state's layout: S is stored TRANSPOSED, [hd (v's index), D], the pairs
@@ -798,7 +799,9 @@ def retention_chunk(q, k, v, log_g, s_in, z_in, valid=None):
     (y [c, n_heads, hd] float32, s_out, z_out). The in-chunk pairs by the
     quadratic expression, the earlier ones through phi(q)^T S_in. `valid` [c]
     bool: rows past a prompt's length (padding: they follow every valid row)
-    neither decay the state nor enter it; their own outputs are arbitrary."""
+    neither decay the state nor enter it; their own outputs are arbitrary.
+    The plain expression (phi(q) and phi(k) whole, in HBM): training's, and
+    the reference of ops/power_retention.power_retention_prefill."""
     c, H, hd = q.shape
     q, k, v, log_g = _retention_heads(q, k, v, log_g)
     dot = partial(jnp.einsum, precision=RETENTION_PRECISION)
@@ -1869,14 +1872,23 @@ def _retention_chunk(cfg: TransformerConfig, ctx):
     """The chunk at positions [c0, c0 + rows) of the sequence whose state is
     page `slot`. It starts from what the page holds, the state after
     position c0 - 1, or from nothing where c0 is 0, whatever the page's last
-    owner left there; `valid` [rows]: the rows below the length."""
+    owner left there; `valid` [rows]: the rows below the length. The kernel
+    that never holds phi in HBM where it can tile the chunk, in place, else
+    the plain expression over a copy of the state."""
+    from ..ops.power_retention import can_tile_prefill, power_retention_prefill
+
     slot, c0 = ctx["block_table"][0], ctx["c0"]
     valid = c0 + jnp.arange(ctx["rows"]) < ctx["length"]
+    use_kernel = can_tile_prefill(ctx["rows"], cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
 
     def attend_in(where: LayerPlace, pool):
         layer, sp, zp = where.layer, pool["s"], pool["z"]
 
         def attend(q, k, v, log_g):
+            if use_kernel:
+                with jax.named_scope("retention.chunk"):
+                    y, sp_, zp_ = power_retention_prefill(q[0], k[0], v[0], log_g[0], sp, zp, layer, slot, c0 > 0, valid, eps=RETENTION_EPS)
+                return y[None].astype(cfg.dtype), (sp_, zp_)
             s_in = jnp.where(c0 > 0, sp[layer, slot], 0.0)
             z_in = jnp.where(c0 > 0, zp[layer, slot], 0.0)
             with jax.named_scope("retention.chunk"):
@@ -1919,6 +1931,13 @@ def _retention_decode_path(cfg: TransformerConfig, page_tokens: int) -> str:
     from ..ops.power_retention import can_tile
 
     return "retention_kernel" if can_tile(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) else "xla_step"
+
+
+def _retention_prefill_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    from ..ops.power_retention import can_tile_prefill
+
+    rows, _ = prefill_chunk_tokens(cfg, 1, page_tokens)
+    return "retention_kernel" if can_tile_prefill(rows, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) else "xla_chunk"
 
 
 # "kda": a state SLOT. Of every KDA layer a sequence keeps `s` [layers, slots,
@@ -2126,11 +2145,12 @@ class LayerKind(NamedTuple):
     chunk: Callable
     step: Callable
     decode_path: Callable  # (cfg, page_tokens) -> which expression `step` runs, for PagedLM.describe
+    prefill_path: Optional[Callable] = None  # the same of `chunk`, where that is a choice of its own (else decode_path's answer holds for both)
 
 
 KINDS = {
     "softmax": LayerKind(("k", "v"), "page", False, _kv_leaves, _softmax_whole, _kv_chunk, _kv_step, paged_attention_path),
-    "retention": LayerKind(("s", "z"), "page", True, _retention_leaves, _retention_whole, _retention_chunk, _retention_step, _retention_decode_path),
+    "retention": LayerKind(("s", "z"), "page", True, _retention_leaves, _retention_whole, _retention_chunk, _retention_step, _retention_decode_path, _retention_prefill_path),
     "kda": LayerKind(("s", "tail"), "slot", True, _kda_leaves, _kda_whole, _kda_chunk, _kda_step, _kda_decode_path),
     "latent": LayerKind(("ckv",), "page", False, _latent_leaves, _latent_whole, _latent_chunk, _latent_step, _latent_path),
 }
@@ -2166,6 +2186,15 @@ def decode_paths(cfg: TransformerConfig, page_tokens: int) -> Dict[str, str]:
     and over the state slots (`decode_state`): PagedLM.describe."""
     keys = {"page": "decode_attention", "slot": "decode_state"}
     return {keys[KINDS[kind].indexed]: KINDS[kind].decode_path(cfg, page_tokens) for kind, _ in cache_layout(cfg).kinds}
+
+
+def prefill_paths(cfg: TransformerConfig, page_tokens: int) -> Dict[str, str]:
+    """Which expression a prefill chunk runs (`prefill_attention` over pages,
+    `prefill_state` over slots), for the kinds that choose it apart from the
+    decode step's: PagedLM.describe, beside `decode_paths`."""
+    keys = {"page": "prefill_attention", "slot": "prefill_state"}
+    rows = [KINDS[kind] for kind, _ in cache_layout(cfg).kinds]
+    return {keys[row.indexed]: row.prefill_path(cfg, page_tokens) for row in rows if row.prefill_path}
 
 
 def init_kv_pages(

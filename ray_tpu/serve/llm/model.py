@@ -294,13 +294,14 @@ class PagedLM:
         executables run over the pages (`decode_attention`: "paged_kernel" or
         "xla_gather", transformer.paged_attention_path; "latent_kernel" or
         "xla_gather" over latent pages; "retention_kernel" or "xla_step" over
-        a state) and over the state slots (`decode_state`:
+        a state, whose prefill chunk says so apart: `prefill_attention`,
+        "retention_kernel" or "xla_chunk") and over the state slots (`decode_state`:
         "kda_kernel" or "xla_step"): each kind's own answer, `KINDS`), and
         what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
 
         devs = self._jax.devices()
-        paths = self._tfm.decode_paths(self.cfg, self.page_tokens)
+        paths = {**self._tfm.decode_paths(self.cfg, self.page_tokens), **self._tfm.prefill_paths(self.cfg, self.page_tokens)}
         cache = {"kind": self.cache_kind, **({"state_bytes": self.state_bytes} if self.state_bytes else {}), "page_bytes": self.page_bytes}
         return {
             "pid": os.getpid(),

@@ -377,6 +377,99 @@ def test_the_decode_kernel_in_interpret_mode_is_retention_step_in_place(heads):
     np.testing.assert_array_equal(z2[1, 1], z[1, 1])
 
 
+def chunk_inputs(seed, c, heads, gates):
+    """A chunk's operands at the kernel's head width, and a pool of two layers
+    and three slots that holds another sequence's leftovers everywhere."""
+    n_heads, n_kv = heads
+    hd, D = 128, tfm.retention_state_dim(128)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q, k, v = (jax.random.normal(ks[i], (c, h, hd)) for i, h in enumerate((n_heads, n_kv, n_kv)))
+    log_g = {"seeded": jax.nn.log_sigmoid(jax.random.normal(ks[3], (c, n_kv))), "near_zero": -1e-3 * jax.random.uniform(ks[3], (c, n_kv)),
+             "strongly_negative": -8.0 - 8.0 * jax.random.uniform(ks[3], (c, n_kv))}[gates]
+    s, z = jax.random.normal(ks[4], (2, 3, n_kv, hd, D)), jnp.abs(jax.random.normal(ks[5], (2, 3, n_kv, D)))
+    return q, k, v, log_g, s, z
+
+
+# (q, k, v, log_g, s, z, layer, slot, carried, valid): ONE jitted function, so that the cases of one shape share its compilation
+prefill_kernel = jax.jit(functools.partial(power_retention.power_retention_prefill, eps=tfm.RETENTION_EPS, interpret=True))
+
+
+PREFILL_CASES = {  # name: (rows, of them valid, (n_heads, n_kv_heads), the gates, whether the slot's state is carried in)
+    "from_nothing_whatever_the_slot_holds": (16, 16, (4, 2), "seeded", False),
+    "from_the_slots_state": (16, 16, (4, 2), "seeded", True),
+    "a_padded_last_chunk": (16, 13, (4, 2), "seeded", True),
+    "a_padded_first_chunk": (16, 5, (4, 2), "seeded", False),
+    "gates_near_zero": (16, 16, (4, 2), "near_zero", True),
+    "gates_strongly_negative": (16, 16, (4, 2), "strongly_negative", True),
+    "five_query_heads_a_kv_head": (8, 8, (10, 2), "seeded", True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_CASES))
+def test_the_prefill_kernel_in_interpret_mode_is_retention_chunk_in_place(case):
+    """One chunk into (layer 1, slot 2) of a pool of two layers and three
+    slots: the valid rows' outputs and the state after them are
+    `retention_chunk`'s from the slot's state, or from nothing where none is
+    carried (the slot then holds NaN: nothing of it may be read into the
+    result), and every other page of the pool is bit for bit what it was."""
+    c, n_valid, heads, gates, carried = PREFILL_CASES[case]
+    q, k, v, log_g, s, z = chunk_inputs(7, c, heads, gates)
+    layer, slot, valid = 1, 2, jnp.arange(c) < n_valid
+    assert power_retention.can_tile_prefill(c, *heads, 128) and not power_retention.can_tile_prefill(12, *heads, 128)
+    assert not power_retention.can_tile_prefill(16, 4, 2, 16)
+    if not carried:
+        s, z = s.at[layer, slot].set(jnp.nan), z.at[layer, slot].set(jnp.nan)
+    y, s2, z2 = prefill_kernel(q, k, v, log_g, s, z, layer, slot, carried, valid)
+    zero = lambda a: a if carried else jnp.zeros_like(a)  # noqa: E731
+    want_y, want_s, want_z = tfm.retention_chunk(q, k, v, log_g, zero(s[layer, slot]), zero(z[layer, slot]), valid)
+    assert worst(y[:n_valid], want_y[:n_valid]) <= 1e-4 * float(jnp.max(jnp.abs(want_y[:n_valid])))
+    np.testing.assert_allclose(s2[layer, slot], want_s, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(z2[layer, slot], want_z, rtol=1e-5, atol=1e-5)
+    for before, after in ((s, s2), (z, z2)):
+        np.testing.assert_array_equal(after[0], before[0])
+        np.testing.assert_array_equal(after[1, :2], before[1, :2])
+
+
+def test_two_chunks_through_the_prefill_kernel_leave_the_state_and_give_the_outputs_of_the_whole():
+    """40 rows as a chunk of 24 from nothing and a padded chunk of 24 from
+    what the first left in the slot: the outputs are `retention_whole`'s over
+    the 40 rows, and the slot's state is what the plain chunks leave."""
+    n, c = 40, 24
+    q, k, v, log_g, s, z = chunk_inputs(8, 2 * c, (4, 2), "near_zero")
+    layer, slot = 0, 1
+    want = tfm.retention_whole(q[None, :n], k[None, :n], v[None, :n], log_g[None, :n], chunk=c)[0]
+    state = (jnp.zeros_like(s[layer, slot]), jnp.zeros_like(z[layer, slot]))
+    got = []
+    for i in range(2):
+        rows, valid = slice(i * c, (i + 1) * c), jnp.arange(i * c, (i + 1) * c) < n
+        y, s, z = prefill_kernel(q[rows], k[rows], v[rows], log_g[rows], s, z, layer, slot, i > 0, valid)
+        _, *state = tfm.retention_chunk(q[rows], k[rows], v[rows], log_g[rows], *state, valid)
+        got.append(y)
+    assert worst(jnp.concatenate(got)[:n], want) <= 1e-4 * float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(s[layer, slot], state[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(z[layer, slot], state[1], rtol=1e-5, atol=1e-5)
+
+
+def test_a_prefill_at_the_kernels_head_width_goes_through_it_and_says_so():
+    """`forward_prefill` at a head of 128 lanes picks the kernel by the shapes
+    (describe() says so), at TINY widths the plain expression; a prompt of
+    three chunks, the last padded, into a slot that held NaN ends at the
+    logits of the whole-sequence forward, which never meets the kernel."""
+    cfg = tfm.tiny(attn_impl="naive", dtype=jnp.float32, retention_degree=2, n_heads=2, n_kv_heads=1, d_head=128, n_layers=2)
+    assert tfm.prefill_paths(cfg, PAGE) == {"prefill_attention": "retention_kernel"} and tfm.prefill_paths(seeded(0)[0], PAGE) == {"prefill_attention": "xla_chunk"}
+    assert tfm.prefill_paths(tfm.tiny(), 16) == {}
+    params = tfm.init_params(jax.random.PRNGKey(2), cfg)
+    lm = PagedLM(cfg, params, num_pages=3, page_tokens=PAGE, max_slots=2, max_pages_per_seq=1)
+    assert lm.describe()["prefill_attention"] == "retention_kernel" and lm.describe()["decode_attention"] == "retention_kernel"
+    n, tokens = 41, jax.random.randint(jax.random.PRNGKey(3), (41,), 1, cfg.vocab_size, jnp.int32)
+    kv = jax.tree_util.tree_map(lambda a: a + jnp.nan, lm.kv)
+    padded = jnp.zeros((1, PAGE), jnp.int32).at[0, :n].set(tokens)
+    logits, kv = jax.jit(lambda p, t, kv: tfm.forward_prefill(p, t, cfg, kv, jnp.array([2]), n, 0))(params, padded, kv)
+    want = jax.jit(lambda p, t: tfm.forward(p, t, cfg))(params, tokens[None])[0, n - 1]
+    assert worst(logits[0], want) <= TOLERANCE
+    assert all(bool(jnp.all(jnp.isnan(kv[name][:, 1]))) and bool(jnp.all(jnp.isfinite(kv[name][:, 2]))) for name in ("s", "z"))
+
+
 # ------------------------------------------- (f) what the other models keep
 
 # sha256 (first 16 hex digits), taken at the parent commit of PR 48 and PR 49 (28daf63, before the four forwards walked one plan),
@@ -504,6 +597,6 @@ def test_one_layout_says_what_a_model_caches_and_paged_lm_reads_it(cell_name):
     assert described["cache"]["kind"] == kind and lm.shares_prefix_pages is shares
     assert set(described["cache"]) == {"kind", "page_bytes"} | ({"state_bytes"} if "slot" in indexed.values() else set())
     assert set(described) == {"pid", "platform", "device_kind", "device_count", "cache", "decode_attention", "peak_bytes_in_use", "compile"} | (
-        {"decode_state"} if "slot" in indexed.values() else set())
+        {"decode_state"} if "slot" in indexed.values() else set()) | ({"prefill_attention"} if kind == "state" else set())
     assert lm._get_decode().__name__ == decode_name and lm._get_prefill(2 if layout.kv else 1).__name__ == decode_name.replace("decode", "prefill") + ("_p2" if layout.kv else "_p1")
     assert lm.page_bytes == sum(math.prod(pool[name].shape) * pool[name].dtype.itemsize for name, by in indexed.items() if by == "page") // pages

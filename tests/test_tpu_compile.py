@@ -291,14 +291,19 @@ def test_retention_decode_step_is_one_kernel_a_layer_over_the_pool_in_place(one_
     assert mem.temp_size_in_bytes < 64 * 2**20
 
 
-def test_retention_prefill_bucket_updates_the_pool_in_place(one_chip, mosaic):
+def test_retention_prefill_bucket_is_one_kernel_a_layer_over_the_pool_in_place(one_chip, mosaic):
     """The one prefill bucket (a page of 4 096 positions walked in 256-row
-    chunks): the pool aliased, and the temporaries (phi of a chunk's queries
-    and keys, one sequence's state in and out) far under the pool."""
+    chunks): a chunk's retention is the Mosaic kernel under its name, the
+    pool aliased, and neither phi of a chunk's queries (341 MB) nor a copy of
+    one sequence's state (34 MB) is among the temporaries."""
+    from ray_tpu.ops import power_retention
+
     cfg, compiled = _compile_state(one_chip, "prefill")
-    mem = compiled.memory_analysis()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert tfm.prefill_paths(cfg, STATE_PAGE_TOKENS) == {"prefill_attention": "retention_kernel"}
+    assert power_retention.PREFILL_KERNEL_NAME in text and "tpu_custom_call" in text
     assert mem.alias_size_in_bytes >= _state_pool_bytes(cfg)
-    assert mem.temp_size_in_bytes < 2**30
+    assert mem.temp_size_in_bytes < 64 * 2**20
 
 
 def test_a_state_models_executables_carry_names_of_their_own(one_chip):
