@@ -497,13 +497,21 @@ PARENT = {
         forward="2c99e380bd5aba4c"),
 }
 
-# The digests that the refactor (PR 48's, landed by PR 49) itself moved, (cell, program) -> (the digest since, why), PARENT keeping what they were. For each,
+# The digests that a later PR moved on purpose, (cell, program) -> (the digest since, why), PARENT keeping what they were. For each of the refactor's (PR 48's, landed by PR 49),
 # the chip's optimised program was compared at published widths (v5e, compiled here; CHANGES.md, PR 49): but for the Mosaic
 # kernels' serialised bodies (transformer.py's line numbers) the same instructions in the same order, the same bytes.
 MOVED = {
     ("trinitymini-serve-agent-turns", "decode"): ("41882a5b82c625bb", "the experts' index (layer - first) is taken before the layer's rope switch, as prefill took it: two scalar ops change places"),
     ("trinitymini-serve-agent-turns", "decode_stats"): ("0f9d81c8ca7b3ff3", "as decode"),
     ("solaropen2-serve-reasoning-batch", "decode_stats"): ("d44acfaf0881fb72", "the step's two counters are summed a member of a segment, as every other routed model's, not over one concatenation"),
+    # PR 55, the whole-sequence attention of the configurations whose `forward` is flash (attn_impl "full"); every decode, prefill,
+    # decode_stats and params digest holds, and so do Trinity's (naive, windows), Brumby's and Solar-Open2's forwards.
+    ("dsllm7b-serve-chat-steady", "forward"): ("06d1b3f2a254b9fb", "PR 55: the causal flash kernels (ops/flash_attention.py) walk a diagonal block in sub-tiles, mask only there, name the block in VMEM on a skipped step and keep row statistics, lse and delta a row's value in every lane"),
+    ("mistral7b-train-seq4k-1chip", "forward"): ("cede48bbb077cf14", "as above"),
+    ("mistral7b-train-seq4k-1chip", "grad"): ("44d8f3d8e3cadcac", "as above, and its two backward kernels"),
+    ("mistral7b-train-seq4k-1chip", "zero_step"): ("5552f02e7829f78d", "as grad: the four-chip cell's program"),
+    ("olmoe-train-seq4k-1chip", "forward"): ("244f65fe8fddd677", "as above"),
+    ("olmoe-train-seq4k-1chip", "grad"): ("6adc8fe8eff1fe69", "as above, and its two backward kernels"),
 }
 
 

@@ -470,3 +470,34 @@ def test_a_latent_stacks_steps_run_the_latent_kernels_over_the_pool_in_place(one
     assert here in text and other not in text and pa.KERNEL_NAME not in text and pa.PREFILL_KERNEL_NAME not in text
     assert mem.alias_size_in_bytes >= pool_bytes and mem.temp_size_in_bytes < pool_bytes * (1 if step == "decode" else 4)
     assert not _copies_over(text, 2**22 if step == "decode" else 2**25)
+
+
+@pytest.mark.parametrize("batch,heads,kv_heads", [(3, 32, 8), (4, 16, 16)], ids=["mistral_gqa", "olmoe"])
+def test_flash_kernels_compile_at_the_training_cells_shapes(one_chip, mosaic, batch, heads, kv_heads):
+    """The three causal flash kernels (forward; dq; dk, dv) at the training
+    cells' attention, q [batch, 4096, heads, 128] in bfloat16, with the tiles
+    the module picks from the shape (square blocks whose diagonal is walked
+    in sub-tiles): Mosaic takes each, inside the VMEM a kernel may use, and
+    the three results keep the signatures `flash_attn_roofline*` tells the
+    kinds apart by (benchmarks/metrics/flash_attn_roofline.json)."""
+    import json
+    import os
+
+    import ray_tpu.ops.flash_attention  # noqa: F401 - the package exports the function under the module's name
+
+    fa = sys.modules["ray_tpu.ops.flash_attention"]
+    sds = _sds(one_chip)
+    s, hd = 4096, 128
+    block_q, block_k, sub = fa._pick_blocks(s, hd, jnp.bfloat16, None, None, False)
+    assert block_q == block_k and sub and block_q >= 2 * sub and fa.causal_work_ratio(s, block_q, block_k, sub) < 1.13
+    q, kv = sds((batch, s, heads, hd), jnp.bfloat16), sds((batch, s, kv_heads, hd), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", "flash_attn_roofline.json")) as f:
+        kinds = json.load(f)["args"]["kinds"]
+    found = [next((kind for kind, pattern in kinds.items() if re.search(pattern, line)), None) for line in calls]
+    assert sorted(found) == ["dkv", "dq", "fwd"], found
