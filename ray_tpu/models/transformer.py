@@ -413,6 +413,55 @@ HOT_SAVE_NAMES = (
 )
 
 
+# THE table of device-side names: every `jax.named_scope` this package opens,
+# each with the work that lies under it (as `KINDS` is the one table of layer
+# kinds). A scope is metadata of the compiled program (an op's `op_name` path)
+# and nothing at run time: a device trace carries it per op (`tf_op`), with
+# the phase beside it (`jvp(`: forward, `transpose(jvp(`: backward,
+# `rematted_computation`: recomputation, neither: the update), and
+# benchmarks/lib/xplane_meta.py and tools/device_scope_report.py split a
+# step's device time by both. An op under nested scopes counts under the
+# innermost. A new layer kind owes its scopes here beside its row in `KINDS`;
+# tests/test_scopes.py holds the table to the source and the compiled steps
+# to the table.
+SCOPES = {
+    "embed": "the token embeddings' gather (and its scale)",
+    "norm": "`_norm`: a block's norms (post-norms too) and the final one",
+    "attn.qkv": "the q, k, v projections (and a retention layer's gate logits)",
+    "attn.qk_norm": "the RMSNorm of q and k between projection and rope",
+    "attn.rope": "rope on q and k",
+    "attn.core": "`attend` and the views of q, k, v and o on both sides of it: the kernels, a cache's writes, XLA's copies round them",
+    "attn.window": "a windowed layer's attention, whole-sequence or paged",
+    "attn.gate": "the attention output's sigmoid gate",
+    "attn.out": "`wo`",
+    "attn.mla.q": "a latent layer's query: low-rank pair, norm, split, rope",
+    "attn.mla.kv_down": "a latent layer's down-projection: c_kv, k_r",
+    "attn.mla.expand": "the expanded form's up-projections of c_kv",
+    "attn.mla.absorb": "the absorbed form's `w_uk` on the query, `w_uv` on the output",
+    "attn.mla.out": "a latent layer's `wo`",
+    "kda.gates": "a KDA layer's decay and beta",
+    "kda.conv": "its short convolutions and q/k norms",
+    "kda.chunk": "its chunked recurrence (prefill, whole sequences)",
+    "kda.step": "its one-token recurrence (decode)",
+    "kda.out": "its output norm, gate",
+    "retention.chunk": "a retention layer's prefill chunk",
+    "ffn": "the dense feed-forward (a routed layer keeps its `moe.*`)",
+    "moe.router": "router logits, top-k, weights",
+    "moe.route.groups": "group-limited selection",
+    "moe.dispatch": "rows sorted by expert",
+    "moe.experts": "the experts' products",
+    "moe.combine": "rows back to token order under the router's weights",
+    "moe.shared": "the shared expert",
+    "residual": "a block's residual adds",
+    "head": "the logits' product",
+    "loss": "log-softmax, the target's gather, the mean (train/zero.py: its mean over the devices)",
+    "optimizer": "`tx.update` and `optax.apply_updates` of the unsharded step",
+    "zero.grad_scatter": "train/zero.py: a gradient's flattening and its reduce-scatter",
+    "zero.update": "train/zero.py: the parameters' shards, `tx.update`, `apply_updates`",
+    "zero.param_gather": "train/zero.py: the updated shards' all-gather and unflattening",
+}
+
+
 def rms_norm(x, scale, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * lax.rsqrt(var + eps)).astype(x.dtype) * scale
@@ -437,9 +486,10 @@ def layer_norm(x, scale, eps):
 
 
 def _norm(x, scale, cfg: TransformerConfig):
-    if cfg.norm_type == "layer":
-        return layer_norm(x, scale, cfg.norm_eps)
-    return rms_norm(x, scale, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        if cfg.norm_type == "layer":
+            return layer_norm(x, scale, cfg.norm_eps)
+        return rms_norm(x, scale, cfg.norm_eps)
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -484,15 +534,17 @@ def _cos_sin(cfg: TransformerConfig, angles):
 
 
 def rope_tables(cfg: TransformerConfig, seq_len: int):
-    freqs = _rope_freqs(cfg)
-    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * freqs[None, :]
-    return _cos_sin(cfg, angles)  # [seq, rotary_dim/2]
+    with jax.named_scope("attn.rope"):
+        freqs = _rope_freqs(cfg)
+        angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * freqs[None, :]
+        return _cos_sin(cfg, angles)  # [seq, rotary_dim/2]
 
 
 def rope_at(cfg: TransformerConfig, positions):
     """rope_tables' rows at the positions given [n]: cos, sin [n, rotary_dim/2]."""
-    angles = positions.astype(jnp.float32)[:, None] * _rope_freqs(cfg)[None, :]
-    return _cos_sin(cfg, angles)
+    with jax.named_scope("attn.rope"):
+        angles = positions.astype(jnp.float32)[:, None] * _rope_freqs(cfg)[None, :]
+        return _cos_sin(cfg, angles)
 
 
 def _rotate(x, cos, sin, interleave: bool):
@@ -562,10 +614,12 @@ def _per_layer(cfg: TransformerConfig, first: int, n: int):
     for what the config does not vary (nothing rides, the body is as before)."""
     windows = rope = None
     if cfg.windows:
-        w = jnp.asarray(cfg.windows[first:first + n], jnp.int32)
-        windows = jnp.where(w > 0, w, NO_WINDOW)
+        with jax.named_scope("attn.window"):
+            w = jnp.asarray(cfg.windows[first:first + n], jnp.int32)
+            windows = jnp.where(w > 0, w, NO_WINDOW)
     if cfg.rope_layers:
-        rope = jnp.asarray(cfg.rope_layers[first:first + n], bool)
+        with jax.named_scope("attn.rope"):
+            rope = jnp.asarray(cfg.rope_layers[first:first + n], bool)
     return windows, rope
 
 
@@ -580,7 +634,8 @@ def _rope_switch(cos, sin, rope_on):
     where the layer's switch (a traced bool) is off."""
     if rope_on is None:
         return cos, sin
-    return jnp.where(rope_on, cos, 1.0), jnp.where(rope_on, sin, 0.0)
+    with jax.named_scope("attn.rope"):
+        return jnp.where(rope_on, cos, 1.0), jnp.where(rope_on, sin, 0.0)
 
 
 class StackMember(NamedTuple):
@@ -875,21 +930,22 @@ def _qkv(h, ap, cfg: TransformerConfig, split: bool, gated: bool = False):
     ([b, s, n_heads * hd], [b, s, n_kv_heads * hd]); `_block` says which.
     A fourth beside them: the retention gate's logits [b, s, n_kv_heads]
     float32 (`gated`: a retention layer), else None."""
-    q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
-    k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
-    v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
-    if cfg.qk_norm:
-        # over the whole projection, before the split into heads (OLMoE), or
-        # over each head's dims with one scale for all heads (afmoe)
-        with jax.named_scope("attn.qk_norm"):
-            norm = partial(_rms_norm_per_head, head_dim=cfg.head_dim) if cfg.qk_norm_per_head else rms_norm
-            q = norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
-            k = norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
-    view = (lambda t: t.reshape(*t.shape[:2], -1, cfg.head_dim)) if split else (lambda t: t)
-    gate = None
-    if gated:
-        gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
-    return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype), gate
+    with jax.named_scope("attn.qkv"):
+        q = jnp.einsum("bsd,dk->bsk", h, ap["wq"], preferred_element_type=jnp.float32)
+        k = jnp.einsum("bsd,dk->bsk", h, ap["wk"], preferred_element_type=jnp.float32)
+        v = jnp.einsum("bsd,dk->bsk", h, ap["wv"], preferred_element_type=jnp.float32)
+        if cfg.qk_norm:
+            # over the whole projection, before the split into heads (OLMoE), or
+            # over each head's dims with one scale for all heads (afmoe)
+            with jax.named_scope("attn.qk_norm"):
+                norm = partial(_rms_norm_per_head, head_dim=cfg.head_dim) if cfg.qk_norm_per_head else rms_norm
+                q = norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
+                k = norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
+        view = (lambda t: t.reshape(*t.shape[:2], -1, cfg.head_dim)) if split else (lambda t: t)
+        gate = None
+        if gated:
+            gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
+        return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype), gate
 
 
 def _ffn(h, mp, cfg: TransformerConfig, experts=None):
@@ -899,6 +955,13 @@ def _ffn(h, mp, cfg: TransformerConfig, experts=None):
     dense layers do not); `experts` as `_routed_ffn` takes it."""
     if "router" in mp:
         return _routed_ffn(h, mp, cfg, experts=experts)
+    with jax.named_scope("ffn"):
+        return _dense_ffn(h, mp, cfg)
+
+
+def _dense_ffn(h, mp, cfg: TransformerConfig):
+    """SwiGLU or gelu of h through one set of matrices, under the caller's
+    scope (`ffn`; a routed layer's shared expert: `moe.shared`)."""
     up = jnp.einsum("bsd,df->bsf", h, mp["w_up"], preferred_element_type=jnp.float32)
     if cfg.mlp_act == "swiglu":
         gate = jnp.einsum(
@@ -1002,8 +1065,8 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
 
     b, s, d = h.shape
     n, k, E = b * s, cfg.n_experts_per_tok, cfg.n_experts
-    x = h.reshape(n, d)
     with jax.named_scope("moe.router"):
+        x = h.reshape(n, d)
         probs, ranked = _router_probs(x, mp, cfg)
         probs = _ckpt(probs, "moe_route")
         top_e = _ckpt(lax.top_k(ranked, k)[1], "moe_route")  # [n, k], most probable first
@@ -1015,12 +1078,18 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
             top_p = top_p * cfg.route_scale
     if experts is not None and not experts_grouped_at(n):
         stack, index = experts
-        layer = {name: _layer_of(stack[name], index) for name in EXPERT_WEIGHTS}
-        out = _every_expert_ffn(x, layer, top_e, top_p, cfg).reshape(b, s, d)
+        with jax.named_scope("moe.experts"):
+            layer = {name: _layer_of(stack[name], index) for name in EXPERT_WEIGHTS}
+        out = _every_expert_ffn(x, layer, top_e, top_p, cfg)
+        with jax.named_scope("moe.combine"):
+            out = out.reshape(b, s, d)
         if "shared" in mp:
             with jax.named_scope("moe.shared"):
-                out = out + _ffn(h, mp["shared"], cfg)
-        return (out, _tokens_per_expert(top_e, E)) if counts else out
+                out = out + _dense_ffn(h, mp["shared"], cfg)
+        if not counts:
+            return out
+        with jax.named_scope("moe.router"):
+            return out, _tokens_per_expert(top_e, E)
     held, rows_per_expert = cfg.experts_held, None
     with jax.named_scope("moe.dispatch"):
         flat_e = top_e.reshape(n * k)
@@ -1055,7 +1124,7 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
         out = combine_rows(ys, top_p, order, inverse).reshape(b, s, d)
     if "shared" in mp:
         with jax.named_scope("moe.shared"):
-            out = out + _ffn(h, mp["shared"], cfg)
+            out = out + _dense_ffn(h, mp["shared"], cfg)
     return (out, group_sizes if rows_per_expert is None else rows_per_expert) if counts else out
 
 
@@ -1089,16 +1158,19 @@ def _kda_mixer(h, ap, cfg: TransformerConfig, attend):
     def proj(x, w):
         return jnp.einsum("bsd,dk->bsk", x, w, preferred_element_type=jnp.float32)
 
-    q, k, v = (proj(h, ap[name]).astype(cfg.dtype) for name in ("wq", "wk", "wv"))
+    with jax.named_scope("attn.qkv"):
+        q, k, v = [proj(h, ap[name]).astype(cfg.dtype) for name in ("wq", "wk", "wv")]
     with jax.named_scope("kda.gates"):
         f = proj(proj(h, ap["w_fa"]).astype(cfg.dtype), ap["w_fb"])
         g, beta = kda.gates(f, ap["a_log"], ap["dt_bias"], proj(h, ap["w_b"]))
-    o, kept = attend(q, k, v, g, beta, tuple(ap["conv_" + name] for name in "qkv"))
+    with jax.named_scope("attn.core"):
+        o, kept = attend(q, k, v, g, beta, tuple(ap["conv_" + name] for name in "qkv"))
     with jax.named_scope("kda.out"):
         gate = proj(proj(h, ap["w_ga"]).astype(cfg.dtype), ap["w_gb"]) + ap["b_g"].astype(jnp.float32)
         o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * ap["o_norm"]["scale"].astype(jnp.float32)
         y = (o.reshape(*gate.shape) * jax.nn.sigmoid(gate)).astype(cfg.dtype)
-    return _ckpt(proj(y, ap["wo"]).astype(cfg.dtype), "attn_out_bf16"), kept
+    with jax.named_scope("attn.out"):
+        return _ckpt(proj(y, ap["wo"]).astype(cfg.dtype), "attn_out_bf16"), kept
 
 
 def latent_softmax_scale(cfg: TransformerConfig) -> float:
@@ -1147,7 +1219,8 @@ def _mla_mixer(h, ap, cfg: TransformerConfig, cos, sin, attend):
         kv = proj(h, ap["wkv_a"])
         c_kv = rms_norm(kv[..., :c], ap["kv_a_norm"]["scale"], cfg.norm_eps)
         k_r = rotate(kv[:, :, None, c:])[:, :, 0]
-    o, kept = attend(q_nope, q_rope, c_kv, k_r, ap["w_uk"], ap["w_uv"])
+    with jax.named_scope("attn.core"):
+        o, kept = attend(q_nope, q_rope, c_kv, k_r, ap["w_uk"], ap["w_uv"])
     with jax.named_scope("attn.mla.out"):
         out = proj(o.reshape(b, s, cfg.n_heads * cfg.v_head_dim), ap["wo"])
     return _ckpt(out, "attn_out_bf16"), kept
@@ -1209,22 +1282,25 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
         # Rope's elementwise pass is also what keeps the split into heads off the projections: with nothing between
         # them the compiler gives the dot a head-shaped result and re-lays `wq` out for it, a transpose of 67 MB a step.
         q, k = lax.optimization_barrier((q, k))
-    q = _ckpt(apply_rope(q, cos, sin, cfg) if rotates else q, "q_bf16")
-    k = _ckpt(apply_rope(k, cos, sin, cfg) if rotates else k, "k_bf16")
-    v = _ckpt(v, "v_bf16")
-    heads = [t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)]
-    o, kept = attend(*heads) if gate is None else attend(*heads, jax.nn.log_sigmoid(gate))
-    o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
+    with jax.named_scope("attn.rope"):
+        q = _ckpt(apply_rope(q, cos, sin, cfg) if rotates else q, "q_bf16")
+        k = _ckpt(apply_rope(k, cos, sin, cfg) if rotates else k, "k_bf16")
+    with jax.named_scope("attn.core"):
+        v = _ckpt(v, "v_bf16")
+        heads = [t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)]
+        o, kept = attend(*heads) if gate is None else attend(*heads, jax.nn.log_sigmoid(gate))
+        o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     if cfg.attn_gate:
         with jax.named_scope("attn.gate"):
             gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
             o = (o * jax.nn.sigmoid(gate)).astype(cfg.dtype)
-    attn_out = _ckpt(
-        jnp.einsum(
-            "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
-        ).astype(cfg.dtype),
-        "attn_out_bf16",
-    )
+    with jax.named_scope("attn.out"):
+        attn_out = _ckpt(
+            jnp.einsum(
+                "bsk,kd->bsd", o, ap["wo"], preferred_element_type=jnp.float32
+            ).astype(cfg.dtype),
+            "attn_out_bf16",
+        )
     return _block_ffn(x, attn_out, kept, h, layer_params, cfg, stats, experts)
 
 
@@ -1242,16 +1318,19 @@ def _block_ffn(x, attn_out, kept, h, layer_params, cfg: TransformerConfig, stats
     if cfg.parallel_block:
         mlp_in = h
     else:
-        x = x + attn_out
+        with jax.named_scope("residual"):
+            x = x + attn_out
         mlp_in = _norm(x, layer_params["mlp_norm"]["scale"], cfg)
-    mlp_in = _ckpt(mlp_in, "mlp_in_bf16")
+    with jax.named_scope("norm"):  # the norm's output, named for the save frontier
+        mlp_in = _ckpt(mlp_in, "mlp_in_bf16")
     if stats == "experts" and "router" in mp:  # forward_decode: the experts this step's rows chose
         mlp_out, rows_per_expert = _routed_ffn(mlp_in, mp, cfg, counts=True, experts=experts)
     else:
         mlp_out, rows_per_expert = _ffn(mlp_in, mp, cfg, experts), None
     if cfg.post_norms:
         mlp_out = _norm(mlp_out, layer_params["post_mlp_norm"]["scale"], cfg)
-    out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
+    with jax.named_scope("residual"):
+        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
     if stats == "route":  # routing_stats: what the router did with this layer's input
         return out, kept, _route_stats(mlp_in.reshape(b * s, d), mp, cfg)
     if stats == "experts":
@@ -1379,16 +1458,18 @@ def _in_stack_order(plan, segments_ys):
 
 
 def _embed(params: PyTree, tokens, cfg: TransformerConfig):
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
-    return x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype) if cfg.embed_scale else x
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+        return x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype) if cfg.embed_scale else x
 
 
 def _logits(params: PyTree, x):
     """Final-norm hidden states [..., d] -> logits [..., vocab] float32."""
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"]["embedding"].T
-    return jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"]["embedding"].T
+        return jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
 
 
 def _route_stats(x, mp, cfg: TransformerConfig):
@@ -1469,15 +1550,16 @@ def next_token_loss(
     (rather than slicing to seq-1) so the sequence dim stays divisible by
     the "seq" mesh axis under sequence parallelism."""
     logits = forward(params, tokens, cfg, mesh)
-    targets = jnp.roll(tokens, -1, axis=1)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    s = tokens.shape[1]
-    valid = jnp.arange(s)[None, :] < s - 1  # last position has no target
-    m = jnp.broadcast_to(valid, nll.shape).astype(nll.dtype)
-    if mask is not None:
-        m = m * jnp.roll(mask, -1, axis=1).astype(nll.dtype)
-    return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+    with jax.named_scope("loss"):
+        targets = jnp.roll(tokens, -1, axis=1)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        s = tokens.shape[1]
+        valid = jnp.arange(s)[None, :] < s - 1  # last position has no target
+        m = jnp.broadcast_to(valid, nll.shape).astype(nll.dtype)
+        if mask is not None:
+            m = m * jnp.roll(mask, -1, axis=1).astype(nll.dtype)
+        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -1543,8 +1625,9 @@ def build_train_step(
             loss, grads = jax.value_and_grad(next_token_loss)(
                 params, tokens, cfg, mesh
             )
-            updates, opt_state = tx.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                return optax.apply_updates(params, updates), opt_state, loss
 
         return init_state, jax.jit(
             train_step, donate_argnums=(0, 1) if donate else ()

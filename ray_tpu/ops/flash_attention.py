@@ -60,6 +60,10 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK = 1024
 SUB = 256
 NEG_INF = -1e30
+# The kernels' names in the compiled program and in a device trace.
+FWD_KERNEL_NAME = "flash_attention_fwd"
+DQ_KERNEL_NAME = "flash_attention_dq"
+DKV_KERNEL_NAME = "flash_attention_dkv"
 LANES = 128  # a row statistic (running max, normalizer, lse, delta) holds the row's value in every lane of a vector register
 
 
@@ -372,6 +376,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, blocks, interpret, heads):
             _scratch((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name=FWD_KERNEL_NAME,
     )(q, k, v)
     return o, lse
 
@@ -429,6 +434,7 @@ def _flash_bwd_impl(causal, scale, blocks, interpret, heads, q, k, v, lse, do, d
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[_scratch((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=DQ_KERNEL_NAME,
     )(q, k, v, do, lse, delta)
 
     # dk/dv walk the kv-side batch axis; the q/do/lse/delta index maps fan
@@ -465,6 +471,7 @@ def _flash_bwd_impl(causal, scale, blocks, interpret, heads, q, k, v, lse, do, d
             _scratch((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name=DKV_KERNEL_NAME,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

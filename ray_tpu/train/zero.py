@@ -216,40 +216,46 @@ def build_zero_step(
 
     def inner(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        g_leaves = jax.tree_util.tree_leaves(grads)
-        g_shards = {}
-        for i, g in enumerate(g_leaves):
-            flat = jnp.reshape(g, (-1,))
-            pad = sharder.padded[i] - sharder.sizes[i]
-            if pad:
-                flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-            # reduce_scatter: sum of per-device grads, sliced to this
-            # device's shard; /n turns sum-of-local-means into the global
-            # mean (equal local batch sizes by construction of the spec).
-            g_shards[str(i)] = (
-                lax.psum_scatter(flat, axis, scatter_dimension=0, tiled=True) / n
-            )
-        p_leaves = jax.tree_util.tree_leaves(params)
-        r = lax.axis_index(axis)
-        p_shards = {}
-        for i, p in enumerate(p_leaves):
-            flat = jnp.reshape(p, (-1,))
-            pad = sharder.padded[i] - sharder.sizes[i]
-            if pad:
-                flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-            p_shards[str(i)] = lax.dynamic_slice(
-                flat, (r * (sharder.padded[i] // n),), (sharder.padded[i] // n,)
-            )
         import optax
 
-        updates, new_opt = tx.update(g_shards, opt_state, p_shards)
-        new_p_shards = optax.apply_updates(p_shards, updates)
-        new_flats = {
-            k: lax.all_gather(new_p_shards[k], axis, axis=0, tiled=True)
-            for k in idx_keys
-        }
-        new_params = sharder.unflatten(new_flats)
-        return new_params, new_opt, lax.pmean(loss, axis)
+        # The three scopes are rows of models/transformer.SCOPES: a device
+        # trace splits the step's time by them.
+        g_leaves = jax.tree_util.tree_leaves(grads)
+        g_shards = {}
+        with jax.named_scope("zero.grad_scatter"):
+            for i, g in enumerate(g_leaves):
+                flat = jnp.reshape(g, (-1,))
+                pad = sharder.padded[i] - sharder.sizes[i]
+                if pad:
+                    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+                # reduce_scatter: sum of per-device grads, sliced to this
+                # device's shard; /n turns sum-of-local-means into the global
+                # mean (equal local batch sizes by construction of the spec).
+                g_shards[str(i)] = (
+                    lax.psum_scatter(flat, axis, scatter_dimension=0, tiled=True) / n
+                )
+        with jax.named_scope("zero.update"):
+            p_leaves = jax.tree_util.tree_leaves(params)
+            r = lax.axis_index(axis)
+            p_shards = {}
+            for i, p in enumerate(p_leaves):
+                flat = jnp.reshape(p, (-1,))
+                pad = sharder.padded[i] - sharder.sizes[i]
+                if pad:
+                    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+                p_shards[str(i)] = lax.dynamic_slice(
+                    flat, (r * (sharder.padded[i] // n),), (sharder.padded[i] // n,)
+                )
+            updates, new_opt = tx.update(g_shards, opt_state, p_shards)
+            new_p_shards = optax.apply_updates(p_shards, updates)
+        with jax.named_scope("zero.param_gather"):
+            new_flats = {
+                k: lax.all_gather(new_p_shards[k], axis, axis=0, tiled=True)
+                for k in idx_keys
+            }
+            new_params = sharder.unflatten(new_flats)
+        with jax.named_scope("loss"):
+            return new_params, new_opt, lax.pmean(loss, axis)
 
     batch_spec = P(axis)
     stepped = shard_map(
@@ -295,13 +301,17 @@ def build_zero_update(
 
         import optax
 
-        p_shards, g_shards = shard_of(params), shard_of(grads)
-        updates, new_opt = tx.update(g_shards, opt_state, p_shards)
-        new_p = optax.apply_updates(p_shards, updates)
-        flats = {
-            k: lax.all_gather(v, axis, axis=0, tiled=True) for k, v in new_p.items()
-        }
-        return sharder.unflatten(flats), new_opt
+        with jax.named_scope("zero.grad_scatter"):  # a slice here: the gradients come reduced
+            g_shards = shard_of(grads)
+        with jax.named_scope("zero.update"):
+            p_shards = shard_of(params)
+            updates, new_opt = tx.update(g_shards, opt_state, p_shards)
+            new_p = optax.apply_updates(p_shards, updates)
+        with jax.named_scope("zero.param_gather"):
+            flats = {
+                k: lax.all_gather(v, axis, axis=0, tiled=True) for k, v in new_p.items()
+            }
+            return sharder.unflatten(flats), new_opt
 
     fn = shard_map(
         inner, mesh, in_specs=(P(), opt_specs, P()), out_specs=(P(), opt_specs)
