@@ -456,9 +456,9 @@ SCOPES = {
     "head": "the logits' product",
     "loss": "log-softmax, the target's gather, the mean (train/zero.py: its mean over the devices)",
     "optimizer": "`tx.update` and `optax.apply_updates` of the unsharded step",
-    "zero.grad_scatter": "train/zero.py: a gradient's flattening and its reduce-scatter",
-    "zero.update": "train/zero.py: the parameters' shards, `tx.update`, `apply_updates`",
-    "zero.param_gather": "train/zero.py: the updated shards' all-gather and unflattening",
+    "zero.grad_scatter": "train/zero.py: a gradient's reduce-scatter along the leaf's cut dimension, the shard's flattening",
+    "zero.update": "train/zero.py: the parameters' shards, `tx.update`",
+    "zero.param_gather": "train/zero.py: the updates' all-gather into the leaf's own shape, `apply_updates` on the whole leaf",
 }
 
 
@@ -1596,7 +1596,7 @@ def build_train_step(
     """The standard data-parallel train step (fwd+bwd+optimizer), with the
     optimizer update optionally ZeRO-sharded over `zero_axis`
     (train/zero.py: reduce_scatter grads -> shard-local update ->
-    all_gather params; per-chip optimizer state ~1/N).
+    all_gather the updates; per-chip optimizer state ~1/N).
 
     Returns `(init_state, step)`:
       init_state(rng) -> (params, opt_state)  [opt_state sharded when zero]

@@ -4,27 +4,46 @@ Plain data parallelism keeps a FULL copy of the optimizer state on every
 chip — for adamw that is 2x the params in fp32-equivalent bytes, the
 single biggest slab of HBM after the params themselves. The
 ZeRO-1 fix: shard the optimizer state over the data axis, so each chip
-updates only its 1/N slice of the flattened parameter vector:
+updates only its 1/N of every parameter leaf:
 
     local grads --reduce_scatter--> grad shard
     grad shard + opt-state shard --tx.update--> param-delta shard
-    updated param shard --all_gather--> full params
+    param-delta shard --all_gather--> full delta, added to the full params
 
-One reduce_scatter + one all_gather move exactly the same bytes as the
-allreduce they replace (an allreduce IS reduce_scatter + all_gather),
-so the collective cost is unchanged while per-chip optimizer state
-drops to ~1/N. The update itself is elementwise for the adam family,
-so shard-local tx.update is numerically identical to the unsharded
-update (tests/test_elastic.py pins this step-for-step).
+One reduce_scatter + one all_gather move the bytes of the allreduce they
+replace (an allreduce IS reduce_scatter + all_gather), and per-chip
+optimizer state drops to ~1/N. The update itself is elementwise for the
+adam family, so shard-local tx.update is numerically identical to the
+unsharded update (tests/test_elastic.py pins this step-for-step).
 
-Representation: every param leaf is flattened and zero-padded to a
-multiple of the axis size so shards are SPMD-uniform. The pad region
+What a chip's shard is, and why. A leaf with an inner dimension (any but
+the first) divisible by N is cut along the last such dimension: chip r owns
+`leaf[..., r*k:(r+1)*k, ...]`, its gradient goes to `psum_scatter` in the
+shape the backward pass left it in, and the updates' slices come back from
+`all_gather` in the leaf's own shape (`ZeroSharder.all_gather_tree` says why
+the updates and not the updated slices); only the SHARD is flattened, for
+the optimizer. The form is the TPU compiler's: it keeps a reduce-scatter whose
+scattered dimension is an inner one of the array as it lies (v5e 2x2,
+`bf16[4, 4096, 14336]`: 1 reduce-scatter, 470 MB of temporaries) and
+rewrites one of a flattened vector, or along the major-most dimension, to an
+all-reduce of the WHOLE gradient plus a slice (0 reduce-scatters, 940 MB).
+Flattened, the four-chip Mistral step paid 8 all-reduces (40 ms), 14 ms of
+copies of their results and an all-gather on top, a tenth of the step
+(PERF.md §6, PR 56 and PR 57): the collective cost was not unchanged. A leaf with
+no such dimension (a `[d]` scale, a scalar, an odd size: kilobytes) is
+flattened, zero-padded to a multiple of N and cut into N runs.
+
+Representation: the optimizer state is built over `{str(i): vector}`, leaf
+i's shard flattened, `padded[i] // N` elements a chip; the global vector is
+the chips' shards one after another (for a cut leaf a permutation of the
+flattened leaf, `ZeroSharder._to_flat`). A flat-cut leaf's pad region
 provably stays zero through adam-family updates (zero grad, zero m/v,
-zero weight-decay on a zero param), which is what makes `to_logical` /
-`from_logical` — the unpadded, param-shaped view used by the elastic
-checkpoint format — exact at ANY world size: save the logical tree via
-`elastic_checkpoint.save_state`, restore and `from_logical` onto a mesh
-of a different size, and the trajectory continues bit-for-bit.
+zero weight-decay on a zero param), and a permutation loses nothing, which
+is what makes `to_logical` / `from_logical` — the unpadded, param-shaped
+view used by the elastic checkpoint format — exact at ANY world size: save
+the logical tree via `elastic_checkpoint.save_state`, restore and
+`from_logical` onto a mesh of a different size, and the trajectory
+continues bit-for-bit.
 """
 
 from __future__ import annotations
@@ -47,46 +66,128 @@ def _axis_size(mesh: Mesh, axis: str) -> int:
 
 
 class ZeroSharder:
-    """The flatten/pad/shard mapping between a logical param tree and the
-    dict-of-flat-vectors representation the sharded update runs on.
+    """Which elements of each param leaf a chip owns, and the mapping between
+    a logical param tree and the dict-of-flat-vectors representation the
+    sharded update runs on.
 
-    The sharded tree is `{str(i): padded_flat_vector}` keyed by leaf
-    index — a dict so optimizer states built over it carry the leaf index
-    in their tree paths, which is what lets `to_logical`/`from_logical`
-    map optimizer moments back to param shapes without knowing the
-    optimizer's structure.
+    The partition is decided here, once, from a leaf's SHAPE and the axis
+    size alone (`dims`): a leaf with an inner dimension (any but the first)
+    divisible by n is cut along the last such dimension, chip r owning
+    `leaf[..., r*k:(r+1)*k, ...]`; a leaf with none (a `[d]` scale, a scalar,
+    an odd size) is flattened, zero-padded to a multiple of n and cut into n
+    runs, as every leaf once was.
+
+    The sharded tree is `{str(i): flat_vector}` keyed by leaf index — a dict
+    so optimizer states built over it carry the leaf index in their tree
+    paths, which is what lets `to_logical`/`from_logical` map optimizer
+    moments back to param shapes without knowing the optimizer's structure.
+    A global vector is the concatenation of the chips' flattened shards
+    (`padded[i]` elements): for a cut leaf a permutation of the flattened
+    leaf (`_to_flat` / `_from_flat`, the one reshape-transpose), else the
+    padded flattening itself.
     """
 
     def __init__(self, params_like: PyTree, mesh: Mesh, axis: str = "data"):
         self.mesh = mesh
         self.axis = axis
-        self.n = _axis_size(mesh, axis)
+        self.n = n = _axis_size(mesh, axis)
         leaves, self.treedef = jax.tree_util.tree_flatten(
             jax.eval_shape(lambda: params_like)
         )
         self.shapes = [tuple(l.shape) for l in leaves]
         self.dtypes = [l.dtype for l in leaves]
         self.sizes = [int(math.prod(s)) if s else 1 for s in self.shapes]
-        self.padded = [-(-s // self.n) * self.n for s in self.sizes]
+        self.padded = [-(-s // n) * n for s in self.sizes]
+        # The dimension a leaf is cut along, None where it is cut flat.
+        self.dims: List[Optional[int]] = [
+            next((d for d in range(len(s) - 1, 0, -1) if s[d] and s[d] % n == 0), None)
+            for s in self.shapes
+        ]
+
+    # ---------------------------------------------------- the partition
+    def _shard_shape(self, i: int) -> Tuple[int, ...]:
+        """A cut leaf's slice on one chip, before it is flattened."""
+        s, d = self.shapes[i], self.dims[i]
+        return s[:d] + (s[d] // self.n,) + s[d + 1 :]
+
+    def _to_flat(self, i: int, leaf):
+        """A whole leaf (numpy or jax) -> its global vector: chip r's shard
+        at [r*m, (r+1)*m), m = padded[i] // n. A flat-cut leaf's pad is left
+        to the caller."""
+        d = self.dims[i]
+        if d is None:
+            return leaf.reshape((-1,))
+        shard = self._shard_shape(i)
+        chips_first = (d,) + tuple(range(d)) + tuple(range(d + 1, len(shard) + 1))
+        return leaf.reshape(shard[:d] + (self.n,) + shard[d:]).transpose(chips_first).reshape((-1,))
+
+    def _from_flat(self, i: int, flat):
+        """Inverse of `_to_flat` (pad dropped)."""
+        d = self.dims[i]
+        if d is None:
+            return flat[: self.sizes[i]].reshape(self.shapes[i])
+        shard = self._shard_shape(i)
+        chips_at_d = tuple(range(1, d + 1)) + (0,) + tuple(range(d + 1, len(shard) + 1))
+        return flat.reshape((self.n,) + shard).transpose(chips_at_d).reshape(self.shapes[i])
+
+    def _padded_flat(self, i: int, leaf: jax.Array) -> jax.Array:
+        flat = self._to_flat(i, leaf)
+        pad = self.padded[i] - self.sizes[i]
+        if pad:
+            flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+        return flat
+
+    # Inside a shard_map over `axis`, on a leaf every chip holds whole:
+    def local_shard(self, i: int, leaf: jax.Array, r) -> jax.Array:
+        """Chip r's shard of the leaf, flattened for the optimizer."""
+        m = self.padded[i] // self.n
+        d = self.dims[i]
+        if d is None:
+            return lax.dynamic_slice_in_dim(self._padded_flat(i, leaf), r * m, m)
+        k = self.shapes[i][d] // self.n
+        return lax.dynamic_slice_in_dim(leaf, r * k, k, d).reshape((-1,))
+
+    def local_shards(self, tree: PyTree, r) -> Dict[str, jax.Array]:
+        return {str(i): self.local_shard(i, leaf, r) for i, leaf in enumerate(jax.tree_util.tree_leaves(tree))}
+
+    def reduce_scatter(self, i: int, g: jax.Array) -> jax.Array:
+        """The chips' sum of a per-chip gradient, this chip's shard of it,
+        flattened. A cut leaf goes to the collective in the shape the
+        backward pass left it in (see the module docstring)."""
+        d = self.dims[i]
+        if d is None:
+            return lax.psum_scatter(self._padded_flat(i, g), self.axis, scatter_dimension=0, tiled=True)
+        return lax.psum_scatter(g, self.axis, scatter_dimension=d, tiled=True).reshape((-1,))
+
+    def all_gather(self, i: int, shard: jax.Array) -> jax.Array:
+        """The chips' flattened shards -> the whole leaf in its own shape."""
+        d = self.dims[i]
+        if d is None:
+            return self._from_flat(i, lax.all_gather(shard, self.axis, axis=0, tiled=True))
+        return lax.all_gather(shard.reshape(self._shard_shape(i)), self.axis, axis=d, tiled=True)
+
+    def all_gather_tree(self, shards: Dict[str, jax.Array]) -> PyTree:
+        """The chips' shard dicts -> the logical tree, every leaf whole.
+
+        The step gathers the UPDATES and adds them to the whole parameters
+        rather than gathering updated shards: the TPU compiler never lets an
+        all-gather's result be the step's output buffer. A bare gather is
+        copied into it, and with the parameters donated they are also copied
+        OUT of it at the step's start: 3.2 GiB more live through a Mistral
+        step, enough to make XLA rematerialise the head's logits (PERF.md §6,
+        PR 57: +19 ms). An elementwise op behind the gather writes the output
+        in place, and the sum is the same numbers on the same elements."""
+        leaves = [self.all_gather(i, shards[str(i)]) for i in range(len(self.shapes))]
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     # ------------------------------------------------------------ params
     def flatten(self, tree: PyTree) -> Dict[str, jax.Array]:
-        """Logical tree -> padded flat dict (global arrays)."""
+        """Logical tree -> dict of global vectors (global arrays)."""
         leaves = jax.tree_util.tree_leaves(tree)
-        out = {}
-        for i, leaf in enumerate(leaves):
-            flat = jnp.reshape(leaf, (-1,))
-            pad = self.padded[i] - self.sizes[i]
-            if pad:
-                flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-            out[str(i)] = flat
-        return out
+        return {str(i): self._padded_flat(i, jnp.asarray(leaf)) for i, leaf in enumerate(leaves)}
 
     def unflatten(self, flats: Dict[str, jax.Array]) -> PyTree:
-        leaves = [
-            jnp.reshape(flats[str(i)][: self.sizes[i]], self.shapes[i])
-            for i in range(len(self.shapes))
-        ]
+        leaves = [self._from_flat(i, flats[str(i)]) for i in range(len(self.shapes))]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def shard_struct(self) -> Dict[str, jax.ShapeDtypeStruct]:
@@ -118,49 +219,42 @@ class ZeroSharder:
         return jax.tree_util.tree_map_with_path(one, opt_state)
 
     def to_logical(self, opt_state: PyTree) -> PyTree:
-        """Sharded (padded flat) optimizer state -> world-size-independent
-        logical tree: moment leaves reshaped to their param's shape, pad
-        dropped. This is the form `elastic_checkpoint` stores."""
+        """Sharded optimizer state -> world-size-independent logical tree:
+        moment leaves in their param's shape and element order, pad dropped.
+        This is the form `elastic_checkpoint` stores."""
 
         def one(path, leaf):
             i = self._leaf_index(path)
+            arr = jax.device_get(leaf)
             if (
                 i is not None
                 and getattr(leaf, "ndim", 0) == 1
                 and leaf.shape[0] == self.padded[i]
             ):
-                arr = jax.device_get(leaf)
-                return arr[: self.sizes[i]].reshape(self.shapes[i])
-            return jax.device_get(leaf)
+                return self._from_flat(i, arr)
+            return arr
 
         return jax.tree_util.tree_map_with_path(one, opt_state)
 
     def from_logical(self, logical: PyTree) -> PyTree:
-        """Inverse of to_logical at THIS sharder's world size: re-pad with
-        zeros (exact — the pad region of a fresh or restored run is zero by
-        construction) and place each moment sharded over the axis."""
+        """Inverse of to_logical at THIS sharder's world size: re-cut (and
+        re-pad with zeros: exact — the pad region of a fresh or restored run
+        is zero by construction) and place each moment sharded over the
+        axis."""
 
         def one(path, leaf):
             i = self._leaf_index(path)
             arr = jnp.asarray(leaf)
-            if (
-                i is not None
-                and tuple(arr.shape) == self.shapes[i]
-                and self.padded[i] // self.n >= 1
-            ):
-                flat = jnp.reshape(arr, (-1,))
-                pad = self.padded[i] - self.sizes[i]
-                if pad:
-                    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
+            if i is not None and tuple(arr.shape) == self.shapes[i]:
                 return jax.device_put(
-                    flat, NamedSharding(self.mesh, P(self.axis))
+                    self._padded_flat(i, arr), NamedSharding(self.mesh, P(self.axis))
                 )
             return jax.device_put(arr, NamedSharding(self.mesh, P()))
 
         return jax.tree_util.tree_map_with_path(one, logical)
 
     def place_opt(self, opt_state: PyTree) -> PyTree:
-        """Device-places a (host) padded-flat optimizer state under its
+        """Device-places a (host) flat-vector optimizer state under its
         sharding specs (restore path at the SAME representation)."""
         specs = self.opt_specs(opt_state)
         return jax.tree_util.tree_map(
@@ -172,17 +266,12 @@ class ZeroSharder:
 
 def init_opt_state(tx, params: PyTree, mesh: Mesh, axis: str = "data") -> PyTree:
     """Optimizer state sharded over `axis`: each device initializes state
-    for only ITS slice of the flattened params (~1/N bytes per chip)."""
+    for only ITS shard of the params (~1/N bytes per chip)."""
     sharder = ZeroSharder(params, mesh, axis)
     struct = jax.eval_shape(tx.init, sharder.shard_struct())
     specs = sharder.opt_specs(struct)
-
-    def inner(flats):
-        local = {k: v for k, v in flats.items()}
-        return tx.init(local)
-
     fn = shard_map(
-        inner,
+        tx.init,
         mesh,
         in_specs=({str(i): P(axis) for i in range(len(sharder.shapes))},),
         out_specs=specs,
@@ -204,13 +293,12 @@ def build_zero_step(
 
     `loss_fn(params, local_batch)` computes the MEAN loss of its local
     batch shard; `batch` is sharded over `axis` on dim 0. Per-device
-    grads go through ONE reduce_scatter (grad shard), the shard-local
-    tx.update, and ONE all_gather (updated params) — allreduce-equivalent
-    bytes, 1/N optimizer state.
+    grads go through ONE reduce_scatter a leaf (grad shard), the
+    shard-local tx.update, and ONE all_gather a leaf (the updates, added
+    to the whole params) — allreduce-equivalent bytes, 1/N optimizer state.
     """
     sharder = ZeroSharder(params_like, mesh, axis)
     n = sharder.n
-    idx_keys = [str(i) for i in range(len(sharder.shapes))]
     opt_struct = jax.eval_shape(tx.init, sharder.shard_struct())
     opt_specs = sharder.opt_specs(opt_struct)
 
@@ -220,40 +308,18 @@ def build_zero_step(
 
         # The three scopes are rows of models/transformer.SCOPES: a device
         # trace splits the step's time by them.
-        g_leaves = jax.tree_util.tree_leaves(grads)
-        g_shards = {}
         with jax.named_scope("zero.grad_scatter"):
-            for i, g in enumerate(g_leaves):
-                flat = jnp.reshape(g, (-1,))
-                pad = sharder.padded[i] - sharder.sizes[i]
-                if pad:
-                    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-                # reduce_scatter: sum of per-device grads, sliced to this
-                # device's shard; /n turns sum-of-local-means into the global
-                # mean (equal local batch sizes by construction of the spec).
-                g_shards[str(i)] = (
-                    lax.psum_scatter(flat, axis, scatter_dimension=0, tiled=True) / n
-                )
-        with jax.named_scope("zero.update"):
-            p_leaves = jax.tree_util.tree_leaves(params)
-            r = lax.axis_index(axis)
-            p_shards = {}
-            for i, p in enumerate(p_leaves):
-                flat = jnp.reshape(p, (-1,))
-                pad = sharder.padded[i] - sharder.sizes[i]
-                if pad:
-                    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-                p_shards[str(i)] = lax.dynamic_slice(
-                    flat, (r * (sharder.padded[i] // n),), (sharder.padded[i] // n,)
-                )
-            updates, new_opt = tx.update(g_shards, opt_state, p_shards)
-            new_p_shards = optax.apply_updates(p_shards, updates)
-        with jax.named_scope("zero.param_gather"):
-            new_flats = {
-                k: lax.all_gather(new_p_shards[k], axis, axis=0, tiled=True)
-                for k in idx_keys
+            # /n turns sum-of-local-means into the global mean (equal local
+            # batch sizes by construction of the spec).
+            g_shards = {
+                str(i): sharder.reduce_scatter(i, g) / n
+                for i, g in enumerate(jax.tree_util.tree_leaves(grads))
             }
-            new_params = sharder.unflatten(new_flats)
+        with jax.named_scope("zero.update"):
+            p_shards = sharder.local_shards(params, lax.axis_index(axis))
+            updates, new_opt = tx.update(g_shards, opt_state, p_shards)
+        with jax.named_scope("zero.param_gather"):
+            new_params = optax.apply_updates(params, sharder.all_gather_tree(updates))
         with jax.named_scope("loss"):
             return new_params, new_opt, lax.pmean(loss, axis)
 
@@ -280,38 +346,20 @@ def build_zero_update(
     test pins THIS against a plain tx.update — identical elementwise
     math, just sliced)."""
     sharder = ZeroSharder(params_like, mesh, axis)
-    n = sharder.n
     opt_struct = jax.eval_shape(tx.init, sharder.shard_struct())
     opt_specs = sharder.opt_specs(opt_struct)
 
     def inner(params, opt_state, grads):
-        r = lax.axis_index(axis)
-
-        def shard_of(tree):
-            out = {}
-            for i, leaf in enumerate(jax.tree_util.tree_leaves(tree)):
-                flat = jnp.reshape(leaf, (-1,))
-                pad = sharder.padded[i] - sharder.sizes[i]
-                if pad:
-                    flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-                out[str(i)] = lax.dynamic_slice(
-                    flat, (r * (sharder.padded[i] // n),), (sharder.padded[i] // n,)
-                )
-            return out
-
         import optax
 
+        r = lax.axis_index(axis)
         with jax.named_scope("zero.grad_scatter"):  # a slice here: the gradients come reduced
-            g_shards = shard_of(grads)
+            g_shards = sharder.local_shards(grads, r)
         with jax.named_scope("zero.update"):
-            p_shards = shard_of(params)
+            p_shards = sharder.local_shards(params, r)
             updates, new_opt = tx.update(g_shards, opt_state, p_shards)
-            new_p = optax.apply_updates(p_shards, updates)
         with jax.named_scope("zero.param_gather"):
-            flats = {
-                k: lax.all_gather(v, axis, axis=0, tiled=True) for k, v in new_p.items()
-            }
-            return sharder.unflatten(flats), new_opt
+            return optax.apply_updates(params, sharder.all_gather_tree(updates)), new_opt
 
     fn = shard_map(
         inner, mesh, in_specs=(P(), opt_specs, P()), out_specs=(P(), opt_specs)

@@ -141,19 +141,38 @@ def _mesh(n):
     return Mesh(np.array(jax.devices("cpu")[:n]), ("data",))
 
 
-def _toy_problem():
+# The toy problem's leaves by how ZeroSharder cuts them at n = 2 and 4: "flat"
+# has no inner dimension divisible by n (every leaf flattened, padded and cut
+# into runs, as before PR 57), "sliced" has leaves cut along a dimension (a
+# stacked [L, d, f], a 2-D [vocab, d]) beside a 1-D and a scalar that stay flat.
+TOY_SHAPES = {
+    "flat": {"w": (13, 7), "b": (5,)},
+    "sliced": {"w": (13, 8), "b": (4,), "u": (3, 8, 12)},
+}
+TOY_KINDS = sorted(TOY_SHAPES)
+
+
+def _toy_problem(kind="flat"):
     import jax
     import jax.numpy as jnp
 
+    shapes = TOY_SHAPES[kind]
+    cols = shapes["w"][1]
     params = {
-        "w": jax.random.normal(jax.random.PRNGKey(0), (13, 7), jnp.float32),
-        "b": jnp.zeros((5,), jnp.float32),
+        "w": jax.random.normal(jax.random.PRNGKey(0), shapes["w"], jnp.float32),
+        "b": jnp.zeros(shapes["b"], jnp.float32),
         "s": jnp.float32(2.0),
     }
+    if "u" in shapes:
+        params["u"] = jax.random.normal(jax.random.PRNGKey(3), shapes["u"], jnp.float32)
 
     def loss_fn(p, batch):
         x, y = batch
-        pred = x @ p["w"] @ jnp.ones((7,), jnp.float32) + p["b"].sum() * p["s"]
+        h = x @ p["w"]
+        if "u" in p:  # a stack of layers: h <- h + tanh(h u_l) u_l^T
+            for layer in range(p["u"].shape[0]):
+                h = h + jnp.tanh(h @ p["u"][layer]) @ p["u"][layer].T
+        pred = h @ jnp.ones((cols,), jnp.float32) + p["b"].sum() * p["s"]
         return jnp.mean((pred - y) ** 2)
 
     x = jax.random.normal(jax.random.PRNGKey(1), (16, 13))
@@ -161,7 +180,99 @@ def _toy_problem():
     return params, loss_fn, x, y
 
 
-def test_zero_update_matches_unsharded_step_for_step():
+# (shape, the dimension it is cut along at n = 2 and at n = 4; None = flat)
+PARTITION_CASES = {
+    "stacked_3d": ((3, 8, 12), 2, 2),
+    "stacked_3d_first_inner": ((3, 8, 6), 2, 1),
+    "matrix_2d": ((16, 8), 1, 1),
+    "vector_1d": ((8,), None, None),
+    "not_divisible": ((13, 7), None, None),
+    "scalar": ((), None, None),
+}
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("case", sorted(PARTITION_CASES))
+def test_zero_partition_round_trips_exactly(case, n):
+    """What a chip owns of a leaf is decided by the leaf's shape and n alone,
+    and every view of it agrees: the global vector `flatten` makes is the
+    chips' flattened shards one after another, a sliced leaf's shard is its
+    slice along the cut dimension, `unflatten` and `to_logical` undo it bit
+    for bit (a permutation and a zero pad: no arithmetic)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.train import zero
+
+    shape, dim_at_2, dim_at_4 = PARTITION_CASES[case]
+    size = int(np.prod(shape)) if shape else 1
+    leaf = jnp.arange(1, size + 1, dtype=jnp.float32).reshape(shape)
+    tree = {"only": leaf}
+    sharder = zero.ZeroSharder(tree, _mesh(n), "data")
+    dim = dim_at_2 if n == 2 else dim_at_4
+    assert sharder.dims == [dim]
+    flat = sharder.flatten(tree)["0"]
+    m = sharder.padded[0] // n
+    assert flat.shape == (sharder.padded[0],) and sharder.shard_struct()["0"].shape == (m,)
+    shards = [np.asarray(flat[r * m : (r + 1) * m]) for r in range(n)]
+    if dim is None:
+        want = np.concatenate([np.asarray(leaf).reshape(-1), np.zeros(sharder.padded[0] - size, np.float32)])
+        np.testing.assert_array_equal(np.concatenate(shards), want)
+    else:
+        k = shape[dim] // n
+        for r, shard in enumerate(shards):
+            np.testing.assert_array_equal(shard, np.asarray(jax.lax.slice_in_dim(leaf, r * k, (r + 1) * k, axis=dim)).reshape(-1))
+    np.testing.assert_array_equal(np.asarray(sharder.unflatten({"0": flat})["only"]), np.asarray(leaf))
+    # the optimizer state's two views: {"0": moment} is what optax builds over the shard dict
+    logical = {"mu": {"0": np.asarray(leaf)}, "count": np.int32(3)}
+    placed = sharder.from_logical(logical)
+    assert placed["mu"]["0"].shape == (sharder.padded[0],)
+    np.testing.assert_array_equal(np.asarray(placed["mu"]["0"]), np.asarray(flat))
+    back = sharder.to_logical(placed)
+    assert back["mu"]["0"].shape == shape and int(back["count"]) == 3
+    np.testing.assert_array_equal(back["mu"]["0"], np.asarray(leaf))
+
+
+def test_zero_step_lowers_to_a_reduce_scatter_of_the_leaf_in_its_own_shape():
+    """The gradient goes to the collective as the backward pass left it: the
+    lowered step holds a `reduce_scatter` whose operand has the leaf's shape
+    and whose result is the slice along the cut dimension, for every sliced
+    leaf, and no whole gradient is flattened before one (the TPU compiler
+    rewrites a reduce-scatter of a flattened vector to all-reduce + slice:
+    train/zero.py's docstring)."""
+    import re
+
+    import optax
+
+    from ray_tpu.train import zero
+
+    params, loss_fn, x, y = _toy_problem("sliced")
+    tx = optax.adamw(1e-2)
+    mesh = _mesh(4)
+    step, sharder = zero.build_zero_step(loss_fn, tx, params, mesh, axis="data", donate=False)
+    opt = zero.init_opt_state(tx, params, mesh, axis="data")
+    text = step.lower(params, opt, (x, y)).as_text()
+    found = re.findall(
+        r'"stablehlo\.reduce_scatter"\(%\w+\) <\{[^}]*scatter_dimension = (\d+) : i64[^}]*\}> \(\{.*?\}\) : \(tensor<([0-9x]*)xf32>\) -> tensor<([0-9x]*)xf32>',
+        text, flags=re.S,
+    )
+    got = sorted((tuple(map(int, a.split("x"))), int(d), tuple(map(int, b.split("x")))) for d, a, b in found)
+    want = []
+    for shape, dim, padded in zip(sharder.shapes, sharder.dims, sharder.padded):
+        if dim is None:
+            want.append(((padded,), 0, (padded // 4,)))
+        else:
+            want.append((shape, dim, shape[:dim] + (shape[dim] // 4,) + shape[dim + 1 :]))
+    assert got == sorted(want)
+    sliced = [w for w in want if len(w[0]) > 1]
+    assert sorted(s for s, _d, _r in sliced) == [(3, 8, 12), (13, 8)]
+    # the same for the updated parameters: gathered along the cut dimension, in the leaf's shape
+    gathered = re.findall(r'"stablehlo\.all_gather"\(%\w+\) <\{all_gather_dim = (\d+) : i64[^}]*\}> : \(tensor<[0-9x]*xf32>\) -> tensor<([0-9x]*)xf32>', text)
+    assert sorted((tuple(map(int, s.split("x"))), int(d)) for d, s in gathered) == sorted((w[0], w[1]) for w in want)
+
+
+@pytest.mark.parametrize("kind", TOY_KINDS)
+def test_zero_update_matches_unsharded_step_for_step(kind):
     """Identical grads through the sharded update vs plain tx.update must
     agree to float32 ulp over multiple steps (elementwise adam math,
     just sliced)."""
@@ -170,7 +281,7 @@ def test_zero_update_matches_unsharded_step_for_step():
 
     from ray_tpu.train import zero
 
-    params, loss_fn, x, y = _toy_problem()
+    params, loss_fn, x, y = _toy_problem(kind)
     tx = optax.adamw(1e-2)
     mesh = _mesh(4)
     update, sharder = zero.build_zero_update(tx, params, mesh, axis="data")
@@ -182,7 +293,7 @@ def test_zero_update_matches_unsharded_step_for_step():
         p_sharded, opt_sharded = update(p_sharded, opt_sharded, grads)
         u, opt_ref = tx.update(grads, opt_ref, p_ref)
         p_ref = optax.apply_updates(p_ref, u)
-        for k in ("w", "b", "s"):
+        for k in params:
             np.testing.assert_allclose(
                 np.asarray(p_sharded[k]), np.asarray(p_ref[k]),
                 rtol=0, atol=5e-7,  # <= a few float32 ulps from XLA fusion
@@ -190,7 +301,8 @@ def test_zero_update_matches_unsharded_step_for_step():
             )
 
 
-def test_zero_fused_step_trajectory_and_bytes():
+@pytest.mark.parametrize("kind", TOY_KINDS)
+def test_zero_fused_step_trajectory_and_bytes(kind):
     """The fused step (reduce_scatter local grads -> shard update ->
     all_gather) tracks the unsharded DP step, and per-chip optimizer
     state is >= ~2x smaller at world 4 (acceptance criterion)."""
@@ -200,7 +312,7 @@ def test_zero_fused_step_trajectory_and_bytes():
 
     from ray_tpu.train import zero
 
-    params, loss_fn, x, y = _toy_problem()
+    params, loss_fn, x, y = _toy_problem(kind)
     tx = optax.adamw(1e-2)
     mesh = _mesh(4)
     step, _ = zero.build_zero_step(loss_fn, tx, params, mesh, axis="data", donate=False)
@@ -224,7 +336,7 @@ def test_zero_fused_step_trajectory_and_bytes():
         pz, opt_z, lz = step(pz, opt_z, batch)
         pu, opt_full, lu = ref_step(pu, opt_full, (x, y))
         np.testing.assert_allclose(float(lz), float(lu), rtol=1e-5)
-    for k in ("w", "b", "s"):
+    for k in params:
         np.testing.assert_allclose(
             np.asarray(pz[k]), np.asarray(pu[k]), rtol=1e-3, atol=1e-4
         )
@@ -233,7 +345,8 @@ def test_zero_fused_step_trajectory_and_bytes():
     assert shard_bytes * 2 <= full_bytes, (full_bytes, shard_bytes)
 
 
-def test_zero_logical_state_reshards_across_worlds(tmp_path):
+@pytest.mark.parametrize("kind", TOY_KINDS)
+def test_zero_logical_state_reshards_across_worlds(tmp_path, kind):
     """Optimizer state saved through the elastic format at world 4
     restores at world 2 and continues the SAME trajectory (reshard is
     exact: the pad region provably stays zero)."""
@@ -242,7 +355,7 @@ def test_zero_logical_state_reshards_across_worlds(tmp_path):
 
     from ray_tpu.train import elastic_checkpoint as ec, zero
 
-    params, loss_fn, x, y = _toy_problem()
+    params, loss_fn, x, y = _toy_problem(kind)
     tx = optax.adamw(1e-2)
     mesh4, mesh2 = _mesh(4), _mesh(2)
     upd4, sh4 = zero.build_zero_update(tx, params, mesh4, axis="data")
@@ -265,7 +378,7 @@ def test_zero_logical_state_reshards_across_worlds(tmp_path):
     upd2, _ = zero.build_zero_update(tx, params, mesh2, axis="data")
     p2_resharded, opt2 = upd2(p1_at2, opt2, grads)
     p2_straight, opt4 = upd4(p1, opt4, grads)
-    for k in ("w", "b", "s"):
+    for k in params:
         np.testing.assert_allclose(
             np.asarray(p2_resharded[k]), np.asarray(p2_straight[k]),
             rtol=0, atol=5e-7,
