@@ -29,6 +29,11 @@ Parameters of a traffic file read here (all lengths in tokens):
   user_turn_tokens    dist
   answer_tokens       dist  (max_new_tokens of the request; no EOS)
   turns_per_session   closed: fixed count; open: ignored (followup_share decides)
+  stagger_first_session  closed: client c's FIRST session is cut to turns - (c * turns // clients)
+                      turns, so that at any instant the clients stand spread evenly over a
+                      session's turns (an eighth of them at each of 8) instead of walking
+                      through it in step; by turns, not by seconds, so it holds for a faster
+                      or slower program alike. Off where the key is absent.
   followup_share      open: probability that an arrival continues an open session
   followup_min_gap_s  open: a session is continued only this long after its last turn was due
   carry_history       whether a turn's prompt holds the session's earlier turns
@@ -143,13 +148,14 @@ def generate(traffic: Dict[str, Any], horizon_s: float) -> List[Request]:
     n_clients = int(traffic["clients"])
     per_client = int(horizon_s / 0.05 / max(1, n_clients)) + 8
     turns = int(traffic.get("turns_per_session", 1))
+    stagger = bool(traffic.get("stagger_first_session", False))
     n_sessions = 0
     for c in range(n_clients):
         made = 0
         while made < per_client:
             s = new_session(n_sessions)
             n_sessions += 1
-            for _ in range(turns):
+            for _ in range(turns - (c * turns // n_clients if stagger and made == 0 else 0)):
                 turn_len = _draw(rng, traffic["user_turn_tokens"])
                 answer_len = _draw(rng, traffic["answer_tokens"])
                 if s.next_len(turn_len, carry) > cap:

@@ -51,7 +51,7 @@ def test_the_cell_is_correct_and_reads_its_counters():
     # every context (1 122 tokens and more) lies past TINY's window of 16 on five of six layers
     assert 100 / 6 < got["decode_kv_window_read_pct"] < 25
     assert 0 < got["decode_experts_touched_pct"] <= 100
-    assert got["serve_compiles_in_window.afmoe"] == 0 and got["prefix_hit_page_share_pct.afmoe"] > 50
+    assert got["serve_compiles_in_window"] == 0 and got["prefix_hit_page_share_pct"] > 50
     assert not [name for name in got if "roofline" in name or "idle" in name]  # no device number from a CPU
     assert set(rehearse_one(spec.ROOT, CELL, 0)["metrics"]) == {"serve_tok_s", "setup_s"}
 

@@ -49,7 +49,7 @@ def test_the_cell_is_correct_and_reads_its_counters():
     got = {name: m["value"] for name, m in line["metrics"].items()}
     # TINY: two layers of 2 K/V heads x 136 pairs x 17 float32 in and out a live row, beside 90 k float32 weights
     assert 30 < got["decode_state_bytes_share_pct"] < 60
-    assert 1 <= got["decode_batch_mean.retention"] <= 2 and got["serve_compiles_in_window.retention"] == 0
+    assert 1 <= got["decode_batch_mean"] <= 2 and got["serve_compiles_in_window"] == 0
     assert not [name for name in got if "roofline" in name or "idle" in name or "time_share" in name]  # no device number from a CPU
     assert set(rehearse_one(spec.ROOT, CELL, 0)["metrics"]) == {"serve_tok_s", "setup_s"}
 

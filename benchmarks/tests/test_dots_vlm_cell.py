@@ -29,11 +29,11 @@ def test_the_cell_is_correct_and_reads_its_counters():
     assert facts["served_sample"]["margins"]["positions"] >= 100
     got = {name: m["value"] for name, m in line["metrics"].items()}
     # TINY: 8 of 16 experts held (rank 1: half of groups 2 and 3) and 4 picks a token out of 2 of 4 groups
-    assert 20 < got["decode_held_pick_pct.mla"] < 80
+    assert 20 < got["decode_held_pick_pct"] < 80
     assert 0 < got["decode_latent_bytes_share_pct"] < 50
     # tests/tiny.json's documents of 96-176 tokens asked several questions: most prompt pages are hits
-    assert got["prefix_hit_page_share_pct.mla"] > 50
-    assert 1 <= got["decode_batch_mean.mla"] <= 2 and got["serve_compiles_in_window.mla"] == 0
+    assert got["prefix_hit_page_share_pct"] > 50
+    assert 1 <= got["decode_batch_mean"] <= 2 and got["serve_compiles_in_window"] == 0
     assert not [name for name in got if "roofline" in name or "idle" in name or "time_share" in name]  # no device number from a CPU
     assert set(rehearse_one(spec.ROOT, CELL, 0)["metrics"]) == {"serve_tok_s", "setup_s"}
 

@@ -14,6 +14,7 @@ step, its roofline, the kernels' rooflines, the host's prep) reads the same in
 all four."""
 
 import os
+import re
 import types
 
 import pytest
@@ -27,7 +28,7 @@ RECORDED = os.path.join(spec.BENCH_DIR, "recorded")
 ENGINE = os.path.join(RECORDED, "tiny_v5e_engine.xplane.pb.gz")
 OLDER = [os.path.join(RECORDED, "tiny_v5e_llm.xplane.pb.gz"), os.path.join(RECORDED, "tiny_v5e.xplane.pb.gz")]
 DECODE = r"^jit_llm_decode\("
-# PR 40's five metrics: each is a family of files, the plain name and its suffixed twins (`.retention`, `.hybrid`, `.tpot`)
+# PR 40's five metrics: each is a family of files, the plain name and, where the end-to-end metric it moves differs, a twin (`.tpot`)
 FAMILIES = {
     "serve_idle_no_work_pct": ("trace_idle_causes", "engine"), "serve_idle_in_flight_pct": ("trace_idle_causes", "paged forward"),
     "serve_idle_host_pct": ("trace_idle_causes", "engine"), "decode_device_step_p50_ms": ("trace_modules", "paged forward"),
@@ -190,9 +191,11 @@ def test_unnamed_executables_and_spans_without_ordinals_read_as_nothing():
 
 @pytest.mark.parametrize("base", sorted(FAMILIES))
 def test_the_new_metric_files(base):
-    """A family is held to what it must cover, not to a count of cells: every member's `cells` are its BENCHMARK.json
-    entry's `workloads`, every member reads through the family's reader under the family's layer, and every serving
-    cell reports the family once for each end-to-end metric a member moves there (a later cell brings its own twin)."""
+    """A family is held to what it must cover, not to a count of cells: every member reads through the family's reader
+    under the family's layer with the family's args, its cells (the BENCHMARK.json entry's `workloads`, the one place
+    they stand) are serving cells, and every serving cell reports the family once for each end-to-end metric a member
+    moves there (a later cell appends its name to the entry's `workloads`; PR 58: ONE pattern names the decode
+    executable of whatever cache layout, `jit_llm_decode`, `_hybrid`, `_state`, of which a replica compiles one)."""
     reader, layer = FAMILIES[base]
     bench = spec.benchmark_json()
     serving = [w["name"] for w in bench["workloads"] if "serve" in w["name"]]
@@ -202,21 +205,31 @@ def test_the_new_metric_files(base):
     for entry in members:
         mf = spec.load_json(os.path.join(spec.BENCH_DIR, "metrics", entry["name"] + ".json"))
         assert mf["reader"] == reader and mf["layer"] == entry["layer"] == layer and mf["moves"] == entry["moves"], entry["name"]
-        assert mf["cells"] == entry["workloads"] and set(mf["cells"]) <= set(serving), entry["name"]
+        assert set(entry["workloads"]) <= set(serving), entry["name"]
         assert mf["args"] == dict(spec.load_json(os.path.join(spec.BENCH_DIR, "metrics", base + ".json"))["args"], **{k: mf["args"][k] for k in ("module",) if k in mf["args"]})
-        seen += [(c, mf["moves"]) for c in mf["cells"]]
+        if "module" in mf["args"]:
+            names = {"jit_llm_decode(7)", "jit_llm_decode_hybrid(7)", "jit_llm_decode_state(7)"} if entry["name"] == base else {"jit_llm_decode(7)"}
+            others = {"jit_llm_decode_other(7)", "jit_llm_prefill_p8(7)", "xjit_llm_decode(7)", "jit_llm_decode_hybrid_state(7)", *names}
+            assert {n for n in others if re.search(mf["args"]["module"], n)} == names, entry["name"]
+        seen += [(c, mf["moves"]) for c in entry["workloads"]]
     assert len(seen) == len(set(seen)) and {c for c, _m in seen} == set(serving)  # no cell twice for one end-to-end metric, none left out
     assert spec.read_metric(types.SimpleNamespace(bench_dir=spec.BENCH_DIR, arch=dense_decoder, config=TINY, allow_cpu=False), base, evidence()) is not None
 
 
+# PR 58: each read what the entry it names reads (same reader, args and `moves`); its cells are on that entry now
+MERGED = spec.load_json(os.path.join(spec.BENCH_DIR, "tools", "renamed_pr58.json"))
+
+
 def test_no_retired_name_is_left():
     """PR 45: the metrics that timed a device step through the host span `bench.decode`, and the share of the idle
-    under no span, are gone from BENCHMARK.json, from metrics/ and from the readers; what stands in their place is there."""
+    under no span, are gone from BENCHMARK.json, from metrics/ and from the readers; what stands in their place is there.
+    PR 58: so are the copies of a quantity under a family's suffix, and the entry each was merged into is there."""
     names = {m["name"] for m in spec.benchmark_json()["per_layer"]}
     files = {f[: -len(".json")] for f in os.listdir(os.path.join(spec.BENCH_DIR, "metrics"))}
     for gone in ("decode_roofline.tpot", "decode_roofline.tok", "decode_roofline.afmoe", "decode_step_p50_ms.tpot", "decode_step_p50_ms.tok",
-                 "decode_step_p50_ms.afmoe", "serve_idle_unexplained_pct", "serve_idle_unexplained_pct.afmoe"):
+                 "decode_step_p50_ms.afmoe", "serve_idle_unexplained_pct", "serve_idle_unexplained_pct.afmoe", *MERGED):
         assert gone not in names and gone not in files
+    assert len(MERGED) == 59 and set(MERGED.values()) <= names & files
     assert {"decode_device_roofline.tpot", "decode_period_p50_ms", "decode_period_p50_ms.tpot"} <= names & files
     readers = os.listdir(os.path.join(spec.BENCH_DIR, "readers"))
     assert "trace_decode_roofline.py" not in readers and "trace_decode_roofline_counted.py" not in readers and "span_period.py" in readers

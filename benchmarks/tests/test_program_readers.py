@@ -71,7 +71,7 @@ def test_the_new_metric_files_name_these_readers_and_paths():
                          ("engine_host_share_pct", "counter_residual_share"),
                          ("decode_host_prep_ms.tpot", "trace_program_spans"),
                          ("decode_host_prep_ms.tok", "trace_program_spans"),
-                         ("prefix_hit_page_share_pct", "counter_ratio"), ("prefix_hit_page_share_pct.afmoe", "counter_ratio")):
+                         ("prefix_hit_page_share_pct", "counter_ratio")):
         mf = spec.load_json(os.path.join(spec.BENCH_DIR, "metrics", name + ".json"))
         assert mf["reader"] == reader
     # the paths exist in what the engine really returns

@@ -81,8 +81,10 @@ def test_without_a_chip_the_command_exits_non_zero_and_prints_no_result():
 
 
 def test_a_fifth_cell_is_added_with_new_files_only(tmp_path):
-    """A configuration, a mix, a metric and a cell: four new files and one
-    entry each in BENCHMARK.json; no file that exists is edited."""
+    """A configuration, a mix, a metric and a cell: three new files, an entry
+    each in BENCHMARK.json, and the cell's name appended to the `workloads` of
+    the generic entries it reports (no entry copied, no suffix); no file that
+    exists is edited."""
     root = str(tmp_path)
     before = copy_of_the_benchmark(root)
     bench = spec.benchmark_json()
@@ -90,7 +92,7 @@ def test_a_fifth_cell_is_added_with_new_files_only(tmp_path):
     new_cfg["num_hidden_layers"] = 6
     mix = spec.load_json(os.path.join(spec.BENCH_DIR, "traffic", "docqa-batch.json"))
     mix.update(clients=2, turns_per_session=2, schedule_seed=7)
-    metric = {"layer": "paged forward", "moves": "serve_tok_s", "cells": ["fifth-cell"], "reader": "span_stat",
+    metric = {"layer": "paged forward", "moves": "serve_tok_s", "reader": "span_stat",
               "args": {"span": "bench.prefill", "stat": "mean_arg", "arg": "cached_tokens"}}
     bench["configs"].append({"name": "fifth-config", "source": new_cfg["source"], "file": "benchmarks/configs/fifth-config.json",
                              "reduced": ["num_hidden_layers"], "why": "test"})
@@ -199,7 +201,7 @@ def test_a_routed_models_cells_are_not_correct_against_a_top_k_minus_1_reference
         "traffic/olmoe-docqa.json": spec.load_json(os.path.join(spec.BENCH_DIR, "traffic", "docqa-batch.json")),
     }
     bench = spec.benchmark_json()
-    cells = {"olmoe-train-wrong": ("olmoe-wrong-reference", "train-fixed-batch-moe", ("train_tok_s_chip", "train_step_p50_ms.moe")),
+    cells = {"olmoe-train-wrong": ("olmoe-wrong-reference", "train-fixed-batch-moe", ("train_tok_s_chip", "train_step_p50_ms")),
              "olmoe-serve": ("olmoe-served", "olmoe-docqa", ("serve_tok_s", "decode_batch_mean")),
              "olmoe-serve-wrong": ("olmoe-served-wrong-reference", "olmoe-docqa", ("serve_tok_s", "decode_batch_mean"))}
     for config in sorted({c for c, _t, _m in cells.values()}):
@@ -235,11 +237,12 @@ FAULTS = {
     "half_batch": ("mistral7b-train-seq4k-1chip", 1, "lib/worker_train.py", STEP,
                    "            params, opt_state, loss = step(params, opt_state, jnp.concatenate([tokens[: tokens.shape[0] // 2]] * 2))\n",
                    {"grad_norm_gap"}),
-    # the exchange between chips left out: every chip keeps its own slice of its own gradient
+    # the exchange between chips left out: every chip keeps its own slice of its own gradient (since PR 57 a leaf's
+    # gradient is scattered along one of its own dimensions; the form before it sliced a flat vector and broke there)
     "exchange_left_out": ("mistral7b-train-seq4k-zero-4chip", 4, "lib/worker_train.py",
                           "    _init_state, step = tfm.build_train_step(cfg, tx, mesh, zero_axis=zero_axis)\n",
-                          "    jax.lax.psum_scatter = lambda x, axis, scatter_dimension=0, tiled=True: n * jax.lax.dynamic_slice(\n"
-                          "        x, (jax.lax.axis_index(axis) * (x.shape[0] // n),), (x.shape[0] // n,))\n"
+                          "    jax.lax.psum_scatter = lambda x, axis, scatter_dimension=0, tiled=True: n * jax.lax.dynamic_slice_in_dim(\n"
+                          "        x, jax.lax.axis_index(axis) * (x.shape[scatter_dimension] // n), x.shape[scatter_dimension] // n, scatter_dimension)\n"
                           "    _init_state, step = tfm.build_train_step(cfg, tx, mesh, zero_axis=zero_axis)\n",
                           {"grad_norm_gap"}),
     # every decoded token altered where it is produced, under the engine

@@ -13,18 +13,20 @@ import glob
 import gzip
 import json
 import os
+import shutil
 
 import pytest
 
-from benchmarks.lib import spec, trace, xplane_meta as xm
+from benchmarks.lib import evidence as on_disk, spec, trace, xplane_meta as xm
 from benchmarks.readers import trace_scope_exposed, trace_scope_mxu, trace_scope_share
+from benchmarks.tools import scope_rows
 
 RECORDED = os.path.join(spec.BENCH_DIR, "recorded")
 DENSE, ROUTED, PARENT = (os.path.join(RECORDED, f"tiny_v5e{tag}.xplane.pb.gz") for tag in ("_scopes", "_moe_scopes", ""))
 TRAIN = "jit(train_step)/"
 
-# The rows that partition a training cell's `XLA Ops` time by scope (ISSUE 56's table; the benchmark holds three of
-# them as metrics, tools/device_scope_report.py prints a row a scope): every name of the table in exactly one.
+# The rows that partition a training cell's `XLA Ops` time by scope (ISSUE 56's table; since PR 58 the benchmark holds
+# all nine as `train_scope_share_pct.<row>`, tools/device_scope_report.py prints a row a scope): every name of the table in exactly one.
 PARTITION = {
     "attn_proj": ["attn.qkv", "attn.qk_norm", "attn.rope", "attn.gate", "attn.out", "attn.mla.q", "attn.mla.kv_down", "attn.mla.expand",
                   "attn.mla.absorb", "attn.mla.out", "kda.gates", "kda.out"],
@@ -144,6 +146,22 @@ def test_the_share_rows_partition_the_ops(path):
     assert rows["attn_core"] > 3 and rows["head_loss"] > 3 and rows["optimizer"] > 1
 
 
+@pytest.mark.parametrize("path", [DENSE, ROUTED], ids=["dense", "routed"])
+def test_the_nine_metric_files_are_the_partition_and_the_tool_adds_them_up(path, tmp_path, capsys):
+    """PR 58 entered all nine rows: `tools/scope_rows.py` reads them from a traced run's evidence on disk
+    (lib/evidence.py), through the metric files, and holds their sum to the listed share of the busy time."""
+    ev = evidence_of(path)
+    ev["cell"].bench_dir = str(tmp_path)
+    os.makedirs(os.path.join(str(tmp_path), "out", f"{ev['cell'].name}-0-trace", "plugins", "profile", "recorded"))
+    shutil.copy(path, os.path.join(ev["cell"].out_prefix + "-trace", "plugins", "profile", "recorded", "tiny.xplane.pb.gz"))
+    on_disk.write(ev["cell"], ev)
+    assert scope_rows.main([ev["cell"].out_prefix]) == 0
+    said = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert said["rows"] == {name: trace_scope_share.read(evidence_of(path), {"scopes": scopes}) for name, scopes in PARTITION.items()}
+    assert said["every_scope_in_one_row"] and abs(said["sum"] - said["listed_share_of_busy_pct"]) < 1e-6
+    assert sorted(said["in_cell"]) == sorted(set(PARTITION) - {"moe_routing", "moe_experts"})  # the Mistral cell lists the dense rows
+
+
 METRIC_FILES = sorted(p for p in glob.glob(os.path.join(spec.BENCH_DIR, "metrics", "*.json")) if json.load(open(p))["reader"].startswith("trace_scope_"))
 
 
@@ -151,7 +169,11 @@ METRIC_FILES = sorted(p for p in glob.glob(os.path.join(spec.BENCH_DIR, "metrics
 def test_a_scope_metric_reads_the_scoped_trace_and_nothing_of_the_parents(path, dense):
     name = os.path.basename(path)[:-5]
     cell = dense["cell"]
-    value = spec.read_metric(cell, name, dense)
+    entry = next(m for m in spec.benchmark_json()["per_layer"] if m["name"] == name)
+    if name.startswith("train_scope_share_pct."):  # a row of the partition, with the row's scopes, in the cells where it is not nought
+        assert spec.metric_file(cell, name)["args"] == {"scopes": PARTITION[name.rpartition(".")[2]]}
+    routed_only = all("olmoe" in c for c in entry["workloads"])  # the routed step's rows read 0 on a dense step, and `ffn` 0 on a routed one
+    value = spec.read_metric(cell, name, evidence_of(ROUTED) if routed_only else dense)
     if name.startswith("zero_exposed_ms"):
         assert value is None  # one chip: no collective
     else:

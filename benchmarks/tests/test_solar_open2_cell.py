@@ -32,7 +32,7 @@ def test_the_cell_is_correct_and_reads_its_counters():
     # TINY: 8 of 16 experts held and 4 picks a token: about half of the picks fall here
     assert 30 < got["decode_held_pick_pct"] < 70
     assert 0 < got["decode_state_bytes_share_pct.hybrid"] < 50 and 0 < got["decode_kv_bytes_share_pct"] < 50
-    assert 1 <= got["decode_batch_mean.hybrid"] <= 4 and got["serve_compiles_in_window.hybrid"] == 0
+    assert 1 <= got["decode_batch_mean"] <= 4 and got["serve_compiles_in_window"] == 0
     assert not [name for name in got if "roofline" in name or "idle" in name or "time_share" in name]  # no device number from a CPU
     assert set(rehearse_one(spec.ROOT, CELL, 0)["metrics"]) == {"serve_tok_s", "setup_s"}
 
@@ -54,14 +54,14 @@ def test_a_wrong_reference_is_not_correct(tmp_path, wrong):
 # cross many borders, and its state pool starts as 1e3 everywhere, which a sound copy never reads into a served token (a
 # prompt starts from zeros whatever its slot held; an inactive row's result is nobody's) and a slot that is not cleared does.
 # name: (the sound line of models/transformer.py, the line in its place)
-STORED = "        return o[None], (kp, vp, sp.at[layer, slot].set(state_out), tp.at[layer, slot].set(tails_out))\n"
-CLEARED = "        state_in = jnp.where(c0 > 0, sp[layer, slot], jnp.zeros((), sp.dtype))\n"
-TAILS = "        tails_in = jnp.where(c0 > 0, tp[layer, slot], jnp.zeros((), tp.dtype))\n"
+STORED = "            return o[None], (sp.at[layer, slot].set(state_out), tp.at[layer, slot].set(tails_out))\n"
+CLEARED = "            state_in = jnp.where(c0 > 0, sp[layer, slot], jnp.zeros((), sp.dtype))\n"
+TAILS = "            tails_in = jnp.where(c0 > 0, tp[layer, slot], jnp.zeros((), tp.dtype))\n"
 PROGRAM_FAULTS = {
     "sound": (CLEARED, CLEARED),
-    "state_not_carried_across_a_chunk_border": (STORED, "        return o[None], (kp, vp, sp, tp.at[layer, slot].set(tails_out))\n"),
-    "old_state_not_cleared_where_a_prompt_starts": (CLEARED, "        state_in = sp[layer, slot]\n"),
-    "tail_not_carried_across_a_chunk_border": (TAILS, "        tails_in = jnp.zeros_like(tp[layer, slot])\n"),
+    "state_not_carried_across_a_chunk_border": (STORED, "            return o[None], (sp, tp.at[layer, slot].set(tails_out))\n"),
+    "old_state_not_cleared_where_a_prompt_starts": (CLEARED, "            state_in = sp[layer, slot]\n"),
+    "tail_not_carried_across_a_chunk_border": (TAILS, "            tails_in = jnp.zeros_like(tp[layer, slot])\n"),
 }
 
 

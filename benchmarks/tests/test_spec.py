@@ -52,11 +52,28 @@ def test_per_layer_metric_has_its_file_and_its_moves_is_reported_in_each_of_its_
     cell = spec.find_cell(CELLS[0])
     mf = spec.metric_file(cell, entry["name"])
     assert mf["layer"] == entry["layer"] and mf["moves"] == entry["moves"]
-    assert mf["cells"] == entry.get("workloads", CELLS)
+    assert set(mf) <= {"layer", "moves", "reader", "args", "what"}  # its cells are the entry's `workloads`, and stand nowhere else
     importlib.import_module(f"benchmarks.readers.{mf['reader']}").read  # the reader exists
     moved = next(m for m in BENCH["end_to_end"] if m["name"] == entry["moves"])
     for c in entry.get("workloads", CELLS):
         assert c in moved.get("workloads", CELLS), f"{entry['name']} moves {moved['name']}, which {c} does not report"
+
+
+def test_one_entry_a_quantity_and_a_metrics_cells_in_one_place():
+    """PR 58. A quantity is split only where what it `moves`, its reader or its args differ: two entries that read
+    the same thing for the same end-to-end metric are one entry with both cells. No metric file names cells (the test
+    above holds a file to its keys), so a PR that adds a cell appends its name to `workloads` lists and copies no entry;
+    and no file is left without an entry."""
+    assert len(BENCH["per_layer"]) <= 128
+    cell = spec.find_cell(CELLS[0])
+    seen = {}
+    for entry in BENCH["per_layer"]:
+        mf = spec.metric_file(cell, entry["name"])
+        key = (mf["reader"], json.dumps(mf.get("args", {}), sort_keys=True), mf["moves"])
+        assert key not in seen, f"{entry['name']} and {seen[key]} read the same quantity for {mf['moves']}: one entry, both cells"
+        seen[key] = entry["name"]
+    files = {f[: -len(".json")] for f in os.listdir(os.path.join(spec.BENCH_DIR, "metrics"))}
+    assert files == {m["name"] for m in METRICS}
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -219,6 +236,27 @@ def test_followup_turn_shares_its_sessions_prefix():
     assert b[: len(a)] == a and len(b) > len(a) + follow.segments[-1][1]  # parent + stand-in answer + new turn
     share = sum(1 for r in reqs if len(r.segments) > 2) / len(reqs)
     assert 0.3 < share < 0.75
+
+
+def test_staggered_clients_stand_spread_over_a_sessions_turns():
+    """agent-turns: after any number of requests a client, an eighth of the 64 clients stand at each
+    of a session's 8 turns (in step, all 64 would open a session, and miss its document, at once);
+    a file without the key draws what it drew before the key was read."""
+    cell = spec.find_cell("trinitymini-serve-agent-turns")
+    assert cell.traffic["stagger_first_session"] is True
+    by_client = {}
+    for r in traffic.generate(cell.traffic, 54):
+        by_client.setdefault(r.client, []).append(r)
+    assert len(by_client) == 64
+    for k in range(12):  # the k-th request of every client: how many of them open a session
+        opens = sum(1 for reqs in by_client.values() if len(reqs[k].segments) == 3)  # shared + document + the turn
+        assert (opens == 64) if k == 0 else (6 <= opens <= 10), (k, opens)
+    plain = dict(cell.traffic)
+    del plain["stagger_first_session"]
+    in_step = {}
+    for r in traffic.generate(plain, 54):
+        in_step.setdefault(r.client, []).append(r)
+    assert [sum(1 for reqs in in_step.values() if len(reqs[k].segments) == 3) for k in (0, 1, 7)] == [64, 0, 0]
 
 
 def test_docqa_questions_share_their_document():

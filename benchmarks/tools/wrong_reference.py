@@ -91,7 +91,6 @@ def add_cells(root: str, workload: str, names) -> dict:
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(root, conf["file"])) as f:
         config = json.load(f)
-    batch_mean = next(m["name"] for m in bench["per_layer"] if m["name"].startswith("decode_batch_mean") and workload in m.get("workloads", []))
     cells = {}
     for name in names:
         arch, cname = f"{config['arch']}_{name}", f"{conf['name']}-{name}"
@@ -103,7 +102,7 @@ def add_cells(root: str, workload: str, names) -> dict:
         cells[name] = f"{workload}-{name}"
         bench["workloads"].append(dict(cell, name=cells[name], config=cname))
         for m in bench["end_to_end"] + bench["per_layer"]:
-            if workload in m.get("workloads", []) and m["name"] in ("serve_tok_s", batch_mean):
+            if workload in m.get("workloads", []) and m["name"] in ("serve_tok_s", "decode_batch_mean"):
                 m["workloads"].append(cells[name])
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)

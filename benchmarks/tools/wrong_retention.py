@@ -85,7 +85,7 @@ def add_cells(root: str, workload: str, names) -> dict:
         cells[name] = f"{workload}-{name}"
         bench["workloads"].append(dict(cell, name=cells[name], config=cname))
         for m in bench["end_to_end"] + bench["per_layer"]:
-            if workload in m.get("workloads", []) and m["name"] in ("serve_tok_s", "decode_batch_mean.retention"):
+            if workload in m.get("workloads", []) and m["name"] in ("serve_tok_s", "decode_batch_mean"):
                 m["workloads"].append(cells[name])
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
