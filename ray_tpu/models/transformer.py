@@ -128,8 +128,9 @@ class TransformerConfig:
     # would add is left out: that partial sum goes on to the next layer.
     n_experts_held: int = 0
     first_expert: int = 0
-    # Solar-Open2 family: the stack is periods of one softmax-attention layer
-    # (this config's heads, gate and all) followed by kda_per_period Kimi
+    # Solar-Open2 family: the stack is periods of one full layer (softmax
+    # attention with this config's heads, gate and all; latent attention where
+    # kv_lora_rank is set: see `full_layers` below) followed by kda_per_period Kimi
     # Delta Attention layers (ops/kda.py: n_heads heads of head_dim key and
     # value channels, a short convolution of kda_conv taps on q, k and v, a
     # per-channel decay through a thin pair of matrices of rank head_dim, a
@@ -163,6 +164,29 @@ class TransformerConfig:
     # keeps the topk_group best before its top-k.
     n_group: int = 1
     topk_group: int = 1
+    # GigaChat3.5 family, each off by default. A KDA stack's full layers may be
+    # latent-attention layers (kv_lora_rank; `attn_gate` then gates them too,
+    # `wg` [d, n_heads * v_head_dim], between `w_uv` and `wo`), and leading
+    # dense layers may stand before its periods: `full_layers` names the full
+    # layers in published order (empty: one at the head of every period), a
+    # dense layer in it is a full layer, one not in it a delta-rule layer.
+    # kda_decay "head": the delta-rule layers take the gated-delta-rule form
+    # (Gated DeltaNet): ONE decay a head through `w_a` [d, n_heads], beta =
+    # sigmoid in (0, 1), kda_key_heads key heads each read by n_heads /
+    # kda_key_heads value heads (0: as many), a full-width output gate
+    # 2 sigmoid(h `w_z`); the recurrence, the state and the kernel are the
+    # "kda" row's, the decay broadcast over a head's channels. kda_head_dim: a
+    # delta-rule head's d_k = d_v where it is not the full layers' d_head.
+    # norm_gate c: every norm's scale is c * sigmoid(w) (a zero-centred gated
+    # norm: w = 0 scales by c / 2), the latent layer's two inner norms too.
+    # swiglu_limit a: SwiGLU as silu(min(gate, a)) * clip(up, -a, a), dense,
+    # shared and routed alike.
+    full_layers: Tuple[int, ...] = ()
+    kda_decay: str = "channel"  # "channel" | "head"
+    kda_key_heads: int = 0
+    kda_head_dim: int = 0
+    norm_gate: float = 0.0
+    swiglu_limit: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -232,15 +256,23 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     held = cfg.experts_held
     if not 0 <= cfg.first_expert <= E - held:
         raise ValueError(f"experts [{cfg.first_expert}, {cfg.first_expert + held}) are not among the router's {E}")
-    per = cfg.kda_per_period
-    if per and (cfg.n_layers % (per + 1) or any(cfg.windows) or any(cfg.rope_layers) or not cfg.rope_layers
-                or cfg.n_dense_layers or cfg.retention_degree or cfg.parallel_block):
-        raise ValueError("a KDA stack is whole periods of (one softmax layer, kda_per_period KDA layers), no rope (rope_layers all off), window or dense layer")
+    per, nd = cfg.kda_per_period, cfg.n_dense_layers
+    if per:
+        heads_of_periods = tuple(range(nd, cfg.n_layers, per + 1))
+        full = cfg.full_layers or heads_of_periods
+        if ((cfg.n_layers - nd) % (per + 1) or tuple(i for i in full if i >= nd) != heads_of_periods
+                or len([i for i in full if i < nd]) not in (0, nd) or any(cfg.windows) or cfg.retention_degree or cfg.parallel_block):
+            raise ValueError("a KDA stack is leading dense layers of ONE kind, then whole periods of (one full layer, kda_per_period delta-rule layers): "
+                             "no other list of full layers, no stack that ends inside a period, no window, retention or parallel block")
+        if not cfg.kv_lora_rank and (any(cfg.rope_layers) or not cfg.rope_layers):
+            raise ValueError("a KDA stack whose periods are headed by a softmax layer has no rope (rope_layers all off): rope in such a period is computed against no reference yet")
+        if cfg.kda_decay not in ("channel", "head") or cfg.n_heads % (cfg.kda_key_heads or cfg.n_heads) or (cfg.kda_decay == "channel" and cfg.kda_key_heads):
+            raise ValueError(f"kda_decay {cfg.kda_decay!r} with {cfg.kda_key_heads} key heads: 'channel' (as many key heads as heads) or 'head' (key heads that divide the {cfg.n_heads} heads)")
     if cfg.kv_lora_rank and not (cfg.q_lora_rank and cfg.qk_nope_dim and cfg.qk_rope_dim and cfg.v_head_dim
                                  and cfg.head_dim == cfg.qk_nope_dim + cfg.qk_rope_dim and cfg.qk_rope_dim % 2 == 0):
         raise ValueError("latent attention needs q_lora_rank, qk_nope_dim, qk_rope_dim (even), v_head_dim, and d_head their query head qk_nope_dim + qk_rope_dim")
-    if cfg.kv_lora_rank and (cfg.retention_degree or per or cfg.attn_gate or cfg.qk_norm or any(cfg.windows) or cfg.rope_layers):
-        raise ValueError("a latent-attention stack has no window, gate, q/k-norm, rope switch, retention or KDA layer")
+    if cfg.kv_lora_rank and (cfg.retention_degree or cfg.qk_norm or any(cfg.windows) or cfg.rope_layers):
+        raise ValueError("a latent-attention layer has no window, q/k-norm, rope switch or retention (a gate it may have, and delta-rule layers beside it in a KDA stack's periods)")
     if cfg.rope_scaling and (cfg.rope_scaling[0] != "yarn" or len(cfg.rope_scaling) != 7):
         raise ValueError(f"rope_scaling {cfg.rope_scaling!r}: ('yarn', factor, original context, beta_fast, beta_slow, mscale, mscale_all_dim) is computed")
     if cfg.n_group > 1 and (cfg.router_score != "sigmoid" or E % cfg.n_group or not 0 < cfg.topk_group <= cfg.n_group or E // cfg.n_group < 2):
@@ -253,36 +285,46 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
     def dense(key, shape, fan_in, dtype=cfg.dtype):
         return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
+    def into_ffn(key, shape):
+        """A SwiGLU's gate or up matrix [.., d, f]. Under a clamp (`swiglu_limit`) one column in sixteen is drawn eight
+        times as wide: a trained model has such outlier channels and the clamp is there for them; at unit scale no
+        pre-activation comes near a limit of 10, and a program that left the clamp out would pass every check."""
+        w = dense(key, shape, d)
+        return w * jnp.where(jnp.arange(shape[-1]) % 16 == 0, 8, 1).astype(w.dtype) if cfg.swiglu_limit else w
+
     # L below: a stack's leading shape, (layers,) or (periods, layers a period).
     def swiglu(k, L, f):
         return {
-            "w_gate": dense(next(k), (*L, d, f), d),
-            "w_up": dense(next(k), (*L, d, f), d),
+            "w_gate": into_ffn(next(k), (*L, d, f)),
+            "w_up": into_ffn(next(k), (*L, d, f)),
             "w_down": dense(next(k), (*L, f, d), f),
         }
 
     def kda_attn(k, L):
-        """A KDA layer's mixer (ops/kda.py). `a_log` and `dt_bias` as the
-        family draws them, so that a seeded state remembers some tens of
-        tokens: a state not carried over a chunk's border, or not cleared,
-        shows in the logits."""
+        """A delta-rule layer's mixer (ops/kda.py), in the form `kda_decay`
+        names. `a_log` and `dt_bias` as the family draws them, so that a
+        seeded state remembers some tens of tokens: a state not carried over
+        a chunk's border, or not cleared, shows in the logits."""
+        hd, by_head = _kda_cfg(cfg).head_dim, cfg.kda_decay == "head"
         n, f32 = nh * hd, jnp.float32
-        dt = jnp.exp(jax.random.uniform(next(k), (*L, n), f32, math.log(0.001), math.log(0.1)))
+        widths = dict(zip("qkv", _kda_widths(_kda_cfg(cfg))))
+        dt = jnp.exp(jax.random.uniform(next(k), (*L, nh if by_head else n), f32, math.log(0.001), math.log(0.1)))
         return {
-            "wq": dense(next(k), (*L, d, n), d),
-            "wk": dense(next(k), (*L, d, n), d),
+            "wq": dense(next(k), (*L, d, widths["q"]), d),
+            "wk": dense(next(k), (*L, d, widths["k"]), d),
             "wv": dense(next(k), (*L, d, n), d),
             "wo": dense(next(k), (*L, n, d), n),
-            **{"conv_" + name: dense(next(k), (*L, n, cfg.kda_conv), cfg.kda_conv) for name in "qkv"},
-            "w_fa": dense(next(k), (*L, d, hd), d),
-            "w_fb": dense(next(k), (*L, hd, n), hd),
+            **{"conv_" + name: dense(next(k), (*L, widths[name], cfg.kda_conv), cfg.kda_conv) for name in "qkv"},
+            # the decay: a head's one logit, or a key channel's through a thin pair
+            **({"w_a": dense(next(k), (*L, d, nh), d)} if by_head else
+               {"w_fa": dense(next(k), (*L, d, hd), d), "w_fb": dense(next(k), (*L, hd, n), hd)}),
             "a_log": jnp.log(jax.random.uniform(next(k), (*L, nh), f32, 1.0, 16.0)),
             "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
             "w_b": dense(next(k), (*L, d, nh), d),
             "o_norm": {"scale": jnp.ones((*L, hd), cfg.dtype)},
-            "w_ga": dense(next(k), (*L, d, hd), d),
-            "w_gb": dense(next(k), (*L, hd, n), hd),
-            "b_g": dense(next(k), (*L, n), 4.0),
+            # the output gate: full width, or a thin pair and its bias
+            **({"w_z": dense(next(k), (*L, d, n), d)} if by_head else
+               {"w_ga": dense(next(k), (*L, d, hd), d), "w_gb": dense(next(k), (*L, hd, n), hd), "b_g": dense(next(k), (*L, n), 4.0)}),
         }
 
     def latent_attn(k, L):
@@ -301,12 +343,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             "wo": dense(next(k), (*L, nh * cfg.v_head_dim, d), nh * cfg.v_head_dim),
         }
 
-    def blocks(k, k2, L, routed: bool, f: int, kda: bool = False):
-        """A stack of alike layers; k2: the stream of what a family has over the llama and OLMoE blocks."""
+    def blocks(k, k2, L, routed: bool, f: int, kind: str):
+        """A stack of alike layers of `kind` (a row of `KINDS`); k2: the stream of what a family has over the llama and OLMoE blocks."""
         L = (L,) if isinstance(L, int) else L
+        kda = kind == "kda"
         out = {
             "attn_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
-            "attn": kda_attn(k, L) if kda else latent_attn(k, L) if cfg.kv_lora_rank else {
+            "attn": kda_attn(k, L) if kda else latent_attn(k, L) if kind == "latent" else {
                 "wq": dense(next(k), (*L, d, nh * hd), d),
                 "wk": dense(next(k), (*L, d, nkv * hd), d),
                 "wv": dense(next(k), (*L, d, nkv * hd), d),
@@ -315,8 +358,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             "mlp_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
             "mlp": (
                 {
-                    "w_gate": dense(next(k), (*L, held, d, f), d),
-                    "w_up": dense(next(k), (*L, held, d, f), d),
+                    "w_gate": into_ffn(next(k), (*L, held, d, f)),
+                    "w_up": into_ffn(next(k), (*L, held, d, f)),
                     "w_down": dense(next(k), (*L, held, f, d), f),
                     "router": dense(next(k), (*L, d, E), d),
                 }
@@ -333,8 +376,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             qn, kn = (hd, hd) if cfg.qk_norm_per_head else (nh * hd, nkv * hd)
             out["attn"]["q_norm"] = {"scale": jnp.ones((*L, qn), cfg.dtype)}
             out["attn"]["k_norm"] = {"scale": jnp.ones((*L, kn), cfg.dtype)}
-        if cfg.attn_gate and not kda:
-            out["attn"]["wg"] = dense(next(k2), (*L, d, nh * hd), d)
+        if cfg.attn_gate and not kda:  # as wide as what it gates: the heads' outputs before `wo`
+            out["attn"]["wg"] = dense(next(k2), (*L, d, nh * (cfg.v_head_dim if kind == "latent" else hd)), d)
         if cfg.retention_degree:
             out["attn"]["wg"] = dense(next(k2), (*L, d, nkv), d)
         if cfg.post_norms:
@@ -355,19 +398,28 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             out["mlp"]["shared"] = swiglu(k2, L, cfg.d_ff_shared)
         return out
 
-    nd = cfg.n_dense_layers
+    kinds = {m.tree: m.kind for _, members in stack_plan(cfg) for m in members}  # each stacked tree's kind of layer
+    periods = (cfg.n_layers - nd) // (per + 1)
     params = {
         "embed": {"embedding": dense(next(k), (v, d), d)},
-        "blocks": blocks(k, k2, (cfg.n_layers - nd) // (per + 1), bool(E), cfg.d_ff),
+        "blocks": blocks(k, k2, periods, bool(E), cfg.d_ff, kinds["blocks"]),
         "final_norm": {"scale": jnp.ones((d,), cfg.dtype)},
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(k), (d, v), d)
+
+    def streams(*salts):  # from streams of their own: the other layers draw as any stack of theirs would
+        return (iter(jax.random.split(jax.random.fold_in(key, salt), 32)) for salt in salts)
+
     if nd:
-        params["dense_blocks"] = blocks(k2, k2, nd, False, cfg.d_ff_dense)
-    if per:  # from streams of their own: the softmax layers draw as any stack of theirs would
-        k3, k4 = (iter(jax.random.split(jax.random.fold_in(key, salt), 32)) for salt in (11, 13))
-        params["kda_blocks"] = blocks(k3, k4, (cfg.n_layers // (per + 1), per), bool(E), cfg.d_ff, kda=True)
+        params["dense_blocks"] = blocks(*(streams(17, 19) if kinds["dense_blocks"] == "kda" else (k2, k2)), nd, False, cfg.d_ff_dense, kinds["dense_blocks"])
+    if per:
+        params["kda_blocks"] = blocks(*streams(11, 13), (periods, per), bool(E), cfg.d_ff, "kda")
+    if cfg.norm_gate:  # c * sigmoid(w): w = 0 where a plain scale is 1 (a delta-rule layer's output norm keeps its plain scale)
+        def gated(path) -> bool:
+            return "norm" in path and "o_norm" not in path
+
+        params = jax.tree_util.tree_map_with_path(lambda path, a: jnp.zeros_like(a) if gated(jax.tree_util.keystr(path)) else a, params)
     return params
 
 
@@ -432,18 +484,18 @@ SCOPES = {
     "attn.rope": "rope on q and k",
     "attn.core": "`attend` and the views of q, k, v and o on both sides of it: the kernels, a cache's writes, XLA's copies round them",
     "attn.window": "a windowed layer's attention, whole-sequence or paged",
-    "attn.gate": "the attention output's sigmoid gate",
+    "attn.gate": "the attention output's sigmoid gate (a softmax layer's, a latent layer's)",
     "attn.out": "`wo`",
     "attn.mla.q": "a latent layer's query: low-rank pair, norm, split, rope",
     "attn.mla.kv_down": "a latent layer's down-projection: c_kv, k_r",
     "attn.mla.expand": "the expanded form's up-projections of c_kv",
     "attn.mla.absorb": "the absorbed form's `w_uk` on the query, `w_uv` on the output",
     "attn.mla.out": "a latent layer's `wo`",
-    "kda.gates": "a KDA layer's decay and beta",
-    "kda.conv": "its short convolutions and q/k norms",
+    "kda.gates": "a delta-rule layer's decay (a key channel's, or a head's broadcast over its channels) and beta",
+    "kda.conv": "its short convolutions and q/k norms (shared key heads repeated to their value heads)",
     "kda.chunk": "its chunked recurrence (prefill, whole sequences)",
     "kda.step": "its one-token recurrence (decode)",
-    "kda.out": "its output norm, gate",
+    "kda.out": "its output norm, gate (thin, or full width)",
     "retention.chunk": "a retention layer's prefill chunk",
     "ffn": "the dense feed-forward (a routed layer keeps its `moe.*`)",
     "moe.router": "router logits, top-k, weights",
@@ -485,11 +537,18 @@ def layer_norm(x, scale, eps):
     return (xf * lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def _norm_scale(w, cfg: TransformerConfig):
+    """What a norm multiplies by: its leaf `w`, or under `cfg.norm_gate` c the gated scale c * sigmoid(w)."""
+    if not cfg.norm_gate:
+        return w
+    return (cfg.norm_gate * jax.nn.sigmoid(w.astype(jnp.float32))).astype(w.dtype)
+
+
 def _norm(x, scale, cfg: TransformerConfig):
     with jax.named_scope("norm"):
         if cfg.norm_type == "layer":
             return layer_norm(x, scale, cfg.norm_eps)
-        return rms_norm(x, scale, cfg.norm_eps)
+        return rms_norm(x, _norm_scale(scale, cfg), cfg.norm_eps)
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -660,12 +719,19 @@ def stack_plan(cfg: TransformerConfig) -> Tuple[Tuple[int, Tuple[StackMember, ..
     (repeats, members), each the members' layers in order, `repeats` times.
     Alike layers are one segment of one member (a routed model's leading dense
     layers a segment of their own); a periodic pattern is one segment whose
-    members are a period. `_walk_stack` walks it, `cache_layout` reads what a
-    served sequence keeps from it; a new pattern is a new return value here."""
+    members are a period: a full layer ("softmax", or "latent" where the
+    config has latent attention), then `kda_per_period` delta-rule layers,
+    behind leading dense layers of whichever kind `full_layers` gives them.
+    `_walk_stack` walks it, `cache_layout` reads what a served sequence keeps
+    from it; a new pattern is a new return value here."""
     per, nd = cfg.kda_per_period, cfg.n_dense_layers
-    if per:
-        return ((cfg.n_layers // (per + 1), (StackMember("softmax", "blocks", 1, 0), StackMember("kda", "kda_blocks", per, 0))),)
     kind = "retention" if cfg.retention_degree else "latent" if cfg.kv_lora_rank else "softmax"
+    if per:
+        # The dense layers are of the kind the published list gives them; `first` counts the layers of a kind before a member's.
+        dense_kind = kind if 0 in cfg.full_layers else "kda"
+        dense = ((nd, (StackMember(dense_kind, "dense_blocks", 1, 0),)),) if nd else ()
+        full_before, kda_before = (nd, 0) if dense_kind == kind else (0, nd)
+        return (*dense, ((cfg.n_layers - nd) // (per + 1), (StackMember(kind, "blocks", 1, full_before), StackMember("kda", "kda_blocks", per, kda_before))))
     dense = ((nd, (StackMember(kind, "dense_blocks", 1, 0),)),) if nd else ()
     return (*dense, (cfg.n_layers - nd, (StackMember(kind, "blocks", 1, nd),)))
 
@@ -959,6 +1025,14 @@ def _ffn(h, mp, cfg: TransformerConfig, experts=None):
         return _dense_ffn(h, mp, cfg)
 
 
+def _swiglu_act(gate, up, cfg: TransformerConfig):
+    """silu(gate) * up in float32; under `cfg.swiglu_limit` a the gate cut from above and up on both sides first."""
+    f32 = jnp.float32
+    if not cfg.swiglu_limit:
+        return jax.nn.silu(gate.astype(f32)) * up.astype(f32)
+    return jax.nn.silu(jnp.minimum(gate.astype(f32), cfg.swiglu_limit)) * jnp.clip(up.astype(f32), -cfg.swiglu_limit, cfg.swiglu_limit)
+
+
 def _dense_ffn(h, mp, cfg: TransformerConfig):
     """SwiGLU or gelu of h through one set of matrices, under the caller's
     scope (`ffn`; a routed layer's shared expert: `moe.shared`)."""
@@ -967,7 +1041,7 @@ def _dense_ffn(h, mp, cfg: TransformerConfig):
         gate = jnp.einsum(
             "bsd,df->bsf", h, mp["w_gate"], preferred_element_type=jnp.float32
         )
-        act = (jax.nn.silu(gate) * up).astype(cfg.dtype)
+        act = _swiglu_act(gate, up, cfg).astype(cfg.dtype)
     else:
         act = jax.nn.gelu(up).astype(cfg.dtype)
     act = _ckpt(act, "mlp_act_bf16")
@@ -1037,7 +1111,7 @@ def _every_expert_ffn(x, experts, top_e, top_p, cfg: TransformerConfig):
         xe = jnp.broadcast_to(x, (held, *x.shape))
         gate = jnp.einsum("end,edf->enf", xe, w_gate, preferred_element_type=cfg.dtype)
         up = jnp.einsum("end,edf->enf", xe, w_up, preferred_element_type=cfg.dtype)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
+        act = _swiglu_act(gate, up, cfg).astype(cfg.dtype)
         ys = jnp.einsum("enf,efd->end", act, w_down, preferred_element_type=cfg.dtype)
     with jax.named_scope("moe.combine"):
         weights = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32) * top_p[..., None].astype(jnp.float32), axis=1)  # [n, E]
@@ -1108,7 +1182,7 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
         xs = _ckpt(dispatch_rows(x, order, inverse, k), "moe_xs_bf16")
     with jax.named_scope("moe.experts"):
         if experts is not None:
-            ys = _grouped_experts(xs, *experts, group_sizes)
+            ys = _grouped_experts(xs, *experts, group_sizes, cfg.swiglu_limit)
         else:
             # Results in the parameters' type (the kernel accumulates in float32):
             # nothing fuses a convert into a grouped matmul, so float32 results
@@ -1116,7 +1190,7 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
             # the backward products read float32 cotangents.
             gate = lax.ragged_dot(xs, mp["w_gate"], group_sizes, preferred_element_type=cfg.dtype)
             up = lax.ragged_dot(xs, mp["w_up"], group_sizes, preferred_element_type=cfg.dtype)
-            act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(cfg.dtype)
+            act = _swiglu_act(gate, up, cfg).astype(cfg.dtype)
             ys = lax.ragged_dot(act, mp["w_down"], group_sizes, preferred_element_type=cfg.dtype)
         if held != E:  # rows behind the last group: whatever the product left there is not a number to weigh
             ys = jnp.where((jnp.arange(n * k) < jnp.sum(group_sizes))[:, None], ys, 0)
@@ -1128,11 +1202,12 @@ def _routed_ffn(h, mp, cfg: TransformerConfig, counts: bool = False, experts=Non
     return (out, group_sizes if rows_per_expert is None else rows_per_expert) if counts else out
 
 
-def _grouped_experts(xs, stack, index, group_sizes):
-    """The experts' SwiGLU of rows sorted by expert, xs [m, d] -> [m, d], each
-    expert's matrices read where they lie in the group's stack {name:
-    [layers, E, ., .]} at the layer `index` (as `_layer_of` takes it): the
-    leading axes of a stack of periods are merged, which moves nothing."""
+def _grouped_experts(xs, stack, index, group_sizes, limit: float = 0.0):
+    """The experts' SwiGLU (clamped at `limit`, if any) of rows sorted by
+    expert, xs [m, d] -> [m, d], each expert's matrices read where they lie in
+    the group's stack {name: [layers, E, ., .]} at the layer `index` (as
+    `_layer_of` takes it): the leading axes of a stack of periods are merged,
+    which moves nothing."""
     from ..ops import grouped_matmul as gm
 
     if isinstance(index, tuple):
@@ -1140,20 +1215,36 @@ def _grouped_experts(xs, stack, index, group_sizes):
         index = jnp.ravel_multi_index(index, lead, mode="clip")
         stack = {name: w.reshape(-1, *w.shape[len(lead) :]) for name, w in stack.items()}
     plan = gm.visits(group_sizes, xs.shape[0])
-    act = gm.grouped_swiglu(xs, stack["w_gate"], stack["w_up"], index, plan)
+    act = gm.grouped_swiglu(xs, stack["w_gate"], stack["w_up"], index, plan, limit=limit)
     return gm.grouped_matmul(act, stack["w_down"], index, plan)
 
 
+@functools.lru_cache(maxsize=None)
+def _kda_cfg(cfg: TransformerConfig) -> TransformerConfig:
+    """The config as a delta-rule layer reads it: `head_dim` is ITS head's d_k
+    = d_v (`kda_head_dim`, where the full layers' d_head is another size);
+    `n_heads` its value heads, the full layers' own count. Every function of
+    the "kda" row takes this view first; the config itself where the two agree."""
+    return cfg.replace(d_head=cfg.kda_head_dim) if cfg.kda_head_dim else cfg
+
+
 def _kda_mixer(h, ap, cfg: TransformerConfig, attend):
-    """A KDA layer's mixer on its normed input h [b, s, d] -> (its output
-    [b, s, d], kept): the projections, the decay and beta (float32), then the
-    caller's `attend(q, k, v, g, beta, conv) -> (o [b, s, n_heads, head_dim]
-    float32, kept)`, which owns what a sequence keeps: q, k, v [b, s, n_heads *
-    head_dim] are the projections BEFORE the convolution, in the parameters'
-    type (what a tail stores), g [b, s, n_heads, head_dim], beta [b, s,
+    """A delta-rule layer's mixer on its normed input h [b, s, d] -> (its
+    output [b, s, d], kept): the projections, the decay and beta (float32),
+    then the caller's `attend(q, k, v, g, beta, conv) -> (o [b, s, n_heads,
+    head_dim] float32, kept)`, which owns what a sequence keeps: q, k, v [b,
+    s, heads * head_dim] are the projections BEFORE the convolution, in the
+    parameters' type (what a tail stores; q and k of `kda_key_heads` heads
+    where the config shares them), g [b, s, n_heads, head_dim], beta [b, s,
     n_heads], conv the three convolutions' weights; then the output norm a
-    head, the thin gate and `wo`."""
+    head, the gate and `wo`. `kda_decay` "channel": a decay a key channel
+    through a thin pair, beta in (0, 2), a thin gate. "head": one decay a
+    head, broadcast over its channels for the one recurrence, beta in (0, 1),
+    a full-width gate 2 sigmoid(z)."""
     from ..ops import kda
+
+    cfg = _kda_cfg(cfg)
+    by_head = cfg.kda_decay == "head"
 
     def proj(x, w):
         return jnp.einsum("bsd,dk->bsk", x, w, preferred_element_type=jnp.float32)
@@ -1161,14 +1252,20 @@ def _kda_mixer(h, ap, cfg: TransformerConfig, attend):
     with jax.named_scope("attn.qkv"):
         q, k, v = [proj(h, ap[name]).astype(cfg.dtype) for name in ("wq", "wk", "wv")]
     with jax.named_scope("kda.gates"):
-        f = proj(proj(h, ap["w_fa"]).astype(cfg.dtype), ap["w_fb"])
-        g, beta = kda.gates(f, ap["a_log"], ap["dt_bias"], proj(h, ap["w_b"]))
+        if by_head:
+            g, beta = kda.gates_a_head(proj(h, ap["w_a"]), ap["a_log"], ap["dt_bias"], proj(h, ap["w_b"]), cfg.head_dim)
+        else:
+            f = proj(proj(h, ap["w_fa"]).astype(cfg.dtype), ap["w_fb"])
+            g, beta = kda.gates(f, ap["a_log"], ap["dt_bias"], proj(h, ap["w_b"]))
     with jax.named_scope("attn.core"):
         o, kept = attend(q, k, v, g, beta, tuple(ap["conv_" + name] for name in "qkv"))
     with jax.named_scope("kda.out"):
-        gate = proj(proj(h, ap["w_ga"]).astype(cfg.dtype), ap["w_gb"]) + ap["b_g"].astype(jnp.float32)
+        if by_head:
+            gate = proj(h, ap["w_z"])
+        else:
+            gate = proj(proj(h, ap["w_ga"]).astype(cfg.dtype), ap["w_gb"]) + ap["b_g"].astype(jnp.float32)
         o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps) * ap["o_norm"]["scale"].astype(jnp.float32)
-        y = (o.reshape(*gate.shape) * jax.nn.sigmoid(gate)).astype(cfg.dtype)
+        y = (o.reshape(*gate.shape) * (2.0 * jax.nn.sigmoid(gate) if by_head else jax.nn.sigmoid(gate))).astype(cfg.dtype)
     with jax.named_scope("attn.out"):
         return _ckpt(proj(y, ap["wo"]).astype(cfg.dtype), "attn_out_bf16"), kept
 
@@ -1194,7 +1291,8 @@ def _mla_mixer(h, ap, cfg: TransformerConfig, cos, sin, attend):
     [b, s, kv_lora_rank], k_r [b, s, qk_rope_dim], w_uk, w_uv) -> (o [b, s,
     n_heads, v_head_dim], kept)`, which owns what a sequence keeps and in
     which form the up-projections are applied (expanded onto c_kv, or
-    absorbed into the query and the output); then `wo`."""
+    absorbed into the query and the output); then the output's sigmoid gate
+    from h, where the config has one (`attn_gate`), and `wo`."""
     b, s, _ = h.shape
     c, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
     interleave = cfg.rope_style == "interleaved"
@@ -1206,7 +1304,7 @@ def _mla_mixer(h, ap, cfg: TransformerConfig, cos, sin, attend):
         return _rotate(x.astype(jnp.float32), cos, sin, interleave).astype(cfg.dtype)
 
     with jax.named_scope("attn.mla.q"):
-        c_q = rms_norm(proj(h, ap["wq_a"]), ap["q_a_norm"]["scale"], cfg.norm_eps)
+        c_q = rms_norm(proj(h, ap["wq_a"]), _norm_scale(ap["q_a_norm"]["scale"], cfg), cfg.norm_eps)
         q = proj(c_q, ap["wq_b"])
         if b * s < cfg.d_model:
             # As `_block` says of `wq`: with nothing between the projection and its split into heads the compiler gives
@@ -1217,31 +1315,53 @@ def _mla_mixer(h, ap, cfg: TransformerConfig, cos, sin, attend):
         q_nope, q_rope = q[..., :nope], rotate(q[..., nope:])
     with jax.named_scope("attn.mla.kv_down"):
         kv = proj(h, ap["wkv_a"])
-        c_kv = rms_norm(kv[..., :c], ap["kv_a_norm"]["scale"], cfg.norm_eps)
+        c_kv = rms_norm(kv[..., :c], _norm_scale(ap["kv_a_norm"]["scale"], cfg), cfg.norm_eps)
         k_r = rotate(kv[:, :, None, c:])[:, :, 0]
     with jax.named_scope("attn.core"):
         o, kept = attend(q_nope, q_rope, c_kv, k_r, ap["w_uk"], ap["w_uv"])
+    if cfg.attn_gate:  # on the heads' outputs, expanded or absorbed alike: behind `w_uv`, before `wo`
+        with jax.named_scope("attn.gate"):
+            gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
+            o = (o.reshape(*gate.shape) * jax.nn.sigmoid(gate)).astype(cfg.dtype)
     with jax.named_scope("attn.mla.out"):
         out = proj(o.reshape(b, s, cfg.n_heads * cfg.v_head_dim), ap["wo"])
     return _ckpt(out, "attn_out_bf16"), kept
 
 
+def _kda_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """Channels of a delta-rule layer's q, k and v projections (`cfg`: `_kda_cfg`'s view)."""
+    keys = (cfg.kda_key_heads or cfg.n_heads) * cfg.head_dim
+    return keys, keys, cfg.n_heads * cfg.head_dim
+
+
 def _kda_inputs(cfg: TransformerConfig, q, k, v, conv, tails, n_valid=None):
     """What the recurrence reads, from the projections of ONE sequence's rows
-    in order, q, k, v [c, n_heads * head_dim]: the short convolutions from
-    `tails` (`_tail_shape`: 3 x (K - 1) x n_heads * head_dim entries), a sequence's slot of the pool
-    (q's, k's and v's rows before these; zeros at a sequence's start), SiLU,
-    the split into heads, the l2 norms of q and k -> (q, k, v [c, n_heads,
-    head_dim] float32, the tails after the first `n_valid` rows: all of them
-    if None)."""
+    in order, q, k [c, key heads * head_dim], v [c, n_heads * head_dim]: the
+    short convolutions from `tails` (`_tail_shape`: (K - 1) rows of each
+    projection's channels), a sequence's slot of the pool (q's, k's and v's
+    rows before these, one after another; zeros at a sequence's start), SiLU,
+    the split into heads, the l2 norms of q and k, and where value heads
+    share key heads, value head j's q and k from key head j // their ratio ->
+    (q, k, v [c, n_heads, head_dim] float32, the tails after the first
+    `n_valid` rows: all of them if None)."""
     from ..ops import kda
 
+    widths, rows = _kda_widths(cfg), cfg.kda_conv - 1
+    alike = len(set(widths)) == 1
     with jax.named_scope("kda.conv"):
-        tails = tails.reshape(3, cfg.kda_conv - 1, cfg.n_heads * cfg.head_dim)
+        if alike:  # three tails of one width: the flat layout below IS this view
+            tails = tails.reshape(3, cfg.kda_conv - 1, cfg.n_heads * cfg.head_dim)
+        else:
+            flat, ends = tails.reshape(-1), [rows * sum(widths[: i + 1]) for i in range(3)]
+            tails = [flat[end - rows * w : end].reshape(rows, w) for end, w in zip(ends, widths)]
         ys, new_tails = zip(*(kda.short_conv(x, w, tail, n_valid) for x, w, tail in zip((q, k, v), conv, tails)))
-        q, k, v = (y.reshape(y.shape[0], cfg.n_heads, cfg.head_dim) for y in ys)
+        q, k, v = (y.reshape(y.shape[0], -1, cfg.head_dim) for y in ys)
         q, k = kda.qk_norms(q, k)
-    return q, k, v, jnp.stack(new_tails).reshape(_tail_shape(cfg))
+        if q.shape[1] != cfg.n_heads:
+            q, k = (jnp.repeat(t, cfg.n_heads // t.shape[1], axis=1) for t in (q, k))
+    if alike:
+        return q, k, v, jnp.stack(new_tails).reshape(_tail_shape(cfg))
+    return q, k, v, jnp.concatenate([t.reshape(-1) for t in new_tails]).reshape(_tail_shape(cfg))
 
 
 def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str = "", experts=None, kind: str = "softmax"):
@@ -1362,6 +1482,7 @@ def _kda_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
     from ..ops import kda
 
     _naive_only(cfg, "kda", "_kda_whole")
+    cfg = _kda_cfg(cfg)
 
     def one(q, k, v, g, beta, conv):
         zeros = jnp.zeros(_tail_shape(cfg), q.dtype)
@@ -1670,14 +1791,16 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     the experts a token counts its picks that fall on held ones. A latent
     layer counts its low-rank pairs and up-projections and the expanded
     form's pairs (a head's keys qk_nope_dim + qk_rope_dim wide, its values
-    v_head_dim)."""
+    v_head_dim). A KDA stack counts each layer by its kind (`cache_layout`)."""
     ffn = 3 * cfg.d_model * cfg.d_ff
     if cfg.n_experts:
         # under a share, the part of a token's n_experts_per_tok picks that falls on held experts
         ffn = ffn * cfg.n_experts_per_tok * cfg.experts_held / cfg.n_experts + cfg.d_model * (cfg.n_experts + 3 * cfg.d_ff_shared)
     nd = cfg.n_dense_layers
-    n_kda = cfg.n_layers // (cfg.kda_per_period + 1) * cfg.kda_per_period
-    hd, wide = cfg.head_dim, cfg.n_heads * cfg.head_dim
+    n_kda = dict(cache_layout(cfg).kinds).get("kda", 0)
+    wide = cfg.n_heads * cfg.head_dim
+    hd = _kda_cfg(cfg).head_dim  # a delta-rule head's; `values`, `keys`: its value and key heads' channels
+    values, keys = cfg.n_heads * hd, (cfg.kda_key_heads or cfg.n_heads) * hd
     mixer = (
         (3 if cfg.attn_gate else 2) * cfg.d_model * cfg.n_heads * cfg.head_dim
         + 2 * cfg.d_model * cfg.n_kv_heads * cfg.head_dim
@@ -1686,13 +1809,14 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
     if cfg.kv_lora_rank:
         mixer = (
             cfg.d_model * (cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_dim) + cfg.q_lora_rank * wide
-            + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim) + cfg.n_heads * cfg.v_head_dim * cfg.d_model
+            + cfg.kv_lora_rank * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim) + (2 if cfg.attn_gate else 1) * cfg.n_heads * cfg.v_head_dim * cfg.d_model
         )
     n_params = (
         cfg.vocab_size * cfg.d_model
         + (cfg.n_layers - n_kda) * mixer
-        # a KDA layer: q, k, v, o; the decay's and the gate's thin pairs; beta
-        + n_kda * (4 * cfg.d_model * wide + 2 * (cfg.d_model * hd + hd * wide) + cfg.d_model * cfg.n_heads)
+        # a delta-rule layer: q, k (its key heads), v, o; beta; the decay's and the gate's thin pairs, or a head's one logit and the full-width gate
+        + n_kda * (2 * cfg.d_model * (values + keys) + cfg.d_model * cfg.n_heads
+                   + (cfg.d_model * (cfg.n_heads + values) if cfg.kda_decay == "head" else 2 * (cfg.d_model * hd + hd * values)))
         + (cfg.n_layers - nd) * ffn
         + nd * 3 * cfg.d_model * cfg.d_ff_dense
         + (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size)
@@ -1741,12 +1865,12 @@ PREFILL_CHUNK_TOKENS = 256
 
 
 def _tail_shape(cfg: TransformerConfig) -> Tuple[int, int]:
-    """The shape a sequence's convolution tails are stored in, one KDA layer's:
-    the 3 x (kda_conv - 1) rows of n_heads * head_dim as whole (16, 128) tiles
-    where they make such (a slot is then whole tiles of the pool, and a
-    prefill's write of one is no masked update of sixteen slots' tiles), else
-    as one row."""
-    total = 3 * (cfg.kda_conv - 1) * cfg.n_heads * cfg.head_dim
+    """The shape a sequence's convolution tails are stored in, one delta-rule
+    layer's (`cfg`: `_kda_cfg`'s view): the (kda_conv - 1) rows of each of q's,
+    k's and v's channels as whole (16, 128) tiles where they make such (a slot
+    is then whole tiles of the pool, and a prefill's write of one is no masked
+    update of sixteen slots' tiles), else as one row."""
+    total = (cfg.kda_conv - 1) * sum(_kda_widths(cfg))
     return (16, total // 16) if total % (16 * 128) == 0 else (1, total)
 
 
@@ -1814,6 +1938,7 @@ def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
 
 
 # ---- the four kinds of layer, each with what a served sequence keeps of it
+# (a stack may hold two of them: `stack_plan`, `cache_layout`)
 #
 # A kind's three forms are `attend` factories of one calling convention.
 # `whole(cfg, mesh, where)`: a whole sequence that keeps nothing (training,
@@ -2023,11 +2148,14 @@ def _retention_prefill_path(cfg: TransformerConfig, page_tokens: int) -> str:
     return "retention_kernel" if can_tile_prefill(rows, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) else "xla_chunk"
 
 
-# "kda": a state SLOT. Of every KDA layer a sequence keeps `s` [layers, slots,
-# n_heads, head_dim, head_dim] float32, a head's state, and `tail` [layers,
-# slots, *_tail_shape], the 3 x (kda_conv - 1) rows of the q, k and v
-# projections that the short convolution still needs (q's, k's, v's, each
-# oldest first), in the parameters' type: of a fixed size, in ONE slot for the
+# "kda": a state SLOT, of a delta-rule layer in either form (`kda_decay`: Kimi
+# Delta Attention's decay a key channel, Gated DeltaNet's decay a head, which
+# reaches the recurrence broadcast over the head's channels). Every function of
+# the row reads the config through `_kda_cfg`. Of every such layer a sequence
+# keeps `s` [layers, slots, n_heads, head_dim, head_dim] float32, a (value)
+# head's state, and `tail` [layers, slots, *_tail_shape], the (kda_conv - 1)
+# rows of the q, k and v projections that the short convolution still needs
+# (q's, k's, v's, each oldest first), in the parameters' type: of a fixed size, in ONE slot for the
 # sequence's life, which no allocator hands out: decode row i's is slot i + 1
 # (a decode row IS the engine's slot, given at admission), a prefill writes
 # the slot it is told (`slot`; the trash slot 0 from a caller that names
@@ -2037,7 +2165,7 @@ def _retention_prefill_path(cfg: TransformerConfig, page_tokens: int) -> str:
 
 
 def _kda_leaves(cfg: TransformerConfig, slots: int, page_tokens: int):
-    n_kda = cfg.n_layers
+    n_kda, cfg = cfg.n_layers, _kda_cfg(cfg)
     if slots < 2:
         raise ValueError("a KDA stack's pool has a trash slot and at least one state slot: state_slots >= 2")
     return {
@@ -2049,7 +2177,7 @@ def _kda_leaves(cfg: TransformerConfig, slots: int, page_tokens: int):
 def _kda_chunk(cfg: TransformerConfig, ctx):
     from ..ops import kda
 
-    slot, c0 = ctx["slot"], ctx["c0"]
+    cfg, slot, c0 = _kda_cfg(cfg), ctx["slot"], ctx["c0"]
 
     def attend_in(where: LayerPlace, pool):
         layer, sp, tp = where.layer, pool["s"], pool["tail"]
@@ -2074,7 +2202,7 @@ def _kda_step(cfg: TransformerConfig, ctx):
     state, else the plain expression over a gathered copy."""
     from ..ops import kda
 
-    active = ctx["active"]
+    cfg, active = _kda_cfg(cfg), ctx["active"]
 
     def attend_in(where: LayerPlace, pool):
         layer, sp, tp = where.layer, pool["s"], pool["tail"]
@@ -2099,6 +2227,7 @@ def _kda_step(cfg: TransformerConfig, ctx):
 def _kda_decode_path(cfg: TransformerConfig, page_tokens: int) -> str:
     from ..ops import kda
 
+    cfg = _kda_cfg(cfg)
     return "kda_kernel" if kda.can_tile(cfg.n_heads, cfg.head_dim, cfg.head_dim) else "xla_step"
 
 
@@ -2218,7 +2347,9 @@ class LayerKind(NamedTuple):
     """A row of `KINDS`: what a served sequence keeps of a layer of this kind
     and the layer's three forms. A new kind is a row here, its kernels and
     plain expressions under ops/, its architecture file under
-    benchmarks/archs/, and a `stack_plan` that places it."""
+    benchmarks/archs/, and a `stack_plan` that places it. One stack may hold
+    two kinds, one indexed by page and one by slot: K/V pages beside state
+    slots (Solar-Open2), latent pages beside state slots (GigaChat3.5)."""
 
     names: Tuple[str, ...]  # its cache leaves, in the pool's order
     indexed: str  # what their second axis counts: "page" (PagedKVAllocator hands them out; a block table names them) | "slot" (one a decode row)
@@ -2247,7 +2378,7 @@ class CacheLayout(NamedTuple):
     kinds: Tuple[Tuple[str, int], ...]  # (kind, layers of it), in the order the stack first has them
     names: Tuple[str, ...]  # the pool's leaves in their order: the order they ride the scans in
     indexed: Dict[str, str]  # leaf -> "page" | "slot"
-    state: bool  # a sequence keeps a recurrent state: then no full page of one prompt may serve another
+    state: bool  # a sequence keeps a recurrent state, alone or beside K/V or latent pages: then no full page of one prompt may serve another (its state at that page's border is not kept)
     kv: bool  # a page holds `page_tokens` positions, so that a block table grows with its sequence
     paged: Optional[str]  # a leaf whose pages hold positions ([layers, pages, page_tokens, ...]: its third axis says how many); None without one
 

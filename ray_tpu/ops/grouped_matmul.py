@@ -155,10 +155,12 @@ def _down(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
-def _swiglu(x, w_gate, w_up):
+def _swiglu(x, w_gate, w_up, limit: float = 0.0):
     # Each rounded to the weights' type first, as two products that hand over gate and up would.
     gate = jnp.dot(x, w_gate, preferred_element_type=jnp.float32).astype(w_gate.dtype).astype(jnp.float32)
     up = jnp.dot(x, w_up, preferred_element_type=jnp.float32).astype(w_up.dtype).astype(jnp.float32)
+    if limit:  # a clamped SwiGLU: the gate cut from above, up on both sides
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
     return jax.nn.silu(gate) * up
 
 
@@ -211,8 +213,11 @@ def grouped_matmul(xs, stack, layer, plan: Visits, *, tile_rows: int = TILE_ROWS
     return _call(_down, MATMUL_KERNEL_NAME, xs, (stack,), layer, plan, tile_rows, interpret)
 
 
-def grouped_swiglu(xs, gate_stack, up_stack, layer, plan: Visits, *, tile_rows: int = TILE_ROWS, interpret: Optional[bool] = None):
+def grouped_swiglu(xs, gate_stack, up_stack, layer, plan: Visits, *, tile_rows: int = TILE_ROWS, interpret: Optional[bool] = None,
+                   limit: float = 0.0):
     """silu(xs @ gate) * (xs @ up) with each row's own expert of layer
     `layer` of the two stacks [layers, E, K, N] -> [m, N]: grouped_matmul
-    twice and the activation, in one pass over the rows."""
-    return _call(_swiglu, SWIGLU_KERNEL_NAME, xs, (gate_stack, up_stack), layer, plan, tile_rows, interpret)
+    twice and the activation, in one pass over the rows. `limit` a, if not 0:
+    silu(min(gate, a)) * clip(up, -a, a)."""
+    product = functools.partial(_swiglu, limit=limit) if limit else _swiglu
+    return _call(product, SWIGLU_KERNEL_NAME, xs, (gate_stack, up_stack), layer, plan, tile_rows, interpret)

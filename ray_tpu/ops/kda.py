@@ -103,6 +103,16 @@ def gates(f, a_log, dt_bias, b_logit):
     return g, 2.0 * jax.nn.sigmoid(b_logit.astype(jnp.float32))
 
 
+def gates_a_head(a, a_log, dt_bias, b_logit, d_k: int):
+    """The gated-delta-rule form (Gated DeltaNet, arXiv:2412.06464): ONE decay
+    a head. a [..., heads] float32 (the decay's logit), a_log, dt_bias
+    [heads], b_logit [..., heads] -> (g [..., heads, d_k] <= 0, the head's
+    log-decay on every one of its key channels, as the recurrence's three
+    forms and the kernel take it; beta [..., heads] in (0, 1))."""
+    g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(a + dt_bias.astype(jnp.float32))
+    return jnp.broadcast_to(g[..., None], (*g.shape, d_k)), jax.nn.sigmoid(b_logit.astype(jnp.float32))
+
+
 # ------------------------------------------------ the recurrence, three forms
 
 

@@ -289,14 +289,17 @@ class PagedLM:
         (`cache`: "kv_pages", a page `page_tokens` positions of K/V, or
         "state", a page one sequence's whole recurrent state; the bytes of a
         page over all layers either way; or "state+kv_pages", both:
-        `page_bytes` of a page of K/V and `state_bytes` of what one sequence
+        `page_bytes` of a page of K/V (or of latent rows: whatever its
+        page-indexed layers keep) and `state_bytes` of what one sequence
         keeps in its state slot), which expression the decode and prefill
         executables run over the pages (`decode_attention`: "paged_kernel" or
         "xla_gather", transformer.paged_attention_path; "latent_kernel" or
         "xla_gather" over latent pages; "retention_kernel" or "xla_step" over
         a state, whose prefill chunk says so apart: `prefill_attention`,
         "retention_kernel" or "xla_chunk") and over the state slots (`decode_state`:
-        "kda_kernel" or "xla_step"): each kind's own answer, `KINDS`), and
+        "kda_kernel" or "xla_step"): each kind's own answer, `KINDS`; a stack of
+        state layers beside K/V or latent pages reports BOTH `decode_state` and
+        `decode_attention`), and
         what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
 

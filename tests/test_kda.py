@@ -110,6 +110,72 @@ def test_the_three_forms_give_the_references_recurrence(form, kind):
     assert worst(got, want) <= TOLERANCE * max(1.0, float(jnp.max(jnp.abs(want))))
 
 
+def head_wise_inputs(seed, n, kind, value_heads=4, key_heads=2):
+    """The gated-delta-rule form's inputs (GigaChat3.5's layer): ONE decay a
+    value head, beta in (0, 1), `key_heads` key heads each read by value_heads
+    / key_heads value heads (value head j reads key head j // their ratio)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k = kda.qk_norms(*(jax.random.normal(ks[i], (n, key_heads, DK)) for i in range(2)))
+    q, k = (jnp.repeat(t, value_heads // key_heads, axis=1) for t in (q, k))
+    v = jax.random.normal(ks[2], (n, value_heads, DK))
+    log_rate = {"seeded": (-6.0, 0.0), "strong_decay": (1.0, 3.0), "near_one": (-9.0, -7.0)}[kind]
+    a = jax.random.uniform(ks[3], (n, value_heads), minval=log_rate[0], maxval=log_rate[1])
+    # softplus(a + dt_bias) = exp(a) with A_log = 0: the same rates as the per-channel cases draw
+    g, beta = kda.gates_a_head(jnp.log(jnp.expm1(jnp.exp(a))), jnp.zeros((value_heads,)), jnp.zeros((value_heads,)), jax.random.normal(ks[4], (n, value_heads)), DK)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("kind", ["seeded", "strong_decay", "near_one"])
+@pytest.mark.parametrize("form", ["chunk_whole", "chunks_split_anywhere", "padded_last_chunk", "one_token_a_step"])
+def test_the_three_forms_agree_for_a_decay_a_head_and_shared_key_heads(form, kind):
+    """The same three forms with the decay ONE number a head, broadcast over
+    its channels (`gates_a_head`), beta in (0, 1) and two key heads under four
+    value heads, against archs/gigachat3_5.py's token-by-token recurrence,
+    which takes the decay a head as a scalar and never broadcasts it."""
+    from benchmarks.archs import gigachat3_5
+
+    n = 150
+    q, k, v, g, beta = head_wise_inputs(11, n, kind)
+    assert g.shape == (n, 4, DK) and bool(jnp.all(g[..., :1] == g)) and bool(jnp.all((beta > 0) & (beta < 1)))
+    zero = jnp.zeros((4, DK, DK))
+    want = gigachat3_5._delta_rule(q, k, v, g[..., 0], beta)
+    if form == "chunk_whole":
+        got, _ = kda.kda_chunk(q, k, v, g, beta, zero)
+    elif form == "one_token_a_step":
+        got, _ = kda.kda_recurrence(q, k, v, g, beta, zero)
+    else:
+        cuts = [0, 1, 70, 134, n] if form == "chunks_split_anywhere" else [0, 64, n]
+        outs, s = [], zero
+        for a, b in zip(cuts, cuts[1:]):
+            rows = [t[a:b] for t in (q, k, v, g, beta)]
+            valid = None
+            if form == "padded_last_chunk" and b == n:
+                pad = 128 - (b - a)
+                rows = [jnp.concatenate([t, 5.0 * jnp.ones((pad, *t.shape[1:]))]) for t in rows]
+                rows[3] = -jnp.abs(rows[3])
+                valid = jnp.arange(128) < b - a
+            o, s = kda.kda_chunk(*rows, s, valid)
+            outs.append(o[: b - a])
+        got = jnp.concatenate(outs)
+        np.testing.assert_allclose(s, kda.kda_recurrence(q, k, v, g, beta, zero)[1], rtol=1e-4, atol=1e-5)
+    assert worst(got, want) <= TOLERANCE * max(1.0, float(jnp.max(jnp.abs(want))))
+
+
+def test_the_decode_kernel_takes_a_decay_a_head_broadcast_over_its_channels():
+    """`kda_decode` in interpret mode under the head-wise form: 8 value heads
+    over 4 key heads of 128, the decay a head on all 128 of its channels."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    q, k = kda.qk_norms(*(jax.random.normal(ks[i], (2, 4, 128)) for i in range(2)))
+    q, k = (jnp.repeat(t, 2, axis=1) for t in (q, k))
+    v = jax.random.normal(ks[2], (2, 8, 128))
+    g, beta = kda.gates_a_head(jax.random.normal(ks[3], (2, 8)), jnp.zeros((8,)), jnp.zeros((8,)), jax.random.normal(ks[4], (2, 8)), 128)
+    pool = jax.random.normal(ks[5], (1, 3, 8, 128, 128))
+    slots, live = jnp.array([2, 1]), jnp.array([True, True])
+    o, after = kda.kda_decode(q, k, v, g, beta, pool, 0, slots, live, interpret=True)
+    want_o, want_s = kda.kda_step(q, k, v, g, beta, pool[0, slots])
+    assert worst(o, want_o) <= 1e-5 and worst(after[0, slots], want_s) <= 1e-5
+
+
 def test_a_state_not_handed_on_shows():
     q, k, v, g, beta = layer_inputs(8, 64, "near_one")
     zero = jnp.zeros((H, DK, DK))

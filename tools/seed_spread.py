@@ -1,0 +1,86 @@
+"""One prefill and a few decode steps of a serving cell under several seeds'
+weights, in one process: does the time of the program's own work follow the
+seed? (PERF.md T1: a router's selecting bias once made a prefill cost 0.86-0.91
+s by the seed, which spread a whole cell's `serve_tok_s` by 4.5-7 %; two
+chip-minutes here show such a thing before six runs of the cell do.)
+
+    chiprun -- python3 tools/seed_spread.py --workload gigachat35-serve-longanswer-batch --seeds 8 [--prompt 2048] [--steps 8]
+
+Builds the cell's PagedLM at its configuration's widths once (the benchmark's
+own mapping and weights: `benchmarks/lib/correct.init_weights`), then for each
+seed puts that seed's weights in its place, prefills one prompt of `--prompt`
+tokens into row 0 and runs `--steps` decode steps with every row live at
+`--prompt` positions (each row its own pages), on the host's clock around
+calls that end in the tokens' transfer. One JSON line a seed, then the spread
+(IQR / median) of each. Not part of any check.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", type=int, default=8)
+    ap.add_argument("--first-seed", type=int, default=2147484600)
+    ap.add_argument("--prompt", type=int, default=2048)
+    ap.add_argument("--steps", type=int, default=8)
+    args = ap.parse_args()
+
+    import jax
+    import numpy as np
+
+    from benchmarks.lib import correct, spec
+    from benchmarks.lib.stats import iqr_share
+    from benchmarks.lib.worker_train import seeded_key
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.serve.llm.model import PagedLM, PromptTokens
+
+    cell = spec.find_cell(args.workload)
+    cfg = cell.arch.model_config(cell.config)
+    eng = {k: v["value"] for k, v in cell.config["assumed"].items()}
+    init = jax.jit(lambda k: correct.init_weights(tfm, cfg, k))
+    lm = PagedLM(cfg, init(seeded_key(args.first_seed)), num_pages=eng["pool_pages"], page_tokens=eng["page_tokens"],
+                 max_slots=eng["max_slots"], max_pages_per_seq=eng["max_pages_per_seq"])
+    B, T, P = lm.max_slots, lm.page_tokens, lm.max_pages_per_seq
+    n_pages = -(-(args.prompt + args.steps) // T)
+    tables = [[1 + row * P + j for j in range(n_pages)] for row in range(B)]
+    rng = np.random.default_rng(0)
+    rows = []
+    for i in range(args.seeds):
+        seed = args.first_seed + i
+        if i:
+            lm.params = None  # the last seed's weights go before this one's come: two sets do not fit
+            lm.params = init(seeded_key(seed))
+        prompt = PromptTokens([int(t) for t in rng.integers(1, lm.vocab, args.prompt)])
+        prompt.slot = 0
+        lm.prefill(prompt, tables[0][: -(-args.prompt // T)], 0)  # the first call of a shape compiles
+        t0 = time.monotonic()
+        lm.prefill(prompt, tables[0][: -(-args.prompt // T)], 0)
+        prefill_s = time.monotonic() - t0
+        tokens = [int(t) for t in rng.integers(1, lm.vocab, B)]
+        lm.decode(tokens, [args.prompt] * B, tables)
+        steps = []
+        for j in range(args.steps):
+            t0 = time.monotonic()
+            tokens = list(lm.decode(tokens, [args.prompt + 1 + j] * B, tables))
+            steps.append(time.monotonic() - t0)
+        rows.append({"seed": seed, "prefill_ms": 1e3 * prefill_s, "decode_step_ms": 1e3 * statistics.median(steps)})
+        print("seed_spread: " + json.dumps(rows[-1]), flush=True)
+    out = {name: {"median": statistics.median(r[name] for r in rows), "spread": iqr_share([r[name] for r in rows])} for name in ("prefill_ms", "decode_step_ms")}
+    print("seed_spread: " + json.dumps({"workload": args.workload, "prompt": args.prompt, "device": jax.devices()[0].device_kind, **out}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
