@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -505,8 +506,8 @@ SCOPES = {
     "moe.combine": "rows back to token order under the router's weights",
     "moe.shared": "the shared expert",
     "residual": "a block's residual adds",
-    "head": "the logits' product",
-    "loss": "log-softmax, the target's gather, the mean (train/zero.py: its mean over the devices)",
+    "head": "the logits' product (training, `head_loss`: a chunk's, and the two backward products)",
+    "loss": "`head_loss`'s passes over a chunk's logits and its chunk scan, the mean's weights, the cotangent (train/zero.py: the mean over the devices)",
     "optimizer": "`tx.update` and `optax.apply_updates` of the unsharded step",
     "zero.grad_scatter": "train/zero.py: a gradient's reduce-scatter along the leaf's cut dimension, the shard's flattening",
     "zero.update": "train/zero.py: the parameters' shards, `tx.update`",
@@ -1587,10 +1588,7 @@ def _embed(params: PyTree, tokens, cfg: TransformerConfig):
 def _logits(params: PyTree, x):
     """Final-norm hidden states [..., d] -> logits [..., vocab] float32."""
     with jax.named_scope("head"):
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embed"]["embedding"].T
-        return jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
+        return jnp.einsum("...d,dv->...v", x, _head(params), preferred_element_type=jnp.float32)
 
 
 def _route_stats(x, mp, cfg: TransformerConfig):
@@ -1657,6 +1655,110 @@ def forward(
     return _logits(params, forward_hidden(params, tokens, cfg, mesh))
 
 
+def _head(params: PyTree):
+    """The logits' weights [d, vocab]: `lm_head`, or a tied embedding transposed."""
+    head = params.get("lm_head")
+    return params["embed"]["embedding"].T if head is None else head
+
+
+# Float32 logits that `head_loss` holds at one time: it walks the rows in the
+# largest chunks whose [rows, vocab] float32 stay under this. A chunk's product
+# and its three passes (max, sum of exp, dlogits) are bound by their bytes, not
+# by the chunks' count (the function alone on the chip: 256 MiB, 512 MiB and 1
+# GiB read the same to 0.03 ms of 59), so the budget is what the step's peak can
+# spare (Mistral's 3 x 4096 rows hold 1.5 GiB whole, OLMoE's 4 x 4096 3.1), and
+# few chunks: the backward products read up to four laid-out chunks where they
+# were written; OLMoE's eight at 512 MiB were first copied into one buffer, 4.5
+# ms a step (PERF.md, PR 60). 1 GiB: two chunks a Mistral step, four OLMoE's.
+HEAD_LOSS_CHUNK_BYTES = 1 << 30
+# Up to this many chunks are laid out one after another; more of them become a
+# loop. Behind a loop the TPU compiler's scheduler put the head's gradient (and
+# the optimizer's update it fuses with it) AFTER the layers' backward, with
+# dlogits alive all through it (or, the two backward products tied by a barrier,
+# the head's gradient): 0.64 (0.25) GiB more in the plan of Mistral's step,
+# whose peak lies there, and the compiler then recomputed MLP products: that
+# step read 686 ms where the parent's reads 662 (PERF.md, PR 60). Laid out, both
+# backward products are scheduled where the plain expression's stood, before
+# the layers' backward.
+HEAD_LOSS_CHUNKS_LAID_OUT = 16
+
+
+def _loss_chunk_rows(rows: int, vocab: int) -> int:
+    """The largest divisor of `rows` whose float32 logits fit the budget (1 where none does)."""
+    most = max(1, HEAD_LOSS_CHUNK_BYTES // (4 * vocab))
+    return max(c for c in range(1, min(rows, most) + 1) if rows % c == 0)
+
+
+def _head_loss_rows(x, head, targets, weights):
+    """One chunk: rows [..., d] -> (sum of weights x nll, nll [...], dlogits [..., vocab] in the head's dtype)."""
+    with jax.named_scope("head"):
+        logits = jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
+    with jax.named_scope("loss"):
+        shifted = logits - jnp.max(logits, axis=-1, keepdims=True)
+        e = jnp.exp(shifted)
+        total = jnp.sum(e, axis=-1, keepdims=True)
+        hit = lax.broadcasted_iota(targets.dtype, logits.shape, logits.ndim - 1) == targets[..., None]
+        nll = jnp.log(total[..., 0]) - jnp.sum(jnp.where(hit, shifted, 0.0), axis=-1)
+        # d loss / d logits, rounded where it is made: the MXU rounds this operand of both backward products to the
+        # parameters' dtype anyway (DEFAULT precision), and float32 of it is twice the bytes for them to fetch
+        dlogits = ((e / total - hit.astype(jnp.float32)) * weights[..., None]).astype(head.dtype)
+        return jnp.sum(nll * weights), nll, dlogits
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def head_loss(x, head, targets, weights, chunked=True):
+    """sum over rows of weights x cross-entropy(x @ head, targets), float32.
+
+    x [..., d] and head [d, vocab] in the parameters' dtype, targets [...]
+    int, weights [...] float32 (a mean's: mask / count). Logits, log-sum-exp
+    and the sum are float32 as `log_softmax(_logits(...))` makes them, but the
+    logits live a chunk of rows at a time (`HEAD_LOSS_CHUNK_BYTES`), and the
+    derivative is written by hand: d loss / d logits is formed once, in the
+    forward pass, and kept in the head's dtype; the backward pass is its two
+    products with x and with the head, parameters' dtype x parameters' dtype
+    -> float32, and the loss's cotangent on their results. Three products a
+    step, none repeated. `chunked` False: one chunk whatever its size, for a
+    caller whose rows are sharded over devices (a chunk would be one device's)."""
+    return _head_loss_fwd(x, head, targets, weights, chunked)[0]
+
+
+def _head_loss_fwd(x, head, targets, weights, chunked):
+    rows, vocab = math.prod(targets.shape), head.shape[1]
+    chunk = _loss_chunk_rows(rows, vocab) if chunked else rows
+    if chunk == rows:  # as it is shaped: nothing to reshape
+        loss, nll, dlogits = _head_loss_rows(x, head, targets, weights)
+    else:
+
+        def step(loss, chunk_of):
+            # a chunk starts when the one before has written its dlogits: laid out, the compiler would else fuse the
+            # chunks' passes side by side and hold every chunk's float32 logits at once
+            loss, x_c = lax.optimization_barrier((loss, chunk_of[0]))
+            part, nll, dlogits = _head_loss_rows(x_c, head, *chunk_of[1:])
+            loss, dlogits = lax.optimization_barrier((loss + part, dlogits))
+            return loss, (nll, dlogits)
+
+        n = rows // chunk
+        in_chunks = tuple(a.reshape(n, chunk, *a.shape[targets.ndim :]) for a in (x, targets, weights))
+        with jax.named_scope("loss"):
+            loss, (nll, dlogits) = lax.scan(step, jnp.zeros((), jnp.float32), in_chunks, unroll=n <= HEAD_LOSS_CHUNKS_LAID_OUT)
+        nll = nll.reshape(targets.shape)  # dlogits stay [chunks, rows, vocab]: the backward products read the chunks where they were written
+    return loss, (x, head, targets, nll, dlogits)
+
+
+def _head_loss_bwd(_chunked, saved, g):
+    x, head, targets, nll, dlogits = saved
+    with jax.named_scope("head"):
+        rows = x.reshape(*dlogits.shape[:-1], x.shape[-1])
+        dx = jnp.einsum("...v,dv->...d", dlogits, head, preferred_element_type=jnp.float32)
+        dhead = jnp.einsum("...d,...v->dv", rows, dlogits, preferred_element_type=jnp.float32)
+    with jax.named_scope("loss"):
+        dx, dhead = (g * dx).astype(x.dtype).reshape(x.shape), (g * dhead).astype(head.dtype)
+        return dx, dhead, np.zeros(targets.shape, jax.dtypes.float0), g * nll
+
+
+head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
 def next_token_loss(
     params: PyTree,
     tokens: jax.Array,
@@ -1669,18 +1771,25 @@ def next_token_loss(
 
     Runs the forward at full sequence length and masks the final position
     (rather than slicing to seq-1) so the sequence dim stays divisible by
-    the "seq" mesh axis under sequence parallelism."""
-    logits = forward(params, tokens, cfg, mesh)
+    the "seq" mesh axis under sequence parallelism.
+
+    The head and the loss are one function with its own derivative
+    (`head_loss`): float32 are the logits, the log-sum-exp, the picked logit,
+    the mean and every product's accumulation; the parameters' dtype
+    (bfloat16) are the products' operands, d loss / d logits among them. No
+    [batch, seq, vocab] float32 exists: the logits are made and used a chunk
+    of rows at a time. `forward()` still returns whole float32 logits to
+    whoever wants them."""
+    x = forward_hidden(params, tokens, cfg, mesh)
     with jax.named_scope("loss"):
-        targets = jnp.roll(tokens, -1, axis=1)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         s = tokens.shape[1]
-        valid = jnp.arange(s)[None, :] < s - 1  # last position has no target
-        m = jnp.broadcast_to(valid, nll.shape).astype(nll.dtype)
+        targets = jnp.roll(tokens, -1, axis=1)
+        m = jnp.broadcast_to(jnp.arange(s)[None, :] < s - 1, tokens.shape).astype(jnp.float32)  # last position has no target
         if mask is not None:
-            m = m * jnp.roll(mask, -1, axis=1).astype(nll.dtype)
-        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+            m = m * jnp.roll(mask, -1, axis=1).astype(jnp.float32)
+        weights = m / jnp.maximum(jnp.sum(m), 1.0)
+    # under a mesh that shards batch or sequence the rows stay as they lie: one chunk
+    return head_loss(x, _head(params), targets, weights, mesh is None or mesh.size == 1)
 
 
 @partial(jax.jit, static_argnames=("cfg",))

@@ -403,7 +403,9 @@ def test_the_routed_ffn_of_olmoe_lowers_to_the_ops_it_had():
 # Read on the parent commit (PR 36's tree) with this file's own functions. PR 55 moved two counts, both inside the flash kernels the step inlines
 # when interpreted (a body for the blocks below the diagonal and one for those on it, each with its products and exps: dot_general 42 -> 51,
 # exponential 9 -> 13); with attn_impl="naive" the step reads 41 and 7 at PR 55 and at its parent alike: the routed FFN's own ops are unmoved.
-PARENT_OLMOE_OPS = {'stablehlo.dot_general': 51, 'stablehlo.gather': 18, 'stablehlo.scatter': 6, 'stablehlo.sort': 2, 'chlo.top_k': 1, 'stablehlo.exponential': 13, 'stablehlo.logistic': 0}
+# PR 60 took the LOSS's gather and its transpose's scatter out (`head_loss` picks the target's logit with a compare; each op's name stands twice in
+# the text, once in its attribute: gather 18 -> 16, scatter 6 -> 4); the three products of the head and every op of the routed FFN are what they were.
+PARENT_OLMOE_OPS = {'stablehlo.dot_general': 51, 'stablehlo.gather': 16, 'stablehlo.scatter': 4, 'stablehlo.sort': 2, 'chlo.top_k': 1, 'stablehlo.exponential': 13, 'stablehlo.logistic': 0}
 PARENT_TREES = {'dense': {"['blocks']['attn']['wk']": ((2, 64, 32), 'bfloat16', 64.693),
            "['blocks']['attn']['wo']": ((2, 64, 64), 'bfloat16', 132.358),
            "['blocks']['attn']['wq']": ((2, 64, 64), 'bfloat16', 127.155),

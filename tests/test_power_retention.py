@@ -508,13 +508,15 @@ MOVED = {
     # decode_stats and params digest holds, and so do Trinity's (naive, windows), Brumby's and Solar-Open2's forwards.
     ("dsllm7b-serve-chat-steady", "forward"): ("06d1b3f2a254b9fb", "PR 55: the causal flash kernels (ops/flash_attention.py) walk a diagonal block in sub-tiles, mask only there, name the block in VMEM on a skipped step and keep row statistics, lse and delta a row's value in every lane"),
     ("mistral7b-train-seq4k-1chip", "forward"): ("cede48bbb077cf14", "as above"),
-    ("mistral7b-train-seq4k-1chip", "grad"): ("44d8f3d8e3cadcac", "as above, and its two backward kernels"),
-    # PR 57, by design and this program alone (every other digest of every cell holds): train/zero.py cuts a leaf along a dimension.
-    ("mistral7b-train-seq4k-1chip", "zero_step"): ("e52ebc15bf0b5625", "PR 57: a chip's ZeRO shard is a slice along the leaf's last inner dimension divisible by n, so the gradient goes to "
-                                                   "reduce_scatter in its own shape, and the UPDATES come back from all_gather in the leaf's shape and are added to the whole parameters; the parent's "
-                                                   "was 5552f02e7829f78d (PR 55, as grad: the flash kernels in the four-chip cell's program), PARENT's the one before that"),
     ("olmoe-train-seq4k-1chip", "forward"): ("244f65fe8fddd677", "as above"),
-    ("olmoe-train-seq4k-1chip", "grad"): ("6adc8fe8eff1fe69", "as above, and its two backward kernels"),
+    # PR 60, by design and the three training programs alone (every params, decode, prefill, decode_stats and forward digest of every cell holds):
+    # `next_token_loss` no longer differentiates through `forward()`'s float32 [batch, seq, vocab] logits; head and loss are `transformer.head_loss`,
+    # a custom_vjp that forms dlogits once in the parameters' dtype and whose two backward products take that dtype on both sides.
+    ("mistral7b-train-seq4k-1chip", "grad"): ("d7e149197e7d0038", "PR 60: head and loss as one differentiated function; before it 44d8f3d8e3cadcac (PR 55: the causal flash kernels and their two backward kernels)"),
+    ("mistral7b-train-seq4k-1chip", "zero_step"): ("6e5af522eb057c88", "PR 60, as grad: the four-chip cell's program calls the same loss inside its shard_map; before it e52ebc15bf0b5625 (PR 57: a chip's ZeRO shard is a "
+                                                   "slice along the leaf's last inner dimension divisible by n, the gradient reduce-scattered in its own shape, the UPDATES gathered in the leaf's shape), "
+                                                   "before that 5552f02e7829f78d (PR 55), PARENT's the one before that"),
+    ("olmoe-train-seq4k-1chip", "grad"): ("db0b6aae5e506878", "PR 60, as Mistral's grad; before it 6adc8fe8eff1fe69 (PR 55)"),
 }
 
 

@@ -237,3 +237,16 @@ def test_the_tools_rows_sum_to_the_busy_time(by, capsys):
         for row in ("| ffn | recompute |", "| attn.core | backward |", "| optimizer | update |", "| loss | forward |"):
             assert row in out, row
         assert "flash_attention_dkv" in out  # the kernels by their names, among a row's largest ops
+
+
+def test_the_chunked_head_and_loss_lie_under_their_scopes(monkeypatch):
+    """`head_loss` over several chunks (PR 60): the scan's slices and the hand-written backward read `head` / `loss`, in both phases."""
+    cfg = TRAINED["dense"]
+    monkeypatch.setattr(tfm, "HEAD_LOSS_CHUNK_BYTES", 4 * cfg.vocab_size * 128)
+    params = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    grad = jax.jit(jax.grad(lambda p, t: tfm.next_token_loss(p, t, cfg)))
+    jaxpr = jax.make_jaxpr(grad)(params, TOKENS)
+    assert any(in_scan and primitive == "dot_general" and "(loss)/scan/head/" in path for in_scan, primitive, path in leaves(jaxpr.jaxpr))
+    assert set(unscoped(jaxpr)) <= {"add_any"}
+    ops = collections.Counter((xm.scope(n, tfm.SCOPES), xm.phase(n)) for n in re.findall(r'op_name="([^"]*)"', grad.lower(params, TOKENS).compile().as_text()))
+    assert all(ops[scope, phase] for scope in ("head", "loss") for phase in ("forward", "backward")), ops
