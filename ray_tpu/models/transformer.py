@@ -188,10 +188,33 @@ class TransformerConfig:
     kda_head_dim: int = 0
     norm_gate: float = 0.0
     swiglu_limit: float = 0.0
+    # MiMo-V2 family, each off by default. window_kv_heads: the window layers
+    # (`windows` > 0) have that many K/V heads where the others have
+    # n_kv_heads, and a served sequence keeps of each of them only a RING of
+    # its window's positions in a state slot (the "window" row of `KINDS`)
+    # beside the K/V pages of the layers that see everything. The stack is then
+    # ONE leading dense layer without a window and whole periods of alike window
+    # layers that end on a layer without one (`stack_plan`). At 0 a window
+    # layer is a softmax layer whose window rides the scan and whose every
+    # position is paged (Trinity). window_rope_theta: rope's base on the window
+    # layers (0: rope_theta). window_sink: a learned logit a query head
+    # (`sink`, float32) in the denominator of a window layer's softmax:
+    # p_ij = exp(s_ij) / (exp(sink) + sum_j exp(s_ij)). value_scale: v times
+    # it, behind its projection. v_head_dim (above) then also says how wide a
+    # softmax or window head's values are where its keys are d_head.
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_sink: bool = False
+    value_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def value_dim(self) -> int:
+        """A softmax or window head's values: as wide as its keys unless v_head_dim says otherwise."""
+        return self.v_head_dim or self.head_dim
 
     @property
     def experts_held(self) -> int:
@@ -278,6 +301,17 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         raise ValueError(f"rope_scaling {cfg.rope_scaling!r}: ('yarn', factor, original context, beta_fast, beta_slow, mscale, mscale_all_dim) is computed")
     if cfg.n_group > 1 and (cfg.router_score != "sigmoid" or E % cfg.n_group or not 0 < cfg.topk_group <= cfg.n_group or E // cfg.n_group < 2):
         raise ValueError("group-limited selection: a sigmoid router whose experts divide into n_group groups of at least two, topk_group of them kept")
+    if cfg.window_kv_heads:
+        if _ring_period(cfg) is None:
+            raise ValueError(
+                f"windows {cfg.windows!r} under window_kv_heads: a stack with window rings is global first (ONE leading dense layer "
+                "without a window: n_dense_layers 1), then whole periods of alike window layers that end on a global layer"
+            )
+        if (cfg.retention_degree or per or cfg.kv_lora_rank or cfg.qk_norm or cfg.attn_gate or cfg.rope_layers or cfg.parallel_block
+                or nh % cfg.window_kv_heads or nh % nkv):
+            raise ValueError("window rings stand beside plain softmax layers: no retention, delta-rule or latent layer, q/k-norm, gate, rope switch or parallel block; both K/V head counts divide the heads")
+    elif cfg.window_rope_theta or cfg.window_sink or cfg.value_scale != 1.0 or (cfg.v_head_dim and not cfg.kv_lora_rank):
+        raise ValueError("window_rope_theta, window_sink, value_scale and a softmax head's v_head_dim are computed in a stack with window rings (window_kv_heads) alone")
     k = iter(jax.random.split(key, 16))
     # What this model has over the llama and OLMoE blocks draws from a stream
     # of its own: theirs give the same weights for a key as before.
@@ -348,13 +382,14 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         """A stack of alike layers of `kind` (a row of `KINDS`); k2: the stream of what a family has over the llama and OLMoE blocks."""
         L = (L,) if isinstance(L, int) else L
         kda = kind == "kda"
+        kvh, vd = cfg.window_kv_heads if kind == "window" else nkv, cfg.value_dim  # of a softmax, retention or window layer's own projections
         out = {
             "attn_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
             "attn": kda_attn(k, L) if kda else latent_attn(k, L) if kind == "latent" else {
                 "wq": dense(next(k), (*L, d, nh * hd), d),
-                "wk": dense(next(k), (*L, d, nkv * hd), d),
-                "wv": dense(next(k), (*L, d, nkv * hd), d),
-                "wo": dense(next(k), (*L, nh * hd, d), nh * hd),
+                "wk": dense(next(k), (*L, d, kvh * hd), d),
+                "wv": dense(next(k), (*L, d, kvh * vd), d),
+                "wo": dense(next(k), (*L, nh * vd, d), nh * vd),
             },
             "mlp_norm": {"scale": jnp.ones((*L, d), cfg.dtype)},
             "mlp": (
@@ -381,6 +416,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
             out["attn"]["wg"] = dense(next(k2), (*L, d, nh * (cfg.v_head_dim if kind == "latent" else hd)), d)
         if cfg.retention_degree:
             out["attn"]["wg"] = dense(next(k2), (*L, d, nkv), d)
+        if kind == "window" and cfg.window_sink:
+            # A trained sink is non-zero; zeros would hide the term from every check: exp(0) = 1 beside some tens of keys.
+            out["attn"]["sink"] = jax.random.normal(next(k2), (*L, nh), jnp.float32)
         if cfg.post_norms:
             out["post_attn_norm"] = {"scale": jnp.ones((*L, d), cfg.dtype)}
             out["post_mlp_norm"] = {"scale": jnp.ones((*L, d), cfg.dtype)}
@@ -400,7 +438,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         return out
 
     kinds = {m.tree: m.kind for _, members in stack_plan(cfg) for m in members}  # each stacked tree's kind of layer
-    periods = (cfg.n_layers - nd) // (per + 1)
+    ring_per = _ring_period(cfg) or 0
+    periods = (cfg.n_layers - nd) // ((per or ring_per) + 1)
     params = {
         "embed": {"embedding": dense(next(k), (v, d), d)},
         "blocks": blocks(k, k2, periods, bool(E), cfg.d_ff, kinds["blocks"]),
@@ -416,6 +455,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> PyTree:
         params["dense_blocks"] = blocks(*(streams(17, 19) if kinds["dense_blocks"] == "kda" else (k2, k2)), nd, False, cfg.d_ff_dense, kinds["dense_blocks"])
     if per:
         params["kda_blocks"] = blocks(*streams(11, 13), (periods, per), bool(E), cfg.d_ff, "kda")
+    if ring_per:
+        params["window_blocks"] = blocks(*streams(23, 29), (periods, ring_per), bool(E), cfg.d_ff, "window")
     if cfg.norm_gate:  # c * sigmoid(w): w = 0 where a plain scale is 1 (a delta-rule layer's output norm keeps its plain scale)
         def gated(path) -> bool:
             return "norm" in path and "o_norm" not in path
@@ -593,6 +634,11 @@ def _cos_sin(cfg: TransformerConfig, angles):
     return (cos, sin) if m == 1.0 else (cos * m, sin * m)
 
 
+def _window_rope_cfg(cfg: TransformerConfig) -> TransformerConfig:
+    """The config as rope on a window layer reads it: `window_rope_theta` its base."""
+    return cfg.replace(rope_theta=cfg.window_rope_theta)
+
+
 def rope_tables(cfg: TransformerConfig, seq_len: int):
     with jax.named_scope("attn.rope"):
         freqs = _rope_freqs(cfg)
@@ -673,7 +719,7 @@ def _per_layer(cfg: TransformerConfig, first: int, n: int):
     n), the values that ride a group's scan beside its stacked weights; None
     for what the config does not vary (nothing rides, the body is as before)."""
     windows = rope = None
-    if cfg.windows:
+    if cfg.windows and not cfg.window_kv_heads:  # a window ring's size is static: nothing of it rides
         with jax.named_scope("attn.window"):
             w = jnp.asarray(cfg.windows[first:first + n], jnp.int32)
             windows = jnp.where(w > 0, w, NO_WINDOW)
@@ -722,11 +768,19 @@ def stack_plan(cfg: TransformerConfig) -> Tuple[Tuple[int, Tuple[StackMember, ..
     layers a segment of their own); a periodic pattern is one segment whose
     members are a period: a full layer ("softmax", or "latent" where the
     config has latent attention), then `kda_per_period` delta-rule layers,
-    behind leading dense layers of whichever kind `full_layers` gives them.
+    behind leading dense layers of whichever kind `full_layers` gives them;
+    or (`window_kv_heads`) a period of window layers that keep a ring, then
+    one softmax layer, behind ONE leading dense softmax layer.
     `_walk_stack` walks it, `cache_layout` reads what a served sequence keeps
     from it; a new pattern is a new return value here."""
     per, nd = cfg.kda_per_period, cfg.n_dense_layers
     kind = "retention" if cfg.retention_degree else "latent" if cfg.kv_lora_rank else "softmax"
+    if cfg.window_kv_heads:
+        # The leading dense global layer, then periods of window layers that end on a global one: the global layers
+        # are the "softmax" row's (the dense one its first), the others the "window" row's.
+        ring_per = _ring_period(cfg)
+        return ((1, (StackMember("softmax", "dense_blocks", 1, 0),)),
+                ((cfg.n_layers - 1) // (ring_per + 1), (StackMember("window", "window_blocks", ring_per, 0), StackMember("softmax", "blocks", 1, 1))))
     if per:
         # The dense layers are of the kind the published list gives them; `first` counts the layers of a kind before a member's.
         dense_kind = kind if 0 in cfg.full_layers else "kda"
@@ -735,6 +789,18 @@ def stack_plan(cfg: TransformerConfig) -> Tuple[Tuple[int, Tuple[StackMember, ..
         return (*dense, ((cfg.n_layers - nd) // (per + 1), (StackMember(kind, "blocks", 1, full_before), StackMember("kda", "kda_blocks", per, kda_before))))
     dense = ((nd, (StackMember(kind, "dense_blocks", 1, 0),)),) if nd else ()
     return (*dense, (cfg.n_layers - nd, (StackMember(kind, "blocks", 1, nd),)))
+
+
+def _ring_period(cfg: TransformerConfig) -> Optional[int]:
+    """The window layers of one period of a stack with window rings
+    (`window_kv_heads`): `windows` is 0 for the leading dense layer, then whole
+    periods of that many alike windows and one 0. None for any other list."""
+    w = cfg.windows
+    if not cfg.window_kv_heads or cfg.n_dense_layers != 1 or len(w) != cfg.n_layers or len(w) < 3 or w[0] or not w[1]:
+        return None
+    per = next((i for i in range(1, len(w)) if not w[i]), len(w)) - 1
+    period = (w[1],) * per + (0,)
+    return per if (len(w) - 1) % (per + 1) == 0 and tuple(w[1:]) == period * ((len(w) - 1) // (per + 1)) else None
 
 
 class LayerPlace(NamedTuple):
@@ -793,17 +859,28 @@ def _experts_in_place(blocks: PyTree):
     return riding, {name: mlp[name] for name in EXPERT_WEIGHTS}
 
 
-def _window_attention(q, k, v, window):
+def _sink_softmax(scores, sink):
+    """softmax over the last axis of scores [.., h, q, k] float32 with one more
+    term in the denominator, exp(sink[h]), that is no key: the probabilities of
+    the keys alone (they sum to less than 1)."""
+    sink = sink.astype(jnp.float32)[:, None, None]
+    top = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+    e = jnp.exp(scores - top)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - top))
+
+
+def _window_attention(q, k, v, window, sink=None):
     """Causal attention of a whole sequence in which query i sees key j iff
     0 <= i - j < window (a traced scalar): the masked plain expression,
-    float32 softmax. q [b, s, h, d], k / v [b, s, kv, d]."""
+    float32 softmax, under `sink` [h] with a sink logit a head in its
+    denominator. q [b, s, h, d], k / v [b, s, kv, d] (v of a width of its own)."""
     rep = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(t, rep, axis=2) if rep > 1 else t for t in (k, v))
     s = q.shape[1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) / math.sqrt(q.shape[-1])
     back = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
     scores = jnp.where((back >= 0) & (back < window), scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    probs = (jax.nn.softmax(scores, axis=-1) if sink is None else _sink_softmax(scores, sink)).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32).astype(q.dtype)
 
 
@@ -1008,11 +1085,13 @@ def _qkv(h, ap, cfg: TransformerConfig, split: bool, gated: bool = False):
                 norm = partial(_rms_norm_per_head, head_dim=cfg.head_dim) if cfg.qk_norm_per_head else rms_norm
                 q = norm(q, ap["q_norm"]["scale"], cfg.norm_eps)
                 k = norm(k, ap["k_norm"]["scale"], cfg.norm_eps)
-        view = (lambda t: t.reshape(*t.shape[:2], -1, cfg.head_dim)) if split else (lambda t: t)
+        if cfg.value_scale != 1.0:
+            v = v * cfg.value_scale
+        view = (lambda t, width: t.reshape(*t.shape[:2], -1, width)) if split else (lambda t, width: t)
         gate = None
         if gated:
             gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
-        return view(q).astype(cfg.dtype), view(k).astype(cfg.dtype), view(v).astype(cfg.dtype), gate
+        return view(q, cfg.head_dim).astype(cfg.dtype), view(k, cfg.head_dim).astype(cfg.dtype), view(v, cfg.value_dim).astype(cfg.dtype), gate
 
 
 def _ffn(h, mp, cfg: TransformerConfig, experts=None):
@@ -1372,7 +1451,8 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
     and the caller passes that, one of the three forms of the layer's `kind`
     (a row of `KINDS`): `attend(q, k, v) -> (o [b, s, n_heads, head_dim],
     kept)`, with q and k after rope; a "retention" layer's `attend(q, k, v,
-    log_g)`, log_g [b, s, n_kv_heads] float32 the gate's log-sigmoid; a "kda"
+    log_g)`, log_g [b, s, n_kv_heads] float32 the gate's log-sigmoid; a "window" layer's `attend(q, k, v, sink)` where it has
+    sink logits [n_heads] (its k and v of `window_kv_heads` heads; v `value_dim` wide, there and in a softmax layer); a "kda"
     layer's as `_kda_mixer` calls it, a "latent" layer's as `_mla_mixer` does. `kept` is whatever the caller wants back
     (the cache leaves it wrote into; None in training). Returns (out, kept),
     and as a third what the router did with this layer's input if `stats` is
@@ -1408,9 +1488,11 @@ def _block(x, layer_params, cfg: TransformerConfig, cos, sin, attend, stats: str
         k = _ckpt(apply_rope(k, cos, sin, cfg) if rotates else k, "k_bf16")
     with jax.named_scope("attn.core"):
         v = _ckpt(v, "v_bf16")
-        heads = [t.reshape(b, s, -1, cfg.head_dim) for t in (q, k, v)]
-        o, kept = attend(*heads) if gate is None else attend(*heads, jax.nn.log_sigmoid(gate))
-        o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
+        heads = [t.reshape(b, s, -1, width) for t, width in ((q, cfg.head_dim), (k, cfg.head_dim), (v, cfg.value_dim))]
+        # beside q, k, v: a retention layer's log-gate, a window layer's sink logits
+        more = (ap["sink"],) if "sink" in ap else () if gate is None else (jax.nn.log_sigmoid(gate),)
+        o, kept = attend(*heads, *more)
+        o = o.reshape(b, s, cfg.n_heads * cfg.value_dim)
     if cfg.attn_gate:
         with jax.named_scope("attn.gate"):
             gate = jnp.einsum("bsd,dk->bsk", h, ap["wg"], preferred_element_type=jnp.float32)
@@ -1470,7 +1552,20 @@ def _naive_only(cfg: TransformerConfig, kind: str, expression: str):
 
 
 def _softmax_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
+    if cfg.value_dim != cfg.head_dim:  # the flash, ring and ulysses kernels take one head size
+        _naive_only(cfg, "softmax layers whose values are narrower than their keys, and window", "attention_reference")
     return lambda q, k, v: (_attention(q, k, v, cfg, mesh, where.window), None)
+
+
+def _window_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
+    """A window layer of a stack with rings over whole sequences: the masked plain expression, its window static."""
+    _naive_only(cfg, "window", "_window_attention")
+
+    def attend(q, k, v, sink=None):
+        with jax.named_scope("attn.window"):
+            return _window_attention(q, k, v, max(cfg.windows), sink), None
+
+    return attend
 
 
 def _retention_whole(cfg: TransformerConfig, mesh: Optional[Mesh], where: LayerPlace):
@@ -1612,9 +1707,11 @@ def forward_hidden(
     cos, sin = rope_tables(cfg, s)
     x = _embed(params, tokens, cfg)
 
+    rope = {"window": rope_tables(_window_rope_cfg(cfg), s)} if cfg.window_rope_theta else {}
+
     def layer(kind, x, where, layer_params):
         attend = KINDS[kind].whole(cfg, mesh, where)
-        return _block(x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), attend, kind=kind)[0]
+        return _block(x, layer_params, cfg, *_rope_switch(*rope.get(kind, (cos, sin)), where.rope_on), attend, kind=kind)[0]
 
     if cfg.remat:
         if cfg.remat_policy == "dots":
@@ -1983,58 +2080,62 @@ def _tail_shape(cfg: TransformerConfig) -> Tuple[int, int]:
     return (16, total // 16) if total % (16 * 128) == 0 else (1, total)
 
 
-def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int, window=None):
+def paged_prefill_attention_gather(q, kp, vp, block_table, start, n_kv_heads: int, window=None, scale: Optional[float] = None):
     """The plain XLA expression of prefill's chunk attention: gathers the
     WHOLE block table [P] out of one layer's pages kp / vp
-    [pages, page_tokens, n_kv_heads * head_dim], casts it to float32 and
+    [pages, page_tokens, n_kv_heads * head_dim] (vp's heads of a width of their
+    own), casts it to float32 and
     softmaxes each row of q [C, n_heads, head_dim] (row i is position
     start + i) over the `P * T`-wide row under the causal mask, and under
-    `window` (a scalar) only the last `window` positions of it. The parity
+    `window` (a scalar) only the last `window` positions of it; `scale`: the
+    scores' factor where it is not head_dim^-0.5 (heads padded with zeros to
+    whole lane tiles: `kv_page_widths`). The parity
     reference of ops/paged_attention.py's paged_prefill_attention and the
     path for shapes that kernel cannot tile (the tiny CPU widths)."""
     C, H, hd = q.shape
     P, T = block_table.shape[0], kp.shape[1]
     kb = kp[block_table].reshape(P * T, n_kv_heads, hd)
-    vb = vp[block_table].reshape(P * T, n_kv_heads, hd)
+    vb = vp[block_table].reshape(P * T, n_kv_heads, vp.shape[-1] // n_kv_heads)
     if H != n_kv_heads:
         kb = jnp.repeat(kb, H // n_kv_heads, axis=1)
         vb = jnp.repeat(vb, H // n_kv_heads, axis=1)
     scores = jnp.einsum(
         "qhd,shd->hqs", q.astype(jnp.float32), kb.astype(jnp.float32)
-    ) / math.sqrt(hd)
+    ) / (math.sqrt(hd) if scale is None else 1.0 / scale)
     seen = jnp.arange(P * T)[None, :] <= start + jnp.arange(C)[:, None]  # [C, P*T]
     if window is not None:
         seen &= jnp.arange(P * T)[None, :] > start + jnp.arange(C)[:, None] - window
     scores = jnp.where(seen[None], scores, -jnp.inf)
     attn = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("hqs,shd->qhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("hqs,shv->qhv", attn, vb.astype(jnp.float32)).astype(q.dtype)
 
 
-def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int, window=None):
+def paged_attention_gather(q, kp, vp, block_tables, lengths, n_kv_heads: int, window=None, scale: Optional[float] = None):
     """The plain XLA expression of decode attention: gathers every slot's
     WHOLE block table out of one layer's pages kp / vp
     [pages, page_tokens, n_kv_heads * head_dim], casts it to float32 and
     softmaxes the `P * T`-wide row under the length mask, and under `window`
     (a scalar) only its last `window` positions. q [B, n_heads,
-    head_dim], lengths [B] (>= 1). The parity reference of
+    head_dim], lengths [B] (>= 1); vp's heads and `scale` as
+    paged_prefill_attention_gather takes them. The parity reference of
     ops/paged_attention.py and the path for shapes that kernel cannot tile
     (the tiny CPU widths); its traffic is the table, not what is live."""
     B, H, hd = q.shape
     P, T = block_tables.shape[1], kp.shape[1]
     kb = kp[block_tables].reshape(B, P * T, n_kv_heads, hd)
-    vb = vp[block_tables].reshape(B, P * T, n_kv_heads, hd)
+    vb = vp[block_tables].reshape(B, P * T, n_kv_heads, vp.shape[-1] // n_kv_heads)
     if H != n_kv_heads:
         kb = jnp.repeat(kb, H // n_kv_heads, axis=2)
         vb = jnp.repeat(vb, H // n_kv_heads, axis=2)
     scores = jnp.einsum(
         "bhd,bshd->bhs", q.astype(jnp.float32), kb.astype(jnp.float32)
-    ) / math.sqrt(hd)
+    ) / (math.sqrt(hd) if scale is None else 1.0 / scale)
     kv_mask = jnp.arange(P * T)[None, :] < lengths[:, None]  # [B, P*T]
     if window is not None:
         kv_mask &= jnp.arange(P * T)[None, :] >= lengths[:, None] - window
     scores = jnp.where(kv_mask[:, None, :], scores, -jnp.inf)
     attn = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhs,bshd->bhd", attn, vb.astype(jnp.float32)).astype(q.dtype)
+    return jnp.einsum("bhs,bshv->bhv", attn, vb.astype(jnp.float32)).astype(q.dtype)
 
 
 def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
@@ -2043,10 +2144,28 @@ def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
     can tile the pool, else "xla_gather". Shapes decide, nothing else does."""
     from ..ops.paged_attention import can_tile
 
-    return "paged_kernel" if can_tile(page_tokens, cfg.head_dim, cfg.dtype) else "xla_gather"
+    k_lanes, v_lanes = kv_page_widths(cfg)
+    return "paged_kernel" if can_tile(page_tokens, k_lanes, cfg.dtype, v_lanes) else "xla_gather"
 
 
-# ---- the four kinds of layer, each with what a served sequence keeps of it
+def kv_page_widths(cfg: TransformerConfig) -> Tuple[int, int]:
+    """(lanes of one K head in a K/V page, of one V head). A V head is
+    `value_dim` wide. A K head is head_dim wide, or, where head_dim is more
+    than one 128-lane tile and not whole tiles (192), padded with zeros to the
+    next whole tile (256): the paged kernels slice a head out of a page's row
+    at lane-tile borders. The padding is stored and read (a third more K bytes
+    at 192); q is padded alike where it meets the pages, and the scores keep
+    head_dim^-0.5."""
+    hd = cfg.head_dim
+    return (hd if hd <= 128 or hd % 128 == 0 else -(-hd // 128) * 128), cfg.value_dim
+
+
+def _to_page_lanes(t, width: int):
+    """t [..., heads, head_dim] with every head padded with zeros to `width` lanes (as it is where it is that wide)."""
+    return t if t.shape[-1] == width else jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, width - t.shape[-1])])
+
+
+# ---- the five kinds of layer, each with what a served sequence keeps of it
 # (a stack may hold two of them: `stack_plan`, `cache_layout`)
 #
 # A kind's three forms are `attend` factories of one calling convention.
@@ -2072,12 +2191,21 @@ def paged_attention_path(cfg: TransformerConfig, page_tokens: int) -> str:
 # and dim are ONE axis of the stored array (split, the device's tiled layout
 # would put heads where the kernel needs tokens, and every step would pay a
 # relayout of the pool). A sequence's block table grows by a page as it
-# fills, and a full page of one prompt may serve another.
+# fills, and a full page of one prompt may serve another. `k` and `v` have
+# widths of their own: `kv_page_widths` (a V head `value_dim` wide, a 192-wide
+# K head padded to 256 lanes).
 
 
 def _kv_leaves(cfg: TransformerConfig, num_pages: int, page_tokens: int):
-    shape = (cfg.n_layers, num_pages, page_tokens, cfg.n_kv_heads * cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    shape = (cfg.n_layers, num_pages, page_tokens)
+    return {name: jnp.zeros((*shape, cfg.n_kv_heads * width), cfg.dtype) for name, width in zip("kv", kv_page_widths(cfg))}
+
+
+def _page_lanes(cfg: TransformerConfig):
+    """(a K head's lanes in a page, what the attention expressions are told beside it): the scores' scale where the
+    heads are padded (`kv_page_widths`), nothing where they are not."""
+    k_lanes, _ = kv_page_widths(cfg)
+    return k_lanes, ({"scale": cfg.head_dim**-0.5} if k_lanes != cfg.head_dim else {})
 
 
 def _chunk_dest_pages(ctx):
@@ -2115,19 +2243,21 @@ def _kv_chunk(cfg: TransformerConfig, ctx):
     T, c0, block_table = ctx["page_tokens"], ctx["c0"], ctx["block_table"]
     pages, dest_page = _chunk_dest_pages(ctx)
     use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+    k_lanes, how = _page_lanes(cfg)
 
     def attend_in(where: LayerPlace, pool):
         layer, window, kp, vp = where.layer, where.window, pool["k"], pool["v"]
 
         def attend(q, k, v):
+            q, k = _to_page_lanes(q, k_lanes), _to_page_lanes(k, k_lanes)
             kp_ = kp.at[layer, dest_page].set(k[0].reshape(pages, T, -1))
             vp_ = vp.at[layer, dest_page].set(v[0].reshape(pages, T, -1))
             # Attend AFTER the write: the chunk's rows read their own k/v from the pages.
             with _window_scope(window):
                 if use_kernel:
-                    o = paged_prefill_attention(q[0], kp_, vp_, layer, block_table, c0, ctx["length"], n_kv_heads=cfg.n_kv_heads, window=window)
+                    o = paged_prefill_attention(q[0], kp_, vp_, layer, block_table, c0, ctx["length"], n_kv_heads=cfg.n_kv_heads, window=window, **how)
                 else:
-                    o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window)
+                    o = paged_prefill_attention_gather(q[0], kp_[layer], vp_[layer], block_table, c0, cfg.n_kv_heads, window, **how)
             return o[None].astype(cfg.dtype), (kp_, vp_)
 
         return attend
@@ -2145,19 +2275,21 @@ def _kv_step(cfg: TransformerConfig, ctx):
     B = pos.shape[0]
     dest_page, dest_slot, lengths = _step_dest(ctx)
     use_kernel = paged_attention_path(cfg, T) == "paged_kernel"
+    k_lanes, how = _page_lanes(cfg)
 
     def attend_in(where: LayerPlace, pool):
         layer, window, kp, vp = where.layer, where.window, pool["k"], pool["v"]
 
         def attend(q, k, v):
+            q, k = _to_page_lanes(q, k_lanes), _to_page_lanes(k, k_lanes)
             kp_ = kp.at[layer, dest_page, dest_slot].set(k.reshape(B, -1))
             vp_ = vp.at[layer, dest_page, dest_slot].set(v.reshape(B, -1))
             # Attend AFTER the append so the new position attends to itself.
             with _window_scope(window):
                 if use_kernel:
-                    o = paged_attention(q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window)
+                    o = paged_attention(q[:, 0], kp_, vp_, layer, block_tables, lengths, n_kv_heads=cfg.n_kv_heads, window=window, **how)
                 else:
-                    o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window)
+                    o = paged_attention_gather(q[:, 0], kp_[layer], vp_[layer], block_tables, pos + 1, cfg.n_kv_heads, window, **how)
             return o.astype(cfg.dtype), (kp_, vp_)
 
         return attend
@@ -2452,23 +2584,135 @@ def _latent_step(cfg: TransformerConfig, ctx):
     return attend_in
 
 
+# "window": a RING in a state slot, of a window layer of a stack that has them
+# beside layers that page every position (`window_kv_heads`; a MiMo-V2 stack).
+# Such a layer's query sees its own position and the window - 1 before it and
+# nothing older, so of each of these layers a sequence keeps its last `window`
+# positions' k and v and no more: `ring_k` [layers, slots, window,
+# window_kv_heads * head_dim] and `ring_v` [.., window_kv_heads * value_dim],
+# position p in row p % window, in ONE slot for the sequence's life, handed out
+# as the "kda" row's are (decode row i's is slot i + 1, a prefill writes the
+# slot it is told, slot 0 is the trash slot). Which position a row holds follows
+# from the sequence's own position alone, so nothing is cleared where a new
+# prompt takes a slot: a row that would hold a position below 0 is masked, and
+# the prompt's first chunk sees nothing of what the slot held. At a window of
+# 128 the ring is a dense batched product of a decode step's rows (no block
+# table, no gather by position): plain jax.numpy under the `attn.window` scope.
+# What a ring held at a page's border is not kept, so nothing of such a
+# sequence is a prefix of another prompt: write_from is 0.
+
+
+def _ring_leaves(cfg: TransformerConfig, slots: int, page_tokens: int):
+    if slots < 2:
+        raise ValueError("a stack with window rings has a trash slot and at least one ring slot: state_slots >= 2")
+    shape, kvh = (cfg.n_layers, slots, max(cfg.windows)), cfg.window_kv_heads
+    return {"ring_k": jnp.zeros((*shape, kvh * cfg.head_dim), cfg.dtype), "ring_v": jnp.zeros((*shape, kvh * cfg.value_dim), cfg.dtype)}
+
+
+def _ring_rows(last, ring: int):
+    """The position each of a ring's rows holds once position `last` ([...]
+    int32) has been written: the greatest p <= last with p % ring == row,
+    [..., ring]; below 0: the row holds nothing of this sequence."""
+    row = jnp.arange(ring)
+    return last[..., None] - (last[..., None] - row) % ring
+
+
+def _ring_attention(q, keys, values, seen, sink):
+    """q [b, rows, n_heads, hd] over keys [b, n, kv_heads, hd] and values [b, n,
+    kv_heads, vd], row i seeing key j where seen [b, rows, n]; float32 softmax
+    with the sink logits [n_heads] in its denominator (None: none). Query head
+    h reads K/V head h // (n_heads / kv_heads); nothing is repeated. Every row
+    sees a key (its own position). -> [b, rows, n_heads, vd] float32."""
+    b, rows, H, hd = q.shape
+    kvh = keys.shape[2]
+    scores = jnp.einsum("bqgrd,bngd->bgrqn", q.reshape(b, rows, kvh, H // kvh, hd), keys, preferred_element_type=jnp.float32) / math.sqrt(hd)
+    scores = jnp.where(seen[:, None, None], scores, -jnp.inf).reshape(b, H, rows, -1)
+    probs = (jax.nn.softmax(scores, axis=-1) if sink is None else _sink_softmax(scores, sink)).astype(values.dtype)
+    o = jnp.einsum("bgrqn,bngv->bqgrv", probs.reshape(b, kvh, H // kvh, rows, -1), values, preferred_element_type=jnp.float32)
+    return o.reshape(b, rows, H, -1)
+
+
+def _ring_chunk(cfg: TransformerConfig, ctx):
+    """The chunk's rows attend over what the ring holds of the positions below
+    the chunk (the last `window` of them: what the chunk before left there;
+    nothing where the chunk is the prompt's first) and over the chunk's own k
+    and v, and leave the last `window` positions below the chunk's end (the
+    length's, in the prompt's last chunk) in the ring."""
+    slot, c0, rows, ring, kvh = ctx["slot"], ctx["c0"], ctx["rows"], max(cfg.windows), cfg.window_kv_heads
+    before = _ring_rows(c0 - 1, ring)  # what the ring's rows hold as the chunk starts
+    q_pos = c0 + jnp.arange(rows)
+    k_pos = jnp.concatenate([before, q_pos])
+    back = q_pos[:, None] - k_pos[None, :]
+    seen = (back >= 0) & (back < ring) & (k_pos >= 0)[None, :]
+    after = _ring_rows(jnp.minimum(c0 + rows, ctx["length"]) - 1, ring)  # and as it ends: from the chunk where that is a row of it
+    from_chunk, chunk_row = (after >= c0)[:, None], jnp.clip(after - c0, 0, rows - 1)
+
+    def attend_in(where: LayerPlace, pool):
+        layer, rk, rv = where.layer, pool["ring_k"], pool["ring_v"]
+
+        def attend(q, k, v, sink=None):
+            with jax.named_scope("attn.window"):
+                k_in, v_in = rk[layer, slot], rv[layer, slot]
+                keys = jnp.concatenate([k_in.reshape(ring, kvh, -1), k[0]])
+                values = jnp.concatenate([v_in.reshape(ring, kvh, -1), v[0]])
+                o = _ring_attention(q, keys[None], values[None], seen[None], sink)
+                k_out = jnp.where(from_chunk, k[0].reshape(rows, -1)[chunk_row], k_in)
+                v_out = jnp.where(from_chunk, v[0].reshape(rows, -1)[chunk_row], v_in)
+                return o.astype(cfg.dtype), (rk.at[layer, slot].set(k_out), rv.at[layer, slot].set(v_out))
+
+        return attend
+
+    return attend_in
+
+
+def _ring_step(cfg: TransformerConfig, ctx):
+    """Each active row writes its k and v into row pos % window of its slot's
+    ring (an inactive one into the trash slot's) and attends over the ring's
+    rows that hold a position of its own sequence."""
+    pos, active, ring, kvh = ctx["pos"], ctx["active"], max(cfg.windows), cfg.window_kv_heads
+    B = pos.shape[0]
+    slots = jnp.where(active, jnp.arange(B) + 1, TRASH_PAGE)
+    seen = (_ring_rows(pos, ring) >= 0)[:, None, :]  # AFTER the write: the new position attends to itself
+
+    def attend_in(where: LayerPlace, pool):
+        layer, rk, rv = where.layer, pool["ring_k"], pool["ring_v"]
+
+        def attend(q, k, v, sink=None):
+            with jax.named_scope("attn.window"):
+                rk_ = rk.at[layer, slots, pos % ring].set(k.reshape(B, -1))
+                rv_ = rv.at[layer, slots, pos % ring].set(v.reshape(B, -1))
+                o = _ring_attention(q, rk_[layer, slots].reshape(B, ring, kvh, -1), rv_[layer, slots].reshape(B, ring, kvh, -1), seen, sink)
+                return o.astype(cfg.dtype), (rk_, rv_)
+
+        return attend
+
+    return attend_in
+
+
+def _ring_path(cfg: TransformerConfig, page_tokens: int) -> str:
+    return "xla_ring"
+
+
 class LayerKind(NamedTuple):
     """A row of `KINDS`: what a served sequence keeps of a layer of this kind
     and the layer's three forms. A new kind is a row here, its kernels and
     plain expressions under ops/, its architecture file under
     benchmarks/archs/, and a `stack_plan` that places it. One stack may hold
     two kinds, one indexed by page and one by slot: K/V pages beside state
-    slots (Solar-Open2), latent pages beside state slots (GigaChat3.5)."""
+    slots (Solar-Open2), latent pages beside state slots (GigaChat3.5), K/V
+    pages of the layers that see everything beside the window layers' rings
+    (MiMo-V2)."""
 
     names: Tuple[str, ...]  # its cache leaves, in the pool's order
     indexed: str  # what their second axis counts: "page" (PagedKVAllocator hands them out; a block table names them) | "slot" (one a decode row)
-    state: bool  # a recurrent state of a fixed size (nothing of it is kept at a page's border), not the positions' k/v
+    state: bool  # of a fixed size whatever the sequence's length, and nothing of it is kept at a page's border: a recurrent state, a window's ring
     leaves: Callable  # (cfg with n_layers the layers of this kind, pages or slots, page_tokens) -> {name: zeros}
     whole: Callable
     chunk: Callable
     step: Callable
     decode_path: Callable  # (cfg, page_tokens) -> which expression `step` runs, for PagedLM.describe
     prefill_path: Optional[Callable] = None  # the same of `chunk`, where that is a choice of its own (else decode_path's answer holds for both)
+    decode_key: Optional[str] = None  # the name describe() says `decode_path` under, where it is not its indexing's ("decode_attention" by page, "decode_state" by slot)
 
 
 KINDS = {
@@ -2476,6 +2720,7 @@ KINDS = {
     "retention": LayerKind(("s", "z"), "page", True, _retention_leaves, _retention_whole, _retention_chunk, _retention_step, _retention_decode_path, _retention_prefill_path),
     "kda": LayerKind(("s", "tail"), "slot", True, _kda_leaves, _kda_whole, _kda_chunk, _kda_step, _kda_decode_path),
     "latent": LayerKind(("ckv",), "page", False, _latent_leaves, _latent_whole, _latent_chunk, _latent_step, _latent_path),
+    "window": LayerKind(("ring_k", "ring_v"), "slot", True, _ring_leaves, _window_whole, _ring_chunk, _ring_step, _ring_path, decode_key="decode_window"),
 }
 
 
@@ -2505,10 +2750,12 @@ def cache_layout(cfg: TransformerConfig) -> CacheLayout:
 
 
 def decode_paths(cfg: TransformerConfig, page_tokens: int) -> Dict[str, str]:
-    """Which expression a decode step runs over the pages (`decode_attention`)
-    and over the state slots (`decode_state`): PagedLM.describe."""
+    """Which expression a decode step runs over the pages (`decode_attention`),
+    over the state slots (`decode_state`) and over the window rings
+    (`decode_window`): PagedLM.describe."""
     keys = {"page": "decode_attention", "slot": "decode_state"}
-    return {keys[KINDS[kind].indexed]: KINDS[kind].decode_path(cfg, page_tokens) for kind, _ in cache_layout(cfg).kinds}
+    rows = [KINDS[kind] for kind, _ in cache_layout(cfg).kinds]
+    return {row.decode_key or keys[row.indexed]: row.decode_path(cfg, page_tokens) for row in rows}
 
 
 def prefill_paths(cfg: TransformerConfig, page_tokens: int) -> Dict[str, str]:
@@ -2625,6 +2872,7 @@ def forward_prefill(
         c0 = anchor + i * C
         cos = lax.dynamic_slice_in_dim(cos_t, c0, C)
         sin = lax.dynamic_slice_in_dim(sin_t, c0, C)
+        rope = {"window": rope_at(_window_rope_cfg(cfg), c0 + jnp.arange(C))} if cfg.window_rope_theta else {}
         x = _embed(params, lax.dynamic_slice_in_dim(tokens, c0, C, axis=1), cfg)
         ctx = dict(call, c0=c0)
         attend_in = {kind: KINDS[kind].chunk(cfg, ctx) for kind, _ in layout.kinds}
@@ -2633,7 +2881,7 @@ def forward_prefill(
         # forward_decode): no copy of it is made a layer or a chunk.
         def layer(kind, carry, where, layer_params, experts):
             x, pool = carry[0], dict(zip(layout.names, carry[1:]))
-            x, own = _block(x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), attend_in[kind](where, pool), experts=experts, kind=kind)
+            x, own = _block(x, layer_params, cfg, *_rope_switch(*rope.get(kind, (cos, sin)), where.rope_on), attend_in[kind](where, pool), experts=experts, kind=kind)
             pool.update(zip(KINDS[kind].names, own))
             return (x, *pool.values()), None
 
@@ -2689,6 +2937,7 @@ def forward_decode(
         T = None
         cos, sin = (t[:, None, :] for t in rope_at(cfg, pos))
 
+    rope = {"window": tuple(t[:, None, :] for t in rope_at(_window_rope_cfg(cfg), pos))} if cfg.window_rope_theta else {}
     x = _embed(params, tokens, cfg)[:, None, :]  # [B,1,d]
     ctx = dict(block_tables=block_tables, pos=pos, active=active, page_tokens=T)
     attend_in = {kind: KINDS[kind].step(cfg, ctx) for kind, _ in layout.kinds}
@@ -2700,7 +2949,7 @@ def forward_decode(
     def layer(kind, carry, where, layer_params, experts):
         x, pool = carry[0], dict(zip(layout.names, carry[1:]))
         x, own, *rows_per_expert = _block(
-            x, layer_params, cfg, *_rope_switch(cos, sin, where.rope_on), attend_in[kind](where, pool),
+            x, layer_params, cfg, *_rope_switch(*rope.get(kind, (cos, sin)), where.rope_on), attend_in[kind](where, pool),
             stats="experts" if stats else "", experts=experts, kind=kind,
         )
         pool.update(zip(KINDS[kind].names, own))

@@ -100,12 +100,13 @@ def _sublanes(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def can_tile(page_tokens: int, head_dim: int, dtype) -> bool:
+def can_tile(page_tokens: int, head_dim: int, dtype, v_head_dim: Optional[int] = None) -> bool:
     """Whether the kernel can tile a pool: a head must fill whole 128-lane
-    tiles and a page whole sublane tiles of the pool's dtype, or a page's DMA
-    and a head's slice would cut through a tile (the tiny CPU widths:
-    head_dim 16, 8-token pages)."""
-    return head_dim % 128 == 0 and page_tokens % _sublanes(dtype) == 0
+    tiles (a K head `head_dim` lanes, a V head `v_head_dim` where that is
+    another width) and a page whole sublane tiles of the pool's dtype, or a
+    page's DMA and a head's slice would cut through a tile (the tiny CPU
+    widths: head_dim 16, 8-token pages)."""
+    return head_dim % 128 == 0 and (v_head_dim or head_dim) % 128 == 0 and page_tokens % _sublanes(dtype) == 0
 
 
 def pick_pages_per_block(page_tokens: int, row_width: int, max_pages: int, dtype) -> int:
@@ -124,13 +125,13 @@ def _kernel(*refs, windowed: bool, **static):
 
 def _decode_kernel(
     layer_ref, lengths_ref, tables_ref, window_ref,  # scalar prefetch (SMEM); window_ref None: no window
-    q_ref, k_hbm, v_hbm,  # [1, H, hd] VMEM; [L, N, T, F] HBM, twice
-    o_ref,  # [1, H, hd]
+    q_ref, k_hbm, v_hbm,  # [1, H, hd] VMEM; [L, N, T, F] HBM, twice (V's rows n_kv_heads * hv wide)
+    o_ref,  # [1, H, hv]
     k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
     *, scale, n_kv_heads, page_tokens, pages_per_block, max_pages,
 ):
     b = pl.program_id(0)
-    H, hd = q_ref.shape[1], q_ref.shape[2]
+    H, hd, hv = q_ref.shape[1], q_ref.shape[2], o_ref.shape[2]
     rep = H // n_kv_heads
     F = n_kv_heads * hd
     T, ppb = page_tokens, pages_per_block
@@ -208,17 +209,17 @@ def _decode_kernel(
         pv = lax.dot_general(
             p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
             precision=exact, preferred_element_type=jnp.float32,
-        )  # [H, F]; row h is wanted in the lanes of its KV head only
+        )  # [H, n_kv_heads * hv]; row h is wanted in the lanes of its KV head only
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
     lax.fori_loop(0, n_blocks, body, None)
 
-    out_kv = lax.broadcasted_iota(jnp.int32, (H, hd), 0) // rep
-    out = jnp.zeros((H, hd), jnp.float32)
+    out_kv = lax.broadcasted_iota(jnp.int32, (H, hv), 0) // rep
+    out = jnp.zeros((H, hv), jnp.float32)
     for g in range(n_kv_heads):
-        out = jnp.where(out_kv == g, acc_scr[:, g * hd:(g + 1) * hd], out)
+        out = jnp.where(out_kv == g, acc_scr[:, g * hv:(g + 1) * hv], out)
     o_ref[0] = (out / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
@@ -232,6 +233,7 @@ def paged_attention(
     *,
     n_kv_heads: int,
     window: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
     pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
@@ -239,19 +241,24 @@ def paged_attention(
 
     q [B, n_heads, head_dim]; k_pages / v_pages the pool
     [layers, pages, page_tokens, n_kv_heads * head_dim], read at `layer`
-    (int32 scalar) and never copied; block_tables [B, P] int32 page indices;
+    (int32 scalar) and never copied; v_pages' heads may be of another width
+    than k_pages' (the result's), and `scale` another factor of the scores than
+    head_dim^-0.5 (heads padded to whole lane tiles);
+    block_tables [B, P] int32 page indices;
     lengths [B] int32, positions [0, lengths[b]) are attended and 0 means an
     inactive slot (output zeros). `window` (int32 scalar, may be traced; None:
     no window, the kernel as it was): only positions [lengths[b] - window,
     lengths[b]) are attended, and the pages wholly below them are not read.
-    Returns [B, n_heads, head_dim] in q's dtype.
+    Returns [B, n_heads, v_pages' head width] in q's dtype.
     """
     B, H, hd = q.shape
     _, _, T, F = k_pages.shape
     P = block_tables.shape[1]
-    if F != n_kv_heads * hd or H % n_kv_heads:
-        raise ValueError(f"pool width {F} is not n_kv_heads {n_kv_heads} x head_dim {hd} (n_heads {H})")
-    if not can_tile(T, hd, k_pages.dtype):
+    Fv = v_pages.shape[3]
+    hv = Fv // n_kv_heads
+    if F != n_kv_heads * hd or H % n_kv_heads or Fv % n_kv_heads:
+        raise ValueError(f"pool width {F} is not n_kv_heads {n_kv_heads} x head_dim {hd} (n_heads {H}; V's {Fv})")
+    if not can_tile(T, hd, k_pages.dtype, hv):
         raise ValueError(
             f"paged attention cannot tile head_dim {hd}, page_tokens {T}, {k_pages.dtype}: "
             "use transformer.paged_attention_gather"
@@ -262,7 +269,7 @@ def paged_attention(
         interpret = _auto_interpret()
     bk = pages_per_block * T
     kern = functools.partial(
-        _kernel, windowed=window is not None, scale=1.0 / math.sqrt(hd), n_kv_heads=n_kv_heads, page_tokens=T,
+        _kernel, windowed=window is not None, scale=1.0 / math.sqrt(hd) if scale is None else scale, n_kv_heads=n_kv_heads, page_tokens=T,
         pages_per_block=pages_per_block, max_pages=P,
     )
     scalars = [jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1)]
@@ -278,17 +285,17 @@ def paged_attention(
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, hv), lambda b, *_: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, bk, F), k_pages.dtype),
-                pltpu.VMEM((2, bk, F), v_pages.dtype),
+                pltpu.VMEM((2, bk, Fv), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((H, 128), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32),
-                pltpu.VMEM((H, F), jnp.float32),
+                pltpu.VMEM((H, Fv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, hv), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=KERNEL_NAME,
@@ -317,13 +324,13 @@ def _prefill_kernel(*refs, windowed: bool, **static):
 
 def _prefill_chunk_kernel(
     layer_ref, start_ref, length_ref, table_ref, window_ref,  # scalar prefetch (SMEM); window_ref None: no window
-    q_ref, k_hbm, v_hbm,  # [bq, H * hd] VMEM; [L, N, T, F] HBM, twice
-    o_ref,  # [bq, H * hd]
+    q_ref, k_hbm, v_hbm,  # [bq, H * hd] VMEM; [L, N, T, F] HBM, twice (V's rows n_kv_heads * hv wide)
+    o_ref,  # [bq, H * hv]
     k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
     *, scale, n_heads, n_kv_heads, page_tokens, pages_per_block, max_pages, heads_unrolled,
 ):
     bq = q_ref.shape[0]
-    hd = q_ref.shape[1] // n_heads
+    hd, hv = q_ref.shape[1] // n_heads, o_ref.shape[1] // n_heads
     rep = n_heads // n_kv_heads
     T, ppb = page_tokens, pages_per_block
     bk = ppb * T
@@ -422,9 +429,12 @@ def _prefill_chunk_kernel(
         def head(h, _):
             lanes = pl.ds(pl.multiple_of(h * hd, 128), hd)
             kv_lanes = pl.ds(pl.multiple_of(h // rep * hd, 128), hd)
-            m_scr[h], l_scr[h], acc_scr[:, lanes] = softmax_step(
-                q_ref[:, lanes], k_buf[slot, :, kv_lanes], v_buf[slot, :, kv_lanes], seen,
-                m_scr[h], l_scr[h], acc_scr[:, lanes],
+            # where a head's values are of another width than its keys: their lanes in v_buf and in the accumulator
+            v_lanes = kv_lanes if hv == hd else pl.ds(pl.multiple_of(h // rep * hv, 128), hv)
+            o_lanes = lanes if hv == hd else pl.ds(pl.multiple_of(h * hv, 128), hv)
+            m_scr[h], l_scr[h], acc_scr[:, o_lanes] = softmax_step(
+                q_ref[:, lanes], k_buf[slot, :, kv_lanes], v_buf[slot, :, v_lanes], seen,
+                m_scr[h], l_scr[h], acc_scr[:, o_lanes],
             )
 
         def heads(i, _):
@@ -436,7 +446,7 @@ def _prefill_chunk_kernel(
     lax.fori_loop(0, n_blocks, body, None)
 
     def finish(h, _):
-        lanes = pl.ds(pl.multiple_of(h * hd, 128), hd)
+        lanes = pl.ds(pl.multiple_of(h * hv, 128), hv)
         o_ref[:, lanes] = (acc_scr[:, lanes] / jnp.maximum(l_scr[h, :, :1], 1e-30)).astype(o_ref.dtype)
 
     lax.fori_loop(0, n_heads, finish, None)
@@ -453,6 +463,7 @@ def paged_prefill_attention(
     *,
     n_kv_heads: int,
     window: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
     block_q: Optional[int] = None,
     pages_per_block: Optional[int] = None,
     heads_unrolled: Optional[int] = None,
@@ -469,14 +480,17 @@ def paged_prefill_attention(
     zeros. Row i attends over positions [0, start + i], under `window` (int32
     scalar, may be traced; None: no window, the kernel as it was) over the
     last `window` of them, and the pages wholly below the reach of a block's
-    first row are not read. Returns [C, n_heads, head_dim] in q's dtype.
+    first row are not read. v_pages' heads and `scale` as paged_attention
+    takes them. Returns [C, n_heads, v_pages' head width] in q's dtype.
     """
     C, H, hd = q.shape
     _, _, T, F = k_pages.shape
     P = block_table.shape[0]
-    if F != n_kv_heads * hd or H % n_kv_heads:
-        raise ValueError(f"pool width {F} is not n_kv_heads {n_kv_heads} x head_dim {hd} (n_heads {H})")
-    if not can_tile(T, hd, k_pages.dtype) or C % T:
+    Fv = v_pages.shape[3]
+    hv = Fv // n_kv_heads
+    if F != n_kv_heads * hd or H % n_kv_heads or Fv % n_kv_heads:
+        raise ValueError(f"pool width {F} is not n_kv_heads {n_kv_heads} x head_dim {hd} (n_heads {H}; V's {Fv})")
+    if not can_tile(T, hd, k_pages.dtype, hv) or C % T:
         raise ValueError(
             f"paged prefill attention cannot tile head_dim {hd}, page_tokens {T}, chunk {C}, {k_pages.dtype}: "
             "use transformer.paged_prefill_attention_gather"
@@ -492,12 +506,12 @@ def paged_prefill_attention(
     item = jnp.dtype(k_pages.dtype).itemsize
     # q and o blocks double-buffered by the pipeline, K and V by hand, the
     # running max / sum / accumulator, and the scores of one head in flight.
-    vmem = 4 * block_q * H * hd * item + 4 * bk * F * item + block_q * H * (2 * 128 + hd) * 4 + 4 * block_q * bk * 4
+    vmem = 2 * block_q * H * (hd + hv) * item + 2 * bk * (F + Fv) * item + block_q * H * (2 * 128 + hv) * 4 + 4 * block_q * bk * 4
     scalars = [jnp.asarray(x, jnp.int32).reshape(1) for x in (layer, start, length)] + [block_table.astype(jnp.int32)]
     if window is not None:
         scalars.append(jnp.asarray(window, jnp.int32).reshape(1))
     kern = functools.partial(
-        _prefill_kernel, windowed=window is not None, scale=1.0 / math.sqrt(hd), n_heads=H, n_kv_heads=n_kv_heads, page_tokens=T,
+        _prefill_kernel, windowed=window is not None, scale=1.0 / math.sqrt(hd) if scale is None else scale, n_heads=H, n_kv_heads=n_kv_heads, page_tokens=T,
         pages_per_block=pages_per_block, max_pages=P,
         heads_unrolled=largest_divisor(H, heads_unrolled or PREFILL_HEADS_UNROLLED),
     )
@@ -511,21 +525,21 @@ def paged_prefill_attention(
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((block_q, H * hd), lambda i, *_: (i, 0)),
+            out_specs=pl.BlockSpec((block_q, H * hv), lambda i, *_: (i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, bk, F), k_pages.dtype),
-                pltpu.VMEM((2, bk, F), v_pages.dtype),
+                pltpu.VMEM((2, bk, Fv), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((H, block_q, 128), jnp.float32),
                 pltpu.VMEM((H, block_q, 128), jnp.float32),
-                pltpu.VMEM((block_q, H * hd), jnp.float32),
+                pltpu.VMEM((block_q, H * hv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((C, H * hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((C, H * hv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=min(100 << 20, max(32 << 20, vmem * 3 // 2))
         ),
         interpret=interpret,
         name=PREFILL_KERNEL_NAME,
     )(*scalars, q.astype(k_pages.dtype).reshape(C, H * hd), k_pages, v_pages)
-    return out.reshape(C, H, hd)
+    return out.reshape(C, H, hv)

@@ -264,6 +264,9 @@ class PagedLM:
         self.state_bytes = sum(self.kv[name].nbytes for name in slotted) // (max_slots + 1)
         # Layers whose pages hold latent rows (transformer.KINDS["latent"]): their counters below.
         self._latent_layers = dict(layout.kinds).get("latent", 0)
+        # How often a decode step moves a live row's slot: a recurrent state is read and written whole, a window's
+        # ring (transformer.KINDS["window"]) is read whole and takes one row.
+        self._state_passes = 1 if "window" in dict(layout.kinds) else 2
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
         # The last decode step's result vector, on the device: the next step's
@@ -297,8 +300,10 @@ class PagedLM:
         "xla_gather" over latent pages; "retention_kernel" or "xla_step" over
         a state, whose prefill chunk says so apart: `prefill_attention`,
         "retention_kernel" or "xla_chunk") and over the state slots (`decode_state`:
-        "kda_kernel" or "xla_step"): each kind's own answer, `KINDS`; a stack of
-        state layers beside K/V or latent pages reports BOTH `decode_state` and
+        "kda_kernel" or "xla_step") or the window layers' rings (`decode_window`:
+        "xla_ring"; `state_bytes` is then one sequence's rings): each kind's own
+        answer, `KINDS`; a stack of state or ring layers beside K/V or latent
+        pages reports BOTH, `decode_state` or `decode_window` and
         `decode_attention`), and
         what compiling cost so far (LLMServer.engine_stats() carries it out)."""
         import os
@@ -542,9 +547,9 @@ class PagedLM:
                 "kv_live": int(cfg.n_layers * live.sum()),
             }
         if self.layout.state:
-            # Every live row's state of every layer is read once and written once: its slot's, or its one page.
+            # Every live row's state of every layer is read once and written once (its slot's, or its one page); a ring is read.
             live = int((pos >= 0).sum())
-            counters["decode_state"] = {"bytes": 2 * live * (self.state_bytes or self.page_bytes), "live_slots": live, "steps": 1}
+            counters["decode_state"] = {"bytes": self._state_passes * live * (self.state_bytes or self.page_bytes), "live_slots": live, "steps": 1}
         if self.layout.state and self.layout.kv:
             # The K/V the live rows read beside their states: every position up to their own, a page's bytes / page_tokens each.
             kv_tokens = int((pos[pos >= 0].astype(np.int64) + 1).sum())

@@ -536,15 +536,24 @@ def test_one_group_is_todays_router_bit_for_bit():
         tfm.init_params(jax.random.PRNGKey(0), moe_cfg(n_group=2))  # a softmax router has no groups
 
 
+# The published routers cut sixteen ways: dots.vlm1's and GigaChat3.5's shape (8 groups of 32, 4 kept, a shared expert), and
+# MiMo-V2's (benchmarks/configs/mimo-v2.5-L7.json: one group, no shared expert to count once, the weights times 1).
+SIXTEEN_WAYS = {
+    "group_limited_with_a_shared_expert": {},
+    "mimo_v2_one_group_no_shared_expert": dict(n_group=1, topk_group=1, d_ff_shared=0, route_scale=1.0),
+}
+
+
+@pytest.mark.parametrize("router", sorted(SIXTEEN_WAYS))
 @pytest.mark.parametrize("form", ["grouped", "every_expert"])
-def test_the_sixteen_shares_of_a_group_limited_layer_add_up_to_the_uncut_layer(form):
+def test_the_sixteen_shares_of_a_group_limited_layer_add_up_to_the_uncut_layer(form, router):
     """The model-configs guide's share test at the published router's shape:
-    256 experts in 8 groups, 4 kept, top-8, cut sixteen ways (16 experts a
-    rank, half a group): every rank routes over all 256 in their groups,
-    renormalises over the 8 chosen whether held or not, and computes its own
-    experts' part; the sixteen parts, the shared expert counted once, are the
-    uncut layer's result."""
-    full = grouped_router_cfg()
+    256 experts (in 8 groups, 4 kept; or in one), top-8, cut sixteen ways (16
+    experts a rank): every rank routes over all 256, renormalises over the 8
+    chosen whether held or not, and computes its own experts' part; the
+    sixteen parts, the shared expert (where the model has one) counted once,
+    are the uncut layer's result."""
+    full = grouped_router_cfg().replace(**SIXTEEN_WAYS[router])
     mp = jax.tree_util.tree_map(lambda a: a[0], tfm.init_params(jax.random.PRNGKey(3), full)["blocks"]["mlp"])
     h = jax.random.normal(jax.random.PRNGKey(4), (2, 9, D), jnp.float32)
 
@@ -556,7 +565,8 @@ def test_the_sixteen_shares_of_a_group_limited_layer_add_up_to_the_uncut_layer(f
         return tfm._routed_ffn(h, riding, cfg, experts=(stack, jnp.int32(0)))
 
     with jax.default_matmul_precision("highest"):
-        whole, shared = ffn(full, mp), tfm._ffn(h, mp["shared"], full)
+        whole, shared = ffn(full, mp), (tfm._ffn(h, mp["shared"], full) if "shared" in mp else 0.0)
         parts = [ffn(*share_of(full, mp, rank, 16)) for rank in range(16)]
+    assert ("shared" in mp) == (router == "group_limited_with_a_shared_expert")
     np.testing.assert_allclose(sum(parts) - 15 * shared, whole, rtol=2e-5, atol=2e-6)
     assert sum(float(jnp.max(jnp.abs(p - shared))) > 1e-3 for p in parts) >= 8  # shares are parts, not nothing

@@ -358,6 +358,82 @@ def test_windowed_prefill_kernel_reads_no_page_below_the_window():
 # ------------------------------------------------- the latent kernels
 
 
+# ---- K heads wider than V heads (MiMo-V2's global layers: 192 beside 128). A 192-wide K head lies in the pages padded
+# with zeros to 256 lanes (transformer.kv_page_widths), q is padded alike where it meets them, the scores keep 192^-0.5.
+
+HK, HK_STORED, HV = 192, 256, 128
+
+
+def _wide_key_pool(dtype, G, seed=0):
+    """(K pool with every head's lanes past 192 zero, V pool of 128-wide heads, the scale)."""
+    rng = np.random.default_rng(seed)
+    kp = rng.standard_normal((2, N, T, G, HK_STORED))
+    kp[..., HK:] = 0.0
+    return jnp.asarray(kp.reshape(2, N, T, G * HK_STORED), dtype), jnp.asarray(rng.standard_normal((2, N, T, G * HV)), dtype), HK**-0.5
+
+
+def _padded_q(rng, dtype, rows, H):
+    q = rng.standard_normal((rows, H, HK_STORED))
+    q[..., HK:] = 0.0
+    return jnp.asarray(q, dtype)
+
+
+@pytest.mark.parametrize("lengths", LENGTHS.values(), ids=LENGTHS.keys())
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_decode_kernel_at_a_key_width_that_is_not_the_value_width(dtype, lengths):
+    """16:4 heads... at test size 8:2: the kernel's result is a V head wide,
+    and equal to the expression's over the same padded pool AND to plain
+    attention over the unpadded 192-wide heads."""
+    H, G = 8, 2
+    kp, vp, scale = _wide_key_pool(dtype, G)
+    rng = np.random.default_rng(1)
+    q = _padded_q(rng, dtype, B, H)
+    bt = np.full((B, P), TRASH_PAGE, np.int32)
+    free = iter(rng.permutation(np.arange(1, N)))
+    for b, n in enumerate(lengths):
+        for j in range(-(-n // T)):
+            bt[b, j] = next(free)
+    bt, lens = jnp.asarray(bt), jnp.asarray(lengths, jnp.int32)
+    out = np.asarray(pa.paged_attention(q, kp, vp, 1, bt, lens, n_kv_heads=G, scale=scale, pages_per_block=2), np.float32)
+    ref = np.asarray(tfm.paged_attention_gather(q, kp[1], vp[1], bt, jnp.maximum(lens, 1), G, scale=scale), np.float32)
+    assert out.shape == (B, H, HV)
+    live = np.asarray(lens) > 0
+    assert np.abs(out[live] - ref[live]).max() < _window_tol(dtype, vp, ref) and not out[~live].any()
+    # the unpadded heads through the same expression at its own scale (192^-0.5 from q's width): the padding changes nothing
+    unpadded = tfm.paged_attention_gather(q[..., :HK], kp[1].reshape(N, T, G, HK_STORED)[..., :HK].reshape(N, T, G * HK), vp[1], bt, jnp.maximum(lens, 1), G)
+    assert np.abs(ref[live] - np.asarray(unpadded, np.float32)[live]).max() < (1e-5 if dtype == jnp.float32 else 2.0 ** -7 * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("chunk", PREFILL_CHUNKS.values(), ids=PREFILL_CHUNKS.keys())
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_prefill_kernel_at_a_key_width_that_is_not_the_value_width(dtype, chunk):
+    C, start, length = chunk
+    H, G = 8, 2
+    kp, vp, scale = _wide_key_pool(dtype, G)
+    rng = np.random.default_rng(2)
+    q = _padded_q(rng, dtype, C, H)
+    bt = np.full((P,), TRASH_PAGE, np.int32)
+    n = -(-length // T)
+    bt[:n] = rng.permutation(np.arange(1, N))[:n]
+    bt = jnp.asarray(bt)
+    out = pa.paged_prefill_attention(q, kp, vp, 1, bt, start, length, n_kv_heads=G, scale=scale, block_q=min(C, 2 * T), pages_per_block=3)
+    ref = tfm.paged_prefill_attention_gather(q, kp[1], vp[1], bt, start, G, scale=scale)
+    rows = max(0, min(C, length - start))
+    out, ref = np.asarray(out, np.float32)[:rows], np.asarray(ref, np.float32)[:rows]
+    assert out.shape == (rows, H, HV) and np.abs(out - ref).max() < _window_tol(dtype, vp, ref)
+
+
+def test_a_key_head_of_192_lies_in_256_lanes_and_the_kernels_take_it():
+    cfg = tfm.tiny(d_head=192, v_head_dim=128, n_heads=8, n_kv_heads=2, dtype=jnp.bfloat16)
+    assert tfm.kv_page_widths(cfg) == (256, 128) and tfm.paged_attention_path(cfg, 16) == "paged_kernel"
+    assert tfm.kv_page_widths(tfm.tiny(d_head=128)) == (128, 128) and tfm.kv_page_widths(tfm.tiny(d_head=24, v_head_dim=16)) == (24, 16)
+    assert pa.can_tile(16, 256, jnp.bfloat16, 128) and not pa.can_tile(16, 256, jnp.bfloat16, 64) and not pa.can_tile(16, 192, jnp.bfloat16, 128)
+    with pytest.raises(ValueError, match="V's 301"):  # V's row is no whole number of heads
+        pa.paged_attention(jnp.zeros((1, 8, 256)), jnp.zeros((1, 4, 16, 512)), jnp.zeros((1, 4, 16, 301)), 0, jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32), n_kv_heads=2)
+    with pytest.raises(ValueError, match="cannot tile"):  # a V head of 150 lanes
+        pa.paged_attention(jnp.zeros((1, 8, 256)), jnp.zeros((1, 4, 16, 512)), jnp.zeros((1, 4, 16, 300)), 0, jnp.zeros((1, 2), jnp.int32), jnp.ones((1,), jnp.int32), n_kv_heads=2)
+
+
 def _latent_inputs(dtype, lengths, seed=0, heads=16, c=128, rope=64):
     """A latent pool of two layers (rows [c_kv | k_r] padded to whole lane
     tiles), absorbed queries of the same width, and block tables as `_inputs` builds them."""

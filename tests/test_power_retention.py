@@ -586,6 +586,8 @@ CACHES = {
     "trinitymini-serve-agent-turns": ("kv_pages", True, "llm_decode", {"k": "page", "v": "page"}),
     "brumby14b-serve-longgen-batch": ("state", False, "llm_decode_state", {"s": "page", "z": "page"}),
     "solaropen2-serve-reasoning-batch": ("state+kv_pages", False, "llm_decode_hybrid", {"k": "page", "v": "page", "s": "slot", "tail": "slot"}),
+    # PR 61: the global layers' K/V pages beside the window layers' rings, one slot a sequence as a state's
+    "mimov25-serve-longctx-batch": ("state+kv_pages", False, "llm_decode_hybrid", {"k": "page", "v": "page", "ring_k": "slot", "ring_v": "slot"}),
 }
 
 
@@ -610,6 +612,6 @@ def test_one_layout_says_what_a_model_caches_and_paged_lm_reads_it(cell_name):
     assert described["cache"]["kind"] == kind and lm.shares_prefix_pages is shares
     assert set(described["cache"]) == {"kind", "page_bytes"} | ({"state_bytes"} if "slot" in indexed.values() else set())
     assert set(described) == {"pid", "platform", "device_kind", "device_count", "cache", "decode_attention", "peak_bytes_in_use", "compile"} | (
-        {"decode_state"} if "slot" in indexed.values() else set()) | ({"prefill_attention"} if kind == "state" else set())
+        {"decode_window" if "ring_k" in indexed else "decode_state"} if "slot" in indexed.values() else set()) | ({"prefill_attention"} if kind == "state" else set())
     assert lm._get_decode().__name__ == decode_name and lm._get_prefill(2 if layout.kv else 1).__name__ == decode_name.replace("decode", "prefill") + ("_p2" if layout.kv else "_p1")
     assert lm.page_bytes == sum(math.prod(pool[name].shape) * pool[name].dtype.itemsize for name, by in indexed.items() if by == "page") // pages

@@ -61,6 +61,8 @@ def main(argv) -> int:
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv-heads", type=int, default=32)
     ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--v-head-dim", type=int, default=0, help="a V head's width where it is not --head-dim (MiMo-V2: 128 beside K heads stored 256 wide)")
+    ap.add_argument("--needed-head-dim", type=int, default=0, help="a K head's own width where --head-dim is what the pages pad it to (192 in 256): scales the scores, counts the bytes and FLOPs")
     ap.add_argument("--page-tokens", type=int, default=16)
     ap.add_argument("--max-pages", type=int, default=256)
     ap.add_argument("--pool-pages", type=int, default=1024)
@@ -99,12 +101,14 @@ def main(argv) -> int:
     bw = peaks["hbm_bytes_per_s"]
     H, G, hd, T, P, N, L = a.heads, a.kv_heads, a.head_dim, a.page_tokens, a.max_pages, a.pool_pages, a.layers
     F = G * hd
+    hv, hk = a.v_head_dim or hd, a.needed_head_dim or hd
+    how = {"scale": hk**-0.5} if hk != hd else {}
     dtype = jnp.bfloat16
     key = jax.random.PRNGKey(0)
     kp = jax.random.normal(key, (1, N, T, F), dtype)
-    vp = jax.random.normal(jax.random.fold_in(key, 1), (1, N, T, F), dtype)
+    vp = jax.random.normal(jax.random.fold_in(key, 1), (1, N, T, G * hv), dtype)
     ppbs = [int(x) for x in a.pages_per_block.split(",") if x] or [None]
-    print(f"device {dev.device_kind}, peak {bw / 1e9:.0f} GB/s; heads {H}:{G} x {hd}, pages of {T}, pool {N} pages, {L} calls a jit")
+    print(f"device {dev.device_kind}, peak {bw / 1e9:.0f} GB/s; heads {H}:{G} x {hd} (K needed {hk}, V {hv}), pages of {T}, pool {N} pages, {L} calls a jit")
     rng = np.random.default_rng(0)
     window = jnp.int32(a.window) if a.window else None
     for B in (int(x) for x in a.batches.split(",")):
@@ -120,18 +124,18 @@ def main(argv) -> int:
             bt, lens = jnp.asarray(bt), jnp.full((B,), length, jnp.int32)
             q = jax.random.normal(jax.random.fold_in(key, B * length), (B, H, hd), dtype)
             # the expression gathers every slot's whole table in float32: held to the first 4 slots
-            ref = tfm.paged_attention_gather(q[:4], kp[0], vp[0], bt[:4], lens[:4], G, window).astype(jnp.float32)
+            ref = tfm.paged_attention_gather(q[:4], kp[0], vp[0], bt[:4], lens[:4], G, window, **how).astype(jnp.float32)
             for ppb in ppbs:
                 @jax.jit
                 def run(q, kp, vp, bt, lens):
                     def step(q, _):
-                        o = pa.paged_attention(q, kp, vp, 0, bt, lens, n_kv_heads=G, window=window, pages_per_block=ppb)
-                        return q + (o * 1e-3).astype(q.dtype), o
+                        o = pa.paged_attention(q, kp, vp, 0, bt, lens, n_kv_heads=G, window=window, pages_per_block=ppb, **how)
+                        return q.at[..., :hv].add((o * 1e-3).astype(q.dtype)), o
                     _, os_ = jax.lax.scan(step, q, None, length=L)
                     return os_[0]
 
                 err, us = err_and_us(run, (q, kp, vp, bt, lens), ref, a.reps, L)
-                nbytes = 2 * B * min(length, a.window or length) * F * jnp.dtype(dtype).itemsize
+                nbytes = B * min(length, a.window or length) * G * (hk + hv) * jnp.dtype(dtype).itemsize  # the NEEDED bytes: no padding
                 print(json.dumps({
                     "batch": B, "live_length": length, "window": a.window, "pages_per_block": ppb or pa.pick_pages_per_block(T, F, P, dtype),
                     "us_per_call": round(us, 1), "kv_bytes": nbytes, "hbm_peak_share_pct": round(100 * nbytes / bw / (us * 1e-6), 1),
@@ -158,9 +162,11 @@ def prefill(a, dev, peak_flops) -> int:
     for layout in a.layouts.split(","):
         H, G = (int(x) for x in layout.split(":"))
         F = G * hd
+        hv, hk = a.v_head_dim or hd, a.needed_head_dim or hd
+        how = {"scale": hk**-0.5} if hk != hd else {}
         kp = jax.random.normal(key, (1, N, T, F), dtype)
-        vp = jax.random.normal(jax.random.fold_in(key, 1), (1, N, T, F), dtype)
-        print(f"device {dev.device_kind}, peak {peak_flops / 1e12:.0f} TFLOP/s; heads {H}:{G} x {hd}, pages of {T}, pool {N} pages, {L} calls a jit")
+        vp = jax.random.normal(jax.random.fold_in(key, 1), (1, N, T, G * hv), dtype)
+        print(f"device {dev.device_kind}, peak {peak_flops / 1e12:.0f} TFLOP/s; heads {H}:{G} x {hd} (K needed {hk}, V {hv}), pages of {T}, pool {N} pages, {L} calls a jit")
         for C in (int(x) for x in a.chunks.split(",")):
             for length in (512, 1024, 2560, 4096):
                 if length < C:
@@ -170,7 +176,7 @@ def prefill(a, dev, peak_flops) -> int:
                 bt[: length // T] = rng.permutation(np.arange(1, N))[: length // T]
                 bt = jnp.asarray(bt)
                 q = jax.random.normal(jax.random.fold_in(key, C * length), (C, H, hd), dtype)
-                ref = tfm.paged_prefill_attention_gather(q, kp[0], vp[0], bt, start, G).astype(jnp.float32)
+                ref = tfm.paged_prefill_attention_gather(q, kp[0], vp[0], bt, start, G, **how).astype(jnp.float32)
                 for bq in bqs:
                     if bq and C % bq:
                         continue
@@ -180,13 +186,13 @@ def prefill(a, dev, peak_flops) -> int:
                             def step(q, _):
                                 o = pa.paged_prefill_attention(
                                     q, kp, vp, 0, bt, start, length, n_kv_heads=G, block_q=bq, pages_per_block=ppb,
-                                    heads_unrolled=unroll)
-                                return q + (o * 1e-3).astype(q.dtype), o
+                                    heads_unrolled=unroll, **how)
+                                return q.at[..., :hv].add((o * 1e-3).astype(q.dtype)), o
                             _, os_ = jax.lax.scan(step, q, None, length=L)
                             return os_[0]
 
                         err, us = err_and_us(run, (q, kp, vp, bt), ref, a.reps, L)
-                        flops = 4 * hd * H * sum(range(start + 1, length + 1))  # q.K and P.V, row i over i + 1 keys
+                        flops = 2 * (hk + hv) * H * sum(range(start + 1, length + 1))  # q.K and P.V, row i over i + 1 keys
                         picked = pa.pick_prefill_blocks(C, T, F, P, dtype)
                         print(json.dumps({
                             "heads": layout, "chunk": C, "live_length": length, "block_q": bq or picked[0],

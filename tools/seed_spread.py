@@ -4,7 +4,7 @@ seed? (PERF.md T1: a router's selecting bias once made a prefill cost 0.86-0.91
 s by the seed, which spread a whole cell's `serve_tok_s` by 4.5-7 %; two
 chip-minutes here show such a thing before six runs of the cell do.)
 
-    chiprun -- python3 tools/seed_spread.py --workload gigachat35-serve-longanswer-batch --seeds 8 [--prompt 2048] [--steps 8]
+    chiprun -- python3 tools/seed_spread.py --workload gigachat35-serve-longanswer-batch --seeds 8 [--seed-list a,b,c] [--prompt 2048] [--steps 8]
 
 Builds the cell's PagedLM at its configuration's widths once (the benchmark's
 own mapping and weights: `benchmarks/lib/correct.init_weights`), then for each
@@ -33,6 +33,7 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, default=8)
     ap.add_argument("--first-seed", type=int, default=2147484600)
+    ap.add_argument("--seed-list", default="", help="these seeds, comma-separated, in place of --seeds ones from --first-seed: the seeds of a cell's own runs, to set each run's reading beside its seed's prefill (six seeds once read 1.1 % where six others read 4.6 %: give it a dozen; PERF.md §6, PR 61)")
     ap.add_argument("--prompt", type=int, default=2048)
     ap.add_argument("--steps", type=int, default=8)
     args = ap.parse_args()
@@ -50,15 +51,15 @@ def main() -> int:
     cfg = cell.arch.model_config(cell.config)
     eng = {k: v["value"] for k, v in cell.config["assumed"].items()}
     init = jax.jit(lambda k: correct.init_weights(tfm, cfg, k))
-    lm = PagedLM(cfg, init(seeded_key(args.first_seed)), num_pages=eng["pool_pages"], page_tokens=eng["page_tokens"],
+    seeds = [int(x) for x in args.seed_list.split(",")] if args.seed_list else [args.first_seed + i for i in range(args.seeds)]
+    lm = PagedLM(cfg, init(seeded_key(seeds[0])), num_pages=eng["pool_pages"], page_tokens=eng["page_tokens"],
                  max_slots=eng["max_slots"], max_pages_per_seq=eng["max_pages_per_seq"])
     B, T, P = lm.max_slots, lm.page_tokens, lm.max_pages_per_seq
     n_pages = -(-(args.prompt + args.steps) // T)
     tables = [[1 + row * P + j for j in range(n_pages)] for row in range(B)]
     rng = np.random.default_rng(0)
     rows = []
-    for i in range(args.seeds):
-        seed = args.first_seed + i
+    for i, seed in enumerate(seeds):
         if i:
             lm.params = None  # the last seed's weights go before this one's come: two sets do not fit
             lm.params = init(seeded_key(seed))
