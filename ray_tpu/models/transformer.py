@@ -2059,15 +2059,32 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
 # read — the attention mask stops at each sequence's length.
 
 TRASH_PAGE = 0
-# Positions one pass of the layers computes in forward_prefill. The weights
-# are read once a chunk, so a chunk must be worth their pass: 256 rows are
-# the v5e ridge (240 FLOP/byte). What a prefix hit still computes is its
-# uncached span rounded UP to chunks (they start where the cache ends), so
-# it must not be larger than that. Chosen on the chip (PERF.md, PR 31, with
-# chunks laid at multiples of their size): 256 and 512 serve a
-# miss-and-three-hits document alike (a miss costs 11 % more, a hit 23 %
-# less), 256 halves a short suffix's wait; 1 024 is 6 % behind.
+# Positions one pass of the layers computes in forward_prefill: the FLOOR, and
+# the chunk a span's tail is walked in. The weights are read once a chunk, so a
+# chunk must be worth their pass: rows at the v5e ridge (240 FLOP/byte) times
+# (the bytes of the layers' weights a pass reads) / (the bytes one ROW
+# multiplies), `prefill_chunk_rows`. A dense stack multiplies all it reads:
+# 256 rows (PERF.md, PR 31, DeepSeek, chunks laid at multiples of their size:
+# 256 and 512 serve a miss-and-three-hits document alike, a miss 11 % dearer, a
+# hit 23 % cheaper; 256 halves a short suffix's wait; 1 024 is 6 % behind). A
+# routed stack reads every held expert and a row multiplies n_experts_per_tok /
+# n_experts of them, so its ridge lies at 2.5 (GigaChat3.5) to 8.5 (Trinity)
+# times the rows: the head of a long span is walked in BIG chunks of
+# PREFILL_CHUNK_CAP rows (`prefill_big_chunk_tokens`; forward_prefill's
+# `big_chunks`, which serve/llm's PagedLM asks for in one executable for all its
+# buckets) and what is left of it, at least a row and at most a big chunk, in
+# chunks of this size, so that what a prefix hit or a short prompt computes is
+# still its uncached span rounded UP to THIS many rows.
 PREFILL_CHUNK_TOKENS = 256
+# The rows of a big chunk, whatever more the ridge asks, and what a stack's weights must ask for, in whole small chunks,
+# to be given big chunks at all. One prefill of a cell's mean prompt with big chunks of none / 512 / 1 024 rows (PERF.md
+# §6, PR 62, tools/prefill_chunk_bench.py, ms): MiMo-V2.5 (6 912 tokens; asks for 898 rows) 272.5 / 205.1 / 207.3,
+# Trinity (2 048; 2 181) 67.3 / 51.1 / 50.6, Solar-Open2 (1 728; 1 217) 77.7 / 65.9 / 67.4: a second size would buy
+# nothing, and beyond 1 024 rows a chunk's float32 activations leave VMEM (MiMo-V2's rope: 11 ms a prompt at 256 rows,
+# 41 at 1 024). The two stacks that ask for less walk small chunks alone, though a miss reads faster in big ones
+# (GigaChat3.5, 651 rows: 75.8 / 68.3 / 70.4 at 1 152 tokens; dots.vlm1, 692: 1 825 / 1 634 / 1 579 at 14 848): dots.vlm1's
+# cell counts a wave of misses that ends sooner as fewer tokens (PERF.md §7 L2, ROADMAP S8 (h)) and cannot judge it.
+PREFILL_CHUNK_CAP = 1024
 
 
 def _tail_shape(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -2637,7 +2654,12 @@ def _ring_chunk(cfg: TransformerConfig, ctx):
     the chunk (the last `window` of them: what the chunk before left there;
     nothing where the chunk is the prompt's first) and over the chunk's own k
     and v, and leave the last `window` positions below the chunk's end (the
-    length's, in the prompt's last chunk) in the ring."""
+    length's, in the prompt's last chunk) in the ring. A chunk of several
+    windows is attended a block of rows at a time, each beside the `window`
+    positions below it (`bands`): a row sees no further, and the masked
+    product of all rows by all keys grows with the chunk's rows squared."""
+    from ..ops.paged_attention import largest_divisor
+
     slot, c0, rows, ring, kvh = ctx["slot"], ctx["c0"], ctx["rows"], max(cfg.windows), cfg.window_kv_heads
     before = _ring_rows(c0 - 1, ring)  # what the ring's rows hold as the chunk starts
     q_pos = c0 + jnp.arange(rows)
@@ -2646,6 +2668,16 @@ def _ring_chunk(cfg: TransformerConfig, ctx):
     seen = (back >= 0) & (back < ring) & (k_pos >= 0)[None, :]
     after = _ring_rows(jnp.minimum(c0 + rows, ctx["length"]) - 1, ring)  # and as it ends: from the chunk where that is a row of it
     from_chunk, chunk_row = (after >= c0)[:, None], jnp.clip(after - c0, 0, rows - 1)
+    # Blocks of at least a window's rows (and a small chunk's): only the first sees what the ring held.
+    blocks = largest_divisor(rows, max(1, rows // max(ring, PREFILL_CHUNK_TOKENS)))
+    block = rows // blocks
+
+    def bands(t):
+        """t [ring + rows, ...], the ring's rows then the chunk's -> [blocks, ring + block, ...]: block b's rows
+        [b * block, (b + 1) * block) of the chunk behind the `ring` rows of t before them."""
+        return jnp.stack([t[b * block : (b + 1) * block + ring] for b in range(blocks)])
+
+    seen = jnp.stack([seen[b * block : (b + 1) * block, b * block : (b + 1) * block + ring] for b in range(blocks)])
 
     def attend_in(where: LayerPlace, pool):
         layer, rk, rv = where.layer, pool["ring_k"], pool["ring_v"]
@@ -2655,7 +2687,7 @@ def _ring_chunk(cfg: TransformerConfig, ctx):
                 k_in, v_in = rk[layer, slot], rv[layer, slot]
                 keys = jnp.concatenate([k_in.reshape(ring, kvh, -1), k[0]])
                 values = jnp.concatenate([v_in.reshape(ring, kvh, -1), v[0]])
-                o = _ring_attention(q, keys[None], values[None], seen[None], sink)
+                o = _ring_attention(q.reshape(blocks, block, *q.shape[2:]), bands(keys), bands(values), seen, sink).reshape(1, rows, q.shape[2], -1)
                 k_out = jnp.where(from_chunk, k[0].reshape(rows, -1)[chunk_row], k_in)
                 v_out = jnp.where(from_chunk, v[0].reshape(rows, -1)[chunk_row], v_in)
                 return o.astype(cfg.dtype), (rk.at[layer, slot].set(k_out), rv.at[layer, slot].set(v_out))
@@ -2783,6 +2815,35 @@ def init_kv_pages(
     return pool
 
 
+@functools.lru_cache(maxsize=None)
+def _layer_weight_bytes(cfg: TransformerConfig) -> Tuple[int, int]:
+    """(bytes of the layers' weights one pass of a chunk reads, the held experts
+    whole; n_experts times the bytes one ROW multiplies: all of a dense layer, of
+    a routed one its router, shared expert and n_experts_per_tok / n_experts of
+    the experts it holds), from the leaves `init_params` draws: embedding, head
+    and final norm are no chunk's pass."""
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+
+    def size(tree) -> int:
+        return sum(math.prod(leaf.shape) * leaf.dtype.itemsize for leaf in jax.tree_util.tree_leaves(tree))
+
+    read = multiplied = 0
+    for tree in {m.tree for _, members in stack_plan(cfg) for m in members}:
+        riding, experts = _experts_in_place(shapes[tree])
+        read += size(riding) + size(experts)
+        multiplied += size(riding) * (cfg.n_experts or 1) + size(experts) * cfg.n_experts_per_tok
+    return read, multiplied
+
+
+def prefill_chunk_rows(cfg: TransformerConfig) -> int:
+    """Rows of a chunk worth one pass of this model's weights: PREFILL_CHUNK_TOKENS
+    (a dense stack's ridge, where a row multiplies every weight the pass reads)
+    times what the pass reads over what a row multiplies, at most
+    PREFILL_CHUNK_CAP. The config alone decides: static wherever it is asked."""
+    read, multiplied = _layer_weight_bytes(cfg)
+    return min(PREFILL_CHUNK_CAP, -(-PREFILL_CHUNK_TOKENS * read * (cfg.n_experts or 1) // multiplied))
+
+
 def prefill_chunk_tokens(cfg: TransformerConfig, bucket_pages: int, page_tokens: int) -> Tuple[int, int]:
     """(positions one chunk of forward_prefill computes, the granule its first
     chunk is anchored to) for a bucket of that many pages. K/V pages: whole
@@ -2803,6 +2864,21 @@ def prefill_chunk_pages(bucket_pages: int, page_tokens: int) -> int:
     return largest_divisor(bucket_pages, max(1, PREFILL_CHUNK_TOKENS // page_tokens))
 
 
+def prefill_big_chunk_tokens(cfg: TransformerConfig, page_tokens: int) -> int:
+    """Positions one BIG chunk computes (forward_prefill with `big_chunks`), or
+    0 where the model has none: PREFILL_CHUNK_CAP rows, for a stack whose
+    weights ask for that many in whole small chunks (`prefill_chunk_rows`
+    rounded up to PREFILL_CHUNK_TOKENS). A dense stack's weights ask for no
+    more than the floor; one that asks for two or three small chunks' rows
+    walks small chunks (PREFILL_CHUNK_CAP's comment); a state alone keeps
+    PREFILL_CHUNK_TOKENS positions whatever its weights ask (retention's
+    chunked form takes the chunk as its block: `flops_per_token`)."""
+    small = max(1, PREFILL_CHUNK_TOKENS // page_tokens) * page_tokens  # a small chunk, in whole pages
+    big = PREFILL_CHUNK_CAP // small * small
+    asked = -(-prefill_chunk_rows(cfg) // PREFILL_CHUNK_TOKENS) * PREFILL_CHUNK_TOKENS
+    return big if cache_layout(cfg).kv and big > small and asked >= PREFILL_CHUNK_CAP else 0
+
+
 def prefill_chunk_span(length, write_from, chunk_tokens: int, page_tokens: int, minimum=min, maximum=max):
     """(anchor, count) of the chunks forward_prefill computes: chunk i covers
     positions [anchor + i * chunk_tokens, anchor + (i + 1) * chunk_tokens).
@@ -2811,7 +2887,9 @@ def prefill_chunk_span(length, write_from, chunk_tokens: int, page_tokens: int, 
     ceil((length - anchor) / chunk_tokens). The last position's chunk is
     always computed, its logits are the result: where the cache holds the
     whole prompt the anchor is the last position's page. Python ints (PagedLM
-    counts computed tokens with it), or traced scalars with jnp's minimum /
+    counts computed tokens with it, and with a big chunk's size the big chunks
+    of a span: all of them but the last, which holds the last position and is
+    the small chunks' to walk), or traced scalars with jnp's minimum /
     maximum."""
     last = maximum(length - 1, 0)
     anchor = minimum(write_from, last) // page_tokens * page_tokens
@@ -2827,6 +2905,7 @@ def forward_prefill(
     length: jax.Array,
     write_from: jax.Array,
     slot=TRASH_PAGE,
+    big_chunks=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Prefill ONE sequence: computes what the cache does not hold and
     writes it into the paged pool.
@@ -2841,6 +2920,12 @@ def forward_prefill(
       multiple of the page; 0 is a miss, which runs the same loop.
     slot: scalar, the sequence's state slot, of a model that keeps one
       (`KINDS`; the trash slot from a caller that names none).
+    big_chunks: scalar, or None. Given, the call walks that many BIG chunks
+      (`prefill_big_chunk_tokens`) from write_from on and nothing else, and
+      returns no logits: a model whose weights ask for more rows a pass than
+      PREFILL_CHUNK_TOKENS has the head of a long span computed so, and the
+      rest of it by a second call with `write_from` moved behind them (what the
+      big chunks left in pages, slots and rings is the cache that call reads).
 
     The uncached span is walked in chunks (`prefill_chunk_tokens`) with a
     dynamic trip count (`prefill_chunk_span`): the first chunk starts
@@ -2856,7 +2941,7 @@ def forward_prefill(
     layout = cache_layout(cfg)
     _, S = tokens.shape
     T = kv_pages[layout.paged].shape[2] if layout.kv else S // block_table.shape[0]
-    C, granule = prefill_chunk_tokens(cfg, S // T, T)
+    C, granule = prefill_chunk_tokens(cfg, S // T, T) if big_chunks is None else (prefill_big_chunk_tokens(cfg, T), T)
     # What a chunk slices is padded by a chunk: the last one starts at a
     # page below the length, not at a multiple of C, and a dynamic slice
     # that ran past the end would be moved back silently.
@@ -2866,6 +2951,8 @@ def forward_prefill(
     dest_table = jnp.pad(block_table, (0, C // T), constant_values=TRASH_PAGE) if layout.kv else None
     call = dict(block_table=block_table, dest_table=dest_table, length=length, write_from=write_from, slot=slot, rows=C, page_tokens=T)
     anchor, n_chunks = prefill_chunk_span(length, write_from, C, granule, jnp.minimum, jnp.maximum)
+    if big_chunks is not None:
+        n_chunks = big_chunks
 
     def chunk_step(i, carry):
         *pool, _ = carry
@@ -2891,6 +2978,8 @@ def forward_prefill(
 
     h_last = jnp.zeros((cfg.d_model,), cfg.dtype)
     *pool, h_last = lax.fori_loop(0, n_chunks, chunk_step, (*(kv_pages[name] for name in layout.names), h_last))
+    if big_chunks is not None:
+        return None, dict(zip(layout.names, pool))
     h_last = _norm(h_last[None, :], params["final_norm"]["scale"], cfg)
     return _logits(params, h_last), dict(zip(layout.names, pool))
 

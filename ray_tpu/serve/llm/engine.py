@@ -642,6 +642,9 @@ class InferenceEngine:
                         tok, "computed_tokens", len(seq.prompt) - seq.pages.cached_tokens
                     )
                     clk["computed_tokens"] += attrs["computed_tokens"]
+                    walked = getattr(tok, "counters", {}).get("prefill_chunks")
+                    if walked:  # PagedLM: the span's whole big chunks, then its tail in small ones
+                        attrs.update(big_chunks=walked["big"], small_chunks=walked["small"])
                     self._add_counters(tok)
                     tok = int(tok)
                 self._returned()
@@ -831,7 +834,11 @@ class InferenceEngine:
         prefill.tokens: prompt tokens of those calls,
         cached ones included; prefill.computed_tokens: positions their
         executables computed, as the model reports them: the uncached span
-        rounded up to whole chunks, cached positions not; decode.kv_pages: over the completed decode steps, the
+        rounded up to whole chunks, cached positions not; prefill_chunks
+        (PagedLM): the chunks those spans were walked in, `big` ones of
+        `big_rows` positions (transformer.prefill_chunk_rows: a model whose
+        weights ask for more rows a pass than the floor) and `small` ones, of
+        `rows` computed in all; decode.kv_pages: over the completed decode steps, the
         pages their live lengths cover (what a step must read) against
         slots x pages a sequence (what a step that gathers the block
         tables reads); decode_window (a model with attention windows only):

@@ -269,6 +269,10 @@ class PagedLM:
         self._state_passes = 1 if "window" in dict(layout.kinds) else 2
         self._decode_jit = None
         self._prefill_jits: Dict[int, Any] = {}
+        # Positions of a big prefill chunk (transformer.prefill_big_chunk_tokens; 0: this model's weights ask for no more
+        # rows a pass than the small chunk's) and the ONE executable that walks them, whatever the prompt's bucket.
+        self._big_chunk = tfm.prefill_big_chunk_tokens(cfg, page_tokens)
+        self._prefill_big_jit = None
         # The last decode step's result vector, on the device: the next step's
         # operand, from which a row marked -1 takes its token (StepTokens
         # `deferred`). Until a step has run, zeros of that vector's shape (the
@@ -373,6 +377,19 @@ class PagedLM:
             self._prefill_jits[n_pages_bucket] = fn
         return fn
 
+    def _get_prefill_big(self):
+        if self._prefill_big_jit is None:
+            cfg, tfm = self.cfg, self._tfm
+
+            def step(params, tokens, kv, block_table, length, write_from, chunks, *slot):
+                _, kv = tfm.forward_prefill(params, tokens, cfg, kv, block_table, length, write_from, *slot, big_chunks=chunks)
+                return kv
+
+            # jit_llm_prefill_big (jit_llm_prefill_hybrid_big): one for every bucket, over the longest block table
+            step.__name__ = f"llm_prefill{self._suffix}_big"
+            self._prefill_big_jit = self._jax.jit(step, donate_argnums=self._donate((2,)))
+        return self._prefill_big_jit
+
     def _bucket_pages(self, n_pages: int) -> int:
         return min(self.max_pages_per_seq, 1 << max(0, math.ceil(math.log2(n_pages))))
 
@@ -455,40 +472,48 @@ class PagedLM:
         row = getattr(prompt, "slot", None)
         slot = (np.int32(TRASH_PAGE if row is None else row + 1),) if "slot" in self.layout.indexed.values() else ()
         chunk, granule = self._tfm.prefill_chunk_tokens(self.cfg, bucket, T)
-        _anchor, chunks = self._tfm.prefill_chunk_span(len(prompt), int(cached_tokens), chunk, granule)
-        attrs = {"bucket_tokens": S, "computed_tokens": chunks * chunk}
+        # A long span's head in big chunks: all of the span's big chunks but the last, which holds the last position and,
+        # whole or not, is walked in small ones behind them by the bucket's executable, from where the big ones ended.
+        big, small_from = self._big_chunk, int(cached_tokens)
+        start, n_big = self._tfm.prefill_chunk_span(len(prompt), small_from, big or chunk, granule)  # where the cache ends
+        n_big = n_big - 1 if big else 0
+        if n_big:
+            small_from = start + n_big * big
+        _, chunks = self._tfm.prefill_chunk_span(len(prompt), small_from, chunk, granule)
+        computed = n_big * big + chunks * chunk
+        attrs = {"bucket_tokens": S, "computed_tokens": computed, "big_chunks": n_big, "small_chunks": chunks}
         with _tracing.span("llm.prefill.prep", attrs, device=True):
             toks = np.zeros((1, S), dtype=np.int32)
             toks[0, : len(prompt)] = np.asarray(prompt, dtype=np.int32)
             bt = np.full((bucket,), TRASH_PAGE, dtype=np.int32)
             bt[: len(pages)] = np.asarray(pages, dtype=np.int32)
             fn = self._get_prefill(bucket)
-        tok = self._run_step(
-            lambda kv: fn(
-                self.params,
-                toks,
-                kv,
-                bt,
-                np.int32(len(prompt)),
-                np.int32(cached_tokens),
-                *slot,
-            ),
-            "llm.prefill",
-            attrs,
-            getattr(prompt, "launched", None),  # the engine's PromptTokens; a bare list from anyone else
-        )
-        counters = {}
+            if n_big:  # the same prompt and table at the longest bucket's shapes: the big chunks' one executable
+                longest = self._bucket_pages(self.max_pages_per_seq)
+                big_toks, big_bt = np.zeros((1, longest * T), dtype=np.int32), np.full((longest,), TRASH_PAGE, dtype=np.int32)
+                big_toks[0, :S], big_bt[:bucket] = toks[0], bt
+                big_fn = self._get_prefill_big()
+
+        def call(kv):
+            if n_big:  # launched first; the bucket's call queues behind it on the pool it hands on
+                kv = big_fn(self.params, big_toks, kv, big_bt, np.int32(len(prompt)), np.int32(cached_tokens), np.int32(n_big), *slot)
+            return fn(self.params, toks, kv, bt, np.int32(len(prompt)), np.int32(small_from), *slot)
+
+        tok = self._run_step(call, "llm.prefill", attrs, getattr(prompt, "launched", None))  # the engine's PromptTokens; a bare list from anyone else
+        counters = {"prefill_chunks": {"big": n_big, "small": chunks, "big_rows": n_big * big, "rows": computed}}
+        chunks += n_big
         if self.layout.state:
             # The chunks that started from the state their predecessor left (all but a prompt's first).
             counters["prefill_state"] = {"chunks": chunks, "carried_in": chunks - 1}
         if self.cfg.n_experts:
             # A routed model: the rows its routed layers' experts were handed, and those of them sorted to their own experts.
-            rows = chunks * chunk * (self.cfg.n_layers - self.cfg.n_dense_layers)
-            counters["prefill_experts"] = {"rows": rows, "grouped_rows": rows if self._tfm.experts_grouped_at(chunk) else 0, "chunks": chunks}
+            routed_layers = self.cfg.n_layers - self.cfg.n_dense_layers
+            grouped = sum(n * rows for n, rows in ((n_big, big), (chunks - n_big, chunk)) if self._tfm.experts_grouped_at(rows))
+            counters["prefill_experts"] = {"rows": computed * routed_layers, "grouped_rows": grouped * routed_layers, "chunks": chunks}
         if self._latent_layers:
             # The (query, key) pairs of the call's computed rows below the length, a latent layer each (every one
             # attended absorbed: transformer._latent_chunk is the one serving form).
-            n, first = len(prompt), min(_anchor, len(prompt))
+            n, first = len(prompt), min(start, len(prompt))
             pairs = self._latent_layers * (n * (n + 1) - first * (first + 1)) // 2
             counters["prefill_latent"] = {"pairs": pairs, "calls": 1}
         return PrefillToken(tok, attrs["computed_tokens"], counters)

@@ -130,6 +130,7 @@ def test_prefill_hit_or_miss_then_decode_past_the_window_in_a_64_slot_batch(monk
     over two windows' worth and several page edges, every live row's logits
     against the reference's full forward."""
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", 2 * T)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_CAP", 2 * T)  # small chunks alone: big ones and a tail are tests/test_prefill_chunks.py's
     cfg, params = seeded(3)
     kv = tfm.init_kv_pages(cfg, 1 + len(LIVE) * PAGES_PER_SEQ, T)
     prefill = jax.jit(lambda tokens, kv, table, length, write_from: tfm.forward_prefill(params, tokens, cfg, kv, table, length, write_from))
@@ -168,6 +169,7 @@ def test_a_hits_chunks_start_where_the_cache_ends_and_give_the_misss_logits(monk
     cached the last position's page alone is computed and nothing written."""
     length, cached = case
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", 2 * T)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_CAP", 2 * T)  # small chunks alone: big ones and a tail are tests/test_prefill_chunks.py's
     cfg, params = seeded(7)
     tokens = tokens_of(40, length)
     bucket = 1 << (-(-length // T) - 1).bit_length()
@@ -282,6 +284,7 @@ def test_the_engine_serves_it_with_prefix_hits_and_counts_experts_and_windows(mo
     of an engine-free greedy loop, a prefix hit, and the two counters a decode
     step's router and windows feed."""
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", 2 * T)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_CAP", 2 * T)  # small chunks alone: big ones and a tail are tests/test_prefill_chunks.py's
     cfg, params = seeded(5)
     first = [int(t) for t in tokens_of(30, 45)]
     second = first[:40] + [int(t) for t in tokens_of(31, 19)]

@@ -44,6 +44,7 @@ T = 8  # positions a latent page
 @pytest.fixture(autouse=True)
 def small_chunks_at_highest_precision(monkeypatch):
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", CHUNK)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_CAP", CHUNK)  # small chunks alone: big ones and a tail are tests/test_prefill_chunks.py's
     with jax.default_matmul_precision("highest"):
         yield
 
@@ -258,7 +259,7 @@ def test_paged_lm_says_both_paths_and_counts_both_caches():
     prompt.slot = 1  # the engine's admission: decode row 1, so state slot 2
     first = lm.prefill(prompt, [1, 2, 3, 4], 0)
     assert int(first) == int(jnp.argmax(want[0]))
-    assert set(first.counters) == {"prefill_state", "prefill_experts", "prefill_latent"}
+    assert set(first.counters) == {"prefill_chunks", "prefill_state", "prefill_experts", "prefill_latent"}
     assert first.counters["prefill_latent"] == {"pairs": 2 * 30 * 31 // 2, "calls": 1}  # two latent layers
     assert first.counters["prefill_experts"]["rows"] == 2 * CHUNK * 8  # two chunks through the eight routed layers
     assert np.any(np.asarray(lm.kv["s"])[:, 2] != 0) and not np.any(np.asarray(lm.kv["s"])[:, 1] != 0)

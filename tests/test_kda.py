@@ -37,6 +37,7 @@ T = 8  # positions a K/V page
 @pytest.fixture(autouse=True)
 def small_chunks_at_highest_precision(monkeypatch):
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", CHUNK)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_CAP", CHUNK)  # small chunks alone: big ones and a tail are tests/test_prefill_chunks.py's
     with jax.default_matmul_precision("highest"):
         yield
 

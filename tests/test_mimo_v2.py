@@ -40,6 +40,7 @@ def highest_precision():
 def small_chunks(monkeypatch, chunk):
     """PREFILL_CHUNK_TOKENS in a test: a chunk is then `chunk // T` pages."""
     monkeypatch.setattr(tfm, "PREFILL_CHUNK_TOKENS", chunk)
+    monkeypatch.setattr(tfm, "PREFILL_CHUNK_CAP", chunk)  # small chunks alone: big ones and a tail are tests/test_prefill_chunks.py's
 
 
 @functools.lru_cache(maxsize=None)
@@ -236,7 +237,7 @@ def test_paged_lm_says_both_paths_and_counts_both_caches(monkeypatch):
     prompt.slot = 1  # the engine's admission: decode row 1, so ring slot 2
     first = lm.prefill(prompt, [1, 2, 3, 4], 0)
     assert int(first) == int(jnp.argmax(want[0]))
-    assert set(first.counters) == {"prefill_state", "prefill_experts"}
+    assert set(first.counters) == {"prefill_chunks", "prefill_state", "prefill_experts"}
     assert first.counters["prefill_state"] == {"chunks": 2, "carried_in": 1}
     assert first.counters["prefill_experts"]["rows"] == 2 * 16 * 6  # two chunks through the six routed layers
     assert np.any(np.asarray(lm.kv["ring_k"])[:, 2] != 0) and not np.any(np.asarray(lm.kv["ring_k"])[:, 1] != 0)

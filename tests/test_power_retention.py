@@ -477,23 +477,24 @@ def test_a_prefill_at_the_kernels_head_width_goes_through_it_and_says_so():
 # and the lowered text of forward_decode, of forward_prefill, of forward_decode with `stats` as PagedLM asks it of a routed
 # model, of the whole-sequence `forward`, of the training loss's gradient where the configuration has a training cell, and
 # for Mistral of the ZeRO train step over four devices (the four-chip cell's program, where PR 48's benchmark run failed).
-# The first four cells' first three digests are what PR 42 recorded before the state's path existed.
+# The first four cells' first three digests are what PR 42 recorded before the state's path existed. `prefill_long` (PR 62): forward_prefill
+# over a bucket of 2 048 positions, eight chunks of 256 rows where `prefill`'s 64 positions are one chunk whatever a chunk's size, taken at the parent of PR 62 (d329e78).
 PARENT = {
     "mistral7b-train-seq4k-1chip": dict(
-        params="00146abe9d7f8cbe", decode="5b205ddfdad764ec", prefill="acca1b330f25d98a", forward="cab927e22fe03603", grad="90e3cc1409de622b",
+        prefill_long="81d58ff5d6cb5866", params="00146abe9d7f8cbe", decode="5b205ddfdad764ec", prefill="acca1b330f25d98a", forward="cab927e22fe03603", grad="90e3cc1409de622b",
         zero_step="dc207b56dddcc81b"),
     "dsllm7b-serve-chat-steady": dict(
-        params="00146abe9d7f8cbe", decode="c7f2192c2a02f3b2", prefill="114685abbe26da57", forward="1e18187b873812c1"),
+        prefill_long="ad8d462a1b39b2b5", params="00146abe9d7f8cbe", decode="c7f2192c2a02f3b2", prefill="114685abbe26da57", forward="1e18187b873812c1"),
     "olmoe-train-seq4k-1chip": dict(
-        params="9fc2e6536f721f59", decode="125d9f6811579117", prefill="cd4027dbb914c125", decode_stats="dd2fac39bb166a76",
+        prefill_long="08233f552c9add63", params="9fc2e6536f721f59", decode="125d9f6811579117", prefill="cd4027dbb914c125", decode_stats="dd2fac39bb166a76",
         forward="e26fcc5eff7c9a50", grad="1b95257862785e87"),
     "trinitymini-serve-agent-turns": dict(
-        params="dd98a4937de87222", decode="cd393207dca6cc51", prefill="ec3e421d2af401fa", decode_stats="46c3d2dbe563e6ee",
+        prefill_long="a3c3d5e02da7ad31", params="dd98a4937de87222", decode="cd393207dca6cc51", prefill="ec3e421d2af401fa", decode_stats="46c3d2dbe563e6ee",
         forward="bb34c9f1b88a8e6a"),
     "brumby14b-serve-longgen-batch": dict(
-        params="0dbcfc63962591b0", decode="75b94718751bcdc8", prefill="3153248bbbc0868d", forward="304722bdd829cd50"),
+        prefill_long="23473e22899dcdc0", params="0dbcfc63962591b0", decode="75b94718751bcdc8", prefill="3153248bbbc0868d", forward="304722bdd829cd50"),
     "solaropen2-serve-reasoning-batch": dict(
-        params="fa24a5892e238707", decode="4940be13c58a600f", prefill="1e82441d7698dd38", decode_stats="19e20912f9c8350d",
+        prefill_long="883b5337800e6080", params="fa24a5892e238707", decode="4940be13c58a600f", prefill="1e82441d7698dd38", decode_stats="19e20912f9c8350d",
         forward="2c99e380bd5aba4c"),
 }
 
@@ -566,6 +567,8 @@ def test_the_accepted_architectures_draw_the_weights_and_lower_to_the_text_they_
         "decode": lambda: decode(False),
         "prefill": lambda: jax.jit(lambda p, t, kv, bt, n, w, *s: tfm.forward_prefill(p, t, cfg, kv, bt, n, w, *s)).lower(
             params, i32(1, S), kv, i32(P), i32(), i32(), *slot),
+        "prefill_long": lambda: jax.jit(lambda p, t, kv, bt, n, w, *s: tfm.forward_prefill(p, t, cfg, kv, bt, n, w, *s)).lower(
+            params, i32(1, 2048), kv, i32(1 if cfg.retention_degree else 128), i32(), i32(), *slot),
         "decode_stats": lambda: decode(True),
         "forward": lambda: jax.jit(lambda p, t: tfm.forward(p, t, cfg)).lower(params, i32(2, 32)),
         "grad": lambda: jax.jit(jax.grad(lambda p, t: tfm.next_token_loss(p, t, cfg))).lower(params, i32(2, 32)),

@@ -222,6 +222,29 @@ def test_a_routed_models_chunk_takes_the_grouped_kernels_on_the_stack_in_place_a
     assert f"[{E},{rows},{f}]" not in prefill and f"[{E},{SLOTS},{f}]" in decode
 
 
+def test_a_routed_models_big_chunk_compiles_at_1024_rows_and_keeps_the_pool_in_place(one_chip, mosaic):
+    """At Trinity-Mini's widths the weights a pass reads are 8.5 times what a
+    row multiplies: `forward_prefill` with `big_chunks` walks 1 024-row chunks
+    (PR 62). Mosaic takes the paged prefill kernel at four blocks of 256 rows
+    and both grouped kernels at 8 192 (row, expert) pairs, once each a scan
+    body; the pool is aliased and no layer's experts are copied out."""
+    from ray_tpu.ops import grouped_matmul as gm
+
+    cfg = tfm.TransformerConfig(**ROUTED)
+    assert tfm.prefill_big_chunk_tokens(cfg, PAGE_TOKENS) == tfm.PREFILL_CHUNK_CAP == 1024 and tfm.prefill_big_chunk_tokens(tfm.TransformerConfig(), PAGE_TOKENS) == 0
+    sds = _sds(one_chip)
+    shapes = lambda make: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(make))  # noqa: E731
+    params, kv = shapes(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)), shapes(lambda: tfm.init_kv_pages(cfg, POOL_PAGES, PAGE_TOKENS))
+    scalar = sds((), jnp.int32)
+    compiled = jax.jit(lambda p, t, kv, bt, n, w, chunks: tfm.forward_prefill(p, t, cfg, kv, bt, n, w, big_chunks=chunks)[1], donate_argnums=(2,)).lower(
+        params, sds((1, TABLE_PAGES * PAGE_TOKENS), jnp.int32), kv, sds((TABLE_PAGES,), jnp.int32), scalar, scalar, scalar).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for name in (pa.PREFILL_KERNEL_NAME, gm.SWIGLU_KERNEL_NAME, gm.MATMUL_KERNEL_NAME):
+        assert len(re.findall(rf"custom_call_target=\"tpu_custom_call\".*{name}", text)) >= 1, name
+    assert f"[{cfg.n_experts},1024,{cfg.d_ff}]" not in text and not _copies_over(text, 2**24)
+    assert mem.alias_size_in_bytes >= sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree_util.tree_leaves(kv))
+
+
 @pytest.mark.parametrize("step,pages", [("decode", None), ("prefill", 2), ("prefill", 8)])
 def test_paged_executables_carry_their_names_into_the_module(one_chip, step, pages):
     """PagedLM's jitted closures are named for what they are, so a device

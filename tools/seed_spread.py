@@ -28,6 +28,25 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+def cell_paged_lm(workload: str, seed: int):
+    """(the cell's PagedLM at its configuration's widths under `seed`'s weights, seed -> that seed's weights): the
+    benchmark's own mapping and weights. tools/prefill_chunk_bench.py builds its model here too."""
+    import jax
+
+    from benchmarks.lib import correct, spec
+    from benchmarks.lib.worker_train import seeded_key
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.serve.llm.model import PagedLM
+
+    cell = spec.find_cell(workload)
+    cfg = cell.arch.model_config(cell.config)
+    eng = {k: v["value"] for k, v in cell.config["assumed"].items()}
+    init = jax.jit(lambda k: correct.init_weights(tfm, cfg, k))
+    lm = PagedLM(cfg, init(seeded_key(seed)), num_pages=eng["pool_pages"], page_tokens=eng["page_tokens"],
+                 max_slots=eng["max_slots"], max_pages_per_seq=eng["max_pages_per_seq"])
+    return lm, lambda seed: init(seeded_key(seed))
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -41,19 +60,11 @@ def main() -> int:
     import jax
     import numpy as np
 
-    from benchmarks.lib import correct, spec
     from benchmarks.lib.stats import iqr_share
-    from benchmarks.lib.worker_train import seeded_key
-    from ray_tpu.models import transformer as tfm
-    from ray_tpu.serve.llm.model import PagedLM, PromptTokens
+    from ray_tpu.serve.llm.model import PromptTokens
 
-    cell = spec.find_cell(args.workload)
-    cfg = cell.arch.model_config(cell.config)
-    eng = {k: v["value"] for k, v in cell.config["assumed"].items()}
-    init = jax.jit(lambda k: correct.init_weights(tfm, cfg, k))
     seeds = [int(x) for x in args.seed_list.split(",")] if args.seed_list else [args.first_seed + i for i in range(args.seeds)]
-    lm = PagedLM(cfg, init(seeded_key(seeds[0])), num_pages=eng["pool_pages"], page_tokens=eng["page_tokens"],
-                 max_slots=eng["max_slots"], max_pages_per_seq=eng["max_pages_per_seq"])
+    lm, weights_of = cell_paged_lm(args.workload, seeds[0])
     B, T, P = lm.max_slots, lm.page_tokens, lm.max_pages_per_seq
     n_pages = -(-(args.prompt + args.steps) // T)
     tables = [[1 + row * P + j for j in range(n_pages)] for row in range(B)]
@@ -62,7 +73,7 @@ def main() -> int:
     for i, seed in enumerate(seeds):
         if i:
             lm.params = None  # the last seed's weights go before this one's come: two sets do not fit
-            lm.params = init(seeded_key(seed))
+            lm.params = weights_of(seed)
         prompt = PromptTokens([int(t) for t in rng.integers(1, lm.vocab, args.prompt)])
         prompt.slot = 0
         lm.prefill(prompt, tables[0][: -(-args.prompt // T)], 0)  # the first call of a shape compiles
